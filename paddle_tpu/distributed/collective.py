@@ -49,7 +49,6 @@ def _value(x):
 
 @functools.lru_cache(maxsize=64)
 def _allreduce_fn(mesh, axis, op):
-    from ..utils.jax_compat import shard_map
     if op == "prod":
         # no pprod primitive: gather shards then reduce on each device
         def body(v):
@@ -63,8 +62,8 @@ def _allreduce_fn(mesh, axis, op):
         def body(v):
             return red(v)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                             out_specs=P()))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(), check_vma=False))
 
 
 def all_reduce(tensor, op=ReduceOp.SUM, group=None, sync_op=True, axis="dp"):

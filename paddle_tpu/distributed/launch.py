@@ -5,7 +5,9 @@ Reference counterpart: distributed/launch.py:221 + fleet/launch.py:300
 plus the fleet elastic controller's relaunch-on-loss behavior. On TPU,
 devices within a host belong to ONE process (single-controller), so the
 unit of gang membership is the HOST process; `--nproc_per_node` > 1 is the
-single-host multi-process simulation used by tests and CPU meshes.
+single-host multi-process simulation used by tests and CPU meshes, and is
+refused unless JAX_PLATFORMS=cpu (`require_cpu_for_multiproc`): no chip is
+assigned to any child, so on a TPU host they would all open all of them.
 
 Unlike the reference's fire-and-forget spawn loop, this launcher is a
 SUPERVISOR — trainer loss is a first-class event (ROADMAP item 5):
@@ -152,6 +154,21 @@ def _parse_args(argv=None):
     p.add_argument("training_script", type=str)
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return p.parse_args(argv)
+
+
+def require_cpu_for_multiproc(nproc: int, env=None) -> None:
+    """Several processes on one host are the CPU simulation only. Neither
+    this launcher nor `spawn` assigns a chip to a process (plan_gang
+    exports ranks and a coordinator, nothing else), so on a TPU host every
+    child would open every chip and all but the first would hang. Refuse
+    unless the children's environment pins JAX to the CPU."""
+    env = os.environ if env is None else env
+    if nproc > 1 and env.get("JAX_PLATFORMS", "") != "cpu":
+        raise RuntimeError(
+            f"{nproc} processes on one host would each open every "
+            "accelerator on it: there is no per-process chip assignment. "
+            "On a TPU host run ONE process (it drives all local chips); "
+            "set JAX_PLATFORMS=cpu for the multi-process CPU simulation.")
 
 
 def plan_gang(ips: List[str], port: int, nproc_per_node: int,
@@ -591,7 +608,16 @@ class GangSupervisor:
 
 
 def launch(argv=None):
-    sup = GangSupervisor(_parse_args(argv))
+    args = _parse_args(argv)
+    try:
+        # every rank of the plan is started on THIS host (_spawn)
+        require_cpu_for_multiproc(
+            len([ip for ip in args.ips.split(",") if ip.strip()])
+            * max(args.nproc_per_node, 1))
+    except RuntimeError as e:
+        print(f"[launch] REFUSED: {e}", file=sys.stderr, flush=True)
+        raise SystemExit(2)
+    sup = GangSupervisor(args)
     try:
         rc = sup.run()
     except Exception as e:

@@ -7,6 +7,8 @@ first failure). TPU note: within one host all chips belong to ONE process
 (single-controller jax), so nprocs>1 here means multi-host-style simulation
 processes — each worker gets its own rank/endpoint env exactly like the
 reference, and sharding tests use the virtual CPU mesh inside each worker.
+No chip is assigned to a worker, so nprocs>1 is refused unless the workers'
+environment pins JAX_PLATFORMS=cpu (launch.require_cpu_for_multiproc).
 """
 from __future__ import annotations
 
@@ -85,6 +87,9 @@ class SpawnContext:
 def spawn(func, args=(), nprocs=1, join=True, daemon=False, **options):
     """Launch `func` in nprocs processes with the trainer env contract.
     Returns a SpawnContext (reference spawn.py return)."""
+    from .launch import require_cpu_for_multiproc
+    require_cpu_for_multiproc(
+        nprocs, {**os.environ, **(options.get("env") or {})})
     ctx = mp.get_context(options.get("start_method", "spawn"))
     ports = free_ports(nprocs)
     endpoints = [f"127.0.0.1:{p}" for p in ports]

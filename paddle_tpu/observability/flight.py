@@ -13,7 +13,7 @@ the trace ring (observability/trace.py) are serialized by dump() when:
 * the step hang watchdog trips (`FLAGS_step_deadline_ms`,
   framework/executor.py `_deadline_call`) — next to the thread-stack dump;
 * the gang supervisor fails a launch (distributed/launch.py);
-* bench.py records a degraded row (tunnel_degraded / probe timeout).
+* a bench.py row raises (the record carries the dump path).
 
 Overhead when nothing is wrong: two metrics snapshots (a locked dict copy
 of ~tens of entries) per step — bounded with the tracer's ≤5% A/B in

@@ -37,10 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# API drift: new jax names the TPU compiler-params struct
-# pltpu.CompilerParams; 0.4.x calls it TPUCompilerParams — same fields
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from . import interpret_mode
 
 _LANES = 128  # Mosaic lane width; lse stored broadcast over it
 
@@ -94,12 +91,6 @@ def _keep_mask(seed, head, q_off, k_off, block_q, block_k, rate):
     x ^= x >> 16
     thresh = jnp.uint32(min(int(round(rate * 2.0 ** 32)), 2 ** 32 - 1))
     return x >= thresh  # P(keep) = 1 - rate
-
-
-def _interpret():
-    """Interpreter mode: lets the kernels run (and be tested) on CPU."""
-    return (os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1"
-            or jax.default_backend() == "cpu")
 
 
 def _pick_block(s: int, preferred: int) -> int:
@@ -272,9 +263,10 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
             jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
             jax.ShapeDtypeStruct((b * nh, s, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
+        name="flash_attention_fwd",
     )(*operands)
     return out.reshape(b, nh, s, hd), lse
 
@@ -453,9 +445,10 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
+        name="flash_attention_bwd_dq",
     )(*dq_operands)
 
     dkdv_kernel = functools.partial(_flash_bwd_dkdv_kernel, scale=scale,
@@ -486,9 +479,10 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
             jax.ShapeDtypeStruct((b * nh, s, hd), k.dtype),
             jax.ShapeDtypeStruct((b * nh, s, hd), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
+        name="flash_attention_bwd_dkdv",
     )(*dkdv_operands)
 
     return (dq.reshape(b, nh, s, hd), dk.reshape(b, nh, s, hd),

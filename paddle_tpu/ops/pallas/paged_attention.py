@@ -12,31 +12,40 @@ that walks each slot's page-table row with scalar prefetch:
 
 * grid (B, nh, MB): the page table and positions ride SMEM ahead of the
   body, so the k/v BlockSpec index_map picks each step's POOL BLOCK
-  directly — the dense view never exists, in HBM or anywhere else;
+  directly — the dense view never exists in HBM;
 * blocks past a slot's write frontier (j*bs > pos) clamp their index map
   to the previous block — consecutive identical indices make the Mosaic
   pipeline ELIDE the DMA, so out-of-range blocks cost no HBM traffic —
-  and skip compute via pl.when;
-* scores land in a VMEM row initialized to -inf; masked lanes keep the
-  oracle's exact -inf, so the final full-row jax.nn.softmax + context
-  matmul run over bit-identical values at bit-identical width. The
-  softmax is deliberately the full-row form rather than a cross-block
-  online rescale: rescaling reorders the f32 sums, and the serving
-  contract (docs/serving.md) pins BITWISE parity against the oracle —
-  exp/sum over rows whose extra lanes are exactly 0.0 is bit-stable, a
-  cross-block alpha-weighted accumulation is not. The VMEM row costs
-  max_len*4 + max_len*hd*dtype bytes per (slot, head) step — ~1 MB at
-  max_len 2048 / hd 128 — well inside the 16 MB budget;
-* the int8-KV arm converts blocks to f32 IN-KERNEL (exact) and folds
-  the dequantize_abs_max multiplier (scale/127, ops/int8_ops.py) to the
-  post-dot position — the form that is bit-stable across XLA fusion
-  contexts; see kv_dequant_scale for why the naive per-element dequant
-  is not.
+  and skip the staging store via pl.when;
+* block steps only STAGE: each [bs, hd] pool block is stored into this
+  slot's [MB*bs, hd] VMEM rows at sublane offset j*bs (a whole number of
+  tiles for f32 and bf16 at bs 16). Nothing is ever stored at a lane
+  offset — a [1, bs] score slice written at lane j*bs is what Mosaic
+  refused in the first version of this kernel;
+* the score dot, mask, softmax and context run ONCE, at the last block
+  step, over the full-width rows: masked lanes are the oracle's exact
+  -inf and the rows never staged are exact zeros, so the full-row
+  jax.nn.softmax + context matmul see bit-identical values at
+  bit-identical width. The softmax is deliberately the full-row form
+  rather than a cross-block online rescale: rescaling reorders the f32
+  sums, and the serving contract (docs/serving.md) pins BITWISE parity
+  against the oracle in interpret mode — exp/sum over rows whose extra
+  lanes are exactly 0.0 is bit-stable, a cross-block alpha-weighted
+  accumulation is not. The two VMEM rows cost 2 * max_len * hd * itemsize
+  bytes per (slot, head) — 1 MB at max_len 2048 / hd 128 / f32 — well
+  inside the 16 MB budget;
+* MXU accumulators are f32 (Mosaic takes nothing narrower); a bf16 pool's
+  context is rounded to bf16 once, after the dot, as XLA does for the
+  oracle's bf16 einsum;
+* int8-KV pools are staged through an exact int8->f32 convert and the
+  dequantize_abs_max multiplier (scale/127, ops/int8_ops.py) is folded
+  to the post-dot position — the form that is bit-stable across XLA
+  fusion contexts; see kv_dequant_scale for why the naive per-element
+  dequant is not.
 
-Runs under interpret=True on CPU (jax.default_backend() == "cpu" or
-PADDLE_TPU_PALLAS_INTERPRET=1) so the tier-1 parity matrix
-(tests/test_pallas_kernels.py) pins the kernel bit-for-bit against
-paged_attend on every suite run.
+Runs under interpret=True on the CPU backend (ops/pallas.interpret_mode)
+so the tier-1 parity matrix (tests/test_pallas_kernels.py) pins the
+kernel bit-for-bit against paged_attend on every suite run.
 """
 from __future__ import annotations
 
@@ -49,16 +58,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# API drift shim shared with flash_attention.py
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from . import interpret_mode
 
 _INT8_MAX_RANGE = 127.0   # dequantize_abs_max max_range (ops/int8_ops.py)
-
-
-def _interpret():
-    return (os.environ.get("PADDLE_TPU_PALLAS_INTERPRET") == "1"
-            or jax.default_backend() == "cpu")
 
 
 def decode_kernel_enabled() -> bool:
@@ -96,59 +98,17 @@ def kv_dequant_scale(kv_scale) -> float:
 
 
 def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         scores_ref, v_ref_acc, *, block_size, num_blocks,
-                         grid_blocks, scale, kv_scale):
-    """One (slot, head, block) grid step — float-pool arm.
+                         k_row, v_row, *, block_size, grid_blocks, scale,
+                         kv_scale):
+    """One (slot, head, block) grid step.
 
-    pt_ref/pos_ref: SMEM scalar-prefetch ([B, MB] / [B] int32);
-    q_ref [1, hd]; k_ref/v_ref [bs, hd] (this step's pool block);
-    o_ref [1, hd]; scratch: scores_ref [1, MB*bs] f32 (persists across
-    the block dimension), v_ref_acc [MB*bs, hd] (the VMEM-resident value
-    row — never HBM)."""
-    del kv_scale
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    p = pos_ref[b]
-    bs = block_size
+    pt_ref/pos_ref: SMEM scalar prefetch ([B, MB] / [B] int32);
+    q_ref/o_ref [1, hd]; k_ref/v_ref [bs, hd] (this step's pool block);
+    scratch k_row/v_row [MB*bs, hd]: this slot's cache rows, persisting
+    across the block dimension — pool dtype, or f32 for int8 pools.
 
-    @pl.when(j == 0)
-    def _init():
-        # -inf scores == the oracle's additive mask at full width: lanes
-        # never written (masked or out-of-range) contribute exp(-inf)=0
-        # to the softmax sum, bit-identical to paged_attend's masked row
-        scores_ref[...] = jnp.full_like(scores_ref, -jnp.inf)
-        v_ref_acc[...] = jnp.zeros_like(v_ref_acc)
-
-    @pl.when(j * bs <= p)
-    def _block():
-        k = k_ref[...]
-        v = v_ref[...]
-        q = q_ref[...]
-        # same contraction as the oracle's score einsum: f32 accumulate
-        s = jnp.einsum("qd,kd->qk", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(kpos <= p, s, -jnp.inf)
-        scores_ref[0, pl.ds(j * bs, bs)] = s[0]
-        v_ref_acc[pl.ds(j * bs, bs), :] = v.astype(v_ref_acc.dtype)
-
-    @pl.when(j == grid_blocks - 1)
-    def _finish():
-        row = scores_ref[...]                                  # [1, K]
-        probs = jax.nn.softmax(row, axis=-1)
-        vals = v_ref_acc[...]
-        # the oracle's context einsum: probs cast to the value dtype
-        out = jnp.einsum("qk,kd->qd", probs.astype(vals.dtype), vals)
-        o_ref[...] = out.astype(o_ref.dtype)
-
-
-def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                              k_ref_acc, v_ref_acc, *, block_size,
-                              num_blocks, grid_blocks, scale, kv_scale):
-    """int8-pool arm. Block steps only STAGE the exact int8->f32 converts
-    into VMEM scratch; the score dot, mask, softmax and context all run
-    at the final step over the materialized rows. Deferral is what makes
-    the arm bit-stable: a convert feeding a dot in the same fusion
+    Deferring every contraction to the last step is also what keeps the
+    int8 arm bit-stable: a convert feeding a dot in the same fusion
     context lets XLA re-order the contraction (1-ulp drift vs the
     oracle), while a scratch round-trip across grid steps pins the
     converted values before any contraction sees them."""
@@ -159,29 +119,39 @@ def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == 0)
     def _init():
-        # zeros (not garbage) so masked lanes stay finite pre-mask
-        k_ref_acc[...] = jnp.zeros_like(k_ref_acc)
-        v_ref_acc[...] = jnp.zeros_like(v_ref_acc)
+        # rows the walk never stages must be exact zeros: their softmax
+        # weight is 0.0, and 0.0 * stale VMEM could be NaN (values) or
+        # leave a NaN score under the mask (keys)
+        k_row[...] = jnp.zeros_like(k_row)
+        v_row[...] = jnp.zeros_like(v_row)
 
     @pl.when(j * bs <= p)
-    def _block():
-        k_ref_acc[pl.ds(j * bs, bs), :] = k_ref[...].astype(jnp.float32)
-        v_ref_acc[pl.ds(j * bs, bs), :] = v_ref[...].astype(jnp.float32)
+    def _stage():
+        rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
+        k_row[rows, :] = k_ref[...].astype(k_row.dtype)
+        v_row[rows, :] = v_ref[...].astype(v_row.dtype)
 
     @pl.when(j == grid_blocks - 1)
     def _finish():
         q = q_ref[...]
-        krow = k_ref_acc[...]                                  # [K, hd]
-        # folded int8 contract (kv_dequant_scale): dequant multiplier
-        # rides the post-dot scale, mirroring paged_attend's int8 arm
-        c = kv_scale / _INT8_MAX_RANGE
-        s = jnp.einsum("qd,kd->qk", q, krow,
+        k = k_row[...]                                         # [K, hd]
+        v = v_row[...]
+        # int8: the dequant multiplier rides the post-dot scales,
+        # mirroring paged_attend's folded int8 arm (kv_dequant_scale)
+        c = 1.0 if kv_scale is None else kv_scale / _INT8_MAX_RANGE
+        # same contraction as the oracle's score einsum: f32 accumulate
+        s = jnp.einsum("qd,kd->qk", q, k,
                        preferred_element_type=jnp.float32) * (scale * c)
         kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # -inf == the oracle's additive mask at full width: masked lanes
+        # contribute exp(-inf)=0 to the softmax sum
         s = jnp.where(kpos <= p, s, -jnp.inf)
         probs = jax.nn.softmax(s, axis=-1)
-        vals = v_ref_acc[...]
-        out = jnp.einsum("qk,kd->qd", probs.astype(vals.dtype), vals) * c
+        # the oracle's context einsum: probs cast to the value dtype
+        out = jnp.einsum("qk,kd->qd", probs.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        if kv_scale is not None:
+            out = out * c
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -195,8 +165,8 @@ def fused_paged_attention(q, k_pool, v_pool, page_table, pos, *,
     Returns the context [B, nh, 1, hd] bit-identical (f32 path) to
     `paged_attend(q, k_pool, v_pool, page_table, pos, ...)`.
 
-    `max_blocks` (static) bounds the page-table WALK — the scratch row
-    stays full width so the softmax denominators match the oracle at any
+    `max_blocks` (static) bounds the page-table WALK — the VMEM rows
+    stay full width so the softmax denominators match the oracle at any
     hint, while blocks >= max_blocks are never visited at all."""
     b, nh, one, hd = q.shape
     if one != 1:
@@ -223,37 +193,27 @@ def fused_paged_attention(q, k_pool, v_pool, page_table, pos, *,
         jc = jnp.minimum(ji, pos_ref[bi] // bs)
         return (layer, pt_ref[bi, jc], hi, 0, 0)
 
-    body = (_paged_decode_kernel_int8 if kv_scale is not None
-            else _paged_decode_kernel)
     kernel = functools.partial(
-        body, block_size=bs, num_blocks=mb, grid_blocks=grid_blocks,
+        _paged_decode_kernel, block_size=bs, grid_blocks=grid_blocks,
         scale=scale, kv_scale=None if kv_scale is None else float(kv_scale))
-    if kv_scale is not None:
-        # int8 arm stages BOTH converted rows (see the deferred kernel)
-        scratch = [pltpu.VMEM((mb * bs, hd), jnp.float32),
-                   pltpu.VMEM((mb * bs, hd), jnp.float32)]
-    else:
-        scratch = [pltpu.VMEM((1, mb * bs), jnp.float32),
-                   pltpu.VMEM((mb * bs, hd), out_dtype)]
+    row = pl.BlockSpec((None, None, 1, hd),
+                       lambda bi, hi, ji, pt, ps: (bi, hi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nh, grid_blocks),
-        in_specs=[
-            pl.BlockSpec((None, None, 1, hd),
-                         lambda bi, hi, ji, pt, ps: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, None, bs, hd), block_idx),
-            pl.BlockSpec((None, None, None, bs, hd), block_idx),
-        ],
-        out_specs=pl.BlockSpec((None, None, 1, hd),
-                               lambda bi, hi, ji, pt, ps: (bi, hi, 0, 0)),
-        scratch_shapes=scratch,
+        in_specs=[row,
+                  pl.BlockSpec((None, None, None, bs, hd), block_idx),
+                  pl.BlockSpec((None, None, None, bs, hd), block_idx)],
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((mb * bs, hd), out_dtype),
+                        pltpu.VMEM((mb * bs, hd), out_dtype)],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, 1, hd), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
+        name="paged_attention_decode",
     )(page_table, pos, q, k_pool, v_pool)
-    return out

@@ -33,11 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.pallas.flash_attention import _keep_mask
 
 
-# API-drift shims shared repo-wide (utils/jax_compat.py)
-from ..utils.jax_compat import axis_size as _axis_size
-from ..utils.jax_compat import shard_map as _shard_map
-
-
 def _dropout_keep(seed, head_ids, sq, sk, q_off, k_off, rate):
     """[B, nh, sq, sk] keep mask from the flash kernels' counter hash.
     `head_ids` [B, nh] must be the GLOBAL batch-major flat indices
@@ -97,13 +92,13 @@ def _ring_attention_local(q, k, v, mask, *, axis_name, scale, causal,
     """Per-device body under shard_map: local [B, nh, Sl, hd] blocks; mask
     (if any) is the local [B, 1, 1, Sl] key-bias block, rotated in lock
     step with its K/V chunk."""
-    p_size = _axis_size(axis_name)
+    p_size = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, nh, sl, hd = q.shape
     qf = q.astype(jnp.float32)
     head_ids = None
     if dropout > 0.0:
-        tp_size = _axis_size(tp_axis) if tp_axis else 1
+        tp_size = jax.lax.axis_size(tp_axis) if tp_axis else 1
         tp_off = jax.lax.axis_index(tp_axis) * nh if tp_axis else 0
         offs = tp_off + jnp.arange(nh, dtype=jnp.int32)
         head_ids = _global_head_ids(b, offs, nh * tp_size, dp_axis)
@@ -177,13 +172,13 @@ def ring_attention(q, k, v, mesh: Optional[Mesh] = None, axis: str = "sp",
         return body(q, k, v, mask, seed=seed)
 
     if mask is None:
-        return _shard_map(
+        return jax.shard_map(
             lambda q, k, v, s: body(q, k, v, None, seed=s), mesh=mesh,
             in_specs=(spec, spec, spec, P()),
-            out_specs=spec)(q, k, v, seed)
-    return _shard_map(wrapped, mesh=mesh,
-                      in_specs=(spec, spec, spec, mask_spec, P()),
-                      out_specs=spec)(q, k, v, mask, seed)
+            out_specs=spec, check_vma=False)(q, k, v, seed)
+    return jax.shard_map(wrapped, mesh=mesh,
+                         in_specs=(spec, spec, spec, mask_spec, P()),
+                         out_specs=spec, check_vma=False)(q, k, v, mask, seed)
 
 
 def _qkv_spec(mesh, seq_axis):
@@ -252,7 +247,7 @@ def ulysses_attention(q, k, v, mesh: Optional[Mesh] = None, axis: str = "sp",
             # global head ids: tp chunks the pre-all-to-all local heads
             # (nh_l * P of them per tp shard), sp sub-chunks them
             nh_pre = nh_l * p_size
-            tp_size = _axis_size(tp_axis) if tp_axis else 1
+            tp_size = jax.lax.axis_size(tp_axis) if tp_axis else 1
             tp_off = (jax.lax.axis_index(tp_axis) * nh_pre
                       if tp_axis else 0)
             offs = tp_off + rank * nh_l + jnp.arange(nh_l, dtype=jnp.int32)
@@ -266,10 +261,10 @@ def ulysses_attention(q, k, v, mesh: Optional[Mesh] = None, axis: str = "sp",
     spec = _qkv_spec(mesh, axis)
     mask_spec = P(spec[0], None, None, axis)
     if mask is None:
-        return _shard_map(
+        return jax.shard_map(
             lambda q, k, v, s: body(q, k, v, None, s), mesh=mesh,
             in_specs=(spec, spec, spec, P()),
-            out_specs=spec)(q, k, v, seed)
-    return _shard_map(body, mesh=mesh,
-                      in_specs=(spec, spec, spec, mask_spec, P()),
-                      out_specs=spec)(q, k, v, mask, seed)
+            out_specs=spec, check_vma=False)(q, k, v, seed)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec, spec, spec, mask_spec, P()),
+                         out_specs=spec, check_vma=False)(q, k, v, mask, seed)

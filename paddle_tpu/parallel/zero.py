@@ -1360,7 +1360,6 @@ def build_manual_jit(plan: ManualDpPlan, fn, mut_names, ro_names,
     (mut, ro, feeds, rng) -> (fetches, new_state)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from ..utils.jax_compat import shard_map
 
     axis, dp, mesh = plan.axis, plan.dp, plan.mesh
 
@@ -1382,7 +1381,8 @@ def build_manual_jit(plan: ManualDpPlan, fn, mut_names, ro_names,
                 dict(plan.feed_specs), P())
     out_specs = ([spec for _g, spec in plan.fetch_gathers],
                  dict(plan.written_specs))
-    sm = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def ns(spec):
         return NamedSharding(mesh, spec)

@@ -725,7 +725,7 @@ class DecodeEngine:
     def submit(self, request: Request, _handle: Optional[RequestHandle]
                = None, _failover: bool = False, _probe: bool = False,
                bounded: bool = True) -> Optional[RequestHandle]:
-        """Admit or reject a request. The shed taxonomy (docs/serving.md
+        """Admit or reject a request. The shed reasons (docs/serving.md
         "Failure semantics") is typed: overload rejections finish the
         handle with `shed:<reason>` (result() raises ShedError) and count
         `serving.shed_total` + `serving.shed.<reason>`.
@@ -851,7 +851,7 @@ class DecodeEngine:
 
     def _reject_reason(self, req: Request) -> Optional[str]:
         """Validation-only rejects (malformed requests); capacity-driven
-        rejections go through the shed taxonomy instead."""
+        rejections go through the shed reasons instead."""
         plen = int(req.prompt.shape[0])
         if plen < 1:
             return "empty prompt"

@@ -38,7 +38,7 @@ class ShedError(ServingError):
     """The request was SHED by admission control (docs/serving.md
     "Failure semantics"): the engine judged it could not serve it within
     its capacity/deadline contract and rejected it typed-and-early rather
-    than queueing it to time out. `.reason` is the taxonomy key
+    than queueing it to time out. `.reason` is the shed-reason key
     (queue_full | deadline_unmeetable | unfundable | draining |
     engine_dead | admit_fault); the same key lands in the
     `serving.shed.<reason>` counter."""

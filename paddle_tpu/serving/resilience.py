@@ -77,7 +77,7 @@ class NoHealthyReplicaError(ServingError):
 
 def shed_handle(handle: RequestHandle, reason: str,
                 detail: str) -> RequestHandle:
-    """Finish a handle as SHED with the typed taxonomy reason — the ONE
+    """Finish a handle as SHED with its typed shed reason — the ONE
     implementation of the shed contract (counters + trace instant +
     `shed:<reason>` finish), shared by the engine's admission control and
     the frontend's draining gate."""

@@ -439,12 +439,16 @@ def test_bf16_and_int8_weight_arms(tiny_gpt):
         assert all(0 <= t < cfg.vocab_size for t in c.tokens)
 
 
-def test_bench_serving_rows(tiny_gpt):
+def test_bench_serving_rows(tiny_gpt, monkeypatch):
     """The bench-table acceptance shape: rows exist with tokens/s + p50/
     p99 TTFT across >= 3 concurrency levels, for BOTH decode-kernel A/B
     arms with their census stamps (tiny geometry here; hardware rounds
     run the GPT-2-small geometry via bench.py main)."""
     import bench
+    # a shape test on a stand-in device: the roofline fields need peaks,
+    # and bench.py has none for a CPU on purpose
+    monkeypatch.setitem(bench.DEVICE_PEAKS, "cpu",
+                        {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11})
     rows = bench.bench_serving(streams_levels=(1, 2, 3),
                                dtypes=("float32",),
                                prompt_len=8, new_tokens=4, model="tiny")

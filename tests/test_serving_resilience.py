@@ -227,7 +227,7 @@ def test_failover_budget_exhausted_raises_typed(tiny_gpt):
 # admission control + load shedding
 # ---------------------------------------------------------------------------
 
-def test_shed_reason_taxonomy(tiny_gpt, monkeypatch):
+def test_shed_reason_table(tiny_gpt, monkeypatch):
     from paddle_tpu.observability import metrics as m
     cfg, params = tiny_gpt
     for name in ("serving.shed_total", "serving.shed.queue_full",
