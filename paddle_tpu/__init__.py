@@ -20,6 +20,10 @@ Layout:
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0_NS = _time.perf_counter_ns()   # the `startup.import` span's start
+
 # --- fluid-style core -------------------------------------------------------
 from .framework.program import (Program, program_guard, default_main_program,
                                 default_startup_program, in_dygraph_mode,
@@ -150,3 +154,10 @@ from . import inference  # noqa: E402
 from . import profiler  # noqa: E402
 from . import monitor  # noqa: E402
 from .flags import get_flags, set_flags  # noqa: E402
+
+# --- observability: the import's own span, JAX's compile phases as spans ----
+from .observability import compile_events as _compile_events  # noqa: E402
+from .observability import trace as _trace  # noqa: E402
+
+_compile_events.install()
+_trace.complete("startup.import", _IMPORT_T0_NS, _time.perf_counter_ns())

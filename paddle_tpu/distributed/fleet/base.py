@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 
 from ...framework.program import default_main_program
+from ...observability.trace import RecordEvent
 from ...parallel import mesh as mesh_mod
 from ...parallel.mesh import ShardingRules
 from ...parallel.spmd import DistConfig, attach
@@ -356,6 +357,13 @@ class DistributedOptimizer:
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
+        # the inner optimizer's span nests under this one: backward and
+        # optimizer ops there, the strategy's program passes here
+        with RecordEvent("optimizer.minimize", args={"fleet": True}):
+            return self._minimize(loss, startup_program, parameter_list,
+                                  no_grad_set)
+
+    def _minimize(self, loss, startup_program, parameter_list, no_grad_set):
         s = self.user_defined_strategy
         program = loss.block.program
         opt = self.inner_opt

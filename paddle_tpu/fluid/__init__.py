@@ -20,8 +20,8 @@ from .. import regularizer
 from .. import clip
 from .. import io
 from .. import framework
-from ..__init__ import (CPUPlace, CUDAPlace, TPUPlace, is_compiled_with_cuda,
-                        is_compiled_with_tpu)
+from .. import (CPUPlace, CUDAPlace, TPUPlace, is_compiled_with_cuda,
+                is_compiled_with_tpu)
 from .. import compiler  # noqa: F401
 from ..compiler import CompiledProgram, BuildStrategy, ExecutionStrategy  # noqa: F401
 from .. import debugger  # noqa: F401

@@ -23,7 +23,8 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
-from .program import Program, Variable, default_main_program
+from .program import (Program, Variable, default_main_program,
+                      default_startup_program)
 from .scope import Scope, global_scope
 from .. import monitor
 from ..observability import flight as _flight
@@ -903,11 +904,6 @@ class Executor:
         # the training loop consumes on the main thread
         self._staged: "collections.deque[_StagedFeeds]" = collections.deque()
         self._staged_lock = threading.Lock()
-        # device cost attribution per compiled program (annotate_step_cost):
-        # (program uid, version) -> {"device_flops": ..., ...}; dispatch
-        # spans attach the entry so every step in the trace carries its
-        # program's XLA cost analysis
-        self._step_costs: Dict[tuple, dict] = {}
         # pod-scope collective correlation plan per compiled program
         # (_emit_collective_markers): (program uid, version) -> ordered
         # [(kind, bucket)] of the program's collective ops
@@ -1105,18 +1101,29 @@ class Executor:
           dispatch is async, so they may still be computing; np.asarray
           (or .block_until_ready) at the consumer is the sync point.
         """
-        with self._step_window():
+        program = self._resolve_program(program)
+        with self._step_window("run", program):
             return self._run_impl(program, feed, fetch_list, scope,
                                   return_numpy, use_program_cache, sync)
 
+    @staticmethod
+    def _resolve_program(program):
+        program = program or default_main_program()
+        if hasattr(program, "_is_data_parallel"):   # CompiledProgram shim
+            program = program.program
+        return program
+
     @contextlib.contextmanager
-    def _step_window(self):
+    def _step_window(self, kind, program, k=1):
         """One executor step: advance the counter, bracket the flight-
-        recorder window, and fire the FLAGS_profile_start/stop_step
-        triggers. Shared by run() AND run_steps() so a mixed loop (e.g.
-        train_from_dataset dispatching full groups via run_steps and tail
-        batches via run) sees every counter value exactly once — an
-        equality trigger can never be skipped."""
+        recorder window, open the ROOT span `executor.step` (args: step,
+        exe, kind "run" | "run_steps", k, program "startup" | "main", ops)
+        under which every span of this dispatch lies, and fire the
+        FLAGS_profile_start/stop_step triggers. Shared by run() AND
+        run_steps() so a mixed loop (e.g. train_from_dataset dispatching
+        full groups via run_steps and tail batches via run) sees every
+        counter value exactly once — an equality trigger can never be
+        skipped."""
         from .. import profiler as _prof
         from ..flags import flag
         self._step_counter = getattr(self, "_step_counter", 0) + 1
@@ -1130,8 +1137,14 @@ class Executor:
             _prof.start_profiler()
         _flight.begin_step(idx, owner=owner)
         status = "ok"
+        root = _trace.RecordEvent("executor.step", args={
+            "step": idx, "exe": owner, "kind": kind, "k": k,
+            "program": ("startup" if program is default_startup_program()
+                        else "main"),
+            "ops": op_count(program)})
         try:
-            yield idx
+            with root:
+                yield idx
         except BaseException:
             status = "error"
             raise
@@ -1171,170 +1184,173 @@ class Executor:
 
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
                   use_program_cache, sync):
-        program = program or default_main_program()
-        if hasattr(program, "_is_data_parallel"):   # CompiledProgram shim
-            program = program.program
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
-        sync = self._resolve_sync(sync)
+        with _trace.RecordEvent("executor.prepare"):
+            feed = feed or {}
+            fetch_list = fetch_list or []
+            scope = scope or global_scope()
+            sync = self._resolve_sync(sync)
 
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-        gb = program.global_block()
-        for n in fetch_names:
-            if not gb.has_var(n):
-                from . import errors
-                raise errors.NotFound(
-                    "fetch target %r is not a variable of this program", n,
-                    var=n)
+            fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                           for v in fetch_list]
+            gb = program.global_block()
+            for n in fetch_names:
+                if not gb.has_var(n):
+                    from . import errors
+                    raise errors.NotFound(
+                        "fetch target %r is not a variable of this program", n,
+                        var=n)
 
-        # staged windows match the USER feed — before PS hooks add their
-        # pulled-row keys, which stage() never saw (a post-hook match
-        # would always miss on PS programs and silently double the H2D)
-        staged_vals = self._take_staged(program, feed, k=None)
-        # parameter-server hooks (distributed_embedding): pull sparse rows
-        # before the step, push their grads after (distributed/ps.py)
-        ps_hooks = getattr(program, "_ps_hooks", None) or []
-        n_user_fetch = len(fetch_names)
-        if ps_hooks:
-            feed = dict(feed)
-            for h in ps_hooks:
-                feed.update(h.pre(feed))
-                if gb.has_var(h.grad_name) and h.grad_name not in fetch_names:
-                    fetch_names.append(h.grad_name)
-        block = program.global_block()
-        if staged_vals is not None:
-            # coercion + H2D already paid in stage(); hook-added entries
-            # (pulled rows) still coerce here
-            feed_vals = dict(staged_vals)
-            for name, value in feed.items():
-                if name not in feed_vals:
-                    feed_vals[name] = _coerce_feed_value(block, name, value)
-        else:
-            feed_vals = {name: _coerce_feed_value(block, name, value)
-                         for name, value in feed.items()}
-        _ensure_stacked_params(program, scope)
-        _ensure_shared_beta_pows(program, scope)
-        _ensure_zero_state(program, scope)
-        state_names = _referenced_state_names(block, scope, feed_vals)
+            # staged windows match the USER feed — before PS hooks add their
+            # pulled-row keys, which stage() never saw (a post-hook match
+            # would always miss on PS programs and silently double the H2D)
+            staged_vals = self._take_staged(program, feed, k=None)
+            # parameter-server hooks (distributed_embedding): pull sparse rows
+            # before the step, push their grads after (distributed/ps.py)
+            ps_hooks = getattr(program, "_ps_hooks", None) or []
+            n_user_fetch = len(fetch_names)
+            if ps_hooks:
+                feed = dict(feed)
+                for h in ps_hooks:
+                    feed.update(h.pre(feed))
+                    if gb.has_var(h.grad_name) and \
+                            h.grad_name not in fetch_names:
+                        fetch_names.append(h.grad_name)
+            block = program.global_block()
+            if staged_vals is not None:
+                # coercion + H2D already paid in stage(); hook-added entries
+                # (pulled rows) still coerce here
+                feed_vals = dict(staged_vals)
+                for name, value in feed.items():
+                    if name not in feed_vals:
+                        feed_vals[name] = _coerce_feed_value(block, name,
+                                                             value)
+            else:
+                feed_vals = {name: _coerce_feed_value(block, name, value)
+                             for name, value in feed.items()}
+            _ensure_stacked_params(program, scope)
+            _ensure_shared_beta_pows(program, scope)
+            _ensure_zero_state(program, scope)
+            state_names = _referenced_state_names(block, scope, feed_vals)
 
-        key = _block_cache_key(program, feed_vals, fetch_names, state_names)
-        compiled = self._cache.get(key) if use_program_cache else None
-        localsgd_k = getattr(program, "_localsgd_k", 0)
-        if compiled is None:
-            _metrics.inc("executor.compile_cache_misses")
-            with _trace.RecordEvent("compile", args={
-                    "step": self._step_counter,
-                    "ops": op_count(program)}):
-                dist = getattr(program, "_dist_config", None)
-                pp = (int(dist.resolve_mesh().shape.get("pp", 1))
-                      if dist is not None else 1)
-                if pp > 1:
-                    # the pp mesh axis engages true pipeline parallelism:
-                    # stages partitioned by device_guard, placed on pp
-                    # submeshes (parallel/pipeline.py)
-                    if localsgd_k and localsgd_k > 1:
-                        from . import errors
-                        raise errors.Unimplemented(
-                            "LocalSGD over a pp>1 mesh (pipeline stages and "
-                            "per-replica parameter copies are incompatible)")
-                    from ..parallel.pipeline import _PipelineBlock
-                    compiled = _PipelineBlock(program, 0, list(feed_vals),
-                                              fetch_names, state_names)
-                elif localsgd_k and localsgd_k > 1:
-                    compiled = _LocalSGDBlock(program, 0, list(feed_vals),
-                                              fetch_names, state_names,
-                                              localsgd_k)
-                else:
-                    compiled = _make_compiled_block(program, feed_vals,
-                                                    fetch_names, state_names,
-                                                    scope)
-            if use_program_cache:
-                self._cache[key] = compiled
-        else:
-            _metrics.inc("executor.compile_cache_hits")
+            key = _block_cache_key(program, feed_vals, fetch_names,
+                                   state_names)
+            compiled = self._cache.get(key) if use_program_cache else None
+            localsgd_k = getattr(program, "_localsgd_k", 0)
+            if compiled is None:
+                _metrics.inc("executor.compile_cache_misses")
+                with _trace.RecordEvent("executor.build_block"):
+                    dist = getattr(program, "_dist_config", None)
+                    pp = (int(dist.resolve_mesh().shape.get("pp", 1))
+                          if dist is not None else 1)
+                    if pp > 1:
+                        # the pp mesh axis engages true pipeline parallelism:
+                        # stages partitioned by device_guard, placed on pp
+                        # submeshes (parallel/pipeline.py)
+                        if localsgd_k and localsgd_k > 1:
+                            from . import errors
+                            raise errors.Unimplemented(
+                                "LocalSGD over a pp>1 mesh (pipeline stages "
+                                "and per-replica parameter copies are "
+                                "incompatible)")
+                        from ..parallel.pipeline import _PipelineBlock
+                        compiled = _PipelineBlock(program, 0, list(feed_vals),
+                                                  fetch_names, state_names)
+                    elif localsgd_k and localsgd_k > 1:
+                        compiled = _LocalSGDBlock(program, 0, list(feed_vals),
+                                                  fetch_names, state_names,
+                                                  localsgd_k)
+                    else:
+                        compiled = _make_compiled_block(
+                            program, feed_vals, fetch_names, state_names,
+                            scope)
+                if use_program_cache:
+                    self._cache[key] = compiled
+            else:
+                _metrics.inc("executor.compile_cache_hits")
 
-        if staged_vals is not None:
-            # the donation-vs-staging aliasing rule: a staged buffer the
-            # step donates is copied into a fresh buffer pre-dispatch,
-            # and the call serializes (sync) for good measure
-            feed_vals, n_conf = self._resolve_staged_donation(
-                compiled, feed_vals, scope)
-            if n_conf:
-                monitor.stat_add("executor.staging_conflicts", n_conf)
-                _trace.instant("donation_conflict_copy",
-                               args={"n": n_conf,
-                                     "step": self._step_counter})
-                sync = True
+            if staged_vals is not None:
+                # the donation-vs-staging aliasing rule: a staged buffer the
+                # step donates is copied into a fresh buffer pre-dispatch,
+                # and the call serializes (sync) for good measure
+                feed_vals, n_conf = self._resolve_staged_donation(
+                    compiled, feed_vals, scope)
+                if n_conf:
+                    monitor.stat_add("executor.staging_conflicts", n_conf)
+                    _trace.instant("donation_conflict_copy",
+                                   args={"n": n_conf,
+                                         "step": self._step_counter})
+                    sync = True
 
-        rng_key = _next_rng_key(scope, program.random_seed)
-        from ..flags import flag
-        step_idx = self._step_counter
+            rng_key = _next_rng_key(scope, program.random_seed)
+            from ..flags import flag
+            step_idx = self._step_counter
 
-        def _dispatch():
-            if not isinstance(compiled, _CompiledBlock):
-                # _LocalSGDBlock / _PipelineBlock drive the scope themselves
-                return compiled.step(scope, feed_vals, rng_key)
-            state = {n: scope.find(n) for n in state_names}
-            return compiled(state, feed_vals, rng_key)
-
-        # step-level hang watchdog: bound the dispatch (and, below, the
-        # synchronous fetch drain) so a wedged collective surfaces as a
-        # typed error the gang supervisor can restart on, never a hang
-        step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
-        if step_deadline > 0:
-            _raw_dispatch = _dispatch
+            # _LocalSGDBlock / _PipelineBlock drive the scope themselves
+            state = ({n: scope.find(n) for n in state_names}
+                     if isinstance(compiled, _CompiledBlock) else None)
 
             def _dispatch():
-                return _deadline_call(
-                    _raw_dispatch, step_deadline,
-                    f"step dispatch ({op_count(program)} ops)")
+                if state is None:
+                    return compiled.step(scope, feed_vals, rng_key)
+                return compiled(state, feed_vals, rng_key)
 
-        benchmark = flag("FLAGS_benchmark")
+            # step-level hang watchdog: bound the dispatch (and, below, the
+            # synchronous fetch drain) so a wedged collective surfaces as a
+            # typed error the gang supervisor can restart on, never a hang
+            step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
+            if step_deadline > 0:
+                _raw_dispatch = _dispatch
+
+                def _dispatch():
+                    return _deadline_call(
+                        _raw_dispatch, step_deadline,
+                        f"step dispatch ({op_count(program)} ops)")
+
+            benchmark = flag("FLAGS_benchmark")
+            self._emit_collective_markers(program, step_idx)
         t0 = time.perf_counter()
-        self._emit_collective_markers(program, step_idx)
-        with _trace.RecordEvent(f"executor_run#{op_count(program)}ops",
-                                args=self._dispatch_args(program, step_idx)):
+        with _trace.RecordEvent("executor.launch"):
             fetches, new_state = _dispatch()
             if benchmark:  # sync so the wall time is the device time
                 jax.block_until_ready(fetches)
-        _metrics.observe("executor.step_host_ms",
-                         (time.perf_counter() - t0) * 1000.0)
         if benchmark:
             print(f"[benchmark] step {step_idx}: "
                   f"{(time.perf_counter() - t0) * 1000:.3f} ms")
-        for n, v in new_state.items():
-            scope.set(n, v)
-        self._maybe_snapshot(program, scope)
-        if flag("FLAGS_check_nan_inf"):
-            _check_nan_inf(dict(zip(fetch_names, fetches)), new_state)
-        if ps_hooks:
-            fetched_by_name = dict(zip(fetch_names, fetches))
-            for h in ps_hooks:
-                h.post(fetched_by_name)
-            fetches = fetches[:n_user_fetch]
-        user_names = fetch_names[:n_user_fetch] if ps_hooks else fetch_names
-        if not sync and return_numpy and fetches:
-            # lazy-fetch side of the donation rule: a fetch of a WRITTEN
-            # persistable shares (or may share) the buffer the scope just
-            # adopted — the NEXT dispatch donates that buffer, and a
-            # deferred .numpy() would read deleted memory. Snapshot those
-            # rare fetches with a device-side copy (bit-identical, async);
-            # ordinary fetches (losses, activations) pass through untouched.
-            # The sync path is immune (it drains before any next dispatch),
-            # and run_steps' stacked fetches are fresh [k,...] buffers.
-            import jax.numpy as jnp
-            fetches = [jnp.copy(f)
-                       if (n in new_state and hasattr(f, "dtype")) else f
-                       for f, n in zip(fetches, user_names)]
-        if step_deadline > 0 and sync and return_numpy:
-            return _deadline_call(
-                lambda: _package_fetches(fetches, user_names, return_numpy,
-                                         sync, step=step_idx),
-                step_deadline, "fetch materialization")
-        return _package_fetches(fetches, user_names, return_numpy, sync,
-                                step=step_idx)
+        with _trace.RecordEvent("executor.commit"):
+            for n, v in new_state.items():
+                scope.set(n, v)
+            self._maybe_snapshot(program, scope)
+            if flag("FLAGS_check_nan_inf"):
+                _check_nan_inf(dict(zip(fetch_names, fetches)), new_state)
+            if ps_hooks:
+                fetched_by_name = dict(zip(fetch_names, fetches))
+                for h in ps_hooks:
+                    h.post(fetched_by_name)
+                fetches = fetches[:n_user_fetch]
+            user_names = (fetch_names[:n_user_fetch] if ps_hooks
+                          else fetch_names)
+            if not sync and return_numpy and fetches:
+                # lazy-fetch side of the donation rule: a fetch of a WRITTEN
+                # persistable shares (or may share) the buffer the scope
+                # just adopted — the NEXT dispatch donates that buffer, and
+                # a deferred .numpy() would read deleted memory. Snapshot
+                # those rare fetches with a device-side copy (bit-identical,
+                # async); ordinary fetches (losses, activations) pass through
+                # untouched. The sync path is immune (it drains before any
+                # next dispatch), and run_steps' stacked fetches are fresh
+                # [k,...] buffers.
+                import jax.numpy as jnp
+                fetches = [jnp.copy(f)
+                           if (n in new_state and hasattr(f, "dtype")) else f
+                           for f, n in zip(fetches, user_names)]
+            if step_deadline > 0 and sync and return_numpy:
+                return _deadline_call(
+                    lambda: _package_fetches(fetches, user_names,
+                                             return_numpy, sync,
+                                             step=step_idx),
+                    step_deadline, "fetch materialization")
+            return _package_fetches(fetches, user_names, return_numpy, sync,
+                                    step=step_idx)
 
     def _collective_marker_plan(self, program) -> list:
         """Ordered [(kind, bucket_index)] of the program's collective ops —
@@ -1390,32 +1406,16 @@ class Executor:
                 args["k"] = int(k)
             _trace.instant("collective", args=args, cat="collective")
 
-    def _dispatch_args(self, program, step_idx, k=None) -> dict:
-        """Per-step phase annotations for the dispatch span: step index,
-        window size, and — once annotate_step_cost() ran for this program
-        — the XLA device cost attribution (flops/bytes)."""
-        args = {"step": step_idx}
-        if k:
-            args["k"] = int(k)
-        cost = self._step_costs.get((program._uid, program._version))
-        if cost:
-            args.update(cost)
-        return args
-
     def annotate_step_cost(self, feed=None, fetch_list=None, program=None,
                            scope=None, k=None) -> dict:
-        """Device cost attribution per step: XLA's cost analysis (flops,
-        bytes accessed) + CompiledMemoryStats (argument/output/temp bytes)
-        of the jitted step for this signature — computed once via
-        _inspect_compiled (sharing run()'s compile cache), attached to
-        every subsequent dispatch span for this program, emitted as a
-        chrome counter track ("device_step_cost"), and mirrored into the
-        executor.step_flops / executor.step_bytes_accessed gauges. The
-        fields the backend cannot report are simply absent (CPU-mesh XLA
-        reports flops; memory stats availability varies by version)."""
-        prog = program or default_main_program()
-        if hasattr(prog, "_is_data_parallel"):
-            prog = prog.program
+        """XLA's cost analysis (flops, bytes accessed) + CompiledMemoryStats
+        (argument/output/temp bytes) of the jitted step for this signature,
+        via _inspect_compiled (sharing run()'s compile cache), as a dict.
+        The fields the backend cannot report are simply absent (CPU-mesh
+        XLA reports flops; memory stats availability varies by version).
+        XLA's counts are no source for a roofline (PERF.md section 6):
+        nothing is recorded from them."""
+        prog = self._resolve_program(program)
         compiled = self._inspect_compiled(feed, fetch_list, prog, scope, k)
         cost: dict = {}
         try:
@@ -1439,15 +1439,6 @@ class Executor:
                     cost[dst] = int(v)
         except Exception:
             pass
-        if cost:
-            self._step_costs[(prog._uid, prog._version)] = cost
-            _trace.counter_event("device_step_cost", cost)
-            if "device_flops" in cost:
-                _metrics.set_gauge("executor.step_flops",
-                                   cost["device_flops"])
-            if "device_bytes_accessed" in cost:
-                _metrics.set_gauge("executor.step_bytes_accessed",
-                                   cost["device_bytes_accessed"])
         return cost
 
     def run_steps(self, k: int, program: Optional[Program] = None,
@@ -1473,112 +1464,110 @@ class Executor:
         push after (_PsHook.pre_multi/post_multi — the reference's async
         communicator batching). Not supported: Geo-SGD or dense-send hooks,
         pipeline / LocalSGD programs, heter sections."""
-        # one run_steps call is ONE dispatch: it advances the executor's
-        # step counter once, and the flight recorder records it as one
-        # step window (its dispatch span carries k)
-        with self._step_window():
-            return self._run_steps_impl(k, program, feed, fetch_list, scope,
-                                        return_numpy, sync)
-
-    def _run_steps_impl(self, k, program, feed, fetch_list, scope,
-                        return_numpy, sync):
-        program = program or default_main_program()
-        if hasattr(program, "_is_data_parallel"):
-            program = program.program
         from . import errors
         if not isinstance(k, (int, np.integer)) or k < 1:
             raise errors.InvalidArgument(
                 "run_steps needs an integer k >= 1, got %r", k)
         k = int(k)
-        ps_hooks = getattr(program, "_ps_hooks", None) or []
-        if any(not hasattr(h, "pre_multi") for h in ps_hooks):
-            raise errors.Unimplemented(
-                "run_steps with PS hooks that lack window support (e.g. "
-                "dense-send hooks); use per-step run()")
-        if any(getattr(h, "geo_k", 0) > 0 for h in ps_hooks):
-            raise errors.Unimplemented(
-                "run_steps with Geo-SGD hooks (geo needs per-step local "
-                "updates; use per-step run())")
-        if getattr(program, "_localsgd_k", 0) or \
-                getattr(program, "_microbatch_k", 0):
-            raise errors.Unimplemented(
-                "run_steps with LocalSGD/pipeline programs")
-        dist = getattr(program, "_dist_config", None)
-        if dist is not None and \
-                int(dist.resolve_mesh().shape.get("pp", 1)) > 1:
-            raise errors.Unimplemented(
-                "run_steps over a pp>1 mesh (pipeline stages run per-step)")
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
-        sync = self._resolve_sync(sync)
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-        gb = program.global_block()
-        for n in fetch_names:
-            if not gb.has_var(n):
-                raise errors.NotFound(
-                    "fetch target %r is not a variable of this program", n,
-                    var=n)
-        # PS hooks, k-step window mode: ONE pull covering all k batches'
-        # ids, ONE summed push after — the reference's async-communicator
-        # batching (communicator.h), amortizing dispatch + RPC cost over k
-        n_user_fetch = len(fetch_names)
-        # match the USER feed before the hooks add pulled-row keys (see
-        # run(): a post-hook match would always miss on PS programs)
-        staged_vals = self._take_staged(program, feed, k=k)
-        if ps_hooks:
-            feed = dict(feed)
-            for h in ps_hooks:
-                feed.update(h.pre_multi(feed))
-                if gb.has_var(h.grad_name) and h.grad_name not in fetch_names:
-                    fetch_names.append(h.grad_name)
-        if staged_vals is not None:
-            # coercion + H2D already paid in stage(); hook-added entries
-            # (the window's pulled rows) still normalize here
-            feed_vals = dict(staged_vals)
-            extra = {n: v for n, v in feed.items() if n not in feed_vals}
-            if extra:
-                feed_vals.update(_multi_step_feed_vals(gb, extra, k))
-        else:
-            feed_vals = _multi_step_feed_vals(gb, feed, k)
-        _ensure_stacked_params(program, scope)
-        _ensure_shared_beta_pows(program, scope)
-        _ensure_zero_state(program, scope)
-        state_names = _referenced_state_names(gb, scope, feed_vals)
-        key = ("multi", k) + _block_cache_key(program, feed_vals,
-                                              fetch_names, state_names)
-        compiled = self._cache.get(key)
-        if compiled is None:
-            _metrics.inc("executor.compile_cache_misses")
-            with _trace.RecordEvent("compile", args={
-                    "step": self._step_counter, "k": k,
-                    "ops": op_count(program)}):
-                compiled = _make_compiled_block(program, feed_vals,
-                                                fetch_names, state_names,
-                                                scope, multi_k=k)
-            self._cache[key] = compiled
-        else:
-            _metrics.inc("executor.compile_cache_hits")
-        if staged_vals is not None:
-            feed_vals, n_conf = self._resolve_staged_donation(
-                compiled, feed_vals, scope)
-            if n_conf:
-                monitor.stat_add("executor.staging_conflicts", n_conf)
-                _trace.instant("donation_conflict_copy",
-                               args={"n": n_conf,
-                                     "step": self._step_counter})
-                sync = True
-        rng_key = _next_rng_key(scope, program.random_seed)
-        state = {n: scope.find(n) for n in state_names}
-        from ..flags import flag
-        step_idx = self._step_counter
-        step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
-        t0 = time.perf_counter()
-        self._emit_collective_markers(program, step_idx, k=k)
-        with _trace.RecordEvent(f"executor_run_steps#{k}",
-                                args=self._dispatch_args(program, step_idx,
-                                                         k=k)):
+        program = self._resolve_program(program)
+        # one run_steps call is ONE dispatch: it advances the executor's
+        # step counter once, and the flight recorder records it as one
+        # step window (its root span carries k)
+        with self._step_window("run_steps", program, k):
+            return self._run_steps_impl(k, program, feed, fetch_list, scope,
+                                        return_numpy, sync)
+
+    def _run_steps_impl(self, k, program, feed, fetch_list, scope,
+                        return_numpy, sync):
+        from . import errors
+        with _trace.RecordEvent("executor.prepare"):
+            ps_hooks = getattr(program, "_ps_hooks", None) or []
+            if any(not hasattr(h, "pre_multi") for h in ps_hooks):
+                raise errors.Unimplemented(
+                    "run_steps with PS hooks that lack window support (e.g. "
+                    "dense-send hooks); use per-step run()")
+            if any(getattr(h, "geo_k", 0) > 0 for h in ps_hooks):
+                raise errors.Unimplemented(
+                    "run_steps with Geo-SGD hooks (geo needs per-step local "
+                    "updates; use per-step run())")
+            if getattr(program, "_localsgd_k", 0) or \
+                    getattr(program, "_microbatch_k", 0):
+                raise errors.Unimplemented(
+                    "run_steps with LocalSGD/pipeline programs")
+            dist = getattr(program, "_dist_config", None)
+            if dist is not None and \
+                    int(dist.resolve_mesh().shape.get("pp", 1)) > 1:
+                raise errors.Unimplemented(
+                    "run_steps over a pp>1 mesh (pipeline stages run "
+                    "per-step)")
+            feed = feed or {}
+            fetch_list = fetch_list or []
+            scope = scope or global_scope()
+            sync = self._resolve_sync(sync)
+            fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                           for v in fetch_list]
+            gb = program.global_block()
+            for n in fetch_names:
+                if not gb.has_var(n):
+                    raise errors.NotFound(
+                        "fetch target %r is not a variable of this program",
+                        n, var=n)
+            # PS hooks, k-step window mode: ONE pull covering all k batches'
+            # ids, ONE summed push after — the reference's async-
+            # communicator batching (communicator.h), amortizing dispatch +
+            # RPC cost over k
+            n_user_fetch = len(fetch_names)
+            # match the USER feed before the hooks add pulled-row keys (see
+            # run(): a post-hook match would always miss on PS programs)
+            staged_vals = self._take_staged(program, feed, k=k)
+            if ps_hooks:
+                feed = dict(feed)
+                for h in ps_hooks:
+                    feed.update(h.pre_multi(feed))
+                    if gb.has_var(h.grad_name) and \
+                            h.grad_name not in fetch_names:
+                        fetch_names.append(h.grad_name)
+            if staged_vals is not None:
+                # coercion + H2D already paid in stage(); hook-added entries
+                # (the window's pulled rows) still normalize here
+                feed_vals = dict(staged_vals)
+                extra = {n: v for n, v in feed.items() if n not in feed_vals}
+                if extra:
+                    feed_vals.update(_multi_step_feed_vals(gb, extra, k))
+            else:
+                feed_vals = _multi_step_feed_vals(gb, feed, k)
+            _ensure_stacked_params(program, scope)
+            _ensure_shared_beta_pows(program, scope)
+            _ensure_zero_state(program, scope)
+            state_names = _referenced_state_names(gb, scope, feed_vals)
+            key = ("multi", k) + _block_cache_key(program, feed_vals,
+                                                  fetch_names, state_names)
+            compiled = self._cache.get(key)
+            if compiled is None:
+                _metrics.inc("executor.compile_cache_misses")
+                with _trace.RecordEvent("executor.build_block"):
+                    compiled = _make_compiled_block(program, feed_vals,
+                                                    fetch_names, state_names,
+                                                    scope, multi_k=k)
+                self._cache[key] = compiled
+            else:
+                _metrics.inc("executor.compile_cache_hits")
+            if staged_vals is not None:
+                feed_vals, n_conf = self._resolve_staged_donation(
+                    compiled, feed_vals, scope)
+                if n_conf:
+                    monitor.stat_add("executor.staging_conflicts", n_conf)
+                    _trace.instant("donation_conflict_copy",
+                                   args={"n": n_conf,
+                                         "step": self._step_counter})
+                    sync = True
+            rng_key = _next_rng_key(scope, program.random_seed)
+            state = {n: scope.find(n) for n in state_names}
+            from ..flags import flag
+            step_idx = self._step_counter
+            step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
+            self._emit_collective_markers(program, step_idx, k=k)
+        with _trace.RecordEvent("executor.launch"):
             if step_deadline > 0:
                 # the hang watchdog covers the k-step dispatch too (one
                 # wedged collective inside the scan blocks it the same way)
@@ -1587,24 +1576,25 @@ class Executor:
                     step_deadline, f"run_steps(k={k}) dispatch")
             else:
                 fetches, new_state = compiled(state, feed_vals, rng_key)
-        _metrics.observe("executor.step_host_ms",
-                         (time.perf_counter() - t0) * 1000.0)
-        for n, v in new_state.items():
-            scope.set(n, v)
-        self._maybe_snapshot(program, scope)
-        if ps_hooks:
-            fetched_by_name = dict(zip(fetch_names, fetches))
-            for h in ps_hooks:
-                h.post_multi(fetched_by_name)
-            fetches = fetches[:n_user_fetch]
-        user_names = fetch_names[:n_user_fetch] if ps_hooks else fetch_names
-        if step_deadline > 0 and sync and return_numpy:
-            return _deadline_call(
-                lambda: _package_fetches(fetches, user_names, return_numpy,
-                                         sync, step=step_idx),
-                step_deadline, "run_steps fetch materialization")
-        return _package_fetches(fetches, user_names, return_numpy, sync,
-                                step=step_idx)
+        with _trace.RecordEvent("executor.commit"):
+            for n, v in new_state.items():
+                scope.set(n, v)
+            self._maybe_snapshot(program, scope)
+            if ps_hooks:
+                fetched_by_name = dict(zip(fetch_names, fetches))
+                for h in ps_hooks:
+                    h.post_multi(fetched_by_name)
+                fetches = fetches[:n_user_fetch]
+            user_names = (fetch_names[:n_user_fetch] if ps_hooks
+                          else fetch_names)
+            if step_deadline > 0 and sync and return_numpy:
+                return _deadline_call(
+                    lambda: _package_fetches(fetches, user_names,
+                                             return_numpy, sync,
+                                             step=step_idx),
+                    step_deadline, "run_steps fetch materialization")
+            return _package_fetches(fetches, user_names, return_numpy, sync,
+                                    step=step_idx)
 
     def train_from_dataset(self, program=None, dataset=None, scope=None,
                            thread=0, debug=False, fetch_list=None,

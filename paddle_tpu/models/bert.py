@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import layers
 from ..layer_helper import ParamAttr
+from ..observability.trace import RecordEvent
 from .. import initializer as I
 from ..parallel.mesh import ShardingRules
 
@@ -264,23 +265,24 @@ def build_pretrain_program(cfg: BertConfig, use_input_mask=False):
     0 = pad, shape [B, S]) becomes an additive [-1e9/0] key-padding mask
     [B,1,1,S] that rides into the attention kernels — the padded-batch
     real-data path (reference: bert_encoder_functor.cu masks in-kernel)."""
-    input_ids = layers.data(name="input_ids", shape=[cfg.seq_len],
-                            dtype="int64")
-    mlm_labels = layers.data(name="mlm_labels", shape=[cfg.seq_len, 1],
-                             dtype="int64")
-    attn_mask = None
-    if use_input_mask:
-        input_mask = layers.data(name="input_mask", shape=[cfg.seq_len],
-                                 dtype="float32")
-        attn_mask = layers.unsqueeze(
-            layers.scale(input_mask, scale=1e9, bias=-1e9), [1, 2])
-    seq = bert_encoder(input_ids, cfg, attn_mask=attn_mask)
-    loss = bert_pretrain_loss(seq, mlm_labels, cfg)
-    aux = getattr(seq, "_moe_aux_losses", None)
-    if aux:   # switch_moe load-balancing term (Switch eq. 4, scale 0.01)
-        loss = layers.elementwise_add(
-            loss, layers.scale(layers.sums(aux), 0.01 / len(aux)))
-    loss._layer_checkpoints = getattr(seq, "_layer_checkpoints", [])
+    with RecordEvent("program.build", args={"model": "bert"}):
+        input_ids = layers.data(name="input_ids", shape=[cfg.seq_len],
+                                dtype="int64")
+        mlm_labels = layers.data(name="mlm_labels", shape=[cfg.seq_len, 1],
+                                 dtype="int64")
+        attn_mask = None
+        if use_input_mask:
+            input_mask = layers.data(name="input_mask", shape=[cfg.seq_len],
+                                     dtype="float32")
+            attn_mask = layers.unsqueeze(
+                layers.scale(input_mask, scale=1e9, bias=-1e9), [1, 2])
+        seq = bert_encoder(input_ids, cfg, attn_mask=attn_mask)
+        loss = bert_pretrain_loss(seq, mlm_labels, cfg)
+        aux = getattr(seq, "_moe_aux_losses", None)
+        if aux:   # switch_moe load-balancing term (Switch eq. 4, scale 0.01)
+            loss = layers.elementwise_add(
+                loss, layers.scale(layers.sums(aux), 0.01 / len(aux)))
+        loss._layer_checkpoints = getattr(seq, "_layer_checkpoints", [])
     return input_ids, mlm_labels, loss
 
 

@@ -17,6 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import layers
 from ..layer_helper import ParamAttr
+from ..observability.trace import RecordEvent
 from .. import initializer as I
 from ..parallel.mesh import ShardingRules
 
@@ -170,31 +171,33 @@ def build_lm_program(cfg: GPTConfig, fused_head: "bool | None" = None):
     ops/fused_ce.py) that never materializes it; smaller vocabs keep
     the dense pair (single-chunk streaming would pay the backward
     recompute for no memory win). Pass True/False to force either."""
-    tokens = layers.data(name="tokens", shape=[cfg.seq_len], dtype="int64")
-    seq, wte = gpt_decoder(tokens, cfg)
-    auto_head = fused_head is None
-    if auto_head:
-        from ..ops.fused_ce import DEFAULT_CHUNK
-        fused_head = cfg.vocab_size >= 2 * DEFAULT_CHUNK
-    with _stage_guard(cfg)(_last_stage(cfg)):
-        shift_labels = layers.slice(tokens, [1], [1], [cfg.seq_len])
-        shift_labels = layers.unsqueeze(shift_labels, [2])
-        if fused_head:
-            shift_seq = layers.slice(seq, [1], [0], [cfg.seq_len - 1])
-            loss = layers.fused_lm_head_ce(shift_seq, wte, shift_labels)
-            if auto_head:
-                # auto-selected: minimize warns if tp rules vocab-shard wte
-                # (distributed/fleet/base.py _warn_tp_fused_head)
-                loss.block.ops[-1].attrs["auto_selected"] = True
-        else:
-            logits = layers.matmul(seq, wte, transpose_y=True)  # tied head
-            shift_logits = layers.slice(logits, [1], [0],
-                                        [cfg.seq_len - 1])
-            loss = layers.softmax_with_cross_entropy(shift_logits,
-                                                     shift_labels)
-        mean_loss = layers.mean(loss)
-        mean_loss._layer_checkpoints = getattr(seq, "_layer_checkpoints", [])
-        return tokens, mean_loss
+    with RecordEvent("program.build", args={"model": "gpt"}):
+        tokens = layers.data(name="tokens", shape=[cfg.seq_len], dtype="int64")
+        seq, wte = gpt_decoder(tokens, cfg)
+        auto_head = fused_head is None
+        if auto_head:
+            from ..ops.fused_ce import DEFAULT_CHUNK
+            fused_head = cfg.vocab_size >= 2 * DEFAULT_CHUNK
+        with _stage_guard(cfg)(_last_stage(cfg)):
+            shift_labels = layers.slice(tokens, [1], [1], [cfg.seq_len])
+            shift_labels = layers.unsqueeze(shift_labels, [2])
+            if fused_head:
+                shift_seq = layers.slice(seq, [1], [0], [cfg.seq_len - 1])
+                loss = layers.fused_lm_head_ce(shift_seq, wte, shift_labels)
+                if auto_head:
+                    # auto-selected: minimize warns if tp rules vocab-shard wte
+                    # (distributed/fleet/base.py _warn_tp_fused_head)
+                    loss.block.ops[-1].attrs["auto_selected"] = True
+            else:
+                logits = layers.matmul(seq, wte, transpose_y=True)  # tied head
+                shift_logits = layers.slice(logits, [1], [0],
+                                            [cfg.seq_len - 1])
+                loss = layers.softmax_with_cross_entropy(shift_logits,
+                                                         shift_labels)
+            mean_loss = layers.mean(loss)
+            mean_loss._layer_checkpoints = getattr(
+                seq, "_layer_checkpoints", [])
+            return tokens, mean_loss
 
 
 def tp_sharding_rules() -> ShardingRules:

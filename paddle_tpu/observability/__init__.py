@@ -8,10 +8,13 @@ Layering:
 * `metrics` — counters / gauges / histograms under dotted namespaces with
   snapshot/delta views and JSONL export. `paddle_tpu.monitor` is a compat
   shim over it (stat_add -> counter, stat_set -> gauge).
-* `trace` — RecordEvent spans, instants, counter tracks, and cross-thread
-  flow events in a bounded always-on ring; chrome-trace/Perfetto export.
-  `paddle_tpu.profiler` (fluid.profiler / paddle.profiler.Profiler) is a
-  compat shim over it.
+* `trace` — RecordEvent spans (a tree: id, parent, the root's step),
+  instants and cross-thread flow events in a bounded always-on ring, each
+  span mirrored as a `pt/<name>` TraceAnnotation into any jax.profiler
+  capture; chrome-trace/Perfetto export. `paddle_tpu.profiler`
+  (fluid.profiler / paddle.profiler.Profiler) is a compat shim over it.
+* `compile_events` — JAX's trace / lower / backend-compile reports as
+  `compile.*` child spans, persistent-cache hits and misses as counters.
 * `flight` — the last N steps' spans + metric deltas, auto-dumped on step
   watchdog trips, gang failures, and degraded bench rows.
 * `podscope` — pod-scale aggregation: N per-rank flight dumps merged into
@@ -22,6 +25,7 @@ Layering:
 """
 from . import metrics  # noqa: F401
 from . import trace  # noqa: F401
+from . import compile_events  # noqa: F401
 from . import flight  # noqa: F401
 from . import podscope  # noqa: F401
 from .trace import RecordEvent  # noqa: F401

@@ -10,7 +10,8 @@ alive as a shim while adding what the flat dict could not express:
   per-step host ms, fetch-sync ms with p50/p99);
 * **snapshot/delta views** — the flight recorder diffs two snapshots to
   attribute metric movement to ONE step (observability/flight.py);
-* **export** — one JSONL line per metric for offline tooling.
+* **export** — `snapshot()` is plain JSON (bench.py stamps it into
+  every record, flight dumps carry it).
 
 Hot-path cost: one lock + one dict/float op per record (no allocation on
 the counter/gauge path), measured ≤5% of step time by
@@ -20,9 +21,7 @@ docs/observability.md (`executor.*`, `resilience.*`,
 """
 from __future__ import annotations
 
-import json
 import threading
-import time
 from typing import Dict, List, Optional
 
 _lock = threading.Lock()
@@ -149,9 +148,9 @@ def snapshot(percentiles: bool = True) -> Dict[str, dict]:
 
         {"executor.h2d_ms":   {"type": "counter", "value": 12.5},
          "executor.dispatch_queue_depth": {"type": "gauge", "value": 1},
-         "executor.step_host_ms": {"type": "histogram", "count": 20,
-                                   "sum": ..., "min": ..., "max": ...,
-                                   "p50": ..., "p99": ...}}
+         "executor.fetch_sync_ms": {"type": "histogram", "count": 20,
+                                    "sum": ..., "min": ..., "max": ...,
+                                    "p50": ..., "p99": ...}}
 
     percentiles=False skips the p50/p99 fields — they cost a sort of each
     histogram's reservoir, which the flight recorder's twice-per-step
@@ -193,19 +192,3 @@ def delta(prev: Dict[str, dict],
             if c["value"] != pv:
                 out[name] = {"type": COUNTER, "value": c["value"] - pv}
     return out
-
-
-def export_jsonl(path: str) -> str:
-    """One JSON line per metric ({"name", "type", ...fields, "ts"})."""
-    import os
-    snap = snapshot()
-    ts = time.time()
-    d = os.path.dirname(path)
-    if d:
-        os.makedirs(d, exist_ok=True)
-    with open(path, "w") as f:
-        for name in sorted(snap):
-            row = {"name": name, "ts": ts}
-            row.update(snap[name])
-            f.write(json.dumps(row) + "\n")
-    return path

@@ -17,9 +17,22 @@ hard memory bound. Thread ids are REAL idents, with thread-name metadata
 events ("s"/"f" phases sharing cat+name+id) link a step's dispatch to its
 later fetch materialization across threads.
 
+Spans form a TREE: a thread-local stack gives every RecordEvent an `id`
+and the `parent` id of the span open on its thread (None for a root), and
+a span inherits `step` and `exe` from its root — `executor.step`, opened
+by Executor._step_window — so the events of one dispatch can be gathered
+and `self_times` computed. Names are fixed strings (docs/observability.md
+has the table); what varies rides in `args`.
+
+One clock with the device trace: every span also enters a
+`jax.profiler.TraceAnnotation` named `pt/<name>`, so in ANY jax.profiler
+capture, whoever started it, the program's spans lie in the xplane's host
+plane on the profiler's own timeline beside the device operations.
+
 Overhead: one flag lookup when disabled (FLAGS_trace_events=0); enabled,
-two perf_counter_ns calls + a locked deque append per span — bounded ≤5%
-of step time by tests/test_observability.py's timing A/B.
+two perf_counter_ns calls, a TraceMe enter/exit (a no-op check while no
+capture runs) + a locked deque append per span — bounded ≤5% of step time
+by tests/test_observability.py's timing A/B.
 """
 from __future__ import annotations
 
@@ -31,6 +44,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..flags import flag
 from . import metrics as _metrics
 
@@ -38,7 +53,12 @@ _lock = threading.Lock()
 _events: "collections.deque[dict]" = collections.deque(maxlen=65536)
 _thread_names: Dict[int, str] = {}
 _flow_ids = itertools.count(1)
+_span_ids = itertools.count(1)
 _dropped = 0
+_tls = threading.local()     # .stack: the spans open on this thread
+
+# prefix of the program's spans in a jax.profiler capture's host plane
+ANNOTATION_PREFIX = "pt/"
 
 
 def now_us() -> float:
@@ -92,14 +112,36 @@ def _append(ev: dict):
         _events.append(ev)
 
 
+def _stack() -> list:
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        return _tls.stack
+
+
+def _inherit(args: Optional[dict], parent: "Optional[RecordEvent]"):
+    """A child's args: its own plus its root's `step` and `exe`."""
+    if parent is None or not parent.args:
+        return args
+    up = {k: parent.args[k] for k in ("step", "exe") if k in parent.args}
+    if not up:
+        return args
+    if args:
+        up.update(args)
+    return up
+
+
 class RecordEvent:
     """RAII host span (reference platform/profiler.h RecordEvent): a
-    complete ("X") chrome-trace event over the with-block's wall time.
-    `args` ride into the trace verbatim (per-step phase annotations:
-    {"step": n, ...}); extra args can be attached mid-span with
+    complete ("X") chrome-trace event over the with-block's wall time,
+    with an `id`, the `parent` id of the span it was opened under (None
+    for a root) and its root's `step` / `exe` among its args. `args` ride
+    into the trace verbatim; extra args can be attached mid-span with
     add_args()."""
 
-    __slots__ = ("name", "cat", "args", "_t0", "_on")
+    __slots__ = ("name", "cat", "args", "id", "_t0", "_on", "_parent",
+                 "_ann")
 
     def __init__(self, name: str, cat: str = "host", args: Optional[dict] = None):
         self.name = name
@@ -115,18 +157,58 @@ class RecordEvent:
     def __enter__(self):
         self._on = enabled()
         if self._on:
+            stack = _stack()
+            parent = stack[-1] if stack else None
+            self.id = next(_span_ids)
+            self._parent = None if parent is None else parent.id
+            self.args = _inherit(self.args, parent)
+            stack.append(self)
+            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self.name)
+            self._ann.__enter__()
             self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *a):
         if self._on:
             t1 = time.perf_counter_ns()
+            self._ann.__exit__(None, None, None)
+            stack = _stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            elif self in stack:
+                # a child left open (an exception between a bare enter
+                # and exit) must not misparent later spans
+                del stack[stack.index(self):]
             ev = {"name": self.name, "ph": "X", "cat": self.cat,
-                  "ts": self._t0 / 1000.0, "dur": (t1 - self._t0) / 1000.0}
+                  "ts": self._t0 / 1000.0, "dur": (t1 - self._t0) / 1000.0,
+                  "id": self.id, "parent": self._parent}
             if self.args:
                 ev["args"] = dict(self.args)
             _append(ev)
         return False
+
+
+def current_span() -> "Optional[RecordEvent]":
+    """The span open on this thread, if any."""
+    stack = _stack()
+    return stack[-1] if stack else None
+
+
+def complete(name: str, start_ns: int, end_ns: int, cat: str = "host",
+             args: Optional[dict] = None):
+    """Record a span from two `perf_counter_ns` readings taken elsewhere
+    (the package import, a jax.monitoring duration that ends now): its
+    parent is the span open on this thread."""
+    if not enabled():
+        return
+    parent = current_span()
+    ev = {"name": name, "ph": "X", "cat": cat, "ts": start_ns / 1000.0,
+          "dur": (end_ns - start_ns) / 1000.0, "id": next(_span_ids),
+          "parent": None if parent is None else parent.id}
+    args = _inherit(args, parent)
+    if args:
+        ev["args"] = dict(args)
+    _append(ev)
 
 
 def record_event(name, **kw):
@@ -141,15 +223,6 @@ def instant(name: str, args: Optional[dict] = None, cat: str = "host"):
     if args:
         ev["args"] = dict(args)
     _append(ev)
-
-
-def counter_event(name: str, values: Dict[str, float]):
-    """Chrome counter track ("C" phase): per-step device cost attribution
-    (executor.annotate_step_cost) renders as a stacked counter lane."""
-    if not enabled():
-        return
-    _append({"name": name, "ph": "C", "cat": "host", "ts": now_us(),
-             "args": {k: float(v) for k, v in values.items()}})
 
 
 # ---- flow events (cross-thread dispatch -> fetch linkage) -------------------
@@ -191,6 +264,30 @@ def events(since_ts: Optional[float] = None) -> List[dict]:
         return evs
     return [e for e in evs
             if e["ts"] + e.get("dur", 0.0) >= since_ts]
+
+
+def self_times(evs: List[dict]) -> Dict[int, float]:
+    """{span id: self time in microseconds}: a span's duration less the
+    part of its interval that its child spans cover (children that
+    overlap each other are counted once)."""
+    kids: Dict[int, list] = {}
+    for e in evs:
+        if e.get("ph") == "X" and e.get("parent") is not None:
+            kids.setdefault(e["parent"], []).append(
+                (e["ts"], e["ts"] + e["dur"]))
+    out = {}
+    for e in evs:
+        if e.get("ph") != "X" or "id" not in e:
+            continue
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        covered, cur = 0.0, lo
+        for a, b in sorted(kids.get(e["id"], ())):
+            a, b = max(a, cur), min(b, hi)
+            if b > a:
+                covered += b - a
+                cur = b
+        out[e["id"]] = e["dur"] - covered
+    return out
 
 
 _dropped_mirrored = 0

@@ -22,6 +22,7 @@ from .framework.dtype import dtype_name
 from .layer_helper import LayerHelper
 from . import initializer as init_mod
 from . import layers
+from .observability.trace import RecordEvent
 
 __all__ = [
     "Optimizer", "SGD", "SGDOptimizer", "Momentum", "MomentumOptimizer",
@@ -159,9 +160,10 @@ class Optimizer:
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
-        params_grads = self.backward(loss, startup_program, parameter_list,
-                                     no_grad_set)
-        self.apply_gradients(params_grads)
+        with RecordEvent("optimizer.minimize"):
+            params_grads = self.backward(loss, startup_program,
+                                         parameter_list, no_grad_set)
+            self.apply_gradients(params_grads)
         return [], params_grads
 
     # dygraph API

@@ -177,7 +177,7 @@ try:
     names = {e["name"] for e in spans}
     for want in ("stage", "fetch.materialize"):
         assert want in names, f"missing span {want!r} in {sorted(names)}"
-    assert any(n.startswith("executor_run") for n in names), names
+    assert "executor.step" in names and "executor.launch" in names, names
     assert all("ts" in e and "dur" in e for e in spans)
     metas = [e for e in evs if e.get("ph") == "M"
              and e["name"] == "thread_name"]

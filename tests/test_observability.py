@@ -52,7 +52,7 @@ def _build(width=8):
 # typed metrics registry
 # --------------------------------------------------------------------------
 
-def test_metrics_types_snapshot_delta_jsonl(tmp_path):
+def test_metrics_types_snapshot_delta_json():
     for n in ("t.c", "t.g", "t.h"):
         metrics.reset(n)
     metrics.inc("t.c")
@@ -84,11 +84,11 @@ def test_metrics_types_snapshot_delta_jsonl(tmp_path):
     assert d["t.h"]["count"] == 1 and d["t.h"]["sum"] == 5.0
     assert "t.g" not in d                # unmoved gauge omitted
 
-    p = metrics.export_jsonl(str(tmp_path / "m.jsonl"))
-    rows = [json.loads(ln) for ln in open(p)]
-    byname = {r["name"]: r for r in rows}
-    assert byname["t.c"]["value"] == 5.0 and "ts" in byname["t.c"]
-    assert byname["t.h"]["count"] == 101
+    # the export IS the snapshot: plain JSON, what bench.py stamps into its
+    # records and flight dumps carry (export_jsonl wrote a file nothing read)
+    byname = json.loads(json.dumps(metrics.snapshot()))
+    assert byname["t.c"] == {"type": "counter", "value": 5.0}
+    assert byname["t.h"]["count"] == 101 and byname["t.h"]["sum"] == 4955.0
     for n in ("t.c", "t.g", "t.h"):
         metrics.reset(n)
 
@@ -155,14 +155,20 @@ def test_trace_disabled_records_nothing():
 
 def test_traced_async_loop_exports_chrome_trace(tmp_path):
     """20-step async loop with staged feeds: the exported JSON holds host
-    spans for stage/dispatch/fetch, per-step annotations, device cost
-    attribution on the dispatch span, and a flow event linking a step's
+    spans for stage/dispatch/fetch, per-step annotations, no trace of the
+    removed XLA cost attribution, and a flow event linking a step's
     dispatch to its materialization on ANOTHER thread."""
     _fresh()
     exe, loss, feed = _build()
     exe.run(feed=feed, fetch_list=[loss])           # compile + warm
-    exe.annotate_step_cost(feed=feed, fetch_list=[loss])
     trace.clear()
+    # XLA's cost analysis is returned to the caller and recorded nowhere:
+    # no counter track, no gauges, no args on the dispatch spans
+    cost = exe.annotate_step_cost(feed=feed, fetch_list=[loss])
+    assert cost["device_flops"] > 0
+    assert not [e for e in trace.events() if e.get("ph") == "C"]
+    assert not any(n.startswith("executor.step_")
+                   for n in metrics.snapshot())
     flight.clear()
     handles = []
     staged = exe.stage(feed)
@@ -184,12 +190,13 @@ def test_traced_async_loop_exports_chrome_trace(tmp_path):
     spans = [e for e in evs if e.get("ph") == "X"]
     names = {e["name"] for e in spans}
     assert "stage" in names and "fetch.materialize" in names
-    dispatch = [e for e in spans if e["name"].startswith("executor_run")]
+    dispatch = [e for e in spans if e["name"] == "executor.launch"]
     assert len(dispatch) >= 20
-    # per-step phase annotations + device cost attribution ride as args
-    steps_seen = {e["args"]["step"] for e in dispatch if "args" in e}
+    # every dispatch carries its root's step; names carry no payload
+    steps_seen = {e["args"]["step"] for e in dispatch}
     assert len(steps_seen) >= 20
-    assert any("device_flops" in e.get("args", {}) for e in dispatch)
+    assert not any("device_flops" in e["args"] for e in dispatch)
+    assert not any("#" in n for n in names)
     # every span lane has thread-name metadata
     metas = [e for e in evs if e.get("ph") == "M"
              and e["name"] == "thread_name"]
@@ -274,7 +281,7 @@ def test_flight_dump_on_step_deadline_trip(tmp_path):
                for s in d["steps"])
     # the covering trace events include those steps' dispatch spans
     dnames = [e["name"] for e in d["trace_events"]]
-    assert sum(1 for n in dnames if n.startswith("executor_run")) >= 3
+    assert dnames.count("executor.step") >= 3
     assert d["metrics"]["executor.step_deadline_trips"]["value"] == 1
 
 
@@ -465,3 +472,202 @@ def test_tracer_overhead_bounded():
             return
     raise AssertionError(
         f"tracer overhead never came in under 5%: ratios {deltas}")
+
+
+# --------------------------------------------------------------------------
+# the span tree: ids, parents, the root's step; self time; compile phases
+# --------------------------------------------------------------------------
+
+def _spans(evs=None):
+    return [e for e in (trace.events() if evs is None else evs)
+            if e.get("ph") == "X"]
+
+
+def _under(root, evs):
+    """The spans of `evs` that have `root` among their ancestors."""
+    by = {e["id"]: e for e in evs}
+    out = []
+    for e in evs:
+        p = e["parent"]
+        while p is not None and p != root["id"]:
+            p = by[p]["parent"] if p in by else None
+        if p is not None:
+            out.append(e)
+    return out
+
+
+def test_every_span_has_id_parent_and_its_roots_step():
+    _fresh()
+    exe, loss, feed = _build()
+    trace.clear()
+    exe.run(feed=feed, fetch_list=[loss])
+    exe.run_steps(2, feed=feed, fetch_list=[loss])
+    with trace.RecordEvent("outside"):
+        with trace.RecordEvent("inside", args={"n": 1}):
+            pass
+    spans = _spans()
+    ids = [e["id"] for e in spans]
+    assert len(ids) == len(set(ids)) and all("parent" in e for e in spans)
+    assert not any("#" in e["name"] for e in spans)
+    by = {e["id"]: e for e in spans}
+    outside, = [e for e in spans if e["name"] == "outside"]
+    inside, = [e for e in spans if e["name"] == "inside"]
+    assert outside["parent"] is None and inside["parent"] == outside["id"]
+    assert inside["args"] == {"n": 1}            # no step to inherit
+    roots = [e for e in spans if e["name"] == "executor.step"]
+    assert [r["args"]["kind"] for r in roots] == ["run", "run_steps"]
+    assert [r["args"]["k"] for r in roots] == [1, 2]
+    for root in roots:
+        assert root["parent"] is None
+        assert root["args"]["program"] == "main" and root["args"]["ops"] > 0
+        kids = _under(root, spans)
+        assert {"executor.prepare", "executor.launch",
+                "executor.commit"} <= {e["name"] for e in kids}
+        for e in kids:                           # grandchildren too
+            assert e["args"]["step"] == root["args"]["step"]
+            assert e["args"]["exe"] == root["args"]["exe"]
+            assert by[e["parent"]]["ts"] <= e["ts"] + 1.0
+
+
+def test_startup_program_root_says_so():
+    _fresh()
+    x = layers.data(name="x", shape=[4], dtype="float32")
+    layers.fc(x, 2)
+    trace.clear()
+    fluid.Executor().run(fluid.default_startup_program())
+    root, = [e for e in _spans() if e["name"] == "executor.step"]
+    assert root["args"]["program"] == "startup"
+    assert root["args"]["kind"] == "run"
+
+
+def test_self_times_on_a_hand_made_tree():
+    def span(i, parent, ts, dur):
+        return {"name": f"s{i}", "ph": "X", "ts": ts, "dur": dur, "id": i,
+                "parent": parent}
+    evs = [span(1, None, 0.0, 100.0),
+           span(2, 1, 10.0, 30.0),              # 10..40
+           span(3, 1, 35.0, 25.0),              # 35..60 overlaps 2 by 5
+           span(4, 2, 15.0, 10.0),              # grandchild: 2's, not 1's
+           span(5, 1, 90.0, 20.0),              # 90..110: clipped to 100
+           {"name": "i", "ph": "i", "ts": 5.0}]
+    st = trace.self_times(evs)
+    assert st == {1: 100.0 - 50.0 - 10.0, 2: 20.0, 3: 25.0, 4: 10.0,
+                  5: 20.0}
+
+
+def test_a_span_left_open_by_an_exception_does_not_misparent_the_next():
+    trace.clear()
+    with pytest.raises(ValueError):
+        with trace.RecordEvent("a"):
+            trace.RecordEvent("leaked").__enter__()   # never exited
+            raise ValueError
+    with trace.RecordEvent("b"):
+        pass
+    by = {e["name"]: e for e in _spans()}
+    assert by["b"]["parent"] is None and "leaked" not in by
+    assert trace.current_span() is None
+
+
+def test_run_steps_is_one_root_with_three_phases_and_cold_compile_spans():
+    _fresh()
+    exe, loss, feed = _build(width=5)            # a program no test compiled
+    trace.clear()
+    exe.run_steps(3, feed=feed, fetch_list=[loss])
+    cold = _spans()
+    trace.clear()
+    exe.run_steps(3, feed=feed, fetch_list=[loss])
+    warm = _spans()
+    for spans, compiles in ((cold, True), (warm, False)):
+        root, = [e for e in spans if e["name"] == "executor.step"]
+        assert root["args"]["kind"] == "run_steps" and root["args"]["k"] == 3
+        phases = [e["name"] for e in spans if e["parent"] == root["id"]]
+        assert phases == ["executor.prepare", "executor.launch",
+                          "executor.commit"]
+        under = {e["name"] for e in _under(root, spans)}
+        got = {"compile.trace", "compile.lower", "compile.backend"} & under
+        assert got == ({"compile.trace", "compile.lower", "compile.backend"}
+                       if compiles else set()), under
+        assert ("executor.build_block" in under) == compiles
+        assert "compile" not in under
+    # the step program's own phases lie in the launch, where JAX runs them
+    launch, = [e for e in cold if e["name"] == "executor.launch"]
+    in_launch = [e for e in cold if e["parent"] == launch["id"]]
+    assert [e["name"] for e in in_launch] == [
+        "compile.trace", "compile.lower", "compile.backend"]
+    assert all(e["cat"] == "compile" for e in in_launch)
+    # nested jits (every jnp call in the traced step) did not become spans
+    assert sum(e["name"] == "compile.trace" for e in cold) < 20
+    st = trace.self_times(cold)
+    assert st[launch["id"]] < launch["dur"] - in_launch[0]["dur"] + 1.0
+
+
+def test_a_users_own_jit_compiles_into_spans_without_a_parent():
+    import jax
+    import jax.numpy as jnp
+    trace.clear()
+    jax.jit(lambda a: jnp.tanh(a) * 3.25 + 7.5)(jnp.ones((3, 5)))
+    mine = [e for e in _spans() if e["name"].startswith("compile.")]
+    assert {"compile.trace", "compile.lower", "compile.backend"} <= {
+        e["name"] for e in mine}
+    assert all(e["parent"] is None and "step" not in e.get("args", {})
+               for e in mine)
+
+
+def test_persistent_cache_counters_follow_jax_events():
+    from paddle_tpu.observability import compile_events
+    before = (metrics.get("compile.persistent_cache_hits"),
+              metrics.get("compile.persistent_cache_misses"))
+    compile_events._on_event("/jax/compilation_cache/cache_hits")
+    compile_events._on_event("/jax/compilation_cache/cache_misses")
+    compile_events._on_event("/jax/compilation_cache/tasks_using_cache")
+    assert (metrics.get("compile.persistent_cache_hits"),
+            metrics.get("compile.persistent_cache_misses")) == (
+        before[0] + 1, before[1] + 1)
+    snap = metrics.snapshot()
+    assert snap["compile.persistent_cache_hits"]["type"] == "counter"
+
+
+def test_spans_lie_in_a_jax_profiler_capture_under_the_pt_prefix(tmp_path):
+    """One clock with the device trace: in any jax.profiler capture the
+    program's spans are host events named pt/<span> of the xplane."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    _fresh()
+    exe, loss, feed = _build()
+    exe.run_steps(2, feed=feed, fetch_list=[loss])       # compile + warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            exe.run_steps(2, feed=feed, fetch_list=[loss])
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    names = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names += [e.name for e in line.events
+                          if e.name.startswith(trace.ANNOTATION_PREFIX)]
+    for want in ("executor.step", "executor.prepare", "executor.launch",
+                 "executor.commit"):
+        assert names.count("pt/" + want) == 3, (want, sorted(set(names)))
+
+
+def test_the_package_import_is_one_span_recorded_once():
+    import subprocess
+    import sys
+    code = ("import sys, json, paddle_tpu\n"
+            "from paddle_tpu.observability import trace\n"
+            "evs = [e for e in trace.events()"
+            " if e['name'] == 'startup.import']\n"
+            "print(json.dumps({'n': len(evs), 'dur': evs[0]['dur'],"
+            " 'parent': evs[0]['parent'],"
+            " 'twice': 'paddle_tpu.__init__' in sys.modules}))\n")
+    out = subprocess.run([sys.executable, "-c", code], text=True,
+                         stdout=subprocess.PIPE, check=True, timeout=300,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["n"] == 1 and got["parent"] is None and got["dur"] > 0
+    assert not got["twice"]      # fluid used to run the package body again

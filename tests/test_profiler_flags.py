@@ -28,7 +28,7 @@ def test_profiler_collects_spans_and_exports_timeline(tmp_path):
         trace = json.load(f)
     names = [e["name"] for e in trace["traceEvents"]]
     assert len(names) >= 3
-    assert any("executor_run" in n for n in names)
+    assert "executor.step" in names and "executor.launch" in names
     # complete ("X") spans carry ts+dur; the export may also include
     # thread-name metadata ("M") and instant/flow events (no dur)
     spans = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
