@@ -24,6 +24,9 @@ white_list = {
 # ops)
 keep_f32_slots = {
     "fused_lm_head_ce": {"Bias"},
+    # the flash kernels' logsumexp residual is float32 whatever list the op
+    # is on (its grad op reads it as FO:Lse)
+    "fused_attention": {"Lse"},
 }
 
 # ops forced to float32 (reference black list: reductions/normalizations)

@@ -286,7 +286,7 @@ SPECS: Dict[str, OpSpec] = {
         attr_types={"padding_idx": int}, sharding="selected_rows"),
     "fused_attention": OpSpec(
         inputs={"Q": ONE, "K": ONE, "V": ONE, "Mask": OPT},
-        outputs={"Out": ONE},
+        outputs={"Out": ONE, "Lse": OPT},
         attr_types={"scale": _NUM, "dropout": _NUM, "causal": bool,
                     "sequence_parallel": bool, "sp_mode": str},
         sharding="attention"),

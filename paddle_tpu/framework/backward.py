@@ -4,8 +4,11 @@ Reference counterpart: python/paddle/fluid/backward.py:1275 (+ C++ per-op grad
 makers via core.get_grad_op_desc, backward.py:984). TPU-native difference: no
 per-op hand-written grad kernels exist or are needed — each forward op's grad
 is a single generic `__vjp__` op whose lowering calls jax.vjp on the forward
-lowering (ops/registry.py). Gradient aggregation for multi-consumer vars uses
-the reference's rename+sum scheme (backward.py _addup_repetitive_outputs_).
+lowering (ops/registry.py). An op that declares a grad rule also gets its own
+forward outputs as `FO:<slot>` inputs of that `__vjp__` op, the way the
+reference's grad-op makers take Out beside X and Out@GRAD. Gradient
+aggregation for multi-consumer vars uses the reference's rename+sum scheme
+(backward.py _addup_repetitive_outputs_).
 """
 from __future__ import annotations
 
@@ -214,6 +217,12 @@ def append_backward(loss: Variable, parameter_list=None,
                 g = acc.finalize(n)
                 og_names.append(g if g is not None else "@EMPTY@")
             grad_inputs[f"OG:{slot}"] = og_names
+        if opdef.grad is not None:
+            # residuals for the op's grad rule: what the forward launch
+            # already wrote
+            for slot in opdef.residual_slots:
+                if slot in op.outputs and "@EMPTY@" not in op.outputs[slot]:
+                    grad_inputs[f"FO:{slot}"] = list(op.outputs[slot])
 
         grad_outputs = {}
         for slot, names in op.inputs.items():

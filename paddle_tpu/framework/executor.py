@@ -378,13 +378,15 @@ def _current_lowering_program():
 
 def _run_block(block, feed_names, fetch_names, mut_names, ro_names,
                written_state, mut_state: dict, ro_state: dict, feeds: dict,
-               rng_key):
+               rng_key, shapes_only=False):
     """The traced function: sequentially applies each op's lowering over an
-    env dict. This is trace-time Python — at run time it is one XLA program."""
+    env dict. This is trace-time Python — at run time it is one XLA program.
+    `shapes_only` marks a trace whose values nobody runs (jax.eval_shape):
+    lowerings that have a cheaper route for that take it."""
     env = dict(ro_state)
     env.update(mut_state)
     env.update(feeds)
-    ctx = registry.LowerCtx(rng_key=rng_key)
+    ctx = registry.LowerCtx(rng_key=rng_key, is_eval_shape=shapes_only)
     _lowering_programs.append(block.program)
     try:
         return _run_block_inner(block, fetch_names, written_state, env, ctx)
@@ -450,7 +452,7 @@ def _run_block_multistep(k_steps, block, feed_names, fetch_names, mut_names,
     _, st_shapes = jax.eval_shape(
         lambda m, f, kk: _run_block(block, feed_names, fetch_names,
                                     mut_names, ro_names, written_state,
-                                    m, ro_state, f, kk),
+                                    m, ro_state, f, kk, shapes_only=True),
         mut_state, feeds0, jax.random.key(0))
     extra0 = {n: (ro_state[n] if n in ro_state
                   else jnp.zeros(s.shape, s.dtype))
@@ -627,9 +629,11 @@ def _amp_cast_ins(op_type, ins, low_dtype):
     skip = keep_f32_slots.get(op_type, ())
     out = {}
     for slot, vals in ins.items():
-        # grad ops see forward slots plus OG:<slot> cotangents; keep both
-        # f32 for an excluded slot
-        base_slot = slot[3:] if slot.startswith(("OG:", "IG:")) else slot
+        # grad ops see forward slots plus OG:<slot> cotangents and, for an
+        # op with a grad rule, FO:<slot> forward outputs; keep all f32 for
+        # an excluded slot
+        base_slot = slot[3:] if slot.startswith(("OG:", "IG:", "FO:")) \
+            else slot
         if base_slot in skip:
             out[slot] = vals
             continue
@@ -1777,8 +1781,23 @@ class Executor:
         return self._inspect_compiled(feed, fetch_list, program, scope,
                                       k).memory_analysis()
 
+    def step_jaxpr(self, feed=None, fetch_list=None, program=None,
+                   scope=None, k=None):
+        """The jitted step as a ClosedJaxpr, before XLA: what the op
+        lowerings traced to, kernel calls by name included (a Pallas kernel
+        is a `pallas_call` equation here on every backend, and a Mosaic
+        custom call only in a TPU's HLO). Same signature and cache rules as
+        compiled_hlo; traced only, never compiled or run."""
+        return self._inspect_traced(feed, fetch_list, program, scope,
+                                    k).jaxpr
+
     def _inspect_compiled(self, feed=None, fetch_list=None, program=None,
                           scope=None, k=None):
+        return self._inspect_traced(feed, fetch_list, program, scope,
+                                    k).lower().compile()
+
+    def _inspect_traced(self, feed=None, fetch_list=None, program=None,
+                        scope=None, k=None):
         import jax.numpy as jnp
 
         from . import errors
@@ -1840,8 +1859,7 @@ class Executor:
         mut = {n: scope.find(n) for n in compiled.mut_names}
         ro = {n: scope.find(n) for n in compiled.ro_names}
         feeds = {n: jnp.asarray(v) for n, v in feed_vals.items()}
-        return compiled.jitted.lower(
-            mut, ro, feeds, jax.random.key(0)).compile()
+        return compiled.jitted.trace(mut, ro, feeds, jax.random.key(0))
 
     def infer_from_dataset(self, program=None, dataset=None, scope=None,
                            thread=0, debug=False, fetch_list=None,

@@ -814,6 +814,10 @@ def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
     long-context path the reference lacks (parallel/ring_attention.py)."""
     helper = LayerHelper("fused_attention")
     out = helper.create_variable_for_type_inference(q.dtype)
+    # the flash kernels' per-row logsumexp, read by the op's grad rule
+    # (ops/attention.py); an empty placeholder on every other route
+    lse = helper.create_variable_for_type_inference("float32")
+    lse.stop_gradient = True
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if mask is not None:
         inputs["Mask"] = [mask]
@@ -823,7 +827,7 @@ def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
     if scale is not None:
         attrs["scale"] = scale
     helper.append_op("fused_attention", inputs=inputs,
-                     outputs={"Out": [out]}, attrs=attrs)
+                     outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
     return out
 
 

@@ -6,7 +6,11 @@ op whose lowering is either (a) the XLA path — two MXU matmuls + fused
 softmax, which XLA already schedules well — or (b) a Pallas flash-attention
 kernel (ops/pallas/flash_attention.py) when running on real TPU with
 supported shapes, cutting HBM traffic for long sequences. The choice is
-made from the backend and the static shapes alone (`_use_pallas`).
+made from the backend and the static shapes alone (`_route`), once for the
+forward and once more, from the same facts, for the op's grad rule: on the
+flash route the backward takes the forward launch's `Out` and `Lse` and runs
+the two backward kernels; on every other route the rule declines and the
+generic `__vjp__` differentiates the lowering (docs/custom_ops.md).
 """
 from __future__ import annotations
 
@@ -71,18 +75,14 @@ def _use_pallas(q):
     return s >= min_seq and s % 128 == 0 and hd in (64, 128, 256)
 
 
-@register("fused_attention", is_random=True, nondiff_slots=("Mask",))
-def _fused_attention(ctx, ins, attrs):
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
-    mask = ins["Mask"][0] if ins.get("Mask") else None
-    scale = attrs.get("scale", 1.0 / math.sqrt(q.shape[-1]))
-    dropout = attrs.get("dropout", 0.0)
-    if attrs.get("is_test", False):
-        dropout = 0.0
-    key = ctx.op_key(attrs) if dropout else None
-    causal = attrs.get("causal", False)
-    if attrs.get("sequence_parallel") and not ctx.is_eval_shape \
-            and not isinstance(q, jax.ShapeDtypeStruct):
+def _route(ctx, q, mask, attrs):
+    """("sp", fn) | ("flash", None) | ("dense", None): which lowering this
+    op takes, from static facts alone (attrs, mesh, backend, shapes), so
+    the forward and the grad rule cannot disagree. Build-time shape
+    inference always reads "dense"."""
+    if ctx.is_eval_shape or isinstance(q, jax.ShapeDtypeStruct):
+        return "dense", None
+    if attrs.get("sequence_parallel"):
         mesh = _current_mesh()
         if mesh is not None and "sp" in mesh.axis_names \
                 and mesh.shape["sp"] > 1:
@@ -90,32 +90,98 @@ def _fused_attention(ctx, ins, attrs):
                                                    ulysses_attention)
             fn = (ulysses_attention
                   if attrs.get("sp_mode") == "ulysses" else ring_attention)
-            sp_seed = _derive_seed(key) if dropout else None
-            # key-padding masks + in-body counter dropout ride the ring
-            # (round 4; full [S, S] masks still raise — see _check_mask)
-            return {"Out": [fn(q, k, v, mesh=mesh, scale=scale,
-                               causal=causal, mask=mask,
-                               dropout=float(dropout), seed=sp_seed)]}
-    if not ctx.is_eval_shape \
-            and not isinstance(q, jax.ShapeDtypeStruct) and _use_pallas(q) \
-            and (mask is None or _mask_flashable(mask, q)):
+            return "sp", functools.partial(fn, mesh=mesh)
+    if _use_pallas(q) and (mask is None or _mask_flashable(mask, q)):
+        return "flash", None
+    return "dense", None
+
+
+def _unpack(ins, attrs):
+    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    mask = ins["Mask"][0] if ins.get("Mask") else None
+    scale = attrs.get("scale", 1.0 / math.sqrt(q.shape[-1]))
+    dropout = attrs.get("dropout", 0.0)
+    if attrs.get("is_test", False):
+        dropout = 0.0
+    return q, k, v, mask, scale, dropout, attrs.get("causal", False)
+
+
+def _no_lse(q):
+    """`Lse` off the flash route: an empty placeholder that nothing reads
+    (the flash forward writes its per-row logsumexp [B, nh, S] there for
+    the grad rule)."""
+    return jnp.zeros(q.shape[:2] + (0,), jnp.float32)
+
+
+def _flash_failed(e, q, mask, causal, dropout):
+    return RuntimeError(
+        f"pallas flash attention failed for q{tuple(q.shape)} "
+        f"{q.dtype}, mask "
+        f"{None if mask is None else tuple(mask.shape)}, "
+        f"causal={causal}, dropout={dropout}: {e}")
+
+
+def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
+    """Grad rule: on the flash route the backward is the two backward
+    kernels on the `Out` and `Lse` the forward launch wrote. Every other
+    route (and a program built before `Lse` existed) declines, and the
+    generic `__vjp__` differentiates the forward lowering as before."""
+    q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
+    dout = (ogs.get("Out") or [None])[0]
+    if _route(ctx, q, mask, attrs)[0] != "flash" or dout is None \
+            or not outs.get("Out") or not outs.get("Lse"):
+        return None
+    from .pallas.flash_attention import flash_attention_bwd
+    from ..observability import metrics
+    out, lse = outs["Out"][0], outs["Lse"][0]
+    b, nh, s, _ = q.shape
+    seed = _derive_seed(ctx.op_key(attrs)) if dropout else None
+    try:
+        dq, dk, dv = flash_attention_bwd(
+            q, k, v, out, lse.reshape(b * nh, s), dout.astype(out.dtype),
+            scale=scale, causal=causal, dropout=dropout, seed=seed,
+            mask=mask)
+    except Exception as e:
+        raise _flash_failed(e, q, mask, causal, dropout) from e
+    metrics.inc("attention.flash_bwd_residual")
+    return {"Q": [dq], "K": [dk], "V": [dv]}
+
+
+@register("fused_attention", is_random=True, nondiff_slots=("Mask",),
+          grad=_fused_attention_grad, residual_slots=("Out", "Lse"))
+def _fused_attention(ctx, ins, attrs):
+    q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
+    key = ctx.op_key(attrs) if dropout else None
+    b, nh, s, _ = q.shape
+    route, sp_fn = _route(ctx, q, mask, attrs)
+    if route == "sp":
+        sp_seed = _derive_seed(key) if dropout else None
+        # key-padding masks + in-body counter dropout ride the ring
+        # (round 4; full [S, S] masks still raise — see _check_mask)
+        return {"Out": [sp_fn(q, k, v, scale=scale, causal=causal,
+                              mask=mask, dropout=float(dropout),
+                              seed=sp_seed)],
+                "Lse": [_no_lse(q)]}
+    if route == "flash":
         from .pallas.flash_attention import flash_attention
         seed = _derive_seed(key) if dropout else None
         try:
-            out = flash_attention(q, k, v, scale=scale, causal=causal,
-                                  dropout=dropout, seed=seed, mask=mask)
+            out, lse = flash_attention(q, k, v, scale=scale, causal=causal,
+                                       dropout=dropout, seed=seed, mask=mask,
+                                       return_lse=True)
         except Exception as e:
-            raise RuntimeError(
-                f"pallas flash attention failed for q{tuple(q.shape)} "
-                f"{q.dtype}, mask "
-                f"{None if mask is None else tuple(mask.shape)}, "
-                f"causal={causal}, dropout={dropout}: {e}") from e
-        return {"Out": [out]}
+            raise _flash_failed(e, q, mask, causal, dropout) from e
+        if ctx.in_vjp:
+            # the generic __vjp__ (a whole segment under recompute or layer
+            # scan) lowers this forward a second time to differentiate it
+            from ..observability import metrics
+            metrics.inc("attention.flash_bwd_recomputed")
+        return {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}
     if causal:
-        s = q.shape[2]
         tri = jnp.triu(jnp.full((s, s), -1e9, jnp.float32), 1)[None, None]
         mask = tri if mask is None else mask + tri
-    return {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)]}
+    return {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)],
+            "Lse": [_no_lse(q)]}
 
 
 

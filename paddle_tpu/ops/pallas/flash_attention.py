@@ -15,6 +15,13 @@ delta = rowsum(dO ∘ O) is computed in-kernel from resident blocks, so no
 extra residual tensor is materialized. lse is stored broadcast along a
 128-lane trailing dim (the Mosaic-safe layout).
 
+Two ways to the backward kernels. `jax.vjp(flash_attention)` (custom_vjp):
+the forward kernel runs in the vjp's forward pass and hands out/lse to the
+backward. `flash_attention(..., return_lse=True)` + `flash_attention_bwd`:
+the caller keeps out and lse from a forward launch it already made and runs
+the two backward kernels alone (the `fused_attention` op's grad rule,
+ops/attention.py: one forward kernel per layer in a train step).
+
 An additive mask rides into all three kernels (the reference handles padded
 batches in-kernel too — bert_encoder_functor.cu applies the mask inside the
 fused softmax). The mask is normalized to [Bm, Rm, S] where Bm encodes how
@@ -492,20 +499,20 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
            mask_mode):
-    out, _ = _flash_fwd(q, k, v, seed, mask, scale, causal, dropout,
-                        block_q, block_k, mask_mode)
-    return out
+    return _flash_fwd(q, k, v, seed, mask, scale, causal, dropout,
+                      block_q, block_k, mask_mode)
 
 
 def _fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
          mask_mode):
     out, lse = _flash_fwd(q, k, v, seed, mask, scale, causal, dropout,
                           block_q, block_k, mask_mode)
-    return out, (q, k, v, seed, mask, out, lse)
+    return (out, lse), (q, k, v, seed, mask, out, lse)
 
 
-def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, res, do):
+def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, res, cts):
     q, k, v, seed, mask, o, lse = res
+    do, _ = cts     # lse is a residual, not a result: its cotangent is unused
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal,
                             dropout, block_q, block_k, mask_mode)
     import numpy as np
@@ -544,13 +551,8 @@ def _normalize_mask(mask, b, nh, s):
     return mask.reshape(b * nh, mq, s), "bh"
 
 
-def flash_attention(q, k, v, scale=None, causal=False,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    dropout=0.0, seed=None, mask=None):
-    """Tiled attention; `dropout` drops post-softmax probs with an in-kernel
-    counter-based mask keyed on `seed` (traced int32 scalar/array ok);
-    `mask` is an additive bias broadcastable to [B, nh, S(or 1), S] applied
-    to the scaled scores inside all three kernels."""
+def _kernel_args(q, k, v, scale, dropout, seed, mask):
+    """(scale, seed, mask, mask_mode) as all three kernels take them."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if dropout > 0.0 and seed is None:
@@ -566,5 +568,42 @@ def flash_attention(q, k, v, scale=None, causal=False,
     if mask is not None:
         b, nh, s, _ = q.shape
         mask, mask_mode = _normalize_mask(mask, b, nh, s)
-    return _flash(q, k, v, seed, mask, scale, causal, float(dropout),
-                  block_q, block_k, mask_mode)
+    return scale, seed, mask, mask_mode
+
+
+def flash_attention(q, k, v, scale=None, causal=False,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                    dropout=0.0, seed=None, mask=None, return_lse=False):
+    """Tiled attention; `dropout` drops post-softmax probs with an in-kernel
+    counter-based mask keyed on `seed` (traced int32 scalar/array ok);
+    `mask` is an additive bias broadcastable to [B, nh, S(or 1), S] applied
+    to the scaled scores inside all three kernels. With `return_lse` the
+    result is (out, lse): lse is the forward kernel's per-row logsumexp,
+    float32 [B*nh, S], which `flash_attention_bwd` takes beside out. The
+    kernels hold it lane-broadcast ([B*nh, S, 128], 128 copies of each
+    value); what is kept from the forward to the backward is one of them.
+    Differentiated by JAX, the forward kernel runs inside the vjp's forward
+    pass."""
+    scale, seed, mask, mask_mode = _kernel_args(q, k, v, scale, dropout,
+                                                seed, mask)
+    out, lse = _flash(q, k, v, seed, mask, scale, causal, float(dropout),
+                      block_q, block_k, mask_mode)
+    return (out, lse[:, :, 0]) if return_lse else out
+
+
+def flash_attention_bwd(q, k, v, out, lse, dout, scale=None, causal=False,
+                        block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                        dropout=0.0, seed=None, mask=None):
+    """(dq, dk, dv) from the residuals a forward launch already wrote: the
+    two backward kernels alone, with the arguments `flash_attention` took.
+    What `jax.vjp(flash_attention)` computes after running the forward
+    kernel for `out` and `lse` itself."""
+    scale, seed, mask, mask_mode = _kernel_args(q, k, v, scale, dropout,
+                                                seed, mask)
+    # widen lse next to the kernels and not before: the barrier makes the
+    # compact values wait for dout, or XLA hoists this cheap broadcast to
+    # the forward and keeps all [B*nh, S, 128] copies alive until here
+    lse, dout = jax.lax.optimization_barrier((lse, dout))
+    lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_LANES,))
+    return _flash_bwd(q, k, v, out, lse, dout, seed, mask, scale, causal,
+                      float(dropout), block_q, block_k, mask_mode)

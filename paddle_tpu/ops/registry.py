@@ -6,7 +6,10 @@ Where the reference selects a (place, dtype, layout, library) kernel at run time
 here each op has ONE lowering — a pure JAX function — and XLA owns code
 generation, fusion and layout. Gradients do not need hand-written grad kernels:
 `append_backward` emits a generic `__vjp__` op whose lowering calls `jax.vjp`
-on the forward lowering (reference grad-op makers: grad_op_desc_maker.h).
+on the forward lowering (reference grad-op makers: grad_op_desc_maker.h). An
+op whose forward already wrote what its backward needs may declare a grad
+rule (`register(..., grad=fn, residual_slots=(...))`): the `__vjp__` op then
+reads those forward outputs and calls the rule instead (docs/custom_ops.md).
 
 Lowering signature:
     lower(ctx, ins: Dict[slot, List[jax.Array]], attrs: dict)
@@ -32,12 +35,17 @@ _DYN_SENTINEL = 8191
 class LowerCtx:
     """Per-execution context handed to lowerings (rng base key, mesh info)."""
 
-    __slots__ = ("rng_key", "mesh", "is_eval_shape")
+    __slots__ = ("rng_key", "mesh", "is_eval_shape", "in_vjp")
 
-    def __init__(self, rng_key=None, mesh=None, is_eval_shape=False):
+    def __init__(self, rng_key=None, mesh=None, is_eval_shape=False,
+                 in_vjp=False):
         self.rng_key = rng_key
         self.mesh = mesh
         self.is_eval_shape = is_eval_shape
+        # True while the generic __vjp__ lowers a forward op AGAIN to
+        # differentiate it: a lowering whose repeat XLA cannot merge with
+        # the first (a Mosaic kernel) counts itself when it sees this
+        self.in_vjp = in_vjp
 
     def op_key(self, attrs):
         """Deterministic per-op PRNG key: fold the op's stable seed attr into the
@@ -49,7 +57,8 @@ class LowerCtx:
 
 class OpDef:
     def __init__(self, name: str, lower: Callable, infer: Optional[Callable] = None,
-                 is_random: bool = False, nondiff_slots=(), stateful_outputs=()):
+                 is_random: bool = False, nondiff_slots=(), stateful_outputs=(),
+                 grad: Optional[Callable] = None, residual_slots=()):
         self.name = name
         self.lower = lower
         self.infer = infer          # optional custom infer(block, op)
@@ -58,6 +67,13 @@ class OpDef:
         # output slots aliasing an input (e.g. optimizer ParamOut) — excluded
         # from autodiff bookkeeping
         self.stateful_outputs = frozenset(stateful_outputs)
+        # grad(ctx, ins, attrs, outs, ogs) -> {input slot: [grad or None]},
+        # or None to decline (the generic jax.vjp route then runs). `outs`
+        # holds the forward op's outputs of `residual_slots`, which
+        # append_backward wires into the __vjp__ op as "FO:<slot>" inputs
+        # (the reference's grad-op makers take Out beside X and Out@GRAD)
+        self.grad = grad
+        self.residual_slots = tuple(residual_slots)
 
 
 _REGISTRY: Dict[str, OpDef] = {}
@@ -91,11 +107,12 @@ def get_sharding_rule(name: str) -> Optional[str]:
 
 
 def register(name: str, *, infer=None, is_random=False, nondiff_slots=(),
-             stateful_outputs=()):
+             stateful_outputs=(), grad=None, residual_slots=()):
     def deco(fn):
         _REGISTRY[name] = OpDef(name, fn, infer=infer, is_random=is_random,
                                 nondiff_slots=nondiff_slots,
-                                stateful_outputs=stateful_outputs)
+                                stateful_outputs=stateful_outputs,
+                                grad=grad, residual_slots=residual_slots)
         return fn
     return deco
 
@@ -205,13 +222,27 @@ def _lower_vjp(ctx, ins, attrs):
     diff = [tuple(e) for e in attrs["diff_entries"]]
 
     fwd_ins = {slot: list(ins[slot]) for slot in in_slot_counts}
+    if fwd.grad is not None:
+        # the op's own rule, fed the forward's outputs; None = it declines
+        # for this shape/backend and the generic route below runs
+        grads = fwd.grad(
+            ctx, fwd_ins, fwd_attrs,
+            {s: ins[f"FO:{s}"] for s in fwd.residual_slots
+             if f"FO:{s}" in ins},
+            {s: ins.get(f"OG:{s}", []) for s in out_slots})
+        if grads is not None:
+            return {f"IG:{s}": [grads[s][i] if (s, i) in diff else None
+                                for i in range(in_slot_counts[s])]
+                    for s in {s for s, _ in diff}}
     primals = [fwd_ins[s][i] for (s, i) in diff]
+    relower_ctx = LowerCtx(ctx.rng_key, ctx.mesh, ctx.is_eval_shape,
+                           in_vjp=True)
 
     def f(*diff_vals):
         cur = {s: list(vs) for s, vs in fwd_ins.items()}
         for (s, i), v in zip(diff, diff_vals):
             cur[s][i] = v
-        outs = fwd.lower(ctx, cur, fwd_attrs)
+        outs = fwd.lower(relower_ctx, cur, fwd_attrs)
         return [v for s in out_slots for v in outs[s]]
 
     out_flat, vjp_fn = jax.vjp(f, *primals)
