@@ -1,0 +1,27 @@
+"""The grouped matmuls' share of their roofline: the least time the chip
+could take for the experts' matmuls of the traced steps at the assignments
+the program counted there (benchmark/counts_mla_moe.py) over the time of
+XLA's `ragged-dot` kernels in the trace (their metadata kernels
+included)."""
+import statistics
+
+from benchmark import counts, counts_mla_moe, scopes
+
+
+def read(ctx):
+    if ctx["kind"] != "train":
+        return None
+    taken = scopes.group_seconds(ctx, (), ("ragged-dot",))
+    traced = [r["routing"]["local_assignments_per_token"]
+              for r in ctx["readings"][1:1 + ctx["traced_readings"]]
+              if r.get("routing")]
+    if not taken or not traced:
+        return None
+    cfg = ctx["cfg"]
+    expert_layers = cfg["layers"] - min(cfg["first_k_dense_replace"],
+                                        cfg["layers"])
+    flops, nbytes = counts_mla_moe.moe_experts_train_flops_bytes(
+        cfg, statistics.mean(traced) * ctx["rows"] * ctx["seq"],
+        expert_layers)
+    least, _ = counts.roofline_seconds(flops, nbytes, ctx["peaks"])
+    return 100.0 * ctx["traced_readings"] * ctx["k"] * least / taken

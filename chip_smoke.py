@@ -55,6 +55,10 @@ FULL = {
     "bert": {},                          # BertConfig() == BERT-base
     "train": {"seq": 128, "batch": 128},
     "long": {"seq": 1024, "batch": 16, "flash_shape": (2, 12, 1024, 64)},
+    # latent attention: q and k 192 wide, v and the output 128, causal, at
+    # the sequence the benchmark's cell runs (the dkdv kernel's raised VMEM
+    # limit); 8 heads so that the dense side's [1, 8, 4096, 4096] fits
+    "latent": {"flash_shape": (1, 8, 4096, 192), "v_width": 128},
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -71,6 +75,7 @@ TINY = {
                  intermediate_size=64, max_position=64),
     "train": {"seq": 16, "batch": 8},
     "long": {"seq": 32, "batch": 8, "flash_shape": (1, 1, 128, 64)},
+    "latent": {"flash_shape": (1, 1, 128, 192), "v_width": 128},
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -276,25 +281,28 @@ def mosaic_calls(hlo_text):
     return counts
 
 
-def flash_vs_dense(shape):
+def flash_vs_dense(shape, v_width=None, causal=False):
     """Op-level check: flash_attention forward and jax.grad against the
-    dense XLA attention (ops/attention._xla_attention) in bf16."""
+    dense XLA attention (ops/attention._xla_attention) in bf16; `v_width`
+    gives v (and the output) another width than q and k."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.attention import _xla_attention
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
     rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-               for _ in range(3))
+    q, k, v = (jnp.asarray(rng.randn(*shape[:3], width), jnp.bfloat16)
+               for width in (shape[3], shape[3], v_width or shape[3]))
     scale = 1.0 / np.sqrt(shape[-1])
+    mask = (jnp.triu(jnp.full((shape[2], shape[2]), -1e9, jnp.float32),
+                     1)[None, None] if causal else None)
 
     def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, scale=scale)
+        out = flash_attention(q, k, v, scale=scale, causal=causal)
         return jnp.sum(out.astype(jnp.float32) ** 2), out
 
     def dense_loss(q, k, v):
-        out = _xla_attention(q, k, v, None, scale, 0.0, None)
+        out = _xla_attention(q, k, v, mask, scale, 0.0, None)
         return jnp.sum(out.astype(jnp.float32) ** 2), out
 
     (_, out_f), g_f = jax.jit(jax.value_and_grad(
@@ -340,6 +348,13 @@ def leg_train_s1024_flash(preset, clock):
     exe.close()
     facts["flash_vs_dense"] = flash_vs_dense(preset["long"]["flash_shape"])
     return facts
+
+
+def leg_attention_two_widths(preset, clock):
+    """The three flash kernels where q and k are wider than v (latent
+    attention), causal, against the dense route."""
+    return flash_vs_dense(preset["latent"]["flash_shape"],
+                          v_width=preset["latent"]["v_width"], causal=True)
 
 
 # ---------------------------------------------------------------------------
@@ -781,6 +796,7 @@ def leg_four_chips(preset, clock):
 
 LEGS = (("train_bert_base_s128", leg_train_s128),
         ("train_bert_base_s1024_flash", leg_train_s1024_flash),
+        ("attention_two_widths", leg_attention_two_widths),
         ("serve_gpt2_small", leg_serve),
         ("kernels", leg_kernels),
         ("four_chips", leg_four_chips))

@@ -17,6 +17,9 @@ white_list = {
     # f32 (preferred_element_type) and the loss returns f32 (ops/fused_ce.py)
     "fused_lm_head_ce",
     "mul", "bmm", "fc",
+    # the experts' grouped matmuls run on bf16 operands; the router does
+    # not (keep_f32_slots below)
+    "routed_moe",
 }
 # per-op input slots excluded from the white-list cast: tiny O(V)/O(H)
 # operands whose quantization buys no MXU time but drifts parity with the
@@ -27,11 +30,15 @@ keep_f32_slots = {
     # the flash kernels' logsumexp residual is float32 whatever list the op
     # is on (its grad op reads it as FO:Lse)
     "fused_attention": {"Lse"},
+    # the router scores tokens in float32 from float32 activations: a
+    # bf16 rounding of either moves which experts a near-tie selects
+    "routed_moe": {"X", "GateW", "SelectBias"},
 }
 
 # ops forced to float32 (reference black list: reductions/normalizations)
 black_list = {
     "softmax", "softmax_with_cross_entropy", "cross_entropy", "layer_norm",
+    "rms_norm",
     "batch_norm", "mean", "reduce_mean", "reduce_sum", "sum", "exp", "log",
     "square", "p_norm", "sigmoid_cross_entropy_with_logits",
 }

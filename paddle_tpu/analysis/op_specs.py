@@ -43,6 +43,7 @@ from ..ops import registry
 # program transforms, never consumed by a specific lowering.
 COMMON_ATTRS = frozenset({
     "op_role", "__rng_seed__", "pipeline_stage", "is_test", "auto_selected",
+    "name_scope",
 })
 
 
@@ -296,6 +297,27 @@ SPECS: Dict[str, OpSpec] = {
         outputs={"Out": ONE, "AuxLoss": OPT, "GateIdx": OPT},
         attr_types={"capacity_factor": _NUM, "top_k": int},
         sharding="moe", cross_batch=True),
+    # the expert layer of sparse decoder LMs (ops/moe.py routed_moe): no
+    # capacity, so no token's result depends on another's
+    "routed_moe": OpSpec(
+        inputs={"X": ONE, "GateW": ONE, "SelectBias": OPT,
+                "ExpertGate": ONE, "ExpertUp": ONE, "ExpertDown": ONE},
+        outputs={"Out": ONE, "TopIdx": OPT, "ExpertLoad": OPT},
+        required_attrs=("top_k",),
+        attr_types={"top_k": int, "routed_scaling": _NUM,
+                    "norm_topk": bool, "experts_total": int,
+                    "expert_offset": int},
+        sharding="moe"),
+    "rms_norm": OpSpec(
+        inputs={"X": ONE, "Scale": OPT}, outputs={"Y": ONE},
+        attr_types={"epsilon": _NUM}, sharding="follow_x"),
+    "rotary_embedding": OpSpec(
+        inputs={"X": ONE}, outputs={"Out": ONE},
+        attr_types={"theta": _NUM, "rotary_dim": int},
+        sharding="follow_x"),
+    "swiglu": OpSpec(
+        inputs={"Gate": ONE, "Up": ONE}, outputs={"Out": ONE},
+        sharding="elementwise"),
     # --- serving tier: paged KV-cache decode ops (ops/paged_ops.py) ------
     # sharding "replicated": serving parallelism is whole-model replicas
     # behind the round-robin frontend (serving/frontend.py) — the pools

@@ -533,7 +533,7 @@ def _rule_moe(ctx, i, op):
     xs = ctx.spec_of((op.inputs.get("X") or [EMPTY])[0])
     for n in op.outputs.get("Out", ()):
         ctx.set_spec(n, xs)
-    for slot in ("AuxLoss", "GateIdx"):
+    for slot in ("AuxLoss", "GateIdx", "TopIdx", "ExpertLoad"):
         for n in op.outputs.get(slot, ()):
             ctx.set_spec(n, ())
 

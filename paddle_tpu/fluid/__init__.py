@@ -3,7 +3,7 @@
 Mirrors python/paddle/fluid/__init__.py's public surface for the covered
 subset so reference-style user code runs unchanged.
 """
-from ..framework.program import (Program, program_guard, device_guard,  # noqa
+from ..framework.program import (Program, program_guard, device_guard, name_scope,  # noqa
                                  default_main_program,
                                  default_startup_program, in_dygraph_mode,
                                  Variable, Parameter)

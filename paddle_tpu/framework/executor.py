@@ -408,7 +408,13 @@ def _run_block_inner(block, fetch_names, written_state, env, ctx):
             ins[slot] = [None if n == "@EMPTY@" else env[n] for n in names]
         if amp_dtype is not None:
             ins = _amp_cast(op, ins, amp_dtype)
-        outs = opdef.lower(ctx, ins, op.attrs)
+        scope = op.attrs.get("name_scope") or (
+            op.type == "__vjp__"
+            and op.attrs["fwd_attrs"].get("name_scope"))
+        # program.name_scope: a group's device work, named
+        with (jax.named_scope(scope) if scope
+              else contextlib.nullcontext()):
+            outs = opdef.lower(ctx, ins, op.attrs)
         for slot, names in op.outputs.items():
             if slot not in outs:
                 continue

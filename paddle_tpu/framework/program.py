@@ -247,6 +247,9 @@ class Block:
             # set by fluid.device_guard (reference framework.py device_guard);
             # consumed by the pipeline transform / stage sharding rules
             op.attrs.setdefault("pipeline_stage", stage)
+        scope = getattr(self.program, "_current_name_scope", None)
+        if scope is not None:
+            op.attrs.setdefault("name_scope", scope)
         self.ops.append(op)
         from ..ops import registry
         registry.infer_op(self, op)  # static shape/dtype inference at build time
@@ -473,6 +476,22 @@ def device_guard(device=None):
         yield
     finally:
         program._current_device_stage = old
+
+
+@contextlib.contextmanager
+def name_scope(name: str):
+    """Ops appended inside carry `name` as their `name_scope` attr: the
+    executor lowers them, and their grad ops, under `jax.named_scope(name)`,
+    so the device work of a group of ops can be told apart in the compiled
+    step's `op_name` metadata and in any `jax.profiler` capture
+    (docs/observability.md)."""
+    program = default_main_program()
+    old = getattr(program, "_current_name_scope", None)
+    program._current_name_scope = name
+    try:
+        yield
+    finally:
+        program._current_name_scope = old
 
 
 @contextlib.contextmanager

@@ -27,7 +27,8 @@ __all__ = [
     "equal", "not_equal", "less_than", "less_equal", "greater_than",
     "greater_equal", "logical_and", "logical_or", "logical_not", "logical_xor",
     "where", "cond_take", "unique", "cumsum", "prelu", "brelu",
-    "fused_attention", "switch_moe",
+    "fused_attention", "switch_moe", "routed_moe", "rms_norm",
+    "rotary_embedding", "swiglu",
 ]
 
 
@@ -871,6 +872,78 @@ def switch_moe(input, num_experts, d_ff, capacity_factor=1.25, name=None,
                      attrs={"capacity_factor": float(capacity_factor),
                             "top_k": int(top_k)})
     return out, aux
+
+
+def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
+               select_bias=None, routed_scaling=1.0, norm_topk=True,
+               experts_total=None, expert_offset=0):
+    """The routed part of a sparse decoder LM's expert layer (DeepSeek-V3
+    family; ops/moe.py routed_moe): sigmoid scores in float32 over ALL
+    `experts_total` experts (`gate_w` [d, experts_total]), the top_k of
+    scores + `select_bias` (a buffer no gradient reaches), weights = the
+    scores at those indices, normalised to sum 1 (`norm_topk`) and times
+    `routed_scaling`. No capacity: no token is dropped. The caller passes
+    the gated experts it HOLDS, `expert_gate/up` [E_held, d, f] and
+    `expert_down` [E_held, f, d], experts `expert_offset` .. +E_held of the
+    whole; the result is their part of sum_k w_k E_{i_k}(x), so the parts
+    of all shares add up to the layer. A shared expert is ordinary
+    `swiglu` ops beside this one. Returns (out, top_idx [N, top_k],
+    expert_load [E_held]: assignments that fell on each held expert)."""
+    helper = LayerHelper("routed_moe")
+    out = helper.create_variable_for_type_inference(input.dtype)
+    idx = helper.create_variable_for_type_inference("int64")
+    load = helper.create_variable_for_type_inference("int32")
+    idx.stop_gradient = True
+    load.stop_gradient = True
+    inputs = {"X": [input], "GateW": [gate_w], "ExpertGate": [expert_gate],
+              "ExpertUp": [expert_up], "ExpertDown": [expert_down]}
+    if select_bias is not None:
+        inputs["SelectBias"] = [select_bias]
+    helper.append_op(
+        "routed_moe", inputs=inputs,
+        outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load]},
+        attrs={"top_k": int(top_k), "routed_scaling": float(routed_scaling),
+               "norm_topk": bool(norm_topk),
+               "experts_total": int(experts_total
+                                    if experts_total is not None
+                                    else gate_w.shape[1]),
+               "expert_offset": int(expert_offset)})
+    return out, idx, load
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """x / sqrt(mean(x^2, last axis) + epsilon) * scale; the scale is a
+    parameter of the last axis' size, initialised to 1."""
+    helper = LayerHelper("rms_norm")
+    scale = helper.create_parameter(param_attr, [int(input.shape[-1])],
+                                    dtype="float32",
+                                    default_initializer=init_mod.Constant(1.0))
+    y = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rms_norm", inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [y]}, attrs={"epsilon": float(epsilon)})
+    return y
+
+
+def rotary_embedding(x, theta=10000.0, rotary_dim=None):
+    """Rotary positions on x [..., S, D]: the last `rotary_dim` features
+    (default all) turn by position along axis -2, over interleaved pairs
+    (2i, 2i+1); the rest passes through."""
+    helper = LayerHelper("rotary_embedding")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"theta": float(theta),
+                            "rotary_dim": int(rotary_dim or x.shape[-1])})
+    return out
+
+
+def swiglu(gate, up):
+    """silu(gate) * up."""
+    helper = LayerHelper("swiglu")
+    out = helper.create_variable_for_type_inference(gate.dtype)
+    helper.append_op("swiglu", inputs={"Gate": [gate], "Up": [up]},
+                     outputs={"Out": [out]})
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,8 @@
 Plus a GPT-style causal-decoder LM (tied embeddings, pre-LN, causal flash
 attention, TP rules) -> gpt.py, and SE-ResNeXt 50/101/152 (the reference's
 canonical dist-test model, grouped convs + squeeze-excitation)
--> se_resnext.py
+-> se_resnext.py, and a DeepSeek-V3-family sparse causal LM (latent
+attention, sigmoid-routed experts without drops, shared experts, one
+expert-parallel rank's share) -> deepseek_v3.py
 """
-from . import lenet, resnet, bert, wide_deep, gpt, se_resnext
+from . import lenet, resnet, bert, wide_deep, gpt, se_resnext, deepseek_v3

@@ -61,7 +61,8 @@ def _mask_flashable(mask, q):
 
 def _use_pallas(q):
     """Static gate for the flash kernels: a TPU backend and a shape they
-    tile. There is no runtime probe and no fallback behind this gate — a
+    tile (q's; `_route` holds v's width, which may differ, to the kernels'
+    too). There is no runtime probe and no fallback behind this gate — a
     kernel that Mosaic refuses, or that fails, raises."""
     import os
     if jax.default_backend() != "tpu":
@@ -72,10 +73,10 @@ def _use_pallas(q):
     # 175 ms/step vs flash 230); flash pays off once the S^2 HBM traffic
     # dominates. Crossover set conservatively at 512, env-overridable.
     min_seq = int(os.environ.get("PADDLE_TPU_FLASH_MIN_SEQ", "512"))
-    return s >= min_seq and s % 128 == 0 and hd in (64, 128, 256)
+    return s >= min_seq and s % 128 == 0 and hd in (64, 128, 192, 256)
 
 
-def _route(ctx, q, mask, attrs):
+def _route(ctx, q, v, mask, attrs):
     """("sp", fn) | ("flash", None) | ("dense", None): which lowering this
     op takes, from static facts alone (attrs, mesh, backend, shapes), so
     the forward and the grad rule cannot disagree. Build-time shape
@@ -91,7 +92,10 @@ def _route(ctx, q, mask, attrs):
             fn = (ulysses_attention
                   if attrs.get("sp_mode") == "ulysses" else ring_attention)
             return "sp", functools.partial(fn, mesh=mesh)
-    if _use_pallas(q) and (mask is None or _mask_flashable(mask, q)):
+    # q and k share one width, v and the output another (latent attention:
+    # 192 and 128)
+    if _use_pallas(q) and v.shape[-1] in (64, 128, 256) \
+            and (mask is None or _mask_flashable(mask, q)):
         return "flash", None
     return "dense", None
 
@@ -128,7 +132,7 @@ def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
     generic `__vjp__` differentiates the forward lowering as before."""
     q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
     dout = (ogs.get("Out") or [None])[0]
-    if _route(ctx, q, mask, attrs)[0] != "flash" or dout is None \
+    if _route(ctx, q, v, mask, attrs)[0] != "flash" or dout is None \
             or not outs.get("Out") or not outs.get("Lse"):
         return None
     from .pallas.flash_attention import flash_attention_bwd
@@ -153,7 +157,7 @@ def _fused_attention(ctx, ins, attrs):
     q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
     key = ctx.op_key(attrs) if dropout else None
     b, nh, s, _ = q.shape
-    route, sp_fn = _route(ctx, q, mask, attrs)
+    route, sp_fn = _route(ctx, q, v, mask, attrs)
     if route == "sp":
         sp_seed = _derive_seed(key) if dropout else None
         # key-padding masks + in-body counter dropout ride the ring

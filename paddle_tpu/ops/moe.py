@@ -184,3 +184,124 @@ def _switch_moe(ctx, ins, attrs):
     return {"Out": [out.reshape(orig_shape)],
             "AuxLoss": [aux.astype(x.dtype)],
             "GateIdx": [expert.astype(INT64_DEVICE_DTYPE)]}
+
+
+# ---------------------------------------------------------------------------
+# routed_moe: the expert layer as sparse decoder LMs deploy it (DeepSeek-V3
+# family): sigmoid scores, a selection bias no gradient reaches, top-k of
+# ALL experts, normalised and scaled weights, no capacity and no drops,
+# gated experts, and the share of one expert-parallel rank: told which
+# experts it holds, it routes over all of them and computes its own part.
+# ---------------------------------------------------------------------------
+
+@jax.custom_vjp
+def _take_rows(x, src, back):
+    """x[src] for `src` a permutation, or the rows of x's k-fold repeat
+    (k copies of x stacked) permuted, with `back` the inverse permutation:
+    the transpose is again a gather (`back`), never a scatter-add."""
+    return x[src]
+
+
+def _take_rows_fwd(x, src, back):
+    return x[src], (back, x.shape[0])
+
+
+def _take_rows_bwd(res, g):
+    back, n = res
+    rows = g[back]
+    if rows.shape[0] != n:          # x was read k times: sum its k readers
+        rows = rows.reshape(rows.shape[0] // n, n, -1).sum(axis=0)
+    return rows, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _held_experts(xt, w_slot, order, inv, valid, sizes, eg, eu, ed):
+    """sum_k w_k E_{i_k}(x) over the slots whose expert is held here.
+
+    xt [N, d]; w_slot [k, N] (0 where the slot's expert is elsewhere);
+    order / inv: the permutation that sorts the k*N slots (slot-major:
+    slot j of token i is row j*N + i, so [k*N, d] splits into [k, N, d]
+    without a relayout) by held expert (foreign slots last) and its
+    inverse; valid [k*N]: sorted rows that belong to a held expert; sizes
+    [E_held], summing to k*N: the last group also takes the foreign slots'
+    rows, as zeros. So the buffers hold every assignment there can be and
+    the grouped matmuls run over all of them: what a step costs is fixed by
+    its shapes and not by the routing (a rank's k*N rows are also what its
+    experts see in the deployment, where the exchange fills them), and a
+    zero row yields a zero row, so nothing past the real groups needs a
+    mask but the gathered input."""
+    n, d = xt.shape
+    k = w_slot.shape[0]
+    cdt = eg.dtype
+    with jax.named_scope("moe.dispatch"):
+        xs = _take_rows(xt.astype(cdt), order % n, inv)       # [k*N, d]
+        xs = jnp.where(valid[:, None], xs, 0)
+    with jax.named_scope("moe.experts"):
+        h = jax.lax.ragged_dot(xs, eg, sizes, preferred_element_type=cdt)
+        u = jax.lax.ragged_dot(xs, eu, sizes, preferred_element_type=cdt)
+        a = (jax.nn.silu(h.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(cdt)
+        y = jax.lax.ragged_dot(a, ed, sizes, preferred_element_type=cdt)
+    with jax.named_scope("moe.combine"):
+        y = _take_rows(y, inv, order).reshape(k, n, d)
+        return jnp.einsum("kn,knd->nd", w_slot, y.astype(jnp.float32))
+
+
+@register("routed_moe", nondiff_slots=("SelectBias",))
+def _routed_moe(ctx, ins, attrs):
+    x = ins["X"][0]                         # [..., d]
+    wg = ins["GateW"][0]                    # [d, E_total]
+    bias = ins["SelectBias"][0] if ins.get("SelectBias") else None
+    eg, eu, ed = (ins[s][0] for s in ("ExpertGate", "ExpertUp",
+                                      "ExpertDown"))  # [E_held, ...]
+    top_k = int(attrs["top_k"])
+    e_total = int(attrs.get("experts_total", wg.shape[1]))
+    off = int(attrs.get("expert_offset", 0))
+    e_held = eg.shape[0]
+    if wg.shape[1] != e_total or off < 0 or off + e_held > e_total:
+        raise ValueError(
+            f"routed_moe: GateW routes over {wg.shape[1]} experts, "
+            f"experts_total={e_total}, held {off}..{off + e_held}")
+    d = x.shape[-1]
+    xt = x.reshape(-1, d)
+    n = xt.shape[0]
+
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            xt.astype(jnp.float32), wg.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))            # [N, E] f32
+        sel = jax.lax.stop_gradient(scores)
+        if bias is not None:
+            sel = sel + bias.astype(jnp.float32)
+        _, idx = jax.lax.top_k(sel, top_k)                   # [N, k]
+        w = jnp.take_along_axis(scores, idx, axis=1)
+        if attrs.get("norm_topk", True):
+            w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+        w = w * float(attrs.get("routed_scaling", 1.0))
+        local = (idx >= off) & (idx < off + e_held)
+        eid = jnp.where(local, idx - off, e_held).T.reshape(-1)  # [k*N]
+        sizes = jnp.sum(eid[:, None] == jnp.arange(e_held)[None, :],
+                        axis=0, dtype=jnp.int32)             # [E_held]
+        order = jnp.argsort(eid, stable=True).astype(jnp.int32)
+        inv = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
+            jnp.arange(n * top_k, dtype=jnp.int32), unique_indices=True)
+        held = jnp.sum(sizes)
+        valid = jnp.arange(n * top_k) < held
+        # the grouped matmuls cover the whole buffer: the foreign slots'
+        # rows, zeros, ride in the last group
+        padded = sizes.at[e_held - 1].add(n * top_k - held)
+        w_slot = jnp.where(local, w, 0.0).T                  # [k, N]
+
+    # keep nothing of the k*N-row buffers from forward to backward: they
+    # are sized for every assignment there can be, and recomputing two
+    # grouped matmuls is cheaper than holding them for every layer
+    out = jax.checkpoint(_held_experts)(xt, w_slot, order, inv, valid,
+                                        padded, eg, eu, ed)
+    if not ctx.is_eval_shape and not ctx.in_vjp:
+        from ..observability import metrics
+        metrics.inc("moe.layers_lowered")
+    return {"Out": [out.astype(eg.dtype).reshape(x.shape)],
+            "TopIdx": [idx.astype(INT64_DEVICE_DTYPE)],
+            "ExpertLoad": [sizes]}

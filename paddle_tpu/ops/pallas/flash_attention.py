@@ -116,6 +116,32 @@ def _pick_block(s: int, preferred: int) -> int:
     return b
 
 
+def _lanes(d: int) -> int:
+    """Lanes a last dimension of `d` occupies in VMEM."""
+    return -(-d // _LANES) * _LANES
+
+
+def _mask_bytes(mask) -> int:
+    if mask is None:
+        return 0
+    return mask.shape[1] * mask.shape[2] * mask.dtype.itemsize
+
+
+def _compiler_params(resident_bytes: int):
+    """Both grid axes parallel. The operands resident per head (K and V
+    of a q-grid kernel; Q, dO, O and the lane-broadcast lse of the k-grid
+    one) are double-buffered; where they outgrow Mosaic's default 16 MiB of
+    scoped VMEM (S = 4096 at 192/128 wide: 16.3 MiB in the dkdv kernel)
+    the limit is raised to what they need, inside the chip's 128 MiB.
+    Shapes under the default compile exactly as before."""
+    need = 2 * resident_bytes + (6 << 20)
+    extra = {}
+    if need > (16 << 20):
+        extra["vmem_limit_bytes"] = min(need, 100 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"), **extra)
+
+
 # mask_mode: how the (batch*head) grid index maps to the mask's leading dim.
 #   "1"  -> mask shared by every head            (Bm == 1)
 #   "b"  -> one mask per batch row, heads share  (Bm == B,    idx = h // nh)
@@ -142,7 +168,9 @@ def _mask_block(mask_ref, q_start, block_q, k_start, block_k):
 
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
                       dropout, block_k, seq_len, has_mask):
-    # q_ref: [block_q, hd]; k_ref/v_ref: [S, hd]; o_ref: [block_q, hd]
+    # q_ref: [block_q, hd]; k_ref: [S, hd]; v_ref: [S, hd_v];
+    # o_ref: [block_q, hd_v]; hd_v may differ from hd (latent attention:
+    # q and k 192 wide, v and the output 128)
     # lse_ref: [block_q, 128] (row value broadcast along lanes)
     # mask_ref (if present): [1 or block_q, S] additive bias
     if has_mask:
@@ -151,7 +179,6 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
         o_ref, lse_ref = rest
         mask_ref = None
     block_q = q_ref.shape[0]
-    hd = q_ref.shape[1]
     head = pl.program_id(0)
     q_idx = pl.program_id(1)
     # MXU operands stay in the input dtype (bf16 under AMP — v5e runs bf16
@@ -161,7 +188,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
 
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, hd), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[1]), jnp.float32)
 
     num_k_blocks = seq_len // block_k
 
@@ -239,11 +266,12 @@ def _mask_spec_kgrid(mask, bk, mask_mode, nh):
 def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
                mask_mode):
     b, nh, s, hd = q.shape
+    hdv = v.shape[-1]
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     q3 = q.reshape(b * nh, s, hd)
     k3 = k.reshape(b * nh, s, hd)
-    v3 = v.reshape(b * nh, s, hd)
+    v3 = v.reshape(b * nh, s, hdv)
     has_mask = mask is not None
     kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                                dropout=dropout, block_k=bk, seq_len=s,
@@ -252,7 +280,7 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
         pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
-        pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
+        pl.BlockSpec((None, s, hdv), lambda h, i: (h, 0, 0)),
     ]
     operands = [seed, q3, k3, v3]
     if has_mask:
@@ -263,25 +291,27 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
         grid=(b * nh, s // bq),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((None, bq, hdv), lambda h, i: (h, i, 0)),
             pl.BlockSpec((None, bq, _LANES), lambda h, i: (h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
+            jax.ShapeDtypeStruct((b * nh, s, hdv), q.dtype),
             jax.ShapeDtypeStruct((b * nh, s, _LANES), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=_compiler_params(
+            s * (_lanes(hd) + _lanes(hdv)) * q.dtype.itemsize
+            + _mask_bytes(mask)),
         interpret=interpret_mode(),
         name="flash_attention_fwd",
     )(*operands)
-    return out.reshape(b, nh, s, hd), lse
+    return out.reshape(b, nh, s, hdv), lse
 
 
 def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                          lse_ref, *rest, scale, causal, dropout, block_k,
                          seq_len, has_mask):
-    # q/do/o: [block_q, hd]; k/v: [S, hd]; lse: [block_q, 128]
+    # q: [block_q, hd]; do/o: [block_q, hd_v]; k: [S, hd]; v: [S, hd_v];
+    # lse: [block_q, 128]
     if has_mask:
         mask_ref, dq_ref = rest
     else:
@@ -345,7 +375,8 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
 def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                            lse_ref, *rest, scale, causal, dropout, block_q,
                            seq_len, has_mask):
-    # k/v: [block_k, hd]; q/do/o: [S, hd]; lse: [S, 128]
+    # k: [block_k, hd]; v: [block_k, hd_v]; q: [S, hd]; do/o: [S, hd_v];
+    # lse: [S, 128]
     # mask_ref (if present): [1 or S, block_k] — this k block's columns
     if has_mask:
         mask_ref, dk_ref, dv_ref = rest
@@ -413,7 +444,7 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     dk, dv = jax.lax.fori_loop(
         start, num_q_blocks, body,
         (jnp.zeros((block_k, hd), jnp.float32),
-         jnp.zeros((block_k, hd), jnp.float32)))
+         jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)))
     dk_ref[:] = dk.astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
@@ -421,13 +452,14 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
 def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
                block_q, block_k, mask_mode):
     b, nh, s, hd = q.shape
+    hdv = v.shape[-1]
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     q3 = q.reshape(b * nh, s, hd)
     k3 = k.reshape(b * nh, s, hd)
-    v3 = v.reshape(b * nh, s, hd)
-    o3 = o.reshape(b * nh, s, hd)
-    do3 = do.reshape(b * nh, s, hd)
+    v3 = v.reshape(b * nh, s, hdv)
+    o3 = o.reshape(b * nh, s, hdv)
+    do3 = do.reshape(b * nh, s, hdv)
     has_mask = mask is not None
 
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, scale=scale,
@@ -437,9 +469,9 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
         pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
-        pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
-        pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
-        pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
+        pl.BlockSpec((None, s, hdv), lambda h, i: (h, 0, 0)),
+        pl.BlockSpec((None, bq, hdv), lambda h, i: (h, i, 0)),
+        pl.BlockSpec((None, bq, hdv), lambda h, i: (h, i, 0)),
         pl.BlockSpec((None, bq, _LANES), lambda h, i: (h, i, 0)),
     ]
     dq_operands = [seed, q3, k3, v3, do3, o3, lse]
@@ -452,8 +484,9 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=_compiler_params(
+            s * (_lanes(hd) + _lanes(hdv)) * q.dtype.itemsize
+            + _mask_bytes(mask)),
         interpret=interpret_mode(),
         name="flash_attention_bwd_dq",
     )(*dq_operands)
@@ -465,9 +498,9 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
         pl.BlockSpec((None, bk, hd), lambda h, i: (h, i, 0)),
-        pl.BlockSpec((None, bk, hd), lambda h, i: (h, i, 0)),
-        pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
-        pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
+        pl.BlockSpec((None, bk, hdv), lambda h, i: (h, i, 0)),
+        pl.BlockSpec((None, s, hdv), lambda h, i: (h, 0, 0)),
+        pl.BlockSpec((None, s, hdv), lambda h, i: (h, 0, 0)),
         pl.BlockSpec((None, s, _LANES), lambda h, i: (h, 0, 0)),
     ]
     dkdv_operands = [seed, q3, k3, v3, do3, o3, lse]
@@ -480,20 +513,21 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         in_specs=dkdv_specs,
         out_specs=[
             pl.BlockSpec((None, bk, hd), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((None, bk, hd), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((None, bk, hdv), lambda h, i: (h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * nh, s, hd), k.dtype),
-            jax.ShapeDtypeStruct((b * nh, s, hd), v.dtype),
+            jax.ShapeDtypeStruct((b * nh, s, hdv), v.dtype),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=_compiler_params(
+            s * (_lanes(hd) + 2 * _lanes(hdv)) * q.dtype.itemsize
+            + s * _LANES * 4 + _mask_bytes(mask)),
         interpret=interpret_mode(),
         name="flash_attention_bwd_dkdv",
     )(*dkdv_operands)
 
     return (dq.reshape(b, nh, s, hd), dk.reshape(b, nh, s, hd),
-            dv.reshape(b, nh, s, hd))
+            dv.reshape(b, nh, s, hdv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))

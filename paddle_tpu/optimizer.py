@@ -143,6 +143,9 @@ class Optimizer:
             op = self._append_optimize_op(block, pg)
             if op is not None:
                 op.attrs["op_role"] = OpRole.Optimize
+                # the update's device work under one name in the compiled
+                # step and in a profiler capture (program.name_scope)
+                op.attrs.setdefault("name_scope", f"optimizer.{self.type}")
         for op in self._finalize_optimize_ops(block):
             op.attrs["op_role"] = OpRole.Optimize
         return []

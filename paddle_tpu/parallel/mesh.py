@@ -147,10 +147,12 @@ REPLICATED = ShardingRules()
 
 
 def moe_sharding_rules(extra=()) -> "ShardingRules":
-    """Expert-parallel rules: shard the leading [E] dim of switch_moe expert
-    weights over the mesh's ep axis (ops/moe.py) — GSPMD then lowers the
+    """Expert-parallel rules: shard the leading [E] dim of switch_moe and
+    routed_moe expert weights over the mesh's ep axis (ops/moe.py) — GSPMD then lowers the
     dispatch einsum to an all-to-all over ICI."""
-    rules = [(r"_expert_(w|b)[12]_?\d*$", P("ep"))]
+    rules = [(r"_expert_(w|b)[12]_?\d*$", P("ep")),
+             # routed_moe's gated experts (models/deepseek_v3.py)
+             (r"_experts_(gate|up|down)_w$", P("ep"))]
     return ShardingRules(list(extra) + rules)
 
 

@@ -1,0 +1,116 @@
+"""Flash attention where q and k are wider than v (latent attention: 192
+and 128): the three kernels under the Pallas interpreter against the dense
+route, forward and through the op's grad rule, causal; and the same
+kernels compiled for a described v5e at the size the benchmark's cell runs
+them (S = 4096, where the dkdv kernel needs more than Mosaic's default
+16 MiB of scoped VMEM).
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid import layers
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops import attention
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.testing import reset_programs
+
+B, NH, DQK, DV = 1, 2, 192, 128
+
+
+def _grads(s, dtype, flash, monkeypatch):
+    """Out and dq, dk, dv of sum(Out * W) through a one-op program."""
+    if flash:
+        monkeypatch.setattr(
+            attention, "_use_pallas",
+            lambda q: q.shape[2] % 128 == 0 and q.shape[3] == DQK)
+    else:
+        monkeypatch.setattr(attention, "_use_pallas", lambda q: False)
+    reset_programs(0)
+    rng = np.random.RandomState(11)
+    widths = {"q": DQK, "k": DQK, "v": DV, "w": DV}
+    feed = {n: rng.randn(B, NH, s, d).astype(np.float32)
+            for n, d in widths.items()}
+    qkv = []
+    for n in ("q", "k", "v"):
+        var = layers.data(name=n, shape=[NH, s, widths[n]], dtype="float32")
+        var.stop_gradient = False
+        qkv.append(var)
+    w = layers.data(name="w", shape=[NH, s, DV], dtype="float32")
+    out = layers.fused_attention(*qkv, causal=True, scale=DQK ** -0.5)
+    assert tuple(out.shape)[1:] == (NH, s, DV)
+    loss = layers.reduce_sum(layers.elementwise_mul(out, w))
+    grads = fluid.gradients(loss, qkv)
+    prog = fluid.default_main_program()
+    if dtype == "bfloat16":
+        import importlib
+        auto_cast = importlib.import_module("paddle_tpu.amp.auto_cast")
+        monkeypatch.setattr(auto_cast, "white_list",
+                            auto_cast.white_list | {"fused_attention"})
+        prog._amp = True
+    before = metrics.get("attention.flash_bwd_residual")
+    vals = fluid.Executor().run(prog, feed=feed, fetch_list=[out] + grads)
+    rose = metrics.get("attention.flash_bwd_residual") - before
+    return [np.asarray(v, np.float32) for v in vals], rose
+
+
+@pytest.mark.parametrize("s", [128, 384])
+@pytest.mark.parametrize("dtype, tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+def test_two_widths_flash_matches_dense(s, dtype, tol, monkeypatch):
+    """tol: float32 differs by the order of the online softmax's sums;
+    bf16 by the rounding of p and ds to 8 bits before their matmuls."""
+    flash, rose = _grads(s, dtype, True, monkeypatch)
+    dense, none = _grads(s, dtype, False, monkeypatch)
+    assert rose == 1 and none == 0      # the grad rule ran, on residuals
+    assert flash[0].shape == (B, NH, s, DV)
+    assert flash[1].shape == flash[2].shape == (B, NH, s, DQK)
+    assert flash[3].shape == (B, NH, s, DV)
+    for got, want, name in zip(flash, dense, ("out", "dq", "dk", "dv")):
+        err = np.abs(got - want).max() / np.abs(want).max()
+        assert err < tol, (name, err)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("dqk, dv, s, rows", [(192, 128, 4096, 2),
+                                              (64, 64, 512, 32)])
+def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
+                                                      monkeypatch):
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    heads = 32 if dqk == 192 else 12
+    dt = jnp.bfloat16 if dqk == 192 else jnp.float32
+
+    def sd(width, dtype=dt, shape=None):
+        return jax.ShapeDtypeStruct(shape or (rows, heads, s, width), dtype,
+                                    sharding=v5e)
+
+    def step(q, k, v, do):
+        o, lse = fa.flash_attention(q, k, v, causal=dqk == 192,
+                                    return_lse=True)
+        return fa.flash_attention_bwd(q, k, v, o, lse, do,
+                                      causal=dqk == 192)
+
+    try:
+        text = jax.jit(step).trace(sd(dqk), sd(dqk), sd(dv), sd(dv)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkdv"):
+        assert text.count(f'{kernel}"') or text.count(kernel), kernel
+    assert text.count("tpu_custom_call") >= 3
