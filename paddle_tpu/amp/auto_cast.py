@@ -20,6 +20,10 @@ white_list = {
     # the experts' grouped matmuls run on bf16 operands; the router does
     # not (keep_f32_slots below)
     "routed_moe",
+    # q, k, v and the cotangent of Out reach the attention matmuls (dense or
+    # the flash kernels) in the compute dtype, like every other matmul's
+    # operands; every dot accumulates f32, softmax and logsumexp stay f32
+    "fused_attention",
 }
 # per-op input slots excluded from the white-list cast: tiny O(V)/O(H)
 # operands whose quantization buys no MXU time but drifts parity with the
@@ -27,9 +31,10 @@ white_list = {
 # ops)
 keep_f32_slots = {
     "fused_lm_head_ce": {"Bias"},
-    # the flash kernels' logsumexp residual is float32 whatever list the op
-    # is on (its grad op reads it as FO:Lse)
-    "fused_attention": {"Lse"},
+    # the flash kernels' logsumexp residual is float32 (the grad op reads it
+    # as FO:Lse), and so is the additive mask: O(B*S) for key padding, added
+    # to the f32 scores, widened per block by the kernels anyway
+    "fused_attention": {"Lse", "Mask"},
     # the router scores tokens in float32 from float32 activations: a
     # bf16 rounding of either moves which experts a near-tie selects
     "routed_moe": {"X", "GateW", "SelectBias"},
@@ -52,8 +57,12 @@ def maybe_autocast_inputs(op_type, in_map, low_dtype):
         target = jnp.float32
     else:
         return in_map
+    skip = keep_f32_slots.get(op_type, ())
     out = {}
     for slot, ts in in_map.items():
+        if slot in skip:
+            out[slot] = ts
+            continue
         cast_ts = []
         for t in ts:
             v = t.value

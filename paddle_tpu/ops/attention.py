@@ -11,6 +11,15 @@ forward and once more, from the same facts, for the op's grad rule: on the
 flash route the backward takes the forward launch's `Out` and `Lse` and runs
 the two backward kernels; on every other route the rule declines and the
 generic `__vjp__` differentiates the lowering (docs/custom_ops.md).
+
+Dtypes. The matmul operands `Q`, `K`, `V` (and, in the backward, the
+cotangent of `Out`) are cast at the op's boundary by the AMP rule every
+matmul op follows (`amp/auto_cast.py`: the op is white-listed), so under
+AMP the flash kernels, the head relayouts around them and the residuals
+`Q`, `K`, `V`, `Out` are in the compute dtype (bf16); without AMP they are
+what the program built. No lowering here casts them again: `Out` has the
+operands' dtype, every dot accumulates float32, and softmax, logsumexp,
+the dropout hash, `Mask` and `Lse` are float32 on every route.
 """
 from __future__ import annotations
 
@@ -21,6 +30,9 @@ import jax
 import jax.numpy as jnp
 
 from .registry import register
+
+# counter suffix by the dtype q reached the flash forward in
+_OPERAND_TAG = {"bfloat16": "bf16", "float32": "f32"}
 
 
 def _xla_attention(q, k, v, mask, scale, dropout, key):
@@ -175,10 +187,12 @@ def _fused_attention(ctx, ins, attrs):
                                        return_lse=True)
         except Exception as e:
             raise _flash_failed(e, q, mask, causal, dropout) from e
+        from ..observability import metrics
+        metrics.inc("attention.flash_operands_"
+                    + _OPERAND_TAG.get(q.dtype.name, q.dtype.name))
         if ctx.in_vjp:
             # the generic __vjp__ (a whole segment under recompute or layer
             # scan) lowers this forward a second time to differentiate it
-            from ..observability import metrics
             metrics.inc("attention.flash_bwd_recomputed")
         return {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}
     if causal:
@@ -186,7 +200,6 @@ def _fused_attention(ctx, ins, attrs):
         mask = tri if mask is None else mask + tri
     return {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)],
             "Lse": [_no_lse(q)]}
-
 
 
 def _current_mesh():

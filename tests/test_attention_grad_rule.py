@@ -9,7 +9,6 @@ per layer in the traced step, the same dq/dk/dv bit for bit as the generic
 route, and the routes that must not change (dense, recompute, layer scan).
 """
 import collections
-import importlib
 import re
 
 import numpy as np
@@ -93,7 +92,7 @@ def attention_grads(mask, dropout, causal, dtype, use_rule):
            and op.attrs["fwd_type"] == "fused_attention"]
     assert len(vjp) == 1 and set(vjp[0].inputs) >= {"FO:Out", "FO:Lse"}
     if dtype != "float32":
-        # AMP with the op white-listed: the executor casts its inputs, and
+        # AMP (the op is white-listed): the executor casts its inputs, and
         # its grad op's, to bfloat16 (layers.cast would stop the gradient)
         prog._amp = True
     opdef = registry.get("fused_attention")
@@ -120,12 +119,7 @@ def attention_grads(mask, dropout, causal, dtype, use_rule):
 @pytest.mark.parametrize("dropout", [0.0, 0.1])
 @pytest.mark.parametrize("mask", [False, True], ids=["nomask", "keypad"])
 def test_rule_gives_the_generic_routes_grads_bit_for_bit(
-        open_gate, monkeypatch, mask, dropout, causal, dtype):
-    if dtype == "bfloat16":
-        # whatever AMP list the op lands on, the residuals keep their dtypes
-        auto_cast = importlib.import_module("paddle_tpu.amp.auto_cast")
-        monkeypatch.setattr(auto_cast, "white_list",
-                            auto_cast.white_list | {"fused_attention"})
+        open_gate, mask, dropout, causal, dtype):
     got, rise, seen = attention_grads(mask, dropout, causal, dtype, True)
     want, rise_generic, _ = attention_grads(mask, dropout, causal, dtype,
                                             False)

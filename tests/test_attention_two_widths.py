@@ -46,10 +46,6 @@ def _grads(s, dtype, flash, monkeypatch):
     grads = fluid.gradients(loss, qkv)
     prog = fluid.default_main_program()
     if dtype == "bfloat16":
-        import importlib
-        auto_cast = importlib.import_module("paddle_tpu.amp.auto_cast")
-        monkeypatch.setattr(auto_cast, "white_list",
-                            auto_cast.white_list | {"fused_attention"})
         prog._amp = True
     before = metrics.get("attention.flash_bwd_residual")
     vals = fluid.Executor().run(prog, feed=feed, fetch_list=[out] + grads)
@@ -85,15 +81,18 @@ def v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("dqk, dv, s, rows", [(192, 128, 4096, 2),
-                                              (64, 64, 512, 32)])
+@pytest.mark.parametrize("dqk, dv, s, rows, dt", [
+    (192, 128, 4096, 2, jnp.bfloat16),
+    # BERT's cell under AMP, and the same shape as a program without AMP
+    # hands it over
+    (64, 64, 512, 32, jnp.bfloat16),
+    (64, 64, 512, 32, jnp.float32)])
 def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
-                                                      monkeypatch):
+                                                      dt, monkeypatch):
     monkeypatch.setattr(fa, "interpret_mode", lambda: False)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     heads = 32 if dqk == 192 else 12
-    dt = jnp.bfloat16 if dqk == 192 else jnp.float32
 
     def sd(width, dtype=dt, shape=None):
         return jax.ShapeDtypeStruct(shape or (rows, heads, s, width), dtype,
