@@ -22,8 +22,8 @@ absorb:
   for, docs/perf_notes.md "Copy census").
 * feed_shadows_state — a feed name that is also referenced persistable
   state: the feed silently overrides the Scope value for the step and
-  removes the buffer from the donated set (executor
-  _referenced_state_names excludes feeds).
+  removes the buffer from the donated set (Executor._resolve_call
+  collects _referenced_state_names without the feeds).
 
 Both the prediction and the floor mirror the executor's own rules — the
 multi-step (run_steps) path donates everything written; the per-step path

@@ -24,7 +24,8 @@ _DEFS: Dict[str, tuple] = {
     "FLAGS_fraction_of_gpu_memory_to_use": (0.92, "no-op on TPU"),
     "FLAGS_paddle_num_threads": (1, "no-op: XLA threadpool"),
     "FLAGS_use_pinned_memory": (True, "no-op"),
-    "FLAGS_benchmark": (False, "sync + time each executor run"),
+    "FLAGS_benchmark": (False, "no-op: the `executor.launch` span holds "
+                               "the time it printed"),
     "FLAGS_profile_start_step": (-1, "auto-start profiler at this step"),
     "FLAGS_profile_stop_step": (-1, "auto-stop profiler at this step"),
     "FLAGS_tensor_array_capacity": (128, "default LoDTensorArray capacity"),

@@ -18,7 +18,7 @@ import contextlib
 import functools
 import itertools
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
@@ -653,7 +653,7 @@ def _amp_cast_ins(op_type, ins, low_dtype):
 
 
 def _coerce_feed_value(block, name, value):
-    """Feed coercion shared by run()/run_steps(): device-side casts for jax
+    """Coercion of ONE feed value (_normalize_feeds): device-side casts for jax
     arrays (feeding device arrays must NOT bounce through host numpy); 64-bit
     ints live as int32 on device (framework/dtype.py policy) with a range
     guard here instead of jax's silent truncation."""
@@ -740,19 +740,9 @@ def _ensure_shared_beta_pows(program, scope):
             scope.erase(n)
 
 
-def _ensure_zero_state(program, scope):
-    """ZeRO-1 checkpoint adoption (parallel/zero.py): an UNSHARDED
-    checkpoint loaded into a ZeRO program leaves per-param accumulator
-    entries in the scope; pack them into the flat bucket vars the program
-    reads and drop the copies (the `_ensure_shared_beta_pows` /
-    `_ensure_stacked_params` pattern — loaded values win)."""
-    from ..parallel.zero import adopt_unsharded_state
-    adopt_unsharded_state(program, scope)
-
-
 def _referenced_state_names(block, scope, feed_vals):
     """Persistable vars that already have values in the scope and are
-    referenced by this block (run()/run_steps() shared)."""
+    referenced by this block."""
     referenced = set()
     for op in block.ops:
         referenced.update(op.input_names())
@@ -764,21 +754,16 @@ def _referenced_state_names(block, scope, feed_vals):
         and v.persistable and scope.has(n) and n not in feed_vals)
 
 
-def _block_cache_key(program, feed_vals, fetch_names, state_names):
-    """The ONE compile-cache key shape shared by run()/run_steps()/
-    compiled_hlo() — they must agree byte-for-byte or compiled_hlo would
-    audit a different block than run() executes."""
-    feed_spec = tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                             for k, v in feed_vals.items()))
-    return (program._uid, program._version, feed_spec, tuple(fetch_names),
-            tuple(state_names))
-
-
-def _multi_step_feed_vals(gb, feed, k):
-    """Normalize run_steps feeds to a leading [k] steps axis (shared by
-    run_steps() and compiled_hlo(k=...)): rank==var rank broadcasts the
-    same batch to every step; rank+1 with dim0==k is per-step slices;
-    anything else is ambiguous -> typed error, no silent mis-slicing."""
+def _normalize_feeds(gb, feed, k):
+    """The ONE feed normaliser, by `k` (Executor._resolve_call and stage()).
+    k=None, the per-step shape: each value coerced to its var's device
+    dtype. run_steps(k): a leading [k] steps axis on top of that:
+    rank==var rank broadcasts the same batch to every step; rank+1 with
+    dim0==k is per-step slices; anything else is ambiguous -> typed error,
+    no silent mis-slicing."""
+    if k is None:
+        return {name: _coerce_feed_value(gb, name, value)
+                for name, value in feed.items()}
     import jax.numpy as jnp
     from . import errors
     feed_vals = {}
@@ -800,10 +785,43 @@ def _multi_step_feed_vals(gb, feed, k):
     return feed_vals
 
 
+def _run_steps_refusal(program):
+    """Why run_steps does not take this program, as the typed error to
+    raise, or None (Executor.run_steps raises it; train_from_dataset falls
+    back to per-step run() on it)."""
+    from . import errors
+    ps_hooks = getattr(program, "_ps_hooks", None) or []
+    if any(not hasattr(h, "pre_multi") for h in ps_hooks):
+        return errors.Unimplemented(
+            "run_steps with PS hooks that lack window support (e.g. "
+            "dense-send hooks); use per-step run()")
+    if any(getattr(h, "geo_k", 0) > 0 for h in ps_hooks):
+        return errors.Unimplemented(
+            "run_steps with Geo-SGD hooks (geo needs per-step local "
+            "updates; use per-step run())")
+    if getattr(program, "_localsgd_k", 0) or \
+            getattr(program, "_microbatch_k", 0):
+        return errors.Unimplemented(
+            "run_steps with LocalSGD/pipeline programs")
+    if _pp_degree(program) > 1:
+        return errors.Unimplemented(
+            "run_steps over a pp>1 mesh (pipeline stages run per-step)")
+    return None
+
+
+def _pp_degree(program) -> int:
+    """Size of the program's mesh along `pp` (1 without a mesh): above 1
+    its step is per-stage programs (parallel/pipeline.py), not one block."""
+    dist = getattr(program, "_dist_config", None)
+    return (int(dist.resolve_mesh().shape.get("pp", 1))
+            if dist is not None else 1)
+
+
 def _make_compiled_block(program, feed_vals, fetch_names, state_names,
                          scope, multi_k=0):
-    """_CompiledBlock constructor call shared by run()/run_steps()/
-    compiled_hlo() (callers store into the cache themselves)."""
+    """The _CompiledBlock constructor call with its shapes and dtypes read
+    off the resolved feeds and the scope (Executor._block_for stores it
+    into the cache)."""
     compiled = _CompiledBlock(
         program, 0, list(feed_vals), fetch_names, state_names,
         feed_shapes={k: tuple(v.shape) for k, v in feed_vals.items()},
@@ -850,8 +868,19 @@ class _StagedFeeds:
                    or feed[n] is self.device_feeds[n] for n in feed)
 
 
+class _Call(NamedTuple):
+    """What Executor._resolve_call made of one call."""
+    fetch_names: list       # the user's, then the PS hooks' gradient fetches
+    n_user_fetch: int
+    feed_vals: dict         # normalised by k; a staged window's device arrays
+    staged: bool            # ... when this is set
+    state_names: list
+    key: tuple              # the compile-cache key
+    ps_hooks: list
+
+
 def _package_fetches(fetches, fetch_names, return_numpy, sync, step=None):
-    """The ONE fetch-return site shared by run()/run_steps().
+    """The ONE fetch-return site of Executor._dispatch.
 
     return_numpy=False: the live device arrays, UNSYNCED — jax dispatch is
     asynchronous, so these may still be computing when returned; the
@@ -970,22 +999,15 @@ class Executor:
         pending window is never evicted before its run). The consuming
         call is matched by program + k + feed-value identity, so pass the
         SAME feed dict (or the returned device dict) to the next run."""
-        program = program or default_main_program()
-        if hasattr(program, "_is_data_parallel"):
-            program = program.program
+        program = self._resolve_program(program)
         scope = scope or global_scope()
-        gb = program.global_block()
+        k = None if k is None else int(k)
         from ..flags import flag
-        with _trace.RecordEvent("stage", args={"k": 0 if k is None else int(k),
+        with _trace.RecordEvent("stage", args={"k": k or 0,
                                                "feeds": len(feed)}):
             t0 = time.perf_counter()
             orig_vals = dict(feed)
-            if k is not None:
-                k = int(k)
-                feed_vals = _multi_step_feed_vals(gb, feed, k)
-            else:
-                feed_vals = {n: _coerce_feed_value(gb, n, v)
-                             for n, v in feed.items()}
+            feed_vals = _normalize_feeds(program.global_block(), feed, k)
             import jax.numpy as jnp
 
             scope_ids = None
@@ -1069,10 +1091,11 @@ class Executor:
         would not help; only a fresh buffer does). stage() already copies
         scope-resident values, so this only fires when state was
         re-pointed at a staged array after staging. Returns
-        (feed_vals, n_conflicts); callers also fall back to sync when
-        n_conflicts > 0 (the conservative serialization the docs
-        promise). Covers the LocalSGD path's `<name>@LOCALSGD` entries
-        too — every block class donates its mut set."""
+        (feed_vals, n_conflicts), the conflicts counted and marked in the
+        trace; the dispatch also falls back to sync when n_conflicts > 0
+        (the conservative serialization the docs promise). Covers the
+        LocalSGD path's `<name>@LOCALSGD` entries too — every block class
+        donates its mut set."""
         mut_names = getattr(compiled, "mut_names", None)
         if not mut_names:
             return staged_vals, 0
@@ -1091,6 +1114,9 @@ class Executor:
                 n_conf += 1
             else:
                 out[name] = v
+        monitor.stat_add("executor.staging_conflicts", n_conf)
+        _trace.instant("donation_conflict_copy",
+                       args={"n": n_conf, "step": self._step_counter})
         return out, n_conf
 
     def run(self, program: Optional[Program] = None, feed: Optional[dict] = None,
@@ -1111,10 +1137,8 @@ class Executor:
           dispatch is async, so they may still be computing; np.asarray
           (or .block_until_ready) at the consumer is the sync point.
         """
-        program = self._resolve_program(program)
-        with self._step_window("run", program):
-            return self._run_impl(program, feed, fetch_list, scope,
-                                  return_numpy, use_program_cache, sync)
+        return self._dispatch(None, program, feed, fetch_list, scope,
+                              return_numpy, use_program_cache, sync)
 
     @staticmethod
     def _resolve_program(program):
@@ -1192,175 +1216,191 @@ class Executor:
         step) — trainers hand it to TrainingGuard / DivergenceSentinel."""
         return self._snapshot_mgr
 
-    def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
-                  use_program_cache, sync):
-        with _trace.RecordEvent("executor.prepare"):
-            feed = feed or {}
-            fetch_list = fetch_list or []
-            scope = scope or global_scope()
-            sync = self._resolve_sync(sync)
+    def _resolve_call(self, program, feed, fetch_list, scope, k,
+                      take_staged=True) -> _Call:
+        """THE resolver: which compiled block a call means (k=None: the
+        per-step shape; k: run_steps(k)'s). Nothing else names the fetches,
+        normalises feeds, adopts loaded state, collects the referenced
+        state or knows the shape of the compile-cache key, so a dispatch
+        and compiled_hlo()/step_jaxpr() of the same signature cannot mean
+        different blocks. Inspection passes take_staged=False: a staged
+        window waits for the dispatch that owns it."""
+        from . import errors
+        feed = feed or {}
+        gb = program.global_block()
+        fetch_names = [v.name if isinstance(v, Variable) else str(v)
+                       for v in fetch_list or []]
+        for n in fetch_names:
+            if not gb.has_var(n):
+                raise errors.NotFound(
+                    "fetch target %r is not a variable of this program", n,
+                    var=n)
+        n_user_fetch = len(fetch_names)
+        # staged windows match the USER feed — before PS hooks add their
+        # pulled-row keys, which stage() never saw (a post-hook match
+        # would always miss on PS programs and silently double the H2D)
+        staged_vals = self._take_staged(program, feed, k) if take_staged \
+            else None
+        # parameter-server hooks (distributed_embedding): pull sparse rows
+        # before the step, push their grads after (distributed/ps.py). In
+        # a k-step window: ONE pull covering all k batches' ids, ONE summed
+        # push after — the reference's async-communicator batching
+        # (communicator.h), amortizing dispatch + RPC cost over k
+        ps_hooks = getattr(program, "_ps_hooks", None) or []
+        if ps_hooks:
+            feed = dict(feed)
+            for h in ps_hooks:
+                feed.update(h.pre(feed) if k is None else h.pre_multi(feed))
+                if gb.has_var(h.grad_name) and \
+                        h.grad_name not in fetch_names:
+                    fetch_names.append(h.grad_name)
+        # a staged window paid coercion + H2D in stage(); only hook-added
+        # entries (the pulled rows) still normalise here
+        feed_vals = dict(staged_vals or {})
+        feed_vals.update(_normalize_feeds(
+            gb, {n: v for n, v in feed.items() if n not in feed_vals}, k))
+        # adoption BEFORE the state names: it renames scope entries the
+        # program reads (a loaded checkpoint's per-layer / per-param /
+        # unsharded entries become the stacked / shared / flat ones)
+        _ensure_stacked_params(program, scope)
+        _ensure_shared_beta_pows(program, scope)
+        from ..parallel.zero import adopt_unsharded_state
+        adopt_unsharded_state(program, scope)
+        state_names = _referenced_state_names(gb, scope, feed_vals)
+        feed_spec = tuple(sorted((n, tuple(v.shape), str(v.dtype))
+                                 for n, v in feed_vals.items()))
+        key = (program._uid, program._version, feed_spec,
+               tuple(fetch_names), tuple(state_names))
+        if k is not None:
+            key = ("multi", k) + key
+        return _Call(fetch_names, n_user_fetch, feed_vals,
+                     staged_vals is not None, state_names, key, ps_hooks)
 
-            fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                           for v in fetch_list]
-            gb = program.global_block()
-            for n in fetch_names:
-                if not gb.has_var(n):
-                    from . import errors
-                    raise errors.NotFound(
-                        "fetch target %r is not a variable of this program", n,
-                        var=n)
-
-            # staged windows match the USER feed — before PS hooks add their
-            # pulled-row keys, which stage() never saw (a post-hook match
-            # would always miss on PS programs and silently double the H2D)
-            staged_vals = self._take_staged(program, feed, k=None)
-            # parameter-server hooks (distributed_embedding): pull sparse rows
-            # before the step, push their grads after (distributed/ps.py)
-            ps_hooks = getattr(program, "_ps_hooks", None) or []
-            n_user_fetch = len(fetch_names)
-            if ps_hooks:
-                feed = dict(feed)
-                for h in ps_hooks:
-                    feed.update(h.pre(feed))
-                    if gb.has_var(h.grad_name) and \
-                            h.grad_name not in fetch_names:
-                        fetch_names.append(h.grad_name)
-            block = program.global_block()
-            if staged_vals is not None:
-                # coercion + H2D already paid in stage(); hook-added entries
-                # (pulled rows) still coerce here
-                feed_vals = dict(staged_vals)
-                for name, value in feed.items():
-                    if name not in feed_vals:
-                        feed_vals[name] = _coerce_feed_value(block, name,
-                                                             value)
-            else:
-                feed_vals = {name: _coerce_feed_value(block, name, value)
-                             for name, value in feed.items()}
-            _ensure_stacked_params(program, scope)
-            _ensure_shared_beta_pows(program, scope)
-            _ensure_zero_state(program, scope)
-            state_names = _referenced_state_names(block, scope, feed_vals)
-
-            key = _block_cache_key(program, feed_vals, fetch_names,
-                                   state_names)
-            compiled = self._cache.get(key) if use_program_cache else None
+    def _block_for(self, program, call, scope, k, use_program_cache=True):
+        """The ONE place that finds a resolved call's block in the cache
+        (`executor.compile_cache_hits`) or builds it (`_misses`): a `jax.jit`
+        object with state placed on the mesh; the first launch compiles."""
+        compiled = self._cache.get(call.key) if use_program_cache else None
+        if compiled is not None:
+            _metrics.inc("executor.compile_cache_hits")
+            return compiled
+        _metrics.inc("executor.compile_cache_misses")
+        with _trace.RecordEvent("executor.build_block"):
             localsgd_k = getattr(program, "_localsgd_k", 0)
-            if compiled is None:
-                _metrics.inc("executor.compile_cache_misses")
-                with _trace.RecordEvent("executor.build_block"):
-                    dist = getattr(program, "_dist_config", None)
-                    pp = (int(dist.resolve_mesh().shape.get("pp", 1))
-                          if dist is not None else 1)
-                    if pp > 1:
-                        # the pp mesh axis engages true pipeline parallelism:
-                        # stages partitioned by device_guard, placed on pp
-                        # submeshes (parallel/pipeline.py)
-                        if localsgd_k and localsgd_k > 1:
-                            from . import errors
-                            raise errors.Unimplemented(
-                                "LocalSGD over a pp>1 mesh (pipeline stages "
-                                "and per-replica parameter copies are "
-                                "incompatible)")
-                        from ..parallel.pipeline import _PipelineBlock
-                        compiled = _PipelineBlock(program, 0, list(feed_vals),
-                                                  fetch_names, state_names)
-                    elif localsgd_k and localsgd_k > 1:
-                        compiled = _LocalSGDBlock(program, 0, list(feed_vals),
-                                                  fetch_names, state_names,
-                                                  localsgd_k)
-                    else:
-                        compiled = _make_compiled_block(
-                            program, feed_vals, fetch_names, state_names,
-                            scope)
-                if use_program_cache:
-                    self._cache[key] = compiled
+            if _pp_degree(program) > 1:
+                # the pp mesh axis engages true pipeline parallelism:
+                # stages partitioned by device_guard, placed on pp
+                # submeshes (parallel/pipeline.py)
+                if localsgd_k and localsgd_k > 1:
+                    from . import errors
+                    raise errors.Unimplemented(
+                        "LocalSGD over a pp>1 mesh (pipeline stages "
+                        "and per-replica parameter copies are "
+                        "incompatible)")
+                from ..parallel.pipeline import _PipelineBlock
+                compiled = _PipelineBlock(program, 0, list(call.feed_vals),
+                                          call.fetch_names, call.state_names)
+            elif localsgd_k and localsgd_k > 1:
+                compiled = _LocalSGDBlock(program, 0, list(call.feed_vals),
+                                          call.fetch_names, call.state_names,
+                                          localsgd_k)
             else:
-                _metrics.inc("executor.compile_cache_hits")
+                compiled = _make_compiled_block(
+                    program, call.feed_vals, call.fetch_names,
+                    call.state_names, scope, multi_k=k or 0)
+        if use_program_cache:
+            self._cache[call.key] = compiled
+        return compiled
 
-            if staged_vals is not None:
-                # the donation-vs-staging aliasing rule: a staged buffer the
-                # step donates is copied into a fresh buffer pre-dispatch,
-                # and the call serializes (sync) for good measure
-                feed_vals, n_conf = self._resolve_staged_donation(
-                    compiled, feed_vals, scope)
-                if n_conf:
-                    monitor.stat_add("executor.staging_conflicts", n_conf)
-                    _trace.instant("donation_conflict_copy",
-                                   args={"n": n_conf,
-                                         "step": self._step_counter})
-                    sync = True
+    def _dispatch(self, k, program, feed, fetch_list, scope, return_numpy,
+                  use_program_cache, sync):
+        """THE dispatch body: run() is this with k=None, run_steps(k) its
+        refusals and this with k (ONE dispatch: the step counter advances
+        once, the root carries k). Under the root `executor.step` exactly
+        one `executor.prepare` (resolve the call, its block, the rng draw),
+        `executor.launch` (the jitted call) and `executor.commit` (state
+        into the scope, hooks, fetches packaged)."""
+        from ..flags import flag
+        program = self._resolve_program(program)
+        with self._step_window("run" if k is None else "run_steps", program,
+                               k or 1) as step_idx:
+            with _trace.RecordEvent("executor.prepare"):
+                scope = scope or global_scope()
+                sync = self._resolve_sync(sync)
+                call = self._resolve_call(program, feed, fetch_list, scope, k)
+                compiled = self._block_for(program, call, scope, k,
+                                           use_program_cache)
+                feed_vals = call.feed_vals
+                if call.staged:
+                    # the donation-vs-staging aliasing rule: a staged
+                    # buffer the step donates is copied into a fresh buffer
+                    # pre-dispatch, and the call serializes (sync) for good
+                    # measure
+                    feed_vals, n_conf = self._resolve_staged_donation(
+                        compiled, feed_vals, scope)
+                    sync = sync or n_conf > 0
+                rng_key = _next_rng_key(scope, program.random_seed)
+                # _LocalSGDBlock / _PipelineBlock drive the scope themselves
+                launch = (functools.partial(
+                    compiled, {n: scope.find(n) for n in call.state_names},
+                    feed_vals, rng_key)
+                    if isinstance(compiled, _CompiledBlock)
+                    else functools.partial(compiled.step, scope, feed_vals,
+                                           rng_key))
+                # step-level hang watchdog: bound the dispatch (and, below,
+                # the synchronous fetch drain) so a wedged collective —
+                # inside a k-step scan just the same — surfaces as a typed
+                # error the gang supervisor can restart on, never a hang
+                step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
+                self._emit_collective_markers(program, step_idx, k)
+            with _trace.RecordEvent("executor.launch"):
+                if step_deadline > 0:
+                    what = "step" if k is None else f"run_steps(k={k})"
+                    fetches, new_state = _deadline_call(
+                        launch, step_deadline,
+                        f"{what} dispatch ({op_count(program)} ops)")
+                else:
+                    fetches, new_state = launch()
+            with _trace.RecordEvent("executor.commit"):
+                for n, v in new_state.items():
+                    scope.set(n, v)
+                self._maybe_snapshot(program, scope)
+                if flag("FLAGS_check_nan_inf"):
+                    # run_steps' stacked [k, ...] fetches scan the same way:
+                    # a NaN in any of the k steps names its variable
+                    _check_nan_inf(dict(zip(call.fetch_names, fetches)),
+                                   new_state)
+                if call.ps_hooks:
+                    fetched_by_name = dict(zip(call.fetch_names, fetches))
+                    for h in call.ps_hooks:
+                        (h.post if k is None else h.post_multi)(
+                            fetched_by_name)
+                    fetches = fetches[:call.n_user_fetch]
+                user_names = call.fetch_names[:call.n_user_fetch]
+                if k is None and not sync and return_numpy and fetches:
+                    # lazy-fetch side of the donation rule: a fetch of a
+                    # WRITTEN persistable shares (or may share) the buffer
+                    # the scope just adopted — the NEXT dispatch donates
+                    # that buffer, and a deferred .numpy() would read
+                    # deleted memory. Snapshot those rare fetches with a
+                    # device-side copy (bit-identical, async); ordinary
+                    # fetches pass through untouched. The sync path is
+                    # immune (it drains before any next dispatch). k=None
+                    # ONLY: a run_steps fetch is a fresh stacked [k, ...]
+                    # buffer sharing nothing with the scope, and a copy of
+                    # it a device copy the step does not need.
+                    import jax.numpy as jnp
+                    fetches = [jnp.copy(f)
+                               if (n in new_state and hasattr(f, "dtype"))
+                               else f for f, n in zip(fetches, user_names)]
 
-            rng_key = _next_rng_key(scope, program.random_seed)
-            from ..flags import flag
-            step_idx = self._step_counter
-
-            # _LocalSGDBlock / _PipelineBlock drive the scope themselves
-            state = ({n: scope.find(n) for n in state_names}
-                     if isinstance(compiled, _CompiledBlock) else None)
-
-            def _dispatch():
-                if state is None:
-                    return compiled.step(scope, feed_vals, rng_key)
-                return compiled(state, feed_vals, rng_key)
-
-            # step-level hang watchdog: bound the dispatch (and, below, the
-            # synchronous fetch drain) so a wedged collective surfaces as a
-            # typed error the gang supervisor can restart on, never a hang
-            step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
-            if step_deadline > 0:
-                _raw_dispatch = _dispatch
-
-                def _dispatch():
-                    return _deadline_call(
-                        _raw_dispatch, step_deadline,
-                        f"step dispatch ({op_count(program)} ops)")
-
-            benchmark = flag("FLAGS_benchmark")
-            self._emit_collective_markers(program, step_idx)
-        t0 = time.perf_counter()
-        with _trace.RecordEvent("executor.launch"):
-            fetches, new_state = _dispatch()
-            if benchmark:  # sync so the wall time is the device time
-                jax.block_until_ready(fetches)
-        if benchmark:
-            print(f"[benchmark] step {step_idx}: "
-                  f"{(time.perf_counter() - t0) * 1000:.3f} ms")
-        with _trace.RecordEvent("executor.commit"):
-            for n, v in new_state.items():
-                scope.set(n, v)
-            self._maybe_snapshot(program, scope)
-            if flag("FLAGS_check_nan_inf"):
-                _check_nan_inf(dict(zip(fetch_names, fetches)), new_state)
-            if ps_hooks:
-                fetched_by_name = dict(zip(fetch_names, fetches))
-                for h in ps_hooks:
-                    h.post(fetched_by_name)
-                fetches = fetches[:n_user_fetch]
-            user_names = (fetch_names[:n_user_fetch] if ps_hooks
-                          else fetch_names)
-            if not sync and return_numpy and fetches:
-                # lazy-fetch side of the donation rule: a fetch of a WRITTEN
-                # persistable shares (or may share) the buffer the scope
-                # just adopted — the NEXT dispatch donates that buffer, and
-                # a deferred .numpy() would read deleted memory. Snapshot
-                # those rare fetches with a device-side copy (bit-identical,
-                # async); ordinary fetches (losses, activations) pass through
-                # untouched. The sync path is immune (it drains before any
-                # next dispatch), and run_steps' stacked fetches are fresh
-                # [k,...] buffers.
-                import jax.numpy as jnp
-                fetches = [jnp.copy(f)
-                           if (n in new_state and hasattr(f, "dtype")) else f
-                           for f, n in zip(fetches, user_names)]
-            if step_deadline > 0 and sync and return_numpy:
-                return _deadline_call(
-                    lambda: _package_fetches(fetches, user_names,
-                                             return_numpy, sync,
-                                             step=step_idx),
-                    step_deadline, "fetch materialization")
-            return _package_fetches(fetches, user_names, return_numpy, sync,
-                                    step=step_idx)
+                def package():
+                    return _package_fetches(fetches, user_names, return_numpy,
+                                            sync, step=step_idx)
+                if step_deadline > 0 and sync and return_numpy:
+                    return _deadline_call(package, step_deadline,
+                                          "fetch materialization")
+                return package()
 
     def _collective_marker_plan(self, program) -> list:
         """Ordered [(kind, bucket_index)] of the program's collective ops —
@@ -1425,8 +1465,7 @@ class Executor:
         XLA reports flops; memory stats availability varies by version).
         XLA's counts are no source for a roofline (PERF.md section 6):
         nothing is recorded from them."""
-        prog = self._resolve_program(program)
-        compiled = self._inspect_compiled(feed, fetch_list, prog, scope, k)
+        compiled = self._inspect_compiled(feed, fetch_list, program, scope, k)
         cost: dict = {}
         try:
             ca = compiled.cost_analysis()
@@ -1478,133 +1517,12 @@ class Executor:
         if not isinstance(k, (int, np.integer)) or k < 1:
             raise errors.InvalidArgument(
                 "run_steps needs an integer k >= 1, got %r", k)
-        k = int(k)
         program = self._resolve_program(program)
-        # one run_steps call is ONE dispatch: it advances the executor's
-        # step counter once, and the flight recorder records it as one
-        # step window (its root span carries k)
-        with self._step_window("run_steps", program, k):
-            return self._run_steps_impl(k, program, feed, fetch_list, scope,
-                                        return_numpy, sync)
-
-    def _run_steps_impl(self, k, program, feed, fetch_list, scope,
-                        return_numpy, sync):
-        from . import errors
-        with _trace.RecordEvent("executor.prepare"):
-            ps_hooks = getattr(program, "_ps_hooks", None) or []
-            if any(not hasattr(h, "pre_multi") for h in ps_hooks):
-                raise errors.Unimplemented(
-                    "run_steps with PS hooks that lack window support (e.g. "
-                    "dense-send hooks); use per-step run()")
-            if any(getattr(h, "geo_k", 0) > 0 for h in ps_hooks):
-                raise errors.Unimplemented(
-                    "run_steps with Geo-SGD hooks (geo needs per-step local "
-                    "updates; use per-step run())")
-            if getattr(program, "_localsgd_k", 0) or \
-                    getattr(program, "_microbatch_k", 0):
-                raise errors.Unimplemented(
-                    "run_steps with LocalSGD/pipeline programs")
-            dist = getattr(program, "_dist_config", None)
-            if dist is not None and \
-                    int(dist.resolve_mesh().shape.get("pp", 1)) > 1:
-                raise errors.Unimplemented(
-                    "run_steps over a pp>1 mesh (pipeline stages run "
-                    "per-step)")
-            feed = feed or {}
-            fetch_list = fetch_list or []
-            scope = scope or global_scope()
-            sync = self._resolve_sync(sync)
-            fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                           for v in fetch_list]
-            gb = program.global_block()
-            for n in fetch_names:
-                if not gb.has_var(n):
-                    raise errors.NotFound(
-                        "fetch target %r is not a variable of this program",
-                        n, var=n)
-            # PS hooks, k-step window mode: ONE pull covering all k batches'
-            # ids, ONE summed push after — the reference's async-
-            # communicator batching (communicator.h), amortizing dispatch +
-            # RPC cost over k
-            n_user_fetch = len(fetch_names)
-            # match the USER feed before the hooks add pulled-row keys (see
-            # run(): a post-hook match would always miss on PS programs)
-            staged_vals = self._take_staged(program, feed, k=k)
-            if ps_hooks:
-                feed = dict(feed)
-                for h in ps_hooks:
-                    feed.update(h.pre_multi(feed))
-                    if gb.has_var(h.grad_name) and \
-                            h.grad_name not in fetch_names:
-                        fetch_names.append(h.grad_name)
-            if staged_vals is not None:
-                # coercion + H2D already paid in stage(); hook-added entries
-                # (the window's pulled rows) still normalize here
-                feed_vals = dict(staged_vals)
-                extra = {n: v for n, v in feed.items() if n not in feed_vals}
-                if extra:
-                    feed_vals.update(_multi_step_feed_vals(gb, extra, k))
-            else:
-                feed_vals = _multi_step_feed_vals(gb, feed, k)
-            _ensure_stacked_params(program, scope)
-            _ensure_shared_beta_pows(program, scope)
-            _ensure_zero_state(program, scope)
-            state_names = _referenced_state_names(gb, scope, feed_vals)
-            key = ("multi", k) + _block_cache_key(program, feed_vals,
-                                                  fetch_names, state_names)
-            compiled = self._cache.get(key)
-            if compiled is None:
-                _metrics.inc("executor.compile_cache_misses")
-                with _trace.RecordEvent("executor.build_block"):
-                    compiled = _make_compiled_block(program, feed_vals,
-                                                    fetch_names, state_names,
-                                                    scope, multi_k=k)
-                self._cache[key] = compiled
-            else:
-                _metrics.inc("executor.compile_cache_hits")
-            if staged_vals is not None:
-                feed_vals, n_conf = self._resolve_staged_donation(
-                    compiled, feed_vals, scope)
-                if n_conf:
-                    monitor.stat_add("executor.staging_conflicts", n_conf)
-                    _trace.instant("donation_conflict_copy",
-                                   args={"n": n_conf,
-                                         "step": self._step_counter})
-                    sync = True
-            rng_key = _next_rng_key(scope, program.random_seed)
-            state = {n: scope.find(n) for n in state_names}
-            from ..flags import flag
-            step_idx = self._step_counter
-            step_deadline = float(flag("FLAGS_step_deadline_ms") or 0.0)
-            self._emit_collective_markers(program, step_idx, k=k)
-        with _trace.RecordEvent("executor.launch"):
-            if step_deadline > 0:
-                # the hang watchdog covers the k-step dispatch too (one
-                # wedged collective inside the scan blocks it the same way)
-                fetches, new_state = _deadline_call(
-                    lambda: compiled(state, feed_vals, rng_key),
-                    step_deadline, f"run_steps(k={k}) dispatch")
-            else:
-                fetches, new_state = compiled(state, feed_vals, rng_key)
-        with _trace.RecordEvent("executor.commit"):
-            for n, v in new_state.items():
-                scope.set(n, v)
-            self._maybe_snapshot(program, scope)
-            if ps_hooks:
-                fetched_by_name = dict(zip(fetch_names, fetches))
-                for h in ps_hooks:
-                    h.post_multi(fetched_by_name)
-                fetches = fetches[:n_user_fetch]
-            user_names = (fetch_names[:n_user_fetch] if ps_hooks
-                          else fetch_names)
-            if step_deadline > 0 and sync and return_numpy:
-                return _deadline_call(
-                    lambda: _package_fetches(fetches, user_names,
-                                             return_numpy, sync,
-                                             step=step_idx),
-                    step_deadline, "run_steps fetch materialization")
-            return _package_fetches(fetches, user_names, return_numpy, sync,
-                                    step=step_idx)
+        refusal = _run_steps_refusal(program)
+        if refusal is not None:
+            raise refusal
+        return self._dispatch(int(k), program, feed, fetch_list, scope,
+                              return_numpy, True, sync)
 
     def train_from_dataset(self, program=None, dataset=None, scope=None,
                            thread=0, debug=False, fetch_list=None,
@@ -1668,16 +1586,11 @@ class Executor:
         fetched = None
         step = 0
         group_k = int(steps_per_loop)
-        real_prog = (program.program
-                     if hasattr(program, "_is_data_parallel") else program)
-        hooks = getattr(real_prog, "_ps_hooks", None) or []
-        ps_window_ok = all(hasattr(h, "pre_multi")
-                           and getattr(h, "geo_k", 0) <= 0 for h in hooks)
-        if group_k > 1 and ((hooks and not ps_window_ok)
-                            or getattr(real_prog, "_localsgd_k", 0)
-                            or getattr(real_prog, "_microbatch_k", 0)):
-            # geo / dense-send hooks need per-step pull-push; sparse window
-            # hooks ride the grouped run_steps path (pre_multi/post_multi)
+        if group_k > 1 and _run_steps_refusal(
+                self._resolve_program(program)) is not None:
+            # e.g. geo / dense-send hooks need per-step pull-push; sparse
+            # window hooks ride the grouped run_steps path (pre_multi/
+            # post_multi)
             group_k = 1
 
         def _shapes(feed):
@@ -1797,29 +1710,20 @@ class Executor:
         return self._inspect_traced(feed, fetch_list, program, scope,
                                     k).jaxpr
 
-    def _inspect_compiled(self, feed=None, fetch_list=None, program=None,
-                          scope=None, k=None):
-        return self._inspect_traced(feed, fetch_list, program, scope,
-                                    k).lower().compile()
+    def _inspect_compiled(self, *signature):
+        return self._inspect_traced(*signature).lower().compile()
 
-    def _inspect_traced(self, feed=None, fetch_list=None, program=None,
-                        scope=None, k=None):
+    def _inspect_traced(self, feed, fetch_list, program, scope, k):
         import jax.numpy as jnp
 
         from . import errors
-        program = program or default_main_program()
-        if hasattr(program, "_is_data_parallel"):
-            program = program.program
+        program = self._resolve_program(program)
         if getattr(program, "_ps_hooks", None) \
-                or getattr(program, "_localsgd_k", 0):
+                or getattr(program, "_localsgd_k", 0) \
+                or _pp_degree(program) > 1:
             raise errors.Unimplemented(
-                "compiled_hlo on PS/LocalSGD programs (their step is not "
-                "one jitted computation)")
-        dist = getattr(program, "_dist_config", None)
-        if dist is not None and \
-                int(dist.resolve_mesh().shape.get("pp", 1)) > 1:
-            raise errors.Unimplemented(
-                "compiled_hlo over a pp>1 mesh (per-stage programs)")
+                "compiled_hlo on PS/LocalSGD programs or over a pp>1 mesh "
+                "(their step is not one jitted computation)")
         if k is not None:
             if isinstance(k, bool) or not isinstance(k, (int, np.integer)) \
                     or k < 1:
@@ -1829,42 +1733,18 @@ class Executor:
                 raise errors.Unimplemented(
                     "compiled_hlo k=%d on a pipeline (microbatched) "
                     "program — run_steps does not take those", int(k))
-        feed = feed or {}
-        fetch_list = fetch_list or []
+            k = int(k)
         scope = scope or global_scope()
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
-        block = program.global_block()
-        for n in fetch_names:
-            if not block.has_var(n):
-                raise errors.NotFound(
-                    "fetch target %r is not a variable of this program", n,
-                    var=n)
-        if k is not None:
-            feed_vals = _multi_step_feed_vals(block, feed, int(k))
-        else:
-            feed_vals = {name: _coerce_feed_value(block, name, value)
-                         for name, value in feed.items()}
-        _ensure_stacked_params(program, scope)
-        _ensure_shared_beta_pows(program, scope)
-        _ensure_zero_state(program, scope)
-        state_names = _referenced_state_names(block, scope, feed_vals)
-        key = _block_cache_key(program, feed_vals, fetch_names, state_names)
-        if k is not None:
-            key = ("multi", int(k)) + key
-        compiled = self._cache.get(key)
-        if compiled is None:
-            compiled = _make_compiled_block(program, feed_vals, fetch_names,
-                                            state_names, scope,
-                                            multi_k=int(k) if k else 0)
-            self._cache[key] = compiled
+        call = self._resolve_call(program, feed, fetch_list, scope, k,
+                                  take_staged=False)
+        compiled = self._block_for(program, call, scope, k)
         if not isinstance(compiled, _CompiledBlock):
             raise errors.Unimplemented(
                 "compiled_hlo: cached entry for this signature is not a "
                 "single jitted block")
         mut = {n: scope.find(n) for n in compiled.mut_names}
         ro = {n: scope.find(n) for n in compiled.ro_names}
-        feeds = {n: jnp.asarray(v) for n, v in feed_vals.items()}
+        feeds = {n: jnp.asarray(v) for n, v in call.feed_vals.items()}
         return compiled.jitted.trace(mut, ro, feeds, jax.random.key(0))
 
     def infer_from_dataset(self, program=None, dataset=None, scope=None,

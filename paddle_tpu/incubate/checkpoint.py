@@ -117,7 +117,8 @@ def _collect_state(program) -> dict:
     (`io._portable_arrays`: ZeRO flat bucket entries split back into their
     per-param views), so the resulting checkpoint loads into a replicated
     program directly and repacks into a ZeRO program of ANY dp width via
-    `executor._ensure_zero_state` on the next dispatch."""
+    `zero.adopt_unsharded_state` on the next dispatch
+    (`Executor._resolve_call`)."""
     from ..io import _portable_arrays
     return _portable_arrays(program, global_scope())
 

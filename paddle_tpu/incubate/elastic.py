@@ -17,8 +17,9 @@ is ELASTIC in two layers:
   layout) — re-sharding is the compiler's job, not the checkpoint's;
 * ZeRO flat-bucket state (parallel/zero.py) is saved as its per-param
   views and REPACKED for the restoring program's own dp width by
-  `executor._ensure_zero_state` on the first post-restore dispatch
-  (`zero.adopt_unsharded_state`), so sharded optimizer/gradient/parameter
+  `zero.adopt_unsharded_state`, which the executor's call resolver
+  (`Executor._resolve_call`) runs on the first post-restore dispatch or
+  inspection, so sharded optimizer/gradient/parameter
   storage survives a train-on-N / resume-on-M resize bit-for-bit. A dp
   the 64-element bucket padding does not divide takes the full-width
   replicated fallback, counted under `executor.zero_manual_fallbacks`.

@@ -44,8 +44,8 @@ def _portable_arrays(program: Program, scope) -> dict:
     ZeRO-1 flat optimizer-state buckets split back into their per-param
     views (parallel/zero.py) — checkpoints are ALWAYS the unsharded format,
     so a replicated program loads them directly and a ZeRO program adopts
-    them back into flat shards (executor._ensure_zero_state), in either
-    direction."""
+    them back into flat shards (zero.adopt_unsharded_state, from
+    Executor._resolve_call), in either direction."""
     arrays = {n: np.asarray(scope.find(n))
               for n in _persistable_names(program, scope)}
     from .parallel.zero import unbucket_state_for_save

@@ -305,7 +305,8 @@ class AdamOptimizer(Optimizer):
             # record the EXACT legacy-checkpoint names this shared var
             # supersedes (checkpoints written before the sharing carried
             # one <param>_beta{idx}_pow_acc_<n> per param) so the
-            # executor's adoption hook (_ensure_shared_beta_pows) can do
+            # executor's adoption hook (_ensure_shared_beta_pows, from
+            # Executor._resolve_call) can do
             # O(1) lookups against a closed list — never a scope scan,
             # and never another live program's shared pow var
             prog = var.block.program

@@ -297,7 +297,8 @@ def apply_layer_scan(program: Program, boundaries: List,
     stacked value lands in the Scope at init (the per-layer init vars flip
     non-persistable there); the Executor also restacks lazily from
     per-layer Scope entries, so unrolled checkpoints load into rolled
-    programs (framework/executor.py _ensure_stacked_params).
+    programs (framework/executor.py _ensure_stacked_params, run by
+    Executor._resolve_call for every dispatch and inspection).
 
     Must run before append_backward. Returns the interior boundary names
     the roll consumed (callers drop them from recompute checkpoint lists —

@@ -1029,7 +1029,9 @@ def _pack_flat(values, b, dtype):
 
 def adopt_unsharded_state(program, scope) -> None:
     """Scope round-trip for ZeRO programs (the `_ensure_shared_beta_pows`
-    adoption pattern): when every per-param entry of a bucket×kind is
+    adoption pattern; `Executor._resolve_call` runs the three adoptions
+    together, before it collects the state names): when every per-param
+    entry of a bucket×kind is
     present in the scope — an UNSHARDED checkpoint was just loaded — pack
     them into the flat bucket var the program reads and drop the per-param
     copies. Loaded values win over a previously flat value; partial sets

@@ -101,7 +101,8 @@ def test_legacy_per_param_pow_checkpoint_adopts_into_shared_pair():
     `<param>_beta{1,2}_pow_acc_*` entry per param (all equal). Loading
     one must not silently restart bias correction at beta^1: the
     executor adopts the legacy value into the shared var and drops the
-    stale copies (mirroring _ensure_stacked_params); disagreeing legacy
+    stale copies (mirroring _ensure_stacked_params, beside it in
+    Executor._resolve_call); disagreeing legacy
     entries are ambiguous and adopt nothing."""
     import jax.numpy as jnp
     from paddle_tpu.framework.scope import global_scope

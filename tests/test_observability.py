@@ -529,6 +529,37 @@ def test_every_span_has_id_parent_and_its_roots_step():
             assert by[e["parent"]]["ts"] <= e["ts"] + 1.0
 
 
+@pytest.mark.parametrize("k", [None, 1, 4])
+def test_one_dispatch_is_one_root_over_one_of_each_phase(k):
+    """run() and run_steps(k) are ONE dispatch body (Executor._dispatch):
+    whatever the shape, a dispatch is one root with the args the benchmark's
+    readers select on, and under it exactly one prepare, launch, commit."""
+    _fresh()
+    exe, loss, feed = _build()
+
+    def dispatch():
+        if k is None:
+            return exe.run(feed=feed, fetch_list=[loss])
+        return exe.run_steps(k, feed=feed, fetch_list=[loss])
+
+    dispatch()                                   # the compile, out of sight
+    trace.clear()
+    out, = dispatch()
+    assert np.asarray(out).shape == (() if k is None else (k,))
+    spans = _spans()
+    root, = [e for e in spans if e["name"] == "executor.step"]
+    assert root["parent"] is None
+    assert set(root["args"]) == {"step", "exe", "kind", "k", "program",
+                                 "ops"}
+    assert root["args"]["kind"] == ("run" if k is None else "run_steps")
+    assert root["args"]["k"] == (k or 1)
+    assert root["args"]["program"] == "main" and root["args"]["ops"] > 0
+    assert root["args"]["step"] == exe._step_counter
+    assert [e["name"] for e in spans if e["parent"] == root["id"]] == [
+        "executor.prepare", "executor.launch", "executor.commit"]
+    assert "executor.build_block" not in {e["name"] for e in spans}
+
+
 def test_startup_program_root_says_so():
     _fresh()
     x = layers.data(name="x", shape=[4], dtype="float32")
