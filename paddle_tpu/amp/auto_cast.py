@@ -36,8 +36,9 @@ keep_f32_slots = {
     # to the f32 scores, widened per block by the kernels anyway
     "fused_attention": {"Lse", "Mask"},
     # the router scores tokens in float32 from float32 activations: a
-    # bf16 rounding of either moves which experts a near-tie selects
-    "routed_moe": {"X", "GateW", "SelectBias"},
+    # bf16 rounding of either moves which experts a near-tie selects; the
+    # slots' weights it made reach the grad op as they were (FO:SortedW)
+    "routed_moe": {"X", "GateW", "SelectBias", "SortedW"},
 }
 
 # ops forced to float32 (reference black list: reductions/normalizations)

@@ -302,7 +302,9 @@ SPECS: Dict[str, OpSpec] = {
     "routed_moe": OpSpec(
         inputs={"X": ONE, "GateW": ONE, "SelectBias": OPT,
                 "ExpertGate": ONE, "ExpertUp": ONE, "ExpertDown": ONE},
-        outputs={"Out": ONE, "TopIdx": OPT, "ExpertLoad": OPT},
+        # H .. Inv: what the forward writes for the op's grad rule
+        outputs={"Out": ONE, "TopIdx": OPT, "ExpertLoad": OPT, "H": OPT,
+                 "U": OPT, "SortedW": OPT, "Order": OPT, "Inv": OPT},
         required_attrs=("top_k",),
         attr_types={"top_k": int, "routed_scaling": _NUM,
                     "norm_topk": bool, "experts_total": int,

@@ -893,15 +893,25 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
     out = helper.create_variable_for_type_inference(input.dtype)
     idx = helper.create_variable_for_type_inference("int64")
     load = helper.create_variable_for_type_inference("int32")
-    idx.stop_gradient = True
-    load.stop_gradient = True
+    # what the op's grad rule reads beside idx and load (ops/moe.py): the
+    # gate and up projections of the sorted rows, the slots' weights in
+    # sorted order, the sort and its inverse
+    h, u = (helper.create_variable_for_type_inference(expert_gate.dtype)
+            for _ in range(2))
+    sorted_w = helper.create_variable_for_type_inference("float32")
+    order, inv = (helper.create_variable_for_type_inference("int32")
+                  for _ in range(2))
+    for v in (idx, load, h, u, sorted_w, order, inv):
+        v.stop_gradient = True
     inputs = {"X": [input], "GateW": [gate_w], "ExpertGate": [expert_gate],
               "ExpertUp": [expert_up], "ExpertDown": [expert_down]}
     if select_bias is not None:
         inputs["SelectBias"] = [select_bias]
     helper.append_op(
         "routed_moe", inputs=inputs,
-        outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load]},
+        outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load],
+                 "H": [h], "U": [u], "SortedW": [sorted_w], "Order": [order],
+                 "Inv": [inv]},
         attrs={"top_k": int(top_k), "routed_scaling": float(routed_scaling),
                "norm_topk": bool(norm_topk),
                "experts_total": int(experts_total

@@ -194,62 +194,205 @@ def _switch_moe(ctx, ins, attrs):
 # experts it holds, it routes over all of them and computes its own part.
 # ---------------------------------------------------------------------------
 
-@jax.custom_vjp
-def _take_rows(x, src, back):
-    """x[src] for `src` a permutation, or the rows of x's k-fold repeat
-    (k copies of x stacked) permuted, with `back` the inverse permutation:
-    the transpose is again a gather (`back`), never a scatter-add."""
-    return x[src]
+# What `routed_moe`'s forward writes for its grad rule (beside `TopIdx` and
+# `ExpertLoad`, which a caller may fetch): the gate and up projections of
+# the sorted rows, [k*N, f] in the compute dtype, the slots' weights in
+# sorted order, and the sort with its inverse. Narrow each: never a
+# [k*N, d] buffer.
+_RESIDUALS = ("H", "U", "SortedW", "Order", "Inv", "TopIdx", "ExpertLoad")
+
+# dW of a grouped matmul: x [m, a] and g [m, b] contracted over the ragged
+# rows, group by group -> [E, a, b] (what JAX's transpose rule emits)
+_DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _take_rows_fwd(x, src, back):
-    return x[src], (back, x.shape[0])
+def _grouped(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=w.dtype)
 
 
-def _take_rows_bwd(res, g):
-    back, n = res
-    rows = g[back]
-    if rows.shape[0] != n:          # x was read k times: sum its k readers
-        rows = rows.reshape(rows.shape[0] // n, n, -1).sum(axis=0)
-    return rows, None, None
+def _grouped_dx(g, w, sizes):
+    return _grouped(g, jnp.swapaxes(w, 1, 2), sizes)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _grouped_dw(x, g, sizes):
+    return jax.lax.ragged_dot_general(x, g, sizes, _DW_DIMS,
+                                      preferred_element_type=g.dtype)
 
 
-def _held_experts(xt, w_slot, order, inv, valid, sizes, eg, eu, ed):
-    """sum_k w_k E_{i_k}(x) over the slots whose expert is held here.
+def _sum_slots(rows, k):
+    """[k*N, d] slot-major -> the float32 sum [N, d] of its k blocks, block
+    by block: no float32 value of the buffer's own size."""
+    n = rows.shape[0] // k
+    return sum(rows[j * n:(j + 1) * n].astype(jnp.float32) for j in range(k))
 
-    xt [N, d]; w_slot [k, N] (0 where the slot's expert is elsewhere);
-    order / inv: the permutation that sorts the k*N slots (slot-major:
-    slot j of token i is row j*N + i, so [k*N, d] splits into [k, N, d]
-    without a relayout) by held expert (foreign slots last) and its
-    inverse; valid [k*N]: sorted rows that belong to a held expert; sizes
-    [E_held], summing to k*N: the last group also takes the foreign slots'
-    rows, as zeros. So the buffers hold every assignment there can be and
-    the grouped matmuls run over all of them: what a step costs is fixed by
-    its shapes and not by the routing (a rank's k*N rows are also what its
-    experts see in the deployment, where the exchange fills them), and a
-    zero row yields a zero row, so nothing past the real groups needs a
-    mask but the gathered input."""
-    n, d = xt.shape
-    k = w_slot.shape[0]
-    cdt = eg.dtype
+
+def _whole_buffer(sizes, rows):
+    """sizes [E_held]: assignments on each held expert. The grouped matmuls
+    cover the whole k*N-row buffer: the last group also takes the foreign
+    slots' rows."""
+    return sizes.at[-1].add(rows - jnp.sum(sizes))
+
+
+def _weighted_act(h, u, w_sorted):
+    """silu(h) * u in float32, and the same times its slot's weight rounded
+    once to the compute dtype: the down projection's operand."""
+    act = jax.nn.silu(h.astype(jnp.float32)) * u.astype(jnp.float32)
+    return act, (act * w_sorted[:, None]).astype(h.dtype)
+
+
+def _experts_fwd(xt, w_sorted, order, inv, sizes, eg, eu, ed):
+    """sum_k w_k E_{i_k}(x) over the slots whose expert is held here, and
+    the residuals (h, u).
+
+    xt [N, d]; order / inv: the permutation that sorts the k*N slots
+    (slot-major: slot j of token i is row j*N + i) by held expert (foreign
+    slots last) and its inverse; w_sorted [k*N] float32: the slots'
+    weights in that order (0 where the slot's expert is elsewhere); sizes
+    [E_held]. The buffers hold every assignment there can be and the
+    grouped matmuls run over all of them, the foreign slots' rows as zeros
+    in the last group: what a step costs is fixed by its shapes and not by
+    the routing (a rank's k*N rows are also what its experts see in the
+    deployment, where the exchange fills them), and a zero row yields a
+    zero row, so nothing needs a mask but the gathered input. The slot's
+    weight goes in AHEAD of the down projection, w (a W) = (w a) W over f
+    columns, so the combine is a plain sum of the k slots."""
+    rows, n = order.shape[0], xt.shape[0]
+    padded = _whole_buffer(sizes, rows)
     with jax.named_scope("moe.dispatch"):
-        xs = _take_rows(xt.astype(cdt), order % n, inv)       # [k*N, d]
-        xs = jnp.where(valid[:, None], xs, 0)
+        valid = jnp.arange(rows) < jnp.sum(sizes)
+        xs = jnp.where(valid[:, None], xt.astype(eg.dtype)[order % n], 0)
     with jax.named_scope("moe.experts"):
-        h = jax.lax.ragged_dot(xs, eg, sizes, preferred_element_type=cdt)
-        u = jax.lax.ragged_dot(xs, eu, sizes, preferred_element_type=cdt)
-        a = (jax.nn.silu(h.astype(jnp.float32))
-             * u.astype(jnp.float32)).astype(cdt)
-        y = jax.lax.ragged_dot(a, ed, sizes, preferred_element_type=cdt)
+        h = _grouped(xs, eg, padded)
+        u = _grouped(xs, eu, padded)
+        _, wa = _weighted_act(h, u, w_sorted)
+        y = _grouped(wa, ed, padded)
     with jax.named_scope("moe.combine"):
-        y = _take_rows(y, inv, order).reshape(k, n, d)
-        return jnp.einsum("kn,knd->nd", w_slot, y.astype(jnp.float32))
+        return _sum_slots(y[inv], rows // n), h, u
 
 
-@register("routed_moe", nondiff_slots=("SelectBias",))
+def _experts_bwd(xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u, g):
+    """The transpose of `_experts_fwd` at g = d Out [N, d], on the h and u
+    it wrote: six grouped matmuls, none of the forward's again. No mask:
+    a foreign slot's weight is 0, so its rows of dh and du are. Returns the
+    gradients of (xt, w_sorted, eg, eu, ed)."""
+    rows, n = order.shape[0], xt.shape[0]
+    cdt = eg.dtype
+    # what is read here is read when the backward gets here: without the
+    # barrier XLA merges the gather of xs below with the forward's and
+    # keeps a [k*N, d] buffer a layer alive in between
+    g, xt, order, inv, h, u = jax.lax.optimization_barrier(
+        (g, xt, order, inv, h, u))
+    padded = _whole_buffer(sizes, rows)
+    with jax.named_scope("moe.combine"):
+        gs = g.astype(cdt)[order % n]                         # [k*N, d]
+    with jax.named_scope("moe.dispatch"):
+        xs = xt.astype(cdt)[order % n]
+    with jax.named_scope("moe.experts"):
+        act, wa = _weighted_act(h, u, w_sorted)
+        ded = _grouped_dw(wa, gs, padded)
+        dwa = _grouped_dx(gs, ed, padded).astype(jnp.float32)
+        dw_sorted = jnp.sum(dwa * act, axis=1)
+        dact = dwa * w_sorted[:, None]
+        hf = h.astype(jnp.float32)
+        sig = jax.nn.sigmoid(hf)
+        dh = (dact * u.astype(jnp.float32)
+              * sig * (1.0 + hf * (1.0 - sig))).astype(cdt)
+        du = (dact * hf * sig).astype(cdt)
+        deg = _grouped_dw(xs, dh, padded)
+        deu = _grouped_dw(xs, du, padded)
+        dxs = _grouped_dx(dh, eg, padded) + _grouped_dx(du, eu, padded)
+    with jax.named_scope("moe.dispatch"):
+        dxt = _sum_slots(dxs[inv], rows // n)
+    return dxt.astype(xt.dtype), dw_sorted, deg, deu, ded
+
+
+@jax.custom_vjp
+def _held_experts(xt, w_sorted, order, inv, sizes, eg, eu, ed):
+    return _experts_fwd(xt, w_sorted, order, inv, sizes, eg, eu, ed)
+
+
+def _held_experts_fwd(*args):
+    out, h, u = _experts_fwd(*args)
+    return (out, h, u), args + (h, u)
+
+
+def _held_experts_bwd(res, cts):
+    dxt, dw, deg, deu, ded = _experts_bwd(*res, cts[0])
+    return dxt, dw, None, None, None, deg, deu, ded
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def _geometry(ins, attrs):
+    wg = ins["GateW"][0]                    # [d, E_total]
+    e_total = int(attrs.get("experts_total", wg.shape[1]))
+    off = int(attrs.get("expert_offset", 0))
+    e_held = ins["ExpertGate"][0].shape[0]
+    if wg.shape[1] != e_total or off < 0 or off + e_held > e_total:
+        raise ValueError(
+            f"routed_moe: GateW routes over {wg.shape[1]} experts, "
+            f"experts_total={e_total}, held {off}..{off + e_held}")
+    return off, e_held
+
+
+def _scores(xt, wg):
+    return jax.nn.sigmoid(jnp.dot(
+        xt.astype(jnp.float32), wg.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))                # [N, E] f32
+
+
+def _slot_weights(scores, idx, local, attrs):
+    """[k, N] float32: the chosen experts' scores, normalised and scaled; 0
+    where the slot's expert is held elsewhere."""
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if attrs.get("norm_topk", True):
+        w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+    w = w * float(attrs.get("routed_scaling", 1.0))
+    return jnp.where(local, w, 0.0).T
+
+
+def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
+    """Grad rule: the backward on what the forward wrote (`_RESIDUALS`).
+    The experts' part is `_experts_bwd`; the router's (GateW, and x through
+    the scores) is the small weight function differentiated alone at the
+    fixed `TopIdx`. Declines when a residual is absent (a program built
+    before they existed), and the generic `__vjp__` differentiates the
+    forward lowering."""
+    g = (ogs.get("Out") or [None])[0]
+    if g is None or not all(outs.get(s) for s in _RESIDUALS):
+        return None
+    x, wg = ins["X"][0], ins["GateW"][0]
+    eg, eu, ed = (ins[s][0] for s in ("ExpertGate", "ExpertUp",
+                                      "ExpertDown"))
+    h, u, w_sorted, order, inv, idx, sizes = (outs[s][0]
+                                              for s in _RESIDUALS)
+    off, e_held = _geometry(ins, attrs)
+    xt = x.reshape(-1, x.shape[-1])
+    local = (idx >= off) & (idx < off + e_held)
+    dxt, dw_sorted, deg, deu, ded = _experts_bwd(
+        xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
+        g.reshape(xt.shape))
+    with jax.named_scope("moe.route"):
+        # back to slot order: sorted by the permutation itself, row j
+        # lands at order[j] (a sort, where a gather of k*N scalars by
+        # `inv` takes ten times as long on a TPU)
+        _, dw = jax.lax.sort((order, dw_sorted), num_keys=1)
+        _, route_vjp = jax.vjp(
+            lambda xt, wg: _slot_weights(_scores(xt, wg), idx, local, attrs),
+            xt, wg)
+        dxt_route, dwg = route_vjp(dw.reshape(idx.shape[1], xt.shape[0]))
+    if not ctx.is_eval_shape:
+        from ..observability import metrics
+        metrics.inc("moe.bwd_residual")
+    return {"X": [(dxt + dxt_route).reshape(x.shape)], "GateW": [dwg],
+            "ExpertGate": [deg], "ExpertUp": [deu], "ExpertDown": [ded]}
+
+
+@register("routed_moe", nondiff_slots=("SelectBias",),
+          grad=_routed_moe_grad, residual_slots=_RESIDUALS)
 def _routed_moe(ctx, ins, attrs):
     x = ins["X"][0]                         # [..., d]
     wg = ins["GateW"][0]                    # [d, E_total]
@@ -257,51 +400,39 @@ def _routed_moe(ctx, ins, attrs):
     eg, eu, ed = (ins[s][0] for s in ("ExpertGate", "ExpertUp",
                                       "ExpertDown"))  # [E_held, ...]
     top_k = int(attrs["top_k"])
-    e_total = int(attrs.get("experts_total", wg.shape[1]))
-    off = int(attrs.get("expert_offset", 0))
-    e_held = eg.shape[0]
-    if wg.shape[1] != e_total or off < 0 or off + e_held > e_total:
-        raise ValueError(
-            f"routed_moe: GateW routes over {wg.shape[1]} experts, "
-            f"experts_total={e_total}, held {off}..{off + e_held}")
+    off, e_held = _geometry(ins, attrs)
     d = x.shape[-1]
     xt = x.reshape(-1, d)
     n = xt.shape[0]
 
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
-            xt.astype(jnp.float32), wg.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))            # [N, E] f32
+        scores = _scores(xt, wg)
         sel = jax.lax.stop_gradient(scores)
         if bias is not None:
             sel = sel + bias.astype(jnp.float32)
         _, idx = jax.lax.top_k(sel, top_k)                   # [N, k]
-        w = jnp.take_along_axis(scores, idx, axis=1)
-        if attrs.get("norm_topk", True):
-            w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
-        w = w * float(attrs.get("routed_scaling", 1.0))
         local = (idx >= off) & (idx < off + e_held)
+        w_slot = _slot_weights(scores, idx, local, attrs)    # [k, N]
         eid = jnp.where(local, idx - off, e_held).T.reshape(-1)  # [k*N]
         sizes = jnp.sum(eid[:, None] == jnp.arange(e_held)[None, :],
                         axis=0, dtype=jnp.int32)             # [E_held]
-        order = jnp.argsort(eid, stable=True).astype(jnp.int32)
+        # the stable argsort of eid, with the slots' weights carried along
+        # as the sort's payload
+        slots = jnp.arange(n * top_k, dtype=jnp.int32)
+        _, order, w_sorted = jax.lax.sort(
+            (eid, slots, w_slot.reshape(-1)), num_keys=1, is_stable=True)
         inv = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
-            jnp.arange(n * top_k, dtype=jnp.int32), unique_indices=True)
-        held = jnp.sum(sizes)
-        valid = jnp.arange(n * top_k) < held
-        # the grouped matmuls cover the whole buffer: the foreign slots'
-        # rows, zeros, ride in the last group
-        padded = sizes.at[e_held - 1].add(n * top_k - held)
-        w_slot = jnp.where(local, w, 0.0).T                  # [k, N]
+            slots, unique_indices=True)
 
-    # keep nothing of the k*N-row buffers from forward to backward: they
-    # are sized for every assignment there can be, and recomputing two
-    # grouped matmuls is cheaper than holding them for every layer
-    out = jax.checkpoint(_held_experts)(xt, w_slot, order, inv, valid,
-                                        padded, eg, eu, ed)
-    if not ctx.is_eval_shape and not ctx.in_vjp:
+    out, h, u = _held_experts(xt, w_sorted, order, inv, sizes, eg, eu, ed)
+    if not ctx.is_eval_shape:
         from ..observability import metrics
-        metrics.inc("moe.layers_lowered")
+        # in_vjp: the generic __vjp__ lowers this forward again to
+        # differentiate it (the rule declined, or a whole segment is
+        # differentiated at once)
+        metrics.inc("moe.bwd_recomputed" if ctx.in_vjp
+                    else "moe.layers_lowered")
     return {"Out": [out.astype(eg.dtype).reshape(x.shape)],
             "TopIdx": [idx.astype(INT64_DEVICE_DTYPE)],
-            "ExpertLoad": [sizes]}
+            "ExpertLoad": [sizes], "H": [h], "U": [u],
+            "SortedW": [w_sorted], "Order": [order], "Inv": [inv]}

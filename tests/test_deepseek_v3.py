@@ -368,3 +368,164 @@ def test_builder_names_scopes_and_checkpoints_and_verifies():
     rules = ds.sharding_rules()
     assert tuple(rules.spec_for("l1_experts_up_w")) == ("ep",)
     assert tuple(rules.spec_for("l0_q_proj_w")) == (None, "tp")
+
+
+# ---------------------------------------------------------------------------
+# routed_moe's grad rule (ops/moe.py `_routed_moe_grad`): the backward on the
+# h, u and sort the forward wrote, six grouped matmuls and none of the
+# forward's again
+# ---------------------------------------------------------------------------
+
+_MOE_COUNTERS = ("moe.bwd_residual", "moe.bwd_recomputed")
+_RULE_ONLY_OUTPUTS = ("H", "U", "SortedW", "Order", "Inv")
+
+
+def _counter_rise(fn):
+    before = [metrics.get(n) for n in _MOE_COUNTERS]
+    out = fn()
+    return out, tuple(int(metrics.get(n) - b)
+                      for n, b in zip(_MOE_COUNTERS, before))
+
+
+def _withhold_residuals(program):
+    """Take the outputs only the grad rule reads off every `routed_moe`: a
+    program built before they existed."""
+    for op in program.global_block().ops:
+        if op.type == "routed_moe":
+            for slot in _RULE_ONLY_OUTPUTS:
+                op.outputs.pop(slot)
+
+
+def _share_gradients(x, params, cot, offset, held, total, top_k=3,
+                     withhold=False):
+    """d sum(Out * cot) / d (x, GateW, ExpertGate, ExpertUp, ExpertDown) of
+    one share's `routed_moe` through a Program, and the counters' rise
+    while its step was traced."""
+    reset_programs(0)
+    n, d = x.shape
+    xv = layers.data(name="x", shape=[d], dtype="float32")
+    cv = layers.data(name="cot", shape=[d], dtype="float32")
+    sl = slice(offset, offset + held)
+    arrays = {"gate_w": params["router_w"], "bias": params["router_bias"],
+              "eg": params["experts_gate_w"][sl],
+              "eu": params["experts_up_w"][sl],
+              "ed": params["experts_down_w"][sl]}
+    var = {k: layers.create_parameter(list(v.shape), "float32", name=k)
+           for k, v in arrays.items()}
+    out, _, _ = layers.routed_moe(
+        xv, var["gate_w"], var["eg"], var["eu"], var["ed"], top_k=top_k,
+        select_bias=var["bias"], routed_scaling=2.448, experts_total=total,
+        expert_offset=offset)
+    loss = layers.reduce_sum(layers.elementwise_mul(out, cv))
+    if withhold:
+        _withhold_residuals(fluid.default_main_program())
+    wrt = [xv] + [var[k] for k in ("gate_w", "eg", "eu", "ed")]
+    grads = fluid.gradients(loss, wrt)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    for k, v in arrays.items():
+        fluid.global_scope().set(k, jnp.asarray(v))
+    got, rise = _counter_rise(lambda: exe.run(
+        feed={"x": x, "cot": cot}, fetch_list=grads))
+    return [np.asarray(g) for g in got], rise
+
+
+def _reference_share_gradients(x, params, cot, offset, held, total, top_k=3):
+    cfg = dict(n_routed_experts=held, experts_total=total,
+               expert_offset=offset, num_experts_per_tok=top_k,
+               routed_scaling_factor=2.448)
+    sl = slice(offset, offset + held)
+
+    def loss(x, router_w, eg, eu, ed):
+        p = {"l_router_w": router_w,
+             "l_router_bias": jnp.asarray(params["router_bias"]),
+             "l_experts_gate_w": eg, "l_experts_up_w": eu,
+             "l_experts_down_w": ed}
+        out, _ = ref.routed_experts(x, p, "l_", cfg)
+        return jnp.sum(out * cot)
+
+    return [np.asarray(g) for g in jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
+        jnp.asarray(x), jnp.asarray(params["router_w"]),
+        *(jnp.asarray(params[f"experts_{n}_w"][sl])
+          for n in ("gate", "up", "down")))]
+
+
+_GRAD_NAMES = ("X", "GateW", "ExpertGate", "ExpertUp", "ExpertDown")
+
+
+@pytest.fixture(scope="module")
+def _share_case():
+    x, params, _ = _uncut_layer(seed=2, skew=5)
+    cot = np.random.RandomState(7).randn(*x.shape).astype(np.float32)
+    got, rise = _share_gradients(x, params, cot, 4, 4, 16)
+    want = _reference_share_gradients(x, params, cot, 4, 4, 16)
+    _, _, load = _layer_share(x, params, 4, 4, 16)
+    return got, rise, want, load, (x, params, cot)
+
+
+@pytest.mark.parametrize("leaf", range(5), ids=_GRAD_NAMES)
+def test_grad_rule_follows_the_reference_layer(leaf, _share_case):
+    """A share of 4 of 16 experts under a skewed selection: most slots are
+    foreign, the held experts' loads uneven; the rule's gradient of every
+    input is `jax.grad`'s of the plain float32 reference layer."""
+    got, rise, want, load, _ = _share_case
+    assert rise == (1, 0)
+    assert load.sum() < 0.5 * 96 * 3 and load.max() > 3 * max(load.min(), 1)
+    err = np.linalg.norm(got[leaf] - want[leaf]) / np.linalg.norm(want[leaf])
+    assert err < 2e-5, (_GRAD_NAMES[leaf], err)
+    assert np.linalg.norm(want[leaf]) > 0
+
+
+def test_generic_route_agrees_when_the_residuals_are_withheld(_share_case):
+    """A program whose `routed_moe` lacks the rule's outputs: the rule
+    declines, the generic `__vjp__` lowers the forward again, and its
+    gradients are the rule's (one algebra: `_experts_bwd`)."""
+    by_rule, _, _, _, inputs = _share_case
+    generic, rise = _share_gradients(*inputs, 4, 4, 16, withhold=True)
+    assert rise == (0, 1)
+    for name, a, b in zip(_GRAD_NAMES, by_rule, generic):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7, err_msg=name)
+
+
+def _census_trainer(withhold=False):
+    """A tiny AMP train step whose expert buffers' shapes are no other
+    value's: k*N = 256 rows of d = 64 (the vocabulary is not 256)."""
+    cfg = dict(CFG, vocab=320)
+    reset_programs(0)
+    _, loss, _ = ds.build_causal_lm_program(model_config(cfg))
+    if withhold:
+        _withhold_residuals(fluid.default_main_program())
+    fleet.init(is_collective=True)
+    strategy = fleet.DistributedStrategy()
+    strategy.amp = True
+    fleet.distributed_optimizer(
+        paddle.optimizer.Adam(learning_rate=1e-3), strategy).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    ids = np.random.RandomState(0).randint(0, 320, (2, B, S)).astype(np.int64)
+    return exe, loss, {"tokens": ids}
+
+
+def test_train_step_census_nine_grouped_matmuls_a_layer():
+    """The step's jaxpr holds 9 `ragged_dot_general` an expert layer (3
+    forward, 6 backward; 12 with the forward's three repeated), nothing
+    under `jax.checkpoint`, and no float32 value of the `[k, N, d]` /
+    `[k*N, d]` buffers' size, forward or backward: the combine weight goes
+    in ahead of the down projection, over f columns."""
+    expert_layers = CFG["layers"] - CFG["first_k_dense_replace"]
+    k, n, d = CFG["num_experts_per_tok"], B * S, CFG["hidden_size"]
+    exe, loss, feed = _census_trainer()
+    jaxpr, rise = _counter_rise(lambda: str(exe.step_jaxpr(feed, [loss],
+                                                           k=2)))
+    assert rise == (expert_layers, 0)
+    assert jaxpr.count("ragged_dot_general") == 9 * expert_layers
+    assert "checkpoint" not in jaxpr and "remat" not in jaxpr
+    assert f"bf16[{k * n},{d}]" in jaxpr
+    for wide in (f"f32[{k},{n},{d}]", f"f32[{k * n},{d}]"):
+        assert wide not in jaxpr, wide
+    # the generic route on the same model: the forward's three again
+    exe, loss, feed = _census_trainer(withhold=True)
+    jaxpr, rise = _counter_rise(lambda: str(exe.step_jaxpr(feed, [loss],
+                                                           k=2)))
+    assert rise == (0, expert_layers)
+    assert jaxpr.count("ragged_dot_general") == 12 * expert_layers
