@@ -59,6 +59,10 @@ FULL = {
     # the sequence the benchmark's cell runs (the dkdv kernel's raised VMEM
     # limit); 8 heads so that the dense side's [1, 8, 4096, 4096] fits
     "latent": {"flash_shape": (1, 8, 4096, 192), "v_width": 128},
+    # a group of 8 query heads on one KV head, causal, with and without a
+    # window of 1024, as a sparse LM's sliding and full layers run them
+    "grouped": {"flash_shape": (1, 8, 4096, 128), "kv_heads": 1,
+                "window": 1024},
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -76,6 +80,7 @@ TINY = {
     "train": {"seq": 16, "batch": 8},
     "long": {"seq": 32, "batch": 8, "flash_shape": (1, 1, 128, 64)},
     "latent": {"flash_shape": (1, 1, 128, 192), "v_width": 128},
+    "grouped": {"flash_shape": (1, 2, 128, 64), "kv_heads": 1, "window": 48},
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -281,24 +286,30 @@ def mosaic_calls(hlo_text):
     return counts
 
 
-def flash_vs_dense(shape, v_width=None, causal=False):
+def flash_vs_dense(shape, v_width=None, causal=False, kv_heads=None,
+                   window=None):
     """Op-level check: flash_attention forward and jax.grad against the
     dense XLA attention (ops/attention._xla_attention) in bf16; `v_width`
-    gives v (and the output) another width than q and k."""
+    gives v (and the output) another width than q and k, `kv_heads` gives k
+    and v fewer heads than q, `window` (with `causal`) a sliding window."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops.attention import _xla_attention
+    from paddle_tpu.ops.attention import _causal_bias, _xla_attention
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
     rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.randn(*shape[:3], width), jnp.bfloat16)
-               for width in (shape[3], shape[3], v_width or shape[3]))
+    q, k, v = (jnp.asarray(rng.randn(shape[0], heads, shape[2], width),
+                           jnp.bfloat16)
+               for heads, width in ((shape[1], shape[3]),
+                                    (kv_heads or shape[1], shape[3]),
+                                    (kv_heads or shape[1],
+                                     v_width or shape[3])))
     scale = 1.0 / np.sqrt(shape[-1])
-    mask = (jnp.triu(jnp.full((shape[2], shape[2]), -1e9, jnp.float32),
-                     1)[None, None] if causal else None)
+    mask = _causal_bias(shape[2], window) if causal else None
 
     def flash_loss(q, k, v):
-        out = flash_attention(q, k, v, scale=scale, causal=causal)
+        out = flash_attention(q, k, v, scale=scale, causal=causal,
+                              window=window)
         return jnp.sum(out.astype(jnp.float32) ** 2), out
 
     def dense_loss(q, k, v):
@@ -355,6 +366,18 @@ def leg_attention_two_widths(preset, clock):
     attention), causal, against the dense route."""
     return flash_vs_dense(preset["latent"]["flash_shape"],
                           v_width=preset["latent"]["v_width"], causal=True)
+
+
+def leg_attention_window_grouped(preset, clock):
+    """The three flash kernels where a group of query heads shares a KV
+    head (K and V at the KV heads' count in HBM, dK and dV summed over the
+    group inside the dkdv kernel), causal, with a sliding window and
+    without, against the dense route."""
+    grouped = preset["grouped"]
+    return {name: flash_vs_dense(grouped["flash_shape"], causal=True,
+                                 kv_heads=grouped["kv_heads"], window=window)
+            for name, window in (("window", grouped["window"]),
+                                 ("full", None))}
 
 
 # ---------------------------------------------------------------------------
@@ -797,6 +820,7 @@ def leg_four_chips(preset, clock):
 LEGS = (("train_bert_base_s128", leg_train_s128),
         ("train_bert_base_s1024_flash", leg_train_s1024_flash),
         ("attention_two_widths", leg_attention_two_widths),
+        ("attention_window_grouped", leg_attention_window_grouped),
         ("serve_gpt2_small", leg_serve),
         ("kernels", leg_kernels),
         ("four_chips", leg_four_chips))

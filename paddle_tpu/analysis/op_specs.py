@@ -289,7 +289,8 @@ SPECS: Dict[str, OpSpec] = {
         inputs={"Q": ONE, "K": ONE, "V": ONE, "Mask": OPT},
         outputs={"Out": ONE, "Lse": OPT},
         attr_types={"scale": _NUM, "dropout": _NUM, "causal": bool,
-                    "sequence_parallel": bool, "sp_mode": str},
+                    "sequence_parallel": bool, "sp_mode": str,
+                    "window": int},
         sharding="attention"),
     "switch_moe": OpSpec(
         inputs={"X": ONE, "GateW": ONE, "ExpertW1": ONE, "ExpertB1": OPT,
@@ -308,14 +309,17 @@ SPECS: Dict[str, OpSpec] = {
         required_attrs=("top_k",),
         attr_types={"top_k": int, "routed_scaling": _NUM,
                     "norm_topk": bool, "experts_total": int,
-                    "expert_offset": int},
+                    "expert_offset": int, "scoring": str},
         sharding="moe"),
     "rms_norm": OpSpec(
         inputs={"X": ONE, "Scale": OPT}, outputs={"Y": ONE},
         attr_types={"epsilon": _NUM}, sharding="follow_x"),
     "rotary_embedding": OpSpec(
         inputs={"X": ONE}, outputs={"Out": ONE},
-        attr_types={"theta": _NUM, "rotary_dim": int},
+        attr_types={"theta": _NUM, "rotary_dim": int, "layout": str,
+                    "rope_type": str, "factor": _NUM,
+                    "original_max_position": int, "beta_fast": _NUM,
+                    "beta_slow": _NUM, "scale": _NUM},
         sharding="follow_x"),
     "swiglu": OpSpec(
         inputs={"Gate": ONE, "Up": ONE}, outputs={"Out": ONE},

@@ -807,9 +807,12 @@ def pow(x, factor=1.0, name=None):
 
 def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
                     causal=False, name=None, sequence_parallel=False,
-                    sp_mode="ring"):
+                    sp_mode="ring", window=None):
     """Fused multi-head attention on [B, nh, S, hd] tensors (reference
-    fused/multihead_matmul_op.cu); pallas flash kernel on TPU. With
+    fused/multihead_matmul_op.cu); pallas flash kernel on TPU. `k` and `v`
+    may carry fewer heads, [B, nkv, S, hd] with nkv dividing nh: query head
+    h attends KV head h // (nh / nkv). With `causal`, `window` w lets a
+    query at position i see keys i-w+1..i only. With
     sequence_parallel=True the op runs ring attention (sp_mode="ring") or
     Ulysses all-to-all (sp_mode="ulysses") over the mesh's sp axis — the
     long-context path the reference lacks (parallel/ring_attention.py)."""
@@ -827,6 +830,11 @@ def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
              "sp_mode": sp_mode}
     if scale is not None:
         attrs["scale"] = scale
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError(f"fused_attention: window={window} needs "
+                             "causal=True and window >= 1")
+        attrs["window"] = int(window)
     helper.append_op("fused_attention", inputs=inputs,
                      outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
     return out
@@ -876,10 +884,11 @@ def switch_moe(input, num_experts, d_ff, capacity_factor=1.25, name=None,
 
 def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
                select_bias=None, routed_scaling=1.0, norm_topk=True,
-               experts_total=None, expert_offset=0):
+               experts_total=None, expert_offset=0, scoring="sigmoid"):
     """The routed part of a sparse decoder LM's expert layer (DeepSeek-V3
-    family; ops/moe.py routed_moe): sigmoid scores in float32 over ALL
-    `experts_total` experts (`gate_w` [d, experts_total]), the top_k of
+    family; ops/moe.py routed_moe): scores in float32 over ALL
+    `experts_total` experts (`gate_w` [d, experts_total]), `scoring`
+    "sigmoid" of each logit or "softmax" over all of them, the top_k of
     scores + `select_bias` (a buffer no gradient reaches), weights = the
     scores at those indices, normalised to sum 1 (`norm_topk`) and times
     `routed_scaling`. No capacity: no token is dropped. The caller passes
@@ -907,17 +916,17 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
               "ExpertUp": [expert_up], "ExpertDown": [expert_down]}
     if select_bias is not None:
         inputs["SelectBias"] = [select_bias]
+    attrs = {"top_k": int(top_k), "routed_scaling": float(routed_scaling),
+             "norm_topk": bool(norm_topk),
+             "experts_total": int(experts_total if experts_total is not None
+                                  else gate_w.shape[1]),
+             "expert_offset": int(expert_offset), "scoring": scoring}
     helper.append_op(
         "routed_moe", inputs=inputs,
         outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load],
                  "H": [h], "U": [u], "SortedW": [sorted_w], "Order": [order],
                  "Inv": [inv]},
-        attrs={"top_k": int(top_k), "routed_scaling": float(routed_scaling),
-               "norm_topk": bool(norm_topk),
-               "experts_total": int(experts_total
-                                    if experts_total is not None
-                                    else gate_w.shape[1]),
-               "expert_offset": int(expert_offset)})
+        attrs=attrs)
     return out, idx, load
 
 
@@ -934,16 +943,27 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
     return y
 
 
-def rotary_embedding(x, theta=10000.0, rotary_dim=None):
+def rotary_embedding(x, theta=10000.0, rotary_dim=None, layout="interleaved",
+                     rope_type="default", factor=1.0, original_max_position=0,
+                     beta_fast=32.0, beta_slow=1.0, scale=1.0):
     """Rotary positions on x [..., S, D]: the last `rotary_dim` features
-    (default all) turn by position along axis -2, over interleaved pairs
-    (2i, 2i+1); the rest passes through."""
+    (default all) turn by position along axis -2; the rest passes through.
+    `layout`: pairs "interleaved" (2j, 2j+1) or "half" (j, j + rotary_dim/2).
+    `rope_type` names the frequency rule: "default", theta^(-2j/rotary_dim),
+    or "yarn", which blends it with the same divided by `factor` over a ramp
+    set by `original_max_position`, `beta_fast`, `beta_slow`
+    (ops/llm_ops.py rotary_frequencies). cos and sin are multiplied by
+    `scale` (yarn's attention factor)."""
     helper = LayerHelper("rotary_embedding")
     out = helper.create_variable_for_type_inference(x.dtype)
+    attrs = {"theta": float(theta),
+             "rotary_dim": int(rotary_dim or x.shape[-1]), "layout": layout,
+             "rope_type": rope_type, "factor": float(factor),
+             "original_max_position": int(original_max_position),
+             "beta_fast": float(beta_fast), "beta_slow": float(beta_slow),
+             "scale": float(scale)}
     helper.append_op("rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]},
-                     attrs={"theta": float(theta),
-                            "rotary_dim": int(rotary_dim or x.shape[-1])})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -8,6 +8,10 @@ attention, TP rules) -> gpt.py, and SE-ResNeXt 50/101/152 (the reference's
 canonical dist-test model, grouped convs + squeeze-excitation)
 -> se_resnext.py, and a DeepSeek-V3-family sparse causal LM (latent
 attention, sigmoid-routed experts without drops, shared experts, one
-expert-parallel rank's share) -> deepseek_v3.py
+expert-parallel rank's share) -> deepseek_v3.py, and a Mellum-2-family
+sparse causal LM (sliding-window and full attention layers in a period,
+grouped KV heads, yarn on the full layers, softmax-routed experts)
+-> mellum.py
 """
-from . import lenet, resnet, bert, wide_deep, gpt, se_resnext, deepseek_v3
+from . import (lenet, resnet, bert, wide_deep, gpt, se_resnext, deepseek_v3,
+               mellum)

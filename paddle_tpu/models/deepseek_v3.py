@@ -168,38 +168,47 @@ def decoder_layer(x, cfg: DeepseekV3Config, n: int):
     return layers.elementwise_add(x, y), (idx, load)
 
 
+def embed_tokens(cfg):
+    """(tokens [B, seq_len] int64, their embeddings [B, seq_len, hidden]),
+    the lookup a gather of the rows held."""
+    s, h = cfg.seq_len, cfg.hidden_size
+    tokens = layers.data(name="tokens", shape=[s], dtype="int64")
+    embed = layers.create_parameter([cfg.vocab_size, h], "float32",
+                                    attr=_w("embed_tokens", cfg))
+    return tokens, layers.reshape(
+        layers.gather(embed, layers.reshape(tokens, [-1])), [-1, s, h])
+
+
+def next_token_loss(x, tokens, cfg):
+    """Final norm, untied head over the vocabulary held, and the mean cross
+    entropy of every position but a row's last against the token that
+    follows it. All `seq_len` positions go through the head (the last one's
+    label is the ignore index), so no shape in the step is `seq_len - 1`."""
+    s = cfg.seq_len
+    x = _norm(x, "final_norm_scale", cfg)
+    logits = _linear(x, cfg.vocab_size, "lm_head_w", cfg)
+    nxt = layers.slice(tokens, [1], [1], [s])
+    none = layers.fill_constant_batch_size_like(nxt, [-1, 1], "int64", -100)
+    labels = layers.unsqueeze(layers.concat([nxt, none], axis=1), [2])
+    ce = layers.softmax_with_cross_entropy(logits, labels, ignore_index=-100)
+    return layers.scale(layers.mean(ce), scale=s / (s - 1.0))
+
+
 def build_causal_lm_program(cfg: DeepseekV3Config):
-    """Next-token objective over `tokens` [B, seq_len]: every position but
-    a row's last is labelled with the token that follows it; the loss is
-    the mean of those positions' cross entropies. All `seq_len` positions
-    go through the head (the last one's label is the ignore index), so no
-    shape in the step is `seq_len - 1`.
+    """Next-token objective over `tokens` [B, seq_len] (`next_token_loss`).
 
     Returns (tokens, loss, routed): `routed` holds, per expert layer, the
     `(top_idx, expert_load)` variables a caller may fetch beside the loss
     (`expert_load` [experts held]: the assignments that fell on each)."""
     with RecordEvent("program.build", args={"model": "deepseek_v3"}):
-        s, h = cfg.seq_len, cfg.hidden_size
-        tokens = layers.data(name="tokens", shape=[s], dtype="int64")
-        embed = layers.create_parameter([cfg.vocab_size, h], "float32",
-                                        attr=_w("embed_tokens", cfg))
-        x = layers.reshape(
-            layers.gather(embed, layers.reshape(tokens, [-1])), [-1, s, h])
+        tokens, x = embed_tokens(cfg)
         ckpts, routed = [], []
         for n in range(cfg.num_hidden_layers):
             x, r = decoder_layer(x, cfg, n)
             ckpts.append(x.name)
             if r is not None:
                 routed.append(r)
-        x = _norm(x, "final_norm_scale", cfg)
-        logits = _linear(x, cfg.vocab_size, "lm_head_w", cfg)
-        nxt = layers.slice(tokens, [1], [1], [s])
-        none = layers.fill_constant_batch_size_like(nxt, [-1, 1], "int64",
-                                                    -100)
-        labels = layers.unsqueeze(layers.concat([nxt, none], axis=1), [2])
-        ce = layers.softmax_with_cross_entropy(logits, labels,
-                                               ignore_index=-100)
-        loss = layers.scale(layers.mean(ce), scale=s / (s - 1.0))
+        loss = next_token_loss(x, tokens, cfg)
         loss._layer_checkpoints = ckpts
         return tokens, loss, routed
 

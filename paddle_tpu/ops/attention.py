@@ -20,6 +20,16 @@ AMP the flash kernels, the head relayouts around them and the residuals
 what the program built. No lowering here casts them again: `Out` has the
 operands' dtype, every dot accumulates float32, and softmax, logsumexp,
 the dropout hash, `Mask` and `Lse` are float32 on every route.
+
+Grouped KV heads and a window. `K` and `V` may carry fewer heads than `Q`,
+[B, nkv, S, hd] with nkv dividing nh: query head h attends KV head
+h // (nh / nkv), read from the operands' shapes. With `causal`, the attr
+`window` w lets a query at position i see keys i-w+1..i. The flash route
+hands both to the kernels (K and V stay at nkv heads in HBM, dK and dV
+leave at nkv heads); the dense route computes the same mathematics over a
+[B, nkv, group, S, S] score tensor; the ring / Ulysses routes know one KV
+head per query head and no window: K and V are repeated there
+(`attention.flash_kv_expanded`), a window raises.
 """
 from __future__ import annotations
 
@@ -36,9 +46,19 @@ _OPERAND_TAG = {"bfloat16": "bf16", "float32": "f32"}
 
 
 def _xla_attention(q, k, v, mask, scale, dropout, key):
-    # q,k,v: [B, nh, S, hd]
-    scores = jnp.einsum("bnqd,bnkd->bnqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
+    # q: [B, nh, S, hd]; k, v: [B, nkv, S, hd], nkv dividing nh. Where
+    # `group` query heads share a KV head the group is an axis of q and of
+    # the scores, and K and V are read as they are
+    b, nh, s, _ = q.shape
+    nkv = k.shape[1]
+    if nkv != nh:
+        scores = jnp.einsum("bngqd,bnkd->bngqk",
+                            q.reshape(b, nkv, nh // nkv, s, -1), k,
+                            preferred_element_type=jnp.float32) * scale
+        scores = scores.reshape(b, nh, s, s)
+    else:
+        scores = jnp.einsum("bnqd,bnkd->bnqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
     if mask is not None:
         scores = scores + mask.astype(scores.dtype)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -47,7 +67,21 @@ def _xla_attention(q, k, v, mask, scale, dropout, key):
         keep = fast_keep_mask(key, 1.0 - dropout, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout), 0.0)
     probs = probs.astype(v.dtype)
+    if nkv != nh:
+        return jnp.einsum("bngqk,bnkd->bngqd",
+                          probs.reshape(b, nkv, nh // nkv, s, s),
+                          v).reshape(b, nh, s, v.shape[-1])
     return jnp.einsum("bnqk,bnkd->bnqd", probs, v)
+
+
+def _causal_bias(s, window):
+    """Additive [1, 1, S, S] bias of a causal layer: -1e9 above the
+    diagonal and, with a window, `window` or more below it."""
+    tri = jnp.triu(jnp.full((s, s), -1e9, jnp.float32), 1)
+    if window is not None:
+        pos = jnp.arange(s)
+        tri = jnp.where(pos[:, None] - pos[None, :] >= window, -1e9, tri)
+    return tri[None, None]
 
 
 def _derive_seed(key):
@@ -99,6 +133,10 @@ def _route(ctx, q, v, mask, attrs):
         mesh = _current_mesh()
         if mesh is not None and "sp" in mesh.axis_names \
                 and mesh.shape["sp"] > 1:
+            if attrs.get("window"):
+                raise NotImplementedError(
+                    "fused_attention: the ring / Ulysses routes have no "
+                    "window")
             from ..parallel.ring_attention import (ring_attention,
                                                    ulysses_attention)
             fn = (ulysses_attention
@@ -120,6 +158,12 @@ def _unpack(ins, attrs):
     if attrs.get("is_test", False):
         dropout = 0.0
     return q, k, v, mask, scale, dropout, attrs.get("causal", False)
+
+
+def _window(attrs):
+    """The attr `window`, absent where the layer has none (the layer
+    function holds it to `causal`)."""
+    return int(attrs.get("window") or 0) or None
 
 
 def _no_lse(q):
@@ -156,7 +200,7 @@ def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
         dq, dk, dv = flash_attention_bwd(
             q, k, v, out, lse.reshape(b * nh, s), dout.astype(out.dtype),
             scale=scale, causal=causal, dropout=dropout, seed=seed,
-            mask=mask)
+            mask=mask, window=_window(attrs))
     except Exception as e:
         raise _flash_failed(e, q, mask, causal, dropout) from e
     metrics.inc("attention.flash_bwd_residual")
@@ -170,7 +214,12 @@ def _fused_attention(ctx, ins, attrs):
     key = ctx.op_key(attrs) if dropout else None
     b, nh, s, _ = q.shape
     route, sp_fn = _route(ctx, q, v, mask, attrs)
+    window = _window(attrs)
     if route == "sp":
+        if k.shape[1] != nh:
+            from ..observability import metrics
+            metrics.inc("attention.flash_kv_expanded")
+            k, v = (jnp.repeat(t, nh // t.shape[1], axis=1) for t in (k, v))
         sp_seed = _derive_seed(key) if dropout else None
         # key-padding masks + in-body counter dropout ride the ring
         # (round 4; full [S, S] masks still raise — see _check_mask)
@@ -184,19 +233,23 @@ def _fused_attention(ctx, ins, attrs):
         try:
             out, lse = flash_attention(q, k, v, scale=scale, causal=causal,
                                        dropout=dropout, seed=seed, mask=mask,
-                                       return_lse=True)
+                                       return_lse=True, window=window)
         except Exception as e:
             raise _flash_failed(e, q, mask, causal, dropout) from e
         from ..observability import metrics
         metrics.inc("attention.flash_operands_"
                     + _OPERAND_TAG.get(q.dtype.name, q.dtype.name))
+        metrics.inc("attention.flash_window" if window
+                    else "attention.flash_full")
+        if k.shape[1] != nh:
+            metrics.inc("attention.flash_kv_grouped")
         if ctx.in_vjp:
             # the generic __vjp__ (a whole segment under recompute or layer
             # scan) lowers this forward a second time to differentiate it
             metrics.inc("attention.flash_bwd_recomputed")
         return {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}
     if causal:
-        tri = jnp.triu(jnp.full((s, s), -1e9, jnp.float32), 1)[None, None]
+        tri = _causal_bias(s, window)
         mask = tri if mask is None else mask + tri
     return {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)],
             "Lse": [_no_lse(q)]}

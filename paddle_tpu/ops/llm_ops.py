@@ -1,5 +1,6 @@
-"""Ops of the decoder LMs after 2020: RMS norm, rotary positions on
-interleaved pairs, the gated (SwiGLU) product.
+"""Ops of the decoder LMs after 2020: RMS norm, rotary positions (pairs
+interleaved or half-split, frequencies by the default rule or yarn's), the
+gated (SwiGLU) product.
 
 Each is one plain `jax.numpy` lowering that XLA fuses with its
 neighbours; gradients come from the generic `__vjp__`. Under AMP the norm
@@ -8,8 +9,11 @@ given and compute in float32 inside (amp/auto_cast.py).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import register
 
@@ -49,12 +53,79 @@ def rotary_interleaved(x, theta: float, rotary_dim: int):
     return jnp.concatenate([x[..., :d - rotary_dim], out], axis=-1)
 
 
+def rotary_frequencies(theta: float, rotary_dim: int, rope_type="default",
+                       factor=1.0, original_max_position=0, beta_fast=32.0,
+                       beta_slow=1.0):
+    """f_j, j < rotary_dim / 2: position p turns pair j by p * f_j. A static
+    float64 table (numpy), made from attrs at trace time.
+
+    "default": f_j = theta^(-2j / rotary_dim) (Su et al. 2021).
+    "yarn" (Peng et al. 2023, NTK-by-parts): pairs that turn more than
+    `beta_fast` times over `original_max_position` positions keep f_j, pairs
+    that turn less than `beta_slow` times get f_j / factor, a linear ramp in
+    j between: with c(r) = rotary_dim ln(original / (2 pi r)) / (2 ln theta),
+    low = floor(c(beta_fast)), high = ceil(c(beta_slow)), both clamped to
+    0..rotary_dim - 1, ramp_j = clip((j - low) / (high - low), 0, 1),
+    f_j = (1 - ramp_j) theta^(-2j/rotary_dim)
+          + ramp_j theta^(-2j/rotary_dim) / factor."""
+    j = np.arange(rotary_dim // 2, dtype=np.float64)
+    freq = theta ** (-2.0 * j / rotary_dim)
+    if rope_type == "default":
+        return freq
+    if rope_type != "yarn":
+        raise ValueError(f"rotary_embedding: unknown rope_type {rope_type!r}")
+
+    def turns_at(r):
+        return rotary_dim * math.log(original_max_position
+                                     / (2 * math.pi * r)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), rotary_dim - 1)
+    ramp = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 - ramp) * freq + ramp * freq / factor
+
+
+def rotary_half(x, freq, rotary_dim: int, scale: float):
+    """Rotate the LAST `rotary_dim` features of x [..., S, D] over the
+    half-split pairs (j, j + rotary_dim / 2) by pos * freq[j], cos and sin
+    times `scale`."""
+    s, d = x.shape[-2], x.shape[-1]
+    half = rotary_dim // 2
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * jnp.asarray(
+        freq, jnp.float32)[None]
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale    # [S, half]
+    rot = x[..., d - rotary_dim:].astype(jnp.float32)
+    a, b = rot[..., :half], rot[..., half:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                          axis=-1).astype(x.dtype)
+    if rotary_dim == d:
+        return out
+    return jnp.concatenate([x[..., :d - rotary_dim], out], axis=-1)
+
+
 @register("rotary_embedding")
 def _rotary_embedding(ctx, ins, attrs):
     x = ins["X"][0]
-    return {"Out": [rotary_interleaved(
-        x, float(attrs.get("theta", 10000.0)),
-        int(attrs.get("rotary_dim", x.shape[-1])))]}
+    theta = float(attrs.get("theta", 10000.0))
+    rotary_dim = int(attrs.get("rotary_dim", x.shape[-1]))
+    layout = attrs.get("layout", "interleaved")
+    rope_type = attrs.get("rope_type", "default")
+    scale = float(attrs.get("scale", 1.0))
+    if layout == "interleaved":
+        if rope_type != "default" or scale != 1.0:
+            raise ValueError(
+                f"rotary_embedding: rope_type {rope_type!r} or a scale "
+                "needs layout \"half\"")
+        return {"Out": [rotary_interleaved(x, theta, rotary_dim)]}
+    if layout != "half":
+        raise ValueError(f"rotary_embedding: unknown layout {layout!r}")
+    freq = rotary_frequencies(
+        theta, rotary_dim, rope_type, float(attrs.get("factor", 1.0)),
+        int(attrs.get("original_max_position", 0)),
+        float(attrs.get("beta_fast", 32.0)),
+        float(attrs.get("beta_slow", 1.0)))
+    return {"Out": [rotary_half(x, freq, rotary_dim, scale)]}
 
 
 @register("swiglu")

@@ -188,8 +188,9 @@ def _switch_moe(ctx, ins, attrs):
 
 # ---------------------------------------------------------------------------
 # routed_moe: the expert layer as sparse decoder LMs deploy it (DeepSeek-V3
-# family): sigmoid scores, a selection bias no gradient reaches, top-k of
-# ALL experts, normalised and scaled weights, no capacity and no drops,
+# family): sigmoid scores (or a softmax over all the experts), a selection
+# bias no gradient reaches (or none), top-k of ALL experts, normalised and
+# scaled weights, no capacity and no drops,
 # gated experts, and the share of one expert-parallel rank: told which
 # experts it holds, it routes over all of them and computes its own part.
 # ---------------------------------------------------------------------------
@@ -338,10 +339,16 @@ def _geometry(ins, attrs):
     return off, e_held
 
 
-def _scores(xt, wg):
-    return jax.nn.sigmoid(jnp.dot(
-        xt.astype(jnp.float32), wg.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))                # [N, E] f32
+def _scores(xt, wg, scoring="sigmoid"):
+    """[N, E] float32 over ALL the experts: "sigmoid" of each logit, or
+    "softmax" over the experts."""
+    logits = jnp.dot(xt.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    if scoring == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    raise ValueError(f"routed_moe: unknown scoring {scoring!r}")
 
 
 def _slot_weights(scores, idx, local, attrs):
@@ -381,8 +388,9 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
         # `inv` takes ten times as long on a TPU)
         _, dw = jax.lax.sort((order, dw_sorted), num_keys=1)
         _, route_vjp = jax.vjp(
-            lambda xt, wg: _slot_weights(_scores(xt, wg), idx, local, attrs),
-            xt, wg)
+            lambda xt, wg: _slot_weights(
+                _scores(xt, wg, attrs.get("scoring", "sigmoid")), idx, local,
+                attrs), xt, wg)
         dxt_route, dwg = route_vjp(dw.reshape(idx.shape[1], xt.shape[0]))
     if not ctx.is_eval_shape:
         from ..observability import metrics
@@ -406,7 +414,7 @@ def _routed_moe(ctx, ins, attrs):
     n = xt.shape[0]
 
     with jax.named_scope("moe.route"):
-        scores = _scores(xt, wg)
+        scores = _scores(xt, wg, attrs.get("scoring", "sigmoid"))
         sel = jax.lax.stop_gradient(scores)
         if bias is not None:
             sel = sel + bias.astype(jnp.float32)

@@ -32,6 +32,16 @@ expanded per head or per query row.
 Layout: [B, nh, S, hd]; grid (batch*heads, blocks); the non-gridded operand
 is fully resident per head — fine up to S~8k at hd 64-128 in 16MB VMEM;
 longer sequences use the ring path in parallel/ring_attention.py.
+
+Grouped KV heads: k and v may be [B, nkv, S, hd] with nkv dividing nh.
+They stay at nkv heads in HBM; a query head reaches its KV head through
+the BlockSpec's index map (h -> h // group), and the dkdv kernel's grid
+gets a group axis that sums the group's query heads into one float32
+dK / dV, resident for the KV head. A causal `window` w (a query at i sees
+keys i-w+1..i) bounds the loops of all three kernels from both sides, so a
+windowed layer visits the blocks its band meets and no others. With
+nkv == nh and no window the three kernels trace to what they did before
+either existed.
 """
 from __future__ import annotations
 
@@ -127,8 +137,10 @@ def _mask_bytes(mask) -> int:
     return mask.shape[1] * mask.shape[2] * mask.dtype.itemsize
 
 
-def _compiler_params(resident_bytes: int):
-    """Both grid axes parallel. The operands resident per head (K and V
+def _compiler_params(resident_bytes: int,
+                     semantics=("parallel", "parallel")):
+    """Both grid axes parallel (the grouped dkdv kernel's two inner axes
+    accumulate and are "arbitrary"). The operands resident per head (K and V
     of a q-grid kernel; Q, dO, O and the lane-broadcast lse of the k-grid
     one) are double-buffered; where they outgrow Mosaic's default 16 MiB of
     scoped VMEM (S = 4096 at 192/128 wide: 16.3 MiB in the dkdv kernel)
@@ -138,8 +150,7 @@ def _compiler_params(resident_bytes: int):
     extra = {}
     if need > (16 << 20):
         extra["vmem_limit_bytes"] = min(need, 100 << 20)
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel"), **extra)
+    return pltpu.CompilerParams(dimension_semantics=semantics, **extra)
 
 
 # mask_mode: how the (batch*head) grid index maps to the mask's leading dim.
@@ -166,8 +177,32 @@ def _mask_block(mask_ref, q_start, block_q, k_start, block_k):
     return mask_ref[pl.ds(q_start, block_q), cols]     # [block_q, block_k]
 
 
+def _visible(q_pos, k_pos, window):
+    """Causal visibility of key positions from query positions; with a
+    window, the last `window` keys up to the query's own."""
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = seen & (q_pos - k_pos < window)
+    return seen
+
+
+def _first_k_block(q_idx, block_q, block_k, window):
+    """First k block that q block `q_idx` sees."""
+    if window is None:
+        return 0
+    return jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
+
+
+def _kv_index(group):
+    """Index map of K / V under a (batch*query heads, q_block) grid: the
+    `group` query heads of a KV head read the same block."""
+    if group == 1:
+        return lambda h, i: (h, 0, 0)
+    return lambda h, i: (h // group, 0, 0)
+
+
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
-                      dropout, block_k, seq_len, has_mask):
+                      dropout, block_k, seq_len, has_mask, window=None):
     # q_ref: [block_q, hd]; k_ref: [S, hd]; v_ref: [S, hd_v];
     # o_ref: [block_q, hd_v]; hd_v may differ from hd (latent attention:
     # q and k 192 wide, v and the output 128)
@@ -208,7 +243,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+            s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         # guard -inf rows (fully-masked): exp(-inf - -inf) -> use safe sub
@@ -238,7 +273,9 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
                                (last + block_k - 1) // block_k)
     else:
         n_blocks = num_k_blocks
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(
+        _first_k_block(q_idx, block_q, block_k, window), n_blocks, body,
+        (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)
     o_ref[:] = out.astype(o_ref.dtype)
     lse = jnp.where(jnp.isfinite(m), m + jnp.log(jnp.maximum(l, 1e-30)),
@@ -264,23 +301,24 @@ def _mask_spec_kgrid(mask, bk, mask_mode, nh):
 
 
 def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
-               mask_mode):
+               mask_mode, window=None):
     b, nh, s, hd = q.shape
-    hdv = v.shape[-1]
+    nkv, hdv = k.shape[1], v.shape[-1]
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     q3 = q.reshape(b * nh, s, hd)
-    k3 = k.reshape(b * nh, s, hd)
-    v3 = v.reshape(b * nh, s, hdv)
+    k3 = k.reshape(b * nkv, s, hd)
+    v3 = v.reshape(b * nkv, s, hdv)
     has_mask = mask is not None
     kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                                dropout=dropout, block_k=bk, seq_len=s,
-                               has_mask=has_mask)
+                               has_mask=has_mask, window=window)
+    kv_index = _kv_index(nh // nkv)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
-        pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
-        pl.BlockSpec((None, s, hdv), lambda h, i: (h, 0, 0)),
+        pl.BlockSpec((None, s, hd), kv_index),
+        pl.BlockSpec((None, s, hdv), kv_index),
     ]
     operands = [seed, q3, k3, v3]
     if has_mask:
@@ -309,7 +347,7 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
 
 def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                          lse_ref, *rest, scale, causal, dropout, block_k,
-                         seq_len, has_mask):
+                         seq_len, has_mask, window=None):
     # q: [block_q, hd]; do/o: [block_q, hd_v]; k: [S, hd]; v: [S, hd_v];
     # lse: [block_q, 128]
     if has_mask:
@@ -346,7 +384,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+            s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -367,17 +405,23 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                                (last + block_k - 1) // block_k)
     else:
         n_blocks = num_k_blocks
-    dq = jax.lax.fori_loop(0, n_blocks, body,
-                           jnp.zeros((block_q, hd), jnp.float32))
+    dq = jax.lax.fori_loop(
+        _first_k_block(q_idx, block_q, block_k, window), n_blocks, body,
+        jnp.zeros((block_q, hd), jnp.float32))
     dq_ref[:] = dq.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                            lse_ref, *rest, scale, causal, dropout, block_q,
-                           seq_len, has_mask):
+                           seq_len, has_mask, window=None, group=1):
     # k: [block_k, hd]; v: [block_k, hd_v]; q: [S, hd]; do/o: [S, hd_v];
     # lse: [S, 128]
     # mask_ref (if present): [1 or S, block_k] — this k block's columns
+    # group == 1: grid (batch*heads, k_block), dk/dv: this k block's rows.
+    # group > 1: grid (batch*kv heads, group, k_block); q, do, o, lse are
+    # ONE query head's and stay resident while its k blocks go by; dk/dv
+    # are the KV head's whole [S, hd] in float32, resident over the two
+    # inner axes, and take the sum over the group's query heads.
     if has_mask:
         mask_ref, dk_ref, dv_ref = rest
     else:
@@ -385,8 +429,13 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
         mask_ref = None
     block_k = k_ref.shape[0]
     hd = k_ref.shape[1]
-    head = pl.program_id(0)
-    k_idx = pl.program_id(1)
+    if group == 1:
+        head = pl.program_id(0)
+        k_idx = pl.program_id(1)
+    else:
+        member = pl.program_id(1)
+        head = pl.program_id(0) * group + member
+        k_idx = pl.program_id(2)
     # MXU operands keep the input dtype (bf16 under AMP), f32 accumulate
     k = k_ref[:]
     v = v_ref[:]
@@ -413,7 +462,7 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+            s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
         if dropout > 0.0:
             keep = _keep_mask(seed_ref[0], head, qb * block_q,
@@ -441,35 +490,57 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
         start = (k_idx * block_k) // block_q
     else:
         start = 0
+    if window is None:
+        stop = num_q_blocks
+    else:
+        # the last query that sees this block's last key is window - 1 on
+        last_q = (k_idx + 1) * block_k - 1 + (window - 1)
+        stop = jnp.minimum(num_q_blocks, last_q // block_q + 1)
     dk, dv = jax.lax.fori_loop(
-        start, num_q_blocks, body,
+        start, stop, body,
         (jnp.zeros((block_k, hd), jnp.float32),
          jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[:] = dk.astype(dk_ref.dtype)
+        dv_ref[:] = dv.astype(dv_ref.dtype)
+        return
+    rows = pl.ds(pl.multiple_of(k_idx * block_k, block_k), block_k)
+
+    @pl.when(member == 0)
+    def _():
+        dk_ref[rows, :] = dk
+        dv_ref[rows, :] = dv
+
+    @pl.when(member > 0)
+    def _():
+        dk_ref[rows, :] += dk
+        dv_ref[rows, :] += dv
 
 
 def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
-               block_q, block_k, mask_mode):
+               block_q, block_k, mask_mode, window=None):
     b, nh, s, hd = q.shape
-    hdv = v.shape[-1]
+    nkv, hdv = k.shape[1], v.shape[-1]
+    group = nh // nkv
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     q3 = q.reshape(b * nh, s, hd)
-    k3 = k.reshape(b * nh, s, hd)
-    v3 = v.reshape(b * nh, s, hdv)
+    k3 = k.reshape(b * nkv, s, hd)
+    v3 = v.reshape(b * nkv, s, hdv)
     o3 = o.reshape(b * nh, s, hdv)
     do3 = do.reshape(b * nh, s, hdv)
     has_mask = mask is not None
+    kv_index = _kv_index(group)
 
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, scale=scale,
                                   causal=causal, dropout=dropout,
-                                  block_k=bk, seq_len=s, has_mask=has_mask)
+                                  block_k=bk, seq_len=s, has_mask=has_mask,
+                                  window=window)
     dq_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
-        pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
-        pl.BlockSpec((None, s, hdv), lambda h, i: (h, 0, 0)),
+        pl.BlockSpec((None, s, hd), kv_index),
+        pl.BlockSpec((None, s, hdv), kv_index),
         pl.BlockSpec((None, bq, hdv), lambda h, i: (h, i, 0)),
         pl.BlockSpec((None, bq, hdv), lambda h, i: (h, i, 0)),
         pl.BlockSpec((None, bq, _LANES), lambda h, i: (h, i, 0)),
@@ -493,7 +564,8 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
 
     dkdv_kernel = functools.partial(_flash_bwd_dkdv_kernel, scale=scale,
                                     causal=causal, dropout=dropout,
-                                    block_q=bq, seq_len=s, has_mask=has_mask)
+                                    block_q=bq, seq_len=s, has_mask=has_mask,
+                                    window=window)
     dkdv_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
@@ -507,48 +579,87 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
     if has_mask:
         dkdv_specs.append(_mask_spec_kgrid(mask, bk, mask_mode, nh))
         dkdv_operands.append(mask)
-    dk, dv = pl.pallas_call(
-        dkdv_kernel,
-        grid=(b * nh, s // bk),
-        in_specs=dkdv_specs,
-        out_specs=[
-            pl.BlockSpec((None, bk, hd), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((None, bk, hdv), lambda h, i: (h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * nh, s, hd), k.dtype),
-            jax.ShapeDtypeStruct((b * nh, s, hdv), v.dtype),
-        ],
-        compiler_params=_compiler_params(
-            s * (_lanes(hd) + 2 * _lanes(hdv)) * q.dtype.itemsize
-            + s * _LANES * 4 + _mask_bytes(mask)),
-        interpret=interpret_mode(),
-        name="flash_attention_bwd_dkdv",
-    )(*dkdv_operands)
+    resident = (s * (_lanes(hd) + 2 * _lanes(hdv)) * q.dtype.itemsize
+                + s * _LANES * 4 + _mask_bytes(mask))
+    if group == 1:
+        dk, dv = pl.pallas_call(
+            dkdv_kernel,
+            grid=(b * nh, s // bk),
+            in_specs=dkdv_specs,
+            out_specs=[
+                pl.BlockSpec((None, bk, hd), lambda h, i: (h, i, 0)),
+                pl.BlockSpec((None, bk, hdv), lambda h, i: (h, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * nh, s, hd), k.dtype),
+                jax.ShapeDtypeStruct((b * nh, s, hdv), v.dtype),
+            ],
+            compiler_params=_compiler_params(resident),
+            interpret=interpret_mode(),
+            name="flash_attention_bwd_dkdv",
+        )(*dkdv_operands)
+    else:
+        # grid (batch*kv heads, group, k_block): the group's query heads in
+        # turn, each one's q, do, o, lse (and mask) resident while its k
+        # blocks go by; k and v follow the KV head; the float32 sums stay in
+        # VMEM until the KV head changes
+        def regrid(spec, head):
+            if spec.index_map is None:          # the seed, in SMEM
+                return spec
+            return pl.BlockSpec(spec.block_shape, lambda hk, g, j: (
+                spec.index_map(head(hk, g), j)))
 
-    return (dq.reshape(b, nh, s, hd), dk.reshape(b, nh, s, hd),
-            dv.reshape(b, nh, s, hdv))
+        def kv_head(hk, g):
+            return hk
+
+        def query_head(hk, g):
+            return hk * group + g
+
+        dk, dv = pl.pallas_call(
+            functools.partial(dkdv_kernel, group=group),
+            grid=(b * nkv, group, s // bk),
+            in_specs=[regrid(spec, kv_head if n in (2, 3) else query_head)
+                      for n, spec in enumerate(dkdv_specs)],
+            out_specs=[
+                pl.BlockSpec((None, s, hd), lambda hk, g, j: (hk, 0, 0)),
+                pl.BlockSpec((None, s, hdv), lambda hk, g, j: (hk, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * nkv, s, hd), jnp.float32),
+                jax.ShapeDtypeStruct((b * nkv, s, hdv), jnp.float32),
+            ],
+            compiler_params=_compiler_params(
+                resident + s * (_lanes(hd) + _lanes(hdv)) * 4,
+                ("parallel", "arbitrary", "arbitrary")),
+            interpret=interpret_mode(),
+            name="flash_attention_bwd_dkdv",
+        )(*dkdv_operands)
+        dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
+
+    return (dq.reshape(b, nh, s, hd), dk.reshape(b, nkv, s, hd),
+            dv.reshape(b, nkv, s, hdv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
-           mask_mode):
+           mask_mode, window):
     return _flash_fwd(q, k, v, seed, mask, scale, causal, dropout,
-                      block_q, block_k, mask_mode)
+                      block_q, block_k, mask_mode, window)
 
 
 def _fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
-         mask_mode):
+         mask_mode, window):
     out, lse = _flash_fwd(q, k, v, seed, mask, scale, causal, dropout,
-                          block_q, block_k, mask_mode)
+                          block_q, block_k, mask_mode, window)
     return (out, lse), (q, k, v, seed, mask, out, lse)
 
 
-def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, res, cts):
+def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, window, res,
+         cts):
     q, k, v, seed, mask, o, lse = res
     do, _ = cts     # lse is a residual, not a result: its cotangent is unused
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal,
-                            dropout, block_q, block_k, mask_mode)
+                            dropout, block_q, block_k, mask_mode, window)
     import numpy as np
     dseed = np.zeros(seed.shape, dtype=jax.dtypes.float0)
     # the op registry declares Mask nondiff (ops/attention.py nondiff_slots);
@@ -585,8 +696,17 @@ def _normalize_mask(mask, b, nh, s):
     return mask.reshape(b * nh, mq, s), "bh"
 
 
-def _kernel_args(q, k, v, scale, dropout, seed, mask):
+def _kernel_args(q, k, v, scale, dropout, seed, mask, causal, window):
     """(scale, seed, mask, mask_mode) as all three kernels take them."""
+    if k.shape[:3] != v.shape[:3] or q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"flash_attention: q{tuple(q.shape)} needs k and v of equal "
+            f"head counts that divide its own, got k{tuple(k.shape)} "
+            f"v{tuple(v.shape)}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"flash_attention: window={window} needs causal=True and "
+            f"window >= 1")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if dropout > 0.0 and seed is None:
@@ -607,9 +727,13 @@ def _kernel_args(q, k, v, scale, dropout, seed, mask):
 
 def flash_attention(q, k, v, scale=None, causal=False,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    dropout=0.0, seed=None, mask=None, return_lse=False):
-    """Tiled attention; `dropout` drops post-softmax probs with an in-kernel
-    counter-based mask keyed on `seed` (traced int32 scalar/array ok);
+                    dropout=0.0, seed=None, mask=None, return_lse=False,
+                    window=None):
+    """Tiled attention of q [B, nh, S, hd] over k, v [B, nkv, S, hd], nkv
+    dividing nh (query head h reads KV head h // (nh / nkv)); with `causal`
+    a `window` w lets a query at i see keys i-w+1..i only. `dropout` drops
+    post-softmax probs with an in-kernel counter-based mask keyed on `seed`
+    (traced int32 scalar/array ok);
     `mask` is an additive bias broadcastable to [B, nh, S(or 1), S] applied
     to the scaled scores inside all three kernels. With `return_lse` the
     result is (out, lse): lse is the forward kernel's per-row logsumexp,
@@ -618,26 +742,26 @@ def flash_attention(q, k, v, scale=None, causal=False,
     value); what is kept from the forward to the backward is one of them.
     Differentiated by JAX, the forward kernel runs inside the vjp's forward
     pass."""
-    scale, seed, mask, mask_mode = _kernel_args(q, k, v, scale, dropout,
-                                                seed, mask)
+    scale, seed, mask, mask_mode = _kernel_args(
+        q, k, v, scale, dropout, seed, mask, causal, window)
     out, lse = _flash(q, k, v, seed, mask, scale, causal, float(dropout),
-                      block_q, block_k, mask_mode)
+                      block_q, block_k, mask_mode, window)
     return (out, lse[:, :, 0]) if return_lse else out
 
 
 def flash_attention_bwd(q, k, v, out, lse, dout, scale=None, causal=False,
                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                        dropout=0.0, seed=None, mask=None):
+                        dropout=0.0, seed=None, mask=None, window=None):
     """(dq, dk, dv) from the residuals a forward launch already wrote: the
     two backward kernels alone, with the arguments `flash_attention` took.
     What `jax.vjp(flash_attention)` computes after running the forward
     kernel for `out` and `lse` itself."""
-    scale, seed, mask, mask_mode = _kernel_args(q, k, v, scale, dropout,
-                                                seed, mask)
+    scale, seed, mask, mask_mode = _kernel_args(
+        q, k, v, scale, dropout, seed, mask, causal, window)
     # widen lse next to the kernels and not before: the barrier makes the
     # compact values wait for dout, or XLA hoists this cheap broadcast to
     # the forward and keeps all [B*nh, S, 128] copies alive until here
     lse, dout = jax.lax.optimization_barrier((lse, dout))
     lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_LANES,))
     return _flash_bwd(q, k, v, out, lse, dout, seed, mask, scale, causal,
-                      float(dropout), block_q, block_k, mask_mode)
+                      float(dropout), block_q, block_k, mask_mode, window)
