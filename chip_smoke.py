@@ -63,6 +63,10 @@ FULL = {
     # window of 1024, as a sparse LM's sliding and full layers run them
     "grouped": {"flash_shape": (1, 8, 4096, 128), "kv_heads": 1,
                 "window": 1024},
+    # an expert layer's grouped matmuls at widths that are odd multiples of
+    # 128: rows x d x f over 16 experts (scripts/grouped_matmul_sweep.py
+    # runs the same function at the benchmark's sizes, tile by tile)
+    "experts": {"rows": 8192, "d": 2304, "f": 896, "experts": 16},
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -81,6 +85,7 @@ TINY = {
     "long": {"seq": 32, "batch": 8, "flash_shape": (1, 1, 128, 64)},
     "latent": {"flash_shape": (1, 1, 128, 192), "v_width": 128},
     "grouped": {"flash_shape": (1, 2, 128, 64), "kv_heads": 1, "window": 48},
+    "experts": {"rows": 96, "d": 384, "f": 128, "experts": 4},
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -95,6 +100,7 @@ TINY = {
 # stated tolerances (CHANGES.md records what the chip actually showed)
 FLASH_FWD_TOL = 2e-2      # bf16 flash vs dense XLA attention, max |diff|
 FLASH_GRAD_TOL = 5e-2     # relative Frobenius error per gradient
+GROUPED_TOL = 2e-2        # bf16 grouped matmul vs ragged_dot, over max |ref|
 DP_LOSS_TOL = 2e-2        # |loss(dp=N) - loss(one device)| per step
 NEAR_TIE = 0.05           # score gap (logit units) a bf16 rounding may decide
 NEAR_TIE_F32 = 0.01       # ... and a float32 one
@@ -751,6 +757,92 @@ def leg_kernels(preset, clock):
 
 
 # ---------------------------------------------------------------------------
+# the expert layer's grouped matmuls
+# ---------------------------------------------------------------------------
+def grouped_matmul_forms(rows, d, f, experts, retile=None, time_xla=True,
+                         launches=10, seed=5):
+    """The three forms of `ops/pallas/grouped_matmul.py` in both
+    orientations an expert layer runs them (`[rows, d] x [E, d, f]`: gate /
+    up forward, dx through down, dW of gate / up; `[rows, f] x [E, f, d]`:
+    down forward, dx through gate / up, dW of down) in bf16 against
+    `jax.lax.ragged_dot` / `ragged_dot_general` on the same operands: the
+    gap over the largest reference value, and the host-clock milliseconds
+    a launch of each (a time only on a chip). Uneven groups with an empty
+    one, the last swollen to the buffer's end. `retile(form, tiles)` may
+    replace the tiles the shape rule gives: the sweep's handle, which
+    times `ragged_dot` once (`time_xla`) and the kernel tile by tile."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    rng = np.random.RandomState(seed)
+    sizes = rng.multinomial(rows // 3, rng.dirichlet(np.ones(experts)))
+    sizes[1] = 0
+    sizes[-1] += rows - sizes.sum()
+    sizes = jnp.asarray(sizes, jnp.int32)
+
+    def operand(*shape):
+        return jnp.asarray(rng.randn(*shape) * 0.1, jnp.bfloat16)
+
+    def ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(launches):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return round(1e3 * (time.perf_counter() - t0) / launches, 4)
+
+    facts = {}
+    for k, n in ((d, f), (f, d)):
+        x, g, w = operand(rows, k), operand(rows, n), operand(experts, k, n)
+        wt = jnp.swapaxes(w, 1, 2) + 0          # [E, n, k], its own buffer
+        forms = {
+            "gmm": (gm.gmm_tiles, (x, w), {},
+                    lambda x, w: jax.lax.ragged_dot(
+                        x, w, sizes, preferred_element_type=w.dtype)),
+            "gmm-t": (gm.gmm_tiles, (x, wt), {"transpose_rhs": True},
+                      lambda x, wt: jax.lax.ragged_dot(
+                          x, jnp.swapaxes(wt, 1, 2), sizes,
+                          preferred_element_type=wt.dtype)),
+            "tgmm": (gm.tgmm_tiles, (x, g), {},
+                     lambda x, g: jax.lax.ragged_dot_general(
+                         x, g, sizes, moe._DW_DIMS,
+                         preferred_element_type=g.dtype)),
+        }
+        for form, (rule, args, kw, xla) in forms.items():
+            tiles = rule(rows, k, n)
+            check(tiles is not None, f"{form} [{rows},{k}]x[{k},{n}]: the "
+                  "kernel does not take the shape")
+            if retile is not None:
+                tiles = retile(form, tiles)
+            call = gm.tgmm if form == "tgmm" else gm.gmm
+            kernel = jax.jit(lambda a, b: call(
+                a, b, gm.group_visits(sizes, rows, tiles.tm), tiles=tiles,
+                **kw))
+            xla = jax.jit(xla)
+            row = _parity(kernel(*args), xla(*args))
+            check(row["max_abs_diff"] <= GROUPED_TOL * row["max_abs_ref"],
+                  f"{form} {k}x{n}: {row} exceeds {GROUPED_TOL}")
+            row.update(tiles=list(tiles), ms_kernel=ms(kernel, *args))
+            if time_xla:
+                row["ms_xla"] = ms(xla, *args)
+            facts[f"{form}.{k}x{n}"] = row
+    return facts
+
+
+def leg_grouped_matmul(preset, clock):
+    ep = preset["experts"]
+    facts = grouped_matmul_forms(ep["rows"], ep["d"], ep["f"], ep["experts"])
+    if preset["expect_mosaic"]:
+        for name, row in facts.items():
+            print(f"[chip_smoke] grouped_matmul {name}: tiles {row['tiles']} "
+                  f"{row['ms_kernel']} ms, ragged_dot {row['ms_xla']} ms, "
+                  f"gap {row['max_abs_diff']:.4g}", flush=True)
+    return facts
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 def leg_four_chips(preset, clock):
@@ -823,6 +915,7 @@ LEGS = (("train_bert_base_s128", leg_train_s128),
         ("attention_window_grouped", leg_attention_window_grouped),
         ("serve_gpt2_small", leg_serve),
         ("kernels", leg_kernels),
+        ("grouped_matmul", leg_grouped_matmul),
         ("four_chips", leg_four_chips))
 
 

@@ -29,6 +29,8 @@ trainer to add.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -209,17 +211,61 @@ _DW_DIMS = jax.lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _grouped(x, w, sizes):
-    return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=w.dtype)
+class _RowGroups:
+    """The groups of one sorted row buffer, for every grouped matmul a
+    layer runs over it in one direction: the sizes over the WHOLE buffer
+    (`_whole_buffer`) and, made once per row tile, the Pallas kernels' visit
+    tables. A matmul goes to `ops/pallas/grouped_matmul.py` where that
+    file's tile rule takes the operands' shapes (widths that are multiples
+    of 128) and to `jax.lax.ragged_dot` where it returns None; `count`:
+    whether this trace's calls count, `moe.grouped_pallas` /
+    `moe.grouped_xla`, once per grouped matmul lowered."""
+
+    def __init__(self, sizes, rows, count):
+        self.sizes, self.rows, self.count = (_whole_buffer(sizes, rows),
+                                             rows, count)
+        self._visits = {}
+
+    def plan(self, rule, x, k, n, out_dtype):
+        """(the rule's tiles for x [rows, k] against n columns, the visit
+        tables under their row tile), or (None, None)."""
+        tiles = rule(self.rows, k, n, x.dtype.itemsize,
+                     jnp.dtype(out_dtype).itemsize)
+        if self.count:
+            from ..observability import metrics
+            metrics.inc("moe.grouped_xla" if tiles is None
+                        else "moe.grouped_pallas")
+        if tiles is None:
+            return None, None
+        if tiles.tm not in self._visits:
+            from .pallas.grouped_matmul import group_visits
+            self._visits[tiles.tm] = group_visits(self.sizes, self.rows,
+                                                  tiles.tm)
+        return tiles, self._visits[tiles.tm]
 
 
-def _grouped_dx(g, w, sizes):
-    return _grouped(g, jnp.swapaxes(w, 1, 2), sizes)
+def _grouped(x, w, groups, transposed=False):
+    """x [m, K] times its group's w[e]: w [E, K, N], or (`transposed`: the
+    dx of a grouped matmul) w [E, N, K] read as its transpose."""
+    from .pallas import grouped_matmul as kernels
+    k, n = (w.shape[2], w.shape[1]) if transposed else w.shape[1:]
+    tiles, visits = groups.plan(kernels.gmm_tiles, x, k, n, w.dtype)
+    if tiles is None:
+        return jax.lax.ragged_dot(
+            x, jnp.swapaxes(w, 1, 2) if transposed else w, groups.sizes,
+            preferred_element_type=w.dtype)
+    return kernels.gmm(x, w, visits, tiles=tiles, transpose_rhs=transposed,
+                       out_dtype=w.dtype)
 
 
-def _grouped_dw(x, g, sizes):
-    return jax.lax.ragged_dot_general(x, g, sizes, _DW_DIMS,
-                                      preferred_element_type=g.dtype)
+def _grouped_dw(x, g, groups):
+    from .pallas import grouped_matmul as kernels
+    tiles, visits = groups.plan(kernels.tgmm_tiles, x, x.shape[1],
+                                g.shape[1], g.dtype)
+    if tiles is None:
+        return jax.lax.ragged_dot_general(x, g, groups.sizes, _DW_DIMS,
+                                          preferred_element_type=g.dtype)
+    return kernels.tgmm(x, g, visits, tiles=tiles, out_dtype=g.dtype)
 
 
 def _sum_slots(rows, k):
@@ -243,7 +289,7 @@ def _weighted_act(h, u, w_sorted):
     return act, (act * w_sorted[:, None]).astype(h.dtype)
 
 
-def _experts_fwd(xt, w_sorted, order, inv, sizes, eg, eu, ed):
+def _experts_fwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed):
     """sum_k w_k E_{i_k}(x) over the slots whose expert is held here, and
     the residuals (h, u).
 
@@ -258,22 +304,24 @@ def _experts_fwd(xt, w_sorted, order, inv, sizes, eg, eu, ed):
     deployment, where the exchange fills them), and a zero row yields a
     zero row, so nothing needs a mask but the gathered input. The slot's
     weight goes in AHEAD of the down projection, w (a W) = (w a) W over f
-    columns, so the combine is a plain sum of the k slots."""
+    columns, so the combine is a plain sum of the k slots. `count`: this
+    trace's grouped matmuls count (`_RowGroups`)."""
     rows, n = order.shape[0], xt.shape[0]
-    padded = _whole_buffer(sizes, rows)
+    groups = _RowGroups(sizes, rows, count)
     with jax.named_scope("moe.dispatch"):
         valid = jnp.arange(rows) < jnp.sum(sizes)
         xs = jnp.where(valid[:, None], xt.astype(eg.dtype)[order % n], 0)
     with jax.named_scope("moe.experts"):
-        h = _grouped(xs, eg, padded)
-        u = _grouped(xs, eu, padded)
+        h = _grouped(xs, eg, groups)
+        u = _grouped(xs, eu, groups)
         _, wa = _weighted_act(h, u, w_sorted)
-        y = _grouped(wa, ed, padded)
+        y = _grouped(wa, ed, groups)
     with jax.named_scope("moe.combine"):
         return _sum_slots(y[inv], rows // n), h, u
 
 
-def _experts_bwd(xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u, g):
+def _experts_bwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
+                 g):
     """The transpose of `_experts_fwd` at g = d Out [N, d], on the h and u
     it wrote: six grouped matmuls, none of the forward's again. No mask:
     a foreign slot's weight is 0, so its rows of dh and du are. Returns the
@@ -285,15 +333,15 @@ def _experts_bwd(xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u, g):
     # keeps a [k*N, d] buffer a layer alive in between
     g, xt, order, inv, h, u = jax.lax.optimization_barrier(
         (g, xt, order, inv, h, u))
-    padded = _whole_buffer(sizes, rows)
+    groups = _RowGroups(sizes, rows, count)
     with jax.named_scope("moe.combine"):
         gs = g.astype(cdt)[order % n]                         # [k*N, d]
     with jax.named_scope("moe.dispatch"):
         xs = xt.astype(cdt)[order % n]
     with jax.named_scope("moe.experts"):
         act, wa = _weighted_act(h, u, w_sorted)
-        ded = _grouped_dw(wa, gs, padded)
-        dwa = _grouped_dx(gs, ed, padded).astype(jnp.float32)
+        ded = _grouped_dw(wa, gs, groups)
+        dwa = _grouped(gs, ed, groups, transposed=True).astype(jnp.float32)
         dw_sorted = jnp.sum(dwa * act, axis=1)
         dact = dwa * w_sorted[:, None]
         hf = h.astype(jnp.float32)
@@ -301,26 +349,27 @@ def _experts_bwd(xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u, g):
         dh = (dact * u.astype(jnp.float32)
               * sig * (1.0 + hf * (1.0 - sig))).astype(cdt)
         du = (dact * hf * sig).astype(cdt)
-        deg = _grouped_dw(xs, dh, padded)
-        deu = _grouped_dw(xs, du, padded)
-        dxs = _grouped_dx(dh, eg, padded) + _grouped_dx(du, eu, padded)
+        deg = _grouped_dw(xs, dh, groups)
+        deu = _grouped_dw(xs, du, groups)
+        dxs = (_grouped(dh, eg, groups, transposed=True)
+               + _grouped(du, eu, groups, transposed=True))
     with jax.named_scope("moe.dispatch"):
         dxt = _sum_slots(dxs[inv], rows // n)
     return dxt.astype(xt.dtype), dw_sorted, deg, deu, ded
 
 
-@jax.custom_vjp
-def _held_experts(xt, w_sorted, order, inv, sizes, eg, eu, ed):
-    return _experts_fwd(xt, w_sorted, order, inv, sizes, eg, eu, ed)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(count, xt, w_sorted, order, inv, sizes, eg, eu, ed):
+    return _experts_fwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed)
 
 
-def _held_experts_fwd(*args):
-    out, h, u = _experts_fwd(*args)
+def _held_experts_fwd(count, *args):
+    out, h, u = _experts_fwd(count, *args)
     return (out, h, u), args + (h, u)
 
 
-def _held_experts_bwd(res, cts):
-    dxt, dw, deg, deu, ded = _experts_bwd(*res, cts[0])
+def _held_experts_bwd(count, res, cts):
+    dxt, dw, deg, deu, ded = _experts_bwd(count, *res, cts[0])
     return dxt, dw, None, None, None, deg, deu, ded
 
 
@@ -380,8 +429,8 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
     xt = x.reshape(-1, x.shape[-1])
     local = (idx >= off) & (idx < off + e_held)
     dxt, dw_sorted, deg, deu, ded = _experts_bwd(
-        xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
-        g.reshape(xt.shape))
+        not ctx.is_eval_shape, xt, w_sorted, order, inv, sizes, eg, eu, ed,
+        h, u, g.reshape(xt.shape))
     with jax.named_scope("moe.route"):
         # back to slot order: sorted by the permutation itself, row j
         # lands at order[j] (a sort, where a gather of k*N scalars by
@@ -432,7 +481,8 @@ def _routed_moe(ctx, ins, attrs):
         inv = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
             slots, unique_indices=True)
 
-    out, h, u = _held_experts(xt, w_sorted, order, inv, sizes, eg, eu, ed)
+    out, h, u = _held_experts(not ctx.is_eval_shape, xt, w_sorted, order,
+                              inv, sizes, eg, eu, ed)
     if not ctx.is_eval_shape:
         from ..observability import metrics
         # in_vjp: the generic __vjp__ lowers this forward again to

@@ -3,8 +3,13 @@ and 128): the three kernels under the Pallas interpreter against the dense
 route, forward and through the op's grad rule, causal; and the same
 kernels compiled for a described v5e at the size the benchmark's cell runs
 them (S = 4096, where the dkdv kernel needs more than Mosaic's default
-16 MiB of scoped VMEM).
+16 MiB of scoped VMEM). This file holds EVERY compile for a described
+chip (one worker loads the TPU's library): the expert layer's grouped
+matmuls (`ops/pallas/grouped_matmul.py`; tests/test_grouped_matmul.py has
+their numerics) at both sparse cells' sizes are at its end.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -14,8 +19,9 @@ import jax.numpy as jnp
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.observability import metrics
-from paddle_tpu.ops import attention
+from paddle_tpu.ops import attention, moe
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import grouped_matmul as gm
 from paddle_tpu.testing import reset_programs
 
 B, NH, DQK, DV = 1, 2, 192, 128
@@ -113,3 +119,63 @@ def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
                    "flash_attention_bwd_dkdv"):
         assert text.count(f'{kernel}"') or text.count(kernel), kernel
     assert text.count("tpu_custom_call") >= 3
+
+
+# rows a layer, d, f of the benchmark's two sparse cells; 16 held experts
+_EXPERT_SHAPES = {"mellum2_12b_ep4_s8192": (65536, 2304, 896),
+                  "kanana2_30b_a3b_ep8_s4096": (49152, 2048, 768)}
+
+
+@pytest.mark.parametrize("cell", _EXPERT_SHAPES)
+def test_an_expert_layers_nine_grouped_matmuls_compile_for_a_v5e(
+        v5e, cell, monkeypatch):
+    """`ops/moe.py`'s forward and backward bodies at a cell's shapes in
+    bf16: 3 + 6 grouped matmuls, every one a Mosaic kernel whose
+    instruction name holds `ragged-dot` (what the benchmark's readers find
+    them by) under the `moe.experts` scope, each within the VMEM its tiles
+    state, and no copy or transpose of a weight operand in front of the
+    dx forms."""
+    monkeypatch.setattr(gm, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    rows, d, f = _EXPERT_SHAPES[cell]
+    n, e = 8192, 16
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(xt, w_sorted, order, inv, sizes, eg, eu, ed, g):
+        out, h, u = moe._experts_fwd(True, xt, w_sorted, order, inv, sizes,
+                                     eg, eu, ed)
+        return out, moe._experts_bwd(True, xt, w_sorted, order, inv, sizes,
+                                     eg, eu, ed, h, u, g)
+
+    args = (sd((n, d)), sd((rows,), jnp.float32), sd((rows,), jnp.int32),
+            sd((rows,), jnp.int32), sd((e,), jnp.int32), sd((e, d, f)),
+            sd((e, d, f)), sd((e, f, d)), sd((n, d)))
+    counters = ("moe.grouped_pallas", "moe.grouped_xla")
+    before = [metrics.get(c) for c in counters]
+    try:
+        traced = jax.jit(layer).trace(*args)
+        text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert [metrics.get(c) - b for c, b in zip(counters, before)] == [9, 0]
+    assert "ragged_dot" not in str(traced.jaxpr)
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'op_name="([^"]*)"', text)
+    assert len(kernels) == 9, kernels
+    for name, op_name in kernels:
+        assert "ragged-dot" in name and "moe.experts" in op_name, (
+            name, op_name)
+    kinds = sorted(name.rsplit(".", 1)[0] for name, _ in kernels)
+    assert kinds == (["ragged-dot-gmm"] * 3 + ["ragged-dot-gmm-t"] * 3
+                     + ["ragged-dot-tgmm"] * 3)
+    # an [E, ., .] operand reaches its kernel as the parameter it is
+    assert not re.search(r"= bf16\[16,\d+,\d+\][^ ]* (copy|transpose)\(",
+                         text)
+    for k, w in ((d, f), (f, d)):
+        for tiles in (gm.gmm_tiles(rows, k, w), gm.tgmm_tiles(rows, k, w)):
+            assert (tiles.tk, tiles.tn) == (k, w)
+            assert tiles.resident_bytes + (8 << 20) <= 100 << 20

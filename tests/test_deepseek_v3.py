@@ -380,11 +380,16 @@ _MOE_COUNTERS = ("moe.bwd_residual", "moe.bwd_recomputed")
 _RULE_ONLY_OUTPUTS = ("H", "U", "SortedW", "Order", "Inv")
 
 
-def _counter_rise(fn):
-    before = [metrics.get(n) for n in _MOE_COUNTERS]
+# once per grouped matmul lowered, by the way its shapes sent it: the
+# Pallas kernels, `jax.lax.ragged_dot`
+_GROUPED_COUNTERS = ("moe.grouped_pallas", "moe.grouped_xla")
+
+
+def _counter_rise(fn, names=_MOE_COUNTERS):
+    before = [metrics.get(n) for n in names]
     out = fn()
     return out, tuple(int(metrics.get(n) - b)
-                      for n, b in zip(_MOE_COUNTERS, before))
+                      for n, b in zip(names, before))
 
 
 def _withhold_residuals(program):
@@ -507,17 +512,20 @@ def _census_trainer(withhold=False):
 
 
 def test_train_step_census_nine_grouped_matmuls_a_layer():
-    """The step's jaxpr holds 9 `ragged_dot_general` an expert layer (3
-    forward, 6 backward; 12 with the forward's three repeated), nothing
+    """A trace of the step lowers 9 grouped matmuls an expert layer (3
+    forward, 6 backward; 12 with the forward's three repeated), counted by
+    `moe.grouped_pallas` + `moe.grouped_xla` (at this preset's widths, no
+    multiples of 128, every one is a `ragged_dot_general`), nothing
     under `jax.checkpoint`, and no float32 value of the `[k, N, d]` /
     `[k*N, d]` buffers' size, forward or backward: the combine weight goes
     in ahead of the down projection, over f columns."""
     expert_layers = CFG["layers"] - CFG["first_k_dense_replace"]
     k, n, d = CFG["num_experts_per_tok"], B * S, CFG["hidden_size"]
     exe, loss, feed = _census_trainer()
-    jaxpr, rise = _counter_rise(lambda: str(exe.step_jaxpr(feed, [loss],
-                                                           k=2)))
-    assert rise == (expert_layers, 0)
+    census = _MOE_COUNTERS + _GROUPED_COUNTERS
+    jaxpr, rise = _counter_rise(
+        lambda: str(exe.step_jaxpr(feed, [loss], k=2)), census)
+    assert rise == (expert_layers, 0, 0, 9 * expert_layers)
     assert jaxpr.count("ragged_dot_general") == 9 * expert_layers
     assert "checkpoint" not in jaxpr and "remat" not in jaxpr
     assert f"bf16[{k * n},{d}]" in jaxpr
@@ -525,7 +533,7 @@ def test_train_step_census_nine_grouped_matmuls_a_layer():
         assert wide not in jaxpr, wide
     # the generic route on the same model: the forward's three again
     exe, loss, feed = _census_trainer(withhold=True)
-    jaxpr, rise = _counter_rise(lambda: str(exe.step_jaxpr(feed, [loss],
-                                                           k=2)))
-    assert rise == (0, expert_layers)
+    jaxpr, rise = _counter_rise(
+        lambda: str(exe.step_jaxpr(feed, [loss], k=2)), census)
+    assert rise == (0, expert_layers, 0, 12 * expert_layers)
     assert jaxpr.count("ragged_dot_general") == 12 * expert_layers

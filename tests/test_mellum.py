@@ -532,7 +532,8 @@ def test_without_window_and_groups_the_kernels_trace_as_before(
 _COUNTERS = ("attention.flash_window", "attention.flash_full",
              "attention.flash_kv_grouped", "attention.flash_kv_expanded",
              "attention.flash_bwd_residual", "moe.layers_lowered",
-             "moe.bwd_residual", "moe.bwd_recomputed")
+             "moe.bwd_residual", "moe.bwd_recomputed",
+             "moe.grouped_pallas", "moe.grouped_xla")
 
 
 def test_builder_names_scopes_and_checkpoints_and_verifies():
@@ -576,14 +577,17 @@ def test_a_trace_of_the_step_counts_its_routes(monkeypatch):
     """With the flash gate open (here: the interpreter), one trace of the
     AMP train step lowers three windowed and one full flash forward, all
     four on grouped KV heads and none expanded, their backward on the
-    forward's residuals, and four expert layers by the op's grad rule. The
-    step's jaxpr holds K and V at the KV heads' count only."""
+    forward's residuals, and four expert layers by the op's grad rule, whose
+    36 grouped matmuls (9 a layer) all go to the Pallas kernels at widths
+    that are multiples of 128, as the cell's are. The step's jaxpr holds K
+    and V at the KV heads' count only, and no `ragged_dot`."""
     monkeypatch.setattr(attention, "_use_pallas",
                         lambda q: q.shape[2] % 128 == 0)
     reset_programs(0)
     cfg = mellum.MellumConfig.tiny()
     cfg.seq_len, cfg.head_dim, cfg.sliding_window = 128, 64, 48
     cfg.num_attention_heads, cfg.num_key_value_heads = 6, 2
+    cfg.hidden_size, cfg.moe_intermediate_size = 128, 256
     _, loss, _ = mellum.build_causal_lm_program(cfg)
     fleet.init(is_collective=True)
     strategy = fleet.DistributedStrategy()
@@ -601,8 +605,10 @@ def test_a_trace_of_the_step_counts_its_routes(monkeypatch):
         "attention.flash_window": 3, "attention.flash_full": 1,
         "attention.flash_kv_grouped": 4, "attention.flash_kv_expanded": 0,
         "attention.flash_bwd_residual": 4, "moe.layers_lowered": 4,
-        "moe.bwd_residual": 4, "moe.bwd_recomputed": 0}
-    assert jaxpr.count("pallas_call") == 12
+        "moe.bwd_residual": 4, "moe.bwd_recomputed": 0,
+        "moe.grouped_pallas": 36, "moe.grouped_xla": 0}
+    assert jaxpr.count("name=flash_attention_") == 12
+    assert "ragged_dot" not in jaxpr and "name=ragged-dot-" in jaxpr
     # q, o, dq, dO at 6 heads; k, v, dk, dv at 2 and never at 6: the only
     # [1, 6, 128, 64] values are q's, and a KV tensor repeated to the query
     # heads would be a `broadcast_in_dim` / `repeat` to that shape from 2
