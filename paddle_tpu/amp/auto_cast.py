@@ -24,6 +24,9 @@ white_list = {
     # the flash kernels) in the compute dtype, like every other matmul's
     # operands; every dot accumulates f32, softmax and logsumexp stay f32
     "fused_attention",
+    # the scan's matmuls (C . B, the decayed products, the states read by
+    # C) run on bf16 operands; the step, A and D do not (keep_f32_slots)
+    "ssm_scan",
 }
 # per-op input slots excluded from the white-list cast: tiny O(V)/O(H)
 # operands whose quantization buys no MXU time but drifts parity with the
@@ -39,6 +42,10 @@ keep_f32_slots = {
     # bf16 rounding of either moves which experts a near-tie selects; the
     # slots' weights it made reach the grad op as they were (FO:SortedW)
     "routed_moe": {"X", "GateW", "SelectBias", "SortedW"},
+    # the step before its softplus, the per-head parameters, and what the
+    # forward wrote for the grad op (FO:States, FO:DtSoft, FO:CumA): the
+    # decays, their running sums and the states are float32
+    "ssm_scan": {"Dt", "DtBias", "ALog", "D", "States", "DtSoft", "CumA"},
 }
 
 # ops forced to float32 (reference black list: reductions/normalizations)

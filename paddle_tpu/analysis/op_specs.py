@@ -301,8 +301,9 @@ SPECS: Dict[str, OpSpec] = {
     # the expert layer of sparse decoder LMs (ops/moe.py routed_moe): no
     # capacity, so no token's result depends on another's
     "routed_moe": OpSpec(
+        # no ExpertGate: experts of the form W_down relu(W_up x)^2
         inputs={"X": ONE, "GateW": ONE, "SelectBias": OPT,
-                "ExpertGate": ONE, "ExpertUp": ONE, "ExpertDown": ONE},
+                "ExpertGate": OPT, "ExpertUp": ONE, "ExpertDown": ONE},
         # H .. Inv: what the forward writes for the op's grad rule
         outputs={"Out": ONE, "TopIdx": OPT, "ExpertLoad": OPT, "H": OPT,
                  "U": OPT, "SortedW": OPT, "Order": OPT, "Inv": OPT},
@@ -324,6 +325,22 @@ SPECS: Dict[str, OpSpec] = {
     "swiglu": OpSpec(
         inputs={"Gate": ONE, "Up": ONE}, outputs={"Out": ONE},
         sharding="elementwise"),
+    "relu2": OpSpec(inputs={"X": ONE}, outputs={"Out": ONE},
+                    sharding="elementwise"),
+    # --- the state-space mixer (ops/ssm.py) -------------------------------
+    "causal_conv1d": OpSpec(
+        inputs={"X": ONE, "W": ONE, "Bias": OPT}, outputs={"Out": ONE},
+        attr_types={"activation": str}, sharding="follow_x"),
+    "ssm_scan": OpSpec(
+        inputs={"X": ONE, "B": ONE, "C": ONE, "Dt": ONE, "DtBias": ONE,
+                "ALog": ONE, "D": ONE},
+        # States .. CumA: what the forward writes for the op's grad rule
+        outputs={"Y": ONE, "States": OPT, "DtSoft": OPT, "CumA": OPT},
+        required_attrs=("chunk_size",), attr_types={"chunk_size": int},
+        sharding="follow_x"),
+    "gated_group_rms_norm": OpSpec(
+        inputs={"X": ONE, "Gate": ONE, "Scale": OPT}, outputs={"Y": ONE},
+        attr_types={"groups": int, "epsilon": _NUM}, sharding="follow_x"),
     # --- serving tier: paged KV-cache decode ops (ops/paged_ops.py) ------
     # sharding "replicated": serving parallelism is whole-model replicas
     # behind the round-robin frontend (serving/frontend.py) — the pools

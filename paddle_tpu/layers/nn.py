@@ -28,7 +28,8 @@ __all__ = [
     "greater_equal", "logical_and", "logical_or", "logical_not", "logical_xor",
     "where", "cond_take", "unique", "cumsum", "prelu", "brelu",
     "fused_attention", "switch_moe", "routed_moe", "rms_norm",
-    "rotary_embedding", "swiglu",
+    "rotary_embedding", "swiglu", "relu2", "causal_conv1d", "ssm_scan",
+    "gated_group_rms_norm",
 ]
 
 
@@ -895,25 +896,30 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
     the gated experts it HOLDS, `expert_gate/up` [E_held, d, f] and
     `expert_down` [E_held, f, d], experts `expert_offset` .. +E_held of the
     whole; the result is their part of sum_k w_k E_{i_k}(x), so the parts
-    of all shares add up to the layer. A shared expert is ordinary
-    `swiglu` ops beside this one. Returns (out, top_idx [N, top_k],
-    expert_load [E_held]: assignments that fell on each held expert)."""
+    of all shares add up to the layer. `expert_gate` None: the experts
+    have no gate, E(x) = W_down relu(W_up x)^2. A shared expert is ordinary
+    `swiglu` (or `relu2`) ops beside this one. Returns (out, top_idx
+    [N, top_k], expert_load [E_held]: assignments that fell on each held
+    expert)."""
     helper = LayerHelper("routed_moe")
     out = helper.create_variable_for_type_inference(input.dtype)
     idx = helper.create_variable_for_type_inference("int64")
     load = helper.create_variable_for_type_inference("int32")
     # what the op's grad rule reads beside idx and load (ops/moe.py): the
-    # gate and up projections of the sorted rows, the slots' weights in
-    # sorted order, the sort and its inverse
-    h, u = (helper.create_variable_for_type_inference(expert_gate.dtype)
-            for _ in range(2))
-    sorted_w = helper.create_variable_for_type_inference("float32")
-    order, inv = (helper.create_variable_for_type_inference("int32")
-                  for _ in range(2))
-    for v in (idx, load, h, u, sorted_w, order, inv):
+    # gate (where the experts have one) and up projections of the sorted
+    # rows, the slots' weights in sorted order, the sort and its inverse
+    dtypes = {"H": expert_up.dtype, "U": expert_up.dtype,
+              "SortedW": "float32", "Order": "int32", "Inv": "int32"}
+    if expert_gate is None:
+        del dtypes["H"]
+    residuals = {slot: helper.create_variable_for_type_inference(dtype)
+                 for slot, dtype in dtypes.items()}
+    for v in (idx, load) + tuple(residuals.values()):
         v.stop_gradient = True
-    inputs = {"X": [input], "GateW": [gate_w], "ExpertGate": [expert_gate],
-              "ExpertUp": [expert_up], "ExpertDown": [expert_down]}
+    inputs = {"X": [input], "GateW": [gate_w]}
+    if expert_gate is not None:
+        inputs["ExpertGate"] = [expert_gate]
+    inputs.update({"ExpertUp": [expert_up], "ExpertDown": [expert_down]})
     if select_bias is not None:
         inputs["SelectBias"] = [select_bias]
     attrs = {"top_k": int(top_k), "routed_scaling": float(routed_scaling),
@@ -924,8 +930,7 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
     helper.append_op(
         "routed_moe", inputs=inputs,
         outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load],
-                 "H": [h], "U": [u], "SortedW": [sorted_w], "Order": [order],
-                 "Inv": [inv]},
+                 **{slot: [v] for slot, v in residuals.items()}},
         attrs=attrs)
     return out, idx, load
 
@@ -974,6 +979,73 @@ def swiglu(gate, up):
     helper.append_op("swiglu", inputs={"Gate": [gate], "Up": [up]},
                      outputs={"Out": [out]})
     return out
+
+
+def relu2(x):
+    """relu(x)^2."""
+    helper = LayerHelper("relu2")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("relu2", inputs={"X": [x]}, outputs={"Out": [out]})
+    return out
+
+
+def causal_conv1d(input, kernel_size, param_attr=None, bias_attr=None,
+                  activation=None):
+    """Depthwise convolution along the sequence of input [B, S, C] that
+    sees the current and the `kernel_size - 1` earlier positions
+    (ops/ssm.py causal_conv1d): weight [kernel_size, C], a bias [C] unless
+    `bias_attr` is False, then `activation` ("silu" or None)."""
+    helper = LayerHelper("causal_conv1d")
+    c = int(input.shape[-1])
+    inputs = {"X": [input],
+              "W": [helper.create_parameter(param_attr, [kernel_size, c],
+                                            dtype="float32")]}
+    if bias_attr is not False:
+        inputs["Bias"] = [helper.create_parameter(
+            bias_attr, [c], dtype="float32", is_bias=True)]
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("causal_conv1d", inputs=inputs, outputs={"Out": [out]},
+                     attrs={"activation": activation or ""})
+    return out
+
+
+def ssm_scan(x, b, c, dt, dt_bias, a_log, d, chunk_size):
+    """The selective scan of a Mamba-2 layer in its chunked form
+    (ops/ssm.py ssm_scan): x [B, S, H, P], b and c [B, S, G, N] (head h
+    reads group h // (H / G)), dt [B, S, H] before its softplus, and the
+    per-head parameters dt_bias, a_log (A = -exp(a_log)), d [H]. S must be
+    a whole number of chunks. Returns y [B, S, H, P]."""
+    helper = LayerHelper("ssm_scan")
+    y = helper.create_variable_for_type_inference(x.dtype)
+    # what the op's grad rule reads: the state each chunk starts from and
+    # the per-token rows
+    residuals = {slot: helper.create_variable_for_type_inference("float32")
+                 for slot in ("States", "DtSoft", "CumA")}
+    for v in residuals.values():
+        v.stop_gradient = True
+    helper.append_op(
+        "ssm_scan",
+        inputs={"X": [x], "B": [b], "C": [c], "Dt": [dt],
+                "DtBias": [dt_bias], "ALog": [a_log], "D": [d]},
+        outputs={"Y": [y], **{slot: [v] for slot, v in residuals.items()}},
+        attrs={"chunk_size": int(chunk_size)})
+    return y
+
+
+def gated_group_rms_norm(input, gate, groups, epsilon=1e-5, param_attr=None):
+    """GroupRMSNorm(input * silu(gate)) * scale: the statistics over each
+    of `groups` equal slices of the last axis; the scale is a parameter of
+    the last axis' size, initialised to 1."""
+    helper = LayerHelper("gated_group_rms_norm")
+    scale = helper.create_parameter(param_attr, [int(input.shape[-1])],
+                                    dtype="float32",
+                                    default_initializer=init_mod.Constant(1.0))
+    y = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("gated_group_rms_norm",
+                     inputs={"X": [input], "Gate": [gate], "Scale": [scale]},
+                     outputs={"Y": [y]},
+                     attrs={"groups": int(groups), "epsilon": float(epsilon)})
+    return y
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,9 @@ attention, sigmoid-routed experts without drops, shared experts, one
 expert-parallel rank's share) -> deepseek_v3.py, and a Mellum-2-family
 sparse causal LM (sliding-window and full attention layers in a period,
 grouped KV heads, yarn on the full layers, softmax-routed experts)
--> mellum.py
+-> mellum.py, and a Nemotron-H-family hybrid causal LM (a Mamba-2
+state-space mixer, ungated relu^2 experts with a shared one, or attention
+without rotary positions a layer, by a pattern string) -> nemotron_h.py
 """
 from . import (lenet, resnet, bert, wide_deep, gpt, se_resnext, deepseek_v3,
-               mellum)
+               mellum, nemotron_h)

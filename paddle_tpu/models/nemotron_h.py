@@ -1,0 +1,238 @@
+"""Nemotron-H-family hybrid causal LM (`model_type` nemotron_h),
+static-graph builder: a decoder whose every layer is ONE mixer or ONE
+feed-forward part under one pre-norm and one residual add, the kind read
+from `hybrid_override_pattern` letter by letter: `M` a Mamba-2 state-space
+mixer (input projection, causal depthwise conv, selective scan in its
+chunked form, gated grouped RMS norm, output projection), `E` sigmoid-routed
+experts of the form W_down relu(W_up x)^2 with a selection bias and a shared
+expert of the same form, `*` attention on grouped KV heads without rotary
+positions (the family takes positions from its state-space layers).
+
+The configuration's keys are the published `config.json`'s. What one
+expert-parallel rank holds is said beside them, as in
+`models/deepseek_v3.py`: `experts_held` experts from `expert_offset` of the
+`n_routed_experts` the router scores; a sliced vocabulary is a smaller
+`vocab_size`. On one chip the routed part is this rank's share of the sum
+and nothing stands in for the other ranks.
+
+Ops of the Program IR only, unrolled. Layer boundaries land on the loss's
+`_layer_checkpoints`. Device work carries `program.name_scope` names:
+`ssm.in_proj`, `ssm.conv`, `ssm.scan`, `ssm.gate_norm`, `ssm.out_proj`;
+`attn.proj`, `attn.attend.full`; `moe.shared`; the routed op names its own
+(`moe.route`, `moe.dispatch`, `moe.experts`, `moe.combine`).
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+from jax.sharding import PartitionSpec as P
+
+from .. import layers
+from .. import initializer as I
+from ..framework.program import name_scope
+from ..layer_helper import ParamAttr
+from ..observability.trace import RecordEvent
+from ..parallel.mesh import ShardingRules, moe_sharding_rules
+from .deepseek_v3 import (_heads, _linear, _norm, _w, embed_tokens,
+                          next_token_loss, record_expert_load)
+
+__all__ = ["NemotronHConfig", "build_causal_lm_program",
+           "record_expert_load", "sharding_rules"]
+
+MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+
+
+@dataclass
+class NemotronHConfig:
+    vocab_size: int = 131072
+    hidden_size: int = 2688
+    num_hidden_layers: int = 52
+    # kind of layer n: its n-th letter
+    hybrid_override_pattern: str = (
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 6
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    layer_norm_epsilon: float = 1e-5
+    initializer_range: float = 0.02
+    seq_len: int = 8192
+    # this rank's share of every expert layer (None: all the experts)
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+
+    @property
+    def rms_norm_eps(self):        # the name `deepseek_v3._norm` reads
+        return self.layer_norm_epsilon
+
+    def kind(self, n: int) -> str:
+        return self.hybrid_override_pattern[n]
+
+    @staticmethod
+    def tiny():
+        return NemotronHConfig(
+            vocab_size=256, hidden_size=64, num_hidden_layers=9,
+            hybrid_override_pattern="MEMEM*EME", num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, mamba_num_heads=8,
+            mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=8,
+            moe_intermediate_size=32, moe_shared_expert_intermediate_size=64,
+            n_routed_experts=8, num_experts_per_tok=2, seq_len=32)
+
+
+def _per_head(name, cfg, initializer):
+    return layers.create_parameter(
+        [cfg.mamba_num_heads], "float32",
+        attr=ParamAttr(name=name, initializer=initializer))
+
+
+def mamba_mixer(x, cfg: NemotronHConfig, pre: str):
+    """Mamba-2: [z | xBC | dt] = x W_in; xBC through the causal conv and
+    silu; the selective scan over x [H, P] with B, C [G, N]; the gated
+    grouped norm with z; W_out. The builder's initial values of `dt_bias`,
+    `A_log`, `D` are constants (a step of 0.01, A = -1, D = 1); a trainer
+    that wants the family's seeded draws sets them in the scope."""
+    hm, p, g, n = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+                   cfg.ssm_state_size)
+    d_in, s = hm * p, cfg.seq_len
+    with name_scope("ssm.in_proj"):
+        zxbcdt = _linear(x, 2 * d_in + 2 * g * n + hm, pre + "in_proj_w", cfg)
+        z, xbc, dt = layers.split(zxbcdt, [d_in, d_in + 2 * g * n, hm], dim=2)
+    with name_scope("ssm.conv"):
+        xbc = layers.causal_conv1d(
+            xbc, cfg.conv_kernel, param_attr=_w(pre + "conv_w", cfg),
+            bias_attr=ParamAttr(name=pre + "conv_b"), activation="silu")
+        xs, b, c = layers.split(xbc, [d_in, g * n, g * n], dim=2)
+    with name_scope("ssm.scan"):
+        y = layers.ssm_scan(
+            layers.reshape(xs, [0, s, hm, p]),
+            layers.reshape(b, [0, s, g, n]), layers.reshape(c, [0, s, g, n]),
+            dt,
+            _per_head(pre + "dt_bias", cfg,
+                      I.Constant(math.log(math.expm1(0.01)))),
+            _per_head(pre + "A_log", cfg, I.Constant(0.0)),
+            _per_head(pre + "D", cfg, I.Constant(1.0)),
+            chunk_size=cfg.chunk_size)
+    with name_scope("ssm.gate_norm"):
+        y = layers.gated_group_rms_norm(
+            layers.reshape(y, [0, s, d_in]), z, groups=g,
+            epsilon=cfg.layer_norm_epsilon,
+            param_attr=ParamAttr(name=pre + "ssm_norm_scale"))
+    with name_scope("ssm.out_proj"):
+        return _linear(y, cfg.hidden_size, pre + "out_proj_w", cfg)
+
+
+def relu2_ffn(x, width, pre, cfg):
+    """W_down relu(W_up x)^2."""
+    return _linear(layers.relu2(_linear(x, width, pre + "up_w", cfg)),
+                   cfg.hidden_size, pre + "down_w", cfg)
+
+
+def expert_layer(x, cfg: NemotronHConfig, pre: str):
+    """(this rank's routed part + the shared expert, top_idx,
+    expert_load): sigmoid scores over ALL `n_routed_experts`, the top
+    `num_experts_per_tok` of score + bias, their weights divided by their
+    sum and scaled; experts without a gate."""
+    h, f = cfg.hidden_size, cfg.moe_intermediate_size
+    held = cfg.experts_held or cfg.n_routed_experts
+    gate_w = layers.create_parameter(
+        [h, cfg.n_routed_experts], "float32", attr=_w(pre + "router_w", cfg))
+    bias = layers.create_parameter(
+        [cfg.n_routed_experts], "float32",
+        attr=ParamAttr(name=pre + "router_bias", trainable=False,
+                       initializer=I.Constant(0.0)))
+    up, down = (layers.create_parameter(
+        shape, "float32", attr=_w(pre + f"experts_{n}_w", cfg))
+        for n, shape in (("up", [held, h, f]), ("down", [held, f, h])))
+    routed, idx, load = layers.routed_moe(
+        x, gate_w, None, up, down, top_k=cfg.num_experts_per_tok,
+        select_bias=bias, routed_scaling=cfg.routed_scaling_factor,
+        norm_topk=cfg.norm_topk_prob, experts_total=cfg.n_routed_experts,
+        expert_offset=cfg.expert_offset)
+    with name_scope("moe.shared"):
+        shared = relu2_ffn(x, cfg.moe_shared_expert_intermediate_size,
+                           pre + "shared_", cfg)
+        return layers.elementwise_add(routed, shared), idx, load
+
+
+def grouped_attention(x, cfg: NemotronHConfig, pre: str):
+    """`num_attention_heads` query heads on `num_key_value_heads` KV heads
+    (query head h attends KV head h // group), causal, no rotary positions.
+    K and V go to the attention op at their own head count."""
+    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+    with name_scope("attn.proj"):
+        q = _heads(_linear(x, nh * hd, pre + "q_proj_w", cfg), nh, hd)
+        k = _heads(_linear(x, nkv * hd, pre + "k_proj_w", cfg), nkv, hd)
+        v = _heads(_linear(x, nkv * hd, pre + "v_proj_w", cfg), nkv, hd)
+    with name_scope("attn.attend.full"):
+        ctx = layers.fused_attention(q, k, v, causal=True,
+                                     scale=1.0 / math.sqrt(hd))
+    with name_scope("attn.proj"):
+        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                             [0, 0, nh * hd])
+        return _linear(ctx, cfg.hidden_size, pre + "o_proj_w", cfg)
+
+
+def decoder_layer(x, cfg: NemotronHConfig, n: int):
+    """x + Mixer_n(RMSNorm(x)); (x_out, (top_idx, expert_load) or None)."""
+    pre, kind = f"l{n}_", cfg.kind(n)
+    a = _norm(x, pre + "norm_scale", cfg)
+    routed = None
+    if kind == MAMBA:
+        y = mamba_mixer(a, cfg, pre)
+    elif kind == EXPERTS:
+        y, idx, load = expert_layer(a, cfg, pre)
+        routed = (idx, load)
+    elif kind == ATTENTION:
+        y = grouped_attention(a, cfg, pre)
+    else:
+        raise ValueError(f"hybrid_override_pattern: no layer kind {kind!r}")
+    return layers.elementwise_add(x, y), routed
+
+
+def build_causal_lm_program(cfg: NemotronHConfig):
+    """Next-token objective over `tokens` [B, seq_len]
+    (`models.deepseek_v3.next_token_loss`). Returns (tokens, loss, routed):
+    `routed` holds, per expert layer, the `(top_idx, expert_load)`
+    variables a caller may fetch beside the loss."""
+    with RecordEvent("program.build", args={"model": "nemotron_h"}):
+        tokens, x = embed_tokens(cfg)
+        ckpts, routed = [], []
+        for n in range(cfg.num_hidden_layers):
+            x, r = decoder_layer(x, cfg, n)
+            ckpts.append(x.name)
+            if r is not None:
+                routed.append(r)
+        loss = next_token_loss(x, tokens, cfg)
+        loss._layer_checkpoints = ckpts
+        return tokens, loss, routed
+
+
+def sharding_rules() -> ShardingRules:
+    """tp / ep rules as data: q, k, v column-parallel by head, the output
+    projections row-parallel, the shared expert by its width, the experts'
+    leading dim over `ep`, the vocabulary over `tp`. k and v split by KV
+    head: `tp` may not pass `num_key_value_heads` (2 as published). The
+    state-space mixer stays whole on every chip: its input projection's
+    columns are [z | x | B | C | dt], and B and C are shared by the 8
+    heads of a group."""
+    return moe_sharding_rules(extra=[
+        (r"_(q|k|v)_proj_w$", P(None, "tp")),
+        (r"_o_proj_w$", P("tp", None)),
+        (r"_shared_up_w$", P(None, "tp")),
+        (r"_shared_down_w$", P("tp", None)),
+        (r"^embed_tokens$", P("tp", None)),
+        (r"^lm_head_w$", P(None, "tp")),
+    ])

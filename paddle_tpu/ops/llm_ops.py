@@ -1,10 +1,10 @@
 """Ops of the decoder LMs after 2020: RMS norm, rotary positions (pairs
 interleaved or half-split, frequencies by the default rule or yarn's), the
-gated (SwiGLU) product.
+gated (SwiGLU) product, the squared ReLU.
 
 Each is one plain `jax.numpy` lowering that XLA fuses with its
 neighbours; gradients come from the generic `__vjp__`. Under AMP the norm
-is on the black list (float32), the other two keep the dtype they are
+is on the black list (float32), the others keep the dtype they are
 given and compute in float32 inside (amp/auto_cast.py).
 """
 from __future__ import annotations
@@ -134,3 +134,11 @@ def _swiglu(ctx, ins, attrs):
     g, u = ins["Gate"][0], ins["Up"][0]
     out = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
     return {"Out": [out.astype(g.dtype)]}
+
+
+@register("relu2")
+def _relu2(ctx, ins, attrs):
+    """relu(x)^2 (So et al. 2021, Primer), the square in float32."""
+    x = ins["X"][0]
+    return {"Out": [jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(
+        x.dtype)]}

@@ -193,15 +193,17 @@ def _switch_moe(ctx, ins, attrs):
 # family): sigmoid scores (or a softmax over all the experts), a selection
 # bias no gradient reaches (or none), top-k of ALL experts, normalised and
 # scaled weights, no capacity and no drops,
-# gated experts, and the share of one expert-parallel rank: told which
-# experts it holds, it routes over all of them and computes its own part.
+# gated experts (or, given no gate matrix, experts of the form
+# W_down relu(W_up x)^2), and the share of one expert-parallel rank: told
+# which experts it holds, it routes over all of them and computes its own
+# part.
 # ---------------------------------------------------------------------------
 
 # What `routed_moe`'s forward writes for its grad rule (beside `TopIdx` and
 # `ExpertLoad`, which a caller may fetch): the gate and up projections of
-# the sorted rows, [k*N, f] in the compute dtype, the slots' weights in
-# sorted order, and the sort with its inverse. Narrow each: never a
-# [k*N, d] buffer.
+# the sorted rows, [k*N, f] in the compute dtype (`H` only where the experts
+# have a gate), the slots' weights in sorted order, and the sort with its
+# inverse. Narrow each: never a [k*N, d] buffer.
 _RESIDUALS = ("H", "U", "SortedW", "Order", "Inv", "TopIdx", "ExpertLoad")
 
 # dW of a grouped matmul: x [m, a] and g [m, b] contracted over the ragged
@@ -283,10 +285,14 @@ def _whole_buffer(sizes, rows):
 
 
 def _weighted_act(h, u, w_sorted):
-    """silu(h) * u in float32, and the same times its slot's weight rounded
-    once to the compute dtype: the down projection's operand."""
-    act = jax.nn.silu(h.astype(jnp.float32)) * u.astype(jnp.float32)
-    return act, (act * w_sorted[:, None]).astype(h.dtype)
+    """silu(h) * u in float32 (h None, an expert without a gate:
+    relu(u)^2), and the same times its slot's weight rounded once to the
+    compute dtype: the down projection's operand."""
+    if h is None:
+        act = jnp.square(jax.nn.relu(u.astype(jnp.float32)))
+    else:
+        act = jax.nn.silu(h.astype(jnp.float32)) * u.astype(jnp.float32)
+    return act, (act * w_sorted[:, None]).astype(u.dtype)
 
 
 def _experts_fwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed):
@@ -304,15 +310,16 @@ def _experts_fwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed):
     deployment, where the exchange fills them), and a zero row yields a
     zero row, so nothing needs a mask but the gathered input. The slot's
     weight goes in AHEAD of the down projection, w (a W) = (w a) W over f
-    columns, so the combine is a plain sum of the k slots. `count`: this
-    trace's grouped matmuls count (`_RowGroups`)."""
+    columns, so the combine is a plain sum of the k slots. `eg` None: the
+    experts have no gate, W_down relu(W_up x)^2, two grouped matmuls, and h
+    is None. `count`: this trace's grouped matmuls count (`_RowGroups`)."""
     rows, n = order.shape[0], xt.shape[0]
     groups = _RowGroups(sizes, rows, count)
     with jax.named_scope("moe.dispatch"):
         valid = jnp.arange(rows) < jnp.sum(sizes)
-        xs = jnp.where(valid[:, None], xt.astype(eg.dtype)[order % n], 0)
+        xs = jnp.where(valid[:, None], xt.astype(eu.dtype)[order % n], 0)
     with jax.named_scope("moe.experts"):
-        h = _grouped(xs, eg, groups)
+        h = None if eg is None else _grouped(xs, eg, groups)
         u = _grouped(xs, eu, groups)
         _, wa = _weighted_act(h, u, w_sorted)
         y = _grouped(wa, ed, groups)
@@ -323,11 +330,11 @@ def _experts_fwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed):
 def _experts_bwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
                  g):
     """The transpose of `_experts_fwd` at g = d Out [N, d], on the h and u
-    it wrote: six grouped matmuls, none of the forward's again. No mask:
-    a foreign slot's weight is 0, so its rows of dh and du are. Returns the
-    gradients of (xt, w_sorted, eg, eu, ed)."""
+    it wrote: six grouped matmuls (four without a gate), none of the
+    forward's again. No mask: a foreign slot's weight is 0, so its rows of
+    dh and du are. Returns the gradients of (xt, w_sorted, eg, eu, ed)."""
     rows, n = order.shape[0], xt.shape[0]
-    cdt = eg.dtype
+    cdt = eu.dtype
     # what is read here is read when the backward gets here: without the
     # barrier XLA merges the gather of xs below with the forward's and
     # keeps a [k*N, d] buffer a layer alive in between
@@ -344,15 +351,21 @@ def _experts_bwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
         dwa = _grouped(gs, ed, groups, transposed=True).astype(jnp.float32)
         dw_sorted = jnp.sum(dwa * act, axis=1)
         dact = dwa * w_sorted[:, None]
-        hf = h.astype(jnp.float32)
-        sig = jax.nn.sigmoid(hf)
-        dh = (dact * u.astype(jnp.float32)
-              * sig * (1.0 + hf * (1.0 - sig))).astype(cdt)
-        du = (dact * hf * sig).astype(cdt)
-        deg = _grouped_dw(xs, dh, groups)
-        deu = _grouped_dw(xs, du, groups)
-        dxs = (_grouped(dh, eg, groups, transposed=True)
-               + _grouped(du, eu, groups, transposed=True))
+        if eg is None:
+            du = (dact * 2.0 * jax.nn.relu(u.astype(jnp.float32))).astype(cdt)
+            deg = None
+            deu = _grouped_dw(xs, du, groups)
+            dxs = _grouped(du, eu, groups, transposed=True)
+        else:
+            hf = h.astype(jnp.float32)
+            sig = jax.nn.sigmoid(hf)
+            dh = (dact * u.astype(jnp.float32)
+                  * sig * (1.0 + hf * (1.0 - sig))).astype(cdt)
+            du = (dact * hf * sig).astype(cdt)
+            deg = _grouped_dw(xs, dh, groups)
+            deu = _grouped_dw(xs, du, groups)
+            dxs = (_grouped(dh, eg, groups, transposed=True)
+                   + _grouped(du, eu, groups, transposed=True))
     with jax.named_scope("moe.dispatch"):
         dxt = _sum_slots(dxs[inv], rows // n)
     return dxt.astype(xt.dtype), dw_sorted, deg, deu, ded
@@ -380,7 +393,7 @@ def _geometry(ins, attrs):
     wg = ins["GateW"][0]                    # [d, E_total]
     e_total = int(attrs.get("experts_total", wg.shape[1]))
     off = int(attrs.get("expert_offset", 0))
-    e_held = ins["ExpertGate"][0].shape[0]
+    e_held = ins["ExpertUp"][0].shape[0]
     if wg.shape[1] != e_total or off < 0 or off + e_held > e_total:
         raise ValueError(
             f"routed_moe: GateW routes over {wg.shape[1]} experts, "
@@ -410,6 +423,13 @@ def _slot_weights(scores, idx, local, attrs):
     return jnp.where(local, w, 0.0).T
 
 
+def _expert_weights(ins):
+    """(gate, up, down) [E_held, ...]; gate None where the op was given no
+    `ExpertGate`: experts of the form W_down relu(W_up x)^2."""
+    eg = ins["ExpertGate"][0] if ins.get("ExpertGate") else None
+    return eg, ins["ExpertUp"][0], ins["ExpertDown"][0]
+
+
 def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
     """Grad rule: the backward on what the forward wrote (`_RESIDUALS`).
     The experts' part is `_experts_bwd`; the router's (GateW, and x through
@@ -418,13 +438,13 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
     before they existed), and the generic `__vjp__` differentiates the
     forward lowering."""
     g = (ogs.get("Out") or [None])[0]
-    if g is None or not all(outs.get(s) for s in _RESIDUALS):
+    eg, eu, ed = _expert_weights(ins)
+    if g is None or not all(outs.get(s) for s in _RESIDUALS
+                            if s != "H" or eg is not None):
         return None
     x, wg = ins["X"][0], ins["GateW"][0]
-    eg, eu, ed = (ins[s][0] for s in ("ExpertGate", "ExpertUp",
-                                      "ExpertDown"))
-    h, u, w_sorted, order, inv, idx, sizes = (outs[s][0]
-                                              for s in _RESIDUALS)
+    h, u, w_sorted, order, inv, idx, sizes = (
+        outs[s][0] if outs.get(s) else None for s in _RESIDUALS)
     off, e_held = _geometry(ins, attrs)
     xt = x.reshape(-1, x.shape[-1])
     local = (idx >= off) & (idx < off + e_held)
@@ -444,8 +464,11 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
     if not ctx.is_eval_shape:
         from ..observability import metrics
         metrics.inc("moe.bwd_residual")
-    return {"X": [(dxt + dxt_route).reshape(x.shape)], "GateW": [dwg],
-            "ExpertGate": [deg], "ExpertUp": [deu], "ExpertDown": [ded]}
+    grads = {"X": [(dxt + dxt_route).reshape(x.shape)], "GateW": [dwg],
+             "ExpertUp": [deu], "ExpertDown": [ded]}
+    if eg is not None:
+        grads["ExpertGate"] = [deg]
+    return grads
 
 
 @register("routed_moe", nondiff_slots=("SelectBias",),
@@ -454,8 +477,7 @@ def _routed_moe(ctx, ins, attrs):
     x = ins["X"][0]                         # [..., d]
     wg = ins["GateW"][0]                    # [d, E_total]
     bias = ins["SelectBias"][0] if ins.get("SelectBias") else None
-    eg, eu, ed = (ins[s][0] for s in ("ExpertGate", "ExpertUp",
-                                      "ExpertDown"))  # [E_held, ...]
+    eg, eu, ed = _expert_weights(ins)       # [E_held, ...]
     top_k = int(attrs["top_k"])
     off, e_held = _geometry(ins, attrs)
     d = x.shape[-1]
@@ -490,7 +512,10 @@ def _routed_moe(ctx, ins, attrs):
         # differentiated at once)
         metrics.inc("moe.bwd_recomputed" if ctx.in_vjp
                     else "moe.layers_lowered")
-    return {"Out": [out.astype(eg.dtype).reshape(x.shape)],
+    outs = {"Out": [out.astype(eu.dtype).reshape(x.shape)],
             "TopIdx": [idx.astype(INT64_DEVICE_DTYPE)],
-            "ExpertLoad": [sizes], "H": [h], "U": [u],
+            "ExpertLoad": [sizes], "U": [u],
             "SortedW": [w_sorted], "Order": [order], "Inv": [inv]}
+    if eg is not None:
+        outs["H"] = [h]
+    return outs

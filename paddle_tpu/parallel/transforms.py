@@ -18,6 +18,7 @@ with a step-counter mask using where-selects — no control-flow blocks needed.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -72,7 +73,11 @@ def _run_sub_ops(ctx, sub_ops, env, amp_dtype, seed_overrides=None):
         if amp_dtype is not None:
             from ..framework.executor import _amp_cast_ins
             op_ins = _amp_cast_ins(od["type"], op_ins, amp_dtype)
-        outs = opdef.lower(ctx, op_ins, at)
+        # program.name_scope, as the executor's own op loop applies it: a
+        # group's device work keeps its name inside a segment
+        with (jax.named_scope(at["name_scope"]) if at.get("name_scope")
+              else contextlib.nullcontext()):
+            outs = opdef.lower(ctx, op_ins, at)
         for s, ns in od["outputs"].items():
             if s not in outs:
                 continue
