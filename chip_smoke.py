@@ -770,11 +770,21 @@ def grouped_matmul_forms(rows, d, f, experts, retile=None, time_xla=True,
     a launch of each (a time only on a chip). Uneven groups with an empty
     one, the last swollen to the buffer's end. `retile(form, tiles)` may
     replace the tiles the shape rule gives: the sweep's handle, which
-    times `ragged_dot` once (`time_xla`) and the kernel tile by tile."""
+    times `ragged_dot` once (`time_xla`) and the kernel tile by tile.
+    Operands and results are held row-major, as a step's loop carries
+    them: left to itself XLA stores an array whose last width is no
+    multiple of 128 with the other width innermost, and a lone kernel
+    would be timed with a relayout before and after it."""
     import jax
     import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops import moe
     from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    def row_major(ndim):
+        return Format(Layout(major_to_minor=tuple(range(ndim))),
+                      SingleDeviceSharding(jax.devices()[0]))
 
     rng = np.random.RandomState(seed)
     sizes = rng.multinomial(rows // 3, rng.dirichlet(np.ones(experts)))
@@ -783,7 +793,9 @@ def grouped_matmul_forms(rows, d, f, experts, retile=None, time_xla=True,
     sizes = jnp.asarray(sizes, jnp.int32)
 
     def operand(*shape):
-        return jnp.asarray(rng.randn(*shape) * 0.1, jnp.bfloat16)
+        return jax.device_put(
+            jnp.asarray(rng.randn(*shape) * 0.1, jnp.bfloat16),
+            row_major(len(shape)))
 
     def ms(fn, *args):
         jax.block_until_ready(fn(*args))
@@ -796,7 +808,9 @@ def grouped_matmul_forms(rows, d, f, experts, retile=None, time_xla=True,
     facts = {}
     for k, n in ((d, f), (f, d)):
         x, g, w = operand(rows, k), operand(rows, n), operand(experts, k, n)
-        wt = jnp.swapaxes(w, 1, 2) + 0          # [E, n, k], its own buffer
+        # [E, n, k], its own buffer
+        wt = jax.jit(lambda w: jnp.swapaxes(w, 1, 2),
+                     out_shardings=row_major(3))(w)
         forms = {
             "gmm": (gm.gmm_tiles, (x, w), {},
                     lambda x, w: jax.lax.ragged_dot(
@@ -817,10 +831,11 @@ def grouped_matmul_forms(rows, d, f, experts, retile=None, time_xla=True,
             if retile is not None:
                 tiles = retile(form, tiles)
             call = gm.tgmm if form == "tgmm" else gm.gmm
+            out = row_major(3 if form == "tgmm" else 2)
             kernel = jax.jit(lambda a, b: call(
                 a, b, gm.group_visits(sizes, rows, tiles.tm), tiles=tiles,
-                **kw))
-            xla = jax.jit(xla)
+                **kw), out_shardings=out)
+            xla = jax.jit(xla, out_shardings=out)
             row = _parity(kernel(*args), xla(*args))
             check(row["max_abs_diff"] <= GROUPED_TOL * row["max_abs_ref"],
                   f"{form} {k}x{n}: {row} exceeds {GROUPED_TOL}")

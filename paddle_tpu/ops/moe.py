@@ -218,8 +218,9 @@ class _RowGroups:
     layer runs over it in one direction: the sizes over the WHOLE buffer
     (`_whole_buffer`) and, made once per row tile, the Pallas kernels' visit
     tables. A matmul goes to `ops/pallas/grouped_matmul.py` where that
-    file's tile rule takes the operands' shapes (widths that are multiples
-    of 128) and to `jax.lax.ragged_dot` where it returns None; `count`:
+    file's tile rule takes the operands' shapes (widths of a lane tile, 128,
+    or more; one that is no multiple of 128 as a single full-width block)
+    and to `jax.lax.ragged_dot` where it returns None; `count`:
     whether this trace's calls count, `moe.grouped_pallas` /
     `moe.grouped_xla`, once per grouped matmul lowered."""
 

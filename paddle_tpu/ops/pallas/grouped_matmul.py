@@ -31,12 +31,17 @@ whenever the blocks fit `VMEM_BUDGET`: tn = N, so the row buffer is read
 once a kernel, and tk = K, so consecutive tiles of one group name the same
 weight block and it is fetched once a group, not once a row tile. Where a
 matrix does not fit, tk shrinks first (the largest multiple of 128 that
-divides K and fits), then tn.
+divides K and fits), then tn. A width that is no multiple of 128 (1856 =
+14.5 x 128) has ONE block, itself: a block dimension equal to the array's
+is legal for Mosaic whatever it divides, VMEM holds it with its lanes
+rounded up to 128 (the resident sums count that), and where the blocks do
+not fit it is the other width that shrinks.
 
 Contract: `sizes` sums to `m` (`ops/moe.py` `_whole_buffer`): every row
-belongs to a group, so every row of the result is written. Widths that are
-no multiple of 128, or a row count no row tile divides, are not taken:
-the tile functions return None and the caller keeps `jax.lax.ragged_dot`.
+belongs to a group, so every row of the result is written. A width under
+one lane tile (128) or no multiple of the sublane tile (8), or a row count
+no row tile divides, is not taken: the tile functions return None and the
+caller keeps `jax.lax.ragged_dot`.
 """
 from __future__ import annotations
 
@@ -51,6 +56,12 @@ from jax.experimental.pallas import tpu as pltpu
 from . import interpret_mode
 
 _LANES = 128
+# a width is also the second-minor dimension of a weight block ([tk, tn],
+# [tn, tk] read transposed, tgmm's result): 8 rows are a float32 register;
+# bf16 packs 16, and a full-width block that ends on half of one is padded
+# by Mosaic like its lanes. What 8 keeps out are widths whose last register
+# would hold a row or two
+_SUBLANES = 8
 # rows a visit: the largest power of two up to ROW_TILE that divides m. 256
 # and 512 take the same time on a v5e and 1024 more (PERF.md section 6,
 # PR 31); Mosaic unrolls a visit's matmul, so the kernel's code, which
@@ -85,14 +96,24 @@ def _row_tile(m: int):
 
 
 def _lane_divisors(dim: int):
-    """Multiples of 128 that divide `dim`, largest first."""
+    """The blocks a width may be cut into, largest first: the multiples of
+    128 that divide it, or, for a width that is no multiple of 128, the
+    width itself alone."""
+    if dim % _LANES:
+        return [dim]
     return [t for t in range(dim, 0, -_LANES) if dim % t == 0]
+
+
+def _in_vmem(width: int):
+    """What a block `width` wide occupies: whole lane tiles."""
+    return -(-width // _LANES) * _LANES
 
 
 def _gmm_resident(tm, tk, tn, in_bytes, out_bytes):
     # x and w blocks and the result block double-buffered; four float32
     # values of the result block's size: one visit's product, the
     # accumulator over k tiles, the masked store's old rows and its select
+    tk, tn = _in_vmem(tk), _in_vmem(tn)
     return (2 * (tm * tk + tk * tn) * in_bytes + 2 * tm * tn * out_bytes
             + 4 * tm * tn * 4)
 
@@ -100,13 +121,14 @@ def _gmm_resident(tm, tk, tn, in_bytes, out_bytes):
 def _tgmm_resident(tm, tk, tn, in_bytes, out_bytes):
     # x and g blocks and the result block double-buffered; the float32
     # accumulator and one visit's product beside it; the masked operand
+    tk, tn = _in_vmem(tk), _in_vmem(tn)
     return (2 * (tm * tk + tm * tn) * in_bytes + 2 * tk * tn * out_bytes
             + 2 * tk * tn * 4 + tm * min(tk, tn) * in_bytes)
 
 
 def _pick(resident, m, k, n, in_bytes, out_bytes):
     tm = _row_tile(m)
-    if tm is None or k % _LANES or n % _LANES:
+    if tm is None or min(k, n) < _LANES or k % _SUBLANES or n % _SUBLANES:
         return None
     for tn in _lane_divisors(n):
         for tk in _lane_divisors(k):

@@ -1,16 +1,21 @@
 #!/usr/bin/env python
 """The sweep that sized `ops/pallas/grouped_matmul.py`'s tiles: the expert
-layer's grouped matmuls at the two sparse cells' shapes, XLA's
+layer's grouped matmuls at the three sparse cells' shapes, XLA's
 `ragged_dot` beside the Pallas kernel under the shape rule's tiles, then
 the kernel under each alternative of `_alternatives` (the rule's tiles
 with one thing moved), milliseconds a launch on the host's clock and the
-share of the MXU's bf16 peak (197 TFLOP/s on a v5e) that is.
+share of the MXU's bf16 peak (197 TFLOP/s on a v5e) that is. Where the
+expert width is no multiple of 128 (the hybrid cell's 1856, one full-width
+block under the rule) the last column is the same kernels on operands
+padded to the next multiple (1920), its share still of the unpadded
+width's operations.
 
-    python scripts/grouped_matmul_sweep.py            # on the chip
+    python scripts/grouped_matmul_sweep.py [cell ...] [--alts rule,tm=512]
 
 A time only on a TPU; elsewhere it refuses. Writes
 chiprun_out/grouped_matmul_sweep.json.
 """
+import argparse
 import json
 import os
 import sys
@@ -21,7 +26,8 @@ import chip_smoke  # noqa: E402
 
 PEAK = 197e12
 CELLS = {"mellum2_12b_ep4_s8192": (65536, 2304, 896, 16),
-         "kanana2_30b_a3b_ep8_s4096": (49152, 2048, 768, 16)}
+         "kanana2_30b_a3b_ep8_s4096": (49152, 2048, 768, 16),
+         "nemotron_twotower_30b_a3b_ep16_s8192": (49152, 2688, 1856, 8)}
 
 
 def _alternatives(gm):
@@ -52,19 +58,36 @@ def _alternatives(gm):
     return {"rule": None, **{k: resized(v) for k, v in alts.items()}}
 
 
+# the alternatives that cut a width: a width that is one block has no cut
+_CUTS_A_WIDTH = ("tk/2", "tn=xla")
+
+
 def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("cells", nargs="*", default=list(CELLS))
+    ap.add_argument("--alts", default="", help="only these, comma-separated")
+    args = ap.parse_args()
     import jax
     if jax.devices()[0].platform != "tpu":
         print("grouped_matmul_sweep: needs a TPU", file=sys.stderr)
         return 2
     from paddle_tpu.ops.pallas import grouped_matmul as gm
     out = {}
-    for cell, (rows, d, f, e) in CELLS.items():
+    for cell in args.cells:
+        rows, d, f, e = CELLS[cell]
         flops = 2 * rows * d * f
-        for alt, retile in _alternatives(gm).items():
+        padded = gm._in_vmem(f)
+        alts = {k: v for k, v in _alternatives(gm).items()
+                if f == padded or k not in _CUTS_A_WIDTH}
+        if f != padded:
+            alts[f"f={padded}"] = None
+        if args.alts:
+            alts = {k: alts[k] for k in args.alts.split(",")}
+        for alt, retile in alts.items():
             try:
                 facts = chip_smoke.grouped_matmul_forms(
-                    rows, d, f, e, retile, time_xla=retile is None)
+                    rows, d, padded if alt.startswith("f=") else f, e,
+                    retile, time_xla=alt == "rule")
             except Exception as exc:  # a tile Mosaic refuses is a finding
                 print(f"{cell} {alt}: {type(exc).__name__}: "
                       f"{str(exc)[:300]}", flush=True)

@@ -6,7 +6,7 @@ them (S = 4096, where the dkdv kernel needs more than Mosaic's default
 16 MiB of scoped VMEM). This file holds EVERY compile for a described
 chip (one worker loads the TPU's library): the expert layer's grouped
 matmuls (`ops/pallas/grouped_matmul.py`; tests/test_grouped_matmul.py has
-their numerics) at both sparse cells' sizes are at its end.
+their numerics) at the three sparse cells' sizes are at its end.
 """
 import re
 
@@ -15,6 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
@@ -121,28 +122,38 @@ def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
     assert text.count("tpu_custom_call") >= 3
 
 
-# rows a layer, d, f of the benchmark's two sparse cells; 16 held experts
-_EXPERT_SHAPES = {"mellum2_12b_ep4_s8192": (65536, 2304, 896),
-                  "kanana2_30b_a3b_ep8_s4096": (49152, 2048, 768)}
+# rows a layer, d, f, held experts of the benchmark's three sparse cells, and
+# whether the experts have a gate (3 + 6 grouped matmuls a layer, or 2 + 4)
+_EXPERT_SHAPES = {
+    "mellum2_12b_ep4_s8192": (65536, 2304, 896, 16, True),
+    "kanana2_30b_a3b_ep8_s4096": (49152, 2048, 768, 16, True),
+    "nemotron_twotower_30b_a3b_ep16_s8192": (49152, 2688, 1856, 8, False)}
 
 
 @pytest.mark.parametrize("cell", _EXPERT_SHAPES)
-def test_an_expert_layers_nine_grouped_matmuls_compile_for_a_v5e(
+def test_an_expert_layers_grouped_matmuls_compile_for_a_v5e(
         v5e, cell, monkeypatch):
     """`ops/moe.py`'s forward and backward bodies at a cell's shapes in
-    bf16: 3 + 6 grouped matmuls, every one a Mosaic kernel whose
-    instruction name holds `ragged-dot` (what the benchmark's readers find
-    them by) under the `moe.experts` scope, each within the VMEM its tiles
-    state, and no copy or transpose of a weight operand in front of the
-    dx forms."""
+    bf16: every grouped matmul a Mosaic kernel whose instruction name
+    holds `ragged-dot` (what the benchmark's readers find them by) under
+    the `moe.experts` scope, each within the VMEM its tiles state, and no
+    copy or transpose of a weight operand in front of the dx forms. The
+    hybrid cell's expert width, 1856 = 14.5 x 128, is one full-width block
+    of every kernel that meets it."""
     monkeypatch.setattr(gm, "interpret_mode", lambda: False)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    rows, d, f = _EXPERT_SHAPES[cell]
-    n, e = 8192, 16
+    rows, d, f, e, gated = _EXPERT_SHAPES[cell]
+    n, per_form = 8192, 3 if gated else 2
 
-    def sd(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    # the weights and their gradients row-major, as a step's loop carries
+    # them. Left to itself the compiler stores an array whose last width
+    # is no multiple of 128 with the other width innermost, and a program
+    # that is one layer would open and close on a change of layout
+    row_major = Format(Layout(major_to_minor=(0, 1, 2)), v5e)
+
+    def sd(shape, dtype=jnp.bfloat16, where=v5e):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
 
     def layer(xt, w_sorted, order, inv, sizes, eg, eu, ed, g):
         out, h, u = moe._experts_fwd(True, xt, w_sorted, order, inv, sizes,
@@ -151,31 +162,41 @@ def test_an_expert_layers_nine_grouped_matmuls_compile_for_a_v5e(
                                      eg, eu, ed, h, u, g)
 
     args = (sd((n, d)), sd((rows,), jnp.float32), sd((rows,), jnp.int32),
-            sd((rows,), jnp.int32), sd((e,), jnp.int32), sd((e, d, f)),
-            sd((e, d, f)), sd((e, f, d)), sd((n, d)))
+            sd((rows,), jnp.int32), sd((e,), jnp.int32),
+            sd((e, d, f), where=row_major) if gated else None,
+            sd((e, d, f), where=row_major), sd((e, f, d), where=row_major),
+            sd((n, d)))
+    grads = (None, None, row_major if gated else None, row_major, row_major)
     counters = ("moe.grouped_pallas", "moe.grouped_xla")
     before = [metrics.get(c) for c in counters]
     try:
-        traced = jax.jit(layer).trace(*args)
+        traced = jax.jit(layer, out_shardings=(None, grads)).trace(*args)
         text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
-    assert [metrics.get(c) - b for c, b in zip(counters, before)] == [9, 0]
+    assert [metrics.get(c) - b for c, b in zip(counters, before)] \
+        == [3 * per_form, 0]
     assert "ragged_dot" not in str(traced.jaxpr)
     kernels = re.findall(
         r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
         r'op_name="([^"]*)"', text)
-    assert len(kernels) == 9, kernels
+    assert len(kernels) == 3 * per_form, kernels
     for name, op_name in kernels:
         assert "ragged-dot" in name and "moe.experts" in op_name, (
             name, op_name)
     kinds = sorted(name.rsplit(".", 1)[0] for name, _ in kernels)
-    assert kinds == (["ragged-dot-gmm"] * 3 + ["ragged-dot-gmm-t"] * 3
-                     + ["ragged-dot-tgmm"] * 3)
+    assert kinds == (["ragged-dot-gmm"] * per_form
+                     + ["ragged-dot-gmm-t"] * per_form
+                     + ["ragged-dot-tgmm"] * per_form)
     # an [E, ., .] operand reaches its kernel as the parameter it is
-    assert not re.search(r"= bf16\[16,\d+,\d+\][^ ]* (copy|transpose)\(",
+    assert not re.search(rf"= bf16\[{e},\d+,\d+\][^ ]* (copy|transpose)\(",
                          text)
     for k, w in ((d, f), (f, d)):
-        for tiles in (gm.gmm_tiles(rows, k, w), gm.tgmm_tiles(rows, k, w)):
-            assert (tiles.tk, tiles.tn) == (k, w)
+        whole, cut = gm.gmm_tiles(rows, k, w), gm.tgmm_tiles(rows, k, w)
+        assert (whole.tk, whole.tn) == (k, w)
+        # where the float32 accumulator does not fit, a multiple of 128 is
+        # cut; a width that is none stays whole
+        assert (cut.tk, cut.tn) == (k, w) if f % 128 == 0 \
+            else f in (cut.tk, cut.tn)
+        for tiles in (whole, cut):
             assert tiles.resident_bytes + (8 << 20) <= 100 << 20

@@ -514,8 +514,8 @@ def _census_trainer(withhold=False):
 def test_train_step_census_nine_grouped_matmuls_a_layer():
     """A trace of the step lowers 9 grouped matmuls an expert layer (3
     forward, 6 backward; 12 with the forward's three repeated), counted by
-    `moe.grouped_pallas` + `moe.grouped_xla` (at this preset's widths, no
-    multiples of 128, every one is a `ragged_dot_general`), nothing
+    `moe.grouped_pallas` + `moe.grouped_xla` (at this preset's widths, under
+    one lane tile of 128, every one is a `ragged_dot_general`), nothing
     under `jax.checkpoint`, and no float32 value of the `[k, N, d]` /
     `[k*N, d]` buffers' size, forward or backward: the combine weight goes
     in ahead of the down projection, over f columns."""

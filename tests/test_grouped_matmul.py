@@ -2,8 +2,9 @@
 interpreter, in float32 against `jax.lax.ragged_dot` /
 `ragged_dot_general`: its three forms on uneven groups (an empty group, a
 boundary inside a row tile, a boundary on a tile's edge, a last group
-swollen to the buffer's end), at widths that are odd multiples of 128; the
-visit tables; the tile rule as a function of shapes; which way `ops/moe.py`
+swollen to the buffer's end), at widths that are odd multiples of 128 and
+at widths of a lane tile or more that are no multiple of 128 (one
+full-width block); the visit tables; the tile rule as a function of shapes; which way `ops/moe.py`
 sends a shape, by the counters; and `routed_moe`'s gradients through the
 kernels against the generic route and the plain float32 reference layer.
 The kernels compiled for a described v5e at the benchmark's sizes are in
@@ -77,6 +78,28 @@ def test_form_follows_ragged_dot(form, k, n, case):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
 
 
+@pytest.mark.parametrize("case", SIZES)
+@pytest.mark.parametrize("k, n", [(232, 384), (384, 232), (232, 168)])
+@pytest.mark.parametrize("form", FORMS)
+def test_a_width_that_is_no_multiple_of_128_is_one_block(form, k, n, case):
+    """232 and 168 stand for 1856 = 14.5 x 128: a lane tile or more, a
+    multiple of 8, no multiple of 128, as the contraction, as the result's
+    width and as both. Its block is the whole width."""
+    held = jnp.asarray(SIZES[case], jnp.int32)
+    x, g, w = _operands(k, n, held.shape[0])
+    groups = moe._RowGroups(held, ROWS, True)
+    fn = jax.jit(lambda x, g, w: _through_moe(form, x, g, w, groups))
+    jaxpr, rise = _rise(lambda: str(jax.make_jaxpr(fn)(x, g, w)))
+    assert rise == (1, 0)
+    assert "pallas_call" in jaxpr and "ragged_dot" not in jaxpr
+    rule = gm.tgmm_tiles if form == "tgmm" else gm.gmm_tiles
+    assert rule(ROWS, k, n, 4, 4)[:3] == (32, k, n)
+    got = _through_moe(form, x, g, w, moe._RowGroups(held, ROWS, False))
+    want = _xla(form, x, g, w, groups.sizes)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
+
+
 @pytest.mark.parametrize("form", FORMS)
 def test_tiles_smaller_than_the_widths(form):
     """The grid's k and n axes: a budget that holds no whole matrix."""
@@ -94,10 +117,11 @@ def test_tiles_smaller_than_the_widths(form):
                                atol=2e-4)
 
 
+@pytest.mark.parametrize("k, n", [(384, 128), (232, 168)])
 @pytest.mark.parametrize("form", FORMS)
-def test_bf16_operands_accumulate_in_float32(form):
+def test_bf16_operands_accumulate_in_float32(form, k, n):
     held = jnp.asarray(SIZES["boundary_in_tile_and_on_edge"], jnp.int32)
-    x, g, w = (a.astype(jnp.bfloat16) for a in _operands(384, 128, 5))
+    x, g, w = (a.astype(jnp.bfloat16) for a in _operands(k, n, 5))
     groups = moe._RowGroups(held, ROWS, False)
     got, rise = _rise(lambda: _through_moe(form, x, g, w, groups))
     assert rise == (0, 0)                # a trace that does not count
@@ -105,14 +129,14 @@ def test_bf16_operands_accumulate_in_float32(form):
     exact = _xla(form, *(a.astype(jnp.float32) for a in (x, g, w)),
                  groups.sizes)
     # one rounding of a float32 sum: within a bf16 ulp of the exact result
-    # (64 to 384 products a sum; bf16 partial sums would be off by several)
+    # (7 to 384 products a sum; bf16 partial sums would be off by several)
     np.testing.assert_allclose(np.asarray(got, np.float32), exact,
                                rtol=2 ** -8, atol=2 ** -8)
 
 
 @pytest.mark.parametrize("k, n", [(384, 96), (100, 128), (32, 16)])
 @pytest.mark.parametrize("form", FORMS)
-def test_a_width_that_is_no_multiple_of_128_keeps_ragged_dot(form, k, n):
+def test_a_width_under_one_lane_tile_keeps_ragged_dot(form, k, n):
     held = jnp.asarray(SIZES["boundary_in_tile_and_on_edge"], jnp.int32)
     x, g, w = _operands(k, n, 5)
     groups = moe._RowGroups(held, ROWS, True)
@@ -123,6 +147,14 @@ def test_a_width_that_is_no_multiple_of_128_keeps_ragged_dot(form, k, n):
     np.testing.assert_allclose(
         _through_moe(form, x, g, w, moe._RowGroups(held, ROWS, False)),
         _xla(form, x, g, w, groups.sizes), rtol=1e-6, atol=1e-6)
+
+
+def test_a_width_no_sublane_tile_divides_keeps_ragged_dot():
+    """132 = 16.5 x 8: the last register of a weight block's second-minor
+    dimension would hold four rows."""
+    for rule in (gm.gmm_tiles, gm.tgmm_tiles):
+        assert rule(96, 132, 256) is None and rule(96, 256, 132) is None
+        assert rule(96, 136, 256)[1:3] == (136, 256)
 
 
 def test_rows_no_row_tile_divides_keep_ragged_dot():
@@ -170,6 +202,31 @@ def test_tile_rule_keeps_a_whole_matrix_resident(k, n, gmm_bytes, tgmm_bytes):
     assert gmm_bytes == (2 * (256 * k + k * n) * 2 + 2 * 256 * n * 2
                          + 4 * 256 * n * 4)
     assert max(gmm_bytes, tgmm_bytes) <= gm.VMEM_BUDGET
+
+
+# the hybrid cell's expert matmuls, [49152, 2688] x [8, 2688, 1856] and
+# back: 1856 = 14.5 x 128 is one block wherever it stands, and VMEM holds it
+# as 1920 lanes
+@pytest.mark.parametrize("k, n, gmm_bytes, tgmm_tiles, tgmm_bytes", [
+    (2688, 1856, 33_226_752, (896, 1856), 23_986_176),
+    (1856, 2688, 36_372_480, (1856, 896), 23_986_176)])
+def test_tile_rule_takes_an_unaligned_width_whole(k, n, gmm_bytes,
+                                                  tgmm_tiles, tgmm_bytes):
+    """`gmm`: a whole weight matrix resident, as at the aligned cells.
+    `tgmm`: a 1856 x 2688 float32 accumulator does not fit, and the width
+    that can be cut is: 2688 = 3 x 896."""
+    assert gm.gmm_tiles(49152, k, n) == gm.Tiles(256, k, n, gmm_bytes)
+    assert gm.tgmm_tiles(49152, k, n) == gm.Tiles(256, *tgmm_tiles,
+                                                  tgmm_bytes)
+    held = {1856: 1920}                  # lanes rounded up to 128
+    pk, pn = (held.get(w, w) for w in (k, n))
+    assert gmm_bytes == (2 * (256 * pk + pk * pn) * 2 + 2 * 256 * pn * 2
+                         + 4 * 256 * pn * 4)
+    tk, tn = (held.get(w, w) for w in tgmm_tiles)
+    assert tgmm_bytes == (2 * (256 * tk + 256 * tn) * 2 + 2 * tk * tn * 2
+                          + 2 * tk * tn * 4 + 256 * min(tk, tn) * 2)
+    assert max(gmm_bytes, tgmm_bytes) <= gm.VMEM_BUDGET \
+        < gm._tgmm_resident(256, k, n, 2, 2)
 
 
 def test_tile_rule_cuts_k_before_n_when_a_matrix_does_not_fit():

@@ -593,23 +593,15 @@ def test_builder_names_scopes_and_checkpoints_and_verifies():
         nemotron_h.build_causal_lm_program(cfg)
 
 
-@pytest.mark.parametrize("recompute, rise", [
-    (False, (4, 4, 0, 4, 4, 0, 1, 1, 1, 0)),
-    (True, (4, 0, 4, 4, 0, 4, 2, 2, 0, 1))], ids=["plain", "recompute"])
-def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
-    """With the flash gate open (here: the interpreter), one trace of the
-    AMP train step lowers four scans, four expert layers and one flash
-    forward on grouped KV heads; their backward by each op's grad rule on
-    the forward's residuals, or, with a checkpoint at every layer boundary
-    (the cell's way: the step does not fit the chip without), by the same
-    backward functions under `jax.vjp` of a whole layer, the forward lowered
-    once more. The step's jaxpr holds no `[S, H, P, N]` value."""
-    from paddle_tpu.ops import attention
-    monkeypatch.setattr(attention, "_use_pallas",
-                        lambda q: q.shape[2] % 128 == 0)
+def _amp_step(recompute, **changed):
+    """(executor, loss, ids [2, 1, 128]) of the tiny preset at 128 tokens
+    in chunks of 32 with `changed` set, its AMP train step built through
+    fleet, with a checkpoint at every layer boundary if `recompute`."""
     reset_programs(0)
     cfg = nemotron_h.NemotronHConfig.tiny()
-    cfg.seq_len, cfg.head_dim, cfg.chunk_size = 128, 64, 32
+    cfg.seq_len, cfg.chunk_size = 128, 32
+    for key, value in changed.items():
+        setattr(cfg, key, value)
     _, loss, _ = nemotron_h.build_causal_lm_program(cfg)
     fleet.init(is_collective=True)
     strategy = fleet.DistributedStrategy()
@@ -624,6 +616,24 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
     exe.run(fluid.default_startup_program())
     ids = np.random.RandomState(0).randint(0, 256, (2, 1, 128)).astype(
         np.int64)
+    return exe, loss, ids
+
+
+@pytest.mark.parametrize("recompute, rise", [
+    (False, (4, 4, 0, 4, 4, 0, 1, 1, 1, 0)),
+    (True, (4, 0, 4, 4, 0, 4, 2, 2, 0, 1))], ids=["plain", "recompute"])
+def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
+    """With the flash gate open (here: the interpreter), one trace of the
+    AMP train step lowers four scans, four expert layers and one flash
+    forward on grouped KV heads; their backward by each op's grad rule on
+    the forward's residuals, or, with a checkpoint at every layer boundary
+    (the cell's way: the step does not fit the chip without), by the same
+    backward functions under `jax.vjp` of a whole layer, the forward lowered
+    once more. The step's jaxpr holds no `[S, H, P, N]` value."""
+    from paddle_tpu.ops import attention
+    monkeypatch.setattr(attention, "_use_pallas",
+                        lambda q: q.shape[2] % 128 == 0)
+    exe, loss, ids = _amp_step(recompute, head_dim=64)
     before = [metrics.get(c) for c in _COUNTERS]
     jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
     assert tuple(int(metrics.get(c) - b)
@@ -638,3 +648,27 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
     for scope in ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
                   "ssm.out_proj", "attn.proj", "moe.shared", "moe.experts"):
         assert f"/{scope}/" in hlo, scope
+
+
+@pytest.mark.parametrize("recompute, kernels", [(False, 24), (True, 40)],
+                         ids=["plain", "recompute"])
+def test_at_an_unaligned_expert_width_every_grouped_matmul_is_a_kernel(
+        recompute, kernels):
+    """The cell's expert width is 1856 = 14.5 x 128; here hidden 128 and an
+    expert width of 232 = 29 x 8: a lane tile or more, no multiple of 128.
+    One trace of the AMP train step sends every grouped matmul (2 forward
+    and 4 backward a layer; under recomputation the trace passes the
+    forward's two twice more) to the Pallas kernels, the width as one
+    block, and none to `jax.lax.ragged_dot`."""
+    exe, loss, ids = _amp_step(recompute, hidden_size=128,
+                               moe_intermediate_size=232)
+    counters = ("moe.layers_lowered", "moe.grouped_pallas",
+                "moe.grouped_xla")
+    before = [metrics.get(c) for c in counters]
+    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
+    assert [int(metrics.get(c) - b) for c, b in zip(counters, before)] \
+        == [4, kernels, 0]
+    assert "ragged_dot" not in jaxpr
+    for form in ("gmm", "gmm-t", "tgmm"):
+        assert re.search(rf"name=ragged-dot-{form}\s", jaxpr), form
+    assert "bf16[8,128,232]" in jaxpr and "bf16[8,232,128]" in jaxpr
