@@ -20,9 +20,23 @@ Layout:
 """
 from __future__ import annotations
 
+import sys as _sys
 import time as _time
 
 _IMPORT_T0_NS = _time.perf_counter_ns()   # the `startup.import` span's start
+
+
+def _entered_with() -> dict:
+    """What the caller had done before the package was entered: the
+    harness imports jax and starts the backend first, a user's script
+    usually neither. Args of the `startup.boot` span."""
+    bridge = _sys.modules.get("jax._src.xla_bridge")
+    return {"jax_imported": "jax" in _sys.modules,
+            "backend_initialized": bool(
+                bridge is not None and bridge.backends_are_initialized())}
+
+
+_ENTERED_WITH = _entered_with()
 
 # --- fluid-style core -------------------------------------------------------
 from .framework.program import (Program, program_guard, default_main_program,
@@ -135,7 +149,6 @@ from . import dataset as _fluid_dataset  # noqa: E402,F401
 # Legacy paddle.dataset.* reader modules live on the same `dataset`
 # namespace as fluid's DatasetFactory (reference python/paddle/dataset/):
 # paddle.dataset.mnist.train() and fluid.dataset.DatasetFactory() both work.
-import sys as _sys  # noqa: E402
 from . import dataset_legacy as _dataset_legacy  # noqa: E402
 
 
@@ -155,9 +168,14 @@ from . import profiler  # noqa: E402
 from . import monitor  # noqa: E402
 from .flags import get_flags, set_flags  # noqa: E402
 
-# --- observability: the import's own span, JAX's compile phases as spans ----
+# --- observability: the time before the package was entered and the import's
+# own span, JAX's compile phases as spans ------------------------------------
 from .observability import compile_events as _compile_events  # noqa: E402
 from .observability import trace as _trace  # noqa: E402
 
 _compile_events.install()
+_created_us = _trace.process_created_us()
+if _created_us is not None:       # no creation time from the OS, no span
+    _trace.complete("startup.boot", int(_created_us * 1000), _IMPORT_T0_NS,
+                    args=_ENTERED_WITH)
 _trace.complete("startup.import", _IMPORT_T0_NS, _time.perf_counter_ns())

@@ -35,6 +35,9 @@ from ..ops import registry
 # flight-recorder owner ids: stable per Executor instance (id() can be
 # reused after GC), assigned lazily by _step_window
 _flight_owner_ids = itertools.count(1)
+# until the first `executor.step` root of a main program has closed: the
+# gauge `startup.time_to_first_step_s` is set there, once a process
+_first_step_unread = True
 
 
 class _CompiledBlock:
@@ -367,6 +370,18 @@ def _buffer_nbytes(block, name, shape) -> int:
     return n * itemsize
 
 
+def _note_time_to_first_step():
+    """The operator's own reading of what a restart costs: seconds from the
+    OS's creation of the process to the close of its first main-program
+    dispatch (no gauge where the OS gives no creation time)."""
+    global _first_step_unread
+    _first_step_unread = False
+    created_us = _trace.process_created_us()
+    if created_us is not None:
+        _metrics.set_gauge("startup.time_to_first_step_s",
+                           (_trace.now_us() - created_us) * 1e-6)
+
+
 # Stack of programs being traced; sub-block ops (__cond__ etc.) look up their
 # sub-blocks through this (trace-time only, never at run time).
 _lowering_programs: List = []
@@ -374,6 +389,83 @@ _lowering_programs: List = []
 
 def _current_lowering_program():
     return _lowering_programs[-1]
+
+
+class _LowerTable:
+    """Lowering seconds by op type over ONE top-level walk of an op list,
+    the `by_op` arg of its `executor.lower_block` span. SELF time by leaf
+    type: what a lowering spends in lowerings it runs itself (`__segment__`,
+    `__layer_scan__` through `_run_sub_ops`, a sub-block's walk) counts to
+    those ops and is taken off the container's row."""
+
+    __slots__ = ("rows", "inner_s")
+    TOP = 8         # rows the span carries
+
+    def __init__(self):
+        self.rows: Dict[str, list] = {}     # type -> [count, self seconds]
+        self.inner_s = 0.0          # seconds in lowerings under the open one
+
+    def top(self) -> list:
+        rows = sorted(self.rows.items(), key=lambda kv: -kv[1][1])
+        return [[t, n, round(sec, 6)] for t, (n, sec) in rows[:self.TOP]]
+
+
+# The table of the walk in progress, kept like _lowering_programs: trace-time
+# only. None outside a walk, and then nothing is timed.
+_lower_table: Optional[_LowerTable] = None
+
+
+@contextlib.contextmanager
+def _lower_walk(n_ops, shapes_only):
+    """Around a walk of an op list. A top-level walk is one
+    `executor.lower_block` span (args `ops`, `shapes_only`, `by_op`: the op
+    types with the most lowering seconds as [type, count, seconds]); a walk
+    inside a lowering (a `__cond__` branch) adds to the table that is open.
+    A context manager and not a wrapper, as `_op_timer` is: no frame of the
+    tracing's own stands between the walk and the lowerings, whose every
+    primitive carries its Python stack."""
+    global _lower_table
+    if _lower_table is not None:
+        yield
+        return
+    table = _lower_table = _LowerTable()
+    span = _trace.RecordEvent("executor.lower_block", args={
+        "ops": n_ops, "shapes_only": bool(shapes_only)})
+    try:
+        with span:
+            try:
+                yield
+            finally:
+                span.add_args(by_op=table.top())
+    finally:
+        _lower_table = None
+
+
+class _op_timer:
+    """`with _op_timer(op_type, attrs): opdef.lower(...)`: two clock
+    readings into the open table (nothing outside a walk). A `__vjp__`
+    counts as `grad(<forward type>)`."""
+
+    __slots__ = ("key", "table", "outer_s", "t0")
+
+    def __init__(self, op_type, attrs):
+        self.key = (f"grad({attrs.get('fwd_type')})"
+                    if op_type == "__vjp__" else op_type)
+
+    def __enter__(self):
+        table = self.table = _lower_table
+        if table is not None:
+            self.outer_s, table.inner_s = table.inner_s, 0.0
+            self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        table = self.table
+        if table is not None:
+            dt = time.perf_counter() - self.t0
+            row = table.rows.setdefault(self.key, [0, 0.0])
+            row[0] += 1
+            row[1] += dt - table.inner_s
+            table.inner_s = self.outer_s + dt
 
 
 def _run_block(block, feed_names, fetch_names, mut_names, ro_names,
@@ -395,34 +487,42 @@ def _run_block(block, feed_names, fetch_names, mut_names, ro_names,
 
 
 def _run_block_inner(block, fetch_names, written_state, env, ctx):
+    """Walk `block.ops` over `env`: the ONE place every lowering route
+    shares (`_run_block`, `_run_block_microbatched`, `parallel/pipeline.py`),
+    so the one place that opens `executor.lower_block`. It runs inside the
+    traced function: the span exists only while JAX traces, never in a warm
+    dispatch."""
     amp_dtype = None
     if getattr(block.program, "_amp", False):
         import jax.numpy as jnp
         amp_dtype = (jnp.bfloat16
                      if getattr(block.program, "_amp_dtype", "bfloat16")
                      == "bfloat16" else jnp.float16)
-    for op in block.ops:
-        opdef = registry.get(op.type)
-        ins = {}
-        for slot, names in op.inputs.items():
-            ins[slot] = [None if n == "@EMPTY@" else env[n] for n in names]
-        if amp_dtype is not None:
-            ins = _amp_cast(op, ins, amp_dtype)
-        scope = op.attrs.get("name_scope") or (
-            op.type == "__vjp__"
-            and op.attrs["fwd_attrs"].get("name_scope"))
-        # program.name_scope: a group's device work, named
-        with (jax.named_scope(scope) if scope
-              else contextlib.nullcontext()):
-            outs = opdef.lower(ctx, ins, op.attrs)
-        for slot, names in op.outputs.items():
-            if slot not in outs:
-                continue
-            vals = outs[slot]
-            for n, v in zip(names, vals):
-                if n == "@EMPTY@" or v is None:
+    with _lower_walk(len(block.ops), ctx.is_eval_shape):
+        for op in block.ops:
+            opdef = registry.get(op.type)
+            ins = {}
+            for slot, names in op.inputs.items():
+                ins[slot] = [None if n == "@EMPTY@" else env[n]
+                             for n in names]
+            if amp_dtype is not None:
+                ins = _amp_cast(op, ins, amp_dtype)
+            scope = op.attrs.get("name_scope") or (
+                op.type == "__vjp__"
+                and op.attrs["fwd_attrs"].get("name_scope"))
+            # program.name_scope: a group's device work, named
+            with (jax.named_scope(scope) if scope
+                  else contextlib.nullcontext()), \
+                    _op_timer(op.type, op.attrs):
+                outs = opdef.lower(ctx, ins, op.attrs)
+            for slot, names in op.outputs.items():
+                if slot not in outs:
                     continue
-                env[n] = v
+                vals = outs[slot]
+                for n, v in zip(names, vals):
+                    if n == "@EMPTY@" or v is None:
+                        continue
+                    env[n] = v
     fetches = [env[n] for n in fetch_names]
     new_state = {n: env[n] for n in written_state if n in env}
     return fetches, new_state
@@ -1171,10 +1271,10 @@ class Executor:
             _prof.start_profiler()
         _flight.begin_step(idx, owner=owner)
         status = "ok"
+        is_startup = program is default_startup_program()
         root = _trace.RecordEvent("executor.step", args={
             "step": idx, "exe": owner, "kind": kind, "k": k,
-            "program": ("startup" if program is default_startup_program()
-                        else "main"),
+            "program": "startup" if is_startup else "main",
             "ops": op_count(program)})
         try:
             with root:
@@ -1183,6 +1283,8 @@ class Executor:
             status = "error"
             raise
         finally:
+            if _first_step_unread and not is_startup:
+                _note_time_to_first_step()
             _flight.end_step(idx, status=status, owner=owner)
             if idx == flag("FLAGS_profile_stop_step"):
                 _prof.stop_profiler()

@@ -24,7 +24,6 @@ from typing import Optional
 import numpy as np
 
 from .. import monitor
-from ..observability import metrics as _metrics
 from ..observability import trace as _trace
 
 
@@ -32,7 +31,6 @@ def _record_sync(dt_s: float, n_values: int = 1):
     """One ledger for every host materialization (lazy or eager)."""
     monitor.stat_add("executor.fetch_sync_count", n_values)
     monitor.stat_add("executor.host_blocked_ms", dt_s * 1000.0)
-    _metrics.observe("executor.fetch_sync_ms", dt_s * 1000.0)
 
 
 class FetchHandle:

@@ -8,7 +8,7 @@ existing call site (`executor.*`, `resilience.*`,
 `executor.zero_manual_fallbacks.*`) therefore lands in the same registry
 the tracer/flight recorder snapshot and diff. New code should use
 `paddle_tpu.observability.metrics` directly (histograms with p50/p99,
-snapshot/delta, JSONL export); the dotted-namespace tables formerly split
+snapshot/delta as plain JSON); the dotted-namespace tables formerly split
 across this docstring, docs/perf_notes.md and docs/resilience.md are
 consolidated in docs/observability.md.
 """

@@ -6,7 +6,7 @@ timeline.py — unified here; see docs/observability.md).
 Layering:
 
 * `metrics` — counters / gauges / histograms under dotted namespaces with
-  snapshot/delta views and JSONL export. `paddle_tpu.monitor` is a compat
+  snapshot/delta views (plain JSON). `paddle_tpu.monitor` is a compat
   shim over it (stat_add -> counter, stat_set -> gauge).
 * `trace` — RecordEvent spans (a tree: id, parent, the root's step),
   instants and cross-thread flow events in a bounded always-on ring, each
@@ -14,7 +14,8 @@ Layering:
   capture; chrome-trace/Perfetto export. `paddle_tpu.profiler`
   (fluid.profiler / paddle.profiler.Profiler) is a compat shim over it.
 * `compile_events` — JAX's trace / lower / backend-compile reports as
-  `compile.*` child spans, persistent-cache hits and misses as counters.
+  `compile.*` child spans; what the persistent cache did for each
+  `compile.backend` as its `cache` arg, and as counters.
 * `flight` — the last N steps' spans + metric deltas, auto-dumped on step
   watchdog trips, gang failures, and degraded bench rows.
 * `podscope` — pod-scale aggregation: N per-rank flight dumps merged into

@@ -180,11 +180,6 @@ def dump(reason: str, path: Optional[str] = None,
             seq = _dump_seq
         since = min((s["t0_us"] for s in step_recs), default=None)
         ident = pod_identity()
-        # the clock handshake: both clocks read back-to-back, so the pair
-        # maps this process's trace (perf_counter) epoch onto the shared
-        # wall clock for pod-scope merging (podscope.align-events)
-        clock = {"wall_time_us": time.time() * 1e6,
-                 "trace_ts_us": _trace.now_us()}
         payload = {
             "format": 1,
             "reason": reason,
@@ -193,7 +188,9 @@ def dump(reason: str, path: Optional[str] = None,
             "world": ident["world"],
             "role": ident["role"],
             "wall_time": time.time(),
-            "clock": clock,
+            # pod-scope merging places every rank on the wall clock by it
+            # (podscope.align-events)
+            "clock": _trace.clock_handshake(),
             "dropped_events": _trace.dropped_events(),
             "steps": step_recs,
             "trace_events": (_trace.process_metadata_events()
@@ -215,7 +212,6 @@ def dump(reason: str, path: Optional[str] = None,
                 os.makedirs(pd, exist_ok=True)
         with open(path, "w") as f:
             json.dump(payload, f)
-        _metrics.inc("observability.flight_dumps")
         return path
     except Exception:
         return None

@@ -7,7 +7,7 @@ alive as a shim while adding what the flat dict could not express:
 
 * **types** — a counter (monotonic sum: retries, fallbacks, h2d_ms) is not
   a gauge (last value: queue depth) is not a histogram (distribution:
-  per-step host ms, fetch-sync ms with p50/p99);
+  time to first token, decode-window ms with p50/p99);
 * **snapshot/delta views** — the flight recorder diffs two snapshots to
   attribute metric movement to ONE step (observability/flight.py);
 * **export** — `snapshot()` is plain JSON (bench.py stamps it into
@@ -148,9 +148,9 @@ def snapshot(percentiles: bool = True) -> Dict[str, dict]:
 
         {"executor.h2d_ms":   {"type": "counter", "value": 12.5},
          "executor.dispatch_queue_depth": {"type": "gauge", "value": 1},
-         "executor.fetch_sync_ms": {"type": "histogram", "count": 20,
-                                    "sum": ..., "min": ..., "max": ...,
-                                    "p50": ..., "p99": ...}}
+         "serving.ttft_ms": {"type": "histogram", "count": 20,
+                             "sum": ..., "min": ..., "max": ...,
+                             "p50": ..., "p99": ...}}
 
     percentiles=False skips the p50/p99 fields — they cost a sort of each
     histogram's reservoir, which the flight recorder's twice-per-step

@@ -66,6 +66,37 @@ def now_us() -> float:
     return time.perf_counter_ns() / 1000.0
 
 
+def clock_handshake() -> dict:
+    """The wall clock and the trace clock read back to back: the pair maps
+    this process's trace (perf_counter) epoch onto the shared wall clock.
+    flight.dump writes it as `clock` (podscope aligns ranks by it);
+    process_created_us moves the OS's creation time onto the trace clock."""
+    return {"wall_time_us": time.time() * 1e6, "trace_ts_us": now_us()}
+
+
+def process_created_us() -> Optional[float]:
+    """When the OS created this process, on the trace clock (microseconds;
+    negative where the process is older than perf_counter's epoch). Linux:
+    the start time of /proc/self/stat in clock ticks since boot, against
+    /proc/uptime, gives the process's age at the instant of a handshake;
+    the wall clock less the age is the creation time, which the handshake's
+    offset moves onto the trace clock. None where the OS gives none."""
+    try:
+        with open("/proc/self/stat") as f:
+            # fields after the command's closing parenthesis: state is the
+            # first, starttime the 20th (proc(5) field 22)
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        clock = clock_handshake()
+        with open("/proc/uptime") as f:
+            up_s = float(f.read().split()[0])
+        age_us = (up_s - ticks / os.sysconf("SC_CLK_TCK")) * 1e6
+    except (OSError, ValueError, IndexError):
+        return None
+    # wall_time_us - age_us is the creation time on the wall clock; the
+    # handshake's offset (wall_time_us - trace_ts_us) taken off it leaves:
+    return clock["trace_ts_us"] - age_us
+
+
 def enabled() -> bool:
     return bool(flag("FLAGS_trace_events"))
 

@@ -62,6 +62,7 @@ def _run_sub_ops(ctx, sub_ops, env, amp_dtype, seed_overrides=None):
     casts (the top-level executor loop applies these per op; fused
     sub-graphs must match) and optional per-op __rng_seed__ overrides
     (traced per-layer seeds inside the scan body)."""
+    from ..framework.executor import _amp_cast_ins, _op_timer
     for j, od in enumerate(sub_ops):
         opdef = registry.get(od["type"])
         op_ins = {s: [None if n == "@EMPTY@" else env[n] for n in ns]
@@ -71,12 +72,13 @@ def _run_sub_ops(ctx, sub_ops, env, amp_dtype, seed_overrides=None):
             at = dict(at)
             at["__rng_seed__"] = seed_overrides[j]
         if amp_dtype is not None:
-            from ..framework.executor import _amp_cast_ins
             op_ins = _amp_cast_ins(od["type"], op_ins, amp_dtype)
         # program.name_scope, as the executor's own op loop applies it: a
-        # group's device work keeps its name inside a segment
+        # group's device work keeps its name inside a segment. _op_timer:
+        # into the walk's `by_op` table under the op's own type, not the
+        # container's (executor.lower_block)
         with (jax.named_scope(at["name_scope"]) if at.get("name_scope")
-              else contextlib.nullcontext()):
+              else contextlib.nullcontext()), _op_timer(od["type"], at):
             outs = opdef.lower(ctx, op_ins, at)
         for s, ns in od["outputs"].items():
             if s not in outs:

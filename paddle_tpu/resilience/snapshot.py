@@ -272,7 +272,6 @@ class SnapshotManager:
             self._newest = standby        # swap AFTER the copy completed
         dt_ms = (time.perf_counter() - t0) * 1000.0
         _metrics.inc("resilience.snapshots")
-        _metrics.observe("resilience.snapshot_ms", dt_ms)
         _trace.instant("snapshot", args={"step": step,
                                          "ms": round(dt_ms, 3)},
                        cat="resilience")
@@ -308,7 +307,6 @@ class SnapshotManager:
             if payload is not None:
                 self._peer = Snapshot(payload[0], payload[1], rank=buddy)
         if payload is not None:
-            _metrics.inc("resilience.peer_replications")
             return int(payload[0])
         return None
 
@@ -336,14 +334,12 @@ class SnapshotManager:
             path = mgr.save(snap.step, arrays=snap.arrays,
                             meta={"kind": "snapshot", "reason": reason,
                                   "rank": self.rank})
-            _metrics.inc("resilience.snapshot_flushes")
         if peer is not None:
             mgr = CheckpointManager(self._peer_dir(peer.rank), max_keep=2)
             mgr.save(peer.step, arrays=peer.arrays,
                      meta={"kind": "peer_snapshot", "reason": reason,
                            "origin_rank": peer.rank,
                            "held_by_rank": self.rank})
-            _metrics.inc("resilience.snapshot_flushes")
         return path
 
     def install_sigterm_flush(self, exit_after: bool = True) -> None:
@@ -437,8 +433,6 @@ def recover(scope, root: Optional[str] = None, rank: Optional[int] = None,
         restored = ckpt_manager.restore_latest(scope=scope)
         if restored is not None:
             chosen, step = "disk", int(restored)
-    if chosen is not None:
-        _metrics.inc(f"resilience.recover_{chosen}")
     if stamp:
         _stamp_recovery(root, rank, chosen, step)
     return chosen, step

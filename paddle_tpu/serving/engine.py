@@ -762,7 +762,6 @@ class DecodeEngine:
             return None
         fid = _trace.new_flow()
         handle = RequestHandle(request, flow_id=fid)
-        _metrics.inc("serving.requests")
         if self._dead:
             return self._shed(handle, "engine_dead",
                               f"engine dead: {self._dead}")
@@ -770,7 +769,6 @@ class DecodeEngine:
             return self._shed(handle, "draining", "engine draining")
         reason = self._reject_reason(request)
         if reason is not None:
-            _metrics.inc("serving.rejected")
             handle._finish(RequestState.REJECTED, reason)
             return handle
         # a budget the pool could NEVER fund must shed now, not park at
@@ -1277,7 +1275,6 @@ class DecodeEngine:
         handle._set_state(RequestState.PREFILL)
         _trace.instant("serving.admit",
                        args={"uid": req.uid, "slot": slot_idx})
-        _metrics.inc("serving.prefills")
         try:
             if matched:
                 first = self._suffix_prefill(slot_idx, req, plen,
@@ -1309,7 +1306,6 @@ class DecodeEngine:
                 handle, pos=plen, gen=1, token=tok, eos=eos,
                 max_new=req.max_new_tokens, temp=float(req.temperature),
                 top_k=int(req.top_k), seed=int(req.seed))
-        _metrics.set_gauge("serving.active_slots", len(self._slots))
         if self.spec is not None:
             # mapped/reserve split (cache.py): keep only the blocks the
             # prefill actually wrote in the page-table row; the rest of
@@ -1544,7 +1540,6 @@ class DecodeEngine:
         return len(emitted), finished
 
     def _apply_window(self, toks: np.ndarray, acts: np.ndarray):
-        n_tokens = 0
         for idx in list(self._slots):
             slot = self._slots.get(idx)
             if slot is None:    # defensively tolerate a concurrent clear
@@ -1554,10 +1549,7 @@ class DecodeEngine:
                 if not acts[t, idx]:
                     break
                 run.append(int(toks[t, idx]))
-            n, _ = self._apply_slot_tokens(idx, slot, run)
-            n_tokens += n
-        _metrics.inc("serving.tokens_out", n_tokens)
-        _metrics.set_gauge("serving.active_slots", len(self._slots))
+            self._apply_slot_tokens(idx, slot, run)
 
     # ---- speculative verify round (serving/spec.py drives this) ---------
     def _verify_args(self, cand: np.ndarray, valid: np.ndarray):

@@ -25,7 +25,6 @@ import signal
 import threading
 from typing import List, Optional
 
-from ..observability import metrics as _metrics
 from .engine import DecodeEngine, EngineConfig
 from .request import Request, RequestHandle
 from .resilience import NoHealthyReplicaError, ServingFrontend  # noqa: F401
@@ -76,7 +75,6 @@ class RoundRobinFrontend:
         for probe in range(n):
             eng = self.engines[(start + probe) % n]
             if eng._dead is None:
-                _metrics.inc("serving.frontend_dispatch")
                 return eng.submit(request, bounded=bounded)
         # every replica dead: a typed signal the caller can act on
         # (restart the service, fail over to another pod) — silently

@@ -148,7 +148,6 @@ class ServingFrontend:
             eng = min(with_room or live, key=lambda e: e.load())
             handle = eng.submit(request, _probe=True, bounded=bounded)
             if handle is not None:
-                _metrics.inc("serving.frontend_dispatch")
                 return handle
         dead = sum(1 for e in self.engines if e.health == Health.DEAD)
         raise NoHealthyReplicaError(
@@ -412,7 +411,6 @@ class ServingFrontend:
             timeout_s = float(flag("FLAGS_serving_drain_timeout_ms")) \
                 / 1000.0
         self._draining = True
-        _metrics.inc("serving.drains")
         deadline = time.monotonic() + timeout_s
         handed_back: List[Request] = []
         for eng in self.engines:
@@ -424,7 +422,6 @@ class ServingFrontend:
             remaining = max(deadline - time.monotonic(), 0.1)
             handed_back.extend(
                 req for req, _ in eng.drain(timeout_s=remaining))
-        _metrics.inc("serving.drained_unstarted", len(handed_back))
         return handed_back
 
     def stop(self):

@@ -414,7 +414,6 @@ class SpecDecoder:
         vtok, n_acc = eng._run_verify(cand, valid)
         self._rounds += 1
         _metrics.inc("serving.spec.rounds")
-        n_tokens = 0
         for idx in list(eng._slots):
             slot = eng._slots.get(idx)
             if slot is None:
@@ -428,11 +427,8 @@ class SpecDecoder:
                 _metrics.inc("serving.spec.proposed", g)
             if a:
                 _metrics.inc("serving.spec.accepted", a)
-            if g - a:
-                _metrics.inc("serving.spec.rejected", g - a)
-            n, finished = eng._apply_slot_tokens(
+            _, finished = eng._apply_slot_tokens(
                 idx, slot, [int(vtok[idx, j]) for j in range(a + 1)])
-            n_tokens += n
             if finished is not None:
                 continue        # released (on_release dropped the mirror)
             # rejected-tail rollback: keep only the blocks covering the
@@ -457,8 +453,6 @@ class SpecDecoder:
                 ds.token = before[idx] if a == 0 else p[a - 1]
                 ds.pos = slot.pos - 1
                 ds.pending = slot.token
-        _metrics.inc("serving.tokens_out", n_tokens)
-        _metrics.set_gauge("serving.active_slots", len(eng._slots))
         if self._proposed:
             _metrics.set_gauge("serving.spec.accept_rate",
                                self._accepted / self._proposed)

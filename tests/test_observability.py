@@ -623,9 +623,12 @@ def test_run_steps_is_one_root_with_three_phases_and_cold_compile_spans():
     # the step program's own phases lie in the launch, where JAX runs them
     launch, = [e for e in cold if e["name"] == "executor.launch"]
     in_launch = [e for e in cold if e["parent"] == launch["id"]]
+    # (the program's own two walks of the block close inside JAX's trace,
+    # which is reported after them)
     assert [e["name"] for e in in_launch] == [
+        "executor.lower_block", "executor.lower_block",
         "compile.trace", "compile.lower", "compile.backend"]
-    assert all(e["cat"] == "compile" for e in in_launch)
+    assert all(e["cat"] == "compile" for e in in_launch[2:])
     # nested jits (every jnp call in the traced step) did not become spans
     assert sum(e["name"] == "compile.trace" for e in cold) < 20
     st = trace.self_times(cold)
@@ -702,3 +705,364 @@ def test_the_package_import_is_one_span_recorded_once():
     got = json.loads(out.stdout.strip().splitlines()[-1])
     assert got["n"] == 1 and got["parent"] is None and got["dur"] > 0
     assert not got["twice"]      # fluid used to run the package body again
+
+
+# --------------------------------------------------------------------------
+# time to first step: startup.boot, the cache outcome of every
+# compile.backend, executor.lower_block (PR 34)
+# --------------------------------------------------------------------------
+
+def _boot_child(prelude=""):
+    """A fresh process that imports the package (after `prelude`) and
+    prints its startup spans, with their start moved onto the wall clock
+    by the same handshake flight.dump writes."""
+    import subprocess
+    import sys
+    code = (prelude +
+            "import json, paddle_tpu\n"
+            "from paddle_tpu.observability import trace\n"
+            "clock = trace.clock_handshake()\n"
+            "off = (clock['wall_time_us'] - clock['trace_ts_us']) * 1e-6\n"
+            "evs = [dict(e, wall_s=e['ts'] * 1e-6 + off)"
+            " for e in trace.events() if e['name'].startswith('startup.')]\n"
+            "print(json.dumps(evs))\n")
+    t_spawn = time.time()
+    out = subprocess.run([sys.executable, "-c", code], text=True,
+                         stdout=subprocess.PIPE, check=True, timeout=300,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    return t_spawn, json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_boot_span_runs_from_the_os_creation_time_to_the_import():
+    t_spawn, evs = _boot_child()
+    boot, = [e for e in evs if e["name"] == "startup.boot"]   # once
+    imp, = [e for e in evs if e["name"] == "startup.import"]
+    assert boot["parent"] is None and boot["dur"] > 0
+    # ends where startup.import starts (two views of one clock reading)
+    assert boot["ts"] + boot["dur"] == pytest.approx(imp["ts"], abs=1.0)
+    # starts at the process's creation: the spawn, give or take the OS's
+    # clock tick and the fork
+    assert -0.1 <= boot["wall_s"] - t_spawn <= 1.0
+    assert boot["args"] == {"jax_imported": False,
+                            "backend_initialized": False}
+
+
+def test_boot_span_says_what_the_caller_had_done_first():
+    _, evs = _boot_child("import jax\njax.devices()\n")
+    boot, = [e for e in evs if e["name"] == "startup.boot"]
+    assert boot["args"] == {"jax_imported": True,
+                            "backend_initialized": True}
+    assert boot["dur"] > 1e5         # jax's import is in it: over 0.1 s
+
+
+def test_no_creation_time_from_the_os_no_boot_span(monkeypatch):
+    import builtins
+    real = builtins.open
+
+    def no_proc(path, *a, **kw):
+        if str(path).startswith("/proc/"):
+            raise FileNotFoundError(path)
+        return real(path, *a, **kw)
+
+    monkeypatch.setattr(builtins, "open", no_proc)
+    assert trace.process_created_us() is None
+    monkeypatch.undo()
+    created = trace.process_created_us()
+    assert created is not None and created < trace.now_us()
+    clock = trace.clock_handshake()
+    assert set(clock) == {"wall_time_us", "trace_ts_us"}
+    assert abs(clock["wall_time_us"] * 1e-6 - time.time()) < 5.0
+
+
+def test_time_to_first_step_is_set_once_by_the_first_main_root(monkeypatch):
+    from paddle_tpu.framework import executor as ex
+    _fresh()
+    metrics.reset("startup.time_to_first_step_s")
+    monkeypatch.setattr(ex, "_first_step_unread", True)
+    x = layers.data(name="x", shape=[6], dtype="float32")
+    loss = layers.mean(layers.fc(x, 3))
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    # the startup program's root does not set it
+    assert "startup.time_to_first_step_s" not in metrics.snapshot()
+    feed = {"x": np.ones((4, 6), np.float32)}
+    exe.run(feed=feed, fetch_list=[loss])
+    got = metrics.snapshot()["startup.time_to_first_step_s"]
+    assert got["type"] == "gauge"
+    age = (trace.now_us() - trace.process_created_us()) * 1e-6
+    assert 0 < got["value"] <= age + 0.05    # /proc/uptime ticks in 10 ms
+    exe.run(feed=feed, fetch_list=[loss])
+    assert metrics.get("startup.time_to_first_step_s") == got["value"]
+    assert ex._first_step_unread is False
+
+
+@pytest.fixture
+def temp_compile_cache(tmp_path):
+    """JAX's persistent cache pointed at an empty directory that keeps
+    every entry, and put back as it was afterwards."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    cc.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    yield
+    cc.reset_cache()
+    for k, v in before.items():
+        jax.config.update(k, v)
+    jax.clear_caches()
+
+
+def _backend_spans(fun_name):
+    got = [e for e in _spans() if e["name"] == "compile.backend"
+           and e["args"].get("fun") == f"jit({fun_name})"]
+    trace.clear()
+    return got
+
+
+def test_compile_backend_says_what_the_persistent_cache_did(
+        temp_compile_cache):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def cache_outcomes(a):
+        return jnp.tanh(a) * 3.0625 + 9.75
+
+    x = np.ones((3, 11), np.float32)
+    counted = ("compile.persistent_cache_hits",
+               "compile.persistent_cache_misses",
+               "compile.persistent_cache_unwritten")
+    before = [metrics.get(n) for n in counted]
+    trace.clear()
+    jax.jit(cache_outcomes)(x)
+    first, = _backend_spans("cache_outcomes")
+    assert first["args"]["cache"] == "miss_written"
+    assert "fetch_s" not in first["args"]
+    jax.clear_caches()
+    jax.jit(cache_outcomes)(x)
+    second, = _backend_spans("cache_outcomes")
+    assert second["args"]["cache"] == "hit"
+    assert 0 < second["args"]["fetch_s"] <= second["dur"] * 1e-6 + 1e-3
+    # compiled, but under JAX's threshold for writing: the next process
+    # compiles it again, and only this outcome says so
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e6)
+    jax.jit(cache_outcomes)(np.ones((5, 13), np.float32))
+    third, = _backend_spans("cache_outcomes")
+    assert third["args"]["cache"] == "miss"
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    jax.clear_caches()
+    jax.jit(cache_outcomes)(x)
+    off, = _backend_spans("cache_outcomes")
+    assert off["args"] == {"fun": "jit(cache_outcomes)", "cache": "off"}
+    assert [metrics.get(n) - b for n, b in zip(counted, before)] == [
+        1, 1, 1]
+
+
+def test_cache_outcome_is_per_thread_and_survives_a_failed_compile():
+    """The record is the compiling thread's own, and one left behind (a
+    compile that raised after JAX said it would use the cache) does not
+    colour the next span of that thread."""
+    from paddle_tpu.observability import compile_events as ce
+    seen = {}
+
+    def other():
+        ce._on_event("/jax/compilation_cache/compile_requests_use_cache")
+        ce._on_event("/jax/compilation_cache/cache_hits")
+        ce._on_duration("/jax/compilation_cache/cache_retrieval_time_sec",
+                        0.25)
+        seen["other"] = ce._cache_args()
+
+    ce._on_event("/jax/compilation_cache/compile_requests_use_cache")
+    t = threading.Thread(target=other)
+    t.start()
+    t.join()
+    assert seen["other"] == {"cache": "hit", "fetch_s": 0.25}
+    trace.clear()
+    # this thread's compile raised: no backend report. The next compile
+    # starts with its lowering, which drops the stale record
+    ce._on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.01,
+                    fun_name="jit(next)")
+    ce._on_duration("/jax/core/compile/backend_compile_duration", 0.02,
+                    fun_name="jit(next)")
+    backend, = [e for e in _spans() if e["name"] == "compile.backend"]
+    assert backend["args"] == {"fun": "jit(next)", "cache": "off"}
+
+
+def _lower_blocks(spans=None):
+    return [e for e in (spans if spans is not None else _spans())
+            if e["name"] == "executor.lower_block"]
+
+
+def _by_op_seconds(span):
+    rows = span["args"]["by_op"]
+    assert all(len(r) == 3 and isinstance(r[0], str) and r[1] >= 1
+               and r[2] >= 0 for r in rows) and len(rows) <= 8
+    assert [r[2] for r in rows] == sorted((r[2] for r in rows),
+                                          reverse=True)
+    return sum(r[2] for r in rows)
+
+
+def test_lower_block_is_one_span_a_trace_and_none_on_a_warm_dispatch():
+    _fresh()
+    exe, loss, feed = _build(width=7)            # a program no test compiled
+    trace.clear()
+    exe.run(feed=feed, fetch_list=[loss])
+    cold = _spans()
+    walk, = _lower_blocks(cold)
+    launch, = [e for e in cold if e["name"] == "executor.launch"]
+    assert walk["parent"] == launch["id"]
+    assert walk["args"]["ops"] == len(
+        fluid.default_main_program().global_block().ops)
+    assert walk["args"]["shapes_only"] is False
+    assert walk["args"]["step"] == launch["args"]["step"]
+    # it lies inside JAX's own trace of the step, which is reported after it
+    outer, = [e for e in cold if e["name"] == "compile.trace"
+              and e["parent"] == launch["id"]]
+    assert outer["ts"] <= walk["ts"] + 1.0
+    assert walk["ts"] + walk["dur"] <= outer["ts"] + outer["dur"] + 1.0
+    assert 0 < _by_op_seconds(walk) <= walk["dur"] * 1e-6
+    types = {r[0] for r in walk["args"]["by_op"]}
+    assert types & {"mul", "grad(mul)", "adam", "tanh", "grad(tanh)"}, types
+    assert not any(t == "__vjp__" for t in types)
+    trace.clear()
+    exe.run(feed=feed, fetch_list=[loss])
+    assert _lower_blocks() == []
+
+
+def test_run_steps_walks_the_block_twice_a_trace_once_for_shapes():
+    _fresh()
+    exe, loss, feed = _build(width=9)
+    trace.clear()
+    exe.run_steps(2, feed=feed, fetch_list=[loss])
+    walks = _lower_blocks()
+    assert [w["args"]["shapes_only"] for w in walks] == [True, False]
+    assert len({w["parent"] for w in walks}) == 1
+    trace.clear()
+    exe.run_steps(2, feed=feed, fetch_list=[loss])
+    assert _lower_blocks() == []
+
+
+def test_ops_inside_a_segment_count_to_their_own_types():
+    from paddle_tpu.parallel.transforms import apply_recompute
+    _fresh()
+    x = layers.data(name="x", shape=[6], dtype="float32")
+    h = layers.fc(x, 10, act="tanh")
+    out = layers.mean(layers.fc(h, 1))
+    prog = fluid.default_main_program()
+    apply_recompute(prog, [out.name])
+    top = [op.type for op in prog.global_block().ops]
+    assert "__segment__" in top and "tanh" not in top
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    trace.clear()
+    exe.run(feed={"x": np.ones((4, 6), np.float32)}, fetch_list=[out])
+    walk, = _lower_blocks()
+    assert walk["args"]["ops"] == len(top)
+    rows = {r[0]: r for r in walk["args"]["by_op"]}
+    # the leaves by their own names, each counted; the container keeps
+    # only what it spent outside them
+    assert rows["tanh"][1] == 1 and rows["mul"][1] == 2
+    assert rows["__segment__"][1] == top.count("__segment__")
+    inside = sum(r[2] for t, r in rows.items() if t != "__segment__")
+    assert inside > 0
+    assert _by_op_seconds(walk) <= walk["dur"] * 1e-6
+
+
+def test_lower_table_self_time_on_hand_made_lowerings(monkeypatch):
+    """A container's row is its own time less the lowerings it ran; a
+    `__vjp__` is `grad(<forward type>)`; outside a walk nothing is timed."""
+    from paddle_tpu.framework import executor as ex
+
+    def leaf(op_type, attrs=None):
+        with ex._op_timer(op_type, attrs or {}):
+            time.sleep(0.02)
+
+    def box():
+        with ex._op_timer("box", {}):
+            time.sleep(0.01)
+            leaf("leaf")
+            leaf("__vjp__", {"fwd_type": "leaf"})
+
+    assert ex._lower_table is None
+    box()                                                    # not timed
+    table = ex._LowerTable()
+    monkeypatch.setattr(ex, "_lower_table", table)
+    t0 = time.perf_counter()
+    box()
+    wall = time.perf_counter() - t0
+    rows = table.rows
+    assert set(rows) == {"box", "leaf", "grad(leaf)"}
+    assert rows["leaf"][0] == rows["grad(leaf)"][0] == rows["box"][0] == 1
+    assert rows["leaf"][1] >= 0.02 and rows["grad(leaf)"][1] >= 0.02
+    assert 0.01 <= rows["box"][1] < rows["leaf"][1] + rows["grad(leaf)"][1]
+    assert sum(r[1] for r in rows.values()) <= wall
+    assert [r[0] for r in table.top()][-1] == "box"
+
+
+def test_a_walk_inside_a_lowering_adds_to_the_open_table():
+    """A sub-block's walk (a `__cond__` branch) opens no second span."""
+    from paddle_tpu.framework import executor as ex
+    trace.clear()
+    with ex._lower_walk(3, False):
+        outer = ex._lower_table
+        with ex._op_timer("__cond__", {}):
+            with ex._lower_walk(2, False):
+                assert ex._lower_table is outer
+                with ex._op_timer("scale", {}):
+                    pass
+        assert ex._lower_table is outer
+    assert ex._lower_table is None
+    walk, = _lower_blocks()
+    assert walk["args"]["ops"] == 3 and walk["parent"] is None
+    assert {r[0] for r in walk["args"]["by_op"]} == {"__cond__", "scale"}
+    with pytest.raises(ValueError):              # a lowering that raises
+        with ex._lower_walk(1, True):
+            raise ValueError
+    assert ex._lower_table is None
+
+
+def _ring_shape(events):
+    """What a dispatch put into the ring, without times and ids: the
+    events in order, each with its parent's name and its arg keys."""
+    by = {e["id"]: e["name"] for e in events if e.get("ph") == "X"}
+    return [(e["ph"], e["name"], by.get(e.get("parent")),
+             tuple(sorted(e.get("args", {})))) for e in events]
+
+
+@pytest.mark.parametrize("k", [None, 3])
+def test_a_warm_dispatch_puts_the_parents_events_into_the_ring(k):
+    """The pin that the dispatch path got nothing from PR 34: a warm
+    `run` / `run_steps(k)` records the root, its three phases and the
+    fetch's flow pair, as before it."""
+    _fresh()
+    exe, loss, feed = _build()
+
+    def dispatch():                  # lazy fetches: a FetchHandle each
+        if k is None:
+            return exe.run(feed=feed, fetch_list=[loss], sync=False)
+        return exe.run_steps(k, feed=feed, fetch_list=[loss], sync=False)
+
+    out, = dispatch()
+    out.numpy()
+    trace.clear()
+    out, = dispatch()
+    dispatched = _ring_shape(trace.events())
+    step_args = ("exe", "step")
+    root_args = ("exe", "k", "kind", "ops", "program", "step")
+    assert dispatched == [
+        ("X", "executor.prepare", "executor.step", step_args),
+        ("X", "executor.launch", "executor.step", step_args),
+        ("s", "fetch", None, ("name", "step")),
+        ("X", "executor.commit", "executor.step", step_args),
+        ("X", "executor.step", None, root_args)]
+    out.numpy()                                  # the host reads the fetch
+    drained = _ring_shape(trace.events())[len(dispatched):]
+    assert drained == [("X", "fetch.materialize", None, ("name",)),
+                       ("f", "fetch", None, ("name",))]
