@@ -233,7 +233,7 @@ def _lower_vjp(ctx, ins, attrs):
         if grads is not None:
             return {f"IG:{s}": [grads[s][i] if (s, i) in diff else None
                                 for i in range(in_slot_counts[s])]
-                    for s in {s for s, _ in diff}}
+                    for s in dict.fromkeys(s for s, _ in diff)}
     primals = [fwd_ins[s][i] for (s, i) in diff]
     relower_ctx = LowerCtx(ctx.rng_key, ctx.mesh, ctx.is_eval_shape,
                            in_vjp=True)

@@ -546,7 +546,10 @@ def _apply_recompute(program: Program, checkpoints: List[str]):
         for s2 in segments:
             for op in s2:
                 all_reads.update(op.input_names())
-        for n in seg_produced:
+        # in the order the segment's ops first produce them: the op's
+        # output list reaches the step's HLO and with it the persistent
+        # compile cache's key, so it may not follow a set of names
+        for n in dict.fromkeys(n for op in seg for n in op.output_names()):
             if n in later_reads or n in ck or n not in all_reads:
                 seg_outputs.append(n)
         sub_descs = [{"type": op.type, "inputs": op.inputs,

@@ -531,10 +531,9 @@ def _apply_grad_bucketing(program: Program, startup_program: Program,
                 prod_idx[n] = i
     dense_pgs.sort(key=lambda pg: prod_idx.get(pg[1].name, 1 << 30))
 
-    raw_grads = {g.name for _, g in dense_pgs}
     # grad -> the single per-param update op consuming it (stage 1 targets)
     update_ops: Dict[str, Operator] = {}
-    grad_consumers: Dict[str, int] = {g: 0 for g in raw_grads}
+    grad_consumers: Dict[str, int] = {g.name: 0 for _, g in dense_pgs}
     for op in block.ops:
         for n in op.input_names():
             if n in grad_consumers:

@@ -250,6 +250,73 @@ print(json.dumps({"base": base, "rc": rc}))
     np.testing.assert_allclose(out["base"], out["rc"], rtol=1e-4, atol=1e-6)
 
 
+def test_recompute_segment_outputs_in_production_order():
+    """A `__segment__`'s `Out` / `out_names` list the variables that leave
+    it in the order its sub-ops first produce them, never in the order of a
+    set of names: the list reaches the step's HLO, and XLA's persistent
+    compile cache keys the step by it (PERF.md section 6, PR 35). WHICH
+    variables leave is the old rule: read by a later segment, a checkpoint,
+    or read by nobody."""
+    from paddle_tpu.framework.backward import append_backward
+    from paddle_tpu.parallel.transforms import apply_recompute
+    prog = fluid.default_main_program()
+    gb = prog.global_block()
+    gb.create_var(name="x", shape=(4, 8), dtype="float32", is_data=True)
+    w = gb.create_parameter(name="w", shape=(8, 8), dtype="float32")
+    for n in ("z_first", "inner", "a_second", "m_third", "h1",
+              "d", "e", "unread", "loss"):
+        gb.create_var(name=n, shape=(1,) if n == "loss" else (4, 8),
+                      dtype="float32")
+
+    def op(kind, out, x, y=None, **attrs):
+        ins = {"X": [x]} if y is None else {"X": [x], "Y": [y]}
+        gb.append_op(kind, inputs=ins, outputs={"Out": [out]}, attrs=attrs)
+
+    # segment 1, closed by the checkpoint h1
+    op("mul", "z_first", "x", w.name)        # read by segment 2
+    op("relu", "inner", "z_first")           # read inside segment 1 only
+    op("scale", "a_second", "inner", scale=2.0)        # read by segment 2
+    op("elementwise_add", "m_third", "a_second", "z_first")    # segment 2
+    op("elementwise_add", "h1", "m_third", "inner")            # checkpoint
+    # segment 2
+    op("elementwise_add", "d", "h1", "z_first")        # read inside only
+    op("elementwise_add", "e", "d", "a_second")        # read inside only
+    op("elementwise_add", "unread", "e", "m_third")    # read by nobody
+    op("mean", "loss", "e")                            # read by nobody
+    apply_recompute(prog, ["h1"])
+
+    segs = [o for o in gb.ops if o.type == "__segment__"]
+    assert [o.type for o in gb.ops] == ["__segment__", "__segment__"]
+    assert segs[0].inputs["X"] == ["x", "w"]
+    assert segs[0].outputs["Out"] == ["z_first", "a_second", "m_third", "h1"]
+    assert segs[1].inputs["X"] == ["h1", "z_first", "a_second", "m_third"]
+    assert segs[1].outputs["Out"] == ["unread", "loss"]
+    for seg in segs:
+        assert seg.attrs["out_names"] == seg.outputs["Out"]
+        assert seg.attrs["in_names"] == seg.inputs["X"]
+
+    # the backward built from it follows: each `__vjp__` re-lowers its
+    # segment from the same lists, one cotangent slot an output
+    append_backward(gb.var("loss"))
+    vjps = [o for o in gb.ops if o.type == "__vjp__"]
+    assert len(vjps) == 2
+    for vjp, seg in zip(vjps, reversed(segs)):
+        assert vjp.attrs["fwd_attrs"]["out_names"] == seg.outputs["Out"]
+        assert vjp.inputs["X"] == seg.inputs["X"]
+        assert len(vjp.inputs["OG:Out"]) == len(seg.outputs["Out"])
+
+    # and the rewritten program runs
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    paddle.global_scope().set("w", np.eye(8, dtype=np.float32))
+    xv = np.random.RandomState(0).rand(4, 8).astype(np.float32)
+    got, grad = exe.run(feed={"x": xv}, fetch_list=["loss", "w@GRAD"])
+    z, a = xv, 2.0 * xv                 # w = I, x >= 0
+    np.testing.assert_allclose(
+        got, np.mean((a + z) + z + z + a, keepdims=True), rtol=1e-6)
+    assert np.asarray(grad).shape == (8, 8) and np.abs(grad).sum() > 0
+
+
 def test_fleet_strategy_gradient_merge():
     """k=2 gradient merge over halved batches == full-batch SGD every step
     (reference GradientMergeOptimizer semantics)."""
