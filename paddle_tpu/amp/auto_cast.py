@@ -27,6 +27,9 @@ white_list = {
     # the scan's matmuls (C . B, the decayed products, the states read by
     # C) run on bf16 operands; the step, A and D do not (keep_f32_slots)
     "ssm_scan",
+    # the delta rule's matmuls (the decayed products, the states as a
+    # factor) run on bf16 operands; the decay and beta do not
+    "kda_scan",
 }
 # per-op input slots excluded from the white-list cast: tiny O(V)/O(H)
 # operands whose quantization buys no MXU time but drifts parity with the
@@ -46,6 +49,9 @@ keep_f32_slots = {
     # forward wrote for the grad op (FO:States, FO:DtSoft, FO:CumA): the
     # decays, their running sums and the states are float32
     "ssm_scan": {"Dt", "DtBias", "ALog", "D", "States", "DtSoft", "CumA"},
+    # a channel's log decay, beta before its sigmoid, and the chunk states
+    # the forward wrote for the grad op (FO:States) are float32
+    "kda_scan": {"G", "Beta", "States"},
 }
 
 # ops forced to float32 (reference black list: reductions/normalizations)

@@ -310,7 +310,8 @@ SPECS: Dict[str, OpSpec] = {
         required_attrs=("top_k",),
         attr_types={"top_k": int, "routed_scaling": _NUM,
                     "norm_topk": bool, "experts_total": int,
-                    "expert_offset": int, "scoring": str},
+                    "expert_offset": int, "scoring": str, "n_group": int,
+                    "topk_group": int},
         sharding="moe"),
     "rms_norm": OpSpec(
         inputs={"X": ONE, "Scale": OPT}, outputs={"Y": ONE},
@@ -341,6 +342,22 @@ SPECS: Dict[str, OpSpec] = {
     "gated_group_rms_norm": OpSpec(
         inputs={"X": ONE, "Gate": ONE, "Scale": OPT}, outputs={"Y": ONE},
         attr_types={"groups": int, "epsilon": _NUM}, sharding="follow_x"),
+    # --- the gated delta rule (ops/kda.py) --------------------------------
+    "kda_gate": OpSpec(
+        inputs={"X": ONE, "ALog": ONE, "DtBias": ONE}, outputs={"G": ONE},
+        required_attrs=("lower_bound",), attr_types={"lower_bound": _NUM},
+        sharding="follow_x"),
+    "kda_scan": OpSpec(
+        inputs={"Q": ONE, "K": ONE, "V": ONE, "G": ONE, "Beta": ONE},
+        # States: what the forward writes for the op's grad rule
+        outputs={"Y": ONE, "States": OPT},
+        required_attrs=("chunk_size",), attr_types={"chunk_size": int},
+        sharding="follow_x"),
+    "l2_norm": OpSpec(inputs={"X": ONE}, outputs={"Out": ONE},
+                      attr_types={"epsilon": _NUM, "scale": _NUM},
+                      sharding="elementwise"),
+    "head_gate": OpSpec(inputs={"X": ONE, "Gate": ONE}, outputs={"Out": ONE},
+                        sharding="follow_x"),
     # --- serving tier: paged KV-cache decode ops (ops/paged_ops.py) ------
     # sharding "replicated": serving parallelism is whole-model replicas
     # behind the round-robin frontend (serving/frontend.py) — the pools

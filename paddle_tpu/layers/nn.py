@@ -29,7 +29,7 @@ __all__ = [
     "where", "cond_take", "unique", "cumsum", "prelu", "brelu",
     "fused_attention", "switch_moe", "routed_moe", "rms_norm",
     "rotary_embedding", "swiglu", "relu2", "causal_conv1d", "ssm_scan",
-    "gated_group_rms_norm",
+    "gated_group_rms_norm", "l2_norm", "head_gate", "kda_gate", "kda_scan",
 ]
 
 
@@ -885,14 +885,19 @@ def switch_moe(input, num_experts, d_ff, capacity_factor=1.25, name=None,
 
 def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
                select_bias=None, routed_scaling=1.0, norm_topk=True,
-               experts_total=None, expert_offset=0, scoring="sigmoid"):
+               experts_total=None, expert_offset=0, scoring="sigmoid",
+               n_group=1, topk_group=1):
     """The routed part of a sparse decoder LM's expert layer (DeepSeek-V3
     family; ops/moe.py routed_moe): scores in float32 over ALL
     `experts_total` experts (`gate_w` [d, experts_total]), `scoring`
     "sigmoid" of each logit or "softmax" over all of them, the top_k of
     scores + `select_bias` (a buffer no gradient reaches), weights = the
     scores at those indices, normalised to sum 1 (`norm_topk`) and times
-    `routed_scaling`. No capacity: no token is dropped. The caller passes
+    `routed_scaling`. `n_group` > 1 limits the selection to groups: the
+    experts in `n_group` equal groups of consecutive ones, a group's score
+    the sum of its two highest scores + `select_bias`, the top_k taken
+    among the experts of the best `topk_group` groups. No capacity: no
+    token is dropped. The caller passes
     the gated experts it HOLDS, `expert_gate/up` [E_held, d, f] and
     `expert_down` [E_held, f, d], experts `expert_offset` .. +E_held of the
     whole; the result is their part of sum_k w_k E_{i_k}(x), so the parts
@@ -927,6 +932,8 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
              "experts_total": int(experts_total if experts_total is not None
                                   else gate_w.shape[1]),
              "expert_offset": int(expert_offset), "scoring": scoring}
+    if n_group > 1:
+        attrs.update(n_group=int(n_group), topk_group=int(topk_group))
     helper.append_op(
         "routed_moe", inputs=inputs,
         outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load],
@@ -1045,6 +1052,57 @@ def gated_group_rms_norm(input, gate, groups, epsilon=1e-5, param_attr=None):
                      inputs={"X": [input], "Gate": [gate], "Scale": [scale]},
                      outputs={"Y": [y]},
                      attrs={"groups": int(groups), "epsilon": float(epsilon)})
+    return y
+
+
+def l2_norm(x, scale=1.0, epsilon=1e-6):
+    """x / sqrt(sum(x^2, last axis) + epsilon) * scale."""
+    helper = LayerHelper("l2_norm")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("l2_norm", inputs={"X": [x]}, outputs={"Out": [out]},
+                     attrs={"scale": float(scale),
+                            "epsilon": float(epsilon)})
+    return out
+
+
+def head_gate(x, gate):
+    """x [..., H, D] times sigmoid(gate [..., H]): one scalar a head."""
+    helper = LayerHelper("head_gate")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("head_gate", inputs={"X": [x], "Gate": [gate]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def kda_gate(x, a_log, dt_bias, lower_bound):
+    """The bounded decay of a Kimi-delta layer (ops/kda.py kda_gate): x
+    [B, S, H * K], a_log [H], dt_bias [H * K] -> g [B, S, H, K] float32 =
+    lower_bound * sigmoid(exp(a_log) * (x + dt_bias)), a channel's log
+    decay in (lower_bound, 0)."""
+    helper = LayerHelper("kda_gate")
+    g = helper.create_variable_for_type_inference("float32")
+    helper.append_op("kda_gate",
+                     inputs={"X": [x], "ALog": [a_log], "DtBias": [dt_bias]},
+                     outputs={"G": [g]},
+                     attrs={"lower_bound": float(lower_bound)})
+    return g
+
+
+def kda_scan(q, k, v, g, beta, chunk_size):
+    """The gated delta rule in its chunked form (ops/kda.py kda_scan): q, k
+    [B, S, H, K], v [B, S, H, V], g [B, S, H, K] a channel's log decay
+    (`kda_gate`), beta [B, S, H] before its sigmoid. S must be a whole
+    number of chunks. Returns y [B, S, H, V]."""
+    helper = LayerHelper("kda_scan")
+    y = helper.create_variable_for_type_inference(v.dtype)
+    # what the op's grad rule reads: the state each chunk starts from
+    states = helper.create_variable_for_type_inference("float32")
+    states.stop_gradient = True
+    helper.append_op(
+        "kda_scan",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
+        outputs={"Y": [y], "States": [states]},
+        attrs={"chunk_size": int(chunk_size)})
     return y
 
 

@@ -13,7 +13,11 @@ sparse causal LM (sliding-window and full attention layers in a period,
 grouped KV heads, yarn on the full layers, softmax-routed experts)
 -> mellum.py, and a Nemotron-H-family hybrid causal LM (a Mamba-2
 state-space mixer, ungated relu^2 experts with a shared one, or attention
-without rotary positions a layer, by a pattern string) -> nemotron_h.py
+without rotary positions a layer, by a pattern string) -> nemotron_h.py,
+and a Ling-3.0-family hybrid causal LM (Kimi-delta linear attention with
+latent attention in the last layer of every group, head-wise output gates,
+sigmoid-routed experts picked inside the best groups, a chip's share of the
+heads as of the experts) -> ling.py
 """
 from . import (lenet, resnet, bert, wide_deep, gpt, se_resnext, deepseek_v3,
-               mellum, nemotron_h)
+               mellum, nemotron_h, ling)

@@ -1,6 +1,7 @@
 """Ops of the decoder LMs after 2020: RMS norm, rotary positions (pairs
 interleaved or half-split, frequencies by the default rule or yarn's), the
-gated (SwiGLU) product, the squared ReLU.
+gated (SwiGLU) product, the squared ReLU, the L2 norm over a head and the
+head-wise sigmoid gate.
 
 Each is one plain `jax.numpy` lowering that XLA fuses with its
 neighbours; gradients come from the generic `__vjp__`. Under AMP the norm
@@ -142,3 +143,22 @@ def _relu2(ctx, ins, attrs):
     x = ins["X"][0]
     return {"Out": [jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(
         x.dtype)]}
+
+
+@register("l2_norm")
+def _l2_norm(ctx, ins, attrs):
+    """x / sqrt(sum(x^2, last axis) + epsilon) * scale, in float32: a
+    head's queries and keys under the delta rule."""
+    x = ins["X"][0].astype(jnp.float32)
+    y = x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
+                          + attrs.get("epsilon", 1e-6))
+    return {"Out": [(y * attrs.get("scale", 1.0)).astype(ins["X"][0].dtype)]}
+
+
+@register("head_gate")
+def _head_gate(ctx, ins, attrs):
+    """X [..., H, D] times sigmoid(Gate [..., H]): one scalar a head."""
+    x, gate = ins["X"][0], ins["Gate"][0]
+    out = x.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))[..., None]
+    return {"Out": [out.astype(x.dtype)]}

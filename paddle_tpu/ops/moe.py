@@ -191,7 +191,8 @@ def _switch_moe(ctx, ins, attrs):
 # ---------------------------------------------------------------------------
 # routed_moe: the expert layer as sparse decoder LMs deploy it (DeepSeek-V3
 # family): sigmoid scores (or a softmax over all the experts), a selection
-# bias no gradient reaches (or none), top-k of ALL experts, normalised and
+# bias no gradient reaches (or none), top-k of ALL experts (or, `n_group` >
+# 1, of the experts in the best `topk_group` groups), normalised and
 # scaled weights, no capacity and no drops,
 # gated experts (or, given no gate matrix, experts of the form
 # W_down relu(W_up x)^2), and the share of one expert-parallel rank: told
@@ -414,6 +415,21 @@ def _scores(xt, wg, scoring="sigmoid"):
     raise ValueError(f"routed_moe: unknown scoring {scoring!r}")
 
 
+def _group_limited(sel, n_group, topk_group):
+    """sel [N, E] with every expert outside the best `topk_group` of
+    `n_group` equal groups of consecutive experts at -inf; a group's score
+    is the sum of its two highest entries."""
+    n, e = sel.shape
+    if e % n_group or not 0 < topk_group <= n_group:
+        raise ValueError(f"routed_moe: {e} experts in {n_group} groups, "
+                         f"{topk_group} of them kept")
+    grouped = sel.reshape(n, n_group, e // n_group)
+    best_two, _ = jax.lax.top_k(grouped, 2)
+    _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(n_group), axis=1)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(n, e)
+
+
 def _slot_weights(scores, idx, local, attrs):
     """[k, N] float32: the chosen experts' scores, normalised and scaled; 0
     where the slot's expert is held elsewhere."""
@@ -490,6 +506,9 @@ def _routed_moe(ctx, ins, attrs):
         sel = jax.lax.stop_gradient(scores)
         if bias is not None:
             sel = sel + bias.astype(jnp.float32)
+        n_group = int(attrs.get("n_group", 1))
+        if n_group > 1:
+            sel = _group_limited(sel, n_group, int(attrs["topk_group"]))
         _, idx = jax.lax.top_k(sel, top_k)                   # [N, k]
         local = (idx >= off) & (idx < off + e_held)
         w_slot = _slot_weights(scores, idx, local, attrs)    # [k, N]
@@ -513,6 +532,8 @@ def _routed_moe(ctx, ins, attrs):
         # differentiated at once)
         metrics.inc("moe.bwd_recomputed" if ctx.in_vjp
                     else "moe.layers_lowered")
+        if n_group > 1 and not ctx.in_vjp:
+            metrics.inc("moe.group_limited_layers")
     outs = {"Out": [out.astype(eu.dtype).reshape(x.shape)],
             "TopIdx": [idx.astype(INT64_DEVICE_DTYPE)],
             "ExpertLoad": [sizes], "U": [u],

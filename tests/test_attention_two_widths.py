@@ -6,7 +6,9 @@ them (S = 4096, where the dkdv kernel needs more than Mosaic's default
 16 MiB of scoped VMEM). This file holds EVERY compile for a described
 chip (one worker loads the TPU's library): the expert layer's grouped
 matmuls (`ops/pallas/grouped_matmul.py`; tests/test_grouped_matmul.py has
-their numerics) at the three sparse cells' sizes are at its end.
+their numerics) at the four sparse cells' sizes are at its end, and after
+them the gated delta rule (`ops/kda.py`; tests/test_ling.py has its
+numerics) at the linear-attention cell's.
 """
 import re
 
@@ -20,7 +22,7 @@ from jax.experimental.layout import Format, Layout
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.observability import metrics
-from paddle_tpu.ops import attention, moe
+from paddle_tpu.ops import attention, kda, moe
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import grouped_matmul as gm
 from paddle_tpu.testing import reset_programs
@@ -127,7 +129,8 @@ def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
 _EXPERT_SHAPES = {
     "mellum2_12b_ep4_s8192": (65536, 2304, 896, 16, True),
     "kanana2_30b_a3b_ep8_s4096": (49152, 2048, 768, 16, True),
-    "nemotron_twotower_30b_a3b_ep16_s8192": (49152, 2688, 1856, 8, False)}
+    "nemotron_twotower_30b_a3b_ep16_s8192": (49152, 2688, 1856, 8, False),
+    "ling3_flash_vl_ep64_tp2_s8192": (65536, 2560, 768, 8, True)}
 
 
 @pytest.mark.parametrize("cell", _EXPERT_SHAPES)
@@ -200,3 +203,37 @@ def test_an_expert_layers_grouped_matmuls_compile_for_a_v5e(
             else f in (cut.tk, cut.tn)
         for tiles in (whole, cut):
             assert tiles.resident_bytes + (8 << 20) <= 100 << 20
+
+
+def test_the_gated_delta_rule_compiles_for_a_v5e_at_the_cells_size(v5e):
+    """`ops/kda.py`'s forward and its grad rule's backward at one layer of
+    the linear-attention cell (1 x 8,192 positions, 16 heads of 128, bf16
+    operands, chunks of 64): XLA takes the triangular solve and the blocked
+    decayed products, the chunk states are the only `[.., 128, 128]` value a
+    position-free axis carries (128 chunks, never 8,192 positions), and the
+    layer's temporaries stay under 3 GB."""
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    s, h, d, chunk = 8192, 16, 128, 64
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(q, k, v, g, beta, do):
+        o, states = kda._kda_fwd(chunk, q, k, v, g, beta)
+        return o, states, kda._kda_bwd(chunk, q, k, v, g, beta, states, do)
+
+    rows = (1, s, h, d)
+    try:
+        compiled = jax.jit(layer).trace(
+            sd(rows), sd(rows), sd(rows), sd(rows, jnp.float32),
+            sd(rows[:3], jnp.float32), sd(rows)).lower(
+                lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    assert f"f32[1,{s // chunk},{h},{d},{d}]" in text
+    assert not re.search(rf"\[1,{s},{h},{d},{d}\]|\[1,{h},{s},{d},{d}\]",
+                         text)
+    assert "kda.scan.solve" in text and "kda.scan.carry" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9

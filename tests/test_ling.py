@@ -1,0 +1,763 @@
+"""The Ling-3.0-family hybrid LM (`models/ling.py`: Kimi-delta linear
+attention with latent attention in the last layer of every group, head-wise
+output gates, sigmoid-routed experts picked inside the best groups, a
+chip's share of the heads and of the experts) against its plain float32
+reference (`benchmark/reference/ling3.py`), on the CPU at tiny widths with
+seeded weights; and what the model forced on the ops: the chunked gated
+delta rule (`ops/kda.py`) against the token-by-token recurrence, its grad
+rule on the chunk states, the bounded decay gate, the L2 norm and the
+head-wise gate, and `routed_moe` with group-limited selection.
+"""
+import hashlib
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import paddle_tpu as paddle  # noqa: E402
+import paddle_tpu.fluid as fluid  # noqa: E402
+from paddle_tpu.distributed import fleet  # noqa: E402
+from paddle_tpu.fluid import layers  # noqa: E402
+from paddle_tpu.models import deepseek_v3, ling  # noqa: E402
+from paddle_tpu.observability import metrics  # noqa: E402
+from paddle_tpu.ops import registry  # noqa: E402
+from paddle_tpu.testing import reset_programs  # noqa: E402
+from benchmark.reference import ling3 as ref  # noqa: E402
+
+S, B = 32, 4
+# published layers 1..4 of a model whose groups are 3 layers: layer 1 KDA
+# with the dense part, layer 2 latent with experts, layers 3 and 4 KDA with
+# experts; heads 2..3 of 4, experts 4..7 of 16 (group 1 of 4)
+CFG = dict(hidden_size=64, num_hidden_layers=12, layer_group_size=3,
+           first_k_dense_replace=2, layers=4, first_layer=1,
+           num_attention_heads=2, heads_total=4, head_offset=2, head_dim=16,
+           qk_nope_head_dim=16, qk_rope_head_dim=8, rotary_dim=8,
+           v_head_dim=16, kv_lora_rank=32, short_conv_kernel_size=4,
+           kda_lower_bound=-5, kda_chunk_size=16, intermediate_size=128,
+           moe_intermediate_size=32, moe_shared_expert_intermediate_size=32,
+           num_experts=4, experts_total=16, expert_offset=4,
+           num_experts_per_tok=2, n_group=4, topk_group=2,
+           routed_scaling_factor=2.5, norm_topk_prob=True, rms_norm_eps=1e-6,
+           rope_theta=6000000, expert_swiglu_limit_list=[0] * 12,
+           share_expert_swiglu_limit_list=[0] * 12, vocab=256,
+           reference_scan_tokens_per_block=8,
+           assumed={"initializer_std": 0.02, "select_bias_std": 0.03})
+SHARED = ("hidden_size", "num_hidden_layers", "layer_group_size",
+          "first_k_dense_replace", "head_dim", "qk_nope_head_dim",
+          "qk_rope_head_dim", "v_head_dim", "kv_lora_rank",
+          "short_conv_kernel_size", "kda_lower_bound", "kda_chunk_size",
+          "intermediate_size", "moe_intermediate_size",
+          "moe_shared_expert_intermediate_size", "num_experts_per_tok",
+          "n_group", "topk_group", "routed_scaling_factor", "norm_topk_prob",
+          "rms_norm_eps", "rope_theta", "expert_offset", "first_layer")
+
+
+def model_config(cfg, seq=S):
+    return ling.LingConfig(
+        vocab_size=cfg["vocab"], num_layers_held=cfg["layers"],
+        num_experts=cfg["experts_total"], experts_held=cfg["num_experts"],
+        num_attention_heads=cfg["heads_total"],
+        heads_held=cfg["num_attention_heads"], seq_len=seq,
+        **{k: cfg[k] for k in SHARED})
+
+
+def batches(k, seed=0):
+    rng = np.random.RandomState(seed)
+    ids = rng.randint(0, CFG["vocab"], (k, B, S)).astype(np.int64)
+    labels = np.concatenate([ids[:, :, 1:], np.full((k, B, 1), -100)], 2)
+    return ids, labels
+
+
+def trained_program(amp, k, ids):
+    """The program's losses, first routed choice and scope after `k` steps
+    of `run_steps` from the reference's seeded weights."""
+    reset_programs(0)
+    _, loss, routed = ling.build_causal_lm_program(model_config(CFG))
+    fleet.init(is_collective=True)
+    strategy = fleet.DistributedStrategy()
+    strategy.amp = amp
+    fleet.distributed_optimizer(
+        paddle.optimizer.Adam(learning_rate=ref.ADAM["lr"]),
+        strategy).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    scope = fluid.global_scope()
+    for name, value in ref.init_params(CFG, jax.random.key(3)).items():
+        assert tuple(scope.find(name).shape) == tuple(value.shape), name
+        scope.set(name, value)
+    out = exe.run_steps(k, feed={"tokens": ids[:k]},
+                        fetch_list=[loss, routed[0][0]])
+    return np.asarray(out[0]).reshape(-1), np.asarray(out[1]), scope
+
+
+def reference_states(k, ids, labels):
+    """[(loss, grads, params, m, v) after each of k reference steps]."""
+    params, buffers = ref.split_state(
+        CFG, ref.init_params(CFG, jax.random.key(3)))
+    m = jax.tree.map(jnp.zeros_like, params)
+    v = jax.tree.map(jnp.zeros_like, params)
+    key = ref._cfg_key(CFG)
+    states, first_idx = [], None
+    for t in range(k):
+        val, idx, grads = ref._block_grad(params, buffers, ids[t], labels[t],
+                                          key, None)
+        n = float((labels[t] != -100).sum())
+        grads = jax.tree.map(lambda g: g / n, grads)
+        first_idx = idx if first_idx is None else first_idx
+        copy = jax.tree.map(jnp.array, (params, m, v))
+        params, m, v = ref._adam(*copy, grads, float(t + 1))
+        states.append((float(val) / n, grads, params, m, v))
+    return states, np.asarray(first_idx)
+
+
+DATA_SEED = 1
+
+
+# Tolerances, as in test_nemotron_h.py. float32: the program and the
+# reference differ in the order of their float32 sums (the chunked delta
+# rule with its triangular solve against the recurrence among them). AMP:
+# every matmul operand is rounded to bf16 (2^-9 = 0.2 % an operand); over a
+# leaf's gradient the roundings average to 2 to 5 per cent of the leaf's
+# norm at this size (the head's own leaf reads 1.8), and Adam's first two
+# steps move each weight by at most lr a step whatever the gradient's size.
+# With 16 experts in 4 groups some token of the 128 sits at a near-tie of
+# two experts' or two groups' scores under bf16 rounding in every data seed
+# tried (17 of 17; one to four of the 256 choices of a layer differ): one
+# token going to another expert is 10 to 50 % of the router's and of an
+# expert's gradient here, a comparison of routings and not of arithmetic
+# (on the chip `route_mismatch_share` is that comparison), so under AMP
+# those leaves are held to ten times the tolerance; in float32 the routing
+# is the reference's, token for token.
+_ROUTED = ("router_w", "experts_gate_w", "experts_up_w", "experts_down_w")
+
+
+@pytest.mark.parametrize("amp, grad_tol, loss_tol", [
+    (False, 1e-4, 1e-6), (True, 6e-2, 2e-4)], ids=["float32", "amp"])
+def test_program_follows_the_reference(amp, grad_tol, loss_tol):
+    def tol(name):
+        return grad_tol * (10 if amp and name.endswith(_ROUTED) else 1)
+
+    ids, labels = batches(2, seed=DATA_SEED)
+    states, ref_idx = reference_states(2, ids, labels)
+    counters = ("kda.bwd_residual", "kda.bwd_recomputed",
+                "moe.group_limited_layers")
+    before = [metrics.get(c) for c in counters]
+    losses, idx, scope = trained_program(amp, 1, ids)
+    # the three delta-rule layers' backward took the rule, on the forward's
+    # residuals; the three expert layers selected inside groups
+    assert [metrics.get(c) - b for c, b in zip(counters, before)] == [3, 0, 3]
+    loss1, grads1 = states[0][0], states[0][1]
+    assert abs(losses[0] - loss1) / loss1 < loss_tol
+    for name, want in grads1.items():
+        got = np.asarray(scope.find(name + "_moment1_0"),
+                         np.float32) / (1 - ref.ADAM["beta1"])
+        err = np.linalg.norm(got - np.asarray(want)) / max(
+            np.linalg.norm(np.asarray(want)), 1e-12)
+        assert err < tol(name), (name, err)
+    mismatch = (np.sort(idx[0].reshape(ref_idx.shape), 1)
+                != np.sort(ref_idx, 1)).mean()
+    assert mismatch <= (0.02 if amp else 0)
+    losses, _, scope = trained_program(amp, 2, ids)
+    for t in range(2):
+        assert abs(losses[t] - states[t][0]) / states[t][0] < loss_tol
+    _, _, params, m, v = states[1]
+    lr = ref.ADAM["lr"]
+    p0 = ref.init_params(CFG, jax.random.key(3))
+    for name in params:
+        got = np.asarray(scope.find(name), np.float32)
+        want = np.asarray(params[name])
+        assert np.abs(got - want).max() <= (4.1 if amp else 0.5) * lr, name
+        moved = np.linalg.norm(want - np.asarray(p0[name]))
+        # Adam's first steps move an element by lr times its gradient's
+        # sign: one element of a norm weight of 16 whose tiny gradient
+        # turned is 0.35 of the leaf's move, and a token routed elsewhere
+        # turns signs all over the routed leaves
+        share = (0.6 if name.endswith(_ROUTED) else 0.45) if amp else 2e-3
+        assert np.linalg.norm(got - want) <= share * moved, name
+        # against the accumulator's size after either step: where the two
+        # steps' gradients cancel (a decay's A_log, two numbers a layer) the
+        # sum is a tenth of its terms and carries their rounding
+        for acc, want, first in (("_moment1_0", m, states[0][3]),
+                                 ("_moment2_0", v, states[0][4])):
+            got = np.asarray(scope.find(name + acc), np.float32)
+            err = np.linalg.norm(got - np.asarray(want[name])) / max(
+                np.linalg.norm(np.asarray(want[name])),
+                np.linalg.norm(np.asarray(first[name])), 1e-20)
+            assert err < 2 * tol(name), (name, acc, err)
+
+
+@pytest.mark.parametrize("fault, moved, least", [
+    (dict(kda_state_dtype="bfloat16"), "the delta rule's state in bf16",
+     0.005),
+    (dict(kda_no_delta=True), "beta k k^T S left out", 0.05),
+    (dict(no_group_limit=True), "plain top-k of all experts", 0.2),
+    (dict(kda_heads_kept=1), "half of the delta rule's heads left out",
+     0.5)], ids=lambda v: v if isinstance(v, str) else "")
+def test_the_reference_tells_each_fault_apart(fault, moved, least):
+    """What the new mechanisms admit going wrong each moves the reference's
+    own gradients by far more than the float32 tolerance above (32 tokens
+    here; the chip's `calibrate` has the readings at 8,192)."""
+    ids, labels = batches(1, seed=DATA_SEED)
+    params, buffers = ref.split_state(
+        CFG, ref.init_params(CFG, jax.random.key(3)))
+    _, _, want = ref._block_grad(params, buffers, ids[0], labels[0],
+                                 ref._cfg_key(CFG), None)
+    bad_cfg = dict(CFG, assumed=dict(CFG["assumed"], **fault))
+    _, _, got = ref._block_grad(params, buffers, ids[0], labels[0],
+                                ref._cfg_key(bad_cfg), None)
+    worst = max(float(jnp.linalg.norm(got[n] - want[n])
+                      / jnp.linalg.norm(want[n])) for n in want)
+    assert worst > least, (moved, worst)
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule: chunks against the recurrence
+# ---------------------------------------------------------------------------
+
+def _delta_operands(seed, b=2, s=128, h=3, dk=16, dv=16, power=0.3):
+    """q, k L2-normed as the builder norms them; g in (-5, 0), most of it
+    near the bound (`power` < 1 pushes the uniform draw towards 1)."""
+    rng = np.random.RandomState(seed)
+    unit = lambda t: t / np.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    return {"Q": unit(rng.randn(b, s, h, dk)) * dk ** -0.5,
+            "K": unit(rng.randn(b, s, h, dk)), "V": rng.randn(b, s, h, dv),
+            "G": -5.0 * rng.uniform(0, 1, (b, s, h, dk)) ** power,
+            "Beta": rng.randn(b, s, h)}
+
+
+def _recurrence(ins):
+    """The reference's token-by-token delta rule on the op's operands."""
+    q, k, v, g, raw = (jnp.asarray(ins[n], jnp.float32)
+                       for n in ("Q", "K", "V", "G", "Beta"))
+    return ref.delta_rule(q, k, v, g, jax.nn.sigmoid(raw),
+                          dict(CFG, reference_scan_tokens_per_block=8))
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64, 128], ids=lambda c: f"chunk{c}")
+def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(chunk):
+    """`kda_scan` in chunks of 8 (one block), 16, 64 (four blocks of 16, the
+    cell's) and the whole row against the plain recurrence, the decays drawn
+    down to the bound of -5 (the running sum reaches -300 inside a chunk of
+    64: a form that takes exp(-G) over a whole chunk reads inf): the output,
+    and the gradient of every operand by the op's grad rule on the forward's
+    residual (float32: the order of the sums)."""
+    ins = {k: jnp.asarray(v, jnp.float32)
+           for k, v in _delta_operands(chunk).items()}
+    assert float(ins["G"].min()) < -4.99
+    opdef = registry.get("kda_scan")
+    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
+    attrs = {"chunk_size": chunk}
+    with jax.default_matmul_precision("highest"):
+        outs = opdef.lower(ctx, {k: [v] for k, v in ins.items()}, attrs)
+        want, vjp = jax.vjp(lambda t: _recurrence(t), ins)
+        cot = jnp.asarray(np.random.RandomState(9).randn(*want.shape),
+                          jnp.float32)
+        before = metrics.get("kda.bwd_residual")
+        grads = opdef.grad(ctx, {k: [v] for k, v in ins.items()}, attrs,
+                           {s: outs[s] for s in opdef.residual_slots},
+                           {"Y": [cot]})
+        assert metrics.get("kda.bwd_residual") == before + 1
+        # and differentiated by JAX (a segment under recompute): the same
+        by_jax = jax.grad(lambda k: jnp.sum(opdef.lower(
+            ctx, {**{n: [v] for n, v in ins.items()}, "K": [k]},
+            attrs)["Y"][0] * cot))(ins["K"])
+    y = outs["Y"][0]
+    assert outs["States"][0].shape == (2, 128 // chunk, 3, 16, 16)
+    assert bool(jnp.isfinite(y).all())
+    assert float(jnp.abs(y - want).max() / jnp.abs(want).max()) < 5e-6
+    for name, ref_grad in vjp(cot)[0].items():
+        err = float(jnp.linalg.norm(grads[name][0] - ref_grad)
+                    / jnp.linalg.norm(ref_grad))
+        # the decay's gradient sums differences of running sums as long as
+        # the chunk: float32 noise of 2e-5 at a chunk of 128
+        assert err < 1e-4, (name, err)
+    np.testing.assert_allclose(by_jax, grads["K"][0], rtol=1e-5, atol=1e-6)
+
+
+def test_a_row_or_a_chunk_of_the_wrong_length_is_refused():
+    ins = {k: [jnp.asarray(v, jnp.float32)]
+           for k, v in _delta_operands(0, s=48).items()}
+    opdef = registry.get("kda_scan")
+    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
+    with pytest.raises(ValueError, match="whole number of chunks"):
+        opdef.lower(ctx, ins, {"chunk_size": 32})
+    with pytest.raises(ValueError, match="blocks of 16"):
+        opdef.lower(ctx, ins, {"chunk_size": 24})
+    with pytest.raises(ValueError, match="Beta"):
+        opdef.lower(ctx, dict(ins, Beta=[ins["Beta"][0][:, :, :2]]),
+                    {"chunk_size": 16})
+
+
+def test_delta_rule_in_bf16_keeps_decay_and_states_float32():
+    """Under AMP q, k, v arrive in bf16: the output is bf16 and within
+    bf16's rounding of the float32 result; the chunk states stay float32."""
+    ins = _delta_operands(3, power=2.0)
+    low = {k: jnp.asarray(v, jnp.bfloat16 if k in "QKV" else jnp.float32)
+           for k, v in ins.items()}
+    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
+    outs = registry.get("kda_scan").lower(
+        ctx, {k: [v] for k, v in low.items()}, {"chunk_size": 64})
+    want = _recurrence(ins)
+    assert outs["Y"][0].dtype == jnp.bfloat16
+    assert outs["States"][0].dtype == jnp.float32
+    err = float(jnp.abs(outs["Y"][0].astype(jnp.float32) - want).max()
+                / jnp.abs(want).max())
+    assert err < 3e-2, err
+
+
+def _run_op(op_type, inputs, outputs, attrs):
+    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
+    got = registry.get(op_type).lower(
+        ctx, {k: [jnp.asarray(v)] for k, v in inputs.items()}, attrs)
+    return [np.asarray(got[o][0]) for o in outputs]
+
+
+def test_decay_gate_l2_norm_and_head_gate_ops():
+    rng = np.random.RandomState(2)
+    x = rng.randn(2, 5, 3 * 4).astype(np.float32) * 3
+    a_log = np.log(rng.uniform(1, 16, 3)).astype(np.float32)
+    dt_bias = rng.randn(12).astype(np.float32)
+    g, = _run_op("kda_gate", {"X": x, "ALog": a_log, "DtBias": dt_bias},
+                 ["G"], {"lower_bound": -5.0})
+    pre = (x + dt_bias).reshape(2, 5, 3, 4) * np.exp(a_log)[:, None]
+    np.testing.assert_allclose(g, -2.5 * (1 + np.tanh(pre / 2)), rtol=1e-5,
+                               atol=1e-6)
+    assert g.shape == (2, 5, 3, 4) and g.min() >= -5 and g.max() <= 0
+    half, = _run_op("kda_gate", {"X": x.astype(jnp.bfloat16), "ALog": a_log,
+                                 "DtBias": dt_bias}, ["G"],
+                    {"lower_bound": -5.0})
+    assert half.dtype == np.float32
+    h = rng.randn(2, 5, 3, 4).astype(np.float32)
+    y, = _run_op("l2_norm", {"X": h}, ["Out"], {"scale": 0.5})
+    np.testing.assert_allclose(
+        y, 0.5 * h / np.sqrt((h ** 2).sum(-1, keepdims=True) + 1e-6),
+        rtol=1e-5)
+    np.testing.assert_allclose(y, 0.5 * np.asarray(ref.l2_norm(h)), rtol=1e-6)
+    gate = rng.randn(2, 5, 3).astype(np.float32)
+    z, = _run_op("head_gate", {"X": h, "Gate": gate}, ["Out"], {})
+    np.testing.assert_allclose(z, h / (1 + np.exp(-gate))[..., None],
+                               rtol=1e-5)
+    low, = _run_op("head_gate", {"X": h.astype(jnp.bfloat16), "Gate": gate},
+                   ["Out"], {})
+    assert low.dtype == jnp.bfloat16
+
+
+def test_new_ops_have_specs_and_amp_placement():
+    from paddle_tpu.amp.auto_cast import (black_list, keep_f32_slots,
+                                          white_list)
+    from paddle_tpu.analysis import op_specs  # noqa: F401
+    for op in ("kda_gate", "kda_scan", "l2_norm", "head_gate"):
+        assert registry.get_spec(op) is not None, op
+        assert op not in black_list
+    assert "kda_scan" in white_list and "kda_gate" not in white_list
+    assert keep_f32_slots["kda_scan"] >= {"G", "Beta", "States"}
+    opdef = registry.get("kda_scan")
+    assert opdef.grad is not None
+    assert opdef.residual_slots == ("States",)
+
+
+# ---------------------------------------------------------------------------
+# group-limited selection
+# ---------------------------------------------------------------------------
+
+def _selection_by_loop(sel, n_group, topk_group, top_k):
+    """Each token's chosen experts, a loop a token: the groups' scores, the
+    best groups (ties to the lower index), the best experts among theirs."""
+    t, e = sel.shape
+    size = e // n_group
+    out = []
+    for row in sel:
+        score = [sum(sorted(row[j * size:(j + 1) * size])[-2:])
+                 for j in range(n_group)]
+        kept = sorted(range(n_group), key=lambda j: (-score[j], j))[
+            :topk_group]
+        allowed = [i for i in range(e) if i // size in kept]
+        out.append(sorted(sorted(allowed, key=lambda i: (-row[i], i))[
+            :top_k]))
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("n_group, topk_group, top_k", [
+    (8, 4, 8), (4, 1, 3), (2, 2, 4)], ids=lambda v: str(v))
+def test_group_limited_selection_against_a_plain_loop(n_group, topk_group,
+                                                      top_k):
+    """`routed_moe`'s TopIdx with `n_group` > 1, and the reference's
+    selection, are a plain loop's; with every group kept it is the plain
+    top-k."""
+    rng = np.random.RandomState(n_group)
+    n, d, total, held, f = 96, 16, 32, 4, 8
+    x = rng.randn(n, d).astype(np.float32)
+    wg = rng.randn(d, total).astype(np.float32) * 0.5
+    bias = rng.randn(total).astype(np.float32) * 0.1
+    eg, eu = (rng.randn(held, d, f).astype(np.float32) for _ in range(2))
+    ed = rng.randn(held, f, d).astype(np.float32)
+    attrs = {"top_k": top_k, "routed_scaling": 2.5, "norm_topk": True,
+             "experts_total": total, "expert_offset": 8, "n_group": n_group,
+             "topk_group": topk_group}
+    before = metrics.get("moe.group_limited_layers")
+    out, idx, load = _run_op(
+        "routed_moe", {"X": x, "GateW": wg, "SelectBias": bias,
+                       "ExpertGate": eg, "ExpertUp": eu, "ExpertDown": ed},
+        ["Out", "TopIdx", "ExpertLoad"], attrs)
+    assert metrics.get("moe.group_limited_layers") == before + 1
+    sel = 1 / (1 + np.exp(-(x.astype(np.float64) @ wg))) + bias
+    want = _selection_by_loop(sel, n_group, topk_group, top_k)
+    assert (np.sort(idx, 1) == want).all()
+    cfg = dict(n_group=n_group, topk_group=topk_group,
+               num_experts_per_tok=top_k, norm_topk_prob=True,
+               routed_scaling_factor=2.5, assumed={})
+    ref_idx, _ = ref.route(jnp.asarray(x), jnp.asarray(wg),
+                           jnp.asarray(bias), cfg)
+    assert (np.sort(np.asarray(ref_idx), 1) == want).all()
+    assert (load == np.bincount(want.reshape(-1), minlength=total)[8:12]).all()
+    if topk_group == n_group:
+        plain = np.sort(np.argsort(-sel, 1, kind="stable")[:, :top_k], 1)
+        assert (want == plain).all()
+    with pytest.raises(ValueError, match="groups"):
+        _run_op("routed_moe", {"X": x, "GateW": wg, "ExpertGate": eg,
+                               "ExpertUp": eu, "ExpertDown": ed}, ["Out"],
+                dict(attrs, n_group=5))
+
+
+def _jaxpr_digest(fn, *structs, cut=r"(moe|grouped_matmul)\.py:\d+"):
+    text = str(jax.make_jaxpr(fn)(*structs))
+    return hashlib.sha256(re.sub(cut, r"\1.py:N", text).encode()).hexdigest()
+
+
+def test_without_groups_routed_moe_traces_as_before(monkeypatch):
+    """`n_group` 1 (every cell the benchmark had): the op's forward and its
+    grad rule trace to the jaxpr of the tree before group-limited selection
+    (commit 40a2d5b, jax 0.9.0; the digest is `tests/test_nemotron_h.py`'s
+    for sigmoid scoring with a bias, made there), whether the attr is left
+    out or given as 1."""
+    from paddle_tpu.ops.pallas import grouped_matmul
+    monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
+    n, d, f, held, total = 512, 128, 256, 4, 16
+    opdef = registry.get("routed_moe")
+
+    def step(attrs):
+        def fn(x, wg, sb, eg, eu, ed, g):
+            ctx = registry.LowerCtx(rng_key=None)
+            ins = {"X": [x], "GateW": [wg], "ExpertGate": [eg],
+                   "ExpertUp": [eu], "ExpertDown": [ed], "SelectBias": [sb]}
+            outs = opdef.lower(ctx, ins, attrs)
+            grads = opdef.grad(ctx, ins, attrs,
+                               {s: outs[s] for s in opdef.residual_slots},
+                               {"Out": [g]})
+            return outs["Out"][0], [grads[s][0] for s in (
+                "X", "GateW", "ExpertGate", "ExpertUp", "ExpertDown")]
+        return fn
+
+    bf, sd = jnp.bfloat16, jax.ShapeDtypeStruct
+    structs = (sd((n, d), jnp.float32), sd((d, total), jnp.float32),
+               sd((total,), jnp.float32), sd((held, d, f), bf),
+               sd((held, d, f), bf), sd((held, f, d), bf), sd((n, d), bf))
+    attrs = {"top_k": 2, "routed_scaling": 2.5, "norm_topk": True,
+             "experts_total": total, "expert_offset": 4,
+             "scoring": "sigmoid"}
+    want = "2d66c1be3a857fdad27059c9c02828973d4d5717b9a4099ed27d0e6c5fa275bf"
+    assert _jaxpr_digest(step(attrs), *structs) == want
+    assert _jaxpr_digest(step(dict(attrs, n_group=1, topk_group=1)),
+                         *structs) == want
+    assert _jaxpr_digest(step(dict(attrs, n_group=4, topk_group=2)),
+                         *structs) != want
+
+
+def test_latent_attention_as_kanana_calls_it_traces_as_before():
+    """`models/deepseek_v3.latent_attention` was given a sibling
+    (`ling.gated_latent_attention`) and not an option: the tiny preset's
+    float32 train step traces to the jaxpr of the tree before this model
+    (commit 40a2d5b, jax 0.9.0; the digest was made there, source lines
+    cut)."""
+    reset_programs(0)
+    cfg = deepseek_v3.DeepseekV3Config.tiny()
+    _, loss, _ = deepseek_v3.build_causal_lm_program(cfg)
+    paddle.optimizer.Adam(learning_rate=1e-3).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    ids = np.zeros((2, 2, cfg.seq_len), np.int64)
+    text = re.sub(r"[\w/.\-]+\.py:\d+", "F:N",
+                  str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)))
+    assert text.count("rsqrt") >= 3 * 3
+    assert hashlib.sha256(text.encode()).hexdigest() == KANANA_DIGEST
+
+
+KANANA_DIGEST = (
+    "da6b4d44942ba1fb15bb0cd0ec27bb5ee3b8277a72ce1ebc69d08f3d70d62e95")
+
+
+# ---------------------------------------------------------------------------
+# the shares add up
+# ---------------------------------------------------------------------------
+
+def _attention_program(kind, cfg, x, params, pre):
+    """One share's attention layer of `kind` through a Program."""
+    reset_programs(0)
+    mcfg = model_config(cfg, seq=x.shape[1])
+    xv = layers.data(name="x", shape=list(x.shape[1:]), dtype="float32")
+    build = (ling.gated_latent_attention if kind == ling.LATENT
+             else ling.kda_attention)
+    out = build(xv, mcfg, pre)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    for name, value in params.items():
+        assert tuple(fluid.global_scope().find(name).shape) == tuple(
+            value.shape), name
+        fluid.global_scope().set(name, jnp.asarray(value))
+    return np.asarray(exe.run(feed={"x": x}, fetch_list=[out])[0])
+
+
+def _head_share(params, cfg, lo, hi):
+    """The leaves of heads lo..hi of an uncut attention layer: columns of
+    the projections into heads (and their conv kernels and per-head
+    parameters), rows of W_o; what every chip holds whole as it is."""
+    hd, total = cfg["head_dim"], cfg["heads_total"]
+    out = {}
+    for name, value in params.items():
+        value = np.asarray(value)
+        leaf = name.split("_", 1)[1]
+        if leaf == "o_proj_w":
+            rows = value.reshape(total, -1, value.shape[1])[lo:hi]
+            out[name] = rows.reshape(-1, value.shape[1])
+        elif leaf in ("kv_a_proj_w",) or leaf.endswith("_scale"):
+            out[name] = value
+        else:    # [..., heads x width]: the held heads' columns
+            cols = value.reshape(value.shape[:-1] + (total, -1))[..., lo:hi, :]
+            out[name] = cols.reshape(value.shape[:-1] + (-1,))
+    assert out[name.split("_", 1)[0] + "_o_proj_w"].shape[0] == (hi - lo) * hd
+    return out
+
+
+@pytest.mark.parametrize("kind, n", [(ling.KDA, 1), (ling.LATENT, 2)])
+def test_the_two_head_shares_add_up_to_the_uncut_attention(kind, n):
+    """Heads 0..1 and 2..3 of 4, as the configuration cuts 32 into two of
+    16: the two shares' attention outputs (each through the program, built
+    for its held heads only) sum to the reference's uncut layer, in both
+    kinds of layer; each share is the reference's share."""
+    whole = dict(CFG, num_attention_heads=4, head_offset=0, layers=12,
+                 first_layer=0)
+    pre = f"l{n}_"
+    attn = [k for k in ref.param_shapes(whole) if k.startswith(pre) and not (
+        "norm_scale" in k and k.split("_", 1)[1] in (
+            "attn_norm_scale", "ffn_norm_scale")) and not any(
+        part in k for part in ("mlp_", "experts_", "shared_", "router_"))]
+    key = jax.random.key(5)
+    params = {k: ref.init_leaf(whole, key, k) for k in attn}
+    rng = np.random.RandomState(4)
+    x = rng.randn(2, 32, CFG["hidden_size"]).astype(np.float32)
+    attend = ref.latent_attention if kind == ling.LATENT else ref.kda_attention
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(attend(jnp.asarray(x), params, pre, whole))
+        total = 0.0
+        for lo in (0, 2):
+            share_cfg = dict(CFG, head_offset=lo)
+            share = _head_share(params, whole, lo, lo + 2)
+            got = _attention_program(kind, share_cfg, x, share, pre)
+            part = np.asarray(attend(
+                jnp.asarray(x), {k: jnp.asarray(v) for k, v in share.items()},
+                pre, share_cfg))
+            np.testing.assert_allclose(got, part, rtol=2e-4, atol=2e-6)
+            total = total + got
+    np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-6)
+    assert np.abs(want).max() > 1e-3
+
+
+def _uncut_expert_layer(seed=0, n=96, d=32, f=16, total=32):
+    rng = np.random.RandomState(seed)
+    mat = lambda *shape: rng.randn(*shape).astype(np.float32) * 0.2  # noqa: E731
+    return rng.randn(n, d).astype(np.float32), {
+        "router_w": mat(d, total) * 1.5, "router_bias": mat(total) * 0.25,
+        "experts_gate_w": mat(total, d, f), "experts_up_w": mat(total, d, f),
+        "experts_down_w": mat(total, f, d), "shared_gate_w": mat(d, f),
+        "shared_up_w": mat(d, f), "shared_down_w": mat(f, d)}
+
+
+def _expert_cfg(held, total, offset):
+    return dict(num_experts=held, experts_total=total, expert_offset=offset,
+                num_experts_per_tok=4, n_group=8, topk_group=4,
+                norm_topk_prob=True, routed_scaling_factor=2.5, assumed={})
+
+
+def test_the_ranks_routed_parts_and_the_shared_expert_add_up():
+    """32 experts in 8 groups of 4, cut into 16 shares of 2 as the
+    configuration cuts 512 into 64 of 8: the routed parts all shares give
+    (`routed_moe` with the group limit, through a Program), plus the shared
+    expert that every rank computes alike counted ONCE, are the uncut
+    reference's expert layer; every share's TopIdx is the reference's
+    group-limited choice."""
+    x, params = _uncut_expert_layer()
+    p = {"l_" + k: jnp.asarray(v) for k, v in params.items()}
+    with jax.default_matmul_precision("highest"):
+        whole, want_idx = ref.expert_layer(jnp.asarray(x)[None], p, "l_",
+                                           _expert_cfg(32, 32, 0))
+    want_idx = np.asarray(want_idx)
+    total, loads = 0.0, []
+    for offset in range(0, 32, 2):
+        reset_programs(0)
+        sl = slice(offset, offset + 2)
+        arrays = {"gate_w": params["router_w"],
+                  "eg": params["experts_gate_w"][sl],
+                  "eu": params["experts_up_w"][sl],
+                  "ed": params["experts_down_w"][sl]}
+        xv = layers.data(name="x", shape=[x.shape[1]], dtype="float32")
+        var = {k: layers.create_parameter(list(v.shape), "float32", name=k)
+               for k, v in arrays.items()}
+        bias = layers.create_parameter([32], "float32", name="bias")
+        out, idx, load = layers.routed_moe(
+            xv, var["gate_w"], var["eg"], var["eu"], var["ed"], top_k=4,
+            select_bias=bias, routed_scaling=2.5, experts_total=32,
+            expert_offset=offset, n_group=8, topk_group=4)
+        exe = fluid.Executor()
+        exe.run(fluid.default_startup_program())
+        for k, v in dict(arrays, bias=params["router_bias"]).items():
+            fluid.global_scope().set(k, jnp.asarray(v))
+        out, idx, load = (np.asarray(t) for t in exe.run(
+            feed={"x": x}, fetch_list=[out, idx, load]))
+        total = total + out
+        loads.append(load)
+        assert (np.sort(idx, 1) == np.sort(want_idx, 1)).all()
+    op = next(op for op in fluid.default_main_program().global_block().ops
+              if op.type == "routed_moe")
+    assert (op.attrs["n_group"], op.attrs["topk_group"]) == (8, 4)
+    shared = np.asarray(ref.swiglu_ffn(
+        jnp.asarray(x), p["l_shared_gate_w"], p["l_shared_up_w"],
+        p["l_shared_down_w"]))
+    np.testing.assert_allclose(total + shared, np.asarray(whole)[0],
+                               rtol=2e-4, atol=2e-5)
+    assert (np.concatenate(loads) == np.bincount(
+        want_idx.reshape(-1), minlength=32)).all()
+    # the limit bites: some token's plain top-4 leaves its four best groups
+    plain, _ = ref.route(jnp.asarray(x), p["l_router_w"], p["l_router_bias"],
+                         dict(_expert_cfg(32, 32, 0),
+                              assumed={"no_group_limit": True}))
+    assert (np.sort(np.asarray(plain), 1) != np.sort(want_idx, 1)).any()
+
+
+# ---------------------------------------------------------------------------
+# the builder
+# ---------------------------------------------------------------------------
+
+_COUNTERS = ("kda.layers_lowered", "kda.bwd_residual", "kda.bwd_recomputed",
+             "moe.layers_lowered", "moe.bwd_residual", "moe.bwd_recomputed",
+             "moe.group_limited_layers", "attention.flash_full",
+             "attention.flash_bwd_residual",
+             "attention.flash_bwd_recomputed")
+
+
+def test_builder_names_scopes_and_checkpoints_and_verifies():
+    from paddle_tpu.analysis import verifier
+    from paddle_tpu.observability import trace
+    reset_programs(0)
+    trace.clear()
+    cfg = ling.LingConfig.tiny()
+    _, loss, routed = ling.build_causal_lm_program(cfg)
+    built = [e for e in trace.events() if e["name"] == "program.build"]
+    assert built and built[-1]["args"]["model"] == "ling"
+    prog = fluid.default_main_program()
+    ops = prog.global_block().ops
+    # published layers 1..4 of groups of 3: KDA, latent, KDA, KDA; the
+    # dense part in layer 1, experts from layer 2 on
+    kinds = {"kda_scan": "K", "fused_attention": "L", "routed_moe": "E"}
+    assert "".join(kinds[op.type] for op in ops
+                   if op.type in kinds) == "KLEKEKE"
+    assert [cfg.kind(n) for n in cfg.layers_here()] == [
+        "kda", "latent", "kda", "kda"]
+    names = {p.name for p in prog.global_block().all_parameters()}
+    assert {"l1_mlp_gate_w", "l2_q_norm_scale", "l2_kv_a_proj_w",
+            "l3_f_proj_w", "l4_A_log", "l4_experts_down_w"} <= names
+    assert not any(n.startswith(("l0_", "l5_")) for n in names)
+    scopes = {op.attrs.get("name_scope") for op in ops}
+    assert {"kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
+            "mla.proj", "mla.attend", "moe.shared"} <= scopes
+    scan = next(op for op in ops if op.type == "kda_scan")
+    assert scan.attrs["chunk_size"] == 16
+    assert scan.attrs["name_scope"] == "kda.scan"
+    assert "States" in scan.outputs
+    # no rotary in the KDA layers; q and k of the one latent layer
+    assert [op.type for op in ops].count("rotary_embedding") == 2
+    assert [op.type for op in ops].count("head_gate") == 4
+    convs = [op for op in ops if op.type == "causal_conv1d"]
+    assert len(convs) == 9 and not any("Bias" in op.inputs for op in convs)
+    moe_ops = [op for op in ops if op.type == "routed_moe"]
+    assert all("ExpertGate" in op.inputs and "SelectBias" in op.inputs
+               and (op.attrs["n_group"], op.attrs["topk_group"]) == (4, 2)
+               for op in moe_ops)
+    assert len(loss._layer_checkpoints) == 4 and len(routed) == 3
+    paddle.optimizer.Adam(1e-4).minimize(loss)
+    errors = [f for f in verifier.verify_program(prog)
+              if f.severity == "error"]
+    assert not errors, errors
+    rules = ling.sharding_rules()
+    assert tuple(rules.spec_for("l2_experts_up_w")) == ("ep",)
+    assert tuple(rules.spec_for("l1_f_proj_w")) == (None, "tp")
+    assert tuple(rules.spec_for("l1_k_conv_w")) == (None, "tp")
+    assert tuple(rules.spec_for("l1_A_log")) == ("tp",)
+    assert tuple(rules.spec_for("l1_o_proj_w")) == ("tp", None)
+    assert tuple(rules.spec_for("l2_kv_a_proj_w")) == ()
+    with pytest.raises(ValueError, match="clamp"):
+        reset_programs(0)
+        cfg.expert_swiglu_limit_list = (0, 0, 0, 4)
+        ling.build_causal_lm_program(cfg)
+    with pytest.raises(ValueError, match="heads"):
+        reset_programs(0)
+        ling.build_causal_lm_program(ling.LingConfig(
+            **{**vars(ling.LingConfig.tiny()), "heads_held": 5}))
+
+
+def _amp_step(recompute, **changed):
+    """(executor, loss, ids [2, 1, 128]) of the tiny preset at 128 tokens
+    in chunks of 64 with `changed` set, its AMP train step built through
+    fleet, with a checkpoint at every layer boundary if `recompute`."""
+    reset_programs(0)
+    cfg = ling.LingConfig.tiny()
+    cfg.seq_len, cfg.kda_chunk_size = 128, 64
+    for key, value in changed.items():
+        setattr(cfg, key, value)
+    _, loss, _ = ling.build_causal_lm_program(cfg)
+    fleet.init(is_collective=True)
+    strategy = fleet.DistributedStrategy()
+    strategy.amp = True
+    if recompute:
+        strategy.recompute = True
+        strategy.recompute_configs = {
+            "checkpoints": list(loss._layer_checkpoints)}
+    fleet.distributed_optimizer(paddle.optimizer.Adam(1e-3),
+                                strategy).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    ids = np.random.RandomState(0).randint(0, 256, (2, 1, 128)).astype(
+        np.int64)
+    return exe, loss, ids
+
+
+@pytest.mark.parametrize("recompute, rise", [
+    (False, (3, 3, 0, 3, 3, 0, 3, 1, 1, 0)),
+    (True, (3, 0, 3, 3, 0, 3, 3, 2, 0, 1))], ids=["plain", "recompute"])
+def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
+    """With the flash gate open (here: the interpreter), one trace of the
+    AMP train step lowers three delta-rule layers, three group-limited
+    expert layers and one flash forward; their backward by each op's grad
+    rule on the forward's residuals, or, with a checkpoint at every layer
+    boundary, by the same backward functions under `jax.vjp` of a whole
+    layer, the forward lowered once more. The step's jaxpr holds no
+    `[S, H, K, V]` value: the states are a chunk's."""
+    from paddle_tpu.ops import attention
+    monkeypatch.setattr(attention, "_use_pallas",
+                        lambda q: q.shape[2] % 128 == 0)
+    exe, loss, ids = _amp_step(recompute, qk_nope_head_dim=56,
+                               v_head_dim=64, head_dim=64)
+    before = [metrics.get(c) for c in _COUNTERS]
+    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
+    assert tuple(int(metrics.get(c) - b)
+                 for c, b in zip(_COUNTERS, before)) == rise
+    # 4 heads of 64 x 64 at 128 positions in 2 chunks
+    assert "f32[1,2,4,64,64]" in jaxpr
+    assert not re.search(r"\[1,128,4,64,64\]|\[1,4,128,64,64\]", jaxpr)
+    assert "triangular_solve" in jaxpr

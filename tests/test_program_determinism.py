@@ -85,12 +85,33 @@ def _hybrid_step():
     return exe, {"tokens": ids}, [loss], 2
 
 
+def _linear_attention_step():
+    """The tiny linear-attention preset's AMP step with a checkpoint at
+    every layer boundary, two steps a call: the benchmark cell's route
+    (`tests/test_ling.py` `_amp_step(True)`)."""
+    import paddle_tpu as paddle
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import ling
+    cfg = ling.LingConfig.tiny()
+    cfg.seq_len, cfg.kda_chunk_size = 128, 64
+    _, loss, _ = ling.build_causal_lm_program(cfg)
+    _fleet_minimize(loss, paddle.optimizer.Adam(1e-3), amp=True,
+                    recompute=True, recompute_configs=_layer_checkpoints)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    ids = np.random.RandomState(0).randint(0, 256, (2, 1, 128)).astype(
+        np.int64)
+    return exe, {"tokens": ids}, [loss], 2
+
+
 ROUTES = {
     # route: (builder, virtual CPU devices, the op types its passes leave in
     # the program: a route that fell back to the plain program would pass
     # for the wrong reason)
     "plain": (_bert_step, 1, ()),
     "recompute_hybrid_amp": (_hybrid_step, 1, ("__segment__",)),
+    "recompute_linear_attention_amp": (_linear_attention_step, 1,
+                                       ("__segment__",)),
     "recompute_bert": (lambda: _bert_step(
         recompute=True, recompute_configs=_layer_checkpoints), 1,
         ("__segment__",)),
