@@ -21,6 +21,9 @@ published width of models the repo already has:
                                speculative decoding
   kernels                      every pallas_call family compiled by Mosaic
                                and compared with its jnp oracle
+  ssm_scan                     the selective scan's two chunk kernels at
+                               the hybrid cell's shape beside the
+                               `jax.numpy` form on the same operands
   four_chips                   the first trainer on every visible device
                                (dp=N), replicated and ZeRO-1; runs when JAX
                                finds at least four
@@ -39,6 +42,7 @@ printed per leg as information, labelled with the device they ran on.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -67,6 +71,11 @@ FULL = {
     # 128: rows x d x f over 16 experts (scripts/grouped_matmul_sweep.py
     # runs the same function at the benchmark's sizes, tile by tile)
     "experts": {"rows": 8192, "d": 2304, "f": 896, "experts": 16},
+    # one state-space layer's selective scan at the hybrid cell's size:
+    # 1 x 8,192 positions, 64 heads of 64 in 8 groups, state 128, chunks
+    # of 128
+    "scan": {"b": 1, "s": 8192, "h": 64, "p": 64, "g": 8, "n": 128,
+             "chunk": 128},
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -86,6 +95,8 @@ TINY = {
     "latent": {"flash_shape": (1, 1, 128, 192), "v_width": 128},
     "grouped": {"flash_shape": (1, 2, 128, 64), "kv_heads": 1, "window": 48},
     "experts": {"rows": 96, "d": 384, "f": 128, "experts": 4},
+    "scan": {"b": 1, "s": 256, "h": 4, "p": 64, "g": 2, "n": 128,
+             "chunk": 128},
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -101,6 +112,7 @@ TINY = {
 FLASH_FWD_TOL = 2e-2      # bf16 flash vs dense XLA attention, max |diff|
 FLASH_GRAD_TOL = 5e-2     # relative Frobenius error per gradient
 GROUPED_TOL = 2e-2        # bf16 grouped matmul vs ragged_dot, over max |ref|
+SCAN_TOL = 2e-2           # bf16 scan kernels vs the jax.numpy form, likewise
 DP_LOSS_TOL = 2e-2        # |loss(dp=N) - loss(one device)| per step
 NEAR_TIE = 0.05           # score gap (logit units) a bf16 rounding may decide
 NEAR_TIE_F32 = 0.01       # ... and a float32 one
@@ -756,6 +768,18 @@ def leg_kernels(preset, clock):
     return facts
 
 
+def _ms_a_launch(launches, fn, *args):
+    """Host-clock milliseconds a launch of a jitted `fn`, compiled and run
+    once before the clock starts."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(launches):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round(1e3 * (time.perf_counter() - t0) / launches, 4)
+
+
 # ---------------------------------------------------------------------------
 # the expert layer's grouped matmuls
 # ---------------------------------------------------------------------------
@@ -797,13 +821,7 @@ def grouped_matmul_forms(rows, d, f, experts, retile=None, time_xla=True,
             jnp.asarray(rng.randn(*shape) * 0.1, jnp.bfloat16),
             row_major(len(shape)))
 
-    def ms(fn, *args):
-        jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        for _ in range(launches):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return round(1e3 * (time.perf_counter() - t0) / launches, 4)
+    ms = functools.partial(_ms_a_launch, launches)
 
     facts = {}
     for k, n in ((d, f), (f, d)):
@@ -854,6 +872,110 @@ def leg_grouped_matmul(preset, clock):
             print(f"[chip_smoke] grouped_matmul {name}: tiles {row['tiles']} "
                   f"{row['ms_kernel']} ms, ragged_dot {row['ms_xla']} ms, "
                   f"gap {row['max_abs_diff']:.4g}", flush=True)
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# the selective scan's chunk kernels
+# ---------------------------------------------------------------------------
+def ssm_scan_forms(b, s, h, p, g, n, chunk, heads=None, time_xla=True,
+                   launches=10, seed=7):
+    """`ops/pallas/ssm_chunk.py`'s two kernels on one layer's operands in
+    bf16 (decays and states float32) beside `ops/ssm.py`'s `jax.numpy`
+    form on the same operands: each result's gap over the largest
+    reference value, the host-clock milliseconds a launch of each (a time
+    only on a chip), and the kernels' share of the least time their bytes
+    take (the rows and `States` once each way; `None` off a chip).
+    The rows enter as the `[B, S, H * P]` and `[B, S, G * N]` views a
+    mixer's projection gives and are cut into heads inside the timed
+    function, as in a step: the chip stores a `[.., 64, 64]` array with
+    half its lanes empty, and a lone call on such operands would be timed
+    with a relayout before and after it. `heads`: heads a grid step, in
+    place of the shape rule's whole group: the sweep's handle, which times
+    the `jax.numpy` form once (`time_xla`)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ssm
+    from paddle_tpu.ops.pallas import ssm_chunk
+
+    rng = np.random.RandomState(seed)
+
+    def rows(*shape, dtype=jnp.bfloat16):
+        return jnp.asarray(rng.randn(*shape), dtype)
+
+    x, dy = rows(b, s, h * p), rows(b, s, h * p)
+    bm, cm = rows(b, s, g * n), rows(b, s, g * n)
+    # steps and decays as the hybrid configuration's initialisation gives
+    # them: dt around 0.01 to 0.1, A in -16 .. -1
+    dt_raw = jnp.asarray(rng.uniform(-5.0, -2.0, (b, s, h)), jnp.float32)
+    a_log = jnp.asarray(np.log(rng.uniform(1, 16, h)), jnp.float32)
+    d = jnp.asarray(rng.randn(h), jnp.float32)
+    dt, cum = jax.jit(lambda *a: ssm._decays(*a, chunk))(
+        dt_raw, jnp.zeros((h,), jnp.float32), a_log)
+    plan = ssm_chunk.plan((b, s, h, p), (b, s, g, n), chunk, x.dtype.itemsize,
+                          heads=heads)
+    check(plan is not None, f"x {(b, s, h, p)} B {(b, s, g, n)} chunks of "
+          f"{chunk}, {heads} heads a step: the kernels do not take the shape")
+
+    def by_heads(fn):
+        """fn on x, B, C (and dy) cut into heads, its x-shaped and B-shaped
+        results merged again."""
+        def call(x, bm, cm, dt, cum, d, *rest):
+            rest = tuple(t.reshape(b, s, h, p) if t.shape == x.shape else t
+                         for t in rest)
+            outs = fn(x.reshape(b, s, h, p), bm.reshape(b, s, g, n),
+                      cm.reshape(b, s, g, n), dt, cum, d, *rest)
+            return tuple(
+                t.reshape(b, s, -1) if t.shape[:2] == (b, s) and t.ndim == 4
+                else t for t in outs)
+        return jax.jit(call)
+
+    ms = functools.partial(_ms_a_launch, launches)
+
+    ops = (x, bm, cm, dt, cum, d)
+    forward = {"kernel": by_heads(lambda *a: ssm_chunk.ssd_fwd(plan, *a)),
+               "xla": by_heads(lambda *a: ssm._ssd_fwd(chunk, *a))}
+    backward = {"kernel": by_heads(lambda *a: ssm_chunk.ssd_bwd(plan, *a)),
+                "xla": by_heads(lambda *a: ssm._ssd_bwd(chunk, *a))}
+    _, states = forward["xla"](*ops)
+    on_chip = jax.devices()[0].platform == "tpu"
+    rows_bytes = x.nbytes + bm.nbytes + cm.nbytes + dt.nbytes + 2 * cum.nbytes
+    least = {"fwd": rows_bytes + x.nbytes + states.nbytes,
+             "bwd": 2 * rows_bytes + x.nbytes + states.nbytes}
+    facts = {"plan": list(plan)}
+    for name, forms, args, outs in (
+            ("fwd", forward, ops, ("y", "states")),
+            ("bwd", backward, ops + (states, dy),
+             ("dx", "db", "dc", "ddt", "dcum", "dd"))):
+        got, want = forms["kernel"](*args), forms["xla"](*args)
+        row = {}
+        for out, a, w in zip(outs, got, want):
+            row[out] = _parity(a, w)
+            check(row[out]["max_abs_diff"]
+                  <= SCAN_TOL * row[out]["max_abs_ref"],
+                  f"ssm scan {name} {out}: {row[out]} exceeds {SCAN_TOL}")
+        row["ms_kernel"] = ms(forms["kernel"], *args)
+        if time_xla:
+            row["ms_xla"] = ms(forms["xla"], *args)
+        row["bytes_least_share"] = None
+        if on_chip:
+            import bench
+            peak = bench.device_peaks(
+                jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+            row["bytes_least_share"] = round(
+                least[name] / peak / (1e-3 * row["ms_kernel"]), 4)
+        facts[name] = row
+    return facts
+
+
+def leg_ssm_scan(preset, clock):
+    facts = ssm_scan_forms(**preset["scan"])
+    if preset["expect_mosaic"]:
+        for name in ("fwd", "bwd"):
+            row = facts[name]
+            print(f"[chip_smoke] ssm_scan {name}: kernel {row['ms_kernel']} "
+                  f"ms ({row['bytes_least_share']} of its bytes' least "
+                  f"time), jax.numpy form {row['ms_xla']} ms", flush=True)
     return facts
 
 
@@ -931,6 +1053,7 @@ LEGS = (("train_bert_base_s128", leg_train_s128),
         ("serve_gpt2_small", leg_serve),
         ("kernels", leg_kernels),
         ("grouped_matmul", leg_grouped_matmul),
+        ("ssm_scan", leg_ssm_scan),
         ("four_chips", leg_four_chips))
 
 

@@ -36,6 +36,17 @@ is the rule's own function, so a segment differentiated as a whole
 (recompute, layer scan) gets the same gradients with the forward lowered
 once more (`ssm.bwd_recomputed` counts those, `ssm.bwd_residual` the
 rule's).
+
+Both directions of the core have two lowerings, chosen from the operands'
+shapes alone (`_route`): where the state and a group's heads x features
+are whole lane tiles (128) and the chunk a multiple of 128, the two Pallas
+kernels of `ops/pallas/ssm_chunk.py`, a grid over (batch, group, chunk)
+with the state (backward: its cotangent) in VMEM scratch, in which a
+chunk's `[L, L]` matrices never reach HBM; everywhere else (every tiny
+configuration) the `jax.numpy` form below, `_ssd_fwd` / `_ssd_bwd`, which
+is also the kernels' specification and their oracle in the tests. Same
+operands, same results, same residuals either way; `ssm.scan_pallas` /
+`ssm.scan_xla` count each lowering that stays in the program.
 """
 from __future__ import annotations
 
@@ -275,18 +286,50 @@ def _ssd_bwd(chunk, x, bm, cm, dt, cum, d, hprev, dy):
             dd.astype(d.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _ssd(chunk, x, bm, cm, dt, cum, d):
-    return _ssd_fwd(chunk, x, bm, cm, dt, cum, d)
+def _route(chunk, count, x, bm):
+    """The Pallas kernels' plan where their shape rule takes the operands
+    (`ops/pallas/ssm_chunk.py` `plan`), else None: the `jax.numpy` form
+    above. `count`: whether this trace's call counts, `ssm.scan_pallas` /
+    `ssm.scan_xla`, once per forward or backward lowered."""
+    from .pallas import ssm_chunk
+    plan = ssm_chunk.plan(x.shape, bm.shape, chunk, x.dtype.itemsize)
+    if count:
+        from ..observability import metrics
+        metrics.inc("ssm.scan_xla" if plan is None else "ssm.scan_pallas")
+    return plan
 
 
-def _ssd_vjp_fwd(chunk, *args):
-    y, hprev = _ssd_fwd(chunk, *args)
+def _scan_fwd(chunk, count, x, bm, cm, dt, cum, d):
+    plan = _route(chunk, count, x, bm)
+    if plan is None:
+        return _ssd_fwd(chunk, x, bm, cm, dt, cum, d)
+    from .pallas import ssm_chunk
+    return ssm_chunk.ssd_fwd(plan, x, bm, cm, dt, cum, d)
+
+
+def _scan_bwd(chunk, count, x, bm, cm, dt, cum, d, hprev, dy):
+    plan = _route(chunk, count, x, bm)
+    if plan is None:
+        return _ssd_bwd(chunk, x, bm, cm, dt, cum, d, hprev, dy)
+    from .pallas import ssm_chunk
+    return ssm_chunk.ssd_bwd(plan, x, bm, cm, dt, cum, d, hprev, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _ssd(chunk, count, relowered, x, bm, cm, dt, cum, d):
+    # `relowered` (the generic `__vjp__` differentiates a whole segment):
+    # JAX traces this body to a jaxpr it then replaces by the two rules
+    # below, so what is traced here is in no program and does not count
+    return _scan_fwd(chunk, count and not relowered, x, bm, cm, dt, cum, d)
+
+
+def _ssd_vjp_fwd(chunk, count, relowered, *args):
+    y, hprev = _scan_fwd(chunk, count, *args)
     return (y, hprev), args + (hprev,)
 
 
-def _ssd_vjp_bwd(chunk, res, cts):
-    return _ssd_bwd(chunk, *res, cts[0])
+def _ssd_vjp_bwd(chunk, count, relowered, res, cts):
+    return _scan_bwd(chunk, count, *res, cts[0])
 
 
 _ssd.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)
@@ -313,8 +356,8 @@ def _ssm_scan_grad(ctx, ins, attrs, outs, ogs):
                                                      "D"))
     hprev, dt, cum = (outs[s][0] for s in _RESIDUALS)
     chunk = _chunk_size(x, attrs)
-    dx, dbm, dcm, ddt, dcum, dd = _ssd_bwd(chunk, x, bm, cm, dt, cum, d,
-                                           hprev, dy)
+    dx, dbm, dcm, ddt, dcum, dd = _scan_bwd(
+        chunk, not ctx.is_eval_shape, x, bm, cm, dt, cum, d, hprev, dy)
     _, decays_vjp = jax.vjp(
         lambda *a: _decays(*a, chunk), dt_raw, dt_bias, a_log)
     ddt_raw, ddt_bias, da_log = decays_vjp((ddt, dcum))
@@ -333,7 +376,8 @@ def _ssm_scan(ctx, ins, attrs):
         raise ValueError(f"ssm_scan: {h} heads on B {bm.shape}, C {cm.shape}")
     chunk = _chunk_size(x, attrs)
     dt, cum = _decays(ins["Dt"][0], ins["DtBias"][0], ins["ALog"][0], chunk)
-    y, hprev = _ssd(chunk, x, bm, cm, dt, cum, ins["D"][0])
+    y, hprev = _ssd(chunk, not ctx.is_eval_shape, ctx.in_vjp, x, bm, cm, dt,
+                    cum, ins["D"][0])
     if not ctx.is_eval_shape:
         from ..observability import metrics
         metrics.inc("ssm.bwd_recomputed" if ctx.in_vjp

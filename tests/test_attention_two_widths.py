@@ -8,7 +8,9 @@ chip (one worker loads the TPU's library): the expert layer's grouped
 matmuls (`ops/pallas/grouped_matmul.py`; tests/test_grouped_matmul.py has
 their numerics) at the four sparse cells' sizes are at its end, and after
 them the gated delta rule (`ops/kda.py`; tests/test_ling.py has its
-numerics) at the linear-attention cell's.
+numerics) at the linear-attention cell's and the selective scan's chunk
+kernels (`ops/pallas/ssm_chunk.py`; tests/test_nemotron_h.py has their
+numerics) at the hybrid cell's.
 """
 import re
 
@@ -22,9 +24,10 @@ from jax.experimental.layout import Format, Layout
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.observability import metrics
-from paddle_tpu.ops import attention, kda, moe
+from paddle_tpu.ops import attention, kda, moe, registry
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import grouped_matmul as gm
+from paddle_tpu.ops.pallas import ssm_chunk
 from paddle_tpu.testing import reset_programs
 
 B, NH, DQK, DV = 1, 2, 192, 128
@@ -237,3 +240,64 @@ def test_the_gated_delta_rule_compiles_for_a_v5e_at_the_cells_size(v5e):
                          text)
     assert "kda.scan.solve" in text and "kda.scan.carry" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_the_selective_scan_compiles_for_a_v5e_at_the_cells_size(
+        v5e, monkeypatch):
+    """`ssm_scan`'s forward and its grad rule's backward at one layer of
+    the hybrid cell (1 x 8,192 positions, 64 heads of 64 in 8 groups, state
+    128, chunks of 128, bf16 rows as the mixer's projection and conv hand
+    them over, `[1, 8192, 4096]` and `[1, 8192, 1024]`): Mosaic takes both
+    kernels; the chunk states are the one `[.., 64, 128]` float32 value
+    with the 64 chunks in front; no float32 `[128, 128]` matrix a chunk
+    and head reaches HBM; x, B, C and dy reach the calls as the parameters
+    they are and Y, dx, dB, dC leave them as the results they are, no copy
+    or transpose of a `[1, 8192, ..]` bf16 value; and the layer's
+    temporaries stay under 128 MB (the `jax.numpy` form's `[L, L]` matrices
+    alone were 0.4 GB forward and 1.5 GB backward)."""
+    monkeypatch.setattr(ssm_chunk, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    b, s, h, p, g, n, chunk = 1, 8192, 64, 64, 8, 128, 128
+    opdef = registry.get("ssm_scan")
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(x, bm, cm, dt, dt_bias, a_log, d, dy):
+        ctx = registry.LowerCtx(rng_key=None)
+        ins = {"X": [x.reshape(b, s, h, p)], "B": [bm.reshape(b, s, g, n)],
+               "C": [cm.reshape(b, s, g, n)], "Dt": [dt],
+               "DtBias": [dt_bias], "ALog": [a_log], "D": [d]}
+        attrs = {"chunk_size": chunk}
+        outs = opdef.lower(ctx, ins, attrs)
+        grads = opdef.grad(ctx, ins, attrs,
+                           {k: outs[k] for k in opdef.residual_slots},
+                           {"Y": [dy.reshape(b, s, h, p)]})
+        wide, narrow = (b, s, h * p), (b, s, g * n)
+        return (outs["Y"][0].reshape(wide), outs["States"][0],
+                grads["X"][0].reshape(wide), grads["B"][0].reshape(narrow),
+                grads["C"][0].reshape(narrow),
+                [grads[k][0] for k in ("Dt", "DtBias", "ALog", "D")])
+
+    per_head = sd((h,), jnp.float32)
+    counters = ("ssm.scan_pallas", "ssm.scan_xla")
+    before = [metrics.get(c) for c in counters]
+    try:
+        compiled = jax.jit(layer).trace(
+            sd((b, s, h * p)), sd((b, s, g * n)), sd((b, s, g * n)),
+            sd((b, s, h), jnp.float32), per_head, per_head, per_head,
+            sd((b, s, h * p))).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert [metrics.get(c) - v for c, v in zip(counters, before)] == [2, 0]
+    text = compiled.as_text()
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(k.rsplit(".", 1)[0] for k in kernels) \
+        == ["ssm-chunk-bwd", "ssm-chunk-fwd"], kernels
+    assert f"f32[{b},{s // chunk},{h},{p},{n}]" in text
+    assert not re.search(rf"f32\[[\d,]*{chunk},{chunk}\]", text)
+    assert not re.search(rf"= bf16\[{b},{s},\d+\][^ ]* (copy|transpose)\(",
+                         text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 128e6

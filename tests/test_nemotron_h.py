@@ -227,16 +227,31 @@ def _recurrence(ins):
         dict(CFG, reference_scan_tokens_per_block=8))
 
 
-@pytest.mark.parametrize("chunk", [4, 8, 32], ids=lambda c: f"chunk{c}")
-def test_chunked_scan_is_the_recurrence_forward_and_backward(chunk):
-    """`ssm_scan` in chunks of 4, 8 and the whole row against the plain
+# widths the Pallas kernels' shape rule takes (`ops/pallas/ssm_chunk.py`
+# `plan`: state 128, a group's heads x features whole lane tiles, chunks of
+# 128): three chunks, so that the carry and the reverse chain run, in two
+# groups of two and of four heads. Here under the Pallas interpreter
+_KERNEL_SHAPES = {"kernel-2x2": dict(b=2, s=384, h=4, p=64, g=2, n=128),
+                  "kernel-2x4": dict(b=1, s=384, h=8, p=64, g=2, n=128)}
+_ROUTES = ("ssm.scan_pallas", "ssm.scan_xla")
+
+
+@pytest.mark.parametrize("chunk, shape", [
+    (4, {}), (8, {}), (32, {})] + [(128, v) for v in _KERNEL_SHAPES.values()],
+    ids=["chunk4", "chunk8", "chunk32"] + list(_KERNEL_SHAPES))
+def test_chunked_scan_is_the_recurrence_forward_and_backward(chunk, shape):
+    """`ssm_scan` in chunks of 4, 8 and the whole row (the `jax.numpy`
+    form) and at widths the Pallas kernels take, against the plain
     recurrence: the output, and the gradient of every operand by the op's
-    grad rule on the forward's residuals (float32: the order of the sums)."""
+    grad rule on the forward's residuals (float32: the order of the sums).
+    Each lowering counts its route, forward and backward."""
     ins = {k: jnp.asarray(v, jnp.float32)
-           for k, v in _scan_operands(chunk).items()}
+           for k, v in _scan_operands(chunk, **shape).items()}
+    b, s, h, p = ins["X"].shape
     opdef = registry.get("ssm_scan")
     ctx = registry.LowerCtx(rng_key=jax.random.key(0))
     attrs = {"chunk_size": chunk}
+    routes = [metrics.get(c) for c in _ROUTES]
     with jax.default_matmul_precision("highest"):
         outs = opdef.lower(ctx, {k: [v] for k, v in ins.items()}, attrs)
         want, vjp = jax.vjp(lambda t: _recurrence(t), ins)
@@ -247,21 +262,53 @@ def test_chunked_scan_is_the_recurrence_forward_and_backward(chunk):
                            {s: outs[s] for s in opdef.residual_slots},
                            {"Y": [cot]})
         assert metrics.get("ssm.bwd_residual") == before + 1
+    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] \
+        == ([2, 0] if shape else [0, 2])
     y = outs["Y"][0]
-    assert outs["States"][0].shape == (2, 32 // chunk, 4, 8, 16)
-    assert float(jnp.abs(y - want).max() / jnp.abs(want).max()) < 2e-6
+    assert outs["States"][0].shape == (b, s // chunk, h, p, ins["B"].shape[3])
+    # a chunk of 128 positions: running sums down to -600, and the
+    # `jax.numpy` form itself reads 7.4e-6 on these operands
+    assert float(jnp.abs(y - want).max() / jnp.abs(want).max()) \
+        < (1e-5 if shape else 2e-6)
     for name, ref_grad in vjp(cot)[0].items():
         got = grads[name][0]
         err = float(jnp.linalg.norm(got - ref_grad)
                     / jnp.linalg.norm(ref_grad))
         # A_log's gradient sums differences of running sums as long as the
-        # chunk: float32 noise of 4e-5 at a chunk of 32
+        # chunk: float32 noise of 4e-5 at a chunk of 32, 6e-5 at 128
         assert err < 1e-4, (name, err)
-    # and differentiated by JAX (a segment under recompute): the same
+    if shape:
+        _kernels_follow_the_form(chunk, ins, outs, cot, grads)
+    # and differentiated by JAX (a segment under recompute): the same, by
+    # the same two lowerings
     by_jax = jax.grad(lambda x: jnp.sum(opdef.lower(
         ctx, {**{k: [v] for k, v in ins.items()}, "X": [x]},
         attrs)["Y"][0] * cot))(ins["X"])
     np.testing.assert_allclose(by_jax, grads["X"][0], rtol=1e-5, atol=1e-6)
+    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] \
+        == ([4, 0] if shape else [0, 4])
+
+
+def _kernels_follow_the_form(chunk, ins, outs, cot, grads):
+    """The kernels' results beside the `jax.numpy` form's on the same
+    operands, which the kernels follow line for line: float32's last
+    digits, the order of a sum over heads or positions."""
+    x, bm, cm, d = (ins[k] for k in ("X", "B", "C", "D"))
+    dt, cum = outs["DtSoft"][0], outs["CumA"][0]
+    with jax.default_matmul_precision("highest"):
+        y, states = ssm._ssd_fwd(chunk, x, bm, cm, dt, cum, d)
+        form = ssm._ssd_bwd(chunk, x, bm, cm, dt, cum, d, states, cot)
+        _, decays_vjp = jax.vjp(lambda *a: ssm._decays(*a, chunk),
+                                ins["Dt"], ins["DtBias"], ins["ALog"])
+        form = dict(zip(("X", "B", "C", "D", "Dt", "DtBias", "ALog"),
+                        form[:3] + form[5:] + decays_vjp(form[3:5])))
+    np.testing.assert_allclose(outs["States"][0], states, rtol=1e-6,
+                               atol=1e-6 * float(jnp.abs(states).max()))
+    assert float(jnp.abs(outs["Y"][0] - y).max() / jnp.abs(y).max()) < 5e-7
+    for name, want in form.items():
+        err = float(jnp.linalg.norm(grads[name][0] - want)
+                    / jnp.linalg.norm(want))
+        assert err < 2e-5, (name, err)
 
 
 def test_a_row_that_is_no_whole_number_of_chunks_is_refused():
@@ -274,16 +321,24 @@ def test_a_row_that_is_no_whole_number_of_chunks_is_refused():
                 {"chunk_size": 8})
 
 
-def test_scan_in_bf16_keeps_decays_and_states_float32():
+@pytest.mark.parametrize("chunk, shape", [
+    (8, {}), (128, _KERNEL_SHAPES["kernel-2x2"])], ids=["form", "kernel"])
+def test_scan_in_bf16_keeps_decays_and_states_float32(chunk, shape):
     """Under AMP the operands X, B, C arrive in bf16: the output is bf16 and
     within bf16's rounding of the float32 result; what the forward writes
-    for the backward stays float32."""
-    ins = _scan_operands(3)
+    for the backward stays float32. By the `jax.numpy` form and by the
+    Pallas kernels, which round the same values at the same places: beside
+    the form on the same operands the kernel's output differs by a last bf16
+    digit here and there, its states by float32's."""
+    ins = _scan_operands(3, **shape)
     low = {k: jnp.asarray(v, jnp.bfloat16 if k in "XBC" else jnp.float32)
            for k, v in ins.items()}
     ctx = registry.LowerCtx(rng_key=jax.random.key(0))
+    routes = [metrics.get(c) for c in _ROUTES]
     outs = registry.get("ssm_scan").lower(
-        ctx, {k: [v] for k, v in low.items()}, {"chunk_size": 8})
+        ctx, {k: [v] for k, v in low.items()}, {"chunk_size": chunk})
+    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] \
+        == ([1, 0] if shape else [0, 1])
     want = _recurrence(ins)
     assert outs["Y"][0].dtype == jnp.bfloat16
     assert all(outs[s][0].dtype == jnp.float32
@@ -291,6 +346,73 @@ def test_scan_in_bf16_keeps_decays_and_states_float32():
     err = float(jnp.abs(outs["Y"][0].astype(jnp.float32) - want).max()
                 / jnp.abs(want).max())
     assert err < 2e-2, err
+    if shape:
+        y, states = ssm._ssd_fwd(chunk, low["X"], low["B"], low["C"],
+                                 outs["DtSoft"][0], outs["CumA"][0], low["D"])
+        top = float(jnp.abs(y.astype(jnp.float32)).max())
+        gap = jnp.abs(outs["Y"][0].astype(jnp.float32)
+                      - y.astype(jnp.float32))
+        assert float(gap.max()) <= 2 ** -7 * top
+        assert float(jnp.mean(gap > 0)) < 0.01
+        np.testing.assert_allclose(outs["States"][0], states, rtol=1e-5,
+                                   atol=1e-6 * float(jnp.abs(states).max()))
+
+
+def test_the_kernels_shape_rule_and_the_form_it_leaves():
+    """`ops/pallas/ssm_chunk.py` `plan` reads the route from the operands'
+    shapes and nothing else: state width and a group's heads x features
+    whole lane tiles, chunks a multiple of 128. What it leaves counts
+    `ssm.scan_xla` and lowers to the `jax.numpy` form as the tree before
+    the kernels traced it (commit d302be6, jax 0.9.0: the digest was made
+    there, source lines cut)."""
+    from paddle_tpu.ops.pallas import ssm_chunk
+    cell = ssm_chunk.plan((1, 8192, 64, 64), (1, 8192, 8, 128), 128)
+    assert cell[:7] == (8, 64, 128, 128, 8, 1, 64)
+    assert cell.resident_bytes + (8 << 20) < 16 << 20
+    for x, bm, chunk in (((2, 384, 4, 64), (2, 384, 2, 128), 128),
+                         ((1, 512, 2, 128), (1, 512, 1, 256), 256),
+                         ((1, 256, 32, 16), (1, 256, 4, 128), 128)):
+        assert ssm_chunk.plan(x, bm, chunk, 4) is not None, (x, bm, chunk)
+    for x, bm, chunk, why in (
+            ((2, 32, 4, 8), (2, 32, 2, 16), 8, "the tiny preset"),
+            ((1, 384, 4, 64), (1, 384, 2, 64), 128, "state under a tile"),
+            ((1, 384, 4, 64), (1, 384, 2, 192), 128, "state 1.5 tiles"),
+            ((1, 384, 4, 48), (1, 384, 2, 128), 128, "96 features a group"),
+            ((1, 384, 4, 64), (1, 384, 2, 128), 64, "chunks of 64"),
+            ((1, 384, 4, 64), (1, 384, 2, 128), 192, "chunks of 192"),
+            ((1, 16384, 64, 512), (1, 16384, 1, 128), 128, "VMEM")):
+        assert ssm_chunk.plan(x, bm, chunk) is None, why
+
+    opdef = registry.get("ssm_scan")
+
+    def step(x, bm, cm, dt, dt_bias, a_log, d, dy):
+        ctx = registry.LowerCtx(rng_key=None)
+        ins = {"X": [x], "B": [bm], "C": [cm], "Dt": [dt],
+               "DtBias": [dt_bias], "ALog": [a_log], "D": [d]}
+        attrs = {"chunk_size": 64}
+        outs = opdef.lower(ctx, ins, attrs)
+        grads = opdef.grad(ctx, ins, attrs,
+                           {s: outs[s] for s in opdef.residual_slots},
+                           {"Y": [dy]})
+        return outs["Y"][0], [grads[s][0] for s in ins]
+
+    def sd(*shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    bf = jnp.bfloat16
+    routes = [metrics.get(c) for c in _ROUTES]
+    text = str(jax.make_jaxpr(step)(
+        sd(1, 256, 4, 64, dt=bf), sd(1, 256, 2, 128, dt=bf),
+        sd(1, 256, 2, 128, dt=bf), sd(1, 256, 4), sd(4), sd(4), sd(4),
+        sd(1, 256, 4, 64, dt=bf)))
+    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] == [0, 2]
+    assert "pallas_call" not in text
+    text = re.sub(r"ssm\.py:\d+", "ssm.py:N", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == _FORM_DIGEST
+
+
+_FORM_DIGEST = (
+    "1375f04793a9e6b096efdf47f28b6b2e03140aa247f07b6da2aa50ae34df3e7a")
 
 
 def test_causal_conv_and_gated_group_norm_ops():
@@ -545,7 +667,7 @@ _COUNTERS = ("ssm.layers_lowered", "ssm.bwd_residual", "ssm.bwd_recomputed",
              "moe.layers_lowered", "moe.bwd_residual", "moe.bwd_recomputed",
              "attention.flash_full", "attention.flash_kv_grouped",
              "attention.flash_bwd_residual",
-             "attention.flash_bwd_recomputed")
+             "attention.flash_bwd_recomputed") + _ROUTES
 
 
 def test_builder_names_scopes_and_checkpoints_and_verifies():
@@ -620,8 +742,8 @@ def _amp_step(recompute, **changed):
 
 
 @pytest.mark.parametrize("recompute, rise", [
-    (False, (4, 4, 0, 4, 4, 0, 1, 1, 1, 0)),
-    (True, (4, 0, 4, 4, 0, 4, 2, 2, 0, 1))], ids=["plain", "recompute"])
+    (False, (4, 4, 0, 4, 4, 0, 1, 1, 1, 0, 0, 8)),
+    (True, (4, 0, 4, 4, 0, 4, 2, 2, 0, 1, 0, 12))], ids=["plain", "recompute"])
 def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
     """With the flash gate open (here: the interpreter), one trace of the
     AMP train step lowers four scans, four expert layers and one flash
@@ -629,7 +751,9 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
     the forward's residuals, or, with a checkpoint at every layer boundary
     (the cell's way: the step does not fit the chip without), by the same
     backward functions under `jax.vjp` of a whole layer, the forward lowered
-    once more. The step's jaxpr holds no `[S, H, P, N]` value."""
+    once more. At the preset's widths (state 16, 8 features a head) every
+    scan, forward and backward, is the `jax.numpy` form. The step's jaxpr
+    holds no `[S, H, P, N]` value."""
     from paddle_tpu.ops import attention
     monkeypatch.setattr(attention, "_use_pallas",
                         lambda q: q.shape[2] % 128 == 0)
@@ -648,6 +772,41 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
     for scope in ("ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm",
                   "ssm.out_proj", "attn.proj", "moe.shared", "moe.experts"):
         assert f"/{scope}/" in hlo, scope
+
+
+@pytest.mark.parametrize("recompute, kernels", [(False, 8), (True, 12)],
+                         ids=["plain", "recompute"])
+def test_at_the_cells_scan_widths_every_scan_of_the_step_is_a_kernel(
+        recompute, kernels):
+    """State 128, 64 features a head, two groups of two heads, 256
+    positions in chunks of 128 (the cell's widths, a thirty-second of its
+    heads and positions): one trace of the AMP train step sends every scan
+    to the Pallas kernels, 4 forward and 4 backward, and under
+    recomputation, the cell's way, the 4 forward once more; none keeps the
+    `jax.numpy` form. The counter reads the calls the step keeps: neither
+    the shapes-only walk nor the body JAX traces and drops where a segment
+    is differentiated as a whole. Each kernel is traced once for the four
+    layers, and the chunk states are the one `[.., 64, 128]` float32 value
+    a layer writes."""
+    exe, loss, ids = _amp_step(
+        recompute, seq_len=256, chunk_size=128, mamba_num_heads=4,
+        mamba_head_dim=64, ssm_state_size=128)
+    ids = np.random.RandomState(0).randint(0, 256, (2, 1, 256)).astype(
+        np.int64)
+    from paddle_tpu.ops.pallas import ssm_chunk
+    entries = (ssm_chunk._ssd_fwd, ssm_chunk._ssd_bwd)
+    before = [metrics.get(c) for c in _ROUTES]
+    traced = [f._cache_size() for f in entries]
+    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
+    assert [int(metrics.get(c) - b) for c, b in zip(_ROUTES, before)] \
+        == [kernels, 0]
+    # the four layers enter each kernel through one jitted function: one
+    # trace of it (none here if the other case of this test made it)
+    assert all(f._cache_size() - t <= 1 for f, t in zip(entries, traced))
+    for name in ("ssm-chunk-fwd", "ssm-chunk-bwd"):
+        assert re.search(rf"name={name}\s", jaxpr), name
+    assert "f32[1,2,4,64,128]" in jaxpr
+    assert not re.search(r"f32\[1,2,2,2,128,128\]", jaxpr)
 
 
 @pytest.mark.parametrize("recompute, kernels", [(False, 24), (True, 40)],
