@@ -301,9 +301,11 @@ SPECS: Dict[str, OpSpec] = {
     # the expert layer of sparse decoder LMs (ops/moe.py routed_moe): no
     # capacity, so no token's result depends on another's
     "routed_moe": OpSpec(
-        # no ExpertGate: experts of the form W_down relu(W_up x)^2
+        # no ExpertGate: experts of the form W_down relu(W_up x)^2; ExpertX:
+        # what the experts read where it is not what the router scores
         inputs={"X": ONE, "GateW": ONE, "SelectBias": OPT,
-                "ExpertGate": OPT, "ExpertUp": ONE, "ExpertDown": ONE},
+                "ExpertGate": OPT, "ExpertUp": ONE, "ExpertDown": ONE,
+                "ExpertX": OPT},
         # H .. Inv: what the forward writes for the op's grad rule
         outputs={"Out": ONE, "TopIdx": OPT, "ExpertLoad": OPT, "H": OPT,
                  "U": OPT, "SortedW": OPT, "Order": OPT, "Inv": OPT},

@@ -886,7 +886,7 @@ def switch_moe(input, num_experts, d_ff, capacity_factor=1.25, name=None,
 def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
                select_bias=None, routed_scaling=1.0, norm_topk=True,
                experts_total=None, expert_offset=0, scoring="sigmoid",
-               n_group=1, topk_group=1):
+               n_group=1, topk_group=1, expert_input=None):
     """The routed part of a sparse decoder LM's expert layer (DeepSeek-V3
     family; ops/moe.py routed_moe): scores in float32 over ALL
     `experts_total` experts (`gate_w` [d, experts_total]), `scoring`
@@ -903,11 +903,16 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
     whole; the result is their part of sum_k w_k E_{i_k}(x), so the parts
     of all shares add up to the layer. `expert_gate` None: the experts
     have no gate, E(x) = W_down relu(W_up x)^2. A shared expert is ordinary
-    `swiglu` (or `relu2`) ops beside this one. Returns (out, top_idx
-    [N, top_k], expert_load [E_held]: assignments that fell on each held
-    expert)."""
+    `swiglu` (or `relu2`) ops beside this one. `expert_input` [..., d_e]:
+    what the experts read and write where it is not what the router scores
+    (a latent of `input`: the experts are [E_held, d_e, f] / [E_held, f,
+    d_e] and `out` has its shape); None: `input`. The row buffers hold
+    min(top_k, E_held) x N rows: a token cannot pick one expert twice.
+    Returns (out, top_idx [N, top_k], expert_load [E_held]: assignments
+    that fell on each held expert)."""
     helper = LayerHelper("routed_moe")
-    out = helper.create_variable_for_type_inference(input.dtype)
+    out = helper.create_variable_for_type_inference(
+        (input if expert_input is None else expert_input).dtype)
     idx = helper.create_variable_for_type_inference("int64")
     load = helper.create_variable_for_type_inference("int32")
     # what the op's grad rule reads beside idx and load (ops/moe.py): the
@@ -927,6 +932,8 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
     inputs.update({"ExpertUp": [expert_up], "ExpertDown": [expert_down]})
     if select_bias is not None:
         inputs["SelectBias"] = [select_bias]
+    if expert_input is not None:
+        inputs["ExpertX"] = [expert_input]
     attrs = {"top_k": int(top_k), "routed_scaling": float(routed_scaling),
              "norm_topk": bool(norm_topk),
              "experts_total": int(experts_total if experts_total is not None

@@ -11,15 +11,27 @@ positions (the family takes positions from its state-space layers).
 The configuration's keys are the published `config.json`'s. What one
 expert-parallel rank holds is said beside them, as in
 `models/deepseek_v3.py`: `experts_held` experts from `expert_offset` of the
-`n_routed_experts` the router scores; a sliced vocabulary is a smaller
-`vocab_size`. On one chip the routed part is this rank's share of the sum
-and nothing stands in for the other ranks.
+`n_routed_experts` the router scores; `mamba_heads_held` heads in
+`mamba_groups_held` whole B/C groups of a state-space mixer, and
+`heads_held` query heads on the `kv_heads_held` KV heads they read (the
+projections into heads are built for the held heads only, the output
+projection's rows with them; heads are alike to the program, so which of
+them these are is the checkpoint loader's business and no key here); a
+sliced vocabulary is a smaller `vocab_size`. On one chip the routed part
+and a mixer's output are this chip's share of their sums and nothing stands
+in for the other chips.
+
+`moe_latent_size` (None in the family's older members): the routed experts
+live in a latent of that width, z = x W_a in front of the dispatch and
+(sum_k w_k E_k(z)) W_b behind the combine; the router and the shared expert
+read x at the full width.
 
 Ops of the Program IR only, unrolled. Layer boundaries land on the loss's
 `_layer_checkpoints`. Device work carries `program.name_scope` names:
 `ssm.in_proj`, `ssm.conv`, `ssm.scan`, `ssm.gate_norm`, `ssm.out_proj`;
-`attn.proj`, `attn.attend.full`; `moe.shared`; the routed op names its own
-(`moe.route`, `moe.dispatch`, `moe.experts`, `moe.combine`).
+`attn.proj`, `attn.attend.full`; `moe.shared`, `moe.latent_down`,
+`moe.latent_up`; the routed op names its own (`moe.route`, `moe.dispatch`,
+`moe.experts`, `moe.combine`).
 """
 from __future__ import annotations
 
@@ -62,6 +74,8 @@ class NemotronHConfig:
     chunk_size: int = 128
     moe_intermediate_size: int = 1856
     moe_shared_expert_intermediate_size: int = 3712
+    # the routed experts' own width (None: hidden_size)
+    moe_latent_size: "int | None" = None
     n_routed_experts: int = 128
     num_experts_per_tok: int = 6
     routed_scaling_factor: float = 2.5
@@ -69,9 +83,13 @@ class NemotronHConfig:
     layer_norm_epsilon: float = 1e-5
     initializer_range: float = 0.02
     seq_len: int = 8192
-    # this rank's share of every expert layer (None: all the experts)
+    # this chip's share of every layer (None: everything)
     experts_held: "int | None" = None
     expert_offset: int = 0
+    mamba_heads_held: "int | None" = None
+    mamba_groups_held: "int | None" = None
+    heads_held: "int | None" = None
+    kv_heads_held: "int | None" = None
 
     @property
     def rms_norm_eps(self):        # the name `deepseek_v3._norm` reads
@@ -79,6 +97,33 @@ class NemotronHConfig:
 
     def kind(self, n: int) -> str:
         return self.hybrid_override_pattern[n]
+
+    def mamba_share(self) -> tuple:
+        """(heads, B/C groups) a state-space mixer is built for: whole
+        groups, each with all its heads (the gated norm's statistics are a
+        group's, and B and C are shared by a group's heads)."""
+        hm = self.mamba_heads_held or self.mamba_num_heads
+        g = self.mamba_groups_held or self.n_groups
+        if (g > self.n_groups
+                or hm * self.n_groups != g * self.mamba_num_heads):
+            raise ValueError(
+                f"{hm} heads in {g} groups held of {self.mamba_num_heads} "
+                f"in {self.n_groups}: a share is whole groups")
+        return hm, g
+
+    def attention_share(self) -> tuple:
+        """(query heads, KV heads) an attention layer is built for: the
+        held KV heads with all their query heads, or, where fewer chips
+        than that hold a KV head each, one KV head with some of its."""
+        nh = self.heads_held or self.num_attention_heads
+        nkv = self.kv_heads_held or self.num_key_value_heads
+        group = self.num_attention_heads // self.num_key_value_heads
+        if (nkv > self.num_key_value_heads
+                or not (nh == nkv * group or nkv == 1 and 0 < nh <= group)):
+            raise ValueError(
+                f"{nh} query heads on {nkv} KV heads held of "
+                f"{self.num_attention_heads} on {self.num_key_value_heads}")
+        return nh, nkv
 
     @staticmethod
     def tiny():
@@ -90,21 +135,39 @@ class NemotronHConfig:
             moe_intermediate_size=32, moe_shared_expert_intermediate_size=64,
             n_routed_experts=8, num_experts_per_tok=2, seq_len=32)
 
+    @staticmethod
+    def tiny_latent_share():
+        """`tiny()`'s twin with the experts in a latent, more slots a token
+        than experts held (6 of 32 with 4 held) and one chip's share of the
+        heads: one B/C group of two with its 4 heads, 2 of 8 query heads on
+        one KV head of two."""
+        return NemotronHConfig(
+            vocab_size=256, hidden_size=64, num_hidden_layers=9,
+            hybrid_override_pattern="MEMEM*EME", num_attention_heads=8,
+            num_key_value_heads=2, head_dim=16, mamba_num_heads=8,
+            mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=8,
+            moe_intermediate_size=32, moe_shared_expert_intermediate_size=64,
+            moe_latent_size=24, n_routed_experts=32, num_experts_per_tok=6,
+            routed_scaling_factor=5.0, seq_len=32, experts_held=4,
+            mamba_heads_held=4, mamba_groups_held=1, heads_held=2,
+            kv_heads_held=1)
 
-def _per_head(name, cfg, initializer):
+
+def _per_head(name, heads, initializer):
     return layers.create_parameter(
-        [cfg.mamba_num_heads], "float32",
+        [heads], "float32",
         attr=ParamAttr(name=name, initializer=initializer))
 
 
 def mamba_mixer(x, cfg: NemotronHConfig, pre: str):
     """Mamba-2: [z | xBC | dt] = x W_in; xBC through the causal conv and
     silu; the selective scan over x [H, P] with B, C [G, N]; the gated
-    grouped norm with z; W_out. The builder's initial values of `dt_bias`,
+    grouped norm with z; W_out. H and G are the held heads and groups
+    (`mamba_share`): W_in's columns [z | x | B | C | dt] of those alone,
+    W_out's rows with them. The builder's initial values of `dt_bias`,
     `A_log`, `D` are constants (a step of 0.01, A = -1, D = 1); a trainer
     that wants the family's seeded draws sets them in the scope."""
-    hm, p, g, n = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
-                   cfg.ssm_state_size)
+    (hm, g), p, n = cfg.mamba_share(), cfg.mamba_head_dim, cfg.ssm_state_size
     d_in, s = hm * p, cfg.seq_len
     with name_scope("ssm.in_proj"):
         zxbcdt = _linear(x, 2 * d_in + 2 * g * n + hm, pre + "in_proj_w", cfg)
@@ -119,10 +182,10 @@ def mamba_mixer(x, cfg: NemotronHConfig, pre: str):
             layers.reshape(xs, [0, s, hm, p]),
             layers.reshape(b, [0, s, g, n]), layers.reshape(c, [0, s, g, n]),
             dt,
-            _per_head(pre + "dt_bias", cfg,
+            _per_head(pre + "dt_bias", hm,
                       I.Constant(math.log(math.expm1(0.01)))),
-            _per_head(pre + "A_log", cfg, I.Constant(0.0)),
-            _per_head(pre + "D", cfg, I.Constant(1.0)),
+            _per_head(pre + "A_log", hm, I.Constant(0.0)),
+            _per_head(pre + "D", hm, I.Constant(1.0)),
             chunk_size=cfg.chunk_size)
     with name_scope("ssm.gate_norm"):
         y = layers.gated_group_rms_norm(
@@ -143,8 +206,11 @@ def expert_layer(x, cfg: NemotronHConfig, pre: str):
     """(this rank's routed part + the shared expert, top_idx,
     expert_load): sigmoid scores over ALL `n_routed_experts`, the top
     `num_experts_per_tok` of score + bias, their weights divided by their
-    sum and scaled; experts without a gate."""
+    sum and scaled; experts without a gate. With `moe_latent_size` the
+    experts read z = x W_a and what they sum goes through W_b; the router
+    and the shared expert read x."""
     h, f = cfg.hidden_size, cfg.moe_intermediate_size
+    width = cfg.moe_latent_size or h
     held = cfg.experts_held or cfg.n_routed_experts
     gate_w = layers.create_parameter(
         [h, cfg.n_routed_experts], "float32", attr=_w(pre + "router_w", cfg))
@@ -154,12 +220,20 @@ def expert_layer(x, cfg: NemotronHConfig, pre: str):
                        initializer=I.Constant(0.0)))
     up, down = (layers.create_parameter(
         shape, "float32", attr=_w(pre + f"experts_{n}_w", cfg))
-        for n, shape in (("up", [held, h, f]), ("down", [held, f, h])))
+        for n, shape in (("up", [held, width, f]),
+                         ("down", [held, f, width])))
+    z = None
+    if cfg.moe_latent_size:
+        with name_scope("moe.latent_down"):
+            z = _linear(x, width, pre + "latent_down_w", cfg)
     routed, idx, load = layers.routed_moe(
         x, gate_w, None, up, down, top_k=cfg.num_experts_per_tok,
         select_bias=bias, routed_scaling=cfg.routed_scaling_factor,
         norm_topk=cfg.norm_topk_prob, experts_total=cfg.n_routed_experts,
-        expert_offset=cfg.expert_offset)
+        expert_offset=cfg.expert_offset, expert_input=z)
+    if cfg.moe_latent_size:
+        with name_scope("moe.latent_up"):
+            routed = _linear(routed, h, pre + "latent_up_w", cfg)
     with name_scope("moe.shared"):
         shared = relu2_ffn(x, cfg.moe_shared_expert_intermediate_size,
                            pre + "shared_", cfg)
@@ -168,10 +242,11 @@ def expert_layer(x, cfg: NemotronHConfig, pre: str):
 
 def grouped_attention(x, cfg: NemotronHConfig, pre: str):
     """`num_attention_heads` query heads on `num_key_value_heads` KV heads
-    (query head h attends KV head h // group), causal, no rotary positions.
-    K and V go to the attention op at their own head count."""
-    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
+    (query head h attends KV head h // group), causal, no rotary positions;
+    built for the held query heads and the KV heads they read
+    (`attention_share`). K and V go to the attention op at their own head
+    count."""
+    (nh, nkv), hd = cfg.attention_share(), cfg.head_dim
     with name_scope("attn.proj"):
         q = _heads(_linear(x, nh * hd, pre + "q_proj_w", cfg), nh, hd)
         k = _heads(_linear(x, nkv * hd, pre + "k_proj_w", cfg), nkv, hd)
@@ -225,9 +300,15 @@ def sharding_rules() -> ShardingRules:
     projections row-parallel, the shared expert by its width, the experts'
     leading dim over `ep`, the vocabulary over `tp`. k and v split by KV
     head: `tp` may not pass `num_key_value_heads` (2 as published). The
-    state-space mixer stays whole on every chip: its input projection's
-    columns are [z | x | B | C | dt], and B and C are shared by the 8
-    heads of a group."""
+    state-space mixer has no rule here and stays whole under these: its
+    input projection's columns are [z | x | B | C | dt], and B and C are
+    shared by the heads of a group, so a plain split of the columns is no
+    share. A GROUP a chip is: that group's z, x, B, C and dt columns, its
+    rows of the output projection, its slice of the gated norm, which is
+    what `mamba_heads_held` / `mamba_groups_held` build and a rule would
+    have to say by column ranges. Where more chips share a layer's heads
+    than it has KV heads, a KV head lives on several of them, each with
+    some of its query heads (`heads_held` / `kv_heads_held`)."""
     return moe_sharding_rules(extra=[
         (r"_(q|k|v)_proj_w$", P(None, "tp")),
         (r"_o_proj_w$", P("tp", None)),

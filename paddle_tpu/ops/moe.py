@@ -202,9 +202,11 @@ def _switch_moe(ctx, ins, attrs):
 
 # What `routed_moe`'s forward writes for its grad rule (beside `TopIdx` and
 # `ExpertLoad`, which a caller may fetch): the gate and up projections of
-# the sorted rows, [k*N, f] in the compute dtype (`H` only where the experts
+# the sorted rows, [rows, f] in the compute dtype (`H` only where the experts
 # have a gate), the slots' weights in sorted order, and the sort with its
-# inverse. Narrow each: never a [k*N, d] buffer.
+# inverse. Narrow each: never a [rows, d] buffer. rows = min(k, E_held) * N:
+# a token cannot pick one expert twice, so more assignments than that never
+# arrive here (`_token_rows`).
 _RESIDUALS = ("H", "U", "SortedW", "Order", "Inv", "TopIdx", "ExpertLoad")
 
 # dW of a grouped matmul: x [m, a] and g [m, b] contracted over the ragged
@@ -286,6 +288,31 @@ def _whole_buffer(sizes, rows):
     return sizes.at[-1].add(rows - jnp.sum(sizes))
 
 
+def _token_rows(inv, top_k, e_held):
+    """What the combine gathers by. inv [k*N]: the sorted row of every slot
+    (slot-major). With k <= E_held that is it, and the buffer has k*N rows.
+    With k > E_held a token has at most E_held slots on a held expert, and
+    the sort put the held slots first: the buffer is the first E_held * N
+    sorted rows, and a token's held slots are among its E_held lowest rows,
+    [E_held * N] slot-major (the others of them foreign: rows of zeros, or
+    past the buffer's end)."""
+    if top_k <= e_held:
+        return inv
+    return jnp.sort(inv.reshape(top_k, -1), axis=0)[:e_held].reshape(-1)
+
+
+def _buffer(a, rows):
+    """The first `rows` of a value in sorted order: all of it where the
+    buffer holds every slot."""
+    return a if a.shape[0] == rows else a[:rows]
+
+
+def _gather_rows(y, inv, bounded):
+    """y [rows, d] at inv; `bounded`: a row past the buffer's end (a
+    foreign slot) reads zeros."""
+    return y.at[inv].get(mode="fill", fill_value=0) if bounded else y[inv]
+
+
 def _weighted_act(h, u, w_sorted):
     """silu(h) * u in float32 (h None, an expert without a gate:
     relu(u)^2), and the same times its slot's weight rounded once to the
@@ -301,32 +328,36 @@ def _experts_fwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed):
     """sum_k w_k E_{i_k}(x) over the slots whose expert is held here, and
     the residuals (h, u).
 
-    xt [N, d]; order / inv: the permutation that sorts the k*N slots
+    xt [N, d]; order: the permutation that sorts the k*N slots
     (slot-major: slot j of token i is row j*N + i) by held expert (foreign
-    slots last) and its inverse; w_sorted [k*N] float32: the slots'
-    weights in that order (0 where the slot's expert is elsewhere); sizes
-    [E_held]. The buffers hold every assignment there can be and the
-    grouped matmuls run over all of them, the foreign slots' rows as zeros
-    in the last group: what a step costs is fixed by its shapes and not by
-    the routing (a rank's k*N rows are also what its experts see in the
-    deployment, where the exchange fills them), and a zero row yields a
-    zero row, so nothing needs a mask but the gathered input. The slot's
-    weight goes in AHEAD of the down projection, w (a W) = (w a) W over f
-    columns, so the combine is a plain sum of the k slots. `eg` None: the
-    experts have no gate, W_down relu(W_up x)^2, two grouped matmuls, and h
-    is None. `count`: this trace's grouped matmuls count (`_RowGroups`)."""
-    rows, n = order.shape[0], xt.shape[0]
+    slots last); inv [rows]: what the combine gathers by, the inverse of
+    `order` or its bounded form (`_token_rows`), rows = min(k, E_held) * N;
+    w_sorted [k*N] float32: the slots' weights in sorted order (0 where the
+    slot's expert is elsewhere); sizes [E_held]. The buffers hold every
+    assignment there can be and the grouped matmuls run over all of them,
+    the foreign slots' rows as zeros in the last group: what a step costs
+    is fixed by its shapes and not by the routing (with k <= E_held a
+    rank's k*N rows are also what its experts see in the deployment, where
+    the exchange fills them), and a zero row yields a zero row, so nothing
+    needs a mask but the gathered input. The slot's weight goes in AHEAD of
+    the down projection, w (a W) = (w a) W over f columns, so the combine
+    is a plain sum of a token's slots. `eg` None: the experts have no gate,
+    W_down relu(W_up x)^2, two grouped matmuls, and h is None. `count`:
+    this trace's grouped matmuls count (`_RowGroups`)."""
+    rows, n = inv.shape[0], xt.shape[0]
     groups = _RowGroups(sizes, rows, count)
     with jax.named_scope("moe.dispatch"):
         valid = jnp.arange(rows) < jnp.sum(sizes)
-        xs = jnp.where(valid[:, None], xt.astype(eu.dtype)[order % n], 0)
+        xs = jnp.where(valid[:, None],
+                       xt.astype(eu.dtype)[_buffer(order, rows) % n], 0)
     with jax.named_scope("moe.experts"):
         h = None if eg is None else _grouped(xs, eg, groups)
         u = _grouped(xs, eu, groups)
-        _, wa = _weighted_act(h, u, w_sorted)
+        _, wa = _weighted_act(h, u, _buffer(w_sorted, rows))
         y = _grouped(wa, ed, groups)
     with jax.named_scope("moe.combine"):
-        return _sum_slots(y[inv], rows // n), h, u
+        return _sum_slots(_gather_rows(y, inv, order.shape[0] != rows),
+                          rows // n), h, u
 
 
 def _experts_bwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
@@ -335,24 +366,26 @@ def _experts_bwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
     it wrote: six grouped matmuls (four without a gate), none of the
     forward's again. No mask: a foreign slot's weight is 0, so its rows of
     dh and du are. Returns the gradients of (xt, w_sorted, eg, eu, ed)."""
-    rows, n = order.shape[0], xt.shape[0]
+    rows, n = inv.shape[0], xt.shape[0]
+    bounded = order.shape[0] != rows
     cdt = eu.dtype
     # what is read here is read when the backward gets here: without the
     # barrier XLA merges the gather of xs below with the forward's and
-    # keeps a [k*N, d] buffer a layer alive in between
+    # keeps a [rows, d] buffer a layer alive in between
     g, xt, order, inv, h, u = jax.lax.optimization_barrier(
         (g, xt, order, inv, h, u))
     groups = _RowGroups(sizes, rows, count)
+    held, w_held = _buffer(order, rows), _buffer(w_sorted, rows)
     with jax.named_scope("moe.combine"):
-        gs = g.astype(cdt)[order % n]                         # [k*N, d]
+        gs = g.astype(cdt)[held % n]                          # [rows, d]
     with jax.named_scope("moe.dispatch"):
-        xs = xt.astype(cdt)[order % n]
+        xs = xt.astype(cdt)[held % n]
     with jax.named_scope("moe.experts"):
-        act, wa = _weighted_act(h, u, w_sorted)
+        act, wa = _weighted_act(h, u, w_held)
         ded = _grouped_dw(wa, gs, groups)
         dwa = _grouped(gs, ed, groups, transposed=True).astype(jnp.float32)
         dw_sorted = jnp.sum(dwa * act, axis=1)
-        dact = dwa * w_sorted[:, None]
+        dact = dwa * w_held[:, None]
         if eg is None:
             du = (dact * 2.0 * jax.nn.relu(u.astype(jnp.float32))).astype(cdt)
             deg = None
@@ -369,7 +402,9 @@ def _experts_bwd(count, xt, w_sorted, order, inv, sizes, eg, eu, ed, h, u,
             dxs = (_grouped(dh, eg, groups, transposed=True)
                    + _grouped(du, eu, groups, transposed=True))
     with jax.named_scope("moe.dispatch"):
-        dxt = _sum_slots(dxs[inv], rows // n)
+        dxt = _sum_slots(_gather_rows(dxs, inv, bounded), rows // n)
+    if bounded:      # the slots past the buffer's end are foreign: weight 0
+        dw_sorted = jnp.pad(dw_sorted, (0, order.shape[0] - rows))
     return dxt.astype(xt.dtype), dw_sorted, deg, deu, ded
 
 
@@ -440,6 +475,14 @@ def _slot_weights(scores, idx, local, attrs):
     return jnp.where(local, w, 0.0).T
 
 
+def _expert_input(ins, xt):
+    """(`ExpertX` [..., d_e] or None, the rows the experts read [N, d_e]):
+    what the experts read and write where it is not what the router scores
+    (e.g. a latent of `X`); None and the router's own rows `xt` without."""
+    xe = ins["ExpertX"][0] if ins.get("ExpertX") else None
+    return xe, xt if xe is None else xe.reshape(-1, xe.shape[-1])
+
+
 def _expert_weights(ins):
     """(gate, up, down) [E_held, ...]; gate None where the op was given no
     `ExpertGate`: experts of the form W_down relu(W_up x)^2."""
@@ -451,9 +494,9 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
     """Grad rule: the backward on what the forward wrote (`_RESIDUALS`).
     The experts' part is `_experts_bwd`; the router's (GateW, and x through
     the scores) is the small weight function differentiated alone at the
-    fixed `TopIdx`. Declines when a residual is absent (a program built
-    before they existed), and the generic `__vjp__` differentiates the
-    forward lowering."""
+    fixed `TopIdx`; with `ExpertX` each input gets its own path's. Declines
+    when a residual is absent (a program built before they existed), and
+    the generic `__vjp__` differentiates the forward lowering."""
     g = (ogs.get("Out") or [None])[0]
     eg, eu, ed = _expert_weights(ins)
     if g is None or not all(outs.get(s) for s in _RESIDUALS
@@ -464,10 +507,11 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
         outs[s][0] if outs.get(s) else None for s in _RESIDUALS)
     off, e_held = _geometry(ins, attrs)
     xt = x.reshape(-1, x.shape[-1])
+    xe, xet = _expert_input(ins, xt)
     local = (idx >= off) & (idx < off + e_held)
     dxt, dw_sorted, deg, deu, ded = _experts_bwd(
-        not ctx.is_eval_shape, xt, w_sorted, order, inv, sizes, eg, eu, ed,
-        h, u, g.reshape(xt.shape))
+        not ctx.is_eval_shape, xet, w_sorted, order, inv, sizes, eg, eu, ed,
+        h, u, g.reshape(xet.shape))
     with jax.named_scope("moe.route"):
         # back to slot order: sorted by the permutation itself, row j
         # lands at order[j] (a sort, where a gather of k*N scalars by
@@ -481,8 +525,12 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
     if not ctx.is_eval_shape:
         from ..observability import metrics
         metrics.inc("moe.bwd_residual")
-    grads = {"X": [(dxt + dxt_route).reshape(x.shape)], "GateW": [dwg],
-             "ExpertUp": [deu], "ExpertDown": [ded]}
+    grads = {"GateW": [dwg], "ExpertUp": [deu], "ExpertDown": [ded]}
+    if xe is None:
+        grads["X"] = [(dxt + dxt_route).reshape(x.shape)]
+    else:        # the route's gradient to what it read, the experts' to theirs
+        grads.update(X=[dxt_route.reshape(x.shape)],
+                     ExpertX=[dxt.reshape(xe.shape)])
     if eg is not None:
         grads["ExpertGate"] = [deg]
     return grads
@@ -500,6 +548,9 @@ def _routed_moe(ctx, ins, attrs):
     d = x.shape[-1]
     xt = x.reshape(-1, d)
     n = xt.shape[0]
+    xe, xet = _expert_input(ins, xt)
+    if xet.shape[0] != n:
+        raise ValueError(f"routed_moe: ExpertX has {xet.shape[0]} rows, X {n}")
 
     with jax.named_scope("moe.route"):
         scores = _scores(xt, wg, attrs.get("scoring", "sigmoid"))
@@ -520,10 +571,11 @@ def _routed_moe(ctx, ins, attrs):
         slots = jnp.arange(n * top_k, dtype=jnp.int32)
         _, order, w_sorted = jax.lax.sort(
             (eid, slots, w_slot.reshape(-1)), num_keys=1, is_stable=True)
-        inv = jnp.zeros((n * top_k,), jnp.int32).at[order].set(
-            slots, unique_indices=True)
+        inv = _token_rows(
+            jnp.zeros((n * top_k,), jnp.int32).at[order].set(
+                slots, unique_indices=True), top_k, e_held)
 
-    out, h, u = _held_experts(not ctx.is_eval_shape, xt, w_sorted, order,
+    out, h, u = _held_experts(not ctx.is_eval_shape, xet, w_sorted, order,
                               inv, sizes, eg, eu, ed)
     if not ctx.is_eval_shape:
         from ..observability import metrics
@@ -534,7 +586,12 @@ def _routed_moe(ctx, ins, attrs):
                     else "moe.layers_lowered")
         if n_group > 1 and not ctx.in_vjp:
             metrics.inc("moe.group_limited_layers")
-    outs = {"Out": [out.astype(eu.dtype).reshape(x.shape)],
+        if top_k > e_held and not ctx.in_vjp:
+            metrics.inc("moe.rows_bounded")
+        if xe is not None and not ctx.in_vjp:
+            metrics.inc("moe.latent_layers_lowered")
+    outs = {"Out": [out.astype(eu.dtype).reshape(
+                x.shape if xe is None else xe.shape)],
             "TopIdx": [idx.astype(INT64_DEVICE_DTYPE)],
             "ExpertLoad": [sizes], "U": [u],
             "SortedW": [w_sorted], "Order": [order], "Inv": [inv]}
