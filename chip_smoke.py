@@ -24,6 +24,8 @@ published width of models the repo already has:
   ssm_scan                     the selective scan's two chunk kernels at
                                the hybrid cell's shape beside the
                                `jax.numpy` form on the same operands
+  kda_scan                     the gated delta rule's two chunk kernels at
+                               the linear-attention cell's shape, likewise
   four_chips                   the first trainer on every visible device
                                (dp=N), replicated and ZeRO-1; runs when JAX
                                finds at least four
@@ -76,6 +78,9 @@ FULL = {
     # of 128
     "scan": {"b": 1, "s": 8192, "h": 64, "p": 64, "g": 8, "n": 128,
              "chunk": 128},
+    # one linear-attention layer's gated delta rule at the KDA cell's size:
+    # 1 x 8,192 positions, 16 heads of 128, chunks of 64
+    "delta": {"b": 1, "s": 8192, "h": 16, "d": 128, "chunk": 64},
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -97,6 +102,7 @@ TINY = {
     "experts": {"rows": 96, "d": 384, "f": 128, "experts": 4},
     "scan": {"b": 1, "s": 256, "h": 4, "p": 64, "g": 2, "n": 128,
              "chunk": 128},
+    "delta": {"b": 1, "s": 128, "h": 2, "d": 128, "chunk": 64},
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -930,33 +936,47 @@ def ssm_scan_forms(b, s, h, p, g, n, chunk, heads=None, time_xla=True,
                 else t for t in outs)
         return jax.jit(call)
 
-    ms = functools.partial(_ms_a_launch, launches)
-
     ops = (x, bm, cm, dt, cum, d)
     forward = {"kernel": by_heads(lambda *a: ssm_chunk.ssd_fwd(plan, *a)),
                "xla": by_heads(lambda *a: ssm._ssd_fwd(chunk, *a))}
     backward = {"kernel": by_heads(lambda *a: ssm_chunk.ssd_bwd(plan, *a)),
                 "xla": by_heads(lambda *a: ssm._ssd_bwd(chunk, *a))}
     _, states = forward["xla"](*ops)
-    on_chip = jax.devices()[0].platform == "tpu"
     rows_bytes = x.nbytes + bm.nbytes + cm.nbytes + dt.nbytes + 2 * cum.nbytes
     least = {"fwd": rows_bytes + x.nbytes + states.nbytes,
              "bwd": 2 * rows_bytes + x.nbytes + states.nbytes}
+    return _kernels_beside_form(
+        "ssm scan", plan, least, time_xla, launches,
+        ("fwd", forward, ops, ("y", "states")),
+        ("bwd", backward, ops + (states, dy),
+         ("dx", "db", "dc", "ddt", "dcum", "dd")))
+
+
+def _kernels_beside_form(label, plan, least, time_xla, launches, *passes,
+                         tol=lambda out: SCAN_TOL, also=None):
+    """The facts of `ssm_scan_forms` / `kda_scan_forms`: for each pass
+    (name, {"kernel": fn, "xla": fn}, args, output names) every output's
+    gap inside `tol(output)` of its largest reference value, the
+    milliseconds a launch of the kernel (and of the form, `time_xla`), what
+    `also(name, args)` adds, and the share of the least time `least[name]`
+    bytes take at the chip's HBM rate (`None` off a chip)."""
+    import jax
+    ms = functools.partial(_ms_a_launch, launches)
+    on_chip = jax.devices()[0].platform == "tpu"
     facts = {"plan": list(plan)}
-    for name, forms, args, outs in (
-            ("fwd", forward, ops, ("y", "states")),
-            ("bwd", backward, ops + (states, dy),
-             ("dx", "db", "dc", "ddt", "dcum", "dd"))):
+    for name, forms, args, outs in passes:
         got, want = forms["kernel"](*args), forms["xla"](*args)
         row = {}
         for out, a, w in zip(outs, got, want):
             row[out] = _parity(a, w)
             check(row[out]["max_abs_diff"]
-                  <= SCAN_TOL * row[out]["max_abs_ref"],
-                  f"ssm scan {name} {out}: {row[out]} exceeds {SCAN_TOL}")
+                  <= tol(out) * row[out]["max_abs_ref"],
+                  f"{label} {name} {out}: {row[out]} exceeds {tol(out)}")
         row["ms_kernel"] = ms(forms["kernel"], *args)
         if time_xla:
             row["ms_xla"] = ms(forms["xla"], *args)
+        if also is not None:
+            row.update(also(name, args))
         row["bytes_least_share"] = None
         if on_chip:
             import bench
@@ -974,6 +994,96 @@ def leg_ssm_scan(preset, clock):
         for name in ("fwd", "bwd"):
             row = facts[name]
             print(f"[chip_smoke] ssm_scan {name}: kernel {row['ms_kernel']} "
+                  f"ms ({row['bytes_least_share']} of its bytes' least "
+                  f"time), jax.numpy form {row['ms_xla']} ms", flush=True)
+    return facts
+
+
+# ---------------------------------------------------------------------------
+# the gated delta rule's chunk kernels
+# ---------------------------------------------------------------------------
+def kda_scan_forms(b, s, h, d, chunk, heads=None, time_xla=True,
+                   without_solve=False, launches=10, seed=11):
+    """`ops/pallas/kda_chunk.py`'s two kernels on one layer's operands in
+    bf16 (decay, beta and states float32) beside `ops/kda.py`'s `jax.numpy`
+    form on the same operands: each result's gap over the largest
+    reference value, the host-clock milliseconds a launch of each (a time
+    only on a chip), and the kernels' share of the least time their bytes
+    take (the rows and `States` once each way; `None` off a chip). The rows
+    enter as the `[B, S, H * d]` views a layer's projection gives and are
+    cut into heads inside the timed function, as in a step. q and k are
+    L2-normed a head and g lies in (-5, 0), as the builder makes them.
+    `heads`: heads a grid step, in place of the shape rule's own: the
+    sweep's handle, which times the `jax.numpy` form once (`time_xla`).
+    `without_solve` also times the forward kernel with the solve left out
+    (a wrong answer, timed only): the difference is what the in-kernel
+    solve costs."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kda
+    from paddle_tpu.ops.pallas import kda_chunk
+
+    rng = np.random.RandomState(seed)
+    bf = jnp.bfloat16
+
+    def unit(t):
+        return t / np.linalg.norm(t, axis=-1, keepdims=True)
+
+    def merged(t, dtype):
+        return jnp.asarray(t.reshape(b, s, h * d), dtype)
+
+    q = merged(unit(rng.randn(b, s, h, d)) * d ** -0.5, bf)
+    k = merged(unit(rng.randn(b, s, h, d)), bf)
+    v, do = merged(rng.randn(b, s, h, d), bf), merged(rng.randn(b, s, h, d), bf)
+    g = merged(-5.0 * rng.uniform(0, 1, (b, s, h, d)) ** 0.5, jnp.float32)
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.randn(b, s, h))), jnp.float32)
+    plan = kda_chunk.plan((b, s, h, d), (b, s, h, d), chunk, bf, heads=heads)
+    check(plan is not None, f"q {(b, s, h, d)} chunks of {chunk}, {heads} "
+          "heads a step: the kernels do not take the shape")
+
+    def by_heads(fn):
+        """fn on q, k, v, g (and do) cut into heads, its row-shaped results
+        merged again."""
+        def call(*args):
+            outs = fn(*(t.reshape(b, s, h, d) if t.shape == q.shape else t
+                        for t in args))
+            return tuple(t.reshape(b, s, h * d) if t.shape == (b, s, h, d)
+                         else t for t in outs)
+        return jax.jit(call)
+
+    ops = (q, k, v, g, beta)
+    forward = {"kernel": by_heads(lambda *a: kda_chunk.kda_fwd(plan, *a)),
+               "xla": by_heads(lambda *a: kda._kda_fwd(chunk, *a))}
+    backward = {"kernel": by_heads(lambda *a: kda_chunk.kda_bwd(plan, *a)),
+                "xla": by_heads(lambda *a: kda._kda_bwd(chunk, *a))}
+    _, states = forward["xla"](*ops)
+    rows_bytes = q.nbytes + k.nbytes + v.nbytes + g.nbytes + beta.nbytes
+    least = {"fwd": rows_bytes + v.nbytes + states.nbytes,
+             "bwd": 2 * rows_bytes + v.nbytes + states.nbytes}
+
+    def without(name, args):
+        if not (without_solve and name == "fwd"):
+            return {}
+        return {"ms_kernel_without_solve": _ms_a_launch(launches, by_heads(
+            lambda *a: kda_chunk.kda_fwd(plan, *a, solve=False)), *args)}
+
+    # dg is a difference of sums as long as the chunk: the form's own bf16
+    # gap to float32 is a tenth of its largest value
+    return _kernels_beside_form(
+        "kda scan", plan, least, time_xla, launches,
+        ("fwd", forward, ops, ("y", "states")),
+        ("bwd", backward, ops + (states, do),
+         ("dq", "dk", "dv", "dg", "dbeta")),
+        tol=lambda out: 10 * SCAN_TOL if out == "dg" else SCAN_TOL,
+        also=without)
+
+
+def leg_kda_scan(preset, clock):
+    facts = kda_scan_forms(**preset["delta"])
+    if preset["expect_mosaic"]:
+        for name in ("fwd", "bwd"):
+            row = facts[name]
+            print(f"[chip_smoke] kda_scan {name}: kernel {row['ms_kernel']} "
                   f"ms ({row['bytes_least_share']} of its bytes' least "
                   f"time), jax.numpy form {row['ms_xla']} ms", flush=True)
     return facts
@@ -1054,6 +1164,7 @@ LEGS = (("train_bert_base_s128", leg_train_s128),
         ("kernels", leg_kernels),
         ("grouped_matmul", leg_grouped_matmul),
         ("ssm_scan", leg_ssm_scan),
+        ("kda_scan", leg_kda_scan),
         ("four_chips", leg_four_chips))
 
 

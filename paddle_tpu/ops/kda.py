@@ -42,6 +42,19 @@ is the rule's own function, so a segment differentiated as a whole
 (recompute, layer scan) gets the same gradients with the forward lowered
 once more (`kda.bwd_recomputed` counts those, `kda.bwd_residual` the
 rule's).
+
+Both directions of the core have two lowerings, chosen from the operands'
+shapes and dtype alone (`_route`): where K and V are one lane tile (128),
+the chunk 32, 64 or 128 and the rows bf16 or float32, the two Pallas
+kernels of `ops/pallas/kda_chunk.py`, a grid over (batch, head block,
+chunk) with the state (backward: its cotangent) in VMEM scratch, in which
+everything a chunk makes (running sums, decayed rows, the `[L, L]`
+products, the solve as an explicit float32 inverse, `U`, `W`) never
+reaches HBM; everywhere else (every tiny configuration, a head of 64 or
+96, float16) the `jax.numpy` form below, `_kda_fwd` / `_kda_bwd`, which is
+also the kernels' specification and their oracle in the tests. Same
+operands, same results, same residual either way; `kda.scan_pallas` /
+`kda.scan_xla` count each lowering that stays in the program.
 """
 from __future__ import annotations
 
@@ -231,18 +244,51 @@ def _kda_bwd(chunk, q, k, v, g, beta, states, do):
     return local_vjp((du, dw, dkend, ddecay, dqplus, dpqk))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _kda(chunk, q, k, v, g, beta):
-    return _kda_fwd(chunk, q, k, v, g, beta)
+def _route(chunk, count, q, v):
+    """The Pallas kernels' plan where their shape rule takes the operands
+    (`ops/pallas/kda_chunk.py` `plan`), else None: the `jax.numpy` form
+    above. `count`: whether this trace's call counts, `kda.scan_pallas` /
+    `kda.scan_xla`, once per forward or backward lowered."""
+    from .pallas import kda_chunk
+    plan = kda_chunk.plan(q.shape, v.shape, chunk, q.dtype) \
+        if v.dtype == q.dtype else None
+    if count:
+        from ..observability import metrics
+        metrics.inc("kda.scan_xla" if plan is None else "kda.scan_pallas")
+    return plan
 
 
-def _kda_vjp_fwd(chunk, *args):
-    o, states = _kda_fwd(chunk, *args)
+def _scan_fwd(chunk, count, q, k, v, g, beta):
+    plan = _route(chunk, count, q, v)
+    if plan is None:
+        return _kda_fwd(chunk, q, k, v, g, beta)
+    from .pallas import kda_chunk
+    return kda_chunk.kda_fwd(plan, q, k, v, g, beta)
+
+
+def _scan_bwd(chunk, count, q, k, v, g, beta, states, do):
+    plan = _route(chunk, count, q, v)
+    if plan is None:
+        return _kda_bwd(chunk, q, k, v, g, beta, states, do)
+    from .pallas import kda_chunk
+    return kda_chunk.kda_bwd(plan, q, k, v, g, beta, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _kda(chunk, count, relowered, q, k, v, g, beta):
+    # `relowered` (the generic `__vjp__` differentiates a whole segment):
+    # JAX traces this body to a jaxpr it then replaces by the two rules
+    # below, so what is traced here is in no program and does not count
+    return _scan_fwd(chunk, count and not relowered, q, k, v, g, beta)
+
+
+def _kda_vjp_fwd(chunk, count, relowered, *args):
+    o, states = _scan_fwd(chunk, count, *args)
     return (o, states), args + (states,)
 
 
-def _kda_vjp_bwd(chunk, res, cts):
-    return _kda_bwd(chunk, *res, cts[0])
+def _kda_vjp_bwd(chunk, count, relowered, res, cts):
+    return _scan_bwd(chunk, count, *res, cts[0])
 
 
 _kda.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)
@@ -271,9 +317,9 @@ def _kda_scan_grad(ctx, ins, attrs, outs, ogs):
         return None
     q, k, v, g, raw = (ins[s][0] for s in ("Q", "K", "V", "G", "Beta"))
     beta, beta_vjp = jax.vjp(_beta, raw)
-    dq, dk, dv, dg, dbeta = _kda_bwd(_chunk_size(q, attrs), q, k, v,
-                                     g.astype(_F32), beta,
-                                     outs["States"][0], do)
+    dq, dk, dv, dg, dbeta = _scan_bwd(
+        _chunk_size(q, attrs), not ctx.is_eval_shape, q, k, v,
+        g.astype(_F32), beta, outs["States"][0], do)
     if not ctx.is_eval_shape:
         from ..observability import metrics
         metrics.inc("kda.bwd_residual")
@@ -290,8 +336,8 @@ def _kda_scan(ctx, ins, attrs):
     if k.shape != q.shape or g.shape != k.shape or raw.shape != q.shape[:3]:
         raise ValueError(f"kda_scan: Q {q.shape}, K {k.shape}, G {g.shape}, "
                          f"Beta {raw.shape}")
-    y, states = _kda(_chunk_size(q, attrs), q, k, v, g.astype(_F32),
-                     _beta(raw))
+    y, states = _kda(_chunk_size(q, attrs), not ctx.is_eval_shape,
+                     ctx.in_vjp, q, k, v, g.astype(_F32), _beta(raw))
     if not ctx.is_eval_shape:
         from ..observability import metrics
         metrics.inc("kda.bwd_recomputed" if ctx.in_vjp
