@@ -198,6 +198,20 @@ def _switch_moe(ctx, ins, attrs):
 # W_down relu(W_up x)^2), and the share of one expert-parallel rank: told
 # which experts it holds, it routes over all of them and computes its own
 # part.
+#
+# The route moves no scalar by a gather or a scatter, forward or backward.
+# On a TPU v5e those are priced by the element and not by the byte: one of
+# k*N scalars takes 0.23 to 0.46 ms at 49,152 slots (PR 29's trace) and 0.42
+# (the inverse permutation's scatter) to 0.92 ms (`take_along_axis` out of
+# [4096, 512]) at 90,112 (PR 41's), what moving 300 to 750 MB takes, where
+# a sort of the same slots takes 0.08 to 0.10 and carries a payload for
+# nothing, and a select reduced inside one fusion 0.08. So the route uses a
+# reduce or a sort's payload: the slots' weights are picked by a one-hot
+# reduce (`_slot_weights`), ride through the stable sort by held expert as
+# its payload and get their gradient back by a sort on the permutation
+# (`_sort_slots`), which is also how the permutation is inverted
+# (`_unsort`). The gather form is the oracle of `tests/test_moe_route.py`,
+# bit for bit, where a step's scalar moves are counted too (none).
 # ---------------------------------------------------------------------------
 
 # What `routed_moe`'s forward writes for its grad rule (beside `TopIdx` and
@@ -467,12 +481,52 @@ def _group_limited(sel, n_group, topk_group):
 
 def _slot_weights(scores, idx, local, attrs):
     """[k, N] float32: the chosen experts' scores, normalised and scaled; 0
-    where the slot's expert is held elsewhere."""
-    w = jnp.take_along_axis(scores, idx, axis=1)
+    where the slot's expert is held elsewhere. scores[n, idx[n, j]] as a
+    reduce over the experts of a select (no gather: the note above): a
+    token picks no expert twice, so every sum has one term that is not 0
+    and is the gather's value bit for bit; JAX's transpose is the same
+    select reduced over the slots, the scatter-add's bit for bit. XLA keeps
+    the [N, k, E] select inside the reduce's fusion; the barrier keeps it
+    from folding the sum over a token's slots below into that reduce, one
+    sum over (slot, expert) that rounds in another order."""
+    picked = idx[:, :, None] == jnp.arange(scores.shape[1])
+    w = jax.lax.optimization_barrier(
+        jnp.sum(jnp.where(picked, scores[:, None, :], 0.0), axis=2))
     if attrs.get("norm_topk", True):
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
     w = w * float(attrs.get("routed_scaling", 1.0))
     return jnp.where(local, w, 0.0).T
+
+
+def _unsort(order, a_sorted):
+    """a_sorted [k*N] back in slot order: sorted by the permutation itself,
+    row j lands at order[j]. Of the slots' own numbers that is the inverse
+    permutation."""
+    return jax.lax.sort((order, a_sorted), num_keys=1)[1]
+
+
+@jax.custom_vjp
+def _sort_slots(eid, w):
+    """(order, w_sorted): the stable argsort of eid [k*N], and the slots'
+    weights w in that order, carried along as the sort's payload. The
+    payload's gradient comes back by `_unsort` (JAX's own rule for a sort
+    gathers the tangent by `order`, and its transpose scatters)."""
+    slots = jnp.arange(eid.shape[0], dtype=jnp.int32)
+    _, order, w_sorted = jax.lax.sort((eid, slots, w), num_keys=1,
+                                      is_stable=True)
+    return order, w_sorted
+
+
+def _sort_slots_fwd(eid, w):
+    order, w_sorted = _sort_slots(eid, w)
+    return (order, w_sorted), order
+
+
+def _sort_slots_bwd(order, cts):
+    return None, _unsort(order, cts[1])
+
+
+_sort_slots.defvjp(_sort_slots_fwd, _sort_slots_bwd)
 
 
 def _expert_input(ins, xt):
@@ -513,10 +567,7 @@ def _routed_moe_grad(ctx, ins, attrs, outs, ogs):
         not ctx.is_eval_shape, xet, w_sorted, order, inv, sizes, eg, eu, ed,
         h, u, g.reshape(xet.shape))
     with jax.named_scope("moe.route"):
-        # back to slot order: sorted by the permutation itself, row j
-        # lands at order[j] (a sort, where a gather of k*N scalars by
-        # `inv` takes ten times as long on a TPU)
-        _, dw = jax.lax.sort((order, dw_sorted), num_keys=1)
+        dw = _unsort(order, dw_sorted)
         _, route_vjp = jax.vjp(
             lambda xt, wg: _slot_weights(
                 _scores(xt, wg, attrs.get("scoring", "sigmoid")), idx, local,
@@ -566,14 +617,10 @@ def _routed_moe(ctx, ins, attrs):
         eid = jnp.where(local, idx - off, e_held).T.reshape(-1)  # [k*N]
         sizes = jnp.sum(eid[:, None] == jnp.arange(e_held)[None, :],
                         axis=0, dtype=jnp.int32)             # [E_held]
-        # the stable argsort of eid, with the slots' weights carried along
-        # as the sort's payload
-        slots = jnp.arange(n * top_k, dtype=jnp.int32)
-        _, order, w_sorted = jax.lax.sort(
-            (eid, slots, w_slot.reshape(-1)), num_keys=1, is_stable=True)
+        order, w_sorted = _sort_slots(eid, w_slot.reshape(-1))
         inv = _token_rows(
-            jnp.zeros((n * top_k,), jnp.int32).at[order].set(
-                slots, unique_indices=True), top_k, e_held)
+            _unsort(order, jnp.arange(n * top_k, dtype=jnp.int32)),
+            top_k, e_held)
 
     out, h, u = _held_experts(not ctx.is_eval_shape, xet, w_sorted, order,
                               inv, sizes, eg, eu, ed)

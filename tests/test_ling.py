@@ -675,8 +675,8 @@ def test_without_groups_routed_moe_traces_as_before(monkeypatch):
     """`n_group` 1 (every cell the benchmark had): the op's forward and its
     grad rule trace to the jaxpr of the tree before group-limited selection
     (commit 40a2d5b, jax 0.9.0; the digest is `tests/test_nemotron_h.py`'s
-    for sigmoid scoring with a bias, made there), whether the attr is left
-    out or given as 1."""
+    for sigmoid scoring with a bias, made there and changed with it by
+    PR 41's route), whether the attr is left out or given as 1."""
     from paddle_tpu.ops.pallas import grouped_matmul
     monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
     n, d, f, held, total = 512, 128, 256, 4, 16
@@ -702,7 +702,7 @@ def test_without_groups_routed_moe_traces_as_before(monkeypatch):
     attrs = {"top_k": 2, "routed_scaling": 2.5, "norm_topk": True,
              "experts_total": total, "expert_offset": 4,
              "scoring": "sigmoid"}
-    want = "2d66c1be3a857fdad27059c9c02828973d4d5717b9a4099ed27d0e6c5fa275bf"
+    want = "756425e5b9df58c88211a24260d81274489de5600b84480885065b2d97d77f9f"
     assert _jaxpr_digest(step(attrs), *structs) == want
     assert _jaxpr_digest(step(dict(attrs, n_group=1, topk_group=1)),
                          *structs) == want
@@ -715,7 +715,7 @@ def test_latent_attention_as_kanana_calls_it_traces_as_before():
     (`ling.gated_latent_attention`) and not an option: the tiny preset's
     float32 train step traces to the jaxpr of the tree before this model
     (commit 40a2d5b, jax 0.9.0; the digest was made there, source lines
-    cut)."""
+    cut, and again with PR 41's route in `routed_moe`)."""
     reset_programs(0)
     cfg = deepseek_v3.DeepseekV3Config.tiny()
     _, loss, _ = deepseek_v3.build_causal_lm_program(cfg)
@@ -730,7 +730,7 @@ def test_latent_attention_as_kanana_calls_it_traces_as_before():
 
 
 KANANA_DIGEST = (
-    "da6b4d44942ba1fb15bb0cd0ec27bb5ee3b8277a72ce1ebc69d08f3d70d62e95")
+    "b55dd5734b173553d7c9752e5e345b292002dc0118da0dfaf078759bc831ca74")
 
 
 # ---------------------------------------------------------------------------
@@ -1075,15 +1075,16 @@ def _bert_pretrain():
 
 @pytest.mark.parametrize("cell, digest", [
     ("bert", "680dd440f44ce047ab42aefcc7b90d4a5196cb72a073633a721ac25285f98ca7"),
-    ("mellum", "2f7fa217c1c819fede4ff57ff19bfe40a5c2485abd2d186a1e553ede579a1e1b"),
+    ("mellum", "ece5b162f3f2d703cacc41a43320bc2ae04f46f1dfcef901271578999e044e88"),
     ("latent_hybrid",
-     "8bdacc815f74d080da1bd5e469d330fcd5baeda7f0d31be32b4635b5ae6c1197")])
+     "ce94d43b5b24963e07c270f8de8b711da0ef8d8ea3d4a1909f00d4de6d174779")])
 def test_the_other_cells_builders_trace_as_before(cell, digest):
     """The delta rule's kernels are reached from `ops/kda.py` alone, and
     `models/ling.py` is its only caller: the tiny float32 train step of the
     builders behind the other cells traces to the jaxpr of the tree before
     the kernels (commit fa2014b, jax 0.9.0; the digests were made there,
-    source lines cut). BERT's (both BERT cells), the sliding-window one's
+    source lines cut, the two sparse ones again with PR 41's route in
+    `routed_moe`). BERT's (both BERT cells), the sliding-window one's
     and the latent-expert hybrid's here; kanana's is
     `test_latent_attention_as_kanana_calls_it_traces_as_before` above, the
     hybrid's `tests/test_nemotron3_super.py::

@@ -411,21 +411,21 @@ _CALLS = {
     "kanana2": (dict(gate=True, bias=True, held=16, total=128, attrs={
         "top_k": 6, "routed_scaling": 2.5, "norm_topk": True,
         "scoring": "sigmoid"}),
-        "1644cd00f81e97bb05f6ceb5a0a04cd1cb234cf46ca3b4b9b79e1d5127ba87bf"),
+        "3dc72d526f2140d1089f83a8e141efcb801baed0596627724782a08eb22381f0"),
     "mellum2": (dict(gate=True, bias=False, held=16, total=64, attrs={
         "top_k": 8, "routed_scaling": 1.0, "norm_topk": True,
         "scoring": "softmax"}),
-        "6697040df5003f1cd6460445b6d1065d1b2ca41abddf64d7015bec54a8663f1e"),
+        "654c4e85dab8f7eacf2ae98e6f9287531019d176c2d291bc9e02584803353e7a"),
     "nemotron_twotower": (dict(gate=False, bias=True, held=8, total=128,
                                attrs={"top_k": 6, "routed_scaling": 2.5,
                                       "norm_topk": True,
                                       "scoring": "sigmoid"}),
-                          "4dc82a9a53c6e7bbd5eb90a3ab738f86bd4e028b03e07163"
-                          "2ace4bd209c2993f"),
+                          "1c71ac024a90176c6f3a5f4dd2cae00ed6723204c1842908"
+                          "84be311f4b5cd52b"),
     "ling3": (dict(gate=True, bias=True, held=8, total=512, attrs={
         "top_k": 8, "routed_scaling": 2.5, "norm_topk": True,
         "scoring": "sigmoid", "n_group": 8, "topk_group": 4}),
-        "4447ea0b7828cda63438c0b4eb8efb88a4e318f165382886bfb8e707fdf3d401"),
+        "b1d2f1e9a4db8534c42e2506e9f7d790fa876875b70e643275c4c297d288f1f8"),
 }
 
 
@@ -460,10 +460,12 @@ def _routed_jaxpr(gate, bias, held, total, attrs, n=256, d=128, f=256):
 def test_as_the_four_sparse_cells_call_it_routed_moe_traces_as_before(
         cell, monkeypatch):
     """One input and no more slots a token than experts held: the op's
-    forward and its grad rule trace to the jaxpr of the tree before the
-    experts' own input and the bounded buffer (commit 147251f, jax 0.9.0;
-    the digests were made there, source lines cut). A deliberate change to
-    `routed_moe` changes the digests with it."""
+    forward and its grad rule trace to one jaxpr whatever the experts' own
+    input and the bounded buffer added (jax 0.9.0, source lines cut). The
+    digests were made at commit 147251f, before both, and again where a
+    deliberate change to `routed_moe` changed them with it: PR 41's route
+    without scalar gathers (`tests/test_moe_route.py` holds that route to
+    the one before it, bit for bit)."""
     from paddle_tpu.ops.pallas import grouped_matmul
     monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
     call, digest = _CALLS[cell]
@@ -473,8 +475,9 @@ def test_as_the_four_sparse_cells_call_it_routed_moe_traces_as_before(
 
 def test_without_a_latent_and_with_every_head_the_model_traces_as_before():
     """`NemotronHConfig.tiny()` has no latent and holds every head: its
-    float32 train step traces to the jaxpr of the tree before the new keys
-    (commit 147251f, jax 0.9.0; source lines cut)."""
+    float32 train step traces to the jaxpr it had before the new keys
+    (jax 0.9.0; source lines cut; made at commit 147251f, and again with
+    PR 41's route, the one change to its ops since)."""
     reset_programs(0)
     cfg = nemotron_h.NemotronHConfig.tiny()
     _, loss, _ = nemotron_h.build_causal_lm_program(cfg)
@@ -485,7 +488,7 @@ def test_without_a_latent_and_with_every_head_the_model_traces_as_before():
     text = re.sub(r"[\w/.\-]+\.py:\d+", "F:N",
                   str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)))
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "015838cec084bacab4420967788b3de73beb2f333adb69e204dc9dd2c7a8c328")
+        "dc9e4d6bbb4513b2741e514caf9d4b7195cf9afe151839d818cf5f3bd66c00c9")
 
 
 # ---------------------------------------------------------------------------

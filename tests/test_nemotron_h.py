@@ -643,9 +643,9 @@ def _gated_jaxpr(scoring, bias, monkeypatch):
 
 @pytest.mark.parametrize("scoring, bias, digest", [
     ("sigmoid", True,
-     "2d66c1be3a857fdad27059c9c02828973d4d5717b9a4099ed27d0e6c5fa275bf"),
+     "756425e5b9df58c88211a24260d81274489de5600b84480885065b2d97d77f9f"),
     ("softmax", False,
-     "c087cba62117f85d518f5face8bdb13dc1916ba55d6ad120864566d2f2964719")],
+     "5ddf58c49db0d1315192a97be0bcc0e83840cdca364ea69ef75fc61e8fb0d287")],
     ids=["sigmoid-bias", "softmax"])
 def test_with_a_gate_routed_moe_traces_as_before(scoring, bias, digest,
                                                  monkeypatch):
@@ -653,7 +653,8 @@ def test_with_a_gate_routed_moe_traces_as_before(scoring, bias, digest,
     the jaxpr of the tree before experts without a gate (commit 44019f7,
     jax 0.9.0; the digests were made there, source lines cut). The two
     sparse cells that run gated experts must not pay for the other form. A
-    deliberate change to `routed_moe` changes the digests with it."""
+    deliberate change to `routed_moe` changes the digests with it: PR 41's
+    route without scalar gathers did (`tests/test_moe_route.py`)."""
     text = _gated_jaxpr(scoring, bias, monkeypatch)
     assert text.count("pallas_call") == 6
     assert hashlib.sha256(text.encode()).hexdigest() == digest
