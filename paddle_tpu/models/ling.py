@@ -37,10 +37,10 @@ from .. import layers
 from .. import initializer as I
 from ..framework.program import name_scope
 from ..layer_helper import ParamAttr
-from ..observability.trace import RecordEvent
-from ..parallel.mesh import ShardingRules, moe_sharding_rules
-from .deepseek_v3 import (_heads, _linear, _norm, _w, embed_tokens,
-                          gated_ffn, next_token_loss, record_expert_load)
+from ..parallel.mesh import ShardingRules
+from . import causal_lm
+from .causal_lm import (_heads, _linear, _norm, _w, gated_ffn,
+                        record_expert_load)
 
 __all__ = ["LingConfig", "build_causal_lm_program", "record_expert_load",
            "sharding_rules"]
@@ -212,28 +212,11 @@ def expert_layer(x, cfg: LingConfig, pre: str, n: int):
     _no_clamp(cfg.expert_swiglu_limit_list, n, "expert_swiglu_limit_list")
     _no_clamp(cfg.share_expert_swiglu_limit_list, n,
               "share_expert_swiglu_limit_list")
-    h, f = cfg.hidden_size, cfg.moe_intermediate_size
-    held = cfg.experts_held or cfg.num_experts
-    gate_w = layers.create_parameter(
-        [h, cfg.num_experts], "float32", attr=_w(pre + "router_w", cfg))
-    bias = layers.create_parameter(
-        [cfg.num_experts], "float32",
-        attr=ParamAttr(name=pre + "router_bias", trainable=False,
-                       initializer=I.Constant(0.0)))
-    experts = [layers.create_parameter(
-        shape, "float32", attr=_w(pre + f"experts_{name}_w", cfg))
-        for name, shape in (("gate", [held, h, f]), ("up", [held, h, f]),
-                            ("down", [held, f, h]))]
-    routed, idx, load = layers.routed_moe(
-        x, gate_w, *experts, top_k=cfg.num_experts_per_tok, select_bias=bias,
-        routed_scaling=cfg.routed_scaling_factor,
-        norm_topk=cfg.norm_topk_prob, experts_total=cfg.num_experts,
-        expert_offset=cfg.expert_offset, n_group=cfg.n_group,
-        topk_group=cfg.topk_group)
-    with name_scope("moe.shared"):
-        shared = gated_ffn(x, cfg.moe_shared_expert_intermediate_size,
-                           pre + "shared_", cfg)
-        return layers.elementwise_add(routed, shared), idx, load
+    return causal_lm.expert_layer(
+        x, cfg, pre, experts_total=cfg.num_experts,
+        routed_scaling=cfg.routed_scaling_factor, n_group=cfg.n_group,
+        topk_group=cfg.topk_group,
+        shared=(gated_ffn, cfg.moe_shared_expert_intermediate_size))
 
 
 def decoder_layer(x, cfg: LingConfig, n: int):
@@ -253,20 +236,11 @@ def decoder_layer(x, cfg: LingConfig, n: int):
 
 def build_causal_lm_program(cfg: LingConfig):
     """Next-token objective over `tokens` [B, seq_len]
-    (`models.deepseek_v3.next_token_loss`). Returns (tokens, loss, routed):
-    `routed` holds, per expert layer, the `(top_idx, expert_load)`
-    variables a caller may fetch beside the loss."""
-    with RecordEvent("program.build", args={"model": "ling"}):
-        tokens, x = embed_tokens(cfg)
-        ckpts, routed = [], []
-        for n in cfg.layers_here():
-            x, r = decoder_layer(x, cfg, n)
-            ckpts.append(x.name)
-            if r is not None:
-                routed.append(r)
-        loss = next_token_loss(x, tokens, cfg)
-        loss._layer_checkpoints = ckpts
-        return tokens, loss, routed
+    (`causal_lm.build_causal_lm_program`) of the layers held: (tokens,
+    loss, routed), `routed` the `(top_idx, expert_load)` variables of each
+    expert layer."""
+    return causal_lm.build_causal_lm_program(
+        cfg, "ling", decoder_layer, cfg.layers_here())
 
 
 def sharding_rules() -> ShardingRules:
@@ -276,13 +250,10 @@ def sharding_rules() -> ShardingRules:
     projections row-parallel, the dense and shared feed-forward parts by
     their width, the experts' leading dim over `ep`, the vocabulary over
     `tp`. `kv_a_proj` and the norms stay whole on every chip."""
-    return moe_sharding_rules(extra=[
+    return causal_lm.sharding_rules([
         (r"_(q|k|v|f|b|g|kv_b)_proj_w$", P(None, "tp")),
         (r"_(q|k|v)_conv_w$", P(None, "tp")),
         (r"_(A_log|dt_bias)$", P("tp")),
-        (r"_o_proj_w$", P("tp", None)),
         (r"_(mlp|shared)_(gate|up)_w$", P(None, "tp")),
         (r"_(mlp|shared)_down_w$", P("tp", None)),
-        (r"^embed_tokens$", P("tp", None)),
-        (r"^lm_head_w$", P(None, "tp")),
     ])

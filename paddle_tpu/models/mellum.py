@@ -23,17 +23,14 @@ its own (`moe.route`, `moe.dispatch`, `moe.experts`, `moe.combine`).
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 
 from jax.sharding import PartitionSpec as P
 
 from .. import layers
-from ..framework.program import name_scope
-from ..observability.trace import RecordEvent
-from ..parallel.mesh import ShardingRules, moe_sharding_rules
-from .deepseek_v3 import (_heads, _linear, _norm, _w, embed_tokens,
-                          next_token_loss, record_expert_load)
+from ..parallel.mesh import ShardingRules
+from . import causal_lm
+from .causal_lm import _norm, record_expert_load
 
 __all__ = ["MellumConfig", "build_causal_lm_program", "record_expert_load",
            "sharding_rules"]
@@ -102,41 +99,19 @@ def grouped_attention(x, cfg: MellumConfig, pre: str, kind: str):
     (query head h attends KV head h // group), causal, and in a sliding
     layer over the last `sliding_window` keys only. K and V go to the
     attention op at their own head count."""
-    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-    with name_scope("attn.proj"):
-        q = _rotary(_heads(_linear(x, nh * hd, pre + "q_proj_w", cfg),
-                           nh, hd), cfg, kind)
-        k = _rotary(_heads(_linear(x, nkv * hd, pre + "k_proj_w", cfg),
-                           nkv, hd), cfg, kind)
-        v = _heads(_linear(x, nkv * hd, pre + "v_proj_w", cfg), nkv, hd)
-    sliding = kind == SLIDING
-    with name_scope("attn.attend.window" if sliding else "attn.attend.full"):
-        ctx = layers.fused_attention(
-            q, k, v, causal=True, scale=1.0 / math.sqrt(hd),
-            window=cfg.sliding_window if sliding else None)
-    with name_scope("attn.proj"):
-        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                             [0, 0, nh * hd])
-        return _linear(ctx, cfg.hidden_size, pre + "o_proj_w", cfg)
+    return causal_lm.grouped_attention(
+        x, cfg, pre, cfg.num_attention_heads, cfg.num_key_value_heads,
+        rotary=lambda t: _rotary(t, cfg, kind),
+        window=cfg.sliding_window if kind == SLIDING else None)
 
 
 def expert_layer(x, cfg: MellumConfig, pre: str):
     """(this rank's routed part, top_idx, expert_load): a softmax over ALL
     `num_experts` scores, its top `num_experts_per_tok`, their weights
-    divided by their sum."""
-    h, f = cfg.hidden_size, cfg.moe_intermediate_size
-    held = cfg.experts_held or cfg.num_experts
-    gate_w = layers.create_parameter(
-        [h, cfg.num_experts], "float32", attr=_w(pre + "router_w", cfg))
-    experts = [layers.create_parameter(
-        shape, "float32", attr=_w(pre + f"experts_{n}_w", cfg))
-        for n, shape in (("gate", [held, h, f]), ("up", [held, h, f]),
-                         ("down", [held, f, h]))]
-    return layers.routed_moe(
-        x, gate_w, *experts, top_k=cfg.num_experts_per_tok,
-        scoring="softmax", norm_topk=cfg.norm_topk_prob,
-        experts_total=cfg.num_experts, expert_offset=cfg.expert_offset)
+    divided by their sum; no bias, no shared expert."""
+    return causal_lm.expert_layer(
+        x, cfg, pre, experts_total=cfg.num_experts, scoring="softmax",
+        select_bias=False)
 
 
 def decoder_layer(x, cfg: MellumConfig, n: int):
@@ -150,19 +125,10 @@ def decoder_layer(x, cfg: MellumConfig, n: int):
 
 def build_causal_lm_program(cfg: MellumConfig):
     """Next-token objective over `tokens` [B, seq_len]
-    (`models.deepseek_v3.next_token_loss`). Returns (tokens, loss, routed):
-    `routed` holds, per layer, the `(top_idx, expert_load)` variables a
-    caller may fetch beside the loss."""
-    with RecordEvent("program.build", args={"model": "mellum"}):
-        tokens, x = embed_tokens(cfg)
-        ckpts, routed = [], []
-        for n in range(cfg.num_hidden_layers):
-            x, r = decoder_layer(x, cfg, n)
-            ckpts.append(x.name)
-            routed.append(r)
-        loss = next_token_loss(x, tokens, cfg)
-        loss._layer_checkpoints = ckpts
-        return tokens, loss, routed
+    (`causal_lm.build_causal_lm_program`): (tokens, loss, routed), `routed`
+    the `(top_idx, expert_load)` variables of each layer."""
+    return causal_lm.build_causal_lm_program(
+        cfg, "mellum", decoder_layer, range(cfg.num_hidden_layers))
 
 
 def sharding_rules() -> ShardingRules:
@@ -170,9 +136,4 @@ def sharding_rules() -> ShardingRules:
     projection row-parallel, the experts' leading dim over `ep`, the
     vocabulary over `tp`. k and v split by KV head: `tp` may not pass
     `num_key_value_heads` (4 as published)."""
-    return moe_sharding_rules(extra=[
-        (r"_(q|k|v)_proj_w$", P(None, "tp")),
-        (r"_o_proj_w$", P("tp", None)),
-        (r"^embed_tokens$", P("tp", None)),
-        (r"^lm_head_w$", P(None, "tp")),
-    ])
+    return causal_lm.sharding_rules([(r"_(q|k|v)_proj_w$", P(None, "tp"))])

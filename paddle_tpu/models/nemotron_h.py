@@ -44,10 +44,9 @@ from .. import layers
 from .. import initializer as I
 from ..framework.program import name_scope
 from ..layer_helper import ParamAttr
-from ..observability.trace import RecordEvent
-from ..parallel.mesh import ShardingRules, moe_sharding_rules
-from .deepseek_v3 import (_heads, _linear, _norm, _w, embed_tokens,
-                          next_token_loss, record_expert_load)
+from ..parallel.mesh import ShardingRules
+from . import causal_lm
+from .causal_lm import _linear, _norm, _w, record_expert_load, relu2_ffn
 
 __all__ = ["NemotronHConfig", "build_causal_lm_program",
            "record_expert_load", "sharding_rules"]
@@ -90,10 +89,6 @@ class NemotronHConfig:
     mamba_groups_held: "int | None" = None
     heads_held: "int | None" = None
     kv_heads_held: "int | None" = None
-
-    @property
-    def rms_norm_eps(self):        # the name `deepseek_v3._norm` reads
-        return self.layer_norm_epsilon
 
     def kind(self, n: int) -> str:
         return self.hybrid_override_pattern[n]
@@ -196,12 +191,6 @@ def mamba_mixer(x, cfg: NemotronHConfig, pre: str):
         return _linear(y, cfg.hidden_size, pre + "out_proj_w", cfg)
 
 
-def relu2_ffn(x, width, pre, cfg):
-    """W_down relu(W_up x)^2."""
-    return _linear(layers.relu2(_linear(x, width, pre + "up_w", cfg)),
-                   cfg.hidden_size, pre + "down_w", cfg)
-
-
 def expert_layer(x, cfg: NemotronHConfig, pre: str):
     """(this rank's routed part + the shared expert, top_idx,
     expert_load): sigmoid scores over ALL `n_routed_experts`, the top
@@ -209,35 +198,20 @@ def expert_layer(x, cfg: NemotronHConfig, pre: str):
     sum and scaled; experts without a gate. With `moe_latent_size` the
     experts read z = x W_a and what they sum goes through W_b; the router
     and the shared expert read x."""
-    h, f = cfg.hidden_size, cfg.moe_intermediate_size
-    width = cfg.moe_latent_size or h
-    held = cfg.experts_held or cfg.n_routed_experts
-    gate_w = layers.create_parameter(
-        [h, cfg.n_routed_experts], "float32", attr=_w(pre + "router_w", cfg))
-    bias = layers.create_parameter(
-        [cfg.n_routed_experts], "float32",
-        attr=ParamAttr(name=pre + "router_bias", trainable=False,
-                       initializer=I.Constant(0.0)))
-    up, down = (layers.create_parameter(
-        shape, "float32", attr=_w(pre + f"experts_{n}_w", cfg))
-        for n, shape in (("up", [held, width, f]),
-                         ("down", [held, f, width])))
-    z = None
-    if cfg.moe_latent_size:
+    def into(x):
         with name_scope("moe.latent_down"):
-            z = _linear(x, width, pre + "latent_down_w", cfg)
-    routed, idx, load = layers.routed_moe(
-        x, gate_w, None, up, down, top_k=cfg.num_experts_per_tok,
-        select_bias=bias, routed_scaling=cfg.routed_scaling_factor,
-        norm_topk=cfg.norm_topk_prob, experts_total=cfg.n_routed_experts,
-        expert_offset=cfg.expert_offset, expert_input=z)
-    if cfg.moe_latent_size:
+            return _linear(x, cfg.moe_latent_size, pre + "latent_down_w", cfg)
+
+    def out_of(routed):
         with name_scope("moe.latent_up"):
-            routed = _linear(routed, h, pre + "latent_up_w", cfg)
-    with name_scope("moe.shared"):
-        shared = relu2_ffn(x, cfg.moe_shared_expert_intermediate_size,
-                           pre + "shared_", cfg)
-        return layers.elementwise_add(routed, shared), idx, load
+            return _linear(routed, cfg.hidden_size, pre + "latent_up_w", cfg)
+
+    return causal_lm.expert_layer(
+        x, cfg, pre, experts_total=cfg.n_routed_experts, gated=False,
+        routed_scaling=cfg.routed_scaling_factor,
+        latent=(cfg.moe_latent_size, into, out_of) if cfg.moe_latent_size
+        else None,
+        shared=(relu2_ffn, cfg.moe_shared_expert_intermediate_size))
 
 
 def grouped_attention(x, cfg: NemotronHConfig, pre: str):
@@ -246,18 +220,7 @@ def grouped_attention(x, cfg: NemotronHConfig, pre: str):
     built for the held query heads and the KV heads they read
     (`attention_share`). K and V go to the attention op at their own head
     count."""
-    (nh, nkv), hd = cfg.attention_share(), cfg.head_dim
-    with name_scope("attn.proj"):
-        q = _heads(_linear(x, nh * hd, pre + "q_proj_w", cfg), nh, hd)
-        k = _heads(_linear(x, nkv * hd, pre + "k_proj_w", cfg), nkv, hd)
-        v = _heads(_linear(x, nkv * hd, pre + "v_proj_w", cfg), nkv, hd)
-    with name_scope("attn.attend.full"):
-        ctx = layers.fused_attention(q, k, v, causal=True,
-                                     scale=1.0 / math.sqrt(hd))
-    with name_scope("attn.proj"):
-        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
-                             [0, 0, nh * hd])
-        return _linear(ctx, cfg.hidden_size, pre + "o_proj_w", cfg)
+    return causal_lm.grouped_attention(x, cfg, pre, *cfg.attention_share())
 
 
 def decoder_layer(x, cfg: NemotronHConfig, n: int):
@@ -279,20 +242,10 @@ def decoder_layer(x, cfg: NemotronHConfig, n: int):
 
 def build_causal_lm_program(cfg: NemotronHConfig):
     """Next-token objective over `tokens` [B, seq_len]
-    (`models.deepseek_v3.next_token_loss`). Returns (tokens, loss, routed):
-    `routed` holds, per expert layer, the `(top_idx, expert_load)`
-    variables a caller may fetch beside the loss."""
-    with RecordEvent("program.build", args={"model": "nemotron_h"}):
-        tokens, x = embed_tokens(cfg)
-        ckpts, routed = [], []
-        for n in range(cfg.num_hidden_layers):
-            x, r = decoder_layer(x, cfg, n)
-            ckpts.append(x.name)
-            if r is not None:
-                routed.append(r)
-        loss = next_token_loss(x, tokens, cfg)
-        loss._layer_checkpoints = ckpts
-        return tokens, loss, routed
+    (`causal_lm.build_causal_lm_program`): (tokens, loss, routed), `routed`
+    the `(top_idx, expert_load)` variables of each expert layer."""
+    return causal_lm.build_causal_lm_program(
+        cfg, "nemotron_h", decoder_layer, range(cfg.num_hidden_layers))
 
 
 def sharding_rules() -> ShardingRules:
@@ -309,11 +262,8 @@ def sharding_rules() -> ShardingRules:
     have to say by column ranges. Where more chips share a layer's heads
     than it has KV heads, a KV head lives on several of them, each with
     some of its query heads (`heads_held` / `kv_heads_held`)."""
-    return moe_sharding_rules(extra=[
+    return causal_lm.sharding_rules([
         (r"_(q|k|v)_proj_w$", P(None, "tp")),
-        (r"_o_proj_w$", P("tp", None)),
         (r"_shared_up_w$", P(None, "tp")),
         (r"_shared_down_w$", P("tp", None)),
-        (r"^embed_tokens$", P("tp", None)),
-        (r"^lm_head_w$", P(None, "tp")),
     ])

@@ -23,25 +23,23 @@ def _bert_pretrain():
 
 # case: (build, the step's jaxpr, the main and startup Programs)
 _STEPS = {
-    "bert": (lambda: _bert_pretrain,
+    "bert": (_bert_pretrain,
         "680dd440f44ce047ab42aefcc7b90d4a5196cb72a073633a721ac25285f98ca7",
         "1a887741f2cb3cd947d89a5ee6d0d51a89fe4d1e09c813842220fe90a87bd216"),
-    "kanana": (lambda: causal_lm(
-        deepseek_v3, deepseek_v3.DeepseekV3Config.tiny()),
+    "kanana": (causal_lm(deepseek_v3, deepseek_v3.DeepseekV3Config.tiny()),
         "b55dd5734b173553d7c9752e5e345b292002dc0118da0dfaf078759bc831ca74",
         "68f919faeb3f8974451fd71b9189d92b3302fdcccafed71d375a1d9aeb7db095"),
-    "mellum": (lambda: causal_lm(mellum, mellum.MellumConfig.tiny()),
+    "mellum": (causal_lm(mellum, mellum.MellumConfig.tiny()),
         "ece5b162f3f2d703cacc41a43320bc2ae04f46f1dfcef901271578999e044e88",
         "b2ce9db1818f76e633011063241b2970f87d81d443fe71e2729a42e675b7a8f7"),
-    "hybrid": (lambda: causal_lm(
-        nemotron_h, nemotron_h.NemotronHConfig.tiny()),
+    "hybrid": (causal_lm(nemotron_h, nemotron_h.NemotronHConfig.tiny()),
         "dc9e4d6bbb4513b2741e514caf9d4b7195cf9afe151839d818cf5f3bd66c00c9",
         "9a5c537fed32891884608f331a31760f09e32bf3d3d3f94797553efb4695c1c8"),
-    "latent_hybrid": (lambda: causal_lm(
+    "latent_hybrid": (causal_lm(
         nemotron_h, nemotron_h.NemotronHConfig.tiny_latent_share()),
         "ce94d43b5b24963e07c270f8de8b711da0ef8d8ea3d4a1909f00d4de6d174779",
         "80252e65c23ab70ef70b9618c7efd16eba5c3dc969cfd02210b1232675e55d01"),
-    "ling": (lambda: causal_lm(ling, ling.LingConfig.tiny()),
+    "ling": (causal_lm(ling, ling.LingConfig.tiny()),
         "60c17202fc73f82ce61d96a22f7176830b0d9bb93b04242555d4f6854dc22072",
         "ac230eeb40b2e1abfbc7711572c415559b6cfc9c4cb4c278dbf823c2028a8cdf"),
 }
@@ -60,4 +58,4 @@ def test_the_cells_builders_trace_as_before(cell):
     before`, `test_nemotron3_super.py::test_without_a_latent_and_with_every_
     head_the_model_traces_as_before`)."""
     build, step, programs = _STEPS[cell]
-    assert tiny_step_digests(build()) == (step, programs)
+    assert tiny_step_digests(build) == (step, programs)

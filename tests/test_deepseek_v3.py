@@ -6,27 +6,22 @@ weights: loss, every gradient leaf, the state after two Adam steps, the
 shares adding up to the uncut layer, no drops under skew, and the new ops
 against `jax.numpy`.
 """
-import os
-import sys
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import causal_lm_harness as harness
+from causal_lm_harness import B, S, counter_rise, run_op as _run_op
 
-import paddle_tpu as paddle  # noqa: E402
-import paddle_tpu.fluid as fluid  # noqa: E402
-from paddle_tpu.distributed import fleet  # noqa: E402
-from paddle_tpu.fluid import layers  # noqa: E402
-from paddle_tpu.models import deepseek_v3 as ds  # noqa: E402
-from paddle_tpu.observability import metrics  # noqa: E402
-from paddle_tpu.testing import reset_programs  # noqa: E402
-from benchmark.reference import kanana2 as ref  # noqa: E402
+import paddle_tpu as paddle
+import paddle_tpu.fluid as fluid
+from paddle_tpu.models import deepseek_v3 as ds
+from paddle_tpu.observability import metrics
+from paddle_tpu.testing import reset_programs
+from benchmark.reference import kanana2 as ref
 
-S, B = 32, 4
 CFG = dict(hidden_size=64, num_attention_heads=4, qk_nope_head_dim=16,
            qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32,
            intermediate_size=128, moe_intermediate_size=32,
@@ -53,55 +48,13 @@ def model_config(cfg):
         **{k: cfg[k] for k in SHARED})
 
 
-def batches(k, seed=0):
-    rng = np.random.RandomState(seed)
-    ids = rng.randint(0, CFG["vocab"], (k, B, S)).astype(np.int64)
-    labels = np.concatenate([ids[:, :, 1:], np.full((k, B, 1), -100)], 2)
-    return ids, labels
+def seeded_params():
+    return ref.init_params(CFG, jax.random.key(3))
 
 
 def trained_program(amp, k, ids):
-    """The program's losses, first routed choice, expert loads and scope
-    after `k` steps of `run_steps` from the reference's seeded weights."""
-    reset_programs(0)
-    _, loss, routed = ds.build_causal_lm_program(model_config(CFG))
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = amp
-    fleet.distributed_optimizer(
-        paddle.optimizer.Adam(learning_rate=ref.ADAM["lr"]),
-        strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    for name, value in ref.init_params(CFG, jax.random.key(3)).items():
-        assert tuple(scope.find(name).shape) == tuple(value.shape), name
-        scope.set(name, value)
-    out = exe.run_steps(k, feed={"tokens": ids[:k]},
-                        fetch_list=[loss, routed[0][0]]
-                        + [r[1] for r in routed])
-    return np.asarray(out[0]).reshape(-1), np.asarray(out[1]), \
-        np.stack([np.asarray(v) for v in out[2:]]), scope
-
-
-def reference_states(k, ids, labels):
-    """[(loss, grads, params, m, v) after each of k reference steps]."""
-    params, buffers = ref.split_state(
-        CFG, ref.init_params(CFG, jax.random.key(3)))
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
-    key = ref._cfg_key(CFG)
-    states, first_idx = [], None
-    for t in range(k):
-        val, idx, grads = ref._block_grad(params, buffers, ids[t], labels[t],
-                                          key, None)
-        n = float((labels[t] != -100).sum())
-        grads = jax.tree.map(lambda g: g / n, grads)
-        first_idx = idx if first_idx is None else first_idx
-        copy = jax.tree.map(jnp.array, (params, m, v))
-        params, m, v = ref._adam(*copy, grads, float(t + 1))
-        states.append((float(val) / n, grads, params, m, v))
-    return states, np.asarray(first_idx)
+    return harness.trained_program(ds, model_config(CFG), ref,
+                                   seeded_params(), amp, k, ids)
 
 
 # Tolerances. float32: the program and the reference differ in the order of
@@ -123,72 +76,39 @@ def test_program_follows_the_reference(amp, grad_tol, loss_tol):
     # 20 % of a leaf's gradient at this size, and the comparison would be
     # of routings, not of arithmetic (on the chip `route_mismatch_share`
     # is that comparison)
-    ids, labels = batches(2, seed=1)
-    states, ref_idx = reference_states(2, ids, labels)
+    ids, labels = harness.batches(CFG["vocab"], 2, seed=1)
+    states, ref_idx = harness.reference_states(
+        ref, CFG, ref.split_state(CFG, seeded_params()), 2, ids, labels)
 
     # one step: Adam's first moment is (1 - beta1) x the gradient, leaf by
     # leaf
-    losses, idx, _, scope = trained_program(amp, 1, ids)
+    losses, idx, scope = trained_program(amp, 1, ids)
     loss1, grads1 = states[0][0], states[0][1]
     assert abs(losses[0] - loss1) / loss1 < loss_tol
-    for name, want in grads1.items():
-        got = np.asarray(scope.find(name + "_moment1_0"),
-                         np.float32) / (1 - ref.ADAM["beta1"])
-        err = np.linalg.norm(got - np.asarray(want)) / max(
-            np.linalg.norm(np.asarray(want)), 1e-12)
+    for name, err in harness.first_step_gaps(scope, grads1, ref).items():
         assert err < grad_tol, (name, err)
-    mismatch = (np.sort(idx[0].reshape(ref_idx.shape), 1)
-                != np.sort(ref_idx, 1)).mean()
-    assert mismatch == 0
+    assert harness.route_mismatch(idx[0], ref_idx) == 0
     # two steps: losses, parameters and both moments
-    losses, _, _, scope = trained_program(amp, 2, ids)
+    losses, _, scope = trained_program(amp, 2, ids)
     for t in range(2):
         assert abs(losses[t] - states[t][0]) / states[t][0] < loss_tol
-    _, _, params, m, v = states[1]
     lr = ref.ADAM["lr"]
-    p0 = ref.init_params(CFG, jax.random.key(3))
-    for name in params:
-        got = np.asarray(scope.find(name), np.float32)
-        want = np.asarray(params[name])
-        assert np.abs(got - want).max() <= (4.1 * lr if amp
-                                            else 1e-2 * lr), name
-        moved = np.linalg.norm(want - np.asarray(p0[name]))
-        assert np.linalg.norm(got - want) <= (0.3 if amp
-                                              else 1e-3) * moved, name
-        for acc, want in (("_moment1_0", m), ("_moment2_0", v)):
-            got = np.asarray(scope.find(name + acc), np.float32)
-            err = np.linalg.norm(got - np.asarray(want[name])) / max(
-                np.linalg.norm(np.asarray(want[name])), 1e-20)
+    for name, worst, gap, moved, moments in harness.second_step_gaps(
+            scope, states, seeded_params()):
+        assert worst <= (4.1 * lr if amp else 1e-2 * lr), name
+        assert gap <= (0.3 if amp else 1e-3) * moved, name
+        for acc, err in moments.items():
             assert err < 2 * grad_tol, (name, acc, err)
 
 
 def _layer_share(x, params, offset, held, total, bias=True, norm=True,
-                 scaling=2.448, top_k=3):
+                 scaling=2.448, top_k=3, **grad):
     """One share's `routed_moe` output, TopIdx and ExpertLoad through a
-    Program, experts `offset` .. `offset + held` of `total`."""
-    reset_programs(0)
-    n, d = x.shape
-    xv = layers.data(name="x", shape=[d], dtype="float32")
-    mk = lambda name, arr: layers.create_parameter(  # noqa: E731
-        list(arr.shape), "float32", name=name)
-    sl = slice(offset, offset + held)
-    arrays = {"gate_w": params["router_w"],
-              "eg": params["experts_gate_w"][sl],
-              "eu": params["experts_up_w"][sl],
-              "ed": params["experts_down_w"][sl]}
-    if bias:
-        arrays["bias"] = params["router_bias"]
-    var = {k: mk(k, v) for k, v in arrays.items()}
-    out, idx, load = layers.routed_moe(
-        xv, var["gate_w"], var["eg"], var["eu"], var["ed"], top_k=top_k,
-        select_bias=var.get("bias"), routed_scaling=scaling, norm_topk=norm,
-        experts_total=total, expert_offset=offset)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    for k, v in arrays.items():
-        fluid.global_scope().set(k, jnp.asarray(v))
-    got = exe.run(feed={"x": x}, fetch_list=[out, idx, load])
-    return [np.asarray(g) for g in got]
+    Program, experts `offset` .. `offset + held` of `total` (with `cot` in
+    `grad`: its gradients, `harness.routed_share`)."""
+    return harness.routed_share(
+        x, harness.held_arrays(params, offset, held, bias=bias), top_k,
+        total, offset, routed_scaling=scaling, norm_topk=norm, **grad)
 
 
 def _uncut_layer(seed=0, skew=None, n=96, d=32, f=16, total=16, top_k=3):
@@ -281,14 +201,6 @@ def test_record_expert_load_sets_the_gauges():
     assert metrics.get("moe.tokens_dropped") == 0
 
 
-def _run_op(op_type, inputs, outputs, attrs):
-    from paddle_tpu.ops import registry
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    got = registry.get(op_type).lower(
-        ctx, {k: [jnp.asarray(v)] for k, v in inputs.items()}, attrs)
-    return [np.asarray(got[o][0]) for o in outputs]
-
-
 def test_rms_norm_op():
     rng = np.random.RandomState(0)
     x = rng.randn(3, 5, 16).astype(np.float32)
@@ -377,7 +289,6 @@ def test_builder_names_scopes_and_checkpoints_and_verifies():
 # ---------------------------------------------------------------------------
 
 _MOE_COUNTERS = ("moe.bwd_residual", "moe.bwd_recomputed")
-_RULE_ONLY_OUTPUTS = ("H", "U", "SortedW", "Order", "Inv")
 
 
 # once per grouped matmul lowered, by the way its shapes sent it: the
@@ -385,54 +296,13 @@ _RULE_ONLY_OUTPUTS = ("H", "U", "SortedW", "Order", "Inv")
 _GROUPED_COUNTERS = ("moe.grouped_pallas", "moe.grouped_xla")
 
 
-def _counter_rise(fn, names=_MOE_COUNTERS):
-    before = [metrics.get(n) for n in names]
-    out = fn()
-    return out, tuple(int(metrics.get(n) - b)
-                      for n, b in zip(names, before))
-
-
-def _withhold_residuals(program):
-    """Take the outputs only the grad rule reads off every `routed_moe`: a
-    program built before they existed."""
-    for op in program.global_block().ops:
-        if op.type == "routed_moe":
-            for slot in _RULE_ONLY_OUTPUTS:
-                op.outputs.pop(slot)
-
-
-def _share_gradients(x, params, cot, offset, held, total, top_k=3,
-                     withhold=False):
+def _share_gradients(x, params, cot, offset, held, total, withhold=False):
     """d sum(Out * cot) / d (x, GateW, ExpertGate, ExpertUp, ExpertDown) of
     one share's `routed_moe` through a Program, and the counters' rise
     while its step was traced."""
-    reset_programs(0)
-    n, d = x.shape
-    xv = layers.data(name="x", shape=[d], dtype="float32")
-    cv = layers.data(name="cot", shape=[d], dtype="float32")
-    sl = slice(offset, offset + held)
-    arrays = {"gate_w": params["router_w"], "bias": params["router_bias"],
-              "eg": params["experts_gate_w"][sl],
-              "eu": params["experts_up_w"][sl],
-              "ed": params["experts_down_w"][sl]}
-    var = {k: layers.create_parameter(list(v.shape), "float32", name=k)
-           for k, v in arrays.items()}
-    out, _, _ = layers.routed_moe(
-        xv, var["gate_w"], var["eg"], var["eu"], var["ed"], top_k=top_k,
-        select_bias=var["bias"], routed_scaling=2.448, experts_total=total,
-        expert_offset=offset)
-    loss = layers.reduce_sum(layers.elementwise_mul(out, cv))
-    if withhold:
-        _withhold_residuals(fluid.default_main_program())
-    wrt = [xv] + [var[k] for k in ("gate_w", "eg", "eu", "ed")]
-    grads = fluid.gradients(loss, wrt)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    for k, v in arrays.items():
-        fluid.global_scope().set(k, jnp.asarray(v))
-    got, rise = _counter_rise(lambda: exe.run(
-        feed={"x": x, "cot": cot}, fetch_list=grads))
-    return [np.asarray(g) for g in got], rise
+    return counter_rise(lambda: _layer_share(
+        x, params, offset, held, total, cot=cot, withhold=withhold),
+        _MOE_COUNTERS)
 
 
 def _reference_share_gradients(x, params, cot, offset, held, total, top_k=3):
@@ -496,17 +366,9 @@ def _census_trainer(withhold=False):
     """A tiny AMP train step whose expert buffers' shapes are no other
     value's: k*N = 256 rows of d = 64 (the vocabulary is not 256)."""
     cfg = dict(CFG, vocab=320)
-    reset_programs(0)
-    _, loss, _ = ds.build_causal_lm_program(model_config(cfg))
-    if withhold:
-        _withhold_residuals(fluid.default_main_program())
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = True
-    fleet.distributed_optimizer(
-        paddle.optimizer.Adam(learning_rate=1e-3), strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
+    exe, loss, _ = harness.train_step(
+        ds, model_config(cfg), True,
+        built=harness.withhold_residuals if withhold else None)
     ids = np.random.RandomState(0).randint(0, 320, (2, B, S)).astype(np.int64)
     return exe, loss, {"tokens": ids}
 
@@ -523,7 +385,7 @@ def test_train_step_census_nine_grouped_matmuls_a_layer():
     k, n, d = CFG["num_experts_per_tok"], B * S, CFG["hidden_size"]
     exe, loss, feed = _census_trainer()
     census = _MOE_COUNTERS + _GROUPED_COUNTERS
-    jaxpr, rise = _counter_rise(
+    jaxpr, rise = counter_rise(
         lambda: str(exe.step_jaxpr(feed, [loss], k=2)), census)
     assert rise == (expert_layers, 0, 0, 9 * expert_layers)
     assert jaxpr.count("ragged_dot_general") == 9 * expert_layers
@@ -533,7 +395,7 @@ def test_train_step_census_nine_grouped_matmuls_a_layer():
         assert wide not in jaxpr, wide
     # the generic route on the same model: the forward's three again
     exe, loss, feed = _census_trainer(withhold=True)
-    jaxpr, rise = _counter_rise(
+    jaxpr, rise = counter_rise(
         lambda: str(exe.step_jaxpr(feed, [loss], k=2)), census)
     assert rise == (0, expert_layers, 0, 12 * expert_layers)
     assert jaxpr.count("ragged_dot_general") == 12 * expert_layers

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from paddle_tpu.ops import moe
 from paddle_tpu.ops.pallas import grouped_matmul as gm
 
-from test_deepseek_v3 import (_GRAD_NAMES, _GROUPED_COUNTERS, _counter_rise,
+from causal_lm_harness import counter_rise as _counter_rise
+from test_deepseek_v3 import (_GRAD_NAMES, _GROUPED_COUNTERS,
                               _reference_share_gradients, _share_gradients,
                               _uncut_layer)
 

@@ -3,15 +3,12 @@ attention with latent attention in the last layer of every group, head-wise
 output gates, sigmoid-routed experts picked inside the best groups, a
 chip's share of the heads and of the experts) against its plain float32
 reference (`benchmark/reference/ling3.py`), on the CPU at tiny widths with
-seeded weights; and what the model forced on the ops: the chunked gated
-delta rule (`ops/kda.py`) against the token-by-token recurrence, its grad
-rule on the chunk states, the bounded decay gate, the L2 norm and the
-head-wise gate, and `routed_moe` with group-limited selection.
+seeded weights; and what the model forced on `routed_moe`: group-limited
+selection. The chunked gated delta rule (`ops/kda.py`), the bounded decay
+gate, the L2 norm and the head-wise gate have their own file,
+`tests/test_kda_scan.py`.
 """
-import hashlib
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -19,19 +16,17 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import causal_lm_harness as harness
+from causal_lm_harness import S, counter_rise, run_op as _run_op
 
-import paddle_tpu as paddle  # noqa: E402
-import paddle_tpu.fluid as fluid  # noqa: E402
-from paddle_tpu.distributed import fleet  # noqa: E402
-from paddle_tpu.fluid import layers  # noqa: E402
-from paddle_tpu.models import deepseek_v3, ling  # noqa: E402
-from paddle_tpu.observability import metrics  # noqa: E402
-from paddle_tpu.ops import registry  # noqa: E402
-from paddle_tpu.testing import reset_programs  # noqa: E402
-from benchmark.reference import ling3 as ref  # noqa: E402
+import paddle_tpu as paddle
+import paddle_tpu.fluid as fluid
+from paddle_tpu.models import deepseek_v3, ling
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops import registry
+from paddle_tpu.testing import reset_programs
+from benchmark.reference import ling3 as ref
 
-S, B = 32, 4
 # published layers 1..4 of a model whose groups are 3 layers: layer 1 KDA
 # with the dense part, layer 2 latent with experts, layers 3 and 4 KDA with
 # experts; heads 2..3 of 4, experts 4..7 of 16 (group 1 of 4)
@@ -68,53 +63,13 @@ def model_config(cfg, seq=S):
         **{k: cfg[k] for k in SHARED})
 
 
-def batches(k, seed=0):
-    rng = np.random.RandomState(seed)
-    ids = rng.randint(0, CFG["vocab"], (k, B, S)).astype(np.int64)
-    labels = np.concatenate([ids[:, :, 1:], np.full((k, B, 1), -100)], 2)
-    return ids, labels
+def seeded_params():
+    return ref.init_params(CFG, jax.random.key(3))
 
 
 def trained_program(amp, k, ids):
-    """The program's losses, first routed choice and scope after `k` steps
-    of `run_steps` from the reference's seeded weights."""
-    reset_programs(0)
-    _, loss, routed = ling.build_causal_lm_program(model_config(CFG))
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = amp
-    fleet.distributed_optimizer(
-        paddle.optimizer.Adam(learning_rate=ref.ADAM["lr"]),
-        strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    for name, value in ref.init_params(CFG, jax.random.key(3)).items():
-        assert tuple(scope.find(name).shape) == tuple(value.shape), name
-        scope.set(name, value)
-    out = exe.run_steps(k, feed={"tokens": ids[:k]},
-                        fetch_list=[loss, routed[0][0]])
-    return np.asarray(out[0]).reshape(-1), np.asarray(out[1]), scope
-
-
-def reference_states(k, ids, labels):
-    """[(loss, grads, params, m, v) after each of k reference steps]."""
-    params, buffers = ref.split_state(
-        CFG, ref.init_params(CFG, jax.random.key(3)))
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
-    key = ref._cfg_key(CFG)
-    states, first_idx = [], None
-    for t in range(k):
-        val, idx, grads = ref._block_grad(params, buffers, ids[t], labels[t],
-                                          key, None)
-        n = float((labels[t] != -100).sum())
-        grads = jax.tree.map(lambda g: g / n, grads)
-        first_idx = idx if first_idx is None else first_idx
-        copy = jax.tree.map(jnp.array, (params, m, v))
-        params, m, v = ref._adam(*copy, grads, float(t + 1))
-        states.append((float(val) / n, grads, params, m, v))
-    return states, np.asarray(first_idx)
+    return harness.trained_program(ling, model_config(CFG), ref,
+                                   seeded_params(), amp, k, ids)
 
 
 DATA_SEED = 1
@@ -144,52 +99,38 @@ def test_program_follows_the_reference(amp, grad_tol, loss_tol):
     def tol(name):
         return grad_tol * (10 if amp and name.endswith(_ROUTED) else 1)
 
-    ids, labels = batches(2, seed=DATA_SEED)
-    states, ref_idx = reference_states(2, ids, labels)
-    counters = ("kda.bwd_residual", "kda.bwd_recomputed",
-                "moe.group_limited_layers")
-    before = [metrics.get(c) for c in counters]
-    losses, idx, scope = trained_program(amp, 1, ids)
+    ids, labels = harness.batches(CFG["vocab"], 2, seed=DATA_SEED)
+    states, ref_idx = harness.reference_states(
+        ref, CFG, ref.split_state(CFG, seeded_params()), 2, ids, labels)
+    (losses, idx, scope), rise = counter_rise(
+        lambda: trained_program(amp, 1, ids),
+        ("kda.bwd_residual", "kda.bwd_recomputed",
+         "moe.group_limited_layers"))
     # the three delta-rule layers' backward took the rule, on the forward's
     # residuals; the three expert layers selected inside groups
-    assert [metrics.get(c) - b for c, b in zip(counters, before)] == [3, 0, 3]
+    assert rise == (3, 0, 3)
     loss1, grads1 = states[0][0], states[0][1]
     assert abs(losses[0] - loss1) / loss1 < loss_tol
-    for name, want in grads1.items():
-        got = np.asarray(scope.find(name + "_moment1_0"),
-                         np.float32) / (1 - ref.ADAM["beta1"])
-        err = np.linalg.norm(got - np.asarray(want)) / max(
-            np.linalg.norm(np.asarray(want)), 1e-12)
+    for name, err in harness.first_step_gaps(scope, grads1, ref).items():
         assert err < tol(name), (name, err)
-    mismatch = (np.sort(idx[0].reshape(ref_idx.shape), 1)
-                != np.sort(ref_idx, 1)).mean()
-    assert mismatch <= (0.02 if amp else 0)
+    assert harness.route_mismatch(idx[0], ref_idx) <= (0.02 if amp else 0)
     losses, _, scope = trained_program(amp, 2, ids)
     for t in range(2):
         assert abs(losses[t] - states[t][0]) / states[t][0] < loss_tol
-    _, _, params, m, v = states[1]
     lr = ref.ADAM["lr"]
-    p0 = ref.init_params(CFG, jax.random.key(3))
-    for name in params:
-        got = np.asarray(scope.find(name), np.float32)
-        want = np.asarray(params[name])
-        assert np.abs(got - want).max() <= (4.1 if amp else 0.5) * lr, name
-        moved = np.linalg.norm(want - np.asarray(p0[name]))
+    # the accumulators against their size after either step: where the two
+    # steps' gradients cancel (a decay's A_log, two numbers a layer) the
+    # sum is a tenth of its terms and carries their rounding
+    for name, worst, gap, moved, moments in harness.second_step_gaps(
+            scope, states, seeded_params(), floor_by_first_step=True):
+        assert worst <= (4.1 if amp else 0.5) * lr, name
         # Adam's first steps move an element by lr times its gradient's
         # sign: one element of a norm weight of 16 whose tiny gradient
         # turned is 0.35 of the leaf's move, and a token routed elsewhere
         # turns signs all over the routed leaves
         share = (0.6 if name.endswith(_ROUTED) else 0.45) if amp else 2e-3
-        assert np.linalg.norm(got - want) <= share * moved, name
-        # against the accumulator's size after either step: where the two
-        # steps' gradients cancel (a decay's A_log, two numbers a layer) the
-        # sum is a tenth of its terms and carries their rounding
-        for acc, want, first in (("_moment1_0", m, states[0][3]),
-                                 ("_moment2_0", v, states[0][4])):
-            got = np.asarray(scope.find(name + acc), np.float32)
-            err = np.linalg.norm(got - np.asarray(want[name])) / max(
-                np.linalg.norm(np.asarray(want[name])),
-                np.linalg.norm(np.asarray(first[name])), 1e-20)
+        assert gap <= share * moved, name
+        for acc, err in moments.items():
             assert err < 2 * tol(name), (name, acc, err)
 
 
@@ -204,389 +145,12 @@ def test_the_reference_tells_each_fault_apart(fault, moved, least):
     """What the new mechanisms admit going wrong each moves the reference's
     own gradients by far more than the float32 tolerance above (32 tokens
     here; the chip's `calibrate` has the readings at 8,192)."""
-    ids, labels = batches(1, seed=DATA_SEED)
-    params, buffers = ref.split_state(
-        CFG, ref.init_params(CFG, jax.random.key(3)))
-    _, _, want = ref._block_grad(params, buffers, ids[0], labels[0],
-                                 ref._cfg_key(CFG), None)
+    ids, labels = harness.batches(CFG["vocab"], 1, seed=DATA_SEED)
     bad_cfg = dict(CFG, assumed=dict(CFG["assumed"], **fault))
-    _, _, got = ref._block_grad(params, buffers, ids[0], labels[0],
-                                ref._cfg_key(bad_cfg), None)
-    worst = max(float(jnp.linalg.norm(got[n] - want[n])
-                      / jnp.linalg.norm(want[n])) for n in want)
+    worst = harness.worst_leaf_gap(
+        ref, CFG, bad_cfg, ref.split_state(CFG, seeded_params()), ids[0],
+        labels[0])
     assert worst > least, (moved, worst)
-
-
-# ---------------------------------------------------------------------------
-# the gated delta rule: chunks against the recurrence
-# ---------------------------------------------------------------------------
-
-def _delta_operands(seed, b=2, s=128, h=3, dk=16, dv=16, power=0.3):
-    """q, k L2-normed as the builder norms them; g in (-5, 0), most of it
-    near the bound (`power` < 1 pushes the uniform draw towards 1)."""
-    rng = np.random.RandomState(seed)
-    unit = lambda t: t / np.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
-    return {"Q": unit(rng.randn(b, s, h, dk)) * dk ** -0.5,
-            "K": unit(rng.randn(b, s, h, dk)), "V": rng.randn(b, s, h, dv),
-            "G": -5.0 * rng.uniform(0, 1, (b, s, h, dk)) ** power,
-            "Beta": rng.randn(b, s, h)}
-
-
-def _recurrence(ins):
-    """The reference's token-by-token delta rule on the op's operands."""
-    q, k, v, g, raw = (jnp.asarray(ins[n], jnp.float32)
-                       for n in ("Q", "K", "V", "G", "Beta"))
-    return ref.delta_rule(q, k, v, g, jax.nn.sigmoid(raw),
-                          dict(CFG, reference_scan_tokens_per_block=8))
-
-
-# widths the Pallas kernels' shape rule takes (`ops/pallas/kda_chunk.py`
-# `plan`: heads of 128 x 128, chunks of 32, 64 or 128), three chunks, so
-# that the carry and the reverse chain run. Here under the Pallas interpreter
-_KERNEL_SHAPE = dict(b=1, s=192, h=2, dk=128, dv=128)
-_ROUTES = ("kda.scan_pallas", "kda.scan_xla")
-
-
-@pytest.mark.parametrize("chunk, shape", [
-    (8, {}), (16, {}), (64, {}), (128, {}), (64, _KERNEL_SHAPE)],
-    ids=["chunk8", "chunk16", "chunk64", "chunk128", "kernel"])
-def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(chunk,
-                                                                   shape):
-    """`kda_scan` in chunks of 8 (one block), 16, 64 (four blocks of 16, the
-    cell's) and the whole row (the `jax.numpy` form) and at widths the
-    Pallas kernels take, against the plain recurrence, the decays drawn down
-    to the bound of -5 (the running sum reaches -300 inside a chunk of 64: a
-    form that takes exp(-G) over a whole chunk reads inf): the output, and
-    the gradient of every operand by the op's grad rule on the forward's
-    residual (float32: the order of the sums). Each lowering counts its
-    route, forward and backward."""
-    ins = {k: jnp.asarray(v, jnp.float32)
-           for k, v in _delta_operands(chunk, **shape).items()}
-    assert float(ins["G"].min()) < -4.99
-    opdef = registry.get("kda_scan")
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    attrs = {"chunk_size": chunk}
-    routes = [metrics.get(c) for c in _ROUTES]
-    with jax.default_matmul_precision("highest"):
-        outs = opdef.lower(ctx, {k: [v] for k, v in ins.items()}, attrs)
-        want, vjp = jax.vjp(lambda t: _recurrence(t), ins)
-        cot = jnp.asarray(np.random.RandomState(9).randn(*want.shape),
-                          jnp.float32)
-        before = metrics.get("kda.bwd_residual")
-        grads = opdef.grad(ctx, {k: [v] for k, v in ins.items()}, attrs,
-                           {s: outs[s] for s in opdef.residual_slots},
-                           {"Y": [cot]})
-        assert metrics.get("kda.bwd_residual") == before + 1
-        # and differentiated by JAX (a segment under recompute): the same
-        by_jax = jax.grad(lambda k: jnp.sum(opdef.lower(
-            ctx, {**{n: [v] for n, v in ins.items()}, "K": [k]},
-            attrs)["Y"][0] * cot))(ins["K"])
-    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] \
-        == ([4, 0] if shape else [0, 4])
-    y = outs["Y"][0]
-    b, s, h, dk = ins["Q"].shape
-    assert outs["States"][0].shape == (b, s // chunk, h, dk, dk)
-    assert bool(jnp.isfinite(y).all())
-    assert float(jnp.abs(y - want).max() / jnp.abs(want).max()) < 5e-6
-    for name, ref_grad in vjp(cot)[0].items():
-        err = float(jnp.linalg.norm(grads[name][0] - ref_grad)
-                    / jnp.linalg.norm(ref_grad))
-        # the decay's gradient sums differences of running sums as long as
-        # the chunk: float32 noise of 2e-5 at a chunk of 128
-        assert err < 1e-4, (name, err)
-    np.testing.assert_allclose(by_jax, grads["K"][0], rtol=1e-5, atol=1e-6)
-
-
-def test_a_row_or_a_chunk_of_the_wrong_length_is_refused():
-    ins = {k: [jnp.asarray(v, jnp.float32)]
-           for k, v in _delta_operands(0, s=48).items()}
-    opdef = registry.get("kda_scan")
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    with pytest.raises(ValueError, match="whole number of chunks"):
-        opdef.lower(ctx, ins, {"chunk_size": 32})
-    with pytest.raises(ValueError, match="blocks of 16"):
-        opdef.lower(ctx, ins, {"chunk_size": 24})
-    with pytest.raises(ValueError, match="Beta"):
-        opdef.lower(ctx, dict(ins, Beta=[ins["Beta"][0][:, :, :2]]),
-                    {"chunk_size": 16})
-
-
-@pytest.mark.parametrize("shape", [{}, _KERNEL_SHAPE], ids=["form", "kernel"])
-def test_delta_rule_in_bf16_keeps_decay_and_states_float32(shape):
-    """Under AMP q, k, v arrive in bf16: the output is bf16 and within
-    bf16's rounding of the float32 result; the chunk states stay float32.
-    By the `jax.numpy` form and by the Pallas kernels."""
-    ins = _delta_operands(3, power=2.0, **shape)
-    low = {k: jnp.asarray(v, jnp.bfloat16 if k in "QKV" else jnp.float32)
-           for k, v in ins.items()}
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    routes = [metrics.get(c) for c in _ROUTES]
-    outs = registry.get("kda_scan").lower(
-        ctx, {k: [v] for k, v in low.items()}, {"chunk_size": 64})
-    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] \
-        == ([1, 0] if shape else [0, 1])
-    want = _recurrence(ins)
-    assert outs["Y"][0].dtype == jnp.bfloat16
-    assert outs["States"][0].dtype == jnp.float32
-    err = float(jnp.abs(outs["Y"][0].astype(jnp.float32) - want).max()
-                / jnp.abs(want).max())
-    assert err < 3e-2, err
-
-
-def _kernel_operands(seed, chunks, h=4, dtype=jnp.float32, **changed):
-    """(q, k, v, g, beta) as the kernels take them: one row of `chunks`
-    chunks of 64, `h` heads of 128 x 128."""
-    ins = dict(_delta_operands(seed, b=1, s=64 * chunks, h=h, dk=128, dv=128),
-               **changed)
-    return tuple(jnp.asarray(ins[n], dtype if n in "QKV" else jnp.float32)
-                 for n in ("Q", "K", "V", "G")) \
-        + (jax.nn.sigmoid(jnp.asarray(ins["Beta"], jnp.float32)),)
-
-
-def _gaps(got, want):
-    return [float(jnp.linalg.norm((g - w).astype(jnp.float32))
-                  / jnp.linalg.norm(w.astype(jnp.float32)))
-            for g, w in zip(got, want)]
-
-
-_OUTPUTS = ("Y", "States", "dQ", "dK", "dV", "dG", "dBeta")
-
-
-@pytest.mark.parametrize("chunks", [2, 3], ids=lambda c: f"{c}chunks")
-@pytest.mark.parametrize("heads", [1, 2, 4], ids=lambda j: f"{j}heads")
-def test_the_kernels_follow_the_form_at_every_count_of_heads_a_step(heads,
-                                                                    chunks):
-    """`ops/pallas/kda_chunk.py` at 1, 2 and all 4 heads a grid step, over
-    2 and 3 chunks, beside the `jax.numpy` form on the same operands in
-    float32: `Y`, `States` and the five gradients to float32's last digits
-    (the order of a sum; the decay's gradient sums differences of running
-    sums as long as the chunk, 2e-5 in either lowering)."""
-    from paddle_tpu.ops import kda
-    from paddle_tpu.ops.pallas import kda_chunk
-    ops = _kernel_operands(10 * heads + chunks, chunks)
-    plan = kda_chunk.plan(ops[0].shape, ops[2].shape, 64, jnp.float32,
-                          heads=heads)
-    assert plan[:5] == (heads, 128, 64, 4 // heads, chunks)
-    cot = jnp.asarray(np.random.RandomState(9).randn(*ops[2].shape),
-                      jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        y, states = kda._kda_fwd(64, *ops)
-        want = (y, states) + kda._kda_bwd(64, *ops, states, cot)
-        y, states = kda_chunk.kda_fwd(plan, *ops)
-        got = (y, states) + kda_chunk.kda_bwd(plan, *ops, states, cot)
-    for name, gap in zip(_OUTPUTS, _gaps(got, want)):
-        assert gap < (1e-4 if name == "dG" else 5e-6), (name, gap)
-
-
-@pytest.mark.parametrize("heads", [1, 4], ids=lambda j: f"{j}heads")
-def test_the_kernels_in_bf16_stay_inside_the_forms_own_gap(heads):
-    """bf16 rows: both lowerings round the same values to bf16 (the matmul
-    operands) and keep the rest float32, so beside the float32 result on
-    the same rounded rows the kernels' gap is the form's own, output by
-    output (the decay's gradient, a difference of long sums, reads a tenth
-    in either)."""
-    from paddle_tpu.ops import kda
-    from paddle_tpu.ops.pallas import kda_chunk
-    low = _kernel_operands(5, 2, dtype=jnp.bfloat16)
-    exact = tuple(t.astype(jnp.float32) for t in low)
-    plan = kda_chunk.plan(low[0].shape, low[2].shape, 64, jnp.bfloat16,
-                          heads=heads)
-    cot = jnp.asarray(np.random.RandomState(9).randn(*low[2].shape),
-                      jnp.bfloat16)
-    with jax.default_matmul_precision("highest"):
-        y, states = kda._kda_fwd(64, *exact)
-        want = (y, states) + kda._kda_bwd(64, *exact, states,
-                                          cot.astype(jnp.float32))
-        y, states = kda._kda_fwd(64, *low)
-        form = (y, states) + kda._kda_bwd(64, *low, states, cot)
-        y, states = kda_chunk.kda_fwd(plan, *low)
-        got = (y, states) + kda_chunk.kda_bwd(plan, *low, states, cot)
-    assert got[0].dtype == jnp.bfloat16 and got[1].dtype == jnp.float32
-    assert got[5].dtype == jnp.float32 and got[2].dtype == jnp.bfloat16
-    for name, mine, its in zip(_OUTPUTS, _gaps(got, want), _gaps(form, want)):
-        assert mine < 1.25 * its + 1e-4, (name, mine, its)
-        assert mine < (0.3 if name == "dG" else 1e-2), (name, mine)
-
-
-def _stress(case):
-    """Operands made to stress the solve and the decay bound."""
-    ins = _delta_operands(21, b=1, s=128, h=2, dk=128, dv=128)
-    if case in ("equal_k", "beta_0.999"):
-        # every k of a chunk equal and no decay: A is `beta` in every
-        # entry under the diagonal, and (I + A)^-1 cancels powers of it
-        ins["K"] = np.repeat(ins["K"][:, ::64], 64, axis=1)
-        ins["G"] = np.zeros_like(ins["G"])
-    if case == "beta_0.999":
-        ins["Beta"] = np.full_like(ins["Beta"], np.log(0.999 / 0.001))
-    if case == "g_floor":
-        ins["G"] = np.full_like(ins["G"], -5.0)
-    if case == "g_-4.5":
-        ins["G"] = np.full_like(ins["G"], -4.5)
-    if case == "g_zero":
-        ins["G"] = np.zeros_like(ins["G"])
-    return {k: jnp.asarray(v, jnp.float32) for k, v in ins.items()}
-
-
-@pytest.mark.parametrize("case", ["equal_k", "beta_0.999", "g_floor",
-                                  "g_-4.5", "g_zero"])
-def test_the_kernels_solve_and_decay_bound_under_stress(case):
-    """The in-kernel solve (substitution over the 16 x 16 diagonal blocks,
-    the rest by products) where `A` is as far from small as it gets (every
-    k of a chunk equal, beta 0.999: a plain Neumann doubling reads 1e10
-    there), and the block-wise decayed products at the lower bound of g on
-    every channel (`exp(80)` inside a block) and at no decay: the op at the
-    kernels' widths against the token-by-token recurrence at the form's
-    tolerance, output and gradients. At -5 on EVERY channel the last rows
-    of a block are `x exp(-80)`, 1e-37 and under, where float32 runs out of
-    exponent: the form itself reads 5.3e-3 against the recurrence there
-    (4e-7 at -4.5), so that case holds the kernels to the form's digits
-    (output, dQ, dK, dV) and the recurrence to its percent."""
-    from paddle_tpu.ops import kda
-    ins = _stress(case)
-    opdef = registry.get("kda_scan")
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    attrs = {"chunk_size": 64}
-    routes = [metrics.get(c) for c in _ROUTES]
-    with jax.default_matmul_precision("highest"):
-        outs = opdef.lower(ctx, {k: [v] for k, v in ins.items()}, attrs)
-        want, vjp = jax.vjp(lambda t: _recurrence(t), ins)
-        cot = jnp.asarray(np.random.RandomState(9).randn(*want.shape),
-                          jnp.float32)
-        grads = opdef.grad(ctx, {k: [v] for k, v in ins.items()}, attrs,
-                           {s: outs[s] for s in opdef.residual_slots},
-                           {"Y": [cot]})
-        want_grads = vjp(cot)[0]
-        if case == "g_floor":
-            y = outs["Y"][0]
-            assert float(jnp.abs(y - want).max() / jnp.abs(want).max()) < 1e-2
-            ops = tuple(ins[n] for n in ("Q", "K", "V", "G"))
-            beta = jax.nn.sigmoid(ins["Beta"])
-            want, states = kda._kda_fwd(64, *ops, beta)
-            # the decay's own gradient there is float32 noise in either
-            # lowering (the form's lies seven norms off the recurrence's)
-            assert bool(jnp.isfinite(grads["G"][0]).all())
-            want_grads = dict(zip(("Q", "K", "V"), kda._kda_bwd(
-                64, *ops, beta, states, cot)))
-    assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] == [2, 0]
-    y = outs["Y"][0]
-    assert bool(jnp.isfinite(y).all())
-    assert float(jnp.abs(y - want).max() / jnp.abs(want).max()) < 5e-6
-    for name, ref_grad in want_grads.items():
-        scale = float(jnp.linalg.norm(ref_grad))
-        if scale == 0:
-            assert float(jnp.abs(grads[name][0]).max()) == 0, name
-            continue
-        err = float(jnp.linalg.norm(grads[name][0] - ref_grad)) / scale
-        assert err < 1e-4, (name, err)
-
-
-def test_the_kernels_shape_rule_and_the_form_it_leaves(monkeypatch):
-    """`ops/pallas/kda_chunk.py` `plan` reads the route from the operands'
-    shapes and dtype and nothing else: K and V one lane tile, chunks of 32,
-    64 or 128, bf16 or float32, the blocks inside the VMEM budget. What it
-    leaves counts `kda.scan_xla` and lowers to the `jax.numpy` form as the
-    tree before the kernels traced it (commit fa2014b, jax 0.9.0: the
-    digest was made there, source lines cut)."""
-    from paddle_tpu.ops.pallas import kda_chunk
-    cell = kda_chunk.plan((1, 8192, 16, 128), (1, 8192, 16, 128), 64)
-    assert cell[:5] == (4, 128, 64, 4, 128)
-    assert cell.resident_bytes + (8 << 20) < 16 << 20
-    for shape, chunk, dtype in (((2, 128, 3, 128), 64, jnp.float32),
-                                ((1, 256, 2, 128), 128, jnp.bfloat16),
-                                ((1, 96, 5, 128), 32, jnp.bfloat16)):
-        assert kda_chunk.plan(shape, shape, chunk, dtype) is not None
-    for q, v, chunk, dtype, why in (
-            ((2, 32, 4, 16), (2, 32, 4, 16), 16, jnp.float32, "tiny preset"),
-            ((1, 128, 2, 64), (1, 128, 2, 64), 64, jnp.bfloat16, "half tile"),
-            ((1, 128, 2, 96), (1, 128, 2, 96), 64, jnp.bfloat16, "96 wide"),
-            ((1, 128, 2, 128), (1, 128, 2, 64), 64, jnp.bfloat16, "V 64"),
-            ((1, 128, 2, 128), (1, 128, 2, 128), 16, jnp.bfloat16,
-             "chunks of 16"),
-            ((1, 192, 2, 128), (1, 192, 2, 128), 48, jnp.bfloat16,
-             "chunks of 48"),
-            ((1, 128, 2, 128), (1, 128, 2, 128), 64, jnp.float16,
-             "float16")):
-        assert kda_chunk.plan(q, v, chunk, dtype) is None, why
-    monkeypatch.setattr(kda_chunk, "VMEM_BUDGET", 1 << 20)
-    assert kda_chunk.plan((1, 8192, 16, 128), (1, 8192, 16, 128), 64) is None
-
-    opdef = registry.get("kda_scan")
-
-    def step(q, k, v, g, beta, do):
-        ctx = registry.LowerCtx(rng_key=None)
-        ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
-        attrs = {"chunk_size": 64}
-        outs = opdef.lower(ctx, ins, attrs)
-        grads = opdef.grad(ctx, ins, attrs,
-                           {s: outs[s] for s in opdef.residual_slots},
-                           {"Y": [do]})
-        return outs["Y"][0], [grads[s][0] for s in ins]
-
-    def traced(width, dtype):
-        rows = jax.ShapeDtypeStruct((1, 256, 2, width), dtype)
-        routes = [metrics.get(c) for c in _ROUTES]
-        # a new function each time: JAX keeps a traced one by its avals
-        text = str(jax.make_jaxpr(lambda *a: step(*a))(
-            rows, rows, rows,
-            jax.ShapeDtypeStruct((1, 256, 2, width), jnp.float32),
-            jax.ShapeDtypeStruct((1, 256, 2), jnp.float32), rows))
-        return text, [metrics.get(c) - r for c, r in zip(_ROUTES, routes)]
-
-    # over the budget (still patched), float16, and a head of 64
-    for width, dtype in ((128, jnp.bfloat16), (128, jnp.float16),
-                         (64, jnp.bfloat16)):
-        text, rise = traced(width, dtype)
-        assert rise == [0, 2], (width, dtype)
-        assert "pallas_call" not in text and "triangular_solve" in text
-    text = re.sub(r"kda\.py:\d+", "kda.py:N", text)
-    assert hashlib.sha256(text.encode()).hexdigest() == _FORM_DIGEST
-    monkeypatch.undo()
-    text, rise = traced(128, jnp.bfloat16)
-    assert rise == [2, 0]
-    assert "triangular_solve" not in text
-    for name in ("kda-chunk-fwd", "kda-chunk-bwd"):
-        assert re.search(rf"name={name}\s", text), name
-
-
-_FORM_DIGEST = (
-    "96cc656f1231dad9df940ed0fdce2b88748c3f45290b6aee7f3436e89b4e56b5")
-
-
-def _run_op(op_type, inputs, outputs, attrs):
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    got = registry.get(op_type).lower(
-        ctx, {k: [jnp.asarray(v)] for k, v in inputs.items()}, attrs)
-    return [np.asarray(got[o][0]) for o in outputs]
-
-
-def test_decay_gate_l2_norm_and_head_gate_ops():
-    rng = np.random.RandomState(2)
-    x = rng.randn(2, 5, 3 * 4).astype(np.float32) * 3
-    a_log = np.log(rng.uniform(1, 16, 3)).astype(np.float32)
-    dt_bias = rng.randn(12).astype(np.float32)
-    g, = _run_op("kda_gate", {"X": x, "ALog": a_log, "DtBias": dt_bias},
-                 ["G"], {"lower_bound": -5.0})
-    pre = (x + dt_bias).reshape(2, 5, 3, 4) * np.exp(a_log)[:, None]
-    np.testing.assert_allclose(g, -2.5 * (1 + np.tanh(pre / 2)), rtol=1e-5,
-                               atol=1e-6)
-    assert g.shape == (2, 5, 3, 4) and g.min() >= -5 and g.max() <= 0
-    half, = _run_op("kda_gate", {"X": x.astype(jnp.bfloat16), "ALog": a_log,
-                                 "DtBias": dt_bias}, ["G"],
-                    {"lower_bound": -5.0})
-    assert half.dtype == np.float32
-    h = rng.randn(2, 5, 3, 4).astype(np.float32)
-    y, = _run_op("l2_norm", {"X": h}, ["Out"], {"scale": 0.5})
-    np.testing.assert_allclose(
-        y, 0.5 * h / np.sqrt((h ** 2).sum(-1, keepdims=True) + 1e-6),
-        rtol=1e-5)
-    np.testing.assert_allclose(y, 0.5 * np.asarray(ref.l2_norm(h)), rtol=1e-6)
-    gate = rng.randn(2, 5, 3).astype(np.float32)
-    z, = _run_op("head_gate", {"X": h, "Gate": gate}, ["Out"], {})
-    np.testing.assert_allclose(z, h / (1 + np.exp(-gate))[..., None],
-                               rtol=1e-5)
-    low, = _run_op("head_gate", {"X": h.astype(jnp.bfloat16), "Gate": gate},
-                   ["Out"], {})
-    assert low.dtype == jnp.bfloat16
 
 
 def test_new_ops_have_specs_and_amp_placement():
@@ -666,11 +230,6 @@ def test_group_limited_selection_against_a_plain_loop(n_group, topk_group,
                 dict(attrs, n_group=5))
 
 
-def _jaxpr_digest(fn, *structs, cut=r"(moe|grouped_matmul)\.py:\d+"):
-    text = str(jax.make_jaxpr(fn)(*structs))
-    return hashlib.sha256(re.sub(cut, r"\1.py:N", text).encode()).hexdigest()
-
-
 def test_without_groups_routed_moe_traces_as_before(monkeypatch):
     """`n_group` 1 (every cell the benchmark had): the op's forward and its
     grad rule trace to the jaxpr of the tree before group-limited selection
@@ -679,35 +238,19 @@ def test_without_groups_routed_moe_traces_as_before(monkeypatch):
     PR 41's route), whether the attr is left out or given as 1."""
     from paddle_tpu.ops.pallas import grouped_matmul
     monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
-    n, d, f, held, total = 512, 128, 256, 4, 16
-    opdef = registry.get("routed_moe")
+    total = 16
 
-    def step(attrs):
-        def fn(x, wg, sb, eg, eu, ed, g):
-            ctx = registry.LowerCtx(rng_key=None)
-            ins = {"X": [x], "GateW": [wg], "ExpertGate": [eg],
-                   "ExpertUp": [eu], "ExpertDown": [ed], "SelectBias": [sb]}
-            outs = opdef.lower(ctx, ins, attrs)
-            grads = opdef.grad(ctx, ins, attrs,
-                               {s: outs[s] for s in opdef.residual_slots},
-                               {"Out": [g]})
-            return outs["Out"][0], [grads[s][0] for s in (
-                "X", "GateW", "ExpertGate", "ExpertUp", "ExpertDown")]
-        return fn
+    def digest(attrs):
+        return harness.sha256(harness.routed_moe_jaxpr(
+            True, True, 4, total, attrs, n=512))
 
-    bf, sd = jnp.bfloat16, jax.ShapeDtypeStruct
-    structs = (sd((n, d), jnp.float32), sd((d, total), jnp.float32),
-               sd((total,), jnp.float32), sd((held, d, f), bf),
-               sd((held, d, f), bf), sd((held, f, d), bf), sd((n, d), bf))
     attrs = {"top_k": 2, "routed_scaling": 2.5, "norm_topk": True,
              "experts_total": total, "expert_offset": 4,
              "scoring": "sigmoid"}
     want = "756425e5b9df58c88211a24260d81274489de5600b84480885065b2d97d77f9f"
-    assert _jaxpr_digest(step(attrs), *structs) == want
-    assert _jaxpr_digest(step(dict(attrs, n_group=1, topk_group=1)),
-                         *structs) == want
-    assert _jaxpr_digest(step(dict(attrs, n_group=4, topk_group=2)),
-                         *structs) != want
+    assert digest(attrs) == want
+    assert digest(dict(attrs, n_group=1, topk_group=1)) == want
+    assert digest(dict(attrs, n_group=4, topk_group=2)) != want
 
 
 def test_latent_attention_as_kanana_calls_it_traces_as_before():
@@ -716,17 +259,9 @@ def test_latent_attention_as_kanana_calls_it_traces_as_before():
     float32 train step traces to the jaxpr of the tree before this model
     (commit 40a2d5b, jax 0.9.0; the digest was made there, source lines
     cut, and again with PR 41's route in `routed_moe`)."""
-    reset_programs(0)
-    cfg = deepseek_v3.DeepseekV3Config.tiny()
-    _, loss, _ = deepseek_v3.build_causal_lm_program(cfg)
-    paddle.optimizer.Adam(learning_rate=1e-3).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    ids = np.zeros((2, 2, cfg.seq_len), np.int64)
-    text = re.sub(r"[\w/.\-]+\.py:\d+", "F:N",
-                  str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)))
-    assert text.count("rsqrt") >= 3 * 3
-    assert hashlib.sha256(text.encode()).hexdigest() == KANANA_DIGEST
+    step, _ = harness.tiny_step_digests(harness.causal_lm(
+        deepseek_v3, deepseek_v3.DeepseekV3Config.tiny()))
+    assert step == KANANA_DIGEST
 
 
 KANANA_DIGEST = (
@@ -739,19 +274,10 @@ KANANA_DIGEST = (
 
 def _attention_program(kind, cfg, x, params, pre):
     """One share's attention layer of `kind` through a Program."""
-    reset_programs(0)
-    mcfg = model_config(cfg, seq=x.shape[1])
-    xv = layers.data(name="x", shape=list(x.shape[1:]), dtype="float32")
     build = (ling.gated_latent_attention if kind == ling.LATENT
              else ling.kda_attention)
-    out = build(xv, mcfg, pre)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    for name, value in params.items():
-        assert tuple(fluid.global_scope().find(name).shape) == tuple(
-            value.shape), name
-        fluid.global_scope().set(name, jnp.asarray(value))
-    return np.asarray(exe.run(feed={"x": x}, fetch_list=[out])[0])
+    return harness.mixer_program(build, model_config(cfg, seq=x.shape[1]),
+                                 x, params, pre)
 
 
 def _head_share(params, cfg, lo, hi):
@@ -840,26 +366,9 @@ def test_the_ranks_routed_parts_and_the_shared_expert_add_up():
     want_idx = np.asarray(want_idx)
     total, loads = 0.0, []
     for offset in range(0, 32, 2):
-        reset_programs(0)
-        sl = slice(offset, offset + 2)
-        arrays = {"gate_w": params["router_w"],
-                  "eg": params["experts_gate_w"][sl],
-                  "eu": params["experts_up_w"][sl],
-                  "ed": params["experts_down_w"][sl]}
-        xv = layers.data(name="x", shape=[x.shape[1]], dtype="float32")
-        var = {k: layers.create_parameter(list(v.shape), "float32", name=k)
-               for k, v in arrays.items()}
-        bias = layers.create_parameter([32], "float32", name="bias")
-        out, idx, load = layers.routed_moe(
-            xv, var["gate_w"], var["eg"], var["eu"], var["ed"], top_k=4,
-            select_bias=bias, routed_scaling=2.5, experts_total=32,
-            expert_offset=offset, n_group=8, topk_group=4)
-        exe = fluid.Executor()
-        exe.run(fluid.default_startup_program())
-        for k, v in dict(arrays, bias=params["router_bias"]).items():
-            fluid.global_scope().set(k, jnp.asarray(v))
-        out, idx, load = (np.asarray(t) for t in exe.run(
-            feed={"x": x}, fetch_list=[out, idx, load]))
+        out, idx, load = harness.routed_share(
+            x, harness.held_arrays(params, offset, 2), 4, 32, offset,
+            routed_scaling=2.5, n_group=8, topk_group=4)
         total = total + out
         loads.append(load)
         assert (np.sort(idx, 1) == np.sort(want_idx, 1)).all()
@@ -884,6 +393,7 @@ def test_the_ranks_routed_parts_and_the_shared_expert_add_up():
 # the builder
 # ---------------------------------------------------------------------------
 
+_ROUTES = ("kda.scan_pallas", "kda.scan_xla")
 _COUNTERS = ("kda.layers_lowered", "kda.bwd_residual", "kda.bwd_recomputed",
              "moe.layers_lowered", "moe.bwd_residual", "moe.bwd_recomputed",
              "moe.group_limited_layers", "attention.flash_full",
@@ -955,26 +465,11 @@ def _amp_step(recompute, **changed):
     """(executor, loss, ids [2, 1, 128]) of the tiny preset at 128 tokens
     in chunks of 64 with `changed` set, its AMP train step built through
     fleet, with a checkpoint at every layer boundary if `recompute`."""
-    reset_programs(0)
     cfg = ling.LingConfig.tiny()
     cfg.seq_len, cfg.kda_chunk_size = 128, 64
     for key, value in changed.items():
         setattr(cfg, key, value)
-    _, loss, _ = ling.build_causal_lm_program(cfg)
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = True
-    if recompute:
-        strategy.recompute = True
-        strategy.recompute_configs = {
-            "checkpoints": list(loss._layer_checkpoints)}
-    fleet.distributed_optimizer(paddle.optimizer.Adam(1e-3),
-                                strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    ids = np.random.RandomState(0).randint(0, 256, (2, 1, 128)).astype(
-        np.int64)
-    return exe, loss, ids
+    return harness.amp_step(ling, cfg, recompute)
 
 
 @pytest.mark.parametrize("recompute, rise", [
@@ -993,15 +488,13 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
                         lambda q: q.shape[2] % 128 == 0)
     exe, loss, ids = _amp_step(recompute, qk_nope_head_dim=56,
                                v_head_dim=64, head_dim=64)
-    before = [metrics.get(c) for c in _COUNTERS]
-    routes = [metrics.get(c) for c in _ROUTES]
-    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
-    assert tuple(int(metrics.get(c) - b)
-                 for c, b in zip(_COUNTERS, before)) == rise
+    jaxpr, got = counter_rise(
+        lambda: str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)),
+        _COUNTERS + _ROUTES)
+    assert got[:-2] == rise
     # heads of 64 are half a lane tile: every scan the step keeps, forward,
     # backward and the forward lowered once more, is the `jax.numpy` form
-    assert [int(metrics.get(c) - r) for c, r in zip(_ROUTES, routes)] \
-        == [0, 9 if recompute else 6]
+    assert got[-2:] == (0, 9 if recompute else 6)
     # 4 heads of 64 x 64 at 128 positions in 2 chunks
     assert "f32[1,2,4,64,64]" in jaxpr
     assert not re.search(r"\[1,128,4,64,64\]|\[1,4,128,64,64\]", jaxpr)
@@ -1028,13 +521,11 @@ def test_at_the_cells_scan_widths_every_scan_of_the_step_is_a_kernel(
         first_layer=7, num_layers_held=7)
     from paddle_tpu.ops.pallas import kda_chunk
     entries = (kda_chunk._kda_fwd, kda_chunk._kda_bwd)
-    before = [metrics.get(c) for c in _ROUTES]
-    lowered = metrics.get("kda.layers_lowered")
     traced = [f._cache_size() for f in entries]
-    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
-    assert int(metrics.get("kda.layers_lowered") - lowered) == 6
-    assert [int(metrics.get(c) - b) for c, b in zip(_ROUTES, before)] \
-        == [kernels, 0]
+    jaxpr, rise = counter_rise(
+        lambda: str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)),
+        ("kda.layers_lowered",) + _ROUTES)
+    assert rise == (6, kernels, 0)
     # the six layers enter each kernel through one jitted function: one
     # trace of it (none here if the other case of this test made it)
     assert all(f._cache_size() - t <= 1 for f, t in zip(entries, traced))
@@ -1043,57 +534,3 @@ def test_at_the_cells_scan_widths_every_scan_of_the_step_is_a_kernel(
     assert "f32[1,2,4,128,128]" in jaxpr
     assert "triangular_solve" not in jaxpr
     assert not re.search(r"f32\[1,2,4,64,64\]", jaxpr)
-
-
-def _tiny_step_digest(build):
-    """sha256 of a builder's tiny float32 train step of k = 2, source lines
-    cut."""
-    reset_programs(0)
-    loss, feed = build()
-    paddle.optimizer.Adam(learning_rate=1e-3).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    text = re.sub(r"[\w/.\-]+\.py:\d+", "F:N",
-                  str(exe.step_jaxpr(feed, [loss], k=2)))
-    return hashlib.sha256(text.encode()).hexdigest()
-
-
-def _causal_lm(module, cfg):
-    def build():
-        _, loss, _ = module.build_causal_lm_program(cfg)
-        return loss, {"tokens": np.zeros((2, 2, cfg.seq_len), np.int64)}
-    return build
-
-
-def _bert_pretrain():
-    from paddle_tpu.models import bert
-    cfg = bert.BertConfig.tiny()
-    _, _, loss = bert.build_pretrain_program(cfg)
-    return loss, {"input_ids": np.zeros((2, 2, cfg.seq_len), np.int64),
-                  "mlm_labels": np.zeros((2, 2, cfg.seq_len, 1), np.int64)}
-
-
-@pytest.mark.parametrize("cell, digest", [
-    ("bert", "680dd440f44ce047ab42aefcc7b90d4a5196cb72a073633a721ac25285f98ca7"),
-    ("mellum", "ece5b162f3f2d703cacc41a43320bc2ae04f46f1dfcef901271578999e044e88"),
-    ("latent_hybrid",
-     "ce94d43b5b24963e07c270f8de8b711da0ef8d8ea3d4a1909f00d4de6d174779")])
-def test_the_other_cells_builders_trace_as_before(cell, digest):
-    """The delta rule's kernels are reached from `ops/kda.py` alone, and
-    `models/ling.py` is its only caller: the tiny float32 train step of the
-    builders behind the other cells traces to the jaxpr of the tree before
-    the kernels (commit fa2014b, jax 0.9.0; the digests were made there,
-    source lines cut, the two sparse ones again with PR 41's route in
-    `routed_moe`). BERT's (both BERT cells), the sliding-window one's
-    and the latent-expert hybrid's here; kanana's is
-    `test_latent_attention_as_kanana_calls_it_traces_as_before` above, the
-    hybrid's `tests/test_nemotron3_super.py::
-    test_without_a_latent_and_with_every_head_the_model_traces_as_before`."""
-    from paddle_tpu.models import mellum, nemotron_h
-    build = {
-        "bert": _bert_pretrain,
-        "mellum": _causal_lm(mellum, mellum.MellumConfig.tiny()),
-        "latent_hybrid": _causal_lm(
-            nemotron_h, nemotron_h.NemotronHConfig.tiny_latent_share()),
-    }[cell]
-    assert _tiny_step_digest(build) == digest

@@ -7,10 +7,7 @@ ops: the flash kernels' window and grouped KV heads under the Pallas
 interpreter against the dense route, the rotary frequency rules against
 hand-worked numbers, softmax scoring's grad rule.
 """
-import hashlib
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -18,20 +15,19 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import causal_lm_harness as harness
+from causal_lm_harness import S, counter_rise, run_op as _run_op
 
-import paddle_tpu as paddle  # noqa: E402
-import paddle_tpu.fluid as fluid  # noqa: E402
-from paddle_tpu.distributed import fleet  # noqa: E402
-from paddle_tpu.fluid import layers  # noqa: E402
-from paddle_tpu.models import mellum  # noqa: E402
-from paddle_tpu.observability import metrics  # noqa: E402
-from paddle_tpu.ops import attention, llm_ops  # noqa: E402
-from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
-from paddle_tpu.testing import reset_programs  # noqa: E402
-from benchmark.reference import mellum2 as ref  # noqa: E402
+import paddle_tpu as paddle
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid import layers
+from paddle_tpu.models import mellum
+from paddle_tpu.observability import metrics
+from paddle_tpu.ops import attention, llm_ops
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.testing import reset_programs
+from benchmark.reference import mellum2 as ref
 
-S, B = 32, 4
 SLIDING, FULL = "sliding_attention", "full_attention"
 ROPE = {FULL: {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
                "original_max_position_embeddings": 8192, "beta_fast": 32,
@@ -59,52 +55,13 @@ def model_config(cfg):
         **{k: cfg[k] for k in SHARED})
 
 
-def batches(k, seed=0):
-    rng = np.random.RandomState(seed)
-    ids = rng.randint(0, CFG["vocab"], (k, B, S)).astype(np.int64)
-    labels = np.concatenate([ids[:, :, 1:], np.full((k, B, 1), -100)], 2)
-    return ids, labels
+def seeded_params():
+    return ref.init_params(CFG, jax.random.key(3))
 
 
 def trained_program(amp, k, ids):
-    """The program's losses, first routed choice and scope after `k` steps
-    of `run_steps` from the reference's seeded weights."""
-    reset_programs(0)
-    _, loss, routed = mellum.build_causal_lm_program(model_config(CFG))
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = amp
-    fleet.distributed_optimizer(
-        paddle.optimizer.Adam(learning_rate=ref.ADAM["lr"]),
-        strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    for name, value in ref.init_params(CFG, jax.random.key(3)).items():
-        assert tuple(scope.find(name).shape) == tuple(value.shape), name
-        scope.set(name, value)
-    out = exe.run_steps(k, feed={"tokens": ids[:k]},
-                        fetch_list=[loss, routed[0][0]])
-    return np.asarray(out[0]).reshape(-1), np.asarray(out[1]), scope
-
-
-def reference_states(k, ids, labels):
-    """[(loss, grads, params, m, v) after each of k reference steps]."""
-    params = ref.init_params(CFG, jax.random.key(3))
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
-    key = ref._cfg_key(CFG)
-    states, first_idx = [], None
-    for t in range(k):
-        val, idx, grads = ref._block_grad(params, ids[t], labels[t], key,
-                                          None)
-        n = float((labels[t] != -100).sum())
-        grads = jax.tree.map(lambda g: g / n, grads)
-        first_idx = idx if first_idx is None else first_idx
-        copy = jax.tree.map(jnp.array, (params, m, v))
-        params, m, v = ref._adam(*copy, grads, float(t + 1))
-        states.append((float(val) / n, grads, params, m, v))
-    return states, np.asarray(first_idx)
+    return harness.trained_program(mellum, model_config(CFG), ref,
+                                   seeded_params(), amp, k, ids)
 
 
 # Tolerances, as in test_deepseek_v3.py. float32: the program and the
@@ -125,39 +82,25 @@ def test_program_follows_the_reference(amp, grad_tol, loss_tol):
     # another expert is 10 to 20 % of a leaf's gradient here, a comparison
     # of routings and not of arithmetic (on the chip `route_mismatch_share`
     # is that comparison)
-    ids, labels = batches(2, seed=DATA_SEED)
-    states, ref_idx = reference_states(2, ids, labels)
+    ids, labels = harness.batches(CFG["vocab"], 2, seed=DATA_SEED)
+    states, ref_idx = harness.reference_states(
+        ref, CFG, (seeded_params(),), 2, ids, labels)
 
     losses, idx, scope = trained_program(amp, 1, ids)
     loss1, grads1 = states[0][0], states[0][1]
     assert abs(losses[0] - loss1) / loss1 < loss_tol
-    for name, want in grads1.items():
-        got = np.asarray(scope.find(name + "_moment1_0"),
-                         np.float32) / (1 - ref.ADAM["beta1"])
-        err = np.linalg.norm(got - np.asarray(want)) / max(
-            np.linalg.norm(np.asarray(want)), 1e-12)
+    for name, err in harness.first_step_gaps(scope, grads1, ref).items():
         assert err < grad_tol, (name, err)
-    mismatch = (np.sort(idx[0].reshape(ref_idx.shape), 1)
-                != np.sort(ref_idx, 1)).mean()
-    assert mismatch == 0
+    assert harness.route_mismatch(idx[0], ref_idx) == 0
     losses, _, scope = trained_program(amp, 2, ids)
     for t in range(2):
         assert abs(losses[t] - states[t][0]) / states[t][0] < loss_tol
-    _, _, params, m, v = states[1]
     lr = ref.ADAM["lr"]
-    p0 = ref.init_params(CFG, jax.random.key(3))
-    for name in params:
-        got = np.asarray(scope.find(name), np.float32)
-        want = np.asarray(params[name])
-        assert np.abs(got - want).max() <= (4.1 * lr if amp
-                                            else 1e-2 * lr), name
-        moved = np.linalg.norm(want - np.asarray(p0[name]))
-        assert np.linalg.norm(got - want) <= (0.3 if amp
-                                              else 1e-3) * moved, name
-        for acc, want in (("_moment1_0", m), ("_moment2_0", v)):
-            got = np.asarray(scope.find(name + acc), np.float32)
-            err = np.linalg.norm(got - np.asarray(want[name])) / max(
-                np.linalg.norm(np.asarray(want[name])), 1e-20)
+    for name, worst, gap, moved, moments in harness.second_step_gaps(
+            scope, states, seeded_params()):
+        assert worst <= (4.1 * lr if amp else 1e-2 * lr), name
+        assert gap <= (0.3 if amp else 1e-3) * moved, name
+        for acc, err in moments.items():
             assert err < 2 * grad_tol, (name, acc, err)
 
 
@@ -176,16 +119,9 @@ DATA_SEED = 3
 def test_the_reference_tells_each_fault_apart(fault, moved):
     """What the new mechanisms admit going wrong each moves the reference's
     own gradients by far more than any tolerance above."""
-    ids, labels = batches(1, seed=DATA_SEED)
-    _, _, want = ref._block_grad(
-        ref.init_params(CFG, jax.random.key(3)), ids[0], labels[0],
-        ref._cfg_key(CFG), None)
-    bad_cfg = dict(CFG, **fault)
-    _, _, got = ref._block_grad(
-        ref.init_params(CFG, jax.random.key(3)), ids[0], labels[0],
-        ref._cfg_key(bad_cfg), None)
-    worst = max(float(jnp.linalg.norm(got[n] - want[n])
-                      / jnp.linalg.norm(want[n])) for n in want)
+    ids, labels = harness.batches(CFG["vocab"], 1, seed=DATA_SEED)
+    worst = harness.worst_leaf_gap(ref, CFG, dict(CFG, **fault),
+                                   (seeded_params(),), ids[0], labels[0])
     assert worst > 0.1, (moved, worst)
 
 
@@ -213,42 +149,14 @@ def _ref_cfg(held, total, offset, top_k=3, **assumed):
                 assumed=assumed)
 
 
-def _share_program(x, params, offset, held, total, top_k=3, withhold=False,
-                   cot=None):
+def _share_program(x, params, offset, held, total, top_k=3, **grad):
     """One share's `routed_moe` (softmax scoring, no bias) through a
     Program: [Out, TopIdx, ExpertLoad], or with `cot` the gradients of
     sum(Out * cot) with respect to (x, GateW, ExpertGate, ExpertUp,
-    ExpertDown)."""
-    reset_programs(0)
-    n, d = x.shape
-    xv = layers.data(name="x", shape=[d], dtype="float32")
-    xv.stop_gradient = False
-    sl = slice(offset, offset + held)
-    arrays = {"gate_w": params["router_w"],
-              "eg": params["experts_gate_w"][sl],
-              "eu": params["experts_up_w"][sl],
-              "ed": params["experts_down_w"][sl]}
-    var = {k: layers.create_parameter(list(v.shape), "float32", name=k)
-           for k, v in arrays.items()}
-    out, idx, load = layers.routed_moe(
-        xv, var["gate_w"], var["eg"], var["eu"], var["ed"], top_k=top_k,
-        scoring="softmax", experts_total=total, expert_offset=offset)
-    feed, fetch = {"x": x}, [out, idx, load]
-    if cot is not None:
-        cv = layers.data(name="cot", shape=[d], dtype="float32")
-        loss = layers.reduce_sum(layers.elementwise_mul(out, cv))
-        if withhold:
-            for op in fluid.default_main_program().global_block().ops:
-                if op.type == "routed_moe":
-                    for slot in ("H", "U", "SortedW", "Order", "Inv"):
-                        op.outputs.pop(slot)
-        fetch = fluid.gradients(loss, [xv] + [var[k] for k in arrays])
-        feed["cot"] = cot
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    for k, v in arrays.items():
-        fluid.global_scope().set(k, jnp.asarray(v))
-    return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
+    ExpertDown) (`harness.routed_share`)."""
+    return harness.routed_share(
+        x, harness.held_arrays(params, offset, held), top_k, total, offset,
+        scoring="softmax", **grad)
 
 
 def _reference_layer(x, params, cfg):
@@ -308,10 +216,9 @@ def test_softmax_scorings_grad_rule_against_generic_route_and_reference():
     counters = ("moe.bwd_residual", "moe.bwd_recomputed")
     rises = []
     for withhold in (False, True):
-        before = [metrics.get(c) for c in counters]
-        got = _share_program(x, params, 4, 4, 16, withhold=withhold, cot=cot)
-        rises.append(tuple(int(metrics.get(c) - b)
-                           for c, b in zip(counters, before)))
+        got, rise = counter_rise(lambda: _share_program(
+            x, params, 4, 4, 16, withhold=withhold, cot=cot), counters)
+        rises.append(rise)
         if withhold:
             for name, a, b in zip(_GRAD_NAMES, by_rule, got):
                 np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7,
@@ -357,14 +264,6 @@ def test_yarn_frequencies_against_hand_worked_numbers():
     np.testing.assert_allclose(
         llm_ops.rotary_frequencies(500000, 128),
         ref.rope_frequencies(ROPE[SLIDING], 128), rtol=1e-12)
-
-
-def _run_op(op_type, inputs, outputs, attrs):
-    from paddle_tpu.ops import registry
-    ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    got = registry.get(op_type).lower(
-        ctx, {k: [jnp.asarray(v)] for k, v in inputs.items()}, attrs)
-    return [np.asarray(got[o][0]) for o in outputs]
 
 
 @pytest.mark.parametrize("kind", [SLIDING, FULL])
@@ -503,7 +402,7 @@ def _kernels_jaxpr(causal, masked):
     text = str(jax.make_jaxpr(step)(
         x, x, x, x, sd(2, 1, 1, 256, dt=jnp.float32) if masked else None,
         sd(dt=jnp.int32)))
-    return re.sub(r"flash_attention\.py:\d+", "flash_attention.py:N", text)
+    return harness.cut_source_lines(text, "flash_attention")
 
 
 @pytest.mark.parametrize("causal, masked, digest", [
@@ -522,7 +421,7 @@ def test_without_window_and_groups_the_kernels_trace_as_before(
     monkeypatch.setattr(fa, "interpret_mode", lambda: False)
     text = _kernels_jaxpr(causal, masked)
     assert text.count("pallas_call") == 3
-    assert hashlib.sha256(text.encode()).hexdigest() == digest
+    assert harness.sha256(text) == digest
 
 
 # ---------------------------------------------------------------------------
@@ -583,25 +482,14 @@ def test_a_trace_of_the_step_counts_its_routes(monkeypatch):
     and V at the KV heads' count only, and no `ragged_dot`."""
     monkeypatch.setattr(attention, "_use_pallas",
                         lambda q: q.shape[2] % 128 == 0)
-    reset_programs(0)
     cfg = mellum.MellumConfig.tiny()
     cfg.seq_len, cfg.head_dim, cfg.sliding_window = 128, 64, 48
     cfg.num_attention_heads, cfg.num_key_value_heads = 6, 2
     cfg.hidden_size, cfg.moe_intermediate_size = 128, 256
-    _, loss, _ = mellum.build_causal_lm_program(cfg)
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = True
-    fleet.distributed_optimizer(paddle.optimizer.Adam(1e-3),
-                                strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    ids = np.random.RandomState(0).randint(0, 256, (2, 1, 128)).astype(
-        np.int64)
-    before = [metrics.get(c) for c in _COUNTERS]
-    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
-    rise = {c: int(metrics.get(c) - b) for c, b in zip(_COUNTERS, before)}
-    assert rise == {
+    exe, loss, ids = harness.amp_step(mellum, cfg)
+    jaxpr, rise = counter_rise(
+        lambda: str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)), _COUNTERS)
+    assert dict(zip(_COUNTERS, rise)) == {
         "attention.flash_window": 3, "attention.flash_full": 1,
         "attention.flash_kv_grouped": 4, "attention.flash_kv_expanded": 0,
         "attention.flash_bwd_residual": 4, "moe.layers_lowered": 4,

@@ -8,10 +8,7 @@ beside the router's, and a row buffer of min(k, E_held) x N rows where a
 token picks more experts than a rank holds. What every other configuration
 calls traces to the jaxpr of the tree before (commit 147251f).
 """
-import hashlib
-import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -19,21 +16,18 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import causal_lm_harness as harness
+from causal_lm_harness import S, counter_rise
 
-import paddle_tpu as paddle  # noqa: E402
-import paddle_tpu.fluid as fluid  # noqa: E402
-from paddle_tpu.analysis import verifier  # noqa: E402
-from paddle_tpu.distributed import fleet  # noqa: E402
-from paddle_tpu.fluid import layers  # noqa: E402
-from paddle_tpu.models import nemotron_h  # noqa: E402
-from paddle_tpu.observability import metrics  # noqa: E402
-from paddle_tpu.ops import registry  # noqa: E402
-from paddle_tpu.testing import reset_programs  # noqa: E402
-from benchmark.reference import nemotron3_super as ref  # noqa: E402
-from benchmark.reference import nemotron_h as base_ref  # noqa: E402
+import paddle_tpu as paddle
+import paddle_tpu.fluid as fluid
+from paddle_tpu.analysis import verifier
+from paddle_tpu.models import nemotron_h
+from paddle_tpu.ops import registry
+from paddle_tpu.testing import reset_programs
+from benchmark.reference import nemotron3_super as ref
+from benchmark.reference import nemotron_h as base_ref
 
-S, B = 32, 4
 # the reference's configuration of `NemotronHConfig.tiny_latent_share()`:
 # the top-level keys say what is HELD, as the benchmark's file does
 CFG = dict(hidden_size=64, hybrid_override_pattern="MEMEM*EME", layers=9,
@@ -56,13 +50,6 @@ def model_config(seq=S):
     return cfg
 
 
-def batches(k, seed=0):
-    rng = np.random.RandomState(seed)
-    ids = rng.randint(0, CFG["vocab"], (k, B, S)).astype(np.int64)
-    labels = np.concatenate([ids[:, :, 1:], np.full((k, B, 1), -100)], 2)
-    return ids, labels
-
-
 def seeded_params():
     """The reference's seeded leaves, the routers' eight times as wide: at 64
     features a draw of std 0.02 gives logits of std 0.16, every score near
@@ -75,44 +62,8 @@ def seeded_params():
 
 
 def trained_program(amp, k, ids):
-    """The program's losses, first routed choice and scope after `k` steps
-    of `run_steps` from the reference's seeded weights."""
-    reset_programs(0)
-    _, loss, routed = nemotron_h.build_causal_lm_program(model_config())
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = amp
-    fleet.distributed_optimizer(
-        paddle.optimizer.Adam(learning_rate=ref.ADAM["lr"]),
-        strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    scope = fluid.global_scope()
-    for name, value in seeded_params().items():
-        assert tuple(scope.find(name).shape) == tuple(value.shape), name
-        scope.set(name, value)
-    out = exe.run_steps(k, feed={"tokens": ids[:k]},
-                        fetch_list=[loss, routed[0][0]])
-    return np.asarray(out[0]).reshape(-1), np.asarray(out[1]), scope
-
-
-def reference_states(k, ids, labels):
-    """[(loss, grads, params, m, v) after each of k reference steps]."""
-    params, buffers = ref.split_state(CFG, seeded_params())
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
-    key = ref._cfg_key(CFG)
-    states, first_idx = [], None
-    for t in range(k):
-        val, idx, grads = ref._block_grad(params, buffers, ids[t], labels[t],
-                                          key, None)
-        n = float((labels[t] != -100).sum())
-        grads = jax.tree.map(lambda g: g / n, grads)
-        first_idx = idx if first_idx is None else first_idx
-        copy = jax.tree.map(jnp.array, (params, m, v))
-        params, m, v = ref._adam(*copy, grads, float(t + 1))
-        states.append((float(val) / n, grads, params, m, v))
-    return states, np.asarray(first_idx)
+    return harness.trained_program(nemotron_h, model_config(), ref,
+                                   seeded_params(), amp, k, ids)
 
 
 # Under bf16 rounding a token or two of the 128 sits at a near-tie of its
@@ -154,47 +105,34 @@ def test_the_reference_holds_the_leaves_the_equations_name():
     (False, 5e-5, 1e-6, 0.0), (True, 5e-2, 2e-4, 0.01)],
     ids=["float32", "amp"])
 def test_program_follows_the_reference(amp, grad_tol, loss_tol, route_tol):
-    ids, labels = batches(2, seed=DATA_SEED)
-    states, ref_idx = reference_states(2, ids, labels)
-    counters = ("moe.bwd_residual", "moe.rows_bounded",
-                "moe.latent_layers_lowered")
-    before = [metrics.get(c) for c in counters]
-    losses, idx, scope = trained_program(amp, 1, ids)
+    ids, labels = harness.batches(CFG["vocab"], 2, seed=DATA_SEED)
+    states, ref_idx = harness.reference_states(
+        ref, CFG, ref.split_state(CFG, seeded_params()), 2, ids, labels)
+    (losses, idx, scope), rise = counter_rise(
+        lambda: trained_program(amp, 1, ids),
+        ("moe.bwd_residual", "moe.rows_bounded", "moe.latent_layers_lowered"))
     # four expert layers: each by the rule, in a latent, on a bounded buffer
-    assert [metrics.get(c) - b for c, b in zip(counters, before)] == [4, 4, 4]
+    assert rise == (4, 4, 4)
     loss1, grads1 = states[0][0], states[0][1]
     assert abs(losses[0] - loss1) / loss1 < loss_tol
-    for name, want in grads1.items():
-        got = np.asarray(scope.find(name + "_moment1_0"),
-                         np.float32) / (1 - ref.ADAM["beta1"])
-        err = np.linalg.norm(got - np.asarray(want)) / max(
-            np.linalg.norm(np.asarray(want)), 1e-12)
+    for name, err in harness.first_step_gaps(scope, grads1, ref).items():
         assert err < grad_tol, (name, err)
-    mismatch = (np.sort(idx[0].reshape(ref_idx.shape), 1)
-                != np.sort(ref_idx, 1)).mean()
-    assert mismatch <= route_tol
+    assert harness.route_mismatch(idx[0], ref_idx) <= route_tol
     losses, _, scope = trained_program(amp, 2, ids)
     for t in range(2):
         assert abs(losses[t] - states[t][0]) / states[t][0] < loss_tol
-    _, _, params, m, v = states[1]
     lr = ref.ADAM["lr"]
-    p0 = seeded_params()
     errs = []
-    for name in params:
-        got = np.asarray(scope.find(name), np.float32)
-        want = np.asarray(params[name])
-        assert np.abs(got - want).max() <= (4.1 if amp else 0.5) * lr, name
-        moved = np.linalg.norm(want - np.asarray(p0[name]))
-        assert np.linalg.norm(got - want) <= (0.3 if amp
-                                              else 1e-3) * moved, name
-        for acc, want in (("_moment1_0", m), ("_moment2_0", v)):
-            got = np.asarray(scope.find(name + acc), np.float32)
-            errs.append(np.linalg.norm(got - np.asarray(want[name])) / max(
-                np.linalg.norm(np.asarray(want[name])), 1e-20))
+    for name, worst, gap, moved, moments in harness.second_step_gaps(
+            scope, states, seeded_params()):
+        assert worst <= (4.1 if amp else 0.5) * lr, name
+        assert gap <= (0.3 if amp else 1e-3) * moved, name
+        for acc, err in moments.items():
+            errs.append(err)
             # the second batch has tokens of its own at a near-tie, which
             # an expert leaf feels (its second moment twice); the leaves'
             # median is the arithmetic
-            assert errs[-1] < (1.0 if amp else 2 * grad_tol), (name, acc)
+            assert err < (1.0 if amp else 2 * grad_tol), (name, acc)
     assert np.median(errs) < grad_tol
 
 
@@ -210,15 +148,11 @@ def test_program_follows_the_reference(amp, grad_tol, loss_tol, route_tol):
 def test_the_reference_tells_each_fault_apart(wrong, moved, least):
     """Each fault the benchmark's driver holds `correct` to moves the
     reference's own gradients by far more than the tolerances above."""
-    ids, labels = batches(1, seed=DATA_SEED)
-    params, buffers = ref.split_state(
-        CFG, ref.init_params(CFG, jax.random.key(3)))
-    _, _, want = ref._block_grad(params, buffers, ids[0], labels[0],
-                                 ref._cfg_key(CFG), None)
-    _, _, got = ref._block_grad(params, buffers, ids[0], labels[0],
-                                ref._cfg_key(dict(CFG, **wrong)), None)
-    worst = max(float(jnp.linalg.norm(got[n] - want[n])
-                      / jnp.linalg.norm(want[n])) for n in want)
+    ids, labels = harness.batches(CFG["vocab"], 1, seed=DATA_SEED)
+    worst = harness.worst_leaf_gap(
+        ref, CFG, dict(CFG, **wrong),
+        ref.split_state(CFG, ref.init_params(CFG, jax.random.key(3))),
+        ids[0], labels[0])
     assert worst > least, (moved, worst)
 
 
@@ -268,46 +202,13 @@ def _held(params, offset, held):
             for k, v in params.items()}
 
 
-def _share_program(x, z, params, offset, top_k, total=32, cot=None,
-                   withhold=False):
+def _share_program(x, z, params, offset, top_k, total=32, **grad):
     """One share's `routed_moe` through a Program: [Out, TopIdx,
     ExpertLoad], or with `cot` the gradients of sum(Out * cot) with respect
-    to (x, GateW, [ExpertGate,] ExpertUp, ExpertDown[, z])."""
-    reset_programs(0)
-    xv = layers.data(name="x", shape=[x.shape[1]], dtype="float32")
-    xv.stop_gradient = False
-    feed, wrt = {"x": x}, [xv]
-    zv = None
-    if z is not None:
-        zv = layers.data(name="z", shape=[z.shape[1]], dtype="float32")
-        zv.stop_gradient = False
-        feed["z"] = z
-    weights = [k for k in ("gate_w", "eg", "eu", "ed") if k in params]
-    var = {k: layers.create_parameter(list(params[k].shape), "float32",
-                                      name=k) for k in weights}
-    bias = layers.create_parameter([total], "float32", name="bias")
-    bias.stop_gradient = True
-    out, idx, load = layers.routed_moe(
-        xv, var["gate_w"], var.get("eg"), var["eu"], var["ed"], top_k=top_k,
-        select_bias=bias, routed_scaling=5.0, experts_total=total,
-        expert_offset=offset, expert_input=zv)
-    fetch = [out, idx, load]
-    if cot is not None:
-        cv = layers.data(name="cot", shape=[cot.shape[1]], dtype="float32")
-        loss = layers.reduce_sum(layers.elementwise_mul(out, cv))
-        if withhold:
-            for op in fluid.default_main_program().global_block().ops:
-                if op.type == "routed_moe":
-                    for slot in ("U", "SortedW", "Order", "Inv"):
-                        op.outputs.pop(slot)
-        fetch = fluid.gradients(
-            loss, wrt + [var[k] for k in weights] + ([zv] if zv else []))
-        feed["cot"] = cot
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    for k in weights + ["bias"]:
-        fluid.global_scope().set(k, jnp.asarray(params[k]))
-    return [np.asarray(g) for g in exe.run(feed=feed, fetch_list=fetch)]
+    to (x, GateW, [ExpertGate,] ExpertUp, ExpertDown[, z])
+    (`harness.routed_share`)."""
+    return harness.routed_share(x, params, top_k, total, offset, z=z,
+                                routed_scaling=5.0, **grad)
 
 
 @pytest.mark.parametrize("gate, latent, top_k, held", [
@@ -342,11 +243,9 @@ def test_more_slots_than_experts_held_against_a_plain_loop(gate, latent,
                 "moe.latent_layers_lowered")
     rises, got = [], {}
     for withhold in (False, True):
-        before = [metrics.get(c) for c in counters]
-        got[withhold] = _share_program(x, z, share, offset, top_k, cot=cot,
-                                       withhold=withhold)
-        rises.append(tuple(int(metrics.get(c) - b)
-                           for c, b in zip(counters, before)))
+        got[withhold], rise = counter_rise(lambda: _share_program(
+            x, z, share, offset, top_k, cot=cot, withhold=withhold), counters)
+        rises.append(rise)
     bounded, lat = int(top_k > held), int(latent)
     assert rises == [(1, 0, bounded, lat), (0, 1, bounded, lat)]
     names = ["X", "GateW"] + ["ExpertGate"] * gate + [
@@ -429,33 +328,6 @@ _CALLS = {
 }
 
 
-def _routed_jaxpr(gate, bias, held, total, attrs, n=256, d=128, f=256):
-    attrs = dict(attrs, experts_total=total, expert_offset=0)
-    opdef = registry.get("routed_moe")
-    names = ["X", "GateW"] + ["ExpertGate"] * gate + ["ExpertUp",
-                                                      "ExpertDown"]
-
-    def step(x, wg, sb, eg, eu, ed, g):
-        ctx = registry.LowerCtx(rng_key=None)
-        ins = {"X": [x], "GateW": [wg], "ExpertUp": [eu], "ExpertDown": [ed]}
-        if gate:
-            ins["ExpertGate"] = [eg]
-        if bias:
-            ins["SelectBias"] = [sb]
-        outs = opdef.lower(ctx, ins, attrs)
-        grads = opdef.grad(ctx, ins, attrs,
-                           {s: outs[s] for s in opdef.residual_slots
-                            if s in outs}, {"Out": [g]})
-        return outs["Out"][0], [grads[s][0] for s in names]
-
-    bf, sd = jnp.bfloat16, jax.ShapeDtypeStruct
-    text = str(jax.make_jaxpr(step)(
-        sd((n, d), jnp.float32), sd((d, total), jnp.float32),
-        sd((total,), jnp.float32), sd((held, d, f), bf),
-        sd((held, d, f), bf), sd((held, f, d), bf), sd((n, d), bf)))
-    return re.sub(r"(moe|grouped_matmul)\.py:\d+", r"\1.py:N", text)
-
-
 @pytest.mark.parametrize("cell", sorted(_CALLS))
 def test_as_the_four_sparse_cells_call_it_routed_moe_traces_as_before(
         cell, monkeypatch):
@@ -469,8 +341,9 @@ def test_as_the_four_sparse_cells_call_it_routed_moe_traces_as_before(
     from paddle_tpu.ops.pallas import grouped_matmul
     monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
     call, digest = _CALLS[cell]
-    text = _routed_jaxpr(**call)
-    assert hashlib.sha256(text.encode()).hexdigest() == digest
+    call = dict(call, attrs=dict(call["attrs"], experts_total=call["total"],
+                                 expert_offset=0))
+    assert harness.sha256(harness.routed_moe_jaxpr(n=256, **call)) == digest
 
 
 def test_without_a_latent_and_with_every_head_the_model_traces_as_before():
@@ -478,16 +351,9 @@ def test_without_a_latent_and_with_every_head_the_model_traces_as_before():
     float32 train step traces to the jaxpr it had before the new keys
     (jax 0.9.0; source lines cut; made at commit 147251f, and again with
     PR 41's route, the one change to its ops since)."""
-    reset_programs(0)
-    cfg = nemotron_h.NemotronHConfig.tiny()
-    _, loss, _ = nemotron_h.build_causal_lm_program(cfg)
-    paddle.optimizer.Adam(learning_rate=1e-3).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    ids = np.zeros((2, 2, cfg.seq_len), np.int64)
-    text = re.sub(r"[\w/.\-]+\.py:\d+", "F:N",
-                  str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)))
-    assert hashlib.sha256(text.encode()).hexdigest() == (
+    step, _ = harness.tiny_step_digests(harness.causal_lm(
+        nemotron_h, nemotron_h.NemotronHConfig.tiny()))
+    assert step == (
         "dc9e4d6bbb4513b2741e514caf9d4b7195cf9afe151839d818cf5f3bd66c00c9")
 
 
@@ -505,23 +371,14 @@ SHARE = dict(CFG, mamba_num_heads=2, n_groups=1, num_attention_heads=2,
 
 def _mixer_program(kind, x, params, pre):
     """One share's mixer through a Program, built for its held heads."""
-    reset_programs(0)
     mcfg = model_config(seq=x.shape[1])
     mcfg.mamba_num_heads, mcfg.n_groups = 16, 8
     mcfg.mamba_heads_held, mcfg.mamba_groups_held = 2, 1
     mcfg.num_attention_heads, mcfg.num_key_value_heads = 16, 2
     mcfg.heads_held, mcfg.kv_heads_held = 2, 1
-    xv = layers.data(name="x", shape=list(x.shape[1:]), dtype="float32")
     build = (nemotron_h.mamba_mixer if kind == nemotron_h.MAMBA
              else nemotron_h.grouped_attention)
-    out = build(xv, mcfg, pre)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    for name, value in params.items():
-        assert tuple(fluid.global_scope().find(name).shape) == tuple(
-            value.shape), name
-        fluid.global_scope().set(name, jnp.asarray(value))
-    return np.asarray(exe.run(feed={"x": x}, fetch_list=[out])[0])
+    return harness.mixer_program(build, mcfg, x, params, pre)
 
 
 def _cols(value, widths, pick):
@@ -697,28 +554,12 @@ def test_a_trace_of_the_amp_step_counts_its_routes(recompute, rise):
     cell's way), under `jax.vjp` of a whole layer. The two projections'
     scopes reach the compiled step, forward and backward; the experts'
     input reaches the op in bf16 and the router's in float32."""
-    reset_programs(0)
-    cfg = model_config()
-    _, loss, _ = nemotron_h.build_causal_lm_program(cfg)
-    fleet.init(is_collective=True)
-    strategy = fleet.DistributedStrategy()
-    strategy.amp = True
-    if recompute:
-        strategy.recompute = True
-        strategy.recompute_configs = {
-            "checkpoints": list(loss._layer_checkpoints)}
-    fleet.distributed_optimizer(paddle.optimizer.Adam(1e-3),
-                                strategy).minimize(loss)
-    exe = fluid.Executor()
-    exe.run(fluid.default_startup_program())
-    ids = np.random.RandomState(0).randint(0, 256, (2, 1, S)).astype(np.int64)
-    counters = ("moe.layers_lowered", "moe.bwd_residual",
-                "moe.bwd_recomputed", "moe.rows_bounded",
-                "moe.latent_layers_lowered")
-    before = [metrics.get(c) for c in counters]
-    jaxpr = str(exe.step_jaxpr({"tokens": ids}, [loss], k=2))
-    assert tuple(int(metrics.get(c) - b)
-                 for c, b in zip(counters, before)) == rise
+    exe, loss, ids = harness.amp_step(nemotron_h, model_config(), recompute)
+    jaxpr, got = counter_rise(
+        lambda: str(exe.step_jaxpr({"tokens": ids}, [loss], k=2)),
+        ("moe.layers_lowered", "moe.bwd_residual", "moe.bwd_recomputed",
+         "moe.rows_bounded", "moe.latent_layers_lowered"))
+    assert got == rise
     # U [4 x 32, 32] in bf16: 4 held experts' rows, not 6 slots'
     assert "bf16[128,32]" in jaxpr and "bf16[192,32]" not in jaxpr
     hlo = exe.compiled_hlo({"tokens": ids}, [loss], k=2)
