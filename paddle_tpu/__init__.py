@@ -62,7 +62,8 @@ from .ops import (math_ops, nn_ops, tensor_ops, optimizer_ops,  # noqa: F401
                   detection_assign_ops,  # noqa: F401
                   dense_tail_ops, dense_tail_ops2,  # noqa: F401
                   sparse_grad, moe, tail_ops, lod_ops,  # noqa: F401
-                  int8_ops, fused_ce, paged_ops, llm_ops, ssm, kda)  # noqa: F401
+                  int8_ops, fused_ce, paged_ops, llm_ops, ssm, kda,
+                  sparse_index)  # noqa: F401
 
 __version__ = "0.1.0"
 

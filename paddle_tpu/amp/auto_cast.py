@@ -30,6 +30,10 @@ white_list = {
     # the delta rule's matmuls (the decayed products, the states as a
     # factor) run on bf16 operands; the decay and beta do not
     "kda_scan",
+    # the indexer's score products run on bf16 operands like any matmul of
+    # the step; its per-head weights do not (keep_f32_slots), and relu,
+    # weighting, the sum over heads and the selection are float32 inside
+    "sparse_index",
 }
 # per-op input slots excluded from the white-list cast: tiny O(V)/O(H)
 # operands whose quantization buys no MXU time but drifts parity with the
@@ -52,6 +56,9 @@ keep_f32_slots = {
     # a channel's log decay, beta before its sigmoid, and the chunk states
     # the forward wrote for the grad op (FO:States) are float32
     "kda_scan": {"G", "Beta", "States"},
+    # the weights the relu'd products are summed with, and the scores'
+    # cotangent the grad op reads (OG:Scores)
+    "sparse_index": {"W", "Scores"},
 }
 
 # ops forced to float32 (reference black list: reductions/normalizations)

@@ -286,12 +286,25 @@ SPECS: Dict[str, OpSpec] = {
         outputs={"IG:W": ONE},
         attr_types={"padding_idx": int}, sharding="selected_rows"),
     "fused_attention": OpSpec(
-        inputs={"Q": ONE, "K": ONE, "V": ONE, "Mask": OPT},
-        outputs={"Out": ONE, "Lse": OPT},
+        # Select: a learned selection of (query, key) pairs, one a row;
+        # Target: the heads' mean of the probabilities on it
+        inputs={"Q": ONE, "K": ONE, "V": ONE, "Mask": OPT, "Select": OPT},
+        outputs={"Out": ONE, "Lse": OPT, "Target": OPT},
         attr_types={"scale": _NUM, "dropout": _NUM, "causal": bool,
                     "sequence_parallel": bool, "sp_mode": str,
-                    "window": int},
+                    "window": int, "return_target": bool},
         sharding="attention"),
+    # --- the sparse-attention indexer (ops/sparse_index.py) ---------------
+    "sparse_index": OpSpec(
+        inputs={"QI": ONE, "KI": ONE, "W": ONE},
+        outputs={"Scores": ONE, "Select": ONE, "PairsPerQuery": OPT},
+        required_attrs=("topk",), attr_types={"topk": int},
+        sharding="follow_x"),
+    "sparse_index_loss": OpSpec(
+        inputs={"Scores": ONE, "Select": ONE, "Target": ONE},
+        outputs={"Loss": ONE}, sharding="follow_x"),
+    "detach": OpSpec(inputs={"X": ONE}, outputs={"Out": ONE},
+                     sharding="elementwise"),
     "switch_moe": OpSpec(
         inputs={"X": ONE, "GateW": ONE, "ExpertW1": ONE, "ExpertB1": OPT,
                 "ExpertW2": ONE, "ExpertB2": OPT},
@@ -319,11 +332,11 @@ SPECS: Dict[str, OpSpec] = {
         inputs={"X": ONE, "Scale": OPT}, outputs={"Y": ONE},
         attr_types={"epsilon": _NUM}, sharding="follow_x"),
     "rotary_embedding": OpSpec(
-        inputs={"X": ONE}, outputs={"Out": ONE},
+        inputs={"X": ONE, "Positions": OPT}, outputs={"Out": ONE},
         attr_types={"theta": _NUM, "rotary_dim": int, "layout": str,
                     "rope_type": str, "factor": _NUM,
                     "original_max_position": int, "beta_fast": _NUM,
-                    "beta_slow": _NUM, "scale": _NUM},
+                    "beta_slow": _NUM, "scale": _NUM, "sections": _LIST},
         sharding="follow_x"),
     "swiglu": OpSpec(
         inputs={"Gate": ONE, "Up": ONE}, outputs={"Out": ONE},

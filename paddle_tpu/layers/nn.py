@@ -30,6 +30,7 @@ __all__ = [
     "fused_attention", "switch_moe", "routed_moe", "rms_norm",
     "rotary_embedding", "swiglu", "relu2", "causal_conv1d", "ssm_scan",
     "gated_group_rms_norm", "l2_norm", "head_gate", "kda_gate", "kda_scan",
+    "detach", "sparse_index", "sparse_index_loss",
 ]
 
 
@@ -808,7 +809,8 @@ def pow(x, factor=1.0, name=None):
 
 def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
                     causal=False, name=None, sequence_parallel=False,
-                    sp_mode="ring", window=None):
+                    sp_mode="ring", window=None, select=None,
+                    return_target=False):
     """Fused multi-head attention on [B, nh, S, hd] tensors (reference
     fused/multihead_matmul_op.cu); pallas flash kernel on TPU. `k` and `v`
     may carry fewer heads, [B, nkv, S, hd] with nkv dividing nh: query head
@@ -816,7 +818,12 @@ def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
     query at position i see keys i-w+1..i only. With
     sequence_parallel=True the op runs ring attention (sp_mode="ring") or
     Ulysses all-to-all (sp_mode="ulysses") over the mesh's sp axis — the
-    long-context path the reference lacks (parallel/ring_attention.py)."""
+    long-context path the reference lacks (parallel/ring_attention.py).
+    `select` [B, S, S] int8 (`sparse_index`'s; with `causal` alone): a query
+    attends the keys where it is 1, the same for every head of a row; no
+    gradient reaches it. With `return_target` the result is (out, target):
+    `target` [B, S, S] float32, the mean over the query heads of the
+    probabilities on the selected pairs, no gradient through it."""
     helper = LayerHelper("fused_attention")
     out = helper.create_variable_for_type_inference(q.dtype)
     # the flash kernels' per-row logsumexp, read by the op's grad rule
@@ -836,9 +843,22 @@ def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
             raise ValueError(f"fused_attention: window={window} needs "
                              "causal=True and window >= 1")
         attrs["window"] = int(window)
-    helper.append_op("fused_attention", inputs=inputs,
-                     outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
-    return out
+    outputs = {"Out": [out], "Lse": [lse]}
+    if select is not None:
+        if not causal or window is not None or mask is not None or dropout:
+            raise ValueError("fused_attention: select goes with causal=True "
+                             "alone (no mask, dropout or window)")
+        inputs["Select"] = [select]
+    if return_target:
+        if select is None:
+            raise ValueError("fused_attention: return_target needs select")
+        attrs["return_target"] = True
+        target = helper.create_variable_for_type_inference("float32")
+        target.stop_gradient = True
+        outputs["Target"] = [target]
+    helper.append_op("fused_attention", inputs=inputs, outputs=outputs,
+                     attrs=attrs)
+    return (out, target) if return_target else out
 
 
 def switch_moe(input, num_experts, d_ff, capacity_factor=1.25, name=None,
@@ -964,7 +984,8 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 def rotary_embedding(x, theta=10000.0, rotary_dim=None, layout="interleaved",
                      rope_type="default", factor=1.0, original_max_position=0,
-                     beta_fast=32.0, beta_slow=1.0, scale=1.0):
+                     beta_fast=32.0, beta_slow=1.0, scale=1.0, positions=None,
+                     sections=None):
     """Rotary positions on x [..., S, D]: the last `rotary_dim` features
     (default all) turn by position along axis -2; the rest passes through.
     `layout`: pairs "interleaved" (2j, 2j+1) or "half" (j, j + rotary_dim/2).
@@ -972,7 +993,11 @@ def rotary_embedding(x, theta=10000.0, rotary_dim=None, layout="interleaved",
     or "yarn", which blends it with the same divided by `factor` over a ramp
     set by `original_max_position`, `beta_fast`, `beta_slow`
     (ops/llm_ops.py rotary_frequencies). cos and sin are multiplied by
-    `scale` (yarn's attention factor)."""
+    `scale` (yarn's attention factor). `positions` [streams, B, S] with
+    `sections` (layout "half"; x [B, S, D] or [B, heads, S, D]): several
+    position streams, pair j turning by the stream of the section it falls
+    in (`sections` their sizes in pairs, e.g. a published `mrope_section`);
+    None: the row's own positions, whatever the sections."""
     helper = LayerHelper("rotary_embedding")
     out = helper.create_variable_for_type_inference(x.dtype)
     attrs = {"theta": float(theta),
@@ -981,7 +1006,14 @@ def rotary_embedding(x, theta=10000.0, rotary_dim=None, layout="interleaved",
              "original_max_position": int(original_max_position),
              "beta_fast": float(beta_fast), "beta_slow": float(beta_slow),
              "scale": float(scale)}
-    helper.append_op("rotary_embedding", inputs={"X": [x]},
+    inputs = {"X": [x]}
+    if positions is not None:
+        if layout != "half" or not sections:
+            raise ValueError("rotary_embedding: positions need layout "
+                             "\"half\" and sections")
+        inputs["Positions"] = [positions]
+        attrs["sections"] = [int(n) for n in sections]
+    helper.append_op("rotary_embedding", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out
 
@@ -993,6 +1025,50 @@ def swiglu(gate, up):
     helper.append_op("swiglu", inputs={"Gate": [gate], "Up": [up]},
                      outputs={"Out": [out]})
     return out
+
+
+def detach(x):
+    """x with no gradient behind it (ops/sparse_index.py `detach`): inside
+    a recomputed segment too, where `stop_gradient` flags are not read."""
+    helper = LayerHelper("detach")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.stop_gradient = True
+    helper.append_op("detach", inputs={"X": [x]}, outputs={"Out": [out]})
+    return out
+
+
+def sparse_index(q, k, w, topk):
+    """The indexer of sparse attention (ops/sparse_index.py): from its
+    queries `q` [B, H, S, D], its one key head `k` [B, S, D] and the
+    per-head weights `w` [B, S, H], (scores, select, pairs): `scores`
+    [B, S, S] float32, sum_j w[t, j] relu(q[t, j] . k[s]) on the causal
+    pairs; `select` [B, S, S] int8, 1 on the min(t + 1, topk) keys of query t
+    with the largest scores (ties to the lower s), what
+    `fused_attention(select=...)` takes; `pairs` [1] the mean count of
+    selected keys a query. A gradient reaches q, k, w from `scores` alone."""
+    helper = LayerHelper("sparse_index")
+    scores = helper.create_variable_for_type_inference("float32")
+    select = helper.create_variable_for_type_inference("int8")
+    pairs = helper.create_variable_for_type_inference("float32")
+    select.stop_gradient = pairs.stop_gradient = True
+    helper.append_op("sparse_index",
+                     inputs={"QI": [q], "KI": [k], "W": [w]},
+                     outputs={"Scores": [scores], "Select": [select],
+                              "PairsPerQuery": [pairs]},
+                     attrs={"topk": int(topk)})
+    return scores, select, pairs
+
+
+def sparse_index_loss(scores, select, target):
+    """mean over queries of KL(target_t || softmax over the selected of
+    scores_t): the indexer's own loss; a gradient reaches `scores` only."""
+    helper = LayerHelper("sparse_index_loss")
+    loss = helper.create_variable_for_type_inference("float32")
+    helper.append_op("sparse_index_loss",
+                     inputs={"Scores": [scores], "Select": [select],
+                             "Target": [target]},
+                     outputs={"Loss": [loss]})
+    return loss
 
 
 def relu2(x):

@@ -8,17 +8,24 @@ attention, TP rules) -> gpt.py, and SE-ResNeXt 50/101/152 (the reference's
 canonical dist-test model, grouped convs + squeeze-excitation)
 -> se_resnext.py.
 
-Four sparse causal LMs, each one expert-parallel rank's share of a published
+Five sparse causal LMs, each one expert-parallel rank's share of a published
 configuration, trained: deepseek_v3.py (latent attention, sigmoid-routed
 experts without drops, shared experts), mellum.py (sliding-window and full
 attention in a period, grouped KV heads, yarn, softmax-routed experts),
 nemotron_h.py (a Mamba-2 mixer, ungated relu^2 experts with a shared one,
 optionally in a latent, or attention without rotary positions a layer),
 ling.py (Kimi-delta linear attention with latent attention closing every
-group, head-wise gates, group-limited routing). What they share is written
+group, head-wise gates, group-limited routing), keye.py (attention over a
+learned SELECTION of keys: an indexer of a few small heads scores every
+causal pair, each query attends its `topk` best-scored keys, all its heads
+the same ones, and the indexer is trained towards the attention's own
+probabilities; the selection is an int8 variable [B, S, S], one a row,
+`layers.sparse_index`'s output and `layers.fused_attention`'s `select`
+input; three-stream rotary positions; softmax-routed experts). What they
+share is written
 once in causal_lm.py (the leaves, the expert layer around `routed_moe`,
 attention on grouped KV heads, the layer loop, the loss); a model file holds
 its configuration, the mixers of its own and which layer gets what.
 """
 from . import (lenet, resnet, bert, wide_deep, gpt, se_resnext, causal_lm,
-               deepseek_v3, mellum, nemotron_h, ling)
+               deepseek_v3, mellum, nemotron_h, ling, keye)

@@ -128,12 +128,21 @@ def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
             routed, ffn(x, shared_width, pre + "shared_", cfg)), idx, load
 
 
-def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None):
+def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
+                      selection=None):
     """`heads` query heads on `kv_heads` KV heads of `head_dim` (query head
     h attends KV head h // group), causal; `rotary` turns q and k (None: no
     rotary positions), `window` keeps the last `window` keys only (None:
     all of them). K and V go to the attention op at their own head count.
-    Scopes `attn.proj`, and `attn.attend.window` or `attn.attend.full`."""
+    Scopes `attn.proj`, and `attn.attend.window` or `attn.attend.full`.
+
+    `selection`: a learned choice of keys in place of a static window, an
+    int8 variable [B, S, S] (`layers.sparse_index`'s `select`) that is 1
+    where query t attends key s, the same for all `heads` of a row. The
+    result is then (out, target): `target` [B, S, S], the mean over the
+    heads of the attention's probabilities on the selected pairs, which the
+    indexer that chose them is trained towards; scope `attn.attend.sparse`.
+    None: today's ops in today's order."""
     hd = cfg.head_dim
     turn = rotary or (lambda t: t)
     with name_scope("attn.proj"):
@@ -143,14 +152,23 @@ def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None):
                         kv_heads, hd))
         v = _heads(_linear(x, kv_heads * hd, pre + "v_proj_w", cfg),
                    kv_heads, hd)
-    with name_scope("attn.attend.full" if window is None
-                    else "attn.attend.window"):
-        ctx = layers.fused_attention(q, k, v, causal=True,
-                                     scale=1.0 / math.sqrt(hd), window=window)
+    target = None
+    if selection is not None:
+        with name_scope("attn.attend.sparse"):
+            ctx, target = layers.fused_attention(
+                q, k, v, causal=True, scale=1.0 / math.sqrt(hd),
+                select=selection, return_target=True)
+    else:
+        with name_scope("attn.attend.full" if window is None
+                        else "attn.attend.window"):
+            ctx = layers.fused_attention(q, k, v, causal=True,
+                                         scale=1.0 / math.sqrt(hd),
+                                         window=window)
     with name_scope("attn.proj"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, heads * hd])
-        return _linear(ctx, cfg.hidden_size, pre + "o_proj_w", cfg)
+        out = _linear(ctx, cfg.hidden_size, pre + "o_proj_w", cfg)
+    return out if selection is None else (out, target)
 
 
 def embed_tokens(cfg):
@@ -179,11 +197,20 @@ def next_token_loss(x, tokens, cfg):
     return layers.scale(layers.mean(ce), scale=s / (s - 1.0))
 
 
-def build_causal_lm_program(cfg, model, decoder_layer, layer_indices):
+def build_causal_lm_program(cfg, model, decoder_layer, layer_indices,
+                            auxiliary=None):
     """Next-token objective over `tokens` [B, seq_len] (`next_token_loss`)
     of a decoder whose layer n is `decoder_layer(x, cfg, n)` -> (x_out,
     (top_idx, expert_load) or None) for n in `layer_indices`, under the
     span `program.build` with the family's name `model`.
+
+    `auxiliary`: a list the layers append scalar losses of their own to
+    (a learned indexer's, say) while they are built; the objective is then
+    the next-token loss plus their sum, and the loss carries them, in
+    order, as `_auxiliary_losses` and the next-token loss alone as
+    `_lm_loss` (under recomputation that one lies inside the last segment
+    and cannot be fetched: it is the loss less the others). None, or
+    nothing appended: the next-token loss and today's Program.
 
     Returns (tokens, loss, routed): `routed` holds, per expert layer, the
     `(top_idx, expert_load)` variables a caller may fetch beside the loss
@@ -198,6 +225,9 @@ def build_causal_lm_program(cfg, model, decoder_layer, layer_indices):
             if r is not None:
                 routed.append(r)
         loss = next_token_loss(x, tokens, cfg)
+        if auxiliary:
+            lm_loss, loss = loss, layers.sums([loss] + list(auxiliary))
+            loss._lm_loss, loss._auxiliary_losses = lm_loss, list(auxiliary)
         loss._layer_checkpoints = ckpts
         return tokens, loss, routed
 

@@ -30,6 +30,18 @@ leave at nkv heads); the dense route computes the same mathematics over a
 [B, nkv, group, S, S] score tensor; the ring / Ulysses routes know one KV
 head per query head and no window: K and V are repeated there
 (`attention.flash_kv_expanded`), a window raises.
+
+A selection. `Select` [B, S, S] int8 (1 where query t attends key s; a
+learned indexer's, ops/sparse_index.py) is an input of its own, shared by
+all heads of a row and stored once a row; with `causal` alone. The flash
+route hands it to the kernels tile by tile (counter `attn.sparse_pallas`);
+the dense route puts -inf where it is 0 (`attn.sparse_xla`); the ring /
+Ulysses routes raise. No gradient reaches it. With the attr `return_target`
+the op also gives `Target` [B, S, S] float32: the mean over the query heads
+of the probabilities, zero off the selection, what the indexer's loss is
+held to: a fourth kernel on the flash route (`selected_probs_sum`, no
+[B, nh, S, S] array in HBM), the heads' mean of `probs` on the dense one,
+under the scope `attn.index.target`; no gradient passes through it.
 """
 from __future__ import annotations
 
@@ -45,10 +57,12 @@ from .registry import register
 _OPERAND_TAG = {"bfloat16": "bf16", "float32": "f32"}
 
 
-def _xla_attention(q, k, v, mask, scale, dropout, key):
+def _xla_attention(q, k, v, mask, scale, dropout, key, select=None,
+                   want_target=False):
     # q: [B, nh, S, hd]; k, v: [B, nkv, S, hd], nkv dividing nh. Where
     # `group` query heads share a KV head the group is an axis of q and of
-    # the scores, and K and V are read as they are
+    # the scores, and K and V are read as they are. With `want_target` the
+    # result is (out, the heads' mean of probs [B, S, S], no gradient)
     b, nh, s, _ = q.shape
     nkv = k.shape[1]
     if nkv != nh:
@@ -61,17 +75,24 @@ def _xla_attention(q, k, v, mask, scale, dropout, key):
                             preferred_element_type=jnp.float32) * scale
     if mask is not None:
         scores = scores + mask.astype(scores.dtype)
+    if select is not None:
+        scores = jnp.where(select[:, None] != 0, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
+    if want_target:
+        with jax.named_scope("attn.index.target"):
+            target = jax.lax.stop_gradient(jnp.mean(probs, axis=1))
     if dropout and key is not None:
         from .rng import fast_keep_mask
         keep = fast_keep_mask(key, 1.0 - dropout, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout), 0.0)
     probs = probs.astype(v.dtype)
     if nkv != nh:
-        return jnp.einsum("bngqk,bnkd->bngqd",
-                          probs.reshape(b, nkv, nh // nkv, s, s),
-                          v).reshape(b, nh, s, v.shape[-1])
-    return jnp.einsum("bnqk,bnkd->bnqd", probs, v)
+        out = jnp.einsum("bngqk,bnkd->bngqd",
+                         probs.reshape(b, nkv, nh // nkv, s, s),
+                         v).reshape(b, nh, s, v.shape[-1])
+    else:
+        out = jnp.einsum("bnqk,bnkd->bnqd", probs, v)
+    return (out, target) if want_target else out
 
 
 def _causal_bias(s, window):
@@ -166,6 +187,16 @@ def _window(attrs):
     return int(attrs.get("window") or 0) or None
 
 
+def _select(ins, attrs, mask, causal, dropout):
+    """The `Select` input, or None; held to what the routes support."""
+    if not ins.get("Select"):
+        return None
+    if not causal or mask is not None or dropout or _window(attrs):
+        raise ValueError("fused_attention: Select goes with causal=True "
+                         "alone (no Mask, dropout or window)")
+    return ins["Select"][0]
+
+
 def _no_lse(q):
     """`Lse` off the flash route: an empty placeholder that nothing reads
     (the flash forward writes its per-row logsumexp [B, nh, S] there for
@@ -188,6 +219,7 @@ def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
     generic `__vjp__` differentiates the forward lowering as before."""
     q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
     dout = (ogs.get("Out") or [None])[0]
+    select = _select(ins, attrs, mask, causal, dropout)
     if _route(ctx, q, v, mask, attrs)[0] != "flash" or dout is None \
             or not outs.get("Out") or not outs.get("Lse"):
         return None
@@ -200,14 +232,15 @@ def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
         dq, dk, dv = flash_attention_bwd(
             q, k, v, out, lse.reshape(b * nh, s), dout.astype(out.dtype),
             scale=scale, causal=causal, dropout=dropout, seed=seed,
-            mask=mask, window=_window(attrs))
+            mask=mask, window=_window(attrs), select=select)
     except Exception as e:
         raise _flash_failed(e, q, mask, causal, dropout) from e
     metrics.inc("attention.flash_bwd_residual")
     return {"Q": [dq], "K": [dk], "V": [dv]}
 
 
-@register("fused_attention", is_random=True, nondiff_slots=("Mask",),
+@register("fused_attention", is_random=True,
+          nondiff_slots=("Mask", "Select"),
           grad=_fused_attention_grad, residual_slots=("Out", "Lse"))
 def _fused_attention(ctx, ins, attrs):
     q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
@@ -215,6 +248,10 @@ def _fused_attention(ctx, ins, attrs):
     b, nh, s, _ = q.shape
     route, sp_fn = _route(ctx, q, v, mask, attrs)
     window = _window(attrs)
+    select = _select(ins, attrs, mask, causal, dropout)
+    if select is not None:
+        return _selected_attention(ctx, q, k, v, select, scale, route,
+                                   bool(attrs.get("return_target")))
     if route == "sp":
         if k.shape[1] != nh:
             from ..observability import metrics
@@ -253,6 +290,44 @@ def _fused_attention(ctx, ins, attrs):
         mask = tri if mask is None else mask + tri
     return {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)],
             "Lse": [_no_lse(q)]}
+
+
+def _selected_attention(ctx, q, k, v, select, scale, route, want_target):
+    """`fused_attention` over a selection: {"Out", "Lse"[, "Target"]}."""
+    from ..observability import metrics
+    b, nh, s, _ = q.shape
+    if route == "sp":
+        raise NotImplementedError(
+            "fused_attention: the ring / Ulysses routes have no selection")
+    count = not (ctx.is_eval_shape or ctx.in_vjp)
+    if route == "flash":
+        from .pallas.flash_attention import (flash_attention,
+                                             selected_probs_sum)
+        try:
+            out, lse = flash_attention(q, k, v, scale=scale, causal=True,
+                                       return_lse=True, select=select)
+        except Exception as e:
+            raise _flash_failed(e, q, None, True, 0.0) from e
+        if count:
+            metrics.inc("attn.sparse_pallas")
+        if ctx.in_vjp:
+            metrics.inc("attention.flash_bwd_recomputed")
+        outs = {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}
+        if want_target:
+            with jax.named_scope("attn.index.target"):
+                outs["Target"] = [selected_probs_sum(
+                    *jax.lax.stop_gradient((q, k, lse)), select,
+                    scale=scale)]
+        return outs
+    if count and not isinstance(q, jax.ShapeDtypeStruct):
+        metrics.inc("attn.sparse_xla")
+    got = _xla_attention(q, k, v, _causal_bias(s, None), scale, 0.0, None,
+                         select, want_target)
+    out, target = got if want_target else (got, None)
+    outs = {"Out": [out], "Lse": [_no_lse(q)]}
+    if want_target:
+        outs["Target"] = [target]
+    return outs
 
 
 def _current_mesh():

@@ -87,14 +87,32 @@ def rotary_frequencies(theta: float, rotary_dim: int, rope_type="default",
     return (1.0 - ramp) * freq + ramp * freq / factor
 
 
-def rotary_half(x, freq, rotary_dim: int, scale: float):
+def stream_angles(positions, freq, sections, ndim: int):
+    """Angles [B, (1,) S, half] of x [B, (heads,) S, D] under several
+    position streams (Qwen2-VL's multimodal rotary rule): `positions`
+    [streams, B, S]; the pairs in order fall into `sections` (their sizes,
+    summing to half), and pair j turns by the position of ITS section's
+    stream times freq[j]."""
+    if sum(sections) != len(freq) or len(sections) != positions.shape[0]:
+        raise ValueError(
+            f"rotary_embedding: sections {list(sections)} must name one "
+            f"size a stream of Positions {tuple(positions.shape)} and sum "
+            f"to the {len(freq)} pairs")
+    stream = np.repeat(np.arange(len(sections)), sections)       # [half]
+    pos = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., stream]
+    ang = pos * jnp.asarray(freq, jnp.float32)                # [B, S, half]
+    return ang if ndim == 3 else ang[:, None]
+
+
+def rotary_half(x, freq, rotary_dim: int, scale: float, angles=None):
     """Rotate the LAST `rotary_dim` features of x [..., S, D] over the
     half-split pairs (j, j + rotary_dim / 2) by pos * freq[j], cos and sin
-    times `scale`."""
+    times `scale`; `angles` (`stream_angles`) in place of the row's own
+    positions times freq."""
     s, d = x.shape[-2], x.shape[-1]
     half = rotary_dim // 2
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * jnp.asarray(
-        freq, jnp.float32)[None]
+    ang = angles if angles is not None else jnp.arange(
+        s, dtype=jnp.float32)[:, None] * jnp.asarray(freq, jnp.float32)[None]
     cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale    # [S, half]
     rot = x[..., d - rotary_dim:].astype(jnp.float32)
     a, b = rot[..., :half], rot[..., half:]
@@ -105,8 +123,12 @@ def rotary_half(x, freq, rotary_dim: int, scale: float):
     return jnp.concatenate([x[..., :d - rotary_dim], out], axis=-1)
 
 
-@register("rotary_embedding")
+@register("rotary_embedding", nondiff_slots=("Positions",))
 def _rotary_embedding(ctx, ins, attrs):
+    """`Positions` [streams, B, S] (optional, layout "half"): several
+    position streams, the pairs shared out by the attr `sections`; without
+    it the streams are the row's own positions, the sections change no
+    number and the op is what it was."""
     x = ins["X"][0]
     theta = float(attrs.get("theta", 10000.0))
     rotary_dim = int(attrs.get("rotary_dim", x.shape[-1]))
@@ -126,7 +148,10 @@ def _rotary_embedding(ctx, ins, attrs):
         int(attrs.get("original_max_position", 0)),
         float(attrs.get("beta_fast", 32.0)),
         float(attrs.get("beta_slow", 1.0)))
-    return {"Out": [rotary_half(x, freq, rotary_dim, scale)]}
+    if not ins.get("Positions"):
+        return {"Out": [rotary_half(x, freq, rotary_dim, scale)]}
+    return {"Out": [rotary_half(x, freq, rotary_dim, scale, stream_angles(
+        ins["Positions"][0], freq, attrs["sections"], x.ndim))]}
 
 
 @register("swiglu")

@@ -42,6 +42,20 @@ keys i-w+1..i) bounds the loops of all three kernels from both sides, so a
 windowed layer visits the blocks its band meets and no others. With
 nkv == nh and no window the three kernels trace to what they did before
 either existed.
+
+A selection: `select` [B, S, S] int8, 1 where query t attends key s (a
+learned indexer's choice, ops/sparse_index.py), causal, shared by every
+head of a row and so stored once a row: the kernels read the [block_q,
+block_k] tile of it beside each score tile and put -inf where it is 0. It is
+no additive mask (a float [1, 1, S, S] of it would be four times the bytes
+and read once a head) and takes its own path through `flash_attention`
+(`_flash_selected`), so that without it nothing here traces differently.
+The blocks above the diagonal are skipped as in any causal layer; inside
+the triangle a learned selection leaves no block empty, so none is skipped
+(ROADMAP A). `selected_probs_sum` is a fourth kernel: the head-summed
+probabilities on the selected pairs, which the indexer's loss takes as its
+target, from q, k, the forward's lse and the selection, one [block_q, S]
+float32 tile resident while the heads go by; no [B, nh, S, S] array exists.
 """
 from __future__ import annotations
 
@@ -201,13 +215,24 @@ def _kv_index(group):
     return lambda h, i: (h // group, 0, 0)
 
 
+def _selected(s, sel_tile):
+    """Scores with -inf where the selection's int8 tile is 0."""
+    return jnp.where(sel_tile.astype(jnp.int32) != 0, s, -jnp.inf)
+
+
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
-                      dropout, block_k, seq_len, has_mask, window=None):
+                      dropout, block_k, seq_len, has_mask, window=None,
+                      has_select=False):
     # q_ref: [block_q, hd]; k_ref: [S, hd]; v_ref: [S, hd_v];
     # o_ref: [block_q, hd_v]; hd_v may differ from hd (latent attention:
     # q and k 192 wide, v and the output 128)
     # lse_ref: [block_q, 128] (row value broadcast along lanes)
     # mask_ref (if present): [1 or block_q, S] additive bias
+    # sel_ref (if present): [block_q, S] int8, this q block's rows of the
+    # row's selection
+    sel_ref = None
+    if has_select:
+        sel_ref, *rest = rest
     if has_mask:
         mask_ref, o_ref, lse_ref = rest
     else:
@@ -244,6 +269,8 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
+        if sel_ref is not None:
+            s = _selected(s, sel_ref[:, pl.ds(kb * block_k, block_k)])
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         # guard -inf rows (fully-masked): exp(-inf - -inf) -> use safe sub
@@ -300,8 +327,12 @@ def _mask_spec_kgrid(mask, bk, mask_mode, nh):
     return pl.BlockSpec((None, rm, bk), lambda h, j: (bidx(h), 0, j))
 
 
+def _select_bytes(select, rows: int, cols: int) -> int:
+    return 0 if select is None else rows * cols
+
+
 def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
-               mask_mode, window=None):
+               mask_mode, window=None, select=None):
     b, nh, s, hd = q.shape
     nkv, hdv = k.shape[1], v.shape[-1]
     bq = _pick_block(s, block_q)
@@ -313,6 +344,8 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
     kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                                dropout=dropout, block_k=bk, seq_len=s,
                                has_mask=has_mask, window=window)
+    if select is not None:
+        kernel = functools.partial(kernel, has_select=True)
     kv_index = _kv_index(nh // nkv)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -321,6 +354,11 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
         pl.BlockSpec((None, s, hdv), kv_index),
     ]
     operands = [seed, q3, k3, v3]
+    if select is not None:
+        # this q block's rows of the row's selection, whatever the head
+        in_specs.append(pl.BlockSpec((None, bq, s),
+                                     lambda h, i: (h // nh, i, 0)))
+        operands.append(select)
     if has_mask:
         in_specs.append(_mask_spec_qgrid(mask, bq, mask_mode, nh))
         operands.append(mask)
@@ -338,7 +376,7 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
         ],
         compiler_params=_compiler_params(
             s * (_lanes(hd) + _lanes(hdv)) * q.dtype.itemsize
-            + _mask_bytes(mask)),
+            + _mask_bytes(mask) + _select_bytes(select, bq, s)),
         interpret=interpret_mode(),
         name="flash_attention_fwd",
     )(*operands)
@@ -347,9 +385,12 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
 
 def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                          lse_ref, *rest, scale, causal, dropout, block_k,
-                         seq_len, has_mask, window=None):
+                         seq_len, has_mask, window=None, has_select=False):
     # q: [block_q, hd]; do/o: [block_q, hd_v]; k: [S, hd]; v: [S, hd_v];
-    # lse: [block_q, 128]
+    # lse: [block_q, 128]; sel_ref (if present): [block_q, S] int8
+    sel_ref = None
+    if has_select:
+        sel_ref, *rest = rest
     if has_mask:
         mask_ref, dq_ref = rest
     else:
@@ -385,6 +426,8 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
+        if sel_ref is not None:
+            s = _selected(s, sel_ref[:, pl.ds(kb * block_k, block_k)])
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -413,7 +456,8 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
 
 def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                            lse_ref, *rest, scale, causal, dropout, block_q,
-                           seq_len, has_mask, window=None, group=1):
+                           seq_len, has_mask, window=None, group=1,
+                           has_select=False):
     # k: [block_k, hd]; v: [block_k, hd_v]; q: [S, hd]; do/o: [S, hd_v];
     # lse: [S, 128]
     # mask_ref (if present): [1 or S, block_k] — this k block's columns
@@ -422,6 +466,11 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     # ONE query head's and stay resident while its k blocks go by; dk/dv
     # are the KV head's whole [S, hd] in float32, resident over the two
     # inner axes, and take the sum over the group's query heads.
+    # sel_ref (if present): [S, block_k] int8, this k block's columns of
+    # the row's selection
+    sel_ref = None
+    if has_select:
+        sel_ref, *rest = rest
     if has_mask:
         mask_ref, dk_ref, dv_ref = rest
     else:
@@ -463,6 +512,8 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
             k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
+        if sel_ref is not None:
+            s = _selected(s, sel_ref[pl.ds(qb * block_q, block_q), :])
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
         if dropout > 0.0:
             keep = _keep_mask(seed_ref[0], head, qb * block_q,
@@ -518,7 +569,7 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
-               block_q, block_k, mask_mode, window=None):
+               block_q, block_k, mask_mode, window=None, select=None):
     b, nh, s, hd = q.shape
     nkv, hdv = k.shape[1], v.shape[-1]
     group = nh // nkv
@@ -536,6 +587,8 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
                                   causal=causal, dropout=dropout,
                                   block_k=bk, seq_len=s, has_mask=has_mask,
                                   window=window)
+    if select is not None:
+        dq_kernel = functools.partial(dq_kernel, has_select=True)
     dq_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
@@ -546,6 +599,10 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         pl.BlockSpec((None, bq, _LANES), lambda h, i: (h, i, 0)),
     ]
     dq_operands = [seed, q3, k3, v3, do3, o3, lse]
+    if select is not None:
+        dq_specs.append(pl.BlockSpec((None, bq, s),
+                                     lambda h, i: (h // nh, i, 0)))
+        dq_operands.append(select)
     if has_mask:
         dq_specs.append(_mask_spec_qgrid(mask, bq, mask_mode, nh))
         dq_operands.append(mask)
@@ -557,7 +614,7 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
         compiler_params=_compiler_params(
             s * (_lanes(hd) + _lanes(hdv)) * q.dtype.itemsize
-            + _mask_bytes(mask)),
+            + _mask_bytes(mask) + _select_bytes(select, bq, s)),
         interpret=interpret_mode(),
         name="flash_attention_bwd_dq",
     )(*dq_operands)
@@ -566,6 +623,8 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
                                     causal=causal, dropout=dropout,
                                     block_q=bq, seq_len=s, has_mask=has_mask,
                                     window=window)
+    if select is not None:
+        dkdv_kernel = functools.partial(dkdv_kernel, has_select=True)
     dkdv_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((None, s, hd), lambda h, i: (h, 0, 0)),
@@ -576,11 +635,17 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         pl.BlockSpec((None, s, _LANES), lambda h, i: (h, 0, 0)),
     ]
     dkdv_operands = [seed, q3, k3, v3, do3, o3, lse]
+    if select is not None:
+        # under the grouped grid this spec follows the query head, like q
+        dkdv_specs.append(pl.BlockSpec((None, s, bk),
+                                       lambda h, i: (h // nh, 0, i)))
+        dkdv_operands.append(select)
     if has_mask:
         dkdv_specs.append(_mask_spec_kgrid(mask, bk, mask_mode, nh))
         dkdv_operands.append(mask)
     resident = (s * (_lanes(hd) + 2 * _lanes(hdv)) * q.dtype.itemsize
-                + s * _LANES * 4 + _mask_bytes(mask))
+                + s * _LANES * 4 + _mask_bytes(mask)
+                + _select_bytes(select, s, bk))
     if group == 1:
         dk, dv = pl.pallas_call(
             dkdv_kernel,
@@ -671,6 +736,107 @@ def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, window, res,
 _flash.defvjp(_fwd, _bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_selected(q, k, v, seed, select, scale, block_q, block_k):
+    """The causal kernels over a selection; no dropout, mask or window."""
+    return _flash_fwd(q, k, v, seed, None, scale, True, 0.0, block_q,
+                      block_k, None, None, select)
+
+
+def _selected_fwd(q, k, v, seed, select, scale, block_q, block_k):
+    out, lse = _flash_fwd(q, k, v, seed, None, scale, True, 0.0, block_q,
+                          block_k, None, None, select)
+    return (out, lse), (q, k, v, seed, select, out, lse)
+
+
+def _selected_bwd(scale, block_q, block_k, res, cts):
+    import numpy as np
+    q, k, v, seed, select, o, lse = res
+    dq, dk, dv = _flash_bwd(q, k, v, o, lse, cts[0], seed, None, scale, True,
+                            0.0, block_q, block_k, None, None, select)
+    # the selection is a choice, not a number: nothing flows into it
+    return (dq, dk, dv, np.zeros(seed.shape, jax.dtypes.float0),
+            np.zeros(select.shape, jax.dtypes.float0))
+
+
+_flash_selected.defvjp(_selected_fwd, _selected_bwd)
+
+
+def _probs_sum_kernel(q_ref, k_ref, lse_ref, sel_ref, out_ref, *, scale,
+                      block_k, heads):
+    # q: [block_q, hd] of ONE query head; k: [S, hd] of its KV head; lse:
+    # [block_q, 128]; sel: [block_q, S] int8; out: [block_q, S] float32,
+    # resident while the heads (the grid's last axis) go by
+    block_q = q_ref.shape[0]
+    q_idx = pl.program_id(1)
+    head = pl.program_id(2)
+
+    @pl.when(head == 0)
+    def _():
+        out_ref[:] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    q = q_ref[:]
+    lse = lse_ref[:, :1]
+    lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
+
+    def body(kb, _):
+        cols = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+        s = jax.lax.dot_general(q, k_ref[cols, :], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = _selected(jnp.where(q_pos >= k_pos, s, -jnp.inf),
+                      sel_ref[:, cols])
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
+        out_ref[:, cols] += p * (1.0 / heads)
+        return 0
+
+    # the k blocks that meet the causal triangle; the rest stays zero
+    jax.lax.fori_loop(0, ((q_idx + 1) * block_q + block_k - 1) // block_k,
+                      body, 0)
+
+
+def selected_probs_sum(q, k, lse, select, scale=None,
+                       block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+    """The mean over the query heads of the attention probabilities, [B, S,
+    S] float32, zero off the selection: q [B, nh, S, hd], k [B, nkv, S, hd],
+    `lse` [B*nh, S] the logsumexp `flash_attention(..., select=select,
+    return_lse=True)` gave, so each head's row sums to 1 over its selected
+    keys. Each head's scores are taken once more, a tile at a time; the sum
+    over heads stays in VMEM. No gradient is defined: it is a target, and
+    the caller stops the gradient of its operands."""
+    b, nh, s, hd = q.shape
+    nkv = k.shape[1]
+    group = nh // nkv
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
+    bq = _pick_block(s, block_q)
+    bk = _pick_block(s, block_k)
+    lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_LANES,))
+    return pl.pallas_call(
+        functools.partial(_probs_sum_kernel, scale=scale, block_k=bk,
+                          heads=nh),
+        grid=(b, s // bq, nh),
+        in_specs=[
+            pl.BlockSpec((None, bq, hd), lambda r, i, h: (r * nh + h, i, 0)),
+            pl.BlockSpec((None, s, hd),
+                         lambda r, i, h: (r * nkv + h // group, 0, 0)),
+            pl.BlockSpec((None, bq, _LANES),
+                         lambda r, i, h: (r * nh + h, i, 0)),
+            pl.BlockSpec((None, bq, s), lambda r, i, h: (r, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, bq, s), lambda r, i, h: (r, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.float32),
+        compiler_params=_compiler_params(
+            s * _lanes(hd) * q.dtype.itemsize + bq * s * 5
+            + bq * _LANES * 4, ("parallel", "parallel", "arbitrary")),
+        interpret=interpret_mode(),
+        name="selected_probs_sum",
+    )(q.reshape(b * nh, s, hd), k.reshape(b * nkv, s, hd), lse, select)
+
+
 def _normalize_mask(mask, b, nh, s):
     """Additive mask of any shape broadcastable to [B, nh, S, S] (with the
     query dim allowed to be 1) → ([Bm, Rm, S], mask_mode). Key-padding
@@ -694,6 +860,17 @@ def _normalize_mask(mask, b, nh, s):
     if mb == 1:
         return mask[0], "h"
     return mask.reshape(b * nh, mq, s), "bh"
+
+
+def _check_select(q, select, causal, dropout, mask, window):
+    b, _, s, _ = q.shape
+    if not causal or dropout or mask is not None or window is not None:
+        raise ValueError("flash_attention: a selection goes with causal "
+                         "attention alone (no dropout, mask or window)")
+    if select.shape != (b, s, s) or select.dtype != jnp.int8:
+        raise ValueError(f"flash_attention: select must be int8 "
+                         f"[{b}, {s}, {s}], got {select.dtype}"
+                         f"{tuple(select.shape)}")
 
 
 def _kernel_args(q, k, v, scale, dropout, seed, mask, causal, window):
@@ -728,12 +905,13 @@ def _kernel_args(q, k, v, scale, dropout, seed, mask, causal, window):
 def flash_attention(q, k, v, scale=None, causal=False,
                     block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
                     dropout=0.0, seed=None, mask=None, return_lse=False,
-                    window=None):
+                    window=None, select=None):
     """Tiled attention of q [B, nh, S, hd] over k, v [B, nkv, S, hd], nkv
     dividing nh (query head h reads KV head h // (nh / nkv)); with `causal`
     a `window` w lets a query at i see keys i-w+1..i only. `dropout` drops
     post-softmax probs with an in-kernel counter-based mask keyed on `seed`
-    (traced int32 scalar/array ok);
+    (traced int32 scalar/array ok); `select` [B, S, S] int8 keeps, for every
+    head of a row, the pairs where it is 1 (causal attention only);
     `mask` is an additive bias broadcastable to [B, nh, S(or 1), S] applied
     to the scaled scores inside all three kernels. With `return_lse` the
     result is (out, lse): lse is the forward kernel's per-row logsumexp,
@@ -744,14 +922,20 @@ def flash_attention(q, k, v, scale=None, causal=False,
     pass."""
     scale, seed, mask, mask_mode = _kernel_args(
         q, k, v, scale, dropout, seed, mask, causal, window)
-    out, lse = _flash(q, k, v, seed, mask, scale, causal, float(dropout),
-                      block_q, block_k, mask_mode, window)
+    if select is not None:
+        _check_select(q, select, causal, dropout, mask, window)
+        out, lse = _flash_selected(q, k, v, seed, select, scale, block_q,
+                                   block_k)
+    else:
+        out, lse = _flash(q, k, v, seed, mask, scale, causal, float(dropout),
+                          block_q, block_k, mask_mode, window)
     return (out, lse[:, :, 0]) if return_lse else out
 
 
 def flash_attention_bwd(q, k, v, out, lse, dout, scale=None, causal=False,
                         block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                        dropout=0.0, seed=None, mask=None, window=None):
+                        dropout=0.0, seed=None, mask=None, window=None,
+                        select=None):
     """(dq, dk, dv) from the residuals a forward launch already wrote: the
     two backward kernels alone, with the arguments `flash_attention` took.
     What `jax.vjp(flash_attention)` computes after running the forward
@@ -763,5 +947,8 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale=None, causal=False,
     # the forward and keeps all [B*nh, S, 128] copies alive until here
     lse, dout = jax.lax.optimization_barrier((lse, dout))
     lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_LANES,))
+    if select is not None:
+        _check_select(q, select, causal, dropout, mask, window)
     return _flash_bwd(q, k, v, out, lse, dout, seed, mask, scale, causal,
-                      float(dropout), block_q, block_k, mask_mode, window)
+                      float(dropout), block_q, block_k, mask_mode, window,
+                      select)

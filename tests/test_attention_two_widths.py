@@ -127,6 +127,42 @@ def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
     assert text.count("tpu_custom_call") >= 3
 
 
+def test_selection_kernels_compile_for_a_v5e_at_the_cells_size(v5e,
+                                                               monkeypatch):
+    """The learned-selection cell's attention (32 query heads on 4 KV heads
+    of 128, one row of 8,192, bf16): the three kernels with the selection's
+    int8 tile beside each score tile, and `selected_probs_sum`. Mosaic has
+    to take the int8 tiles (`[256, 8192]` rows of a q block, `[8192, 512]`
+    columns of a k block) and their VMEM."""
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    s = 8192
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def step(q, k, v, do, select):
+        o, lse = fa.flash_attention(q, k, v, causal=True, return_lse=True,
+                                    select=select)
+        return fa.flash_attention_bwd(q, k, v, o, lse, do, causal=True,
+                                      select=select), fa.selected_probs_sum(
+            q, k, lse, select)
+
+    try:
+        text = jax.jit(step).trace(
+            sd((1, 32, s, 128)), sd((1, 4, s, 128)), sd((1, 4, s, 128)),
+            sd((1, 32, s, 128)), sd((1, s, s), jnp.int8)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkdv", "selected_probs_sum"):
+        assert text.count(kernel), kernel
+    assert text.count("tpu_custom_call") >= 4
+    assert "s8[1,8192,8192]" in text and "[1,32,8192,8192]" not in text
+
+
 # rows a layer, d, f, held experts of the benchmark's three sparse cells, and
 # whether the experts have a gate (3 + 6 grouped matmuls a layer, or 2 + 4)
 _EXPERT_SHAPES = {

@@ -11,7 +11,8 @@ import pytest
 
 from causal_lm_harness import causal_lm, tiny_step_digests
 
-from paddle_tpu.models import bert, deepseek_v3, ling, mellum, nemotron_h
+from paddle_tpu.models import (bert, deepseek_v3, keye, ling, mellum,
+                               nemotron_h)
 
 
 def _bert_pretrain():
@@ -42,6 +43,10 @@ _STEPS = {
     "ling": (causal_lm(ling, ling.LingConfig.tiny()),
         "60c17202fc73f82ce61d96a22f7176830b0d9bb93b04242555d4f6854dc22072",
         "ac230eeb40b2e1abfbc7711572c415559b6cfc9c4cb4c278dbf823c2028a8cdf"),
+    # made at PR 43, which brought the builder: held from here on
+    "keye": (causal_lm(keye, keye.KeyeConfig.tiny()),
+        "e7f1483278b20170f7207a64a84339842724a53e758ffd5fb9e27d04a6110e51",
+        "181edc8cec578935a222564d31587f4ce7147b7bb9563acd3a6d35c0eb07ea5d"),
 }
 
 
