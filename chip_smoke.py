@@ -26,6 +26,9 @@ published width of models the repo already has:
                                `jax.numpy` form on the same operands
   kda_scan                     the gated delta rule's two chunk kernels at
                                the linear-attention cell's shape, likewise
+  index_scores                 the sparse-attention indexer's two score
+                               kernels at the learned-selection cell's
+                               shape, likewise
   four_chips                   the first trainer on every visible device
                                (dp=N), replicated and ZeRO-1; runs when JAX
                                finds at least four
@@ -81,6 +84,9 @@ FULL = {
     # one linear-attention layer's gated delta rule at the KDA cell's size:
     # 1 x 8,192 positions, 16 heads of 128, chunks of 64
     "delta": {"b": 1, "s": 8192, "h": 16, "d": 128, "chunk": 64},
+    # one layer's indexer at the learned-selection cell's size: 16 heads of
+    # 64 over 1 x 8,192 positions, 2,048 keys a query
+    "index": {"b": 1, "h": 16, "s": 8192, "d": 64, "topk": 2048},
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -103,6 +109,7 @@ TINY = {
     "scan": {"b": 1, "s": 256, "h": 4, "p": 64, "g": 2, "n": 128,
              "chunk": 128},
     "delta": {"b": 1, "s": 128, "h": 2, "d": 128, "chunk": 64},
+    "index": {"b": 1, "h": 2, "s": 256, "d": 64, "topk": 40},
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -688,10 +695,13 @@ def _parity(got, want):
     check(g.shape == w.shape and g.dtype == w.dtype,
           f"shape/dtype {g.shape} {g.dtype} vs {w.shape} {w.dtype}")
     gf, wf = g.astype(np.float64), w.astype(np.float64)
-    check(np.isfinite(gf).all(), "kernel produced non-finite values")
+    # an infinity of the reference (a masked score) is the kernel's too
+    at = np.isfinite(wf)
+    check(np.isfinite(gf[at]).all() and np.array_equal(gf[~at], wf[~at]),
+          "kernel produced non-finite values")
     return {"bitwise": g.tobytes() == w.tobytes(),
-            "max_abs_diff": float(np.max(np.abs(gf - wf))),
-            "max_abs_ref": float(np.max(np.abs(wf)))}
+            "max_abs_diff": float(np.max(np.abs(gf[at] - wf[at]))),
+            "max_abs_ref": float(np.max(np.abs(wf[at])))}
 
 
 # what the chip may differ by when parity is not bitwise (max |diff| over
@@ -1090,6 +1100,75 @@ def leg_kda_scan(preset, clock):
 
 
 # ---------------------------------------------------------------------------
+# the sparse-attention indexer's score kernels
+# ---------------------------------------------------------------------------
+def index_scores_forms(b, h, s, d, topk, blocks=None, time_xla=True,
+                       launches=10, seed=13):
+    """`ops/pallas/index_scores.py`'s two kernels on one layer's operands
+    in bf16 (the weights and the cotangent float32) beside
+    `ops/sparse_index.py`'s `jax.numpy` form on the same operands: each
+    result's gap over the largest reference value, the host-clock
+    milliseconds a launch of each (a time only on a chip), and the kernels'
+    shares of the least time their bytes take (QI, KI, W and the `[S, S]`
+    float32 array once each way) and of the least time the causal pairs'
+    products take at the chip's bf16 rate (one forward, three backward;
+    `None` off a chip). The cotangent is zero off the selection of `topk`
+    keys a query, as the indexer's loss gives it. `blocks` (queries a tile,
+    keys a tile, query rows an inner step) in place of the shape rule's
+    own: the sweep's handle, which times the `jax.numpy` form once
+    (`time_xla`)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import sparse_index
+    from paddle_tpu.ops.pallas import index_scores
+
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(b, h, s, d) * d ** -0.25, jnp.bfloat16)
+    k = jnp.asarray(rng.randn(b, s, d) * d ** -0.25, jnp.bfloat16)
+    w = jnp.asarray(rng.randn(b, s, h) * h ** -0.5, jnp.float32)
+    plan = index_scores.plan(q.shape, q.dtype, blocks)
+    check(plan is not None, f"QI {(b, h, s, d)} in blocks {blocks}: the "
+          "kernels do not take the shape")
+    forward = {
+        "kernel": jax.jit(lambda *a: (index_scores.scores_fwd(plan, *a),)),
+        "xla": jax.jit(lambda *a: (sparse_index._index_fwd(*a, topk)[0],))}
+    backward = {
+        "kernel": jax.jit(lambda *a: index_scores.scores_bwd(plan, *a)),
+        "xla": jax.jit(sparse_index._scores_bwd)}
+    ds = jax.jit(lambda scores, noise: jnp.where(
+        sparse_index.select_topk(scores, topk) != 0, noise, 0.0) / s)(
+            forward["xla"](q, k, w)[0],
+            jnp.asarray(rng.randn(b, s, s), jnp.float32))
+    rows_bytes = q.nbytes + k.nbytes + w.nbytes + ds.nbytes
+    facts = _kernels_beside_form(
+        "index scores", plan,
+        {"fwd": rows_bytes, "bwd": 2 * rows_bytes - ds.nbytes}, time_xla,
+        launches, ("fwd", forward, (q, k, w), ("scores",)),
+        ("bwd", backward, (q, k, w, ds), ("dq", "dk", "dw")))
+    peak = None
+    if jax.devices()[0].platform == "tpu":
+        import bench
+        peak = bench.device_peaks(jax.devices()[0].device_kind)["bf16_flops"]
+    for name, products in (("fwd", 1), ("bwd", 3)):
+        facts[name]["flops_least_share"] = peak and round(
+            products * b * h * d * s * (s + 1) / peak
+            / (1e-3 * facts[name]["ms_kernel"]), 4)
+    return facts
+
+
+def leg_index_scores(preset, clock):
+    facts = index_scores_forms(**preset["index"])
+    if preset["expect_mosaic"]:
+        for name in ("fwd", "bwd"):
+            row = facts[name]
+            print(f"[chip_smoke] index_scores {name}: kernel "
+                  f"{row['ms_kernel']} ms ({row['flops_least_share']} of its "
+                  f"products' least time), jax.numpy form {row['ms_xla']} ms",
+                  flush=True)
+    return facts
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 def leg_four_chips(preset, clock):
@@ -1165,6 +1244,7 @@ LEGS = (("train_bert_base_s128", leg_train_s128),
         ("grouped_matmul", leg_grouped_matmul),
         ("ssm_scan", leg_ssm_scan),
         ("kda_scan", leg_kda_scan),
+        ("index_scores", leg_index_scores),
         ("four_chips", leg_four_chips))
 
 

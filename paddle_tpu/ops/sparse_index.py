@@ -32,6 +32,20 @@ only, and from there `QI`, `KI`, `W` by `sparse_index`'s grad rule
 (`_scores_bwd`: the products once more, a block of queries at a time, no
 [B, H, S, S] array). Nothing reaches `Select`.
 
+The scores and their grad rule have two lowerings, chosen from the
+operands' shapes and dtype alone (`_route`): where the indexer's heads are
+a multiple of 64 wide, a block of 128 or more divides the row and the
+operands are bf16 or float32, the two Pallas kernels of
+`ops/pallas/index_scores.py`, a grid over (batch, query block, key block)
+in which a tile's products of all H heads, their relu and the sum over the
+heads never leave VMEM and the tiles above the diagonal are skipped (the
+selection then reads the finished scores, still a block of `Q_BLOCK`
+queries at a time); everywhere else (every tiny configuration: heads 8
+wide, rows of 40) the `jax.numpy` form, `_index_fwd` / `_scores_bwd`, which
+is also the kernels' specification and their oracle in the tests. Same
+operands, same results either way; `attn.index_pallas` / `attn.index_xla`
+count each lowering that stays in the program.
+
 Dtypes: `QI`, `KI` arrive in the AMP compute dtype like any matmul's
 operands (the op is white-listed, `W` kept float32); every product
 accumulates float32; relu, weighting, the sum over heads, the selection and
@@ -134,16 +148,62 @@ def _scores_bwd(q, k, w, ds):
     return dq.astype(q.dtype), dk.astype(k.dtype), dw.astype(w.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def index_scores_and_select(q, k, w, topk):
+def _route(count, q):
+    """The score kernels' plan where their shape rule takes the operands
+    (`ops/pallas/index_scores.py` `plan`), else None: the `jax.numpy` form
+    above. `count`: whether this trace's call counts, `attn.index_pallas` /
+    `attn.index_xla`, once per forward or backward lowered."""
+    from .pallas import index_scores
+    plan = index_scores.plan(q.shape, q.dtype)
+    if count:
+        from ..observability import metrics
+        metrics.inc("attn.index_xla" if plan is None else "attn.index_pallas")
+    return plan
+
+
+def _routed_fwd(count, q, k, w, topk):
+    """`_index_fwd` by the route the plan chose: the kernel writes the
+    scores whole, the selection reads them a block of `Q_BLOCK` queries at
+    a time."""
+    plan = _route(count, q)
+    if plan is None:
+        return _index_fwd(q, k, w, topk)
+    from .pallas import index_scores
+    b, _, s, _ = q.shape
+    bq = _q_blocks(s)
+    with jax.named_scope("attn.index.score"):
+        scores = index_scores.scores_fwd(plan, q, k, w)
+    with jax.named_scope("attn.index.select"):
+        select = jax.lax.map(
+            lambda lo: select_topk(jax.lax.dynamic_slice_in_dim(
+                scores, lo, bq, axis=1), topk, lo), jnp.arange(0, s, bq))
+    return scores, select.transpose(1, 0, 2, 3).reshape(b, s, s)
+
+
+def _routed_bwd(count, q, k, w, ds):
+    plan = _route(count, q)
+    if plan is None:
+        return _scores_bwd(q, k, w, ds)
+    from .pallas import index_scores
+    with jax.named_scope("attn.index.score"):
+        return index_scores.scores_bwd(plan, q, k, w, ds)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def index_scores_and_select(q, k, w, topk, count=False, relowered=False):
     """(I [B, S, S] float32, -inf above the diagonal; the selection [B, S,
-    S] int8 of `topk` keys a query). A gradient passes through I alone."""
-    return _index_fwd(q, k, w, topk)
+    S] int8 of `topk` keys a query). A gradient passes through I alone.
+    `count`: the route counts (`_route`). `relowered` (the generic
+    `__vjp__` differentiates a whole segment): JAX traces this body to a
+    jaxpr it then replaces by the two rules below, so what is traced here
+    is in no program and does not count."""
+    return _routed_fwd(count and not relowered, q, k, w, topk)
 
 
 index_scores_and_select.defvjp(
-    lambda q, k, w, topk: (_index_fwd(q, k, w, topk), (q, k, w)),
-    lambda topk, res, cts: _scores_bwd(*res, cts[0]))
+    lambda q, k, w, topk, count, relowered: (
+        _routed_fwd(count, q, k, w, topk), (q, k, w)),
+    lambda topk, count, relowered, res, cts: _routed_bwd(count, *res, cts[0]))
 
 
 def index_scores(q, k, w):
@@ -206,7 +266,7 @@ def _sparse_index_grad(ctx, ins, attrs, outs, ogs):
     if ds is None:
         return None
     q, k, w = _unpack(ins)
-    dq, dk, dw = _scores_bwd(q, k, w, ds)
+    dq, dk, dw = _routed_bwd(not ctx.is_eval_shape, q, k, w, ds)
     return {"QI": [dq], "KI": [dk.astype(ins["KI"][0].dtype)], "W": [dw]}
 
 
@@ -217,7 +277,8 @@ def _sparse_index(ctx, ins, attrs):
     topk = int(attrs["topk"])
     if not (ctx.is_eval_shape or ctx.in_vjp):
         metrics.inc("attn.sparse_layers_lowered")
-    scores, select = index_scores_and_select(q, k, w, topk)
+    scores, select = index_scores_and_select(
+        q, k, w, topk, not ctx.is_eval_shape, ctx.in_vjp)
     with jax.named_scope("attn.index.select"):
         pairs = jnp.sum(select, dtype=jnp.float32).reshape(1) / (
             select.shape[0] * select.shape[1])

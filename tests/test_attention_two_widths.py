@@ -10,7 +10,9 @@ their numerics) at the four sparse cells' sizes are at its end, and after
 them the gated delta rule (`ops/kda.py`; tests/test_ling.py has its
 numerics) at the linear-attention cell's and the selective scan's chunk
 kernels (`ops/pallas/ssm_chunk.py`; tests/test_nemotron_h.py has their
-numerics) at the hybrid cell's.
+numerics) at the hybrid cell's, and the indexer's score kernels
+(`ops/pallas/index_scores.py`; tests/test_sparse_index.py has their
+numerics) at the learned-selection cell's.
 """
 import re
 
@@ -27,7 +29,7 @@ from paddle_tpu.observability import metrics
 from paddle_tpu.ops import attention, kda, moe, registry
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import grouped_matmul as gm
-from paddle_tpu.ops.pallas import ssm_chunk
+from paddle_tpu.ops.pallas import index_scores, ssm_chunk
 from paddle_tpu.testing import reset_programs
 
 B, NH, DQK, DV = 1, 2, 192, 128
@@ -337,3 +339,49 @@ def test_the_selective_scan_compiles_for_a_v5e_at_the_cells_size(
     assert not re.search(rf"= bf16\[{b},{s},\d+\][^ ]* (copy|transpose)\(",
                          text)
     assert compiled.memory_analysis().temp_size_in_bytes < 128e6
+
+
+def test_the_indexers_scores_compile_for_a_v5e_at_the_cells_size(
+        v5e, monkeypatch):
+    """`sparse_index`'s forward and its grad rule's backward at one layer
+    of the learned-selection cell (16 indexer heads of 64 over 1 x 8,192
+    positions, bf16 operands, float32 weights, 2,048 keys a query): Mosaic
+    takes both score kernels; the heads' products of a block of queries
+    reach HBM in neither direction, nor does the backward's `g`; what the
+    layer keeps beside its `[S, S]` results stays under the 268 MB that the
+    `jax.numpy` form's block of products alone took."""
+    monkeypatch.setattr(index_scores, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    b, h, s, d, topk = 1, 16, 8192, 64, 2048
+    opdef = registry.get("sparse_index")
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(q, k, w, ds):
+        ctx = registry.LowerCtx(rng_key=None)
+        ins = {"QI": [q], "KI": [k], "W": [w]}
+        outs = opdef.lower(ctx, ins, {"topk": topk})
+        grads = opdef.grad(ctx, ins, {"topk": topk}, {}, {"Scores": [ds]})
+        return (outs["Scores"][0], outs["Select"][0],
+                [grads[slot][0] for slot in ("QI", "KI", "W")])
+
+    counters = ("attn.index_pallas", "attn.index_xla")
+    before = [metrics.get(c) for c in counters]
+    try:
+        compiled = jax.jit(layer).trace(
+            sd((b, h, s, d)), sd((b, s, d)), sd((b, s, h), jnp.float32),
+            sd((b, s, s), jnp.float32)).lower(
+                lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert [metrics.get(c) - v for c, v in zip(counters, before)] == [2, 0]
+    text = compiled.as_text()
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(k.rsplit(".", 1)[0] for k in kernels) \
+        == ["index-scores-bwd", "index-scores-fwd"], kernels
+    assert not re.search(rf"\[({b},)?{h},\d+,{s}\]", text)
+    assert f"s8[{b},{s},{s}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256e6

@@ -306,7 +306,8 @@ def test_builder_names_scopes_and_checkpoints_and_verifies():
 
 
 _COUNTERS = ("attn.sparse_layers_lowered", "attn.sparse_pallas",
-             "attn.sparse_xla", "attention.flash_bwd_residual",
+             "attn.sparse_xla", "attn.index_pallas", "attn.index_xla",
+             "attention.flash_bwd_residual",
              "attention.flash_bwd_recomputed", "moe.layers_lowered")
 
 
@@ -317,12 +318,17 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, monkeypatch):
     AMP train step lowers two indexers and two selected attentions to the
     kernels, with the target's kernel beside each; the backward takes the
     forward's residuals (plain) or the segment is lowered again
-    (recompute: the selection is found again). The step's jaxpr holds no
-    [B, heads, S, S] array and the selection once a row, as int8."""
+    (recompute: the selection is found again). The indexer's heads are 64
+    wide, which its score kernels take: forward and backward of each layer
+    count once (recompute: the forward once more), none falls to the
+    `jax.numpy` form. The step's jaxpr holds no [B, heads, S, S] array,
+    the indexer's heads' products [B, 2, block, S] among them, and the
+    selection once a row, as int8."""
     monkeypatch.setattr(attention, "_use_pallas",
                         lambda q: q.shape[2] % 128 == 0)
     cfg = keye.KeyeConfig.tiny()
     cfg.seq_len, cfg.head_dim, cfg.index_topk = 128, 64, 40
+    cfg.indexer_head_dim = 64
     cfg.num_attention_heads, cfg.num_key_value_heads = 6, 2
     cfg.hidden_size, cfg.moe_intermediate_size = 128, 256
     cfg.mrope_section = (8, 12, 12)
@@ -332,6 +338,7 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, monkeypatch):
     assert dict(zip(_COUNTERS, rise)) == {
         "attn.sparse_layers_lowered": 2, "attn.sparse_pallas": 2,
         "attn.sparse_xla": 0,
+        "attn.index_pallas": 6 if recompute else 4, "attn.index_xla": 0,
         "attention.flash_bwd_residual": 0 if recompute else 2,
         "attention.flash_bwd_recomputed": 2 if recompute else 0,
         "moe.layers_lowered": 2}
@@ -339,8 +346,12 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, monkeypatch):
     assert jaxpr.count("name=flash_attention_fwd") >= 2 * (1 + recompute)
     assert jaxpr.count("name=flash_attention_bwd") == 4
     assert jaxpr.count("name=selected_probs_sum") >= 2 * (1 + recompute)
+    # (the printer names an inner jit's jaxpr once, however many call it)
+    assert "name=index-scores-fwd" in jaxpr
+    assert "name=index-scores-bwd" in jaxpr
     assert "i8[1,128,128]" in jaxpr
     assert "[1,6,128,128]" not in jaxpr and "[1,2,3,128,128]" not in jaxpr
+    assert "f32[1,2,128,128]" not in jaxpr
 
 
 def test_the_selection_gauge_is_the_counts_mean():

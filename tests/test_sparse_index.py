@@ -5,8 +5,10 @@ interpreter, `rotary_embedding`'s position streams): the selection against
 `jax.lax.top_k` (rows shorter than `topk`, planted ties), the scores and
 their grad rule against the plain reference's equations
 (`benchmark/reference/keye_vl2.py`), the selected attention against a plain
-masked softmax forward and backward, which gradients exist and which do not.
-The model that uses them is held in `tests/test_keye.py`.
+masked softmax forward and backward, which gradients exist and which do not;
+the indexer's two score kernels (`ops/pallas/index_scores.py`) under the
+interpreter against the `jax.numpy` form, and the shape rule that routes to
+them. The model that uses them is held in `tests/test_keye.py`.
 """
 import numpy as np
 import pytest
@@ -19,6 +21,7 @@ from causal_lm_harness import counter_rise, run_op
 
 from paddle_tpu.ops import attention, llm_ops, registry, sparse_index
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import index_scores
 from benchmark.reference import keye_vl2 as ref
 
 
@@ -148,6 +151,132 @@ def test_the_loss_is_the_kl_to_the_target_and_moves_the_scores_alone():
     assert not np.asarray(dt).any()
     opdef = registry.get("sparse_index_loss")
     assert {"Select", "Target"} <= opdef.nondiff_slots
+
+
+# ---------------------------------------------------------------------------
+# the score kernels, interpreted, against the `jax.numpy` form
+# ---------------------------------------------------------------------------
+
+_KERNEL_SHAPES = {
+    # (b, h, s, d), dtype, (queries a tile, keys a tile, rows an inner step)
+    "batch_2_float32": ((2, 3, 256, 64), jnp.float32, (128, 128, 32)),
+    # 384 = 3 x 128: the rule's own blocks
+    "three_blocks_bf16": ((1, 2, 384, 64), jnp.bfloat16, None),
+    # a key tile twice a query tile: the second query block's rows end
+    # inside the tile the first one's ended in
+    "wide_key_tile_bf16": ((1, 2, 512, 128), jnp.bfloat16, (128, 256, 32)),
+    "wide_query_tile_float32": ((1, 4, 512, 64), jnp.float32, (256, 128, 64)),
+}
+
+
+def _kernel_case(name):
+    shape, dtype, blocks = _KERNEL_SHAPES[name]
+    q, k, w = _indexer_inputs(8, *shape)
+    q, k = q.astype(dtype), k.astype(dtype)
+    plan = index_scores.plan(shape, dtype, blocks)
+    assert plan is not None and shape[2] // plan.block_q > 1 \
+        and shape[2] // plan.block_k > 1
+    return plan, q, k, w, int(0.3 * shape[2])
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_SHAPES))
+def test_the_forward_kernel_scores_as_the_form_does(case):
+    """`Scores` of the kernel against `_index_fwd`'s: -inf above the
+    diagonal and nowhere else, the finite ones equal to 1e-6 of the largest
+    (the heads are summed in another order), and the selection made from
+    them the same set."""
+    plan, q, k, w, topk = _kernel_case(case)
+    want, select = sparse_index._index_fwd(q, k, w, topk)
+    got = index_scores.scores_fwd(plan, q, k, w)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    got, want = np.asarray(got), np.asarray(want)
+    s = got.shape[-1]
+    above = np.triu(np.ones((s, s), bool), 1)
+    assert np.isneginf(got[:, above]).all()
+    assert np.isfinite(got[:, ~above]).all()
+    np.testing.assert_allclose(got[:, ~above], want[:, ~above], rtol=0,
+                               atol=1e-6 * np.abs(want[:, ~above]).max())
+    np.testing.assert_array_equal(
+        sparse_index.select_topk(jnp.asarray(got), topk), select)
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_SHAPES))
+def test_the_backward_kernel_is_the_forms_grad_rule(case):
+    """dQI, dKI, dW of the kernel against `_scores_bwd`'s at a cotangent
+    that is zero off a selection (and, planted, nonzero above the diagonal,
+    which both drop), in the operands' dtypes."""
+    plan, q, k, w, topk = _kernel_case(case)
+    select = sparse_index.select_topk(sparse_index.index_scores(q, k, w),
+                                      topk)
+    cot = np.where(np.asarray(select) != 0, np.random.RandomState(9).randn(
+        *select.shape), 0.0).astype(np.float32)
+    cot[:, 0, -1] = 1.0
+    cot = jnp.asarray(cot)
+    got = index_scores.scores_bwd(plan, q, k, w, cot)
+    want = sparse_index._scores_bwd(q, k, w, cot)
+    tol = 2e-6 if q.dtype == jnp.float32 else 4e-3    # a bf16 last place
+    for name, a, b in zip(("dQI", "dKI", "dW"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert harness.rel_gap(a, b) < tol and np.linalg.norm(
+            np.asarray(b, np.float32)) > 0, name
+
+
+@pytest.mark.parametrize("shape, dtype, takes", [
+    ((1, 16, 8192, 64), jnp.bfloat16, True),        # the cell's
+    ((2, 4, 1024, 128), jnp.float32, True),
+    ((1, 2, 40, 8), jnp.float32, False),            # a head 8 wide
+    ((1, 2, 200, 64), jnp.bfloat16, False),         # no block divides 200
+    ((1, 2, 256, 96), jnp.bfloat16, False),         # a head no multiple of 64
+    ((1, 2, 256, 64), jnp.float16, False),
+    ((1, 64, 8192, 512), jnp.bfloat16, False)],     # more than VMEM holds
+    ids=["cell", "float32", "head_8", "row_200", "head_96", "float16",
+         "vmem"])
+def test_the_plan_takes_what_the_kernels_can_tile(shape, dtype, takes):
+    plan = index_scores.plan(shape, dtype)
+    assert (plan is not None) == takes
+    if takes:
+        assert shape[2] % plan.block_q == 0 and plan.block_q >= 128
+        assert plan.resident_bytes <= index_scores.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("shape, route", [
+    ((1, 2, 256, 64), "attn.index_pallas"),
+    ((1, 2, 40, 8), "attn.index_xla"),
+    ((1, 2, 200, 64), "attn.index_xla")],
+    ids=["taken", "head_8", "row_200"])
+def test_the_op_counts_the_route_the_plan_chose(shape, route):
+    """Forward and grad rule each count once, by the plan's choice; the
+    other route's counter stays; either route gives the form's results."""
+    q, k, w = _indexer_inputs(10, *shape)
+    counters = ["attn.index_pallas", "attn.index_xla"]
+    want = [int(route == c) for c in counters]
+    (scores, select), rise = counter_rise(
+        lambda: run_op("sparse_index", {"QI": q, "KI": k, "W": w},
+                       ["Scores", "Select"], {"topk": 16}), counters)
+    assert list(rise) == want
+    form, form_select = sparse_index._index_fwd(q, k, w, 16)
+    on = np.isfinite(np.asarray(form))
+    np.testing.assert_allclose(scores[on], np.asarray(form)[on], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(select, form_select)
+    cot = jnp.where(jnp.asarray(select) != 0, 1.0, 0.0)
+    opdef = registry.get("sparse_index")
+    got, rise = counter_rise(
+        lambda: opdef.grad(registry.LowerCtx(rng_key=None),
+                           {"QI": [q], "KI": [k], "W": [w]}, {"topk": 16},
+                           {}, {"Scores": [cot]}), counters)
+    assert list(rise) == want
+    for slot, ref_val in zip(("QI", "KI", "W"),
+                             sparse_index._scores_bwd(q, k, w, cot)):
+        assert harness.rel_gap(got[slot][0], ref_val) < 2e-6, slot
+    # differentiated by JAX (a recomputed segment): the forward's rule and
+    # the backward's count once each, the discarded primal trace does not
+    _, rise = counter_rise(
+        lambda: jax.grad(lambda q: jnp.sum(jnp.where(
+            select != 0, sparse_index.index_scores_and_select(
+                q, k, w, 16, count=True, relowered=True)[0], 0.0)))(q),
+        counters)
+    assert list(rise) == [2 * n for n in want]
 
 
 # ---------------------------------------------------------------------------
