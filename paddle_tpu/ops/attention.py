@@ -204,6 +204,17 @@ def _no_lse(q):
     return jnp.zeros(q.shape[:2] + (0,), jnp.float32)
 
 
+def _count_causal_blocks(s, window):
+    """At trace time, per causal flash forward lowered: the (q block, k
+    block) pairs a head visits, by whether a position can mask a score
+    there (`edge`) or the kernels run their bare loop body (`interior`)."""
+    from .pallas.flash_attention import causal_block_counts
+    from ..observability import metrics
+    interior, edge = causal_block_counts(s, window)
+    metrics.inc("attention.flash_blocks_interior", interior)
+    metrics.inc("attention.flash_blocks_edge", edge)
+
+
 def _flash_failed(e, q, mask, causal, dropout):
     return RuntimeError(
         f"pallas flash attention failed for q{tuple(q.shape)} "
@@ -280,6 +291,8 @@ def _fused_attention(ctx, ins, attrs):
                     else "attention.flash_full")
         if k.shape[1] != nh:
             metrics.inc("attention.flash_kv_grouped")
+        if causal:
+            _count_causal_blocks(s, window)
         if ctx.in_vjp:
             # the generic __vjp__ (a whole segment under recompute or layer
             # scan) lowers this forward a second time to differentiate it
@@ -310,6 +323,7 @@ def _selected_attention(ctx, q, k, v, select, scale, route, want_target):
             raise _flash_failed(e, q, None, True, 0.0) from e
         if count:
             metrics.inc("attn.sparse_pallas")
+        _count_causal_blocks(s, None)
         if ctx.in_vjp:
             metrics.inc("attention.flash_bwd_recomputed")
         outs = {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}

@@ -40,8 +40,24 @@ gets a group axis that sums the group's query heads into one float32
 dK / dV, resident for the KV head. A causal `window` w (a query at i sees
 keys i-w+1..i) bounds the loops of all three kernels from both sides, so a
 windowed layer visits the blocks its band meets and no others. With
-nkv == nh and no window the three kernels trace to what they did before
-either existed.
+nkv == nh and no window the three kernels trace to the jaxpr
+tests/test_mellum.py pins, which has no trace of either.
+
+Which blocks are masked. A causal kernel's inner loop is cut by what a mask
+can do to a block (`_loop_ranges`: from the block sizes, `window` and the
+grid index alone), the ranges run in ascending order so that every sum keeps
+its order: the blocks the diagonal crosses and, with a window, the blocks
+its trailing edge crosses run the whole body (`edge`); the blocks between
+them, where every (query, key) pair is visible (56 of a head's 72 visits at
+S = 4096 with blocks of 256 x 512, 240 of 272 at 8192), run the same body
+with no positions built, compared or selected on, and, where no additive
+mask and no selection rides in, without the guards that are the identity on
+finite scores. A launch that is not causal has one range and its mask is
+data, as before. So that cutting a loop costs nothing, the kernels' large
+sums (the output's, dq, dk, dv) are VMEM scratch, written in place block by
+block, and not loop carries (what a loop carries is copied where it ends),
+and a range whose length is known and short, the diagonal's one or two
+blocks, is written out without a loop (`_run_ranges`).
 
 A selection: `select` [B, S, S] int8, 1 where query t attends key s (a
 learned indexer's choice, ops/sparse_index.py), causal, shared by every
@@ -65,6 +81,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -200,11 +217,101 @@ def _visible(q_pos, k_pos, window):
     return seen
 
 
-def _first_k_block(q_idx, block_q, block_k, window):
-    """First k block that q block `q_idx` sees."""
+def _loop_ranges(idx, block_q, block_k, seq_len, causal, window,
+                 over_q=False):
+    """The inner loop of a kernel as [(start, stop, edge, length), ...], in
+    the order the blocks are visited. `idx` is the grid's block: a q block
+    whose loop goes over k blocks, or with `over_q` a k block whose loop
+    goes over q blocks (the dkdv kernel). A range with `edge` holds the
+    blocks that the diagonal or a window's trailing edge
+    (q_pos - k_pos < window) crosses: a mask can change a score there.
+    In the others (interior) every pair is visible, `_visible` is all
+    true. Blocks with no visible pair are in no range. Without `causal`
+    no block is provably either: one range, the whole loop, `edge` (which
+    compares no positions there). `length` is stop - start where every
+    `idx` gives the same (the diagonal's blocks where one block size
+    divides the other: a q block of 256 lies in one k block of 512, a k
+    block of 512 under two q blocks), else None. Integer arithmetic on
+    `idx`, traced (`program_id`) or numpy (the counters, the tests); a
+    range that is empty for every `idx` (no window) is left out."""
+    xp = jnp if isinstance(idx, jax.Array) else np
+    nest = block_k % block_q == 0 or block_q % block_k == 0
+    if over_q:
+        n = seq_len // block_q
+        if not causal:
+            return [(0, n, True, n)]
+        k_start, k_end = idx * block_k, (idx + 1) * block_k
+        # q blocks strictly before this k block see nothing: start at the
+        # first q block whose rows reach k_start
+        start = k_start // block_q
+        if window is None:
+            stop = n
+        else:
+            # the last query that sees this block's last key is window - 1 on
+            stop = xp.minimum(n, (k_end - 1 + window - 1) // block_q + 1)
+        # past the diagonal from the first q block that starts at k_end on
+        lo = xp.minimum((k_end + block_q - 1) // block_q, stop)
+        diagonal = (start, lo, True,
+                    max(block_k // block_q, 1) if nest else None)
+        if window is None:
+            return [diagonal, (lo, stop, False, None)]
+        # inside the band while the q block's last row still sees k_start
+        hi = xp.maximum(xp.minimum((k_start + window) // block_q, stop), lo)
+        return [diagonal, (lo, hi, False, None), (hi, stop, True, None)]
+    n = seq_len // block_k
+    if not causal:
+        return [(0, n, True, n)]
+    q_start, q_end = idx * block_q, (idx + 1) * block_q
+    # only the k blocks that intersect the causal triangle
+    stop = xp.minimum(n, (q_end + block_k - 1) // block_k)
+    # before the diagonal while the k block ends at or before q_start
+    hi = q_start // block_k
+    diagonal = (hi, stop, True, max(block_q // block_k, 1) if nest else None)
     if window is None:
-        return 0
-    return jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
+        return [(0, hi, False, None), diagonal]
+    # the first k block the q block's first row sees
+    first = xp.maximum(q_start - (window - 1), 0) // block_k
+    # inside the band from the first k block the q block's last row sees
+    # whole
+    lo = xp.minimum(xp.maximum(q_end - window + block_k - 1, 0) // block_k,
+                    hi)
+    return [(first, lo, True, None), (lo, hi, False, None), diagonal]
+
+
+# the most blocks of a range of known length that are written out one by
+# one; longer ranges and ranges whose length the grid index decides loop
+_UNROLL = 2
+
+
+def _run_ranges(ranges, body, carry):
+    """`carry = body(block, carry, edge)` over the ranges in turn: the
+    blocks in the order one loop would take. A range of known, short length
+    is written out block by block (a loop's set-up and drain cost as much
+    as part of a block, and the diagonal is one or two); the others are one
+    `fori_loop` each over the body built for the range's kind. The kernels
+    keep their large sums in VMEM scratch and carry at most a column or
+    two: what a loop carries is copied where the loop ends, and the range
+    is cut in two or three."""
+    for start, stop, edge, length in ranges:
+        if length is not None and length <= _UNROLL:
+            for t in range(length):
+                carry = body(start + t, carry, edge=edge)
+        else:
+            carry = jax.lax.fori_loop(
+                start, stop, functools.partial(body, edge=edge), carry)
+    return carry
+
+
+def causal_block_counts(seq_len, window=None, block_q=None, block_k=None):
+    """(interior, edge): the (q block, k block) pairs one head of a causal
+    forward launch visits, of each kind."""
+    bq = _pick_block(seq_len, block_q or DEFAULT_BLOCK_Q)
+    bk = _pick_block(seq_len, block_k or DEFAULT_BLOCK_K)
+    counts = {False: 0, True: 0}
+    for start, stop, edge, _ in _loop_ranges(np.arange(seq_len // bq), bq,
+                                             bk, seq_len, True, window):
+        counts[edge] += int(np.sum(stop - start))
+    return counts[False], counts[True]
 
 
 def _kv_index(group):
@@ -233,11 +340,10 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
     sel_ref = None
     if has_select:
         sel_ref, *rest = rest
+    mask_ref = None
     if has_mask:
-        mask_ref, o_ref, lse_ref = rest
-    else:
-        o_ref, lse_ref = rest
-        mask_ref = None
+        mask_ref, *rest = rest
+    o_ref, lse_ref, acc_ref = rest
     block_q = q_ref.shape[0]
     head = pl.program_id(0)
     q_idx = pl.program_id(1)
@@ -245,15 +351,18 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
     # matmuls ~4x f32); accumulation is f32 via preferred_element_type, and
     # the scale multiplies the f32 scores AFTER the dot
     q = q_ref[:]
+    # a bias or a selection can empty a row of a block no position masks
+    guarded = has_mask or has_select
 
+    # the output's sum is VMEM scratch, written in place block by block
+    # (`_run_ranges`); the running maximum and normalizer, a column each,
+    # are carried
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, v_ref.shape[1]), jnp.float32)
+    acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    num_k_blocks = seq_len // block_k
-
-    def body(kb, carry):
-        m_prev, l_prev, acc = carry
+    def body(kb, carry, edge):
+        m_prev, l_prev = carry
         k = k_ref[pl.ds(kb * block_k, block_k), :]
         v = v_ref[pl.ds(kb * block_k, block_k), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -263,7 +372,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
             # so the row offset here is 0, not q_idx * block_q
             s = s + _mask_block(mask_ref, 0, block_q,
                                 kb * block_k, block_k).astype(jnp.float32)
-        if causal:
+        if causal and edge:
             q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
@@ -273,11 +382,19 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
             s = _selected(s, sel_ref[:, pl.ds(kb * block_k, block_k)])
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        # guard -inf rows (fully-masked): exp(-inf - -inf) -> use safe sub
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+        if edge or guarded:
+            # guard -inf rows (fully-masked): exp(-inf - -inf) -> use safe
+            # sub
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(s - m_safe)
+            p = jnp.where(jnp.isfinite(s), p, 0.0)
+            alpha = jnp.where(jnp.isfinite(m_prev),
+                              jnp.exp(m_prev - m_safe), 0.0)
+        else:
+            # every score finite, so m_new is: exp(-inf - m_new) is the 0
+            # an empty carry takes
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         if dropout > 0.0:
             # drop AFTER the normalizer accumulates: out = dropout(P) @ V
@@ -288,22 +405,15 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale, causal,
         else:
             p_acc = p
         # probs ride the MXU in the value dtype (f32 accumulate)
-        acc_new = acc * alpha + jax.lax.dot_general(
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p_acc.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        return m_new, l_new
 
-    if causal:
-        # only iterate k blocks that intersect the causal triangle
-        last = (q_idx + 1) * block_q
-        n_blocks = jnp.minimum(num_k_blocks,
-                               (last + block_k - 1) // block_k)
-    else:
-        n_blocks = num_k_blocks
-    m, l, acc = jax.lax.fori_loop(
-        _first_k_block(q_idx, block_q, block_k, window), n_blocks, body,
-        (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-30)
+    m, l = _run_ranges(
+        _loop_ranges(q_idx, block_q, block_k, seq_len, causal, window),
+        body, (m0, l0))
+    out = acc_ref[:] / jnp.maximum(l, 1e-30)
     o_ref[:] = out.astype(o_ref.dtype)
     lse = jnp.where(jnp.isfinite(m), m + jnp.log(jnp.maximum(l, 1e-30)),
                     -jnp.inf)
@@ -374,6 +484,7 @@ def _flash_fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
             jax.ShapeDtypeStruct((b * nh, s, hdv), q.dtype),
             jax.ShapeDtypeStruct((b * nh, s, _LANES), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((bq, hdv), jnp.float32)],
         compiler_params=_compiler_params(
             s * (_lanes(hd) + _lanes(hdv)) * q.dtype.itemsize
             + _mask_bytes(mask) + _select_bytes(select, bq, s)),
@@ -391,13 +502,11 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     sel_ref = None
     if has_select:
         sel_ref, *rest = rest
+    mask_ref = None
     if has_mask:
-        mask_ref, dq_ref = rest
-    else:
-        dq_ref, = rest
-        mask_ref = None
+        mask_ref, *rest = rest
+    dq_ref, dq_acc = rest
     block_q = q_ref.shape[0]
-    hd = q_ref.shape[1]
     head = pl.program_id(0)
     q_idx = pl.program_id(1)
     # MXU operands keep the input dtype (bf16 under AMP), f32 accumulate
@@ -409,9 +518,9 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=1, keepdims=True)          # [block_q, 1]
 
-    num_k_blocks = seq_len // block_k
+    guarded = has_mask or has_select
 
-    def body(kb, dq_acc):
+    def body(kb, carry, edge):
         k = k_ref[pl.ds(kb * block_k, block_k), :]
         v = v_ref[pl.ds(kb * block_k, block_k), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -420,7 +529,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
             # q-grid BlockSpec already row-tiled the mask: offset 0 here
             s = s + _mask_block(mask_ref, 0, block_q,
                                 kb * block_k, block_k).astype(jnp.float32)
-        if causal:
+        if causal and edge:
             q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
@@ -428,7 +537,11 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
             s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
         if sel_ref is not None:
             s = _selected(s, sel_ref[:, pl.ds(kb * block_k, block_k)])
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
+        if edge or guarded:
+            p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
+        else:
+            # every score finite, and so every row's lse
+            p = jnp.exp(s - lse_safe)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout > 0.0:
@@ -438,20 +551,17 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                               kb * block_k, block_q, block_k, dropout)
             dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
         ds = p * (dp - delta) * scale
-        return dq_acc + jax.lax.dot_general(
+        dq_acc[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        return carry
 
-    if causal:
-        last = (q_idx + 1) * block_q
-        n_blocks = jnp.minimum(num_k_blocks,
-                               (last + block_k - 1) // block_k)
-    else:
-        n_blocks = num_k_blocks
-    dq = jax.lax.fori_loop(
-        _first_k_block(q_idx, block_q, block_k, window), n_blocks, body,
-        jnp.zeros((block_q, hd), jnp.float32))
-    dq_ref[:] = dq.astype(dq_ref.dtype)
+    # dq's sum is VMEM scratch, nothing is carried
+    dq_acc[:] = jnp.zeros(dq_acc.shape, jnp.float32)
+    _run_ranges(
+        _loop_ranges(q_idx, block_q, block_k, seq_len, causal, window),
+        body, 0)
+    dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
@@ -471,13 +581,11 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     sel_ref = None
     if has_select:
         sel_ref, *rest = rest
+    mask_ref = None
     if has_mask:
-        mask_ref, dk_ref, dv_ref = rest
-    else:
-        dk_ref, dv_ref = rest
-        mask_ref = None
+        mask_ref, *rest = rest
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
     block_k = k_ref.shape[0]
-    hd = k_ref.shape[1]
     if group == 1:
         head = pl.program_id(0)
         k_idx = pl.program_id(1)
@@ -489,10 +597,9 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
     k = k_ref[:]
     v = v_ref[:]
 
-    num_q_blocks = seq_len // block_q
+    guarded = has_mask or has_select
 
-    def body(qb, carry):
-        dk_acc, dv_acc = carry
+    def body(qb, carry, edge):
         q = q_ref[pl.ds(qb * block_q, block_q), :]
         do = do_ref[pl.ds(qb * block_q, block_q), :]
         o = o_ref[pl.ds(qb * block_q, block_q), :]
@@ -506,7 +613,7 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
             # columns already sliced by the BlockSpec; rows here
             s = s + _mask_block(mask_ref, qb * block_q, block_q,
                                 0, block_k).astype(jnp.float32)
-        if causal:
+        if causal and edge:
             q_pos = qb * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
@@ -514,7 +621,11 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
             s = jnp.where(_visible(q_pos, k_pos, window), s, -jnp.inf)
         if sel_ref is not None:
             s = _selected(s, sel_ref[pl.ds(qb * block_q, block_q), :])
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
+        if edge or guarded:
+            p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
+        else:
+            # every score finite, and so every row's lse
+            p = jnp.exp(s - lse_safe)
         if dropout > 0.0:
             keep = _keep_mask(seed_ref[0], head, qb * block_q,
                               k_idx * block_k, block_q, block_k, dropout)
@@ -522,7 +633,7 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
         else:
             p_drop = p
         # dv += dropout(P)^T @ dO : contract over q rows
-        dv_new = dv_acc + jax.lax.dot_general(
+        dv_acc[:] += jax.lax.dot_general(
             p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -530,27 +641,19 @@ def _flash_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
         if dropout > 0.0:
             dp = jnp.where(keep, dp / (1.0 - dropout), 0.0)
         ds = p * (dp - delta) * scale
-        dk_new = dk_acc + jax.lax.dot_general(
+        dk_acc[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+        return carry
 
-    if causal:
-        # q blocks strictly before this k block see nothing: start at the
-        # first q block whose rows reach k_idx * block_k
-        start = (k_idx * block_k) // block_q
-    else:
-        start = 0
-    if window is None:
-        stop = num_q_blocks
-    else:
-        # the last query that sees this block's last key is window - 1 on
-        last_q = (k_idx + 1) * block_k - 1 + (window - 1)
-        stop = jnp.minimum(num_q_blocks, last_q // block_q + 1)
-    dk, dv = jax.lax.fori_loop(
-        start, stop, body,
-        (jnp.zeros((block_k, hd), jnp.float32),
-         jnp.zeros((block_k, v_ref.shape[1]), jnp.float32)))
+    # dk's and dv's sums are VMEM scratch, nothing is carried
+    dk_acc[:] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[:] = jnp.zeros(dv_acc.shape, jnp.float32)
+    _run_ranges(
+        _loop_ranges(k_idx, block_q, block_k, seq_len, causal, window,
+                     over_q=True),
+        body, 0)
+    dk, dv = dk_acc[:], dv_acc[:]
     if group == 1:
         dk_ref[:] = dk.astype(dk_ref.dtype)
         dv_ref[:] = dv.astype(dv_ref.dtype)
@@ -612,6 +715,7 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, bq, hd), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         compiler_params=_compiler_params(
             s * (_lanes(hd) + _lanes(hdv)) * q.dtype.itemsize
             + _mask_bytes(mask) + _select_bytes(select, bq, s)),
@@ -646,6 +750,8 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
     resident = (s * (_lanes(hd) + 2 * _lanes(hdv)) * q.dtype.itemsize
                 + s * _LANES * 4 + _mask_bytes(mask)
                 + _select_bytes(select, s, bk))
+    dkdv_scratch = [pltpu.VMEM((bk, hd), jnp.float32),
+                    pltpu.VMEM((bk, hdv), jnp.float32)]
     if group == 1:
         dk, dv = pl.pallas_call(
             dkdv_kernel,
@@ -659,6 +765,7 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
                 jax.ShapeDtypeStruct((b * nh, s, hd), k.dtype),
                 jax.ShapeDtypeStruct((b * nh, s, hdv), v.dtype),
             ],
+            scratch_shapes=dkdv_scratch,
             compiler_params=_compiler_params(resident),
             interpret=interpret_mode(),
             name="flash_attention_bwd_dkdv",
@@ -693,6 +800,7 @@ def _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal, dropout,
                 jax.ShapeDtypeStruct((b * nkv, s, hd), jnp.float32),
                 jax.ShapeDtypeStruct((b * nkv, s, hdv), jnp.float32),
             ],
+            scratch_shapes=dkdv_scratch,
             compiler_params=_compiler_params(
                 resident + s * (_lanes(hd) + _lanes(hdv)) * 4,
                 ("parallel", "arbitrary", "arbitrary")),
@@ -725,7 +833,6 @@ def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, window, res,
     do, _ = cts     # lse is a residual, not a result: its cotangent is unused
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal,
                             dropout, block_q, block_k, mask_mode, window)
-    import numpy as np
     dseed = np.zeros(seed.shape, dtype=jax.dtypes.float0)
     # the op registry declares Mask nondiff (ops/attention.py nondiff_slots);
     # a zero cotangent keeps custom_vjp's pytree contract satisfied
@@ -750,7 +857,6 @@ def _selected_fwd(q, k, v, seed, select, scale, block_q, block_k):
 
 
 def _selected_bwd(scale, block_q, block_k, res, cts):
-    import numpy as np
     q, k, v, seed, select, o, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, cts[0], seed, None, scale, True,
                             0.0, block_q, block_k, None, None, select)
@@ -779,23 +885,26 @@ def _probs_sum_kernel(q_ref, k_ref, lse_ref, sel_ref, out_ref, *, scale,
     lse = lse_ref[:, :1]
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
 
-    def body(kb, _):
+    def body(kb, _, edge):
         cols = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
         s = jax.lax.dot_general(q, k_ref[cols, :], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = _selected(jnp.where(q_pos >= k_pos, s, -jnp.inf),
-                      sel_ref[:, cols])
+        if edge:
+            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+        s = _selected(s, sel_ref[:, cols])
+        # the selection can empty a row of any block: the guard stays
         p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
         out_ref[:, cols] += p * (1.0 / heads)
         return 0
 
     # the k blocks that meet the causal triangle; the rest stays zero
-    jax.lax.fori_loop(0, ((q_idx + 1) * block_q + block_k - 1) // block_k,
-                      body, 0)
+    _run_ranges(
+        _loop_ranges(q_idx, block_q, block_k, k_ref.shape[0], True, None),
+        body, 0)
 
 
 def selected_probs_sum(q, k, lse, select, scale=None,

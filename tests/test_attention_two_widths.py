@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 
+import flash_harness
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.observability import metrics
@@ -83,6 +84,26 @@ def test_two_widths_flash_matches_dense(s, dtype, tol, monkeypatch):
         assert err < tol, (name, err)
 
 
+@pytest.mark.parametrize("nkv", [4, 1])
+@pytest.mark.parametrize("window", flash_harness.WINDOWS)
+@pytest.mark.parametrize("s, block_q, block_k", flash_harness.GEOMETRIES)
+def test_two_widths_causal_kernels_match_a_plain_masked_softmax(
+        s, block_q, block_k, window, nkv):
+    """The kernels' three loops (tests/test_flash_attention.py has them at
+    one width) with q and k 192 wide, v and the output 128."""
+    flash_harness.check_causal_kernels(s, block_q, block_k, window, nkv=nkv,
+                                       dqk=DQK, dv=DV)
+
+
+@pytest.mark.parametrize("nkv", [4, 1])
+@pytest.mark.parametrize("s, block_q, block_k", flash_harness.GEOMETRIES)
+def test_two_widths_selected_kernels_take_empty_rows(s, block_q, block_k,
+                                                     nkv):
+    flash_harness.check_causal_kernels(
+        s, block_q, block_k, nkv=nkv, dqk=DQK, dv=DV,
+        select=flash_harness.selection_with_empty_rows(s, block_q, block_k))
+
+
 @pytest.fixture(scope="module")
 def v5e():
     from jax.experimental import topologies
@@ -126,6 +147,37 @@ def test_kernels_compile_for_a_v5e_at_the_cells_sizes(v5e, dqk, dv, s, rows,
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
                    "flash_attention_bwd_dkdv"):
         assert text.count(f'{kernel}"') or text.count(kernel), kernel
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_grouped_kernels_compile_for_a_v5e_at_the_cells_size(v5e, window,
+                                                             monkeypatch):
+    """The sliding and the full layer of the window / full cell (32 query
+    heads on 4 KV heads of 128, one row of 8,192, bf16): Mosaic takes the
+    three loops of each kernel, the grouped dkdv grid among them."""
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def sd(heads):
+        return jax.ShapeDtypeStruct((1, heads, 8192, 128), jnp.bfloat16,
+                                    sharding=v5e)
+
+    def step(q, k, v, do):
+        o, lse = fa.flash_attention(q, k, v, causal=True, window=window,
+                                    return_lse=True)
+        return fa.flash_attention_bwd(q, k, v, o, lse, do, causal=True,
+                                      window=window)
+
+    try:
+        text = jax.jit(step).trace(sd(32), sd(4), sd(4), sd(32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkdv"):
+        assert text.count(kernel), kernel
     assert text.count("tpu_custom_call") >= 3
 
 

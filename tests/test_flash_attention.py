@@ -11,6 +11,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import flash_harness
+from flash_harness import GEOMETRIES, WINDOWS
+
 
 def _dense_ref(q, k, v, scale, causal):
     s = jnp.einsum("bnqd,bnkd->bnqk", q.astype(jnp.float32),
@@ -290,3 +293,136 @@ def test_flash_bf16_matches_f32_dense_reference():
     for g, gr in zip(gb, gref):
         np.testing.assert_allclose(np.asarray(g, np.float32),
                                    np.asarray(gr), rtol=0.1, atol=0.25)
+
+
+# ---------------------------------------------------------------------------
+# the causal kernels' three loops: the blocks a mask can change (the
+# diagonal's, a window's trailing edge's) take the whole loop body, the
+# blocks between them one with no position compare and no row guard
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nkv", [4, 1])
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("s, block_q, block_k", GEOMETRIES)
+def test_causal_kernels_match_a_plain_masked_softmax(s, block_q, block_k,
+                                                     window, nkv):
+    flash_harness.check_causal_kernels(s, block_q, block_k, window, nkv=nkv)
+
+
+@pytest.mark.parametrize("nkv", [4, 1])
+@pytest.mark.parametrize("s, block_q, block_k", GEOMETRIES)
+def test_selected_kernels_take_rows_empty_inside_a_bare_block(
+        s, block_q, block_k, nkv):
+    """A selection can empty a row of a block no position masks, so there
+    the guards stay: rows that keep one key, or none, read 0 elsewhere and
+    no NaN, in the three kernels and in `selected_probs_sum`."""
+    flash_harness.check_causal_kernels(
+        s, block_q, block_k, nkv=nkv,
+        select=flash_harness.selection_with_empty_rows(s, block_q, block_k))
+
+
+@pytest.mark.parametrize("nkv", [4, 1])
+def test_a_row_a_windows_edge_empties_meets_bare_blocks_next(nkv):
+    """S 1024 at 256 / 128 under a window of 700: the last q block's loop
+    starts with three blocks the window's trailing edge crosses; its last
+    row sees nothing of the first two, so its running maximum is still
+    -inf when the three bare blocks come, whose `exp(-inf - m)` has to be
+    the 0 the guard gave."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    s, block_q, block_k, window = 1024, 256, 128, 700
+    ranges = fa._loop_ranges(np.int64(3), block_q, block_k, s, True, window)
+    assert ranges == [(0, 3, True, None), (3, 6, False, None),
+                      (6, 8, True, 2)]
+    rows = np.arange(768, 1024)[:, None]
+    assert not fa._visible(rows, np.arange(256)[None], window)[-1].any()
+    flash_harness.check_causal_kernels(s, block_q, block_k, window, nkv=nkv)
+
+
+def _old_range(idx, block_q, block_k, s, window, over_q):
+    """The one loop the kernels ran before the split."""
+    if over_q:
+        stop = s // block_q if window is None else min(
+            s // block_q,
+            ((idx + 1) * block_k - 1 + window - 1) // block_q + 1)
+        return idx * block_k // block_q, stop
+    first = 0 if window is None else max(
+        idx * block_q - (window - 1), 0) // block_k
+    return first, min(s // block_k,
+                      ((idx + 1) * block_q + block_k - 1) // block_k)
+
+
+@pytest.mark.parametrize("over_q", [False, True])
+@pytest.mark.parametrize("window", WINDOWS + [700, 1024])
+@pytest.mark.parametrize("s, block_q, block_k", GEOMETRIES + [
+    (1024, 256, 128), (2048, 256, 512)])
+def test_loop_ranges_tile_the_old_loop_by_what_a_mask_can_do(
+        s, block_q, block_k, window, over_q):
+    """From `_visible` alone, for every block of the grid: the three
+    ranges tile the one range the kernels walked before; a block called
+    interior has no masked pair; an edge block has both kinds; a block in
+    no range has no visible pair; a range that states its length has it
+    under every grid index, and the diagonal's states it where the blocks
+    nest."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    own, other = (block_k, block_q) if over_q else (block_q, block_k)
+    for idx in range(s // own):
+        ranges = fa._loop_ranges(np.int64(idx), block_q, block_k, s, True,
+                                 window, over_q)
+        assert len(ranges) == (2 if window is None else 3)
+        assert [edge for _, _, edge, _ in ranges] == {
+            (False, 2): [False, True], (True, 2): [True, False]}.get(
+                (over_q, len(ranges)), [True, False, True])
+        bounds = [int(ranges[0][0])] + [int(r[1]) for r in ranges]
+        assert bounds == sorted(bounds)
+        assert all(int(a[1]) == int(b[0])
+                   for a, b in zip(ranges, ranges[1:]))
+        stated = [r[3] for r in ranges]
+        assert all(n is None or n == int(r[1]) - int(r[0])
+                   for r, n in zip(ranges, stated))
+        assert stated[0 if over_q else -1] == max(
+            (block_k // block_q) if over_q else (block_q // block_k), 1)
+        assert (bounds[0], bounds[-1]) == _old_range(
+            idx, block_q, block_k, s, window, over_q)
+        kind = {}
+        for start, stop, edge, _ in ranges:
+            kind.update({blk: edge for blk in range(int(start), int(stop))})
+        for blk in range(s // other):
+            qb, kb = (blk, idx) if over_q else (idx, blk)
+            seen = fa._visible(
+                qb * block_q + np.arange(block_q)[:, None],
+                kb * block_k + np.arange(block_k)[None, :], window)
+            if blk not in kind:
+                assert not seen.any(), (idx, blk)
+            elif kind[blk]:
+                assert seen.any() and not seen.all(), (idx, blk)
+            else:
+                assert seen.all(), (idx, blk)
+
+
+def test_loop_ranges_without_causal_are_one_whole_loop():
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa._loop_ranges(np.int64(1), 128, 256, 512, False, None) \
+        == [(0, 2, True, 2)]
+    assert fa._loop_ranges(np.int64(1), 128, 256, 512, False, None, True) \
+        == [(0, 4, True, 4)]
+
+
+@pytest.mark.parametrize("s, window, blocks, want", [
+    (4096, None, (None, None), (56, 16)),     # a latent-attention layer
+    (8192, None, (None, None), (240, 32)),    # a full layer at 8,192
+    (8192, 1024, (None, None), (30, 60)),     # a sliding one
+    (512, None, (None, None), (0, 2)),
+    (512, None, (128, 128), (6, 4)),
+    (512, 300, (128, 128), (3, 7))])
+def test_causal_block_counts(s, window, blocks, want):
+    """The pairs a head's forward visits by kind, at the default blocks
+    (256 / 512) and at others, against a count from `_visible`."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa.causal_block_counts(s, window, *blocks) == want
+    bq = fa._pick_block(s, blocks[0] or fa.DEFAULT_BLOCK_Q)
+    bk = fa._pick_block(s, blocks[1] or fa.DEFAULT_BLOCK_K)
+    pos = np.arange(s)
+    tiles = fa._visible(pos[:, None], pos[None, :], window).reshape(
+        s // bq, bq, s // bk, bk)
+    some, every = tiles.any((1, 3)), tiles.all((1, 3))
+    assert (int(every.sum()), int((some & ~every).sum())) == want

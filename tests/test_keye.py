@@ -308,7 +308,9 @@ def test_builder_names_scopes_and_checkpoints_and_verifies():
 _COUNTERS = ("attn.sparse_layers_lowered", "attn.sparse_pallas",
              "attn.sparse_xla", "attn.index_pallas", "attn.index_xla",
              "attention.flash_bwd_residual",
-             "attention.flash_bwd_recomputed", "moe.layers_lowered")
+             "attention.flash_bwd_recomputed",
+             "attention.flash_blocks_interior", "attention.flash_blocks_edge",
+             "moe.layers_lowered")
 
 
 @pytest.mark.parametrize("recompute", [False, True],
@@ -341,6 +343,9 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, monkeypatch):
         "attn.index_pallas": 6 if recompute else 4, "attn.index_xla": 0,
         "attention.flash_bwd_residual": 0 if recompute else 2,
         "attention.flash_bwd_recomputed": 2 if recompute else 0,
+        # one block of 128 a layer, the diagonal's, each forward lowered
+        "attention.flash_blocks_interior": 0,
+        "attention.flash_blocks_edge": 4 if recompute else 2,
         "moe.layers_lowered": 2}
     # a recomputed segment's forward is in the jaxpr once more
     assert jaxpr.count("name=flash_attention_fwd") >= 2 * (1 + recompute)

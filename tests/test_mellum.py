@@ -407,17 +407,21 @@ def _kernels_jaxpr(causal, masked):
 
 @pytest.mark.parametrize("causal, masked, digest", [
     (True, False,
-     "20132daa1fcc8c4e2804d02cfe0d43f07df47757c71ccc579112daa343d6bd63"),
+     "d043b132e35d17bb5e47cf54f130f6e8373c212d28777b5356f430b1ced56d1e"),
     (False, True,
-     "2e9e8f69b972891b242954e71a32c7873f864bd603afd5bb86b7380808684b4c")],
+     "7a8ac7519efd115275fd4b599ff84389f8875cb81fbf10e34e36b181f3ca3590")],
     ids=["causal", "mask-and-dropout"])
 def test_without_window_and_groups_the_kernels_trace_as_before(
         causal, masked, digest, monkeypatch):
     """`window=None, nkv == nh`: the three kernels' jaxpr, source lines cut,
-    is the one the tree before windows and groups traced (commit ed39f67,
-    jax 0.9.0; the digests were made there). The cells that run these
-    kernels without either must not pay for them. A deliberate change to
-    the kernels changes the digests with it."""
+    holds no trace of a window or of grouped heads: the cells that run
+    these kernels without either must not pay for them. A deliberate change
+    to the kernels changes the digests with it. They are PR 46's (jax
+    0.9.0): the large sums in VMEM scratch and a range of one or two blocks
+    written out without a loop, in both launches; in the causal one the
+    loop over the blocks before the diagonal, whose body compares no
+    positions. Until then they were the tree's before windows and groups
+    (commit ed39f67)."""
     monkeypatch.setattr(fa, "interpret_mode", lambda: False)
     text = _kernels_jaxpr(causal, masked)
     assert text.count("pallas_call") == 3
@@ -430,7 +434,9 @@ def test_without_window_and_groups_the_kernels_trace_as_before(
 
 _COUNTERS = ("attention.flash_window", "attention.flash_full",
              "attention.flash_kv_grouped", "attention.flash_kv_expanded",
-             "attention.flash_bwd_residual", "moe.layers_lowered",
+             "attention.flash_bwd_residual",
+             "attention.flash_blocks_interior", "attention.flash_blocks_edge",
+             "moe.layers_lowered",
              "moe.bwd_residual", "moe.bwd_recomputed",
              "moe.grouped_pallas", "moe.grouped_xla")
 
@@ -492,7 +498,10 @@ def test_a_trace_of_the_step_counts_its_routes(monkeypatch):
     assert dict(zip(_COUNTERS, rise)) == {
         "attention.flash_window": 3, "attention.flash_full": 1,
         "attention.flash_kv_grouped": 4, "attention.flash_kv_expanded": 0,
-        "attention.flash_bwd_residual": 4, "moe.layers_lowered": 4,
+        "attention.flash_bwd_residual": 4,
+        # 128 positions are one block: the diagonal crosses it
+        "attention.flash_blocks_interior": 0, "attention.flash_blocks_edge": 4,
+        "moe.layers_lowered": 4,
         "moe.bwd_residual": 4, "moe.bwd_recomputed": 0,
         "moe.grouped_pallas": 36, "moe.grouped_xla": 0}
     assert jaxpr.count("name=flash_attention_") == 12
