@@ -326,7 +326,7 @@ SPECS: Dict[str, OpSpec] = {
         attr_types={"top_k": int, "routed_scaling": _NUM,
                     "norm_topk": bool, "experts_total": int,
                     "expert_offset": int, "scoring": str, "n_group": int,
-                    "topk_group": int},
+                    "topk_group": int, "norm_topk_eps": _NUM},
         sharding="moe"),
     "rms_norm": OpSpec(
         inputs={"X": ONE, "Scale": OPT}, outputs={"Y": ONE},
@@ -347,6 +347,9 @@ SPECS: Dict[str, OpSpec] = {
     "causal_conv1d": OpSpec(
         inputs={"X": ONE, "W": ONE, "Bias": OPT}, outputs={"Out": ONE},
         attr_types={"activation": str}, sharding="follow_x"),
+    "gated_short_conv": OpSpec(
+        inputs={"X": ONE, "W": ONE}, outputs={"Out": ONE},
+        sharding="follow_x"),
     "ssm_scan": OpSpec(
         inputs={"X": ONE, "B": ONE, "C": ONE, "Dt": ONE, "DtBias": ONE,
                 "ALog": ONE, "D": ONE},

@@ -28,7 +28,8 @@ __all__ = [
     "greater_equal", "logical_and", "logical_or", "logical_not", "logical_xor",
     "where", "cond_take", "unique", "cumsum", "prelu", "brelu",
     "fused_attention", "switch_moe", "routed_moe", "rms_norm",
-    "rotary_embedding", "swiglu", "relu2", "causal_conv1d", "ssm_scan",
+    "rotary_embedding", "swiglu", "relu2", "causal_conv1d",
+    "gated_short_conv", "ssm_scan",
     "gated_group_rms_norm", "l2_norm", "head_gate", "kda_gate", "kda_scan",
     "detach", "sparse_index", "sparse_index_loss",
 ]
@@ -906,14 +907,17 @@ def switch_moe(input, num_experts, d_ff, capacity_factor=1.25, name=None,
 def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
                select_bias=None, routed_scaling=1.0, norm_topk=True,
                experts_total=None, expert_offset=0, scoring="sigmoid",
-               n_group=1, topk_group=1, expert_input=None):
+               n_group=1, topk_group=1, expert_input=None,
+               norm_topk_eps=None):
     """The routed part of a sparse decoder LM's expert layer (DeepSeek-V3
     family; ops/moe.py routed_moe): scores in float32 over ALL
     `experts_total` experts (`gate_w` [d, experts_total]), `scoring`
     "sigmoid" of each logit or "softmax" over all of them, the top_k of
     scores + `select_bias` (a buffer no gradient reaches), weights = the
     scores at those indices, normalised to sum 1 (`norm_topk`) and times
-    `routed_scaling`. `n_group` > 1 limits the selection to groups: the
+    `routed_scaling`; the sum they are divided by gets `norm_topk_eps`
+    added (None: the op's 1e-20). `n_group` > 1 limits the selection to
+    groups: the
     experts in `n_group` equal groups of consecutive ones, a group's score
     the sum of its two highest scores + `select_bias`, the top_k taken
     among the experts of the best `topk_group` groups. No capacity: no
@@ -961,6 +965,8 @@ def routed_moe(input, gate_w, expert_gate, expert_up, expert_down, top_k,
              "expert_offset": int(expert_offset), "scoring": scoring}
     if n_group > 1:
         attrs.update(n_group=int(n_group), topk_group=int(topk_group))
+    if norm_topk_eps is not None:
+        attrs["norm_topk_eps"] = float(norm_topk_eps)
     helper.append_op(
         "routed_moe", inputs=inputs,
         outputs={"Out": [out], "TopIdx": [idx], "ExpertLoad": [load],
@@ -1096,6 +1102,22 @@ def causal_conv1d(input, kernel_size, param_attr=None, bias_attr=None,
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op("causal_conv1d", inputs=inputs, outputs={"Out": [out]},
                      attrs={"activation": activation or ""})
+    return out
+
+
+def gated_short_conv(input, kernel_size, param_attr=None):
+    """The mixer of a gated short-convolution layer between its two
+    projections (ops/ssm.py gated_short_conv): input [B, S, 3C] = [B | C |
+    u], out[t] = C[t] * sum_j W[j] (B * u)[t - (kernel_size - 1) + j],
+    weight [kernel_size, C] a tap a channel, positions before the row's
+    start read as zeros; no bias, no activation. Returns [B, S, C]."""
+    helper = LayerHelper("gated_short_conv")
+    c = int(input.shape[-1]) // 3
+    w = helper.create_parameter(param_attr, [kernel_size, c],
+                                dtype="float32")
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("gated_short_conv", inputs={"X": [input], "W": [w]},
+                     outputs={"Out": [out]})
     return out
 
 

@@ -8,7 +8,7 @@ attention, TP rules) -> gpt.py, and SE-ResNeXt 50/101/152 (the reference's
 canonical dist-test model, grouped convs + squeeze-excitation)
 -> se_resnext.py.
 
-Five sparse causal LMs, each one expert-parallel rank's share of a published
+Six sparse causal LMs, each one expert-parallel rank's share of a published
 configuration, trained: deepseek_v3.py (latent attention, sigmoid-routed
 experts without drops, shared experts), mellum.py (sliding-window and full
 attention in a period, grouped KV heads, yarn, softmax-routed experts),
@@ -21,11 +21,18 @@ causal pair, each query attends its `topk` best-scored keys, all its heads
 the same ones, and the indexer is trained towards the attention's own
 probabilities; the selection is an int8 variable [B, S, S], one a row,
 `layers.sparse_index`'s output and `layers.fused_attention`'s `select`
-input; three-stream rotary positions; softmax-routed experts). What they
-share is written
+input; three-stream rotary positions; softmax-routed experts), lfm2.py
+(gated short-convolution mixers, `C * conv(B * u)` between two projections
+with no attention, 3 : 1 with attention on grouped KV heads under a
+PER-HEAD NORM: q and k RMS-normed over each head's features, one learned
+scale of `head_dim` shared by the heads, before the rotary turn; a TIED
+HEAD: the logits are `norm(x) E^T` with E the token embedding itself, one
+parameter read by a gather and by a matmul, its gradient the sum of both;
+sigmoid-routed experts with a selection bias after leading dense layers).
+What they share is written
 once in causal_lm.py (the leaves, the expert layer around `routed_moe`,
 attention on grouped KV heads, the layer loop, the loss); a model file holds
 its configuration, the mixers of its own and which layer gets what.
 """
 from . import (lenet, resnet, bert, wide_deep, gpt, se_resnext, causal_lm,
-               deepseek_v3, mellum, nemotron_h, ling, keye)
+               deepseek_v3, mellum, nemotron_h, ling, keye, lfm2)

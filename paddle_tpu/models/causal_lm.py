@@ -30,6 +30,7 @@ from .. import layers
 from .. import initializer as I
 from ..framework.program import name_scope
 from ..layer_helper import ParamAttr
+from ..observability import metrics
 from ..observability.trace import RecordEvent
 from ..parallel.mesh import ShardingRules, moe_sharding_rules
 
@@ -44,12 +45,15 @@ def _linear(x, size, name, cfg):
                      bias_attr=False)
 
 
+_EPSILON_KEYS = ("rms_norm_eps", "layer_norm_epsilon", "norm_eps")
+
+
 def _norm(x, name, cfg):
-    """RMS norm; the epsilon under whichever of the two published names the
-    configuration has (`rms_norm_eps`, or `layer_norm_epsilon` in the
-    Nemotron-H family)."""
-    eps = (cfg.rms_norm_eps if hasattr(cfg, "rms_norm_eps")
-           else cfg.layer_norm_epsilon)
+    """RMS norm over the last axis; the epsilon under whichever of the
+    published names the configuration has (`rms_norm_eps`;
+    `layer_norm_epsilon` in the Nemotron-H family, `norm_eps` in LFM2's)."""
+    eps = next(getattr(cfg, key) for key in _EPSILON_KEYS
+               if hasattr(cfg, key))
     return layers.rms_norm(x, epsilon=eps, param_attr=ParamAttr(name=name))
 
 
@@ -74,14 +78,16 @@ def relu2_ffn(x, width, pre, cfg):
 
 def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
                  select_bias=True, gated=True, routed_scaling=1.0,
-                 n_group=1, topk_group=1, latent=None, shared=None):
+                 n_group=1, topk_group=1, latent=None, shared=None,
+                 norm_topk_eps=None):
     """(this rank's routed part [+ the shared expert], top_idx,
     expert_load) of one expert layer: a router over ALL `experts_total`
     experts (`scoring` "sigmoid" or "softmax"), with `select_bias` a buffer
     added to the scores for the selection alone, the top
     `num_experts_per_tok` (inside the best `topk_group` of `n_group` groups
     where `n_group` > 1), their weights divided by their sum
-    (`norm_topk_prob`) and times `routed_scaling`; the `experts_held`
+    (`norm_topk_prob`; `norm_topk_eps` is added to that sum, None: the
+    op's own 1e-20) and times `routed_scaling`; the `experts_held`
     experts from `expert_offset` that this rank holds, with a gate
     (`gated`: W_down(silu(W_gate x) * W_up x)) or without (W_down
     relu(W_up x)^2).
@@ -117,7 +123,8 @@ def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
         select_bias=bias, routed_scaling=routed_scaling,
         norm_topk=cfg.norm_topk_prob, experts_total=experts_total,
         expert_offset=cfg.expert_offset, scoring=scoring, n_group=n_group,
-        topk_group=topk_group, expert_input=into(x) if latent else None)
+        topk_group=topk_group, expert_input=into(x) if latent else None,
+        norm_topk_eps=norm_topk_eps)
     if latent:
         routed = out_of(routed)
     if not shared:
@@ -129,7 +136,7 @@ def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
 
 
 def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
-                      selection=None):
+                      selection=None, qk_norm=False):
     """`heads` query heads on `kv_heads` KV heads of `head_dim` (query head
     h attends KV head h // group), causal; `rotary` turns q and k (None: no
     rotary positions), `window` keeps the last `window` keys only (None:
@@ -142,14 +149,27 @@ def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
     result is then (out, target): `target` [B, S, S], the mean over the
     heads of the attention's probabilities on the selected pairs, which the
     indexer that chose them is trained towards; scope `attn.attend.sparse`.
-    None: today's ops in today's order."""
+    None: today's ops in today's order.
+
+    `qk_norm`: a per-head norm, q and k each under an RMS norm over a
+    head's `head_dim` features (the configuration's epsilon) with ONE
+    learned scale of `head_dim` that all the heads of q, or of k, share
+    (`q_norm_scale`, `k_norm_scale`), BEFORE the rotary turn; scope
+    `attn.qk_norm`. False: no such ops, and today's in today's order."""
     hd = cfg.head_dim
     turn = rotary or (lambda t: t)
+
+    def normed(t, name):
+        if not qk_norm:
+            return t
+        with name_scope("attn.qk_norm"):
+            return _norm(t, pre + name, cfg)
+
     with name_scope("attn.proj"):
-        q = turn(_heads(_linear(x, heads * hd, pre + "q_proj_w", cfg),
-                        heads, hd))
-        k = turn(_heads(_linear(x, kv_heads * hd, pre + "k_proj_w", cfg),
-                        kv_heads, hd))
+        q = turn(normed(_heads(_linear(x, heads * hd, pre + "q_proj_w", cfg),
+                               heads, hd), "q_norm_scale"))
+        k = turn(normed(_heads(_linear(x, kv_heads * hd, pre + "k_proj_w",
+                                       cfg), kv_heads, hd), "k_norm_scale"))
         v = _heads(_linear(x, kv_heads * hd, pre + "v_proj_w", cfg),
                    kv_heads, hd)
     target = None
@@ -172,24 +192,36 @@ def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
 
 
 def embed_tokens(cfg):
-    """(tokens [B, seq_len] int64, their embeddings [B, seq_len, hidden]),
-    the lookup a gather of the rows held."""
+    """(tokens [B, seq_len] int64, their embeddings [B, seq_len, hidden],
+    the embedding parameter [vocab, hidden]), the lookup a gather of the
+    rows held."""
     s, h = cfg.seq_len, cfg.hidden_size
     tokens = layers.data(name="tokens", shape=[s], dtype="int64")
     embed = layers.create_parameter([cfg.vocab_size, h], "float32",
                                     attr=_w("embed_tokens", cfg))
     return tokens, layers.reshape(
-        layers.gather(embed, layers.reshape(tokens, [-1])), [-1, s, h])
+        layers.gather(embed, layers.reshape(tokens, [-1])), [-1, s, h]), embed
 
 
-def next_token_loss(x, tokens, cfg):
-    """Final norm, untied head over the vocabulary held, and the mean cross
+def next_token_loss(x, tokens, cfg, tied=None):
+    """Final norm, the head over the vocabulary held, and the mean cross
     entropy of every position but a row's last against the token that
     follows it. All `seq_len` positions go through the head (the last one's
-    label is the ignore index), so no shape in the step is `seq_len - 1`."""
+    label is the ignore index), so no shape in the step is `seq_len - 1`.
+
+    `tied`: a tied head, the embedding parameter [vocab, hidden] itself:
+    the logits are `norm(x) E^T` under the scope `head.tied`, no `lm_head_w`
+    is created, and the one parameter is read at two places of the Program,
+    by `embed_tokens`' gather and by this matmul; its gradient is the sum of
+    the gather's scatter-add and the matmul's (`framework/backward.py` adds
+    up a variable's repeated gradients). None: an untied `lm_head_w`."""
     s = cfg.seq_len
     x = _norm(x, "final_norm_scale", cfg)
-    logits = _linear(x, cfg.vocab_size, "lm_head_w", cfg)
+    if tied is None:
+        logits = _linear(x, cfg.vocab_size, "lm_head_w", cfg)
+    else:
+        with name_scope("head.tied"):
+            logits = layers.matmul(x, tied, transpose_y=True)
     nxt = layers.slice(tokens, [1], [1], [s])
     none = layers.fill_constant_batch_size_like(nxt, [-1, 1], "int64", -100)
     labels = layers.unsqueeze(layers.concat([nxt, none], axis=1), [2])
@@ -198,7 +230,7 @@ def next_token_loss(x, tokens, cfg):
 
 
 def build_causal_lm_program(cfg, model, decoder_layer, layer_indices,
-                            auxiliary=None):
+                            auxiliary=None, tie_head=False):
     """Next-token objective over `tokens` [B, seq_len] (`next_token_loss`)
     of a decoder whose layer n is `decoder_layer(x, cfg, n)` -> (x_out,
     (top_idx, expert_load) or None) for n in `layer_indices`, under the
@@ -212,19 +244,24 @@ def build_causal_lm_program(cfg, model, decoder_layer, layer_indices,
     and cannot be fetched: it is the loss less the others). None, or
     nothing appended: the next-token loss and today's Program.
 
+    `tie_head`: the head reads the token embedding (`next_token_loss`'s
+    `tied`); the gauge `lm.tied_head` says which the last Program built has.
+
     Returns (tokens, loss, routed): `routed` holds, per expert layer, the
     `(top_idx, expert_load)` variables a caller may fetch beside the loss
     (`expert_load` [experts held]: the assignments that fell on each). Layer
     boundaries land on the loss's `_layer_checkpoints`."""
     with RecordEvent("program.build", args={"model": model}):
-        tokens, x = embed_tokens(cfg)
+        tokens, x, embed = embed_tokens(cfg)
         ckpts, routed = [], []
         for n in layer_indices:
             x, r = decoder_layer(x, cfg, n)
             ckpts.append(x.name)
             if r is not None:
                 routed.append(r)
-        loss = next_token_loss(x, tokens, cfg)
+        metrics.set_gauge("lm.tied_head", float(tie_head))
+        loss = next_token_loss(x, tokens, cfg,
+                               tied=embed if tie_head else None)
         if auxiliary:
             lm_loss, loss = loss, layers.sums([loss] + list(auxiliary))
             loss._lm_loss, loss._auxiliary_losses = lm_loss, list(auxiliary)
@@ -237,7 +274,9 @@ def sharding_rules(own) -> ShardingRules:
     and its feed-forward widths, column-parallel in and row-parallel out),
     then what every model here has: the attention's output projection
     row-parallel, the experts' leading dim over `ep`, the vocabulary over
-    `tp`."""
+    `tp`. A tied vocabulary is the embedding's rule alone: rows over `tp`
+    serve the gather and, as the columns of E^T, the head; no parameter is
+    named `lm_head_w` and that rule does not fire."""
     return moe_sharding_rules(extra=list(own) + [
         (r"_o_proj_w$", P("tp", None)),
         (r"^embed_tokens$", P("tp", None)),
@@ -256,7 +295,6 @@ def record_expert_load(loads, tokens: int) -> dict:
     steps); counter `moe.tokens_dropped`, which never rises: the op has
     no capacity. Returns the two gauges' values."""
     import numpy as np
-    from ..observability import metrics
     loads = np.asarray(loads, np.float64)
     per_tok = float(loads.sum(axis=-1).mean() / tokens)
     skew = float((loads.max(axis=-1)

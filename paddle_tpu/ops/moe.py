@@ -493,7 +493,8 @@ def _slot_weights(scores, idx, local, attrs):
     w = jax.lax.optimization_barrier(
         jnp.sum(jnp.where(picked, scores[:, None, :], 0.0), axis=2))
     if attrs.get("norm_topk", True):
-        w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=1, keepdims=True)
+                 + float(attrs.get("norm_topk_eps", 1e-20)))
     w = w * float(attrs.get("routed_scaling", 1.0))
     return jnp.where(local, w, 0.0).T
 

@@ -24,6 +24,15 @@ an output projection:
 * `gated_group_rms_norm`: `GroupRMSNorm(x * silu(gate)) * scale`, the
   statistics over each of `groups` equal slices of the last axis.
 
+And the whole mixer of a gated short-convolution layer, which has no state
+to scan: `gated_short_conv`, `C * conv(B * u)` of one projection `[B | C |
+u]`, the convolution `causal_conv1d`'s without bias or activation. One op
+and not `elementwise_mul`, `causal_conv1d`, `elementwise_mul` over a
+`split`: its backward (a `jax.custom_vjp`, so a segment differentiated as a
+whole takes it too) keeps the projection alone, makes the first gate and
+the convolution again and writes `[dB | dC | du]` once. The products are in
+the projection's dtype, the taps and their sum float32.
+
 `ssm_scan` declares a grad rule (docs/custom_ops.md). Its forward writes
 what is narrow: `States` `[B, S / L, H, P, N]` float32, the state each
 chunk starts from, and the per-token rows `DtSoft`, `CumA` `[B, S, H]`
@@ -79,6 +88,89 @@ def _causal_conv1d(ctx, ins, attrs):
     elif act:
         raise ValueError(f"causal_conv1d: unknown activation {act!r}")
     return {"Out": [y.astype(x.dtype)]}
+
+
+# ---------------------------------------------------------------------------
+# gated_short_conv
+# ---------------------------------------------------------------------------
+
+def _padded(x, k, reverse=False):
+    """x [B, S, C] with k - 1 rows of zeros before the row's start
+    (`reverse`: past its end), in x's own dtype: the zeros go on before the
+    widening, so what XLA writes between two fusions is as narrow as x and
+    the first gate's product is rounded to x's dtype, as the builders state
+    it."""
+    pad = (0, k - 1) if reverse else (k - 1, 0)
+    return jnp.pad(x, ((0, 0), pad, (0, 0)))
+
+
+def _taps(x, w, reverse=False):
+    """sum_j w[j] x[t - (K-1) + j] in float32, zeros before the row's start;
+    `reverse`: its transpose, sum_j w[j] x[t + (K-1) - j], zeros past the
+    row's end. x [B, S, C], w [K, C]."""
+    k, s = w.shape[0], x.shape[1]
+    padded = _padded(x, k, reverse)
+    taps = w[::-1] if reverse else w
+    return sum(padded[:, j:j + s].astype(_F32) * taps[j].astype(_F32)
+               for j in range(k))
+
+
+def _thirds(bcx):
+    c = bcx.shape[-1] // 3
+    return bcx[..., :c], bcx[..., c:2 * c], bcx[..., 2 * c:]
+
+
+def _mix(bcx, w):
+    """C * conv(B * u) of bcx = [B | C | u] [B, S, 3C]: both gates'
+    products in bcx's dtype, the taps and their sum in float32."""
+    b, c, u = _thirds(bcx)
+    return c * _taps(b * u, w).astype(bcx.dtype)
+
+
+@jax.custom_vjp
+def _gated_conv(bcx, w):
+    return _mix(bcx, w)
+
+
+def _gated_conv_fwd(bcx, w):
+    return _mix(bcx, w), (bcx, w)
+
+
+def _gated_conv_bwd(res, dy):
+    """From the projection alone: the first gate and the convolution are
+    made again, dC = dy * conv, the convolution's cotangent dy * C goes
+    back through the taps in reverse, and [dB | dC | du] is written once."""
+    bcx, w = res
+    b, c, u = _thirds(bcx)
+    k, s = w.shape[0], bcx.shape[1]
+    dy = dy.astype(bcx.dtype)
+    g = b * u
+    dconv = dy * c
+    dg = _taps(dconv, w, reverse=True).astype(bcx.dtype)
+    dc = dy * _taps(g, w).astype(bcx.dtype)
+    padded = _padded(g, k)
+    dw = jnp.stack([jnp.sum(padded[:, j:j + s].astype(_F32)
+                            * dconv.astype(_F32), axis=(0, 1))
+                    for j in range(k)])
+    return (jnp.concatenate([dg * u, dc, dg * b], axis=-1),
+            dw.astype(w.dtype))
+
+
+_gated_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)
+
+
+@register("gated_short_conv")
+def _gated_short_conv(ctx, ins, attrs):
+    """X [B, S, 3C] = [B | C | u], W [K, C]: Out = C * conv(B * u), conv
+    `causal_conv1d`'s index rule without bias or activation."""
+    x, w = ins["X"][0], ins["W"][0]
+    if x.shape[-1] != 3 * w.shape[1]:
+        raise ValueError(f"gated_short_conv: {x.shape[-1]} features are "
+                         f"not three streams of {w.shape[1]}")
+    if not ctx.is_eval_shape and not ctx.in_vjp:
+        from ..observability import metrics
+        metrics.inc("conv.layers_lowered")
+    return {"Out": [_gated_conv(x, w)]}
 
 
 @register("gated_group_rms_norm")

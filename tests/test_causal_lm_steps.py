@@ -11,7 +11,7 @@ import pytest
 
 from causal_lm_harness import causal_lm, tiny_step_digests
 
-from paddle_tpu.models import (bert, deepseek_v3, keye, ling, mellum,
+from paddle_tpu.models import (bert, deepseek_v3, keye, lfm2, ling, mellum,
                                nemotron_h)
 
 
@@ -47,6 +47,10 @@ _STEPS = {
     "keye": (causal_lm(keye, keye.KeyeConfig.tiny()),
         "e7f1483278b20170f7207a64a84339842724a53e758ffd5fb9e27d04a6110e51",
         "181edc8cec578935a222564d31587f4ce7147b7bb9563acd3a6d35c0eb07ea5d"),
+    # made at PR 47, which brought the builder: held from here on
+    "lfm2": (causal_lm(lfm2, lfm2.Lfm2Config.tiny()),
+        "88e566ffafa22ccc31f0ec920db0f780e814a0cdb05d510ae2f60c8f94f3d2bc",
+        "e62fa019c171501909ad0defefa0b79d43f66412eb57c60bb9f9cbab83aeae50"),
 }
 
 
