@@ -29,6 +29,12 @@ published width of models the repo already has:
   index_scores                 the sparse-attention indexer's two score
                                kernels at the learned-selection cell's
                                shape, likewise
+  recompute_keep               the learned-selection cell's train step
+                               (5 layers under `strategy.recompute`) built
+                               and compiled, not run: its Mosaic calls by
+                               name and its temporary bytes; the flash
+                               forward and the target's kernel once a
+                               layer (a segment keeps what they made)
   four_chips                   the first trainer on every visible device
                                (dp=N), replicated and ZeRO-1; runs when JAX
                                finds at least four
@@ -87,6 +93,16 @@ FULL = {
     # one layer's indexer at the learned-selection cell's size: 16 heads of
     # 64 over 1 x 8,192 positions, 2,048 keys a query
     "index": {"b": 1, "h": 16, "s": 8192, "d": 64, "topk": 2048},
+    # the learned-selection cell's language model as one chip holds it
+    # (benchmark/configs/keye_vl2_30b_a3b_ep8.json): 5 layers, 32 heads of
+    # 128 on 4 KV heads, 16 of 128 experts, 18,992 vocabulary rows, 1 x
+    # 8,192 tokens
+    "keep": dict(vocab_size=18992, hidden_size=2048, num_hidden_layers=5,
+                 num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+                 moe_intermediate_size=768, num_experts=128, experts_held=16,
+                 num_experts_per_tok=8, mrope_section=(16, 24, 24),
+                 rope_theta=1e7, indexer_num_heads=16, indexer_head_dim=64,
+                 index_topk=2048, seq_len=8192),
     "gpt": {},                           # GPTConfig() == GPT-2 small
     "serve": {"max_slots": 8, "max_len": 512, "prompt_lens": (16, 300),
               "new_tokens": (8, 64), "prefix_len": 64, "requests": 12,
@@ -110,6 +126,12 @@ TINY = {
              "chunk": 128},
     "delta": {"b": 1, "s": 128, "h": 2, "d": 128, "chunk": 64},
     "index": {"b": 1, "h": 2, "s": 256, "d": 64, "topk": 40},
+    "keep": dict(vocab_size=256, hidden_size=64, num_hidden_layers=2,
+                 num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                 moe_intermediate_size=32, num_experts=8,
+                 num_experts_per_tok=2, mrope_section=(2, 2, 4),
+                 indexer_num_heads=2, indexer_head_dim=8, index_topk=12,
+                 seq_len=32),
     "gpt": dict(vocab_size=128, hidden_size=32, num_layers=1, num_heads=2,
                 intermediate_size=64, max_position=64, seq_len=32,
                 hidden_dropout=0.0, attention_dropout=0.0),
@@ -309,7 +331,9 @@ def mosaic_calls(hlo_text):
             continue
         name = "?"
         for known in FLASH_KERNELS + ("paged_attention_decode",
-                                      "zero_update_"):
+                                      "zero_update_", "selected_probs_sum",
+                                      "index-scores-fwd", "index-scores-bwd",
+                                      "ragged-dot-"):
             if known in line:
                 name = known
                 break
@@ -1169,6 +1193,70 @@ def leg_index_scores(preset, clock):
 
 
 # ---------------------------------------------------------------------------
+# what a recomputed segment keeps: the learned-selection cell's step
+# ---------------------------------------------------------------------------
+def leg_recompute_keep(preset, clock):
+    """Build the learned-selection LM's AMP train step under
+    `strategy.recompute` (a checkpoint at every layer boundary, as its cell
+    runs it) and compile `run_steps(2)`'s program without running it: the
+    Mosaic calls by name, the temporary bytes, the counters a trace raised.
+    A segment keeps its selection, the target and the flash output beside
+    the layer boundary (`ops/registry.py` `keep_under_recompute`), so the
+    compiled step may launch the flash forward and the target's kernel
+    once a layer, not twice."""
+    import paddle_tpu as paddle
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.models import keye
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.testing import reset_programs
+
+    reset_programs(0)
+    cfg = keye.KeyeConfig(**preset["keep"])
+    _, loss, _ = keye.build_causal_lm_program(cfg)
+    fleet.init(is_collective=True)
+    strategy = fleet.DistributedStrategy()
+    strategy.amp = True
+    strategy.recompute = True
+    strategy.recompute_configs = {"checkpoints": list(loss._layer_checkpoints)}
+    fleet.distributed_optimizer(paddle.optimizer.Adam(learning_rate=1e-4),
+                                strategy).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    feed = {"tokens": np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, 1, cfg.seq_len)).astype(np.int64)}
+    kept = ("recompute.kept_values", "recompute.kept_bytes")
+    before = [metrics.get(n) for n in kept]
+    try:
+        calls = mosaic_calls(exe.compiled_hlo(feed, [loss], k=2))
+        memory = exe.compiled_memory_analysis(feed, [loss], k=2)
+    finally:
+        exe.close()
+        scope = fluid.global_scope()
+        for name in scope.local_names():
+            scope.erase(name)
+    values, nbytes = (int(metrics.get(n) - b) for n, b in zip(kept, before))
+    layers = cfg.num_hidden_layers
+    facts = {"layers": layers, "mosaic_calls": calls,
+             "temp_bytes": int(memory.temp_size_in_bytes),
+             "argument_bytes": int(memory.argument_size_in_bytes),
+             "kept_values": values, "kept_bytes": nbytes}
+    print(f"[chip_smoke] recompute_keep: {layers} layers, Mosaic calls "
+          f"{calls}, temporaries {facts['temp_bytes'] / 1e9:.2f} GB, "
+          f"{values} values kept ({nbytes / 1e9:.2f} GB)", flush=True)
+    # 5 a routed layer and the selection a sparse one; with the kernels,
+    # the target, the flash output and one lane of its logsumexp too
+    check(values == layers * (9 if preset["expect_mosaic"] else 6),
+          f"a trace kept {values} values over {layers} layers")
+    if preset["expect_mosaic"]:
+        for kernel in ("flash_attention_fwd", "selected_probs_sum"):
+            check(0 < calls.get(kernel, 0) <= layers,
+                  f"{kernel}: {calls.get(kernel, 0)} launches a step over "
+                  f"{layers} layers (a kept value is made again)")
+    return facts
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 def leg_four_chips(preset, clock):
@@ -1245,6 +1333,7 @@ LEGS = (("train_bert_base_s128", leg_train_s128),
         ("ssm_scan", leg_ssm_scan),
         ("kda_scan", leg_kda_scan),
         ("index_scores", leg_index_scores),
+        ("recompute_keep", leg_recompute_keep),
         ("four_chips", leg_four_chips))
 
 

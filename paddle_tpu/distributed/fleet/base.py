@@ -82,6 +82,11 @@ class DistributedStrategy:
     amp: bool = False
     amp_configs: dict = field(default_factory=lambda: {
         "init_loss_scaling": 32768.0, "use_pure_bf16": True})
+    # A segment between two checkpoints is recomputed in the backward, all
+    # but the values its ops marked as kept (a learned selection and its
+    # target, 5 B S^2 bytes a layer; a flash attention's output, 2 B S heads
+    # head_dim; a routed layer's choices): parallel/transforms.py
+    # apply_recompute, gauge recompute.kept_bytes.
     recompute: bool = False
     recompute_configs: dict = field(default_factory=lambda: {"checkpoints": []})
     # Rolled-layer programs: roll the model's N isomorphic per-layer op

@@ -398,12 +398,15 @@ class _LowerTable:
     `__layer_scan__` through `_run_sub_ops`, a sub-block's walk) counts to
     those ops and is taken off the container's row."""
 
-    __slots__ = ("rows", "inner_s")
+    __slots__ = ("rows", "inner_s", "kept_bytes")
     TOP = 8         # rows the span carries
 
     def __init__(self):
         self.rows: Dict[str, list] = {}     # type -> [count, self seconds]
         self.inner_s = 0.0          # seconds in lowerings under the open one
+        # bytes this walk's recomputed segments keep beside their boundaries
+        # (ops/registry.py keep_under_recompute: gauge recompute.kept_bytes)
+        self.kept_bytes = 0
 
     def top(self) -> list:
         rows = sorted(self.rows.items(), key=lambda kv: -kv[1][1])

@@ -51,7 +51,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .registry import register
+from .registry import keep_under_recompute, register
 
 # counter suffix by the dtype q reached the flash forward in
 _OPERAND_TAG = {"bfloat16": "bf16", "float32": "f32"}
@@ -329,9 +329,11 @@ def _selected_attention(ctx, q, k, v, select, scale, route, want_target):
         outs = {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}
         if want_target:
             with jax.named_scope("attn.index.target"):
-                outs["Target"] = [selected_probs_sum(
+                # a recomputed segment keeps it: every head's scores once
+                # more for a [B, S, S] float32 the loss's backward reads
+                outs["Target"] = [keep_under_recompute(selected_probs_sum(
                     *jax.lax.stop_gradient((q, k, lse)), select,
-                    scale=scale)]
+                    scale=scale))]
         return outs
     if count and not isinstance(q, jax.ShapeDtypeStruct):
         metrics.inc("attn.sparse_xla")

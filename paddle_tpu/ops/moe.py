@@ -34,7 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .registry import register
+from .registry import keep_under_recompute, register
 from ..framework.dtype import INT64_DEVICE_DTYPE
 
 
@@ -519,7 +519,11 @@ def _sort_slots(eid, w):
 
 
 def _sort_slots_fwd(eid, w):
-    order, w_sorted = _sort_slots(eid, w)
+    # kept by a recomputed segment, marked before they are result AND
+    # residual: one sort gives both, so one of them read unkept in the
+    # backward (`_unsort` by order here, the experts' weights) would run it
+    # again
+    order, w_sorted = map(keep_under_recompute, _sort_slots(eid, w))
     return (order, w_sorted), order
 
 
@@ -612,16 +616,21 @@ def _routed_moe(ctx, ins, attrs):
         n_group = int(attrs.get("n_group", 1))
         if n_group > 1:
             sel = _group_limited(sel, n_group, int(attrs["topk_group"]))
-        _, idx = jax.lax.top_k(sel, top_k)                   # [N, k]
+        # the route's choices are kept by a recomputed segment
+        # (`keep_under_recompute`; the sort's two results in
+        # `_sort_slots_fwd`): a few bytes a slot against a top_k and three
+        # sorts, marked before `_held_experts` takes them as residuals
+        idx = keep_under_recompute(jax.lax.top_k(sel, top_k)[1])  # [N, k]
         local = (idx >= off) & (idx < off + e_held)
         w_slot = _slot_weights(scores, idx, local, attrs)    # [k, N]
         eid = jnp.where(local, idx - off, e_held).T.reshape(-1)  # [k*N]
-        sizes = jnp.sum(eid[:, None] == jnp.arange(e_held)[None, :],
-                        axis=0, dtype=jnp.int32)             # [E_held]
+        sizes = keep_under_recompute(jnp.sum(
+            eid[:, None] == jnp.arange(e_held)[None, :], axis=0,
+            dtype=jnp.int32))                                # [E_held]
         order, w_sorted = _sort_slots(eid, w_slot.reshape(-1))
-        inv = _token_rows(
+        inv = keep_under_recompute(_token_rows(
             _unsort(order, jnp.arange(n * top_k, dtype=jnp.int32)),
-            top_k, e_held)
+            top_k, e_held))
 
     out, h, u = _held_experts(not ctx.is_eval_shape, xet, w_sorted, order,
                               inv, sizes, eg, eu, ed)

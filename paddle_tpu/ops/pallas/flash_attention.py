@@ -86,6 +86,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_mode
+from ..registry import keep_under_recompute
 
 _LANES = 128  # Mosaic lane width; lse stored broadcast over it
 
@@ -820,17 +821,37 @@ def _flash(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
                       block_q, block_k, mask_mode, window)
 
 
+def _kept(out, lse):
+    """What a differentiated forward hands its backward, and what a
+    recomputed segment keeps so that its backward launches no forward
+    kernel (`ops/registry.py` `keep_under_recompute`): `out`, 2 B x S x
+    heads x head_dim bytes, and ONE lane of the kernel's lane-broadcast
+    `lse`, [B*nh, S] float32, not its 128 copies. Marked before `out` is
+    returned as result AND residual: the backward reads what was kept."""
+    return keep_under_recompute(out), keep_under_recompute(lse[:, :, 0])
+
+
+def _widen(lse, dout):
+    """[B*nh, S] -> the kernels' [B*nh, S, 128], next to the backward
+    kernels and not before: the barrier makes the compact values wait for
+    dout, or XLA hoists this cheap broadcast to the forward and keeps all
+    128 copies alive until here."""
+    lse, dout = jax.lax.optimization_barrier((lse, dout))
+    return jnp.broadcast_to(lse[:, :, None], lse.shape + (_LANES,)), dout
+
+
 def _fwd(q, k, v, seed, mask, scale, causal, dropout, block_q, block_k,
          mask_mode, window):
     out, lse = _flash_fwd(q, k, v, seed, mask, scale, causal, dropout,
                           block_q, block_k, mask_mode, window)
-    return (out, lse), (q, k, v, seed, mask, out, lse)
+    out, lse1 = _kept(out, lse)
+    return (out, lse), (q, k, v, seed, mask, out, lse1)
 
 
 def _bwd(scale, causal, dropout, block_q, block_k, mask_mode, window, res,
          cts):
     q, k, v, seed, mask, o, lse = res
-    do, _ = cts     # lse is a residual, not a result: its cotangent is unused
+    lse, do = _widen(lse, cts[0])   # lse's own cotangent: it is no result
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, seed, mask, scale, causal,
                             dropout, block_q, block_k, mask_mode, window)
     dseed = np.zeros(seed.shape, dtype=jax.dtypes.float0)
@@ -853,12 +874,14 @@ def _flash_selected(q, k, v, seed, select, scale, block_q, block_k):
 def _selected_fwd(q, k, v, seed, select, scale, block_q, block_k):
     out, lse = _flash_fwd(q, k, v, seed, None, scale, True, 0.0, block_q,
                           block_k, None, None, select)
-    return (out, lse), (q, k, v, seed, select, out, lse)
+    out, lse1 = _kept(out, lse)
+    return (out, lse), (q, k, v, seed, select, out, lse1)
 
 
 def _selected_bwd(scale, block_q, block_k, res, cts):
     q, k, v, seed, select, o, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, o, lse, cts[0], seed, None, scale, True,
+    lse, do = _widen(lse, cts[0])
+    dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, seed, None, scale, True,
                             0.0, block_q, block_k, None, None, select)
     # the selection is a choice, not a number: nothing flows into it
     return (dq, dk, dv, np.zeros(seed.shape, jax.dtypes.float0),
@@ -1051,11 +1074,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale=None, causal=False,
     kernel for `out` and `lse` itself."""
     scale, seed, mask, mask_mode = _kernel_args(
         q, k, v, scale, dropout, seed, mask, causal, window)
-    # widen lse next to the kernels and not before: the barrier makes the
-    # compact values wait for dout, or XLA hoists this cheap broadcast to
-    # the forward and keeps all [B*nh, S, 128] copies alive until here
-    lse, dout = jax.lax.optimization_barrier((lse, dout))
-    lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (_LANES,))
+    lse, dout = _widen(lse, dout)
     if select is not None:
         _check_select(q, select, causal, dropout, mask, window)
     return _flash_bwd(q, k, v, out, lse, dout, seed, mask, scale, causal,

@@ -20,11 +20,13 @@ sentinel substituted for unknown (-1) batch dims, then maps the sentinel back.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..framework.dtype import convert_dtype
 
@@ -35,7 +37,7 @@ _DYN_SENTINEL = 8191
 class LowerCtx:
     """Per-execution context handed to lowerings (rng base key, mesh info)."""
 
-    __slots__ = ("rng_key", "mesh", "is_eval_shape", "in_vjp")
+    __slots__ = ("rng_key", "mesh", "is_eval_shape", "in_vjp", "pullbacks")
 
     def __init__(self, rng_key=None, mesh=None, is_eval_shape=False,
                  in_vjp=False):
@@ -46,6 +48,10 @@ class LowerCtx:
         # differentiate it: a lowering whose repeat XLA cannot merge with
         # the first (a Mosaic kernel) counts itself when it sees this
         self.in_vjp = in_vjp
+        # what a forward lowering that differentiated itself left for its
+        # __vjp__ op later in the same walk (parallel/transforms.py
+        # `__segment__`): {key: (outputs, pullback)}
+        self.pullbacks = {}
 
     def op_key(self, attrs):
         """Deterministic per-op PRNG key: fold the op's stable seed attr into the
@@ -135,6 +141,70 @@ def all_ops() -> List[str]:
 
 
 # ---------------------------------------------------------------------------
+# Recomputation: what a checkpointed segment keeps beside its boundary
+# ---------------------------------------------------------------------------
+
+# The one name `checkpointed`'s policy saves. An op marks a value with
+# `keep_under_recompute` where it makes it: a choice or a probability that
+# several kernels were spent on and that is small beside a layer's
+# activations (a selection, its target, the flash output, a route's
+# indices). The recomputed forward then reads the kept value, and what
+# only made it is dead code there.
+_KEPT = "kept_under_recompute"
+
+# (count, times) while a checkpointed unit is being lowered (`recomputed`),
+# else None: outside one a mark is no equation at all.
+_recomputing = None
+
+
+def checkpointed(fn):
+    """`fn` as one recomputed unit: `jax.checkpoint` that saves the unit's
+    inputs and the values ops marked inside it, nothing else. Call and
+    differentiate the result under `recomputed`."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(_KEPT))
+
+
+@contextlib.contextmanager
+def recomputed(count, times=1):
+    """Around the lowering and differentiation of a `checkpointed` unit
+    (`__segment__`; a `__layer_scan__` with `remat`, whose body runs
+    `times` layers): marks take effect, and are counted if `count` (the
+    lowering whose residuals the step keeps, once a trace)."""
+    global _recomputing
+    outer, _recomputing = _recomputing, (count, times)
+    try:
+        yield
+    finally:
+        _recomputing = outer
+
+
+def keep_under_recompute(x):
+    """Mark `x` as kept by the checkpointed unit being lowered; `x` itself
+    outside one. Mark a value where it is made, before anything reads it:
+    a reader of the unmarked twin (a `custom_vjp`'s residual) has it made
+    again. Counter `recompute.kept_values`, gauge `recompute.kept_bytes`
+    (docs/observability.md)."""
+    if _recomputing is None:
+        return x
+    count, times = _recomputing
+    if count:
+        from ..framework import executor
+        from ..observability import metrics
+        nbytes = times * x.size * x.dtype.itemsize
+        metrics.inc("recompute.kept_values", times)
+        # summed over one walk of a program's ops (one trace of a step)
+        walk = executor._lower_table
+        if walk is not None:
+            walk.kept_bytes += nbytes
+            nbytes = walk.kept_bytes
+        else:
+            nbytes += metrics.get("recompute.kept_bytes")
+        metrics.set_gauge("recompute.kept_bytes", nbytes)
+    return checkpoint_name(x, _KEPT)
+
+
+# ---------------------------------------------------------------------------
 # Build-time shape/dtype inference (reference: InferShape, shape_inference.h)
 # ---------------------------------------------------------------------------
 
@@ -214,6 +284,35 @@ def make_vjp_attrs(fwd_op, diff_entries, out_slots_order):
     }
 
 
+def cotangents(outs, ogs):
+    """The cotangents of one slot's forward outputs `outs` from what
+    arrived for them, `ogs` (aligned; short, or None, where an output has
+    no reader): zeros for those."""
+    cts = []
+    for j, ref in enumerate(outs):
+        if j < len(ogs) and ogs[j] is not None:
+            ct = ogs[j]
+            # AMP may deliver cotangents in a different float dtype than
+            # this op's output (e.g. bf16 grads into an f32 op) — align.
+            # TensorArray-valued outputs are (buffer, length) pytrees:
+            # align leaf-wise (the length leaf's cotangent is symbolic).
+            if isinstance(ref, tuple):
+                ct = jax.tree_util.tree_map(
+                    lambda c, r: c if c is None
+                    or getattr(c, "dtype", None) == r.dtype
+                    or not jax.numpy.issubdtype(r.dtype, jax.numpy.floating)
+                    else c.astype(r.dtype), tuple(ct), ref)
+            elif ct.dtype != ref.dtype:
+                ct = ct.astype(ref.dtype)
+            cts.append(ct)
+        elif isinstance(ref, tuple):
+            cts.append(jax.tree_util.tree_map(
+                lambda r: jax.numpy.zeros(r.shape, r.dtype), ref))
+        else:
+            cts.append(jax.numpy.zeros(ref.shape, ref.dtype))
+    return cts
+
+
 def _lower_vjp(ctx, ins, attrs):
     fwd = get(attrs["fwd_type"])
     fwd_attrs = attrs["fwd_attrs"]
@@ -251,33 +350,10 @@ def _lower_vjp(ctx, ins, attrs):
     cts = []
     idx = 0
     for s in out_slots:
-        ogs = ins.get(f"OG:{s}", [])
         n_outs = attrs["fwd_output_counts"][s]
-        for j in range(n_outs):
-            ref = out_flat[idx + j]
-            if j < len(ogs) and ogs[j] is not None:
-                ct = ogs[j]
-                # AMP may deliver cotangents in a different float dtype than
-                # this op's output (e.g. bf16 grads into an f32 op) — align.
-                # TensorArray-valued outputs are (buffer, length) pytrees:
-                # align leaf-wise (the length leaf's cotangent is symbolic).
-                if isinstance(ref, tuple):
-                    ct = jax.tree_util.tree_map(
-                        lambda c, r: c if c is None
-                        or getattr(c, "dtype", None) == r.dtype
-                        or not jax.numpy.issubdtype(r.dtype,
-                                                    jax.numpy.floating)
-                        else c.astype(r.dtype), tuple(ct), ref)
-                elif ct.dtype != ref.dtype:
-                    ct = ct.astype(ref.dtype)
-                cts.append(ct)
-            elif isinstance(ref, tuple):
-                cts.append(jax.tree_util.tree_map(
-                    lambda r: jax.numpy.zeros(r.shape, r.dtype), ref))
-            else:
-                cts.append(jax.numpy.zeros(ref.shape, ref.dtype))
+        cts += cotangents(out_flat[idx:idx + n_outs], ins.get(f"OG:{s}", []))
         idx += n_outs
-    grads = vjp_fn(list(cts))
+    grads = vjp_fn(cts)
     by_slot = {}
     for (s, i), g in zip(diff, grads):
         by_slot.setdefault(s, {})[i] = g

@@ -59,7 +59,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .registry import register
+from .registry import keep_under_recompute, register
 
 # queries a block of the score products: the [B, H, block, S] float32
 # products of one block are the largest array the scores ever hold
@@ -279,6 +279,10 @@ def _sparse_index(ctx, ins, attrs):
         metrics.inc("attn.sparse_layers_lowered")
     scores, select = index_scores_and_select(
         q, k, w, topk, not ctx.is_eval_shape, ctx.in_vjp)
+    # one byte a pair against 32 passes over the scores and a running count:
+    # a recomputed segment reads the selection it made (the scores it makes
+    # again, for the loss's backward)
+    select = keep_under_recompute(select)
     with jax.named_scope("attn.index.select"):
         pairs = jnp.sum(select, dtype=jnp.float32).reshape(1) / (
             select.shape[0] * select.shape[1])

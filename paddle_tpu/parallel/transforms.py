@@ -11,9 +11,11 @@ lowering is a `lax.scan` over the per-layer weights stacked along a new
 leading [L] axis — the compiled step program then contains each layer's HLO
 once instead of N times (docs/perf_notes.md "Rolled-layer programs").
 Recompute collapses a forward segment into ONE __segment__ op whose lowering
-is wrapped in jax.checkpoint — the generic __vjp__ then stores only segment
-boundaries and re-runs the segment in backward (XLA schedules the
-rematerialization). Gradient merge gates the (arbitrary) optimizer update ops
+is wrapped in jax.checkpoint — the backward then holds only the segment's
+boundary and the few values ops marked as dear to rebuild and cheap to hold
+(ops/registry.py keep_under_recompute: a learned selection, its target, the
+flash output, an expert layer's route), and re-runs the rest of the segment
+(XLA schedules the rematerialization). Gradient merge gates the (arbitrary) optimizer update ops
 with a step-counter mask using where-selects — no control-flow blocks needed.
 """
 from __future__ import annotations
@@ -90,11 +92,10 @@ def _run_sub_ops(ctx, sub_ops, env, amp_dtype, seed_overrides=None):
     return env
 
 
-@register("__segment__")
-def _lower_segment(ctx, ins, attrs):
+def _segment_fn(ctx, attrs):
+    """The segment's sub-graph as a function of its input list."""
     sub_ops = attrs["sub_ops"]          # list of op descs
-    in_names = attrs["in_names"]
-    out_names = attrs["out_names"]
+    in_names, out_names = attrs["in_names"], attrs["out_names"]
     amp_dtype = _current_amp_dtype()
 
     def run(in_vals):
@@ -102,9 +103,42 @@ def _lower_segment(ctx, ins, attrs):
                            amp_dtype)
         return [env[n] for n in out_names]
 
-    if attrs.get("remat", True):
-        run = jax.checkpoint(run)
-    outs = run(ins["X"])
+    return run
+
+
+def _segment_grad(ctx, ins, attrs, outs, ogs):
+    """Grad rule of a recomputed segment: the pullback its forward lowering
+    left (`_lower_segment`), so the forward is traced once. Declines where
+    there is none (the forward was lowered in another walk: a pipeline
+    stage's sections) and the generic `__vjp__` lowers the segment again."""
+    made = ctx.pullbacks.pop(tuple(attrs["out_names"]), None)
+    if made is None:
+        return None
+    out_vals, pullback = made
+    return {"X": list(pullback(
+        registry.cotangents(out_vals, ogs.get("Out", [])))[0])}
+
+
+@register("__segment__", grad=_segment_grad)
+def _lower_segment(ctx, ins, attrs):
+    """The sub-graph under `jax.checkpoint`. In a program's own walk the
+    lowering differentiates itself there and then (`jax.vjp`; the
+    `__vjp__` op that follows takes the pullback, `_segment_grad`): ONE
+    trace gives the forward its outputs and the backward its residuals,
+    the segment's inputs and the values ops marked
+    (`registry.keep_under_recompute`), so a kept value is made once.
+    Lowered a second time by the generic `__vjp__` the forward pass's
+    values and the differentiated pass's are two computations to XLA, and
+    keeping would only move the work from the backward to the latter: the
+    checkpoint there saves its inputs alone."""
+    run = _segment_fn(ctx, attrs)
+    if not attrs.get("remat", True):
+        return {"Out": run(ins["X"])}
+    if ctx.in_vjp or ctx.is_eval_shape:
+        return {"Out": jax.checkpoint(run)(ins["X"])}
+    with registry.recomputed(count=True):
+        outs, pullback = jax.vjp(registry.checkpointed(run), ins["X"])
+    ctx.pullbacks[tuple(attrs["out_names"])] = (outs, pullback)
     return {"Out": outs}
 
 
@@ -171,10 +205,13 @@ def _lower_layer_scan(ctx, ins, attrs):
                            seed_overrides=seed_slices)
         return env[carry_out], None
 
-    if attrs.get("remat", False):
-        body = jax.checkpoint(body)
-    carry, _ = jax.lax.scan(body, ins["X"][0], (stacked_vals, seeds),
-                            length=n_layers)
+    remat = attrs.get("remat", False)
+    # counted in the lowering that is differentiated (the generic __vjp__'s)
+    with (registry.recomputed(ctx.in_vjp and not ctx.is_eval_shape, n_layers)
+          if remat else contextlib.nullcontext()):
+        carry, _ = jax.lax.scan(
+            registry.checkpointed(body) if remat else body, ins["X"][0],
+            (stacked_vals, seeds), length=n_layers)
     return {"Out": [carry]}
 
 
@@ -488,8 +525,15 @@ def _apply_layer_scan(program: Program, boundaries: List,
 def apply_recompute(program: Program, checkpoints: List[str]):
     """Fuse forward ops into __segment__ ops split at checkpoint vars.
 
-    Backward (__vjp__ of __segment__) then keeps only segment-boundary
-    activations live; everything inside is recomputed.
+    Backward (__vjp__ of __segment__) then keeps the segment-boundary
+    activations live and, beside them, the values the segment's ops marked
+    with `registry.keep_under_recompute` (counter `recompute.kept_values`,
+    gauge `recompute.kept_bytes`); everything else inside is recomputed.
+    What a segment keeps costs, a layer of S positions in rows of B: 5 B S^2
+    bytes where attention runs over a learned selection (its int8 mask and
+    float32 target) and 2 B S heads head_dim (+ 4 B S heads) for a flash
+    attention's output in bf16 and its logsumexp; a routed expert layer's
+    choices are 20 bytes a (token, slot).
     """
     from ..analysis.passes import checked_pass
     with checked_pass("recompute", program):
@@ -682,7 +726,10 @@ class RecomputeWrapper:
     """Optimizer wrapper applying activation checkpointing before backward
     (reference optimizer.py:4547 RecomputeOptimizer; fleet meta-optimizer
     recompute_optimizer.py). Forward ops collapse into __segment__ ops with
-    remat=True, so only checkpoint activations stay live."""
+    remat=True: the checkpoint activations stay live and, beside each, what
+    the segment's ops marked as kept (`apply_recompute`: a sparse-attention
+    layer's selection and target, 5 B S^2 bytes, a flash attention's output,
+    2 B S heads head_dim); the rest is recomputed in the backward."""
 
     def __init__(self, inner, checkpoints):
         self._inner = inner

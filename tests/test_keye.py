@@ -319,11 +319,12 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, monkeypatch):
     """With the flash gate open (here: the interpreter), one trace of the
     AMP train step lowers two indexers and two selected attentions to the
     kernels, with the target's kernel beside each; the backward takes the
-    forward's residuals (plain) or the segment is lowered again
-    (recompute: the selection is found again). The indexer's heads are 64
-    wide, which its score kernels take: forward and backward of each layer
-    count once (recompute: the forward once more), none falls to the
-    `jax.numpy` form. The step's jaxpr holds no [B, heads, S, S] array,
+    forward's residuals (plain) or the layer's segment differentiates
+    itself where it is lowered, once, and keeps the selection, the target
+    and the flash output for its backward (recompute). The indexer's heads
+    are 64 wide, which its score kernels take: forward and backward of each
+    layer count once (recompute: the forward once more, the recomputed
+    scores the loss's backward reads), none falls to the `jax.numpy` form. The step's jaxpr holds no [B, heads, S, S] array,
     the indexer's heads' products [B, 2, block, S] among them, and the
     selection once a row, as int8."""
     monkeypatch.setattr(attention, "_use_pallas",
@@ -342,16 +343,16 @@ def test_a_trace_of_the_step_counts_its_routes(recompute, monkeypatch):
         "attn.sparse_xla": 0,
         "attn.index_pallas": 6 if recompute else 4, "attn.index_xla": 0,
         "attention.flash_bwd_residual": 0 if recompute else 2,
-        "attention.flash_bwd_recomputed": 2 if recompute else 0,
-        # one block of 128 a layer, the diagonal's, each forward lowered
+        "attention.flash_bwd_recomputed": 0,
+        # one block of 128 a layer, the diagonal's
         "attention.flash_blocks_interior": 0,
-        "attention.flash_blocks_edge": 4 if recompute else 2,
+        "attention.flash_blocks_edge": 2,
         "moe.layers_lowered": 2}
-    # a recomputed segment's forward is in the jaxpr once more
-    assert jaxpr.count("name=flash_attention_fwd") >= 2 * (1 + recompute)
     assert jaxpr.count("name=flash_attention_bwd") == 4
-    assert jaxpr.count("name=selected_probs_sum") >= 2 * (1 + recompute)
-    # (the printer names an inner jit's jaxpr once, however many call it)
+    # (the printer names an inner jit's jaxpr once, however many call it:
+    # tests/test_recompute_keep.py counts the launches)
+    assert "name=flash_attention_fwd" in jaxpr
+    assert "name=selected_probs_sum" in jaxpr
     assert "name=index-scores-fwd" in jaxpr
     assert "name=index-scores-bwd" in jaxpr
     assert "i8[1,128,128]" in jaxpr

@@ -474,14 +474,15 @@ def _amp_step(recompute, **changed):
 
 @pytest.mark.parametrize("recompute, rise", [
     (False, (3, 3, 0, 3, 3, 0, 3, 1, 1, 0)),
-    (True, (3, 0, 3, 3, 0, 3, 3, 2, 0, 1))], ids=["plain", "recompute"])
+    (True, (3, 0, 0, 3, 0, 0, 3, 1, 0, 0))], ids=["plain", "recompute"])
 def test_a_trace_of_the_step_counts_its_routes(recompute, rise, monkeypatch):
     """With the flash gate open (here: the interpreter), one trace of the
     AMP train step lowers three delta-rule layers, three group-limited
     expert layers and one flash forward; their backward by each op's grad
     rule on the forward's residuals, or, with a checkpoint at every layer
     boundary, by the same backward functions under `jax.vjp` of a whole
-    layer, the forward lowered once more. The step's jaxpr holds no
+    layer, taken where the layer's segment is lowered, once. The step's
+    jaxpr holds no
     `[S, H, K, V]` value: the states are a chunk's."""
     from paddle_tpu.ops import attention
     monkeypatch.setattr(attention, "_use_pallas",

@@ -545,13 +545,14 @@ def test_builder_names_scopes_and_verifies():
 
 
 @pytest.mark.parametrize("recompute, rise", [
-    (False, (4, 4, 0, 4, 4)), (True, (4, 0, 4, 4, 4))],
+    (False, (4, 4, 0, 4, 4)), (True, (4, 0, 0, 4, 4))],
     ids=["plain", "recompute"])
 def test_a_trace_of_the_amp_step_counts_its_routes(recompute, rise):
     """One trace of the AMP train step lowers four expert layers, each in a
     latent on a bounded buffer; their backward by the op's grad rule on the
     forward's residuals or, with a checkpoint at every layer boundary (the
-    cell's way), under `jax.vjp` of a whole layer. The two projections'
+    cell's way), under `jax.vjp` of a whole layer where its segment is
+    lowered, once (`moe.bwd_recomputed` stays). The two projections'
     scopes reach the compiled step, forward and backward; the experts'
     input reaches the op in bf16 and the router's in float32."""
     exe, loss, ids = harness.amp_step(nemotron_h, model_config(), recompute)
