@@ -224,7 +224,6 @@ class KVClient:
             raise Unavailable("reconnect to pserver %s:%d failed",
                               self._host, self._port)
         self._dead = False
-        stat_add("resilience.reconnects")
 
     def pull(self, table: int, keys: np.ndarray, dim: int) -> np.ndarray:
         keys = np.ascontiguousarray(keys, np.int64)

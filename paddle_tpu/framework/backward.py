@@ -62,9 +62,15 @@ class _GradAccumulator:
         # not be reused — higher-order passes (grad-of-grad) get fresh names
         # (the reference's _rename_grad_ machinery)
         self._taken = set()
+        # the program.name_scope a variable was produced under: the sum of
+        # its repeated gradients is that group's device work
+        self._scope_of: Dict[str, str] = {}
         for op in block.ops:
             self._taken.update(n for n in op.output_names()
                                if n != "@EMPTY@")
+            if op.attrs.get("name_scope"):
+                self._scope_of.update(dict.fromkeys(
+                    op.output_names(), op.attrs["name_scope"]))
 
     def _base_name(self, var_name: str) -> str:
         gname = grad_var_name(var_name)
@@ -102,9 +108,11 @@ class _GradAccumulator:
         fwd = self.block.var(var_name)
         out_var = self.block.create_var(name=sum_out, shape=fwd.shape,
                                         dtype=fwd.dtype, stop_gradient=False)
+        attrs = {"op_role": OpRole.Backward}
+        if var_name in self._scope_of:
+            attrs["name_scope"] = self._scope_of[var_name]
         self.block.append_op("sum", inputs={"X": list(lst)},
-                             outputs={"Out": [sum_out]},
-                             attrs={"op_role": OpRole.Backward})
+                             outputs={"Out": [sum_out]}, attrs=attrs)
         if all(getattr(self.block.var(n), "_is_selected_rows", False)
                for n in lst):   # sparse+sparse stays SelectedRows
             out_var._is_selected_rows = True

@@ -23,12 +23,13 @@ from typing import Dict, List, NamedTuple, Optional, Sequence
 import jax
 import numpy as np
 
-from .program import (Program, Variable, default_main_program,
+from .program import (OpRole, Program, Variable, default_main_program,
                       default_startup_program)
 from .scope import Scope, global_scope
 from .. import monitor
 from ..observability import flight as _flight
 from ..observability import metrics as _metrics
+from ..observability import scopes as _scopes
 from ..observability import trace as _trace
 from ..ops import registry
 
@@ -508,15 +509,9 @@ def _run_block_inner(block, fetch_names, written_state, env, ctx):
             for slot, names in op.inputs.items():
                 ins[slot] = [None if n == "@EMPTY@" else env[n]
                              for n in names]
-            if amp_dtype is not None:
-                ins = _amp_cast(op, ins, amp_dtype)
-            scope = op.attrs.get("name_scope") or (
-                op.type == "__vjp__"
-                and op.attrs["fwd_attrs"].get("name_scope"))
-            # program.name_scope: a group's device work, named
-            with (jax.named_scope(scope) if scope
-                  else contextlib.nullcontext()), \
-                    _op_timer(op.type, op.attrs):
+            with op_scopes(op.type, op.attrs), _op_timer(op.type, op.attrs):
+                if amp_dtype is not None:
+                    ins = _amp_cast(op, ins, amp_dtype)
                 outs = opdef.lower(ctx, ins, op.attrs)
             for slot, names in op.outputs.items():
                 if slot not in outs:
@@ -602,7 +597,6 @@ def _run_block_microbatched(micro_k, block, feed_names, fetch_names,
     reference's per-microbatch scopes share persistables the same way)."""
     import jax
     import jax.numpy as jnp
-    from .program import OpRole
 
     sched_ops, body_ops, post_ops = [], [], []
     for op in block.ops:
@@ -710,6 +704,53 @@ def _run_block_microbatched(micro_k, block, feed_names, fetch_names,
         return fetches, new_state
     finally:
         _lowering_programs.pop()
+
+
+def _phase_of_role(role: int) -> str:
+    """The phase scope of an op from its `op_role`: the update for
+    `Optimize` and `LRSched` (clipping, loss scaling and the finite check
+    go where their role puts them), the backward for every `Backward` op
+    (`__vjp__`, the repeated gradients' `sum`, the loss gradient's seed),
+    else the forward (`Forward`, `Loss`)."""
+    if role & (OpRole.Optimize | OpRole.LRSched):
+        return _scopes.PHASE_OPT
+    if role & OpRole.Backward:
+        return _scopes.PHASE_BWD
+    return _scopes.PHASE_FWD
+
+
+# the phase scope that is open, if one is: an op inside a segment or a
+# rolled layer is lowered under its container's, which is what runs
+_open_phase = None
+
+
+@contextlib.contextmanager
+def op_scopes(op_type, attrs):
+    """The two names every instruction of an op carries in the compiled
+    step's `op_name` and in a profiler capture: outside, the phase of the
+    op's role; inside, its `program.name_scope` (a `__vjp__`'s is its
+    forward op's), where it has one. The ONE place both walks open them
+    (`_run_block_inner`; `parallel/transforms.py` `_run_sub_ops` for the
+    ops inside a segment or a rolled layer, which keep the container's
+    phase: a segment the generic `__vjp__` lowers again is backward work),
+    around the AMP casts of the op's inputs too. A pullback a `__vjp__` op
+    calls (`_segment_grad`) is evaluated under that op's backward scope;
+    the forward a checkpoint runs again is named by JAX
+    (`observability/scopes.py` `classify`)."""
+    global _open_phase
+    scope = attrs.get("name_scope") or (
+        op_type == "__vjp__" and attrs["fwd_attrs"].get("name_scope"))
+    with contextlib.ExitStack() as stack:
+        outer = _open_phase
+        if outer is None:
+            _open_phase = _phase_of_role(attrs.get("op_role", 0))
+            stack.enter_context(jax.named_scope(_open_phase))
+        if scope:
+            stack.enter_context(jax.named_scope(scope))
+        try:
+            yield
+        finally:
+            _open_phase = outer
 
 
 def _amp_cast(op, ins, low_dtype):

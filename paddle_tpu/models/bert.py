@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import layers
 from ..layer_helper import ParamAttr
+from ..framework.program import name_scope
 from ..observability.trace import RecordEvent
 from .. import initializer as I
 from ..parallel.mesh import ShardingRules
@@ -70,34 +71,38 @@ def _attr(name):
 
 def encoder_layer(x, cfg: BertConfig, idx: int, attn_mask=None):
     """One transformer block. Param names carry qkv/proj/ffn markers that the
-    TP sharding rules key on."""
+    TP sharding rules key on. Scopes `attn.proj`, `attn.attend.full`,
+    `ffn.dense` (`moe.switch` with experts); the dropouts, residual adds
+    and layer norms carry the caller's `layer.residual`."""
     h = cfg.hidden_size
     nh = cfg.num_heads
     hd = h // nh
     pre = x
 
-    # fused QKV projection (one MXU matmul instead of three)
-    qkv = layers.fc(x, 3 * h, num_flatten_dims=2,
-                    param_attr=_attr(f"enc{idx}_attn_qkv_w"),
-                    bias_attr=ParamAttr(name=f"enc{idx}_attn_qkv_b"))
-    q, k, v = layers.split(qkv, 3, dim=2)
-
     def heads(t):
         t = layers.reshape(t, [0, 0, nh, hd])
         return layers.transpose(t, [0, 2, 1, 3])  # [B, nh, S, hd]
 
-    q, k, v = heads(q), heads(k), heads(v)
+    with name_scope("attn.proj"):
+        # fused QKV projection (one MXU matmul instead of three)
+        qkv = layers.fc(x, 3 * h, num_flatten_dims=2,
+                        param_attr=_attr(f"enc{idx}_attn_qkv_w"),
+                        bias_attr=ParamAttr(name=f"enc{idx}_attn_qkv_b"))
+        q, k, v = layers.split(qkv, 3, dim=2)
+        q, k, v = heads(q), heads(k), heads(v)
     # sp and non-sp train with the SAME dropout/mask semantics (round 4:
     # the ring/ulysses paths take key-padding masks + counter dropout)
-    ctx = layers.fused_attention(
-        q, k, v, mask=attn_mask, scale=1.0 / math.sqrt(hd),
-        dropout=cfg.attention_dropout,
-        sequence_parallel=cfg.sequence_parallel, sp_mode=cfg.sp_mode)
-    ctx = layers.transpose(ctx, [0, 2, 1, 3])
-    ctx = layers.reshape(ctx, [0, 0, h])
-    proj = layers.fc(ctx, h, num_flatten_dims=2,
-                     param_attr=_attr(f"enc{idx}_attn_proj_w"),
-                     bias_attr=ParamAttr(name=f"enc{idx}_attn_proj_b"))
+    with name_scope("attn.attend.full"):
+        ctx = layers.fused_attention(
+            q, k, v, mask=attn_mask, scale=1.0 / math.sqrt(hd),
+            dropout=cfg.attention_dropout,
+            sequence_parallel=cfg.sequence_parallel, sp_mode=cfg.sp_mode)
+    with name_scope("attn.proj"):
+        ctx = layers.transpose(ctx, [0, 2, 1, 3])
+        ctx = layers.reshape(ctx, [0, 0, h])
+        proj = layers.fc(ctx, h, num_flatten_dims=2,
+                         param_attr=_attr(f"enc{idx}_attn_proj_w"),
+                         bias_attr=ParamAttr(name=f"enc{idx}_attn_proj_b"))
     if cfg.hidden_dropout:
         proj = layers.dropout(proj, cfg.hidden_dropout,
                               dropout_implementation="upscale_in_train")
@@ -110,17 +115,20 @@ def encoder_layer(x, cfg: BertConfig, idx: int, attn_mask=None):
     aux = None
     if cfg.moe_experts > 0:
         # switch-MoE FFN: experts shard over the ep mesh axis (ops/moe.py)
-        ffn, aux = layers.switch_moe(
-            x, num_experts=cfg.moe_experts, d_ff=cfg.intermediate_size,
-            capacity_factor=cfg.moe_capacity_factor, name=f"enc{idx}_moe")
+        with name_scope("moe.switch"):
+            ffn, aux = layers.switch_moe(
+                x, num_experts=cfg.moe_experts, d_ff=cfg.intermediate_size,
+                capacity_factor=cfg.moe_capacity_factor,
+                name=f"enc{idx}_moe")
     else:
-        ffn = layers.fc(x, cfg.intermediate_size, num_flatten_dims=2,
-                        act="gelu",
-                        param_attr=_attr(f"enc{idx}_ffn_in_w"),
-                        bias_attr=ParamAttr(name=f"enc{idx}_ffn_in_b"))
-        ffn = layers.fc(ffn, h, num_flatten_dims=2,
-                        param_attr=_attr(f"enc{idx}_ffn_out_w"),
-                        bias_attr=ParamAttr(name=f"enc{idx}_ffn_out_b"))
+        with name_scope("ffn.dense"):
+            ffn = layers.fc(x, cfg.intermediate_size, num_flatten_dims=2,
+                            act="gelu",
+                            param_attr=_attr(f"enc{idx}_ffn_in_w"),
+                            bias_attr=ParamAttr(name=f"enc{idx}_ffn_in_b"))
+            ffn = layers.fc(ffn, h, num_flatten_dims=2,
+                            param_attr=_attr(f"enc{idx}_ffn_out_w"),
+                            bias_attr=ParamAttr(name=f"enc{idx}_ffn_out_b"))
     if cfg.hidden_dropout:
         ffn = layers.dropout(ffn, cfg.hidden_dropout,
                              dropout_implementation="upscale_in_train")
@@ -139,10 +147,10 @@ def bert_encoder(input_ids, cfg: BertConfig, position_ids=None,
     aux_losses = []
     ckpts = []
     stage = _stage_guard(cfg)
-    with stage(0):
+    with stage(0), name_scope("embed.tokens"):
         x = _bert_embeddings(input_ids, cfg)
     for i in range(cfg.num_layers):
-        with stage(_layer_stage(cfg, i)):
+        with stage(_layer_stage(cfg, i)), name_scope("layer.residual"):
             x = encoder_layer(x, cfg, i, attn_mask)
         if cfg.moe_experts > 0:
             x, aux = x
@@ -234,7 +242,7 @@ def bert_pretrain_loss(seq_out, mlm_labels, cfg: BertConfig):
         fused = (cfg.seq_len >= 512
                  and cfg.vocab_size >= 2 * DEFAULT_CHUNK
                  and not _tp_vocab_shards_head())
-    with _stage_guard(cfg)(_last_stage(cfg)):
+    with _stage_guard(cfg)(_last_stage(cfg)), name_scope("head.mlm"):
         if fused:
             hidden = cfg.hidden_size
             w = layers.create_parameter([hidden, cfg.vocab_size],
@@ -274,14 +282,16 @@ def build_pretrain_program(cfg: BertConfig, use_input_mask=False):
         if use_input_mask:
             input_mask = layers.data(name="input_mask", shape=[cfg.seq_len],
                                      dtype="float32")
-            attn_mask = layers.unsqueeze(
-                layers.scale(input_mask, scale=1e9, bias=-1e9), [1, 2])
+            with name_scope("attn.mask"):
+                attn_mask = layers.unsqueeze(
+                    layers.scale(input_mask, scale=1e9, bias=-1e9), [1, 2])
         seq = bert_encoder(input_ids, cfg, attn_mask=attn_mask)
         loss = bert_pretrain_loss(seq, mlm_labels, cfg)
         aux = getattr(seq, "_moe_aux_losses", None)
         if aux:   # switch_moe load-balancing term (Switch eq. 4, scale 0.01)
-            loss = layers.elementwise_add(
-                loss, layers.scale(layers.sums(aux), 0.01 / len(aux)))
+            with name_scope("head.mlm"):
+                loss = layers.elementwise_add(
+                    loss, layers.scale(layers.sums(aux), 0.01 / len(aux)))
         loss._layer_checkpoints = getattr(seq, "_layer_checkpoints", [])
     return input_ids, mlm_labels, loss
 

@@ -70,6 +70,13 @@ def gated_ffn(x, width, pre, cfg):
         cfg.hidden_size, pre + "down_w", cfg)
 
 
+def dense_ffn(x, width, pre, cfg):
+    """A dense layer's feed-forward part, `gated_ffn` under the scope
+    `ffn.dense` (the shared expert's is `moe.shared`)."""
+    with name_scope("ffn.dense"):
+        return gated_ffn(x, width, pre, cfg)
+
+
 def relu2_ffn(x, width, pre, cfg):
     """W_down relu(W_up x)^2."""
     return _linear(layers.relu2(_linear(x, width, pre + "up_w", cfg)),
@@ -98,7 +105,9 @@ def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
     its parameters are created after the experts' and before the shared
     expert's); the router reads x. `shared` (ffn, width): a shared expert
     `ffn(x, width, pre + "shared_", cfg)` (`gated_ffn` or `relu2_ffn`) added
-    to the routed part under the scope `moe.shared`."""
+    to the routed part under the scope `moe.shared`. The `routed_moe` op
+    itself carries `moe.io`: what it does outside the four parts its
+    lowering names (the AMP casts of the experts it reads, reshapes)."""
     h, f = cfg.hidden_size, cfg.moe_intermediate_size
     width, into, out_of = latent or (h, None, None)
     held = cfg.experts_held or experts_total
@@ -118,13 +127,15 @@ def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
                            initializer=I.Constant(0.0)))
     gate = experts("gate", width, f) if gated else None
     up, down = experts("up", width, f), experts("down", f, width)
-    routed, idx, load = layers.routed_moe(
-        x, gate_w, gate, up, down, top_k=cfg.num_experts_per_tok,
-        select_bias=bias, routed_scaling=routed_scaling,
-        norm_topk=cfg.norm_topk_prob, experts_total=experts_total,
-        expert_offset=cfg.expert_offset, scoring=scoring, n_group=n_group,
-        topk_group=topk_group, expert_input=into(x) if latent else None,
-        norm_topk_eps=norm_topk_eps)
+    expert_input = into(x) if latent else None
+    with name_scope("moe.io"):
+        routed, idx, load = layers.routed_moe(
+            x, gate_w, gate, up, down, top_k=cfg.num_experts_per_tok,
+            select_bias=bias, routed_scaling=routed_scaling,
+            norm_topk=cfg.norm_topk_prob, experts_total=experts_total,
+            expert_offset=cfg.expert_offset, scoring=scoring,
+            n_group=n_group, topk_group=topk_group,
+            expert_input=expert_input, norm_topk_eps=norm_topk_eps)
     if latent:
         routed = out_of(routed)
     if not shared:
@@ -194,13 +205,16 @@ def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
 def embed_tokens(cfg):
     """(tokens [B, seq_len] int64, their embeddings [B, seq_len, hidden],
     the embedding parameter [vocab, hidden]), the lookup a gather of the
-    rows held."""
+    rows held; scope `embed.tokens` (the gather, and in the backward its
+    scatter-add)."""
     s, h = cfg.seq_len, cfg.hidden_size
     tokens = layers.data(name="tokens", shape=[s], dtype="int64")
     embed = layers.create_parameter([cfg.vocab_size, h], "float32",
                                     attr=_w("embed_tokens", cfg))
-    return tokens, layers.reshape(
-        layers.gather(embed, layers.reshape(tokens, [-1])), [-1, s, h]), embed
+    with name_scope("embed.tokens"):
+        return tokens, layers.reshape(
+            layers.gather(embed, layers.reshape(tokens, [-1])),
+            [-1, s, h]), embed
 
 
 def next_token_loss(x, tokens, cfg, tied=None):
@@ -208,6 +222,8 @@ def next_token_loss(x, tokens, cfg, tied=None):
     entropy of every position but a row's last against the token that
     follows it. All `seq_len` positions go through the head (the last one's
     label is the ignore index), so no shape in the step is `seq_len - 1`.
+    Scopes `head.norm`, `head.untied` or `head.tied` (the matmul over the
+    vocabulary), `head.loss` (the labels, the cross entropy, its mean).
 
     `tied`: a tied head, the embedding parameter [vocab, hidden] itself:
     the logits are `norm(x) E^T` under the scope `head.tied`, no `lm_head_w`
@@ -216,17 +232,22 @@ def next_token_loss(x, tokens, cfg, tied=None):
     the gather's scatter-add and the matmul's (`framework/backward.py` adds
     up a variable's repeated gradients). None: an untied `lm_head_w`."""
     s = cfg.seq_len
-    x = _norm(x, "final_norm_scale", cfg)
+    with name_scope("head.norm"):
+        x = _norm(x, "final_norm_scale", cfg)
     if tied is None:
-        logits = _linear(x, cfg.vocab_size, "lm_head_w", cfg)
+        with name_scope("head.untied"):
+            logits = _linear(x, cfg.vocab_size, "lm_head_w", cfg)
     else:
         with name_scope("head.tied"):
             logits = layers.matmul(x, tied, transpose_y=True)
-    nxt = layers.slice(tokens, [1], [1], [s])
-    none = layers.fill_constant_batch_size_like(nxt, [-1, 1], "int64", -100)
-    labels = layers.unsqueeze(layers.concat([nxt, none], axis=1), [2])
-    ce = layers.softmax_with_cross_entropy(logits, labels, ignore_index=-100)
-    return layers.scale(layers.mean(ce), scale=s / (s - 1.0))
+    with name_scope("head.loss"):
+        nxt = layers.slice(tokens, [1], [1], [s])
+        none = layers.fill_constant_batch_size_like(nxt, [-1, 1], "int64",
+                                                    -100)
+        labels = layers.unsqueeze(layers.concat([nxt, none], axis=1), [2])
+        ce = layers.softmax_with_cross_entropy(logits, labels,
+                                               ignore_index=-100)
+        return layers.scale(layers.mean(ce), scale=s / (s - 1.0))
 
 
 def build_causal_lm_program(cfg, model, decoder_layer, layer_indices,
@@ -255,7 +276,8 @@ def build_causal_lm_program(cfg, model, decoder_layer, layer_indices,
         tokens, x, embed = embed_tokens(cfg)
         ckpts, routed = [], []
         for n in layer_indices:
-            x, r = decoder_layer(x, cfg, n)
+            with name_scope("layer.residual"):
+                x, r = decoder_layer(x, cfg, n)
             ckpts.append(x.name)
             if r is not None:
                 routed.append(r)
@@ -263,7 +285,8 @@ def build_causal_lm_program(cfg, model, decoder_layer, layer_indices,
         loss = next_token_loss(x, tokens, cfg,
                                tied=embed if tie_head else None)
         if auxiliary:
-            lm_loss, loss = loss, layers.sums([loss] + list(auxiliary))
+            with name_scope("head.loss"):
+                lm_loss, loss = loss, layers.sums([loss] + list(auxiliary))
             loss._lm_loss, loss._auxiliary_losses = lm_loss, list(auxiliary)
         loss._layer_checkpoints = ckpts
         return tokens, loss, routed

@@ -29,7 +29,7 @@ from .. import layers
 from ..framework.program import name_scope
 from ..parallel.mesh import ShardingRules
 from . import causal_lm
-from .causal_lm import (_heads, _linear, _norm, gated_ffn,
+from .causal_lm import (_heads, _linear, _norm, dense_ffn, gated_ffn,
                         record_expert_load)
 
 __all__ = ["DeepseekV3Config", "build_causal_lm_program",
@@ -122,7 +122,7 @@ def decoder_layer(x, cfg: DeepseekV3Config, n: int):
     f = _norm(x, pre + "ffn_norm_scale", cfg)
     if n < cfg.first_k_dense_replace:
         return layers.elementwise_add(
-            x, gated_ffn(f, cfg.intermediate_size, pre + "mlp_", cfg)), None
+            x, dense_ffn(f, cfg.intermediate_size, pre + "mlp_", cfg)), None
     y, idx, load = expert_layer(f, cfg, pre)
     return layers.elementwise_add(x, y), (idx, load)
 

@@ -123,7 +123,8 @@ def indexer(a, cfg: KeyeConfig, pre: str, positions=None):
             cfg, positions)
         w = layers.scale(_linear(a, nh, pre + "indexer_head_w", cfg),
                          scale=nh ** -0.5 * hd ** -0.5)
-    return layers.sparse_index(q, k, w, cfg.index_topk)
+    with name_scope("dsa.io"):
+        return layers.sparse_index(q, k, w, cfg.index_topk)
 
 
 def decoder_layer(x, cfg: KeyeConfig, n: int, positions, index_losses,
@@ -137,10 +138,11 @@ def decoder_layer(x, cfg: KeyeConfig, n: int, positions, index_losses,
     attended, target = causal_lm.grouped_attention(
         a, cfg, pre, cfg.num_attention_heads, cfg.num_key_value_heads,
         rotary=lambda t: _rotary(t, cfg, positions), selection=select)
-    index_losses.append(layers.sparse_index_loss(scores, select, target))
-    # a copy nothing reads: fetchable where the layer is a recomputed
-    # segment, gone from the compiled step where it is not fetched
-    selections.append((layers.assign(select), pairs))
+    with name_scope("dsa.io"):
+        index_losses.append(layers.sparse_index_loss(scores, select, target))
+        # a copy nothing reads: fetchable where the layer is a recomputed
+        # segment, gone from the compiled step where it is not fetched
+        selections.append((layers.assign(select), pairs))
     x = layers.elementwise_add(x, attended)
     y, idx, load = causal_lm.expert_layer(
         _norm(x, pre + "ffn_norm_scale", cfg), cfg, pre,

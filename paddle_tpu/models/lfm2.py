@@ -37,7 +37,7 @@ from .. import layers
 from ..framework.program import name_scope
 from ..parallel.mesh import ShardingRules
 from . import causal_lm
-from .causal_lm import _linear, _norm, _w, gated_ffn, record_expert_load
+from .causal_lm import _linear, _norm, _w, dense_ffn, record_expert_load
 
 __all__ = ["Lfm2Config", "build_causal_lm_program", "record_expert_load",
            "sharding_rules"]
@@ -140,7 +140,7 @@ def decoder_layer(x, cfg: Lfm2Config, n: int):
     f = _norm(x, pre + "ffn_norm_scale", cfg)
     if n < cfg.num_dense_layers:
         return layers.elementwise_add(
-            x, gated_ffn(f, cfg.intermediate_size, pre + "mlp_", cfg)), None
+            x, dense_ffn(f, cfg.intermediate_size, pre + "mlp_", cfg)), None
     y, idx, load = expert_layer(f, cfg, pre)
     return layers.elementwise_add(x, y), (idx, load)
 

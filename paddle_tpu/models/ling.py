@@ -39,7 +39,7 @@ from ..framework.program import name_scope
 from ..layer_helper import ParamAttr
 from ..parallel.mesh import ShardingRules
 from . import causal_lm
-from .causal_lm import (_heads, _linear, _norm, _w, gated_ffn,
+from .causal_lm import (_heads, _linear, _norm, _w, dense_ffn, gated_ffn,
                         record_expert_load)
 
 __all__ = ["LingConfig", "build_causal_lm_program", "record_expert_load",
@@ -229,7 +229,7 @@ def decoder_layer(x, cfg: LingConfig, n: int):
     f = _norm(x, pre + "ffn_norm_scale", cfg)
     if n < cfg.first_k_dense_replace:
         return layers.elementwise_add(
-            x, gated_ffn(f, cfg.intermediate_size, pre + "mlp_", cfg)), None
+            x, dense_ffn(f, cfg.intermediate_size, pre + "mlp_", cfg)), None
     y, idx, load = expert_layer(f, cfg, pre, n)
     return layers.elementwise_add(x, y), (idx, load)
 

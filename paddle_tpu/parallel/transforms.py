@@ -64,7 +64,7 @@ def _run_sub_ops(ctx, sub_ops, env, amp_dtype, seed_overrides=None):
     casts (the top-level executor loop applies these per op; fused
     sub-graphs must match) and optional per-op __rng_seed__ overrides
     (traced per-layer seeds inside the scan body)."""
-    from ..framework.executor import _amp_cast_ins, _op_timer
+    from ..framework.executor import _amp_cast_ins, _op_timer, op_scopes
     for j, od in enumerate(sub_ops):
         opdef = registry.get(od["type"])
         op_ins = {s: [None if n == "@EMPTY@" else env[n] for n in ns]
@@ -73,14 +73,13 @@ def _run_sub_ops(ctx, sub_ops, env, amp_dtype, seed_overrides=None):
         if seed_overrides is not None and seed_overrides[j] is not None:
             at = dict(at)
             at["__rng_seed__"] = seed_overrides[j]
-        if amp_dtype is not None:
-            op_ins = _amp_cast_ins(od["type"], op_ins, amp_dtype)
-        # program.name_scope, as the executor's own op loop applies it: a
-        # group's device work keeps its name inside a segment. _op_timer:
-        # into the walk's `by_op` table under the op's own type, not the
-        # container's (executor.lower_block)
-        with (jax.named_scope(at["name_scope"]) if at.get("name_scope")
-              else contextlib.nullcontext()), _op_timer(od["type"], at):
+        # the phase and program.name_scope, as the executor's own op loop
+        # opens them: a group's device work keeps its names inside a
+        # segment. _op_timer: into the walk's `by_op` table under the op's
+        # own type, not the container's (executor.lower_block)
+        with op_scopes(od["type"], at), _op_timer(od["type"], at):
+            if amp_dtype is not None:
+                op_ins = _amp_cast_ins(od["type"], op_ins, amp_dtype)
             outs = opdef.lower(ctx, op_ins, at)
         for s, ns in od["outputs"].items():
             if s not in outs:

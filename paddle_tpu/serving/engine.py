@@ -1257,7 +1257,6 @@ class DecodeEngine:
                     self.cache.release(slot_idx)     # have released it
                 with self._cv:
                     self._slots.pop(slot_idx, None)
-                _metrics.inc("serving.prefill_failures")
                 if self._failover is not None:
                     self._failover(self, [(req, handle)],
                                    f"prefill failed: {e!r}",

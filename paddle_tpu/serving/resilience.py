@@ -330,7 +330,6 @@ class ServingFrontend:
         # without this, N transient canary timeouts spread over the
         # engine's lifetime would permanently disable its resurrection
         self._unexpected_errors.pop(id(eng), None)
-        _trace.instant("serving.resurrected", args={"engine": eng._id})
 
     def _try_resurrect_draft(self, eng):
         policy = RetryPolicy(

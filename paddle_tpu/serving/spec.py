@@ -204,9 +204,6 @@ class SpecDecoder:
             self.health = state
             self.health_history.append(state)
             del self.health_history[:-64]
-            _trace.instant("serving.spec.health",
-                           args={"engine": self.engine._id,
-                                 "state": state})
 
     def _degrade(self, why: str):
         """Draft failure -> plain decode. SUSPECT when a frontend is
@@ -345,14 +342,11 @@ class SpecDecoder:
                      (pt, tokens, pos, gen, live, temps, top_ks, seeds,
                       eos, max_new))
         scales = d.scales if d.scales is not None else {}
-        with _trace.RecordEvent("serving.spec_draft",
-                                args={"engine": eng._id,
-                                      "active": len(covered)}):
-            k_pool, v_pool, toks, _ = d._window_jit(
-                d.params, scales, d.cache.k_pool, d.cache.v_pool, *args,
-                d._window_max_blocks())
-            d.cache.update_pools(k_pool, v_pool)
-            toks = np.asarray(toks)             # [gamma, B]
+        k_pool, v_pool, toks, _ = d._window_jit(
+            d.params, scales, d.cache.k_pool, d.cache.v_pool, *args,
+            d._window_max_blocks())
+        d.cache.update_pools(k_pool, v_pool)
+        toks = np.asarray(toks)             # [gamma, B]
         props: Dict[int, List[int]] = {}
         for i in covered:
             chain = [int(toks[s, i]) for s in range(gamma)]
