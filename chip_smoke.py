@@ -29,6 +29,10 @@ published width of models the repo already has:
   index_scores                 the sparse-attention indexer's two score
                                kernels at the learned-selection cell's
                                shape, likewise
+  head_rows                    the masked-LM head's op alone, forward and
+                               backward, at the BERT cells' shape and kept
+                               shares: it computes whole blocks of the
+                               labelled rows and no others
   recompute_keep               the learned-selection cell's train step
                                (5 layers under `strategy.recompute`) built
                                and compiled, not run: its Mosaic calls by
@@ -93,6 +97,11 @@ FULL = {
     # one layer's indexer at the learned-selection cell's size: 16 heads of
     # 64 over 1 x 8,192 positions, 2,048 keys a query
     "index": {"b": 1, "h": 16, "s": 8192, "d": 64, "topk": 2048},
+    # the masked-LM head at the BERT cells' shape: 16,384 rows of 768
+    # against 30,522 vocabulary rows, labelled as `s512_b32_padded` (1,843
+    # rows), as `s128_b128x4` (2,432) and as a causal LM (every row)
+    "head": {"batch": 32, "seq": 512, "hidden": 768, "vocab": 30522,
+             "shares": (0.1125, 0.1484, 1.0)},
     # the learned-selection cell's language model as one chip holds it
     # (benchmark/configs/keye_vl2_30b_a3b_ep8.json): 5 layers, 32 heads of
     # 128 on 4 KV heads, 16 of 128 experts, 18,992 vocabulary rows, 1 x
@@ -126,6 +135,8 @@ TINY = {
              "chunk": 128},
     "delta": {"b": 1, "s": 128, "h": 2, "d": 128, "chunk": 64},
     "index": {"b": 1, "h": 2, "s": 256, "d": 64, "topk": 40},
+    "head": {"batch": 4, "seq": 320, "hidden": 32, "vocab": 200,
+             "shares": (0.1125, 1.0), "chunk": 64},
     "keep": dict(vocab_size=256, hidden_size=64, num_hidden_layers=2,
                  num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                  moe_intermediate_size=32, num_experts=8,
@@ -1193,6 +1204,121 @@ def leg_index_scores(preset, clock):
 
 
 # ---------------------------------------------------------------------------
+# the masked-LM head over the labelled rows
+# ---------------------------------------------------------------------------
+HEAD_GAP = 2e-2     # bf16 operands, float32 sums, against float32 throughout
+
+
+def head_rows_forms(batch, seq, hidden, vocab, shares, chunk=None,
+                    row_blocks=(None,), launches=10, seed=17):
+    """`fused_lm_head_ce`'s lowering alone on `[batch, seq, hidden]` bf16
+    rows against an `[hidden, vocab]` bf16 weight and a float32 bias (what
+    an AMP BERT step hands it), labelled at each of `shares` of the rows,
+    the labels scattered: the rows it computes (`Rows`, and gauge
+    `head.rows_computed_share`), which must be the labelled count up to
+    whole blocks, and the host-clock milliseconds a launch of the forward
+    and of the forward with the backward (a time only on a chip). At the
+    first share the loss and the three gradients are held against the
+    dense pair in float32. `row_blocks`: `ops/fused_ce.py` `ROW_BLOCK`
+    values to run in place of the file's own (None), the sweep's handle."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.ops import fused_ce
+
+    rng = np.random.RandomState(seed)
+    n_rows = batch * seq
+    x = jnp.asarray(rng.randn(batch, seq, hidden), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(hidden, vocab) * hidden ** -0.5, jnp.bfloat16)
+    b = jnp.asarray(rng.randn(vocab) * 0.1, jnp.float32)
+    attrs = {"w_layout": "hv", "chunk": chunk}
+
+    def op(x, w, b, labels):
+        outs = fused_ce._fused_lm_head_ce(
+            None, {"X": [x], "W": [w], "Bias": [b], "Label": [labels]},
+            attrs)
+        return outs["Loss"][0], outs["Rows"][0]
+
+    def mean_loss(x, w, b, labels):
+        loss, rows = op(x, w, b, labels)
+        return jnp.mean(loss), rows
+
+    def dense(x, w, b, labels):
+        f32 = jnp.float32
+        logits = jnp.einsum("bsh,hv->bsv", x.astype(f32), w.astype(f32),
+                            precision="highest") + b
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        lab = labels[..., 0]
+        got = jnp.take_along_axis(logits, jnp.maximum(lab, 0)[..., None],
+                                  axis=-1)[..., 0]
+        return jnp.mean(jnp.where(lab == -100, 0.0, lse - got))
+
+    def labels_at(share):
+        kept = int(round(share * n_rows))
+        lab = np.full((n_rows,), -100, np.int64)
+        lab[rng.permutation(n_rows)[:kept]] = rng.randint(0, vocab, kept)
+        return kept, jnp.asarray(lab.reshape(batch, seq, 1))
+
+    facts = {}
+    for block in row_blocks:
+        own = fused_ce.ROW_BLOCK
+        if block is not None:
+            fused_ce.ROW_BLOCK = block
+        try:
+            r = fused_ce._row_block(n_rows)
+            fwd = jax.jit(op)
+            both = jax.jit(jax.value_and_grad(mean_loss, argnums=(0, 1, 2),
+                                              has_aux=True))
+            for i, share in enumerate(shares):
+                kept, labels = labels_at(share)
+                (loss, rows), grads = both(x, w, b, labels)
+                rows = int(rows[0])
+                check(rows == -(-kept // r) * r,
+                      f"{kept} labelled rows in blocks of {r}: the op "
+                      f"computed {rows}")
+                got = fused_ce.record_rows_share(rows, n_rows)
+                check(metrics.get("head.rows_computed_share") == got,
+                      "gauge head.rows_computed_share was not set")
+                row = {"labelled": kept, "rows_computed": rows,
+                       "rows_computed_share": round(got, 4),
+                       "ms_fwd": _ms_a_launch(launches, fwd, x, w, b,
+                                              labels),
+                       "ms_fwd_bwd": _ms_a_launch(launches, both, x, w, b,
+                                                  labels)}
+                if i == 0:
+                    want, want_g = jax.jit(jax.value_and_grad(
+                        dense, argnums=(0, 1, 2)))(x, w, b, labels)
+                    gaps = {"loss": abs(float(loss) - float(want))
+                            / abs(float(want))}
+                    for name, g, wg in zip(("dx", "dw", "db"), grads,
+                                           want_g):
+                        g, wg = (np.asarray(a, np.float32) for a in (g, wg))
+                        gaps[name] = float(np.abs(g - wg).max()
+                                           / np.abs(wg).max())
+                    check(all(v <= HEAD_GAP for v in gaps.values()),
+                          f"head over labelled rows against the dense "
+                          f"pair in float32: {gaps}")
+                    row["gaps"] = {k: round(v, 6) for k, v in gaps.items()}
+                facts[f"r{r}_share{share}"] = row
+        finally:
+            if block is not None:       # what was traced with it must go
+                fused_ce.ROW_BLOCK = own
+                jax.clear_caches()
+    return facts
+
+
+def leg_head_rows(preset, clock):
+    facts = head_rows_forms(**preset["head"])
+    for name, row in facts.items():
+        print(f"[chip_smoke] head_rows {name}: {row['labelled']} labelled, "
+              f"{row['rows_computed']} rows computed "
+              f"(head.rows_computed_share {row['rows_computed_share']}), "
+              f"fwd {row['ms_fwd']} ms, fwd+bwd {row['ms_fwd_bwd']} ms",
+              flush=True)
+    return facts
+
+
+# ---------------------------------------------------------------------------
 # what a recomputed segment keeps: the learned-selection cell's step
 # ---------------------------------------------------------------------------
 def leg_recompute_keep(preset, clock):
@@ -1333,6 +1459,7 @@ LEGS = (("train_bert_base_s128", leg_train_s128),
         ("ssm_scan", leg_ssm_scan),
         ("kda_scan", leg_kda_scan),
         ("index_scores", leg_index_scores),
+        ("head_rows", leg_head_rows),
         ("recompute_keep", leg_recompute_keep),
         ("four_chips", leg_four_chips))
 

@@ -11,13 +11,15 @@ __all__ = [
 
 
 def fused_lm_head_ce(x, w, label, chunk=None, bias=None, w_layout="vh",
-                     ignore_index=-100):
+                     ignore_index=-100, return_rows=False):
     """Streaming LM-head + cross-entropy: per-token CE of the logits
     `x @ w^T (+ bias)` against `label`, WITHOUT materializing the
     [B, S, V] logits (vocab-chunked online logsumexp; backward
-    recomputes chunks — ops/fused_ce.py). Numerically equivalent to the
-    dense matmul/fc + softmax_with_cross_entropy pair at a fraction of
-    the peak memory when V is large.
+    recomputes chunks — ops/fused_ce.py) and computed only for the rows
+    that carry a label: work is proportional to the labelled count, in
+    steps of one block of `ops/fused_ce.ROW_BLOCK` rows. Numerically
+    equivalent to the dense matmul/fc + softmax_with_cross_entropy pair
+    at a fraction of the peak memory when V is large.
 
     x: [B, S, H]; w: [V, H] (`w_layout="vh"`, e.g. a tied embedding) or
     [H, V] (`w_layout="hv"`, an fc head weight); bias: optional [V];
@@ -27,17 +29,24 @@ def fused_lm_head_ce(x, w, label, chunk=None, bias=None, w_layout="vh",
     that token — loud where the dense gather would be garbage.
     chunk=None uses ops/fused_ce.DEFAULT_CHUNK (the same constant the
     models' auto-select thresholds key on). Returns per-token loss
-    [B, S, 1] (f32)."""
+    [B, S, 1] (f32); with `return_rows` also the op's `Rows` output, an
+    int32 [1] a program may fetch: the rows it computed, the labelled
+    count up to whole blocks (`ops/fused_ce.record_rows_share` turns a
+    fetched value into gauge `head.rows_computed_share`)."""
     helper = LayerHelper("fused_lm_head_ce")
     loss = helper.create_variable_for_type_inference("float32")
     inputs = {"X": [x], "W": [w], "Label": [label]}
     if bias is not None:
         inputs["Bias"] = [bias]
-    helper.append_op("fused_lm_head_ce", inputs=inputs,
-                     outputs={"Loss": [loss]},
+    outputs = {"Loss": [loss]}
+    if return_rows:
+        rows = helper.create_variable_for_type_inference("int32")
+        rows.stop_gradient = True
+        outputs["Rows"] = [rows]
+    helper.append_op("fused_lm_head_ce", inputs=inputs, outputs=outputs,
                      attrs={"chunk": chunk, "w_layout": w_layout,
                             "ignore_index": ignore_index})
-    return loss
+    return (loss, rows) if return_rows else loss
 
 
 def cross_entropy(input, label, soft_label=False, ignore_index=-100):

@@ -41,12 +41,12 @@ class BertConfig:
     # >0: annotate device_guard stages for pipeline parallelism over the pp
     # mesh axis (embeddings stage 0, layers round-robin, head last stage)
     pipeline_stages: int = 0
-    # MLM head as the vocab-chunked streaming CE (ops/fused_ce.py).
-    # None = auto: only at long sequence (>= 512) AND real vocab
-    # (>= 2x the chunk), where the [B,S,V] logits are the memory peak —
-    # at the short-seq bench geometry the dense head fits fine and the
-    # fused backward's chunk recompute (~+7% model FLOPs) would be pure
-    # loss. True/False forces.
+    # MLM head as the vocab-chunked streaming CE over the labelled rows
+    # (ops/fused_ce.py). None = auto: at a real vocab (>= 2x the chunk),
+    # whatever the sequence length — the op computes only the rows that
+    # carry a label, a seventh of a masked LM's, where the dense pair
+    # computes and writes [B, S, V] float32 logits for all of them.
+    # True/False forces.
     fused_mlm_head: "bool | None" = None
 
     @staticmethod
@@ -228,19 +228,21 @@ def _tp_vocab_shards_head() -> bool:
 def bert_pretrain_loss(seq_out, mlm_labels, cfg: BertConfig):
     """Masked-LM head + loss (ERNIE pretraining objective).
 
-    With `cfg.fused_mlm_head` (auto at long seq + real vocab, and only
-    when tensor parallelism does not vocab-shard the head weight —
-    `_tp_vocab_shards_head`) the head runs as the vocab-chunked
-    fused_lm_head_ce (ops/fused_ce.py), which never materializes the
-    [B, S, V] logits — same parameter names/shapes as the dense fc head,
-    so checkpoints are interchangeable. Label contract is identical on
-    both paths for the default ignore_index (-100): ignored tokens
-    contribute zero loss and zero grads."""
+    With `cfg.fused_mlm_head` (auto at a real vocab, at any sequence
+    length, and only when tensor parallelism does not vocab-shard the
+    head weight — `_tp_vocab_shards_head`) the head runs as the
+    vocab-chunked fused_lm_head_ce (ops/fused_ce.py), which computes only
+    the rows that carry a label (as the published BERT gathers the masked
+    positions before its head) and never materializes the [B, S, V]
+    logits — same parameter names/shapes as the dense fc head, so
+    checkpoints are interchangeable. A tiny vocabulary keeps the dense
+    pair. Label contract is identical on both paths for the default
+    ignore_index (-100): ignored tokens contribute zero loss and zero
+    grads."""
     from ..ops.fused_ce import DEFAULT_CHUNK
     fused = cfg.fused_mlm_head
     if fused is None:
-        fused = (cfg.seq_len >= 512
-                 and cfg.vocab_size >= 2 * DEFAULT_CHUNK
+        fused = (cfg.vocab_size >= 2 * DEFAULT_CHUNK
                  and not _tp_vocab_shards_head())
     with _stage_guard(cfg)(_last_stage(cfg)), name_scope("head.mlm"):
         if fused:

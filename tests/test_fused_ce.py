@@ -5,7 +5,12 @@ match the dense matmul+softmax_with_cross_entropy pair to float
 tolerance, with a chunk size that forces multiple scan steps AND a
 ragged final chunk. Memory: the fused program's largest live tensor
 must stay chunk-sized where the dense one materializes [B, S, V]
-logits (asserted on optimized HLO — no hardware needed)."""
+logits (asserted on optimized HLO — no hardware needed). Rows: only the
+rows that carry a label are computed, in whole blocks of `ROW_BLOCK`
+(parity at every kept count against the dense float32 pair, the row
+counts of every product in the jaxpr, the op's `Rows` output)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -178,10 +183,12 @@ def test_fused_ce_hv_layout_with_bias_matches_fc():
 
 
 def test_bert_auto_selects_fused_head():
-    """BERT auto rule: fused MLM head only at long seq AND real vocab —
-    at the short-seq bench geometry the dense head fits HBM and the
-    fused backward's recompute would cost ~7% model FLOPs for nothing."""
+    """BERT auto rule: the fused MLM head at a real vocab whatever the
+    sequence length (the op computes the labelled rows only, so the short
+    geometry gains as the long one does); a vocab under 2x the chunk keeps
+    the dense pair; an explicit `fused_mlm_head` wins either way."""
     from paddle_tpu.models import bert
+    from paddle_tpu.ops.fused_ce import DEFAULT_CHUNK
 
     def head_ops(cfg):
         reset_programs(seed=0)
@@ -189,18 +196,82 @@ def test_bert_auto_selects_fused_head():
         return [op.type for op in fluid.default_main_program()
                 .global_block().ops]
 
-    long_cfg = bert.BertConfig(vocab_size=20000, hidden_size=32,
+    def cfg_at(vocab, seq):
+        return bert.BertConfig(vocab_size=vocab, hidden_size=32,
                                num_layers=1, num_heads=4,
-                               intermediate_size=64, max_position=512,
-                               seq_len=512)
-    assert "fused_lm_head_ce" in head_ops(long_cfg)
-    short_cfg = bert.BertConfig(vocab_size=20000, hidden_size=32,
-                                num_layers=1, num_heads=4,
-                                intermediate_size=64, max_position=16,
-                                seq_len=16)
-    assert "fused_lm_head_ce" not in head_ops(short_cfg)
-    short_cfg.fused_mlm_head = True         # explicit force wins
-    assert "fused_lm_head_ce" in head_ops(short_cfg)
+                               intermediate_size=64, max_position=seq,
+                               seq_len=seq)
+
+    for seq in (16, 128, 512):
+        assert "fused_lm_head_ce" in head_ops(cfg_at(20000, seq)), seq
+    small = cfg_at(2 * DEFAULT_CHUNK - 1, 512)
+    ops = head_ops(small)
+    assert "fused_lm_head_ce" not in ops
+    assert "softmax_with_cross_entropy" in ops
+    small.fused_mlm_head = True             # explicit force wins
+    assert "fused_lm_head_ce" in head_ops(small)
+    real = cfg_at(20000, 16)
+    real.fused_mlm_head = False
+    assert "fused_lm_head_ce" not in head_ops(real)
+
+
+def test_bert_builder_takes_the_op_at_a_real_vocab_and_seq_128():
+    """The four-chip cell's geometry: at a real vocab and seq_len 128 the
+    program holds ONE fused_lm_head_ce op, no [B, S, V] variable, and the
+    dense head's parameter names and shapes (a checkpoint, and the
+    benchmark's weights, load on either path); the tiny preset keeps the
+    dense pair."""
+    from paddle_tpu.models import bert
+
+    def build(cfg):
+        reset_programs(seed=0)
+        bert.build_pretrain_program(cfg)
+        return fluid.default_main_program().global_block()
+
+    cfg = bert.BertConfig(vocab_size=30522, hidden_size=32, num_layers=1,
+                          num_heads=4, intermediate_size=64,
+                          max_position=128, seq_len=128)
+    gb = build(cfg)
+    types = [op.type for op in gb.ops]
+    assert types.count("fused_lm_head_ce") == 1
+    assert "softmax_with_cross_entropy" not in types
+    wide = [v.name for v in gb.vars.values()
+            if len(v.shape) == 3 and v.shape[-1] == cfg.vocab_size]
+    assert not wide, wide
+    assert tuple(gb.var("mlm_head_w").shape) == (32, 30522)
+    assert tuple(gb.var("mlm_head_b").shape) == (30522,)
+    op = gb.ops[types.index("fused_lm_head_ce")]
+    assert op.inputs["W"] == ["mlm_head_w"]
+    assert op.inputs["Bias"] == ["mlm_head_b"]
+    assert op.attrs["w_layout"] == "hv"
+
+    tiny = build(bert.BertConfig.tiny())
+    types = [op.type for op in tiny.ops]
+    assert "fused_lm_head_ce" not in types
+    assert "softmax_with_cross_entropy" in types
+    assert tuple(tiny.var("mlm_head_w").shape) == (64, 1024)
+
+
+def test_gpt_tiny_step_through_the_op_gives_the_loss_it_gave():
+    """GPT-2's tiny step with the head forced through the op labels every
+    shifted position: every block is walked, and three Adam steps read the
+    losses they read before the op looked at its labels (the parent's
+    values, float32 on the CPU; the sums run block by block now)."""
+    from paddle_tpu.models import gpt
+    reset_programs(seed=0)
+    cfg = gpt.GPTConfig.tiny()
+    tokens, loss = gpt.build_lm_program(cfg, fused_head=True)
+    paddle.optimizer.Adam(learning_rate=2e-3).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    rng = np.random.RandomState(0)
+    feed = {"tokens": rng.randint(0, cfg.vocab_size,
+                                  (8, cfg.seq_len)).astype(np.int64)}
+    got = [float(np.asarray(exe.run(feed=feed, fetch_list=[loss])[0])
+                 .reshape(-1)[0]) for _ in range(3)]
+    np.testing.assert_allclose(
+        got, [6.251549243927002, 6.009985446929932, 5.827591419219971],
+        rtol=2e-6)
 
 
 def test_fused_ce_out_of_range_label_is_nan():
@@ -289,6 +360,171 @@ def test_fused_ce_ignore_index_matches_dense():
     for dv, fv in zip(d, f):
         np.testing.assert_allclose(np.asarray(fv), np.asarray(dv),
                                    rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# only the labelled rows are computed
+# ---------------------------------------------------------------------------
+_ROWS = dict(b=2, s=600, h=16, v=37, chunk=16)      # N = 1200 > ROW_BLOCK
+
+
+def _lowering(layout, bias):
+    """(x, w, b, labels, cot) -> (loss . cot, Rows) through the op's own
+    lowering, as a program's walk calls it."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import fused_ce
+
+    def f(x, w, b, labels, cot):
+        ins = {"X": [x], "W": [w], "Label": [labels]}
+        if bias:
+            ins["Bias"] = [b]
+        outs = fused_ce._fused_lm_head_ce(
+            None, ins, {"w_layout": layout, "chunk": _ROWS["chunk"]})
+        return jnp.sum(outs["Loss"][0][..., 0] * cot), outs["Rows"][0]
+    return f
+
+
+@functools.lru_cache(maxsize=None)
+def _rows_pair(layout, bias):
+    """Jitted (op, dense float32 reference), each -> ((value, aux), the
+    gradients of x, w and b): one compile a (layout, bias), whatever the
+    labels."""
+    import jax
+    import jax.numpy as jnp
+
+    def dense(x, w, b, labels, cot):
+        logits = jnp.einsum("bsh,vh->bsv", x, w if layout == "vh" else w.T,
+                            precision="highest")
+        if bias:
+            logits = logits + b
+        lab = labels[..., 0]
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        got = jnp.take_along_axis(logits, jnp.maximum(lab, 0)[..., None],
+                                  axis=-1)[..., 0]
+        return jnp.sum(jnp.where(lab == -100, 0.0, lse - got) * cot), 0
+
+    grad = functools.partial(jax.value_and_grad, argnums=(0, 1, 2),
+                             has_aux=True)
+    return jax.jit(grad(_lowering(layout, bias))), jax.jit(grad(dense))
+
+
+def _rows_case(kept, placement, layout, seed=3):
+    from paddle_tpu.ops.fused_ce import ROW_BLOCK
+    b, s, h, v = (_ROWS[k] for k in "bshv")
+    n_rows = b * s
+    n = {"0": 0, "1": 1, "R-1": ROW_BLOCK - 1, "R": ROW_BLOCK,
+         "R+1": ROW_BLOCK + 1, "11%": round(0.11 * n_rows),
+         "N": n_rows}[kept]
+    rng = np.random.RandomState(seed)
+    if placement == "scattered":
+        at = rng.permutation(n_rows)[:n]
+    else:               # one run of positions from the second row's start:
+        at = (s + np.arange(n)) % n_rows     # up to S of them lie in one row
+    lab = np.full((n_rows,), -100, np.int32)
+    lab[at] = rng.randint(0, v, n)
+    w_shape = (v, h) if layout == "vh" else (h, v)
+    return n, (rng.randn(b, s, h).astype(np.float32) * 0.3,
+               rng.randn(*w_shape).astype(np.float32) * 0.3,
+               rng.randn(v).astype(np.float32) * 0.1,
+               lab.reshape(b, s, 1),
+               rng.rand(b, s).astype(np.float32) + 0.5)
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("layout", ["vh", "hv"])
+@pytest.mark.parametrize("placement", ["scattered", "one_row"])
+@pytest.mark.parametrize("kept", ["0", "1", "R-1", "R", "R+1", "11%", "N"])
+def test_labelled_rows_only_match_dense_float32(kept, placement, layout,
+                                                bias):
+    """Loss and the gradients of x, w and b against the dense float32
+    pair at every kept count where the walk's trip count or a block's
+    filling changes (none, one, a block less one, a block, a block and
+    one, a masked LM's share, every row), both weight layouts, with and
+    without bias, the labels scattered and in one run; and `Rows` reads
+    the labelled count up to whole blocks."""
+    from paddle_tpu.ops.fused_ce import ROW_BLOCK
+    n, args = _rows_case(kept, placement, layout)
+    fused, dense = _rows_pair(layout, bias)
+    (got, rows), got_g = fused(*args)
+    (want, _), want_g = dense(*args)
+    assert int(rows[0]) == -(-n // ROW_BLOCK) * ROW_BLOCK
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5,
+                               atol=2e-6)
+    for name, g, wg in zip("xwb", got_g, want_g):
+        if name == "b" and not bias:
+            continue
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wg),
+                                   rtol=2e-4, atol=2e-6, err_msg=name)
+    if n == 0:          # nothing labelled: nothing walked, exact zeros
+        assert float(got) == 0.0
+        assert not np.asarray(got_g[0]).any()
+        assert not np.asarray(got_g[1]).any()
+
+
+def test_every_product_of_the_op_has_a_block_of_rows():
+    """The jaxpr of the op's forward and backward at N = 1200 > ROW_BLOCK:
+    every dot_general has ROW_BLOCK rows, none has N (nor N padded to
+    whole blocks), and the loops over row blocks are `while`s whose bound
+    is data."""
+    import jax
+    from paddle_tpu.ops.fused_ce import ROW_BLOCK
+    _, args = _rows_case("11%", "scattered", "hv")
+    n_rows = _ROWS["b"] * _ROWS["s"]
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: _lowering("hv", True)(*a)[0], argnums=(0, 1, 2)))(*args)
+    dots, whiles = [], 0
+
+    def walk(jp):
+        nonlocal whiles
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "dot_general":
+                dots.append([tuple(v.aval.shape) for v in eqn.invars])
+            whiles += eqn.primitive.name == "while"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert len(dots) == 4, dots         # logits forward; logits, dx, dw back
+    whole = {n_rows, -(-n_rows // ROW_BLOCK) * ROW_BLOCK}
+    for shapes in dots:
+        dims = {d for shape in shapes for d in shape}
+        assert ROW_BLOCK in dims and not dims & whole, shapes
+    assert whiles >= 2
+
+
+def test_rows_output_is_fetched_and_feeds_the_gauge():
+    """`return_rows=True` wires the op's second output into the program:
+    a fetch reads ceil(n / ROW_BLOCK) * ROW_BLOCK for each batch's own
+    label count from ONE compiled step, and `record_rows_share` sets gauge
+    `head.rows_computed_share` from it."""
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.ops.fused_ce import ROW_BLOCK, record_rows_share
+    reset_programs(seed=0)
+    b, s, h, v = (_ROWS[k] for k in "bshv")
+    feat = layers.data(name="feat", shape=[s, h], dtype="float32")
+    label = layers.data(name="label", shape=[s, 1], dtype="int64")
+    w = layers.create_parameter([v, h], "float32", name="head_w")
+    loss_tok, rows = layers.fused_lm_head_ce(feat, w, label, chunk=16,
+                                             return_rows=True)
+    loss = layers.mean(loss_tok)
+    paddle.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    misses = None
+    for kept in ("0", "11%", "R+1", "N"):
+        n, (x, _, _, lab, _) = _rows_case(kept, "scattered", "vh")
+        lv, rv = exe.run(feed={"feat": x, "label": lab.astype(np.int64)},
+                         fetch_list=[loss, rows])
+        assert np.isfinite(np.asarray(lv)).all()
+        want = -(-n // ROW_BLOCK) * ROW_BLOCK
+        assert int(np.asarray(rv).reshape(-1)[0]) == want, kept
+        share = record_rows_share(np.asarray(rv).reshape(-1)[0], b * s)
+        assert metrics.get("head.rows_computed_share") == share \
+            == want / (b * s)
+        if misses is None:
+            misses = metrics.get("executor.compile_cache_misses")
+    # a trip count that is data compiles once
+    assert metrics.get("executor.compile_cache_misses") == misses
 
 
 def test_bert_fused_auto_select_gated_off_under_tp_vocab_sharding():
