@@ -1048,7 +1048,7 @@ def leg_ssm_scan(preset, clock):
 # the gated delta rule's chunk kernels
 # ---------------------------------------------------------------------------
 def kda_scan_forms(b, s, h, d, chunk, heads=None, time_xla=True,
-                   without_solve=False, launches=10, seed=11):
+                   without_solve=False, launches=10, seed=11, exact=False):
     """`ops/pallas/kda_chunk.py`'s two kernels on one layer's operands in
     bf16 (decay, beta and states float32) beside `ops/kda.py`'s `jax.numpy`
     form on the same operands: each result's gap over the largest
@@ -1062,7 +1062,8 @@ def kda_scan_forms(b, s, h, d, chunk, heads=None, time_xla=True,
     sweep's handle, which times the `jax.numpy` form once (`time_xla`).
     `without_solve` also times the forward kernel with the solve left out
     (a wrong answer, timed only): the difference is what the in-kernel
-    solve costs."""
+    solve costs. `exact`: the decayed products for a decay without a bound
+    in both lowerings, on the same operands with a planted -40 a chunk."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import kda
@@ -1080,9 +1081,13 @@ def kda_scan_forms(b, s, h, d, chunk, heads=None, time_xla=True,
     q = merged(unit(rng.randn(b, s, h, d)) * d ** -0.5, bf)
     k = merged(unit(rng.randn(b, s, h, d)), bf)
     v, do = merged(rng.randn(b, s, h, d), bf), merged(rng.randn(b, s, h, d), bf)
-    g = merged(-5.0 * rng.uniform(0, 1, (b, s, h, d)) ** 0.5, jnp.float32)
+    g = -5.0 * rng.uniform(0, 1, (b, s, h, d)) ** 0.5
+    if exact:
+        g[:, 5::chunk] = -40.0
+    g = merged(g, jnp.float32)
     beta = jnp.asarray(1 / (1 + np.exp(-rng.randn(b, s, h))), jnp.float32)
-    plan = kda_chunk.plan((b, s, h, d), (b, s, h, d), chunk, bf, heads=heads)
+    plan = kda_chunk.plan((b, s, h, d), (b, s, h, d), chunk, bf, heads=heads,
+                          exact=exact)
     check(plan is not None, f"q {(b, s, h, d)} chunks of {chunk}, {heads} "
           "heads a step: the kernels do not take the shape")
 
@@ -1098,9 +1103,11 @@ def kda_scan_forms(b, s, h, d, chunk, heads=None, time_xla=True,
 
     ops = (q, k, v, g, beta)
     forward = {"kernel": by_heads(lambda *a: kda_chunk.kda_fwd(plan, *a)),
-               "xla": by_heads(lambda *a: kda._kda_fwd(chunk, *a))}
+               "xla": by_heads(lambda *a: kda._kda_fwd(chunk, *a,
+                                                       exact=exact))}
     backward = {"kernel": by_heads(lambda *a: kda_chunk.kda_bwd(plan, *a)),
-                "xla": by_heads(lambda *a: kda._kda_bwd(chunk, *a))}
+                "xla": by_heads(lambda *a: kda._kda_bwd(chunk, *a,
+                                                        exact=exact))}
     _, states = forward["xla"](*ops)
     rows_bytes = q.nbytes + k.nbytes + v.nbytes + g.nbytes + beta.nbytes
     least = {"fwd": rows_bytes + v.nbytes + states.nbytes,

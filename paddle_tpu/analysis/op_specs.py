@@ -363,13 +363,15 @@ SPECS: Dict[str, OpSpec] = {
     # --- the gated delta rule (ops/kda.py) --------------------------------
     "kda_gate": OpSpec(
         inputs={"X": ONE, "ALog": ONE, "DtBias": ONE}, outputs={"G": ONE},
-        required_attrs=("lower_bound",), attr_types={"lower_bound": _NUM},
-        sharding="follow_x"),
+        # lower_bound: the bounded form; absent, -exp(ALog) softplus(.)
+        attr_types={"lower_bound": _NUM}, sharding="follow_x"),
     "kda_scan": OpSpec(
         inputs={"Q": ONE, "K": ONE, "V": ONE, "G": ONE, "Beta": ONE},
         # States: what the forward writes for the op's grad rule
         outputs={"Y": ONE, "States": OPT},
-        required_attrs=("chunk_size",), attr_types={"chunk_size": int},
+        required_attrs=("chunk_size",),
+        attr_types={"chunk_size": int, "lower_bound": _NUM,
+                    "beta_scale": _NUM},
         sharding="follow_x"),
     "l2_norm": OpSpec(inputs={"X": ONE}, outputs={"Out": ONE},
                       attr_types={"epsilon": _NUM, "scale": _NUM},

@@ -1171,7 +1171,8 @@ def l2_norm(x, scale=1.0, epsilon=1e-6):
 
 
 def head_gate(x, gate):
-    """x [..., H, D] times sigmoid(gate [..., H]): one scalar a head."""
+    """x [..., H, D] times sigmoid(gate [..., H]): one scalar a head; a
+    gate of x's own shape is one an element."""
     helper = LayerHelper("head_gate")
     out = helper.create_variable_for_type_inference(x.dtype)
     helper.append_op("head_gate", inputs={"X": [x], "Gate": [gate]},
@@ -1179,35 +1180,45 @@ def head_gate(x, gate):
     return out
 
 
-def kda_gate(x, a_log, dt_bias, lower_bound):
-    """The bounded decay of a Kimi-delta layer (ops/kda.py kda_gate): x
-    [B, S, H * K], a_log [H], dt_bias [H * K] -> g [B, S, H, K] float32 =
-    lower_bound * sigmoid(exp(a_log) * (x + dt_bias)), a channel's log
-    decay in (lower_bound, 0)."""
+def kda_gate(x, a_log, dt_bias, lower_bound=None):
+    """The log decay of a Kimi-delta layer (ops/kda.py kda_gate): x
+    [B, S, H * K], a_log [H], dt_bias [H * K] -> g [B, S, H, K] float32.
+    With a `lower_bound` the bounded form, lower_bound * sigmoid(exp(a_log)
+    * (x + dt_bias)) in (lower_bound, 0); with None the original gate,
+    -exp(a_log) * softplus(x + dt_bias), any number <= 0."""
     helper = LayerHelper("kda_gate")
     g = helper.create_variable_for_type_inference("float32")
+    attrs = {} if lower_bound is None else {"lower_bound": float(lower_bound)}
     helper.append_op("kda_gate",
                      inputs={"X": [x], "ALog": [a_log], "DtBias": [dt_bias]},
-                     outputs={"G": [g]},
-                     attrs={"lower_bound": float(lower_bound)})
+                     outputs={"G": [g]}, attrs=attrs)
     return g
 
 
-def kda_scan(q, k, v, g, beta, chunk_size):
+def kda_scan(q, k, v, g, beta, chunk_size, lower_bound=None, beta_scale=1.0):
     """The gated delta rule in its chunked form (ops/kda.py kda_scan): q, k
     [B, S, H, K], v [B, S, H, V], g [B, S, H, K] a channel's log decay
     (`kda_gate`), beta [B, S, H] before its sigmoid. S must be a whole
-    number of chunks. Returns y [B, S, H, V]."""
+    number of chunks. `lower_bound`: what g stays above, a token, where the
+    gate has a bound (at -5.5 or above a chunk's decayed products are made
+    around the running sums at its blocks' starts); None: any g <= 0, and
+    they are made so that no factor passes 1. `beta_scale`: beta =
+    beta_scale * sigmoid(.), 2 where the model lets `I - beta k k^T` have
+    negative eigenvalues. Returns y [B, S, H, V]."""
     helper = LayerHelper("kda_scan")
     y = helper.create_variable_for_type_inference(v.dtype)
     # what the op's grad rule reads: the state each chunk starts from
     states = helper.create_variable_for_type_inference("float32")
     states.stop_gradient = True
+    attrs = {"chunk_size": int(chunk_size)}
+    if lower_bound is not None:
+        attrs["lower_bound"] = float(lower_bound)
+    if beta_scale != 1.0:
+        attrs["beta_scale"] = float(beta_scale)
     helper.append_op(
         "kda_scan",
         inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]},
-        outputs={"Y": [y], "States": [states]},
-        attrs={"chunk_size": int(chunk_size)})
+        outputs={"Y": [y], "States": [states]}, attrs=attrs)
     return y
 
 

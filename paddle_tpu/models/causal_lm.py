@@ -1,9 +1,10 @@
 """What the sparse causal LMs of this directory have in common
-(`deepseek_v3.py`, `mellum.py`, `nemotron_h.py`, `ling.py`), written once:
+(`deepseek_v3.py`, `mellum.py`, `nemotron_h.py`, `ling.py`, `keye.py`,
+`lfm2.py`, `solar.py`), written once:
 the leaves (a seeded weight, a bias-free projection, an RMS norm, the split
 into heads), the token embedding and the next-token loss, the dense and the
 shared feed-forward parts, an expert layer around `layers.routed_moe`,
-attention on grouped KV heads, the layer loop, the sharding rules all four
+attention on grouped KV heads, the layer loop, the sharding rules they all
 carry, and the expert loads' way into the metrics.
 
 Each model file keeps what only it has: its configuration with the
@@ -11,13 +12,13 @@ published keys, the mixers of its own, and `decoder_layer`, which says what
 mixer and what feed-forward part layer n gets. What differs between the
 callers of a function here is an argument of it, every value of which has a
 caller; no function here asks which model it builds. A configuration is
-read by the keys all four spell alike (`hidden_size`, `seq_len`,
+read by the keys they all spell alike (`hidden_size`, `seq_len`,
 `vocab_size`, `initializer_range`, `head_dim`, `moe_intermediate_size`,
 `num_experts_per_tok`, `norm_topk_prob`, `experts_held`, `expert_offset`);
 where the published names differ the caller passes the value.
 
 Ops of the Program IR only, through the one `paddle_tpu.layers` module.
-Parameters are created in the order the four builders always created them:
+Parameters are created in the order the builders always created them:
 a checkpoint is loaded by name, but a startup program is run in order.
 """
 from __future__ import annotations
@@ -147,7 +148,7 @@ def expert_layer(x, cfg, pre, *, experts_total, scoring="sigmoid",
 
 
 def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
-                      selection=None, qk_norm=False):
+                      selection=None, qk_norm=False, gate=False):
     """`heads` query heads on `kv_heads` KV heads of `head_dim` (query head
     h attends KV head h // group), causal; `rotary` turns q and k (None: no
     rotary positions), `window` keeps the last `window` keys only (None:
@@ -166,7 +167,13 @@ def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
     head's `head_dim` features (the configuration's epsilon) with ONE
     learned scale of `head_dim` that all the heads of q, or of k, share
     (`q_norm_scale`, `k_norm_scale`), BEFORE the rotary turn; scope
-    `attn.qk_norm`. False: no such ops, and today's in today's order."""
+    `attn.qk_norm`. False: no such ops, and today's in today's order.
+
+    `gate`: an element-wise output gate, the heads' outputs times
+    sigmoid(x W_gate) (`g_proj_w` [hidden, heads * head_dim], from the
+    layer's normed input) before the output projection, inside the scope
+    `attn.proj` (the gate's projection, its sigmoid and the product).
+    False: no such ops."""
     hd = cfg.head_dim
     turn = rotary or (lambda t: t)
 
@@ -198,8 +205,26 @@ def grouped_attention(x, cfg, pre, heads, kv_heads, rotary=None, window=None,
     with name_scope("attn.proj"):
         ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                              [0, 0, heads * hd])
+        if gate:
+            ctx = layers.head_gate(
+                ctx, _linear(x, heads * hd, pre + "g_proj_w", cfg))
         out = _linear(ctx, cfg.hidden_size, pre + "o_proj_w", cfg)
     return out if selection is None else (out, target)
+
+
+def short_conv_heads(qkv, cfg, pre, heads, width):
+    """A linear-attention layer's q, k, v from their projections `qkv`
+    ([B, S, heads * width] each): a causal depthwise convolution of
+    `short_conv_kernel_size` taps a channel without bias, then silu, cut
+    into `heads` heads; q and k L2-normed a head, q times width^-0.5. Scope
+    `kda.conv`."""
+    with name_scope("kda.conv"):
+        q, k, v = (layers.reshape(layers.causal_conv1d(
+            t, cfg.short_conv_kernel_size,
+            param_attr=_w(pre + f"{n}_conv_w", cfg), bias_attr=False,
+            activation="silu"), [0, cfg.seq_len, heads, width])
+            for n, t in zip("qkv", qkv))
+        return layers.l2_norm(q, scale=width ** -0.5), layers.l2_norm(k), v
 
 
 def embed_tokens(cfg):

@@ -39,8 +39,8 @@ from ..framework.program import name_scope
 from ..layer_helper import ParamAttr
 from ..parallel.mesh import ShardingRules
 from . import causal_lm
-from .causal_lm import (_heads, _linear, _norm, _w, dense_ffn, gated_ffn,
-                        record_expert_load)
+from .causal_lm import (_heads, _linear, _norm, dense_ffn, gated_ffn,
+                        record_expert_load, short_conv_heads)
 
 __all__ = ["LingConfig", "build_causal_lm_program", "record_expert_load",
            "sharding_rules"]
@@ -134,13 +134,7 @@ def kda_attention(x, cfg: LingConfig, pre: str):
         decay = _linear(x, width, pre + "f_proj_w", cfg)
         beta = _linear(x, nh, pre + "b_proj_w", cfg)
         gate = _linear(x, nh, pre + "g_proj_w", cfg)
-    with name_scope("kda.conv"):
-        q, k, v = (layers.reshape(layers.causal_conv1d(
-            t, cfg.short_conv_kernel_size,
-            param_attr=_w(pre + f"{n}_conv_w", cfg), bias_attr=False,
-            activation="silu"), [0, s, nh, hd]) for n, t in zip("qkv", qkv))
-        q = layers.l2_norm(q, scale=hd ** -0.5)
-        k = layers.l2_norm(k)
+    q, k, v = short_conv_heads(qkv, cfg, pre, nh, hd)
     with name_scope("kda.gate"):
         g = layers.kda_gate(
             decay,
@@ -152,7 +146,8 @@ def kda_attention(x, cfg: LingConfig, pre: str):
                     name=pre + "dt_bias", initializer=I.Constant(0.0))),
             cfg.kda_lower_bound)
     with name_scope("kda.scan"):
-        o = layers.kda_scan(q, k, v, g, beta, cfg.kda_chunk_size)
+        o = layers.kda_scan(q, k, v, g, beta, cfg.kda_chunk_size,
+                            lower_bound=cfg.kda_lower_bound)
     with name_scope("kda.out"):
         o = layers.head_gate(_norm(o, pre + "o_norm_scale", cfg), gate)
         return _linear(layers.reshape(o, [0, s, width]), cfg.hidden_size,

@@ -82,8 +82,9 @@ CATALOGUE = {
               "ssm.scan.intra"),
     **_scopes("Short-convolution layer", "models/lfm2.py",
               "conv.in_proj", "conv.mix", "conv.out_proj"),
-    **_scopes("Linear-attention layer", "models/ling.py",
-              "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out"),
+    **_scopes("Linear-attention layer", "models/ling.py, models/solar.py",
+              "kda.proj", "kda.gate", "kda.scan", "kda.out"),
+    **_scopes("Linear-attention layer", "models/causal_lm.py", "kda.conv"),
     **_scopes("Linear-attention layer", "ops/kda.py",
               "kda.scan.intra", "kda.scan.solve", "kda.scan.carry",
               "kda.scan.inter"),

@@ -2,12 +2,14 @@
 attention: Yang et al. 2024, "Gated Delta Networks"; Kimi Linear 2025, the
 decay a vector a head), as two ops a builder puts between its projections:
 
-* `kda_gate`: the bounded decay. `g = lower_bound * sigmoid(exp(ALog_h) *
-  (x + DtBias))` in `(lower_bound, 0)`, one number a channel, float32
-  inside and out.
+* `kda_gate`: the log decay, one number a channel, float32 inside and out.
+  With a `lower_bound` the bounded form, `g = lower_bound * sigmoid(
+  exp(ALog_h) * (x + DtBias))` in `(lower_bound, 0)`; without one the
+  original gate, `g = -exp(ALog_h) * softplus(x + DtBias)`, any g <= 0.
 * `kda_scan`: the recurrence. Per head h (state `S` `[K, V]`, float32, zero
   at a row's start), with `alpha_t = exp(g_t)` `[K]` and `beta_t =
-  sigmoid(Beta_t)`:
+  beta_scale * sigmoid(Beta_t)` (`beta_scale` 2 lets `I - beta k k^T` have
+  eigenvalues in (-1, 1]):
       S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
       o_t = S_t^T q_t
   Computed in chunks of `chunk_size` positions: inside a chunk everything
@@ -25,7 +27,16 @@ decay a vector a head), as two ops a builder puts between its projections:
   of blocks around the running sum at the row block's start: both factors
   of a pair of different blocks are then at most 1, and a block against
   itself reaches `exp(-16 min g)`, which is why `g` must stay above -88 /
-  16 = -5.5 a token (the family's `kda_lower_bound` is -5).
+  16 = -5.5 a token (the family's `kda_lower_bound` is -5). The op learns
+  the bound from the builder (`lower_bound`); with one of -5.5 or above it
+  keeps that form. Without one (any g <= 0) no factor passes 1
+  (`_decayed_products_exact`): a chunk's pairs (l, m) are split by the
+  highest bit in which l and m differ, halves of 32, 16, 8, 4, 2 and 1
+  positions at a chunk of 64, and a level's pairs are ONE product of the
+  rows `x exp(D)` with `k exp(D)`, `D_l` the sum of g from the half's middle
+  to l (upper half) or from l to the middle (lower half): sums of one sign
+  taken from g itself and no difference of running sums, so one decay of
+  -40 costs the pairs beside it no digit; the diagonal is the plain dot.
   The gate, beta, the running sums, the solve and the states are float32;
   the matmul operands (`Q`, `K`, `V`, the decayed products, the state as a
   factor) are in `Q`'s dtype, every dot accumulating float32. The sequence
@@ -62,6 +73,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .registry import register
 
@@ -69,6 +81,9 @@ _F32 = jnp.float32
 # positions between two restarts of the running sum inside a chunk's
 # decayed products
 _SUB = 16
+# the least bound a token's g may have for a block against itself to stay
+# finite as one product around the block's first running sum
+_LEAST_BOUND = -88.0 / _SUB
 
 # what `kda_scan`'s forward writes for its grad rule
 _RESIDUALS = ("States",)
@@ -76,14 +91,17 @@ _RESIDUALS = ("States",)
 
 @register("kda_gate")
 def _kda_gate(ctx, ins, attrs):
-    """X [B, S, H * K], ALog [H], DtBias [H * K] -> G [B, S, H, K] float32
-    in (lower_bound, 0): the log of a channel's decay."""
+    """X [B, S, H * K], ALog [H], DtBias [H * K] -> G [B, S, H, K] float32,
+    the log of a channel's decay: in (lower_bound, 0), or with no
+    `lower_bound` -exp(ALog) softplus(X + DtBias), any number <= 0."""
     x, a_log, dt_bias = ins["X"][0], ins["ALog"][0], ins["DtBias"][0]
     h = a_log.shape[0]
     per_head = x.shape[:-1] + (h, x.shape[-1] // h)
     pre = (x.astype(_F32) + dt_bias.astype(_F32)).reshape(per_head)
-    g = float(attrs["lower_bound"]) * jax.nn.sigmoid(
-        jnp.exp(a_log.astype(_F32))[:, None] * pre)
+    rate = jnp.exp(a_log.astype(_F32))[:, None]
+    if attrs.get("lower_bound") is None:
+        return {"G": [-rate * jax.nn.softplus(pre)]}
+    g = float(attrs["lower_bound"]) * jax.nn.sigmoid(rate * pre)
     return {"G": [g]}
 
 
@@ -98,6 +116,60 @@ def _sub_block(chunk):
         return chunk
     raise ValueError(f"kda_scan: a chunk of {chunk} positions is no whole "
                      f"number of blocks of {_SUB}")
+
+
+def _halves(chunk):
+    """The halves a chunk's pairs are split by where g has no bound: 32,
+    16, 8, 4, 2, 1 at a chunk of 64."""
+    return [1 << i for i in reversed(range((chunk - 1).bit_length()))]
+
+
+def _level(chunk, half):
+    """One level of `_decayed_products_exact`, as constants: (sums [l, j]
+    0 / 1, the positions `D_l` adds up; pair [l, m], the pairs the level
+    holds). Blocks of 2 `half` positions; a pair (l, m) belongs to the
+    level where l lies in a block's upper half and m in its lower one, the
+    level of the highest bit in which l and m differ. `D_l` is the sum of g
+    over middle .. l for an upper l and over l + 1 .. middle - 1 for a lower
+    one, `middle` the upper half's first position."""
+    pos = np.arange(chunk)
+    middle = pos // (2 * half) * (2 * half) + half
+    upper = pos >= middle
+    j = pos[None, :]
+    sums = np.where(upper[:, None],
+                    (j >= middle[:, None]) & (j <= pos[:, None]),
+                    (j > pos[:, None]) & (j < middle[:, None]))
+    pair = ((middle[:, None] == middle[None, :]) & upper[:, None]
+            & ~upper[None, :])
+    return sums, pair
+
+
+def _decayed_products_exact(q, k, g, cdt):
+    """`_decayed_products` for any g <= 0, no factor above 1: the pairs
+    (l, m), m < l, level by level (`_level`), a level ONE product of the
+    rows `x exp(D)` with `k exp(D)`: `exp(D_l) exp(D_m) = exp(G_l - G_m)`
+    there, each `D` a sum of g's of one sign and no difference of running
+    sums, so a decay of -40 at one position costs the pairs beside it no
+    digit; the diagonal is the plain dot. q, k, g [b, c, l, h, d] float32,
+    g the log decays themselves."""
+    chunk = k.shape[2]
+    mkk = mqk = 0.0
+    for half in _halves(chunk):
+        sums, pair = _level(chunk, half)
+        decay = jnp.exp(jnp.einsum(
+            "lj,bcjhd->bclhd", jnp.asarray(sums, _F32), g,
+            precision=jax.lax.Precision.HIGHEST))
+        kc, qc = (k * decay).astype(cdt), (q * decay).astype(cdt)
+        mkk = mkk + jnp.where(pair, _dot("bclhd,bcmhd->bchlm", kc, kc), 0.0)
+        mqk = mqk + jnp.where(pair, _dot("bclhd,bcmhd->bchlm", qc, kc), 0.0)
+    kc = k.astype(cdt).astype(_F32)
+    eye = jnp.eye(chunk, dtype=_F32)
+
+    def with_diagonal(m, x):
+        own = jnp.sum(x.astype(cdt).astype(_F32) * kc, axis=-1)
+        return m + eye * jnp.moveaxis(own, 2, 3)[..., None]
+
+    return with_diagonal(mkk, k), with_diagonal(mqk, q)
 
 
 def _decayed_products(q, k, cum, cdt):
@@ -136,20 +208,22 @@ def _decayed_products(q, k, cum, cdt):
     return products(k), products(q)
 
 
-def _local(chunk, q, k, v, g, beta):
+def _local(chunk, exact, q, k, v, g, beta):
     """What a chunk makes of its own positions, every chunk at once:
     (U, W [b, c, h, l, .], K- exp(G_C) [b, c, h, l, K], exp(G_C)
     [b, c, h, K], Q+ [b, c, h, l, K], lower(Q+ K-^T) [b, c, h, l, m]), all
     float32. q, k, v [B, S, H, D] in the compute dtype, g [B, S, H, K] and
-    beta [B, S, H] float32."""
+    beta [B, S, H] float32. `exact`: g has no bound (`_own_block_exact`)."""
     b, s, h, dk = k.shape
     c, cdt = s // chunk, q.dtype
     rows = (b, c, chunk, h)
     qf, kf, vf = (t.astype(_F32).reshape(rows + (-1,)) for t in (q, k, v))
     beta = beta.reshape(rows)
-    cum = jnp.cumsum(g.reshape(rows + (dk,)), axis=2)
+    g = g.reshape(rows + (dk,))
+    cum = jnp.cumsum(g, axis=2)
     with jax.named_scope("kda.scan.intra"):
-        mkk, mqk = _decayed_products(qf, kf, cum, cdt)
+        mkk, mqk = (_decayed_products_exact(qf, kf, g, cdt) if exact
+                    else _decayed_products(qf, kf, cum, cdt))
         a = jnp.tril(jnp.moveaxis(beta, 2, -1)[..., None] * mkk, -1)
         pqk = jnp.tril(mqk)
     with jax.named_scope("kda.scan.solve"):
@@ -159,7 +233,14 @@ def _local(chunk, q, k, v, g, beta):
             unit_diagonal=True)
         u, w = uw[..., :vf.shape[-1]], uw[..., vf.shape[-1]:]
     total = cum[:, :, -1]                                  # [b, c, h, K]
-    kend = jnp.moveaxis(kf * jnp.exp(total[:, :, None] - cum), 2, 3)
+    if exact:
+        # the g's after a position, summed from the chunk's end: no
+        # difference of running sums
+        to_end = jnp.flip(jnp.cumsum(jnp.flip(jnp.pad(
+            g[:, :, 1:], ((0, 0), (0, 0), (0, 1), (0, 0), (0, 0))), 2), 2), 2)
+    else:
+        to_end = total[:, :, None] - cum
+    kend = jnp.moveaxis(kf * jnp.exp(to_end), 2, 3)
     qplus = jnp.moveaxis(qf * jnp.exp(cum), 2, 3)
     return u, w, kend, jnp.exp(total), qplus, pqk
 
@@ -187,11 +268,11 @@ def _carry(u, wc, kendc, decay):
     return jnp.moveaxis(states, 0, 1)
 
 
-def _kda_fwd(chunk, q, k, v, g, beta):
+def _kda_fwd(chunk, q, k, v, g, beta, exact=False):
     """(o [B, S, H, V] in q's dtype, the state each chunk starts from
     [B, S / chunk, H, K, V] float32)."""
     cdt = q.dtype
-    u, w, kend, decay, qplus, pqk = _local(chunk, q, k, v, g, beta)
+    u, w, kend, decay, qplus, pqk = _local(chunk, exact, q, k, v, g, beta)
     wc = w.astype(cdt)
     with jax.named_scope("kda.scan.carry"):
         states = _carry(u, wc, kend.astype(cdt), decay)
@@ -204,14 +285,14 @@ def _kda_fwd(chunk, q, k, v, g, beta):
     return jnp.moveaxis(o, 2, 3).reshape(v.shape).astype(cdt), states
 
 
-def _kda_bwd(chunk, q, k, v, g, beta, states, do):
+def _kda_bwd(chunk, q, k, v, g, beta, states, do, exact=False):
     """The transpose of `_kda_fwd` at do, on the chunk states it wrote: the
     gradients of (q, k, v, g, beta). A chunk's matrices and its solve are
     made again from the per-token rows and differentiated where they are
     made (`_local`); the chunks' chain runs once, in reverse."""
     cdt = q.dtype
     (u, w, kend, decay, qplus, pqk), local_vjp = jax.vjp(
-        functools.partial(_local, chunk), q, k, v, g, beta)
+        functools.partial(_local, chunk, exact), q, k, v, g, beta)
     b, c, h, _, dv = u.shape
     wc, kendc, sc = w.astype(cdt), kend.astype(cdt), states.astype(cdt)
     doc = jnp.moveaxis(do.astype(cdt).reshape(b, c, chunk, h, dv), 2, 3)
@@ -244,68 +325,80 @@ def _kda_bwd(chunk, q, k, v, g, beta, states, do):
     return local_vjp((du, dw, dkend, ddecay, dqplus, dpqk))
 
 
-def _route(chunk, count, q, v):
+def _route(form, count, q, v):
     """The Pallas kernels' plan where their shape rule takes the operands
     (`ops/pallas/kda_chunk.py` `plan`), else None: the `jax.numpy` form
-    above. `count`: whether this trace's call counts, `kda.scan_pallas` /
-    `kda.scan_xla`, once per forward or backward lowered."""
+    above. `form` (chunk, exact). `count`: whether this trace's call
+    counts, `kda.scan_pallas` / `kda.scan_xla` and `kda.scan_exact` /
+    `kda.scan_bounded`, once per forward or backward lowered."""
     from .pallas import kda_chunk
-    plan = kda_chunk.plan(q.shape, v.shape, chunk, q.dtype) \
+    chunk, exact = form
+    plan = kda_chunk.plan(q.shape, v.shape, chunk, q.dtype, exact=exact) \
         if v.dtype == q.dtype else None
     if count:
         from ..observability import metrics
         metrics.inc("kda.scan_xla" if plan is None else "kda.scan_pallas")
+        metrics.inc("kda.scan_exact" if exact else "kda.scan_bounded")
     return plan
 
 
-def _scan_fwd(chunk, count, q, k, v, g, beta):
-    plan = _route(chunk, count, q, v)
+def _scan_fwd(form, count, q, k, v, g, beta):
+    plan = _route(form, count, q, v)
     if plan is None:
-        return _kda_fwd(chunk, q, k, v, g, beta)
+        return _kda_fwd(form[0], q, k, v, g, beta, exact=form[1])
     from .pallas import kda_chunk
     return kda_chunk.kda_fwd(plan, q, k, v, g, beta)
 
 
-def _scan_bwd(chunk, count, q, k, v, g, beta, states, do):
-    plan = _route(chunk, count, q, v)
+def _scan_bwd(form, count, q, k, v, g, beta, states, do):
+    plan = _route(form, count, q, v)
     if plan is None:
-        return _kda_bwd(chunk, q, k, v, g, beta, states, do)
+        return _kda_bwd(form[0], q, k, v, g, beta, states, do, exact=form[1])
     from .pallas import kda_chunk
     return kda_chunk.kda_bwd(plan, q, k, v, g, beta, states, do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _kda(chunk, count, relowered, q, k, v, g, beta):
+def _kda(form, count, relowered, q, k, v, g, beta):
     # `relowered` (the generic `__vjp__` differentiates a whole segment):
     # JAX traces this body to a jaxpr it then replaces by the two rules
     # below, so what is traced here is in no program and does not count
-    return _scan_fwd(chunk, count and not relowered, q, k, v, g, beta)
+    return _scan_fwd(form, count and not relowered, q, k, v, g, beta)
 
 
-def _kda_vjp_fwd(chunk, count, relowered, *args):
-    o, states = _scan_fwd(chunk, count, *args)
+def _kda_vjp_fwd(form, count, relowered, *args):
+    o, states = _scan_fwd(form, count, *args)
     return (o, states), args + (states,)
 
 
-def _kda_vjp_bwd(chunk, count, relowered, res, cts):
-    return _scan_bwd(chunk, count, *res, cts[0])
+def _kda_vjp_bwd(form, count, relowered, res, cts):
+    return _scan_bwd(form, count, *res, cts[0])
 
 
 _kda.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)
 
 
-def _chunk_size(q, attrs):
+def _form(q, attrs):
+    """(chunk, exact): the chunk's length, and whether a block against
+    itself is made for any g <= 0 (no `lower_bound`, or one under -88 / 16
+    a token) or around one running sum, as the bound allows."""
     chunk = int(attrs["chunk_size"])
     if q.shape[1] % chunk:
         raise ValueError(
             f"kda_scan: a row of {q.shape[1]} positions is no whole number "
             f"of chunks of {chunk}")
     _sub_block(chunk)
-    return chunk
+    bound = attrs.get("lower_bound")
+    return chunk, bound is None or float(bound) < _LEAST_BOUND
 
 
-def _beta(raw):
-    return jax.nn.sigmoid(raw.astype(_F32))
+def _beta(raw, scale=1.0):
+    beta = jax.nn.sigmoid(raw.astype(_F32))
+    return beta if scale == 1.0 else scale * beta
+
+
+def _beta_of(attrs):
+    return functools.partial(_beta, scale=float(attrs.get("beta_scale", 1.0)))
 
 
 def _kda_scan_grad(ctx, ins, attrs, outs, ogs):
@@ -316,9 +409,9 @@ def _kda_scan_grad(ctx, ins, attrs, outs, ogs):
     if do is None or not all(outs.get(s) for s in _RESIDUALS):
         return None
     q, k, v, g, raw = (ins[s][0] for s in ("Q", "K", "V", "G", "Beta"))
-    beta, beta_vjp = jax.vjp(_beta, raw)
+    beta, beta_vjp = jax.vjp(_beta_of(attrs), raw)
     dq, dk, dv, dg, dbeta = _scan_bwd(
-        _chunk_size(q, attrs), not ctx.is_eval_shape, q, k, v,
+        _form(q, attrs), not ctx.is_eval_shape, q, k, v,
         g.astype(_F32), beta, outs["States"][0], do)
     if not ctx.is_eval_shape:
         from ..observability import metrics
@@ -330,14 +423,17 @@ def _kda_scan_grad(ctx, ins, attrs, outs, ogs):
 @register("kda_scan", grad=_kda_scan_grad, residual_slots=_RESIDUALS)
 def _kda_scan(ctx, ins, attrs):
     """Q, K [B, S, H, K], V [B, S, H, V], G [B, S, H, K] (a channel's log
-    decay, above -88 / 16 a token), Beta [B, S, H] before its sigmoid ->
-    Y [B, S, H, V] in Q's dtype, States."""
+    decay: above `lower_bound` a token where the builder gives one, any
+    number <= 0 where it gives none), Beta [B, S, H] before its sigmoid
+    (times `beta_scale`, 1 by default) -> Y [B, S, H, V] in Q's dtype,
+    States."""
     q, k, v, g, raw = (ins[s][0] for s in ("Q", "K", "V", "G", "Beta"))
     if k.shape != q.shape or g.shape != k.shape or raw.shape != q.shape[:3]:
         raise ValueError(f"kda_scan: Q {q.shape}, K {k.shape}, G {g.shape}, "
                          f"Beta {raw.shape}")
-    y, states = _kda(_chunk_size(q, attrs), not ctx.is_eval_shape,
-                     ctx.in_vjp, q, k, v, g.astype(_F32), _beta(raw))
+    y, states = _kda(_form(q, attrs), not ctx.is_eval_shape,
+                     ctx.in_vjp, q, k, v, g.astype(_F32),
+                     _beta_of(attrs)(raw))
     if not ctx.is_eval_shape:
         from ..observability import metrics
         metrics.inc("kda.bwd_recomputed" if ctx.in_vjp

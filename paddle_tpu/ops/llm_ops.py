@@ -182,8 +182,11 @@ def _l2_norm(ctx, ins, attrs):
 
 @register("head_gate")
 def _head_gate(ctx, ins, attrs):
-    """X [..., H, D] times sigmoid(Gate [..., H]): one scalar a head."""
+    """X [..., H, D] times sigmoid(Gate [..., H]): one scalar a head; or,
+    with a Gate of X's own shape, one an element. Float32 inside."""
     x, gate = ins["X"][0], ins["Gate"][0]
-    out = x.astype(jnp.float32) * jax.nn.sigmoid(
-        gate.astype(jnp.float32))[..., None]
+    factor = jax.nn.sigmoid(gate.astype(jnp.float32))
+    if gate.ndim < x.ndim:
+        factor = factor[..., None]
+    out = x.astype(jnp.float32) * factor
     return {"Out": [out.astype(x.dtype)]}

@@ -81,14 +81,17 @@ class Plan(NamedTuple):
     steps: int
     chunks: int
     resident_bytes: int
+    exact: bool = False
 
 
-def plan(q_shape, v_shape, chunk, dtype=jnp.bfloat16, heads=None):
+def plan(q_shape, v_shape, chunk, dtype=jnp.bfloat16, heads=None,
+         exact=False):
     """The kernels' plan for q, k [B, S, H, K], v [B, S, H, V] in chunks of
     `chunk`, or None where they do not take the shape. `heads` a grid step
     (a divisor of H; by default the most that divides H, up to 4: the
     sweep reads 4, 8 and 16 alike and 2 and 1 slower, `PERF.md` section 6,
-    PR 40) is the handle of `chip_smoke.py`'s sweep."""
+    PR 40) is the handle of `chip_smoke.py`'s sweep. `exact`: g has no
+    bound, and a block against itself is made level by level (`_Chunk`)."""
     _, s, h, d = q_shape
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
@@ -109,9 +112,13 @@ def plan(q_shape, v_shape, chunk, dtype=jnp.bfloat16, heads=None):
     resident = (2 * (7 * rows * size + 2 * rows * 4 + heads * d * d * 4)
                 + heads * d * d * 4
                 + 2 * (40 * chunk * d + 12 * chunk * chunk) * 4)
+    if exact:
+        # the levels' sums [4 L, L], their decays and decayed rows [4 L, d]
+        resident += 4 * len(_halves(chunk)) * chunk * (chunk + 3 * d)
     if resident > VMEM_BUDGET:
         return None
-    return Plan(heads, d, chunk, h // heads, s // chunk, resident)
+    return Plan(heads, d, chunk, h // heads, s // chunk, resident,
+                bool(exact))
 
 
 def _dot(a, b, contract, precision=None):
@@ -226,19 +233,88 @@ def _inverses(mats):
     return ts
 
 
+def _halves(chunk):
+    """`ops/kda.py` `_halves`: 32, 16, 8, 4, 2, 1 at a chunk of 64."""
+    return [1 << i for i in reversed(range((chunk - 1).bit_length()))]
+
+
+def _levels(chunk):
+    """`ops/kda.py` `_level` for every half of a chunk, made from iotas
+    once a grid step: (sums [n L, L] bf16, 0 / 1: row l of level i adds up
+    the g's `D_l` is made of; the levels' pairs, n masks [L, L])."""
+    r, c = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
+    sums, pairs = [], []
+    for half in _halves(chunk):
+        block = ~(2 * half - 1)
+        middle = jnp.bitwise_and(r, block) + half
+        upper = r >= middle
+        adds = ((upper & (c >= middle) & (c <= r))
+                | (~upper & (c > r) & (c < middle)))
+        sums.append(jnp.where(adds, 1.0, 0.0))
+        pairs.append((jnp.bitwise_and(c, block) + half == middle) & upper
+                     & (c < middle))
+    return jnp.concatenate(sums, axis=0).astype(jnp.bfloat16), pairs
+
+
+def _thirds(x):
+    """x float32 as three bf16 values that add up to it: a product with a
+    matrix of 0s and 1s (exact in bf16) is then three passes, not the six
+    of `Precision.HIGHEST`."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(_F32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(_F32)).astype(jnp.bfloat16)
+
+
+def _shift(x, down):
+    """x [L, d] moved one row down (row 0 reads 0) or up (the last does)."""
+    n = x.shape[0]
+    row = _iota((n, 1), 0)
+    if down:
+        return jnp.where(row >= 1, pltpu.roll(x, 1, 0), 0.0)
+    return jnp.where(row < n - 1, pltpu.roll(x, n - 1, 0), 0.0)
+
+
 class _Chunk:
     """What a chunk makes of one head's rows (`ops/kda.py` `_local`): q, k,
-    v [L, d] in the compute dtype, g [L, d] and beta [L, 1] float32."""
+    v [L, d] in the compute dtype, g [L, d] and beta [L, 1] float32.
+    `levels` (`_levels`): g has no bound, and the decayed products are made
+    level by level; None: around the running sums at the blocks' starts."""
 
-    def __init__(self, q, k, v, g, beta):
+    def __init__(self, q, k, v, g, beta, levels=None):
         chunk, d = k.shape
         cdt = self.cdt = k.dtype
         self.hi = _HI if cdt == _F32 else None
-        nb = chunk // _SUB
         self.qf, self.kf, self.vf = (t.astype(_F32) for t in (q, k, v))
         self.beta = beta
         cum = self.cum = _running_sum(g)
         self.from_start = jnp.exp(cum)
+        r, c = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
+        self.strict, self.lower = r > c, r >= c
+        self.total = cum[chunk - 1:chunk, :]                  # [1, d]
+        if levels is None:
+            mqk = self._products_around_block_starts()
+            self.to_end = jnp.exp(self.total - cum)
+        else:
+            mqk = self._products_by_levels(g, levels, r == c)
+            # the g's after a position, summed from the chunk's end
+            self.to_end = jnp.exp(_running_sum(_shift(g, down=False),
+                                               reverse=True))
+        self.pqkc = jnp.where(self.lower, mqk, 0.0).astype(cdt)
+        self.kplus = self.kf * self.from_start
+        self.a = jnp.where(self.strict, beta * self.mkk, 0.0)
+        self.kend = self.kf * self.to_end
+        self.kendc = self.kend.astype(cdt)
+        self.qplus = self.qf * self.from_start
+        self.qpc = self.qplus.astype(cdt)
+        self.decay = jnp.exp(self.total)                      # [1, d]
+
+    def _products_around_block_starts(self):
+        """g above -88 / 16 a token: sets `mkk` and what the backward reads
+        (`rows`, `back`, `k_back`, `to_row`), returns `Q+ K-^T` unmasked."""
+        cum, cdt = self.cum, self.cdt
+        chunk, d = cum.shape
+        nb = chunk // _SUB
         row_block = self.row_block = _block(_iota((chunk, 1), 0))
         # the running sum before a block's first position, and a row's own
         self.refs = [jnp.zeros((1, d), _F32)] + [
@@ -256,7 +332,8 @@ class _Chunk:
         for a in range(nb):
             at = slice(a * _SUB, (a + 1) * _SUB)
             rows = jnp.concatenate([xk[at], xq[at]], axis=0)
-            back = jnp.exp(jnp.where(row_block <= a, self.refs[a] - cum, 0.0))
+            back = jnp.exp(jnp.where(row_block <= a, self.refs[a] - cum,
+                                     0.0))
             k_back = (self.kf * back).astype(cdt)
             both = _nt(rows, k_back, self.hi)                 # [32, L]
             mkk.append(both[:_SUB])
@@ -265,19 +342,32 @@ class _Chunk:
             self.back.append(back)
             self.k_back.append(k_back)
         self.mkk = jnp.concatenate(mkk, axis=0)
-        r, c = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
-        self.strict, self.lower = r > c, r >= c
-        self.pqkc = jnp.where(self.lower, jnp.concatenate(mqk, axis=0),
-                              0.0).astype(cdt)
-        self.kplus = self.kf * self.from_start
-        self.a = jnp.where(self.strict, beta * self.mkk, 0.0)
-        self.total = cum[chunk - 1:chunk, :]                  # [1, d]
-        self.to_end = jnp.exp(self.total - cum)
-        self.kend = self.kf * self.to_end
-        self.kendc = self.kend.astype(cdt)
-        self.qplus = self.qf * self.from_start
-        self.qpc = self.qplus.astype(cdt)
-        self.decay = jnp.exp(self.total)                      # [1, d]
+        return jnp.concatenate(mqk, axis=0)
+
+    def _products_by_levels(self, g, levels, diagonal):
+        """Any g <= 0 (`ops/kda.py` `_decayed_products_exact`): sets `mkk`
+        and what the backward reads (a level's decay `level_decay`, its
+        rows k over q [2 L, d] `level_rows`, `sums`, `pairs`), returns
+        `Q+ K-^T` unmasked. A level is one `[2 L, d] x [d, L]` product."""
+        self.sums, self.pairs = levels
+        chunk = g.shape[0]
+        # D [n L, d]: every level's sums of g in three bf16 passes
+        decays = jnp.exp(sum(_nn(self.sums, part) for part in _thirds(g)))
+        self.level_decay, self.level_rows = [], []
+        mkk = mqk = 0.0
+        for i, pair in enumerate(self.pairs):
+            decay = decays[i * chunk:(i + 1) * chunk]
+            rows = jnp.concatenate([(self.kf * decay).astype(self.cdt),
+                                    (self.qf * decay).astype(self.cdt)],
+                                   axis=0)
+            both = _nt(rows, rows[:chunk], self.hi)           # [2 L, L]
+            mkk = mkk + jnp.where(pair, both[:chunk], 0.0)
+            mqk = mqk + jnp.where(pair, both[chunk:], 0.0)
+            self.level_decay.append(decay)
+            self.level_rows.append(rows)
+        self.mkk = mkk
+        own = jnp.sum(self.qf * self.kf, axis=1, keepdims=True)
+        return mqk + jnp.where(diagonal, own, 0.0)
 
     def solved(self, t):
         """[U | W] = T [beta V | beta K+] for T = (I + A)^-1 (None: the
@@ -293,12 +383,14 @@ class _Chunk:
         return (self.u - _nn(self.wc, sc, self.hi)).astype(self.cdt)
 
 
-def _chunks(q_ref, k_ref, v_ref, g_ref, beta, heads, solve=True):
+def _chunks(q_ref, k_ref, v_ref, g_ref, beta, heads, exact, solve=True):
     """A grid step's heads, each with its solve: the heads' inverses are
     made together (`_inverses`)."""
     lanes = [slice(h * _LANES, (h + 1) * _LANES) for h in range(heads)]
+    levels = _levels(q_ref.shape[0]) if exact else None
     chunks = [_Chunk(q_ref[:, at], k_ref[:, at], v_ref[:, at], g_ref[:, at],
-                     beta[:, h:h + 1]) for h, at in enumerate(lanes)]
+                     beta[:, h:h + 1], levels)
+              for h, at in enumerate(lanes)]
     inverses = _inverses([ch.a for ch in chunks]) if solve \
         else [None] * heads
     for ch, t in zip(chunks, inverses):
@@ -306,15 +398,80 @@ def _chunks(q_ref, k_ref, v_ref, g_ref, beta, heads, solve=True):
     return lanes, chunks
 
 
+def _products_around_block_starts_bwd(ch, dmkk, dmqk):
+    """(dq, dk, dG [L, d], None) of `_Chunk._products_around_block_starts`
+    at the cotangents of `K+ K-^T` and `Q+ K-^T` [L, L], masked and rounded:
+    the decays there are differences of the running sum G."""
+    chunk = dmkk.shape[0]
+    nb = chunk // _SUB
+    hi = ch.hi
+    row = _iota((chunk, 1), 0)
+    dkf = jnp.zeros_like(ch.kf)
+    dcum = jnp.zeros_like(ch.cum)
+    drows_k, drows_q = [], []
+    for a in range(nb):
+        blk = slice(a * _SUB, (a + 1) * _SUB)
+        both = jnp.concatenate([dmkk[blk], dmqk[blk]], axis=0)  # [32, L]
+        drows = _nn(both, ch.k_back[a], hi)                   # [32, d]
+        drows_k.append(drows[:_SUB])
+        drows_q.append(drows[_SUB:])
+        # k_back = k exp(ref_a - G_m): rows of later blocks read zeros
+        back = _tn(both, ch.rows[a], hi) * ch.back[a]         # [L, d]
+        dkf = dkf + back
+        moved = back * ch.kf
+        dcum = dcum - moved
+        if a:
+            dcum = dcum + jnp.where(
+                row == a * _SUB - 1,
+                jnp.sum(moved, axis=0, keepdims=True), 0.0)
+    dxk = jnp.concatenate(drows_k, axis=0)
+    dxq = jnp.concatenate(drows_q, axis=0)
+    dkf = dkf + dxk * ch.to_row
+    dqf = dxq * ch.to_row
+    moved = (dxk * ch.kf + dxq * ch.qf) * ch.to_row           # d to_row's
+    dcum = dcum + moved
+    for a in range(1, nb):
+        dcum = dcum - jnp.where(
+            row == a * _SUB - 1,
+            jnp.sum(jnp.where(ch.row_block == a, moved, 0.0), axis=0,
+                    keepdims=True), 0.0)
+    return dqf, dkf, dcum, None
+
+
+def _products_by_levels_bwd(ch, dmkk, dmqk):
+    """(dq, dk, dG, dg [L, d]) of `_Chunk._products_by_levels`: the levels'
+    decays are sums of g itself, so their part goes to dg and none of it
+    to the running sum's cotangent."""
+    chunk = dmkk.shape[0]
+    hi = ch.hi
+    r, c = _iota((chunk, chunk), 0), _iota((chunk, chunk), 1)
+    own = jnp.sum(jnp.where(r == c, dmqk.astype(_F32), 0.0), axis=1,
+                  keepdims=True)
+    dqf, dkf = own * ch.kf, own * ch.qf
+    dsums = []
+    for pair, decay, rows in zip(ch.pairs, ch.level_decay, ch.level_rows):
+        both = jnp.concatenate([jnp.where(pair, dmkk, 0.0),
+                                jnp.where(pair, dmqk, 0.0)], axis=0)
+        drows = _nn(both, rows[:chunk], hi)                   # [2 L, d]
+        dkc = drows[:chunk] + _tn(both, rows, hi)
+        dqc = drows[chunk:]
+        dkf = dkf + dkc * decay
+        dqf = dqf + dqc * decay
+        dsums.append((dkc * ch.kf + dqc * ch.qf) * decay)
+    dsums = jnp.concatenate(dsums, axis=0)                    # [n L, d]
+    dg = sum(_tn(ch.sums, part) for part in _thirds(dsums))
+    return dqf, dkf, jnp.zeros_like(ch.cum), dg
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, y_ref, states_ref,
-                s_ref, *, heads, solve):
+                s_ref, *, heads, exact, solve):
     @pl.when(pl.program_id(2) == 0)
     def _open():
         s_ref[...] = jnp.zeros_like(s_ref)
 
     states_ref[...] = s_ref[...]
     lanes, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, beta_ref[...], heads,
-                            solve)
+                            exact, solve)
     for at, ch in zip(lanes, chunks):
         s = s_ref[at, :]                                      # [K, V]
         sc = s.astype(ch.cdt)
@@ -325,17 +482,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, y_ref, states_ref,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, lam_ref, *, heads):
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, lam_ref, *, heads,
+                exact):
     @pl.when(pl.program_id(2) == 0)
     def _open():
         lam_ref[...] = jnp.zeros_like(lam_ref)
 
     chunk = q_ref.shape[0]
-    nb = chunk // _SUB
     head = _iota((1, heads), 1)
     row = _iota((chunk, 1), 0)
     dbeta_all = jnp.zeros((chunk, heads), _F32)
-    lanes, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, beta_ref[...], heads)
+    lanes, chunks = _chunks(q_ref, k_ref, v_ref, g_ref, beta_ref[...], heads,
+                            exact)
     for h, (at, ch) in enumerate(zip(lanes, chunks)):
         cdt, hi, beta = ch.cdt, ch.hi, ch.beta
         s, lam = states_ref[at, :], lam_ref[at, :]            # [K, V] float32
@@ -364,51 +522,31 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, states_ref, do_ref,
                          keepdims=True)
                  + jnp.sum(da * ch.mkk, axis=1, keepdims=True))
         dbeta_all = jnp.where(head == h, dbeta, dbeta_all)
-        # the decayed products, a row block at a time
         dmkk = (da * beta).astype(cdt)
         dmqk = jnp.where(ch.lower, dpqk, 0.0).astype(cdt)
-        dkf = jnp.zeros_like(ch.kf)
-        dcum = jnp.zeros_like(ch.cum)
-        drows_k, drows_q = [], []
-        for a in range(nb):
-            blk = slice(a * _SUB, (a + 1) * _SUB)
-            both = jnp.concatenate([dmkk[blk], dmqk[blk]], axis=0)  # [32, L]
-            drows = _nn(both, ch.k_back[a], hi)               # [32, d]
-            drows_k.append(drows[:_SUB])
-            drows_q.append(drows[_SUB:])
-            # k_back = k exp(ref_a - G_m): rows of later blocks read zeros
-            back = _tn(both, ch.rows[a], hi) * ch.back[a]     # [L, d]
-            dkf = dkf + back
-            moved = back * ch.kf
-            dcum = dcum - moved
-            if a:
-                dcum = dcum + jnp.where(
-                    row == a * _SUB - 1,
-                    jnp.sum(moved, axis=0, keepdims=True), 0.0)
-        dxk = jnp.concatenate(drows_k, axis=0)
-        dxq = jnp.concatenate(drows_q, axis=0)
-        dkf = dkf + dxk * ch.to_row
-        dqf = dxq * ch.to_row
-        moved = (dxk * ch.kf + dxq * ch.qf) * ch.to_row       # d to_row's
-        dcum = dcum + moved
-        for a in range(1, nb):
-            dcum = dcum - jnp.where(
-                row == a * _SUB - 1,
-                jnp.sum(jnp.where(ch.row_block == a, moved, 0.0), axis=0,
-                        keepdims=True), 0.0)
-        # kend = k exp(G_C - G), decay = exp(G_C), Q+ = q exp(G), K+
+        products = (_products_by_levels_bwd if exact
+                    else _products_around_block_starts_bwd)
+        dqf, dkf, dcum, dg = products(ch, dmkk, dmqk)
+        # kend = k to_end, decay = exp(G_C), Q+ = q exp(G), K+
         dkf = dkf + dkend * ch.to_end + dkplus * ch.from_start
         left = dkend * ch.kend
         dqf = dqf + dqplus * ch.from_start
-        dcum = dcum - left + dqplus * ch.qplus + dkplus * ch.kplus
+        dcum = dcum + dqplus * ch.qplus + dkplus * ch.kplus
         # d decay[k] = sum_v lam[k, v] S[k, v]
-        dtotal = (jnp.sum(left, axis=0, keepdims=True)
-                  + _row_sums_along_lanes(lam * s) * ch.decay)
+        dtotal = _row_sums_along_lanes(lam * s) * ch.decay
+        if exact:
+            # to_end = exp(the g's after a position)
+            dg = dg + _running_sum(_shift(left, down=True))
+        else:
+            # to_end = exp(G_C - G)
+            dcum = dcum - left
+            dtotal = dtotal + jnp.sum(left, axis=0, keepdims=True)
         dcum = dcum + jnp.where(row == chunk - 1, dtotal, 0.0)
         dq_ref[:, at] = dqf.astype(dq_ref.dtype)
         dk_ref[:, at] = dkf.astype(dk_ref.dtype)
         dv_ref[:, at] = dv.astype(dv_ref.dtype)
-        dg_ref[:, at] = _running_sum(dcum, reverse=True)
+        dg_own = _running_sum(dcum, reverse=True)
+        dg_ref[:, at] = dg_own + dg if exact else dg_own
     dbeta_ref[...] = dbeta_all
 
 
@@ -416,7 +554,8 @@ def _specs(plan_, reverse):
     """The blocks both kernels read, in the order of their leading
     arguments (q, k, v, g: one rows block; beta down the sublanes), and the
     map of a States block. `reverse`: the chunks last to first."""
-    heads, d, chunk, _, chunks, _ = plan_
+    heads, d, chunk, chunks = (plan_.heads, plan_.d, plan_.chunk,
+                               plan_.chunks)
 
     def at(c):
         return chunks - 1 - c if reverse else c
@@ -437,6 +576,14 @@ def _operands(plan_, q, k, v, g, beta):
             beta.reshape(b, s, plan_.steps, plan_.heads).transpose(0, 2, 1, 3))
 
 
+def _level_flops(chunk, d):
+    """What the levels add to a position's forward where g has no bound,
+    over `_decayed_products`' four products a chunk: the sums of g (three
+    passes over [n L, L]) and n - 1 products [2 L, d] x [d, L] more."""
+    n = len(_halves(chunk))
+    return 2 * (3 * n * chunk * d + (n - 1) * 2 * chunk * d)
+
+
 def kda_fwd(plan_, q, k, v, g, beta, solve=True):
     """`ops/kda.py` `_kda_fwd` under `plan_`: (o [B, S, H, V] in q's dtype,
     the state each chunk starts from [B, S / L, H, K, V] float32). One
@@ -450,11 +597,12 @@ def kda_fwd(plan_, q, k, v, g, beta, solve=True):
 @functools.partial(jax.jit, static_argnames=("plan_", "solve", "interpret"))
 def _kda_fwd(plan_, q, k, v, g, beta, *, solve, interpret):
     b, s, h, d = q.shape
-    heads, _, chunk, steps, chunks, resident = plan_
+    heads, _, chunk, steps, chunks, resident, exact = plan_
     rows, beta_block, states = _specs(plan_, reverse=False)
     size = q.dtype.itemsize
+    levels = _level_flops(chunk, d) if exact else 0
     y, sprev = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads, solve=solve),
+        functools.partial(_fwd_kernel, heads=heads, exact=exact, solve=solve),
         out_shape=(jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
                    jax.ShapeDtypeStruct((b, chunks, h * d, d), _F32)),
         grid=(b, steps, chunks),
@@ -464,7 +612,7 @@ def _kda_fwd(plan_, q, k, v, g, beta, *, solve, interpret):
         compiler_params=_compiler_params(resident, _SEMANTICS),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * s * h * (5 * chunk * d + 4 * d * d
-                                   + 7 * chunk * chunk),
+                                   + 7 * chunk * chunk) + b * s * h * levels,
             transcendentals=b * s * h * d * (4 + chunk // _SUB),
             bytes_accessed=(b * s * h * (4 * d * size + 4 * d + 4)
                             + 4 * b * chunks * h * d * d)),
@@ -484,12 +632,13 @@ def kda_bwd(plan_, q, k, v, g, beta, states, do):
 @functools.partial(jax.jit, static_argnames=("plan_", "interpret"))
 def _kda_bwd(plan_, q, k, v, g, beta, states, do, *, interpret):
     b, s, h, d = q.shape
-    heads, _, chunk, steps, chunks, resident = plan_
+    heads, _, chunk, steps, chunks, resident, exact = plan_
+    levels = _level_flops(chunk, d) if exact else 0
     rows, beta_block, states_block = _specs(plan_, reverse=True)
     size = q.dtype.itemsize
     like_rows = jax.ShapeDtypeStruct((b, s, h * d), q.dtype)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads),
+        functools.partial(_bwd_kernel, heads=heads, exact=exact),
         out_shape=(like_rows, like_rows, like_rows,
                    jax.ShapeDtypeStruct((b, s, h * d), _F32),
                    jax.ShapeDtypeStruct((b, steps, s, heads), _F32)),
@@ -500,7 +649,8 @@ def _kda_bwd(plan_, q, k, v, g, beta, states, do, *, interpret):
         compiler_params=_compiler_params(resident, _SEMANTICS),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * s * h * (14 * chunk * d + 10 * d * d
-                                   + 9 * chunk * chunk),
+                                   + 9 * chunk * chunk)
+            + 3 * b * s * h * levels,
             transcendentals=b * s * h * d * (4 + chunk // _SUB),
             bytes_accessed=(b * s * h * (8 * d * size + 8 * d + 8)
                             + 4 * b * chunks * h * d * d)),

@@ -9,6 +9,13 @@ the in-kernel solve costs).
 
     python scripts/kda_scan_sweep.py [--heads 4,8,16,2,1]
 
+`--unbounded B,S,H,D` instead times, at that shape and the shape rule's own
+heads a step, the two ways a chunk's decayed products are made: around the
+blocks' running sums (a decay bounded at -5.5 a token) and level by level
+with no factor above 1 (any decay), both kernels of each (`PERF.md` section
+6, PR 51, which also holds the reading of the candidate that lost: a block
+against itself summed pair by pair on the VPU).
+
 A time only on a TPU; elsewhere it refuses. Writes
 chiprun_out/kda_scan_sweep.json.
 """
@@ -22,9 +29,27 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import chip_smoke  # noqa: E402
 
 
+def unbounded(b, s, h, d):
+    shape = dict(b=b, s=s, h=h, d=d, chunk=chip_smoke.FULL["delta"]["chunk"])
+    rows = {"shape": shape}
+    for form in ("bounded", "exact"):
+        rows[form] = chip_smoke.kda_scan_forms(
+            **shape, time_xla=form == "exact", exact=form == "exact")
+        for name in ("fwd", "bwd"):
+            row = rows[form][name]
+            print(f"[kda_scan_sweep] {form} {name}: {row['ms_kernel']} ms"
+                  + (f"; jax.numpy form {row['ms_xla']}"
+                     if "ms_xla" in row else ""), flush=True)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kda_scan_sweep_unbounded.json", "w") as f:
+        json.dump(rows, f, indent=1)
+    return 0
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--heads", default="4,8,16,2,1")
+    ap.add_argument("--unbounded", default=None, metavar="B,S,H,D")
     args = ap.parse_args()
     import jax
     if jax.devices()[0].platform != "tpu":
@@ -32,6 +57,8 @@ def main():
         return 2
     from paddle_tpu import compile_cache
     compile_cache.enable()
+    if args.unbounded:
+        return unbounded(*(int(v) for v in args.unbounded.split(",")))
     rows = {}
     for i, heads in enumerate(int(v) for v in args.heads.split(",")):
         try:

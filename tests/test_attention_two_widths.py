@@ -437,3 +437,56 @@ def test_the_indexers_scores_compile_for_a_v5e_at_the_cells_size(
     assert not re.search(rf"\[({b},)?{h},\d+,{s}\]", text)
     assert f"s8[{b},{s},{s}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+
+
+def test_the_delta_rule_for_any_decay_compiles_for_a_v5e_at_the_cells_size(
+        v5e, monkeypatch):
+    """`kda_scan` WITHOUT a bound on the decay, forward and its grad rule's
+    backward at one layer of the unbounded-decay cell (1 x 4,096 positions, 8
+    heads of 128, bf16 operands, chunks of 64): Mosaic takes both kernels
+    with the level-by-level products (the 0 / 1 matrix of a chunk's sums
+    from iotas, three bf16 passes over it, six `[2 L, d] x [d, L]` products
+    a chunk and head), four heads a grid step inside the VMEM budget. The
+    chunk states are the one `[.., 128, 128]` float32 value the layer
+    writes."""
+    from paddle_tpu.ops.pallas import kda_chunk
+    monkeypatch.setattr(kda_chunk, "interpret_mode", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    s, h, d, chunk = 4096, 8, 128, 64
+    opdef = registry.get("kda_scan")
+    attrs = {"chunk_size": chunk, "beta_scale": 2.0}
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def layer(q, k, v, g, beta, do):
+        ctx = registry.LowerCtx(rng_key=None)
+        ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
+        outs = opdef.lower(ctx, ins, attrs)
+        grads = opdef.grad(ctx, ins, attrs, {"States": outs["States"]},
+                           {"Y": [do]})
+        return outs["Y"][0], [grads[slot][0] for slot in ins]
+
+    rows = (1, s, h, d)
+    plan = kda_chunk.plan(rows, rows, chunk, jnp.bfloat16, exact=True)
+    assert plan.exact and plan.heads == 4
+    assert plan.resident_bytes + (8 << 20) < 16 << 20
+    counters = ("kda.scan_pallas", "kda.scan_exact", "kda.scan_bounded")
+    before = [metrics.get(c) for c in counters]
+    args = (sd(rows), sd(rows), sd(rows), sd(rows, jnp.float32),
+            sd(rows[:3], jnp.float32))
+    try:
+        compiled = jax.jit(layer).trace(*args, sd(rows)).lower(
+            lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert [metrics.get(c) - v for c, v in zip(counters, before)] == [2, 2, 0]
+    text = compiled.as_text()
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(k.rsplit(".", 1)[0] for k in kernels) \
+        == ["kda-chunk-bwd", "kda-chunk-fwd"], kernels
+    assert f"f32[1,{s // chunk},{h * d},{d}]" in text
+    assert "triangular_solve" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6

@@ -15,7 +15,7 @@ import pytest
 from causal_lm_harness import causal_lm, tiny_step_digests
 
 from paddle_tpu.models import (bert, deepseek_v3, keye, lfm2, ling, mellum,
-                               nemotron_h)
+                               nemotron_h, solar)
 
 
 def _bert_pretrain():
@@ -43,9 +43,12 @@ _STEPS = {
         nemotron_h, nemotron_h.NemotronHConfig.tiny_latent_share()),
         "ce94d43b5b24963e07c270f8de8b711da0ef8d8ea3d4a1909f00d4de6d174779",
         "b386de2cac892bcce0a6ce879279d0c0d29f3c36288c8ddd0e6c44f5ae746174"),
+    # PR 51 changed this row's Programs digest and not its step's: the
+    # builder hands `kda_scan` its `kda_lower_bound` (the attr `lower_bound`,
+    # which keeps the op on the form the step had)
     "ling": (causal_lm(ling, ling.LingConfig.tiny()),
         "60c17202fc73f82ce61d96a22f7176830b0d9bb93b04242555d4f6854dc22072",
-        "97fd904933b7516a7e24d13fbf4b795362bbb1769a03c4ebeb1b47415c9874ef"),
+        "bbf99a35aa77935087ad52f1b9fb4025bfb272668a14d1c4fcfb9eeaf605fa74"),
     # made at PR 43, which brought the builder: held from here on
     "keye": (causal_lm(keye, keye.KeyeConfig.tiny()),
         "e7f1483278b20170f7207a64a84339842724a53e758ffd5fb9e27d04a6110e51",
@@ -54,6 +57,10 @@ _STEPS = {
     "lfm2": (causal_lm(lfm2, lfm2.Lfm2Config.tiny()),
         "88e566ffafa22ccc31f0ec920db0f780e814a0cdb05d510ae2f60c8f94f3d2bc",
         "0afdfcecfbee5f872840d5dfd1052d5794bac36697610f3732278d5b219f7af5"),
+    # made at PR 51, which brought the builder: held from here on
+    "solar": (causal_lm(solar, solar.SolarConfig.tiny()),
+        "e1628ecb4becbc7f830f4f1b978b3308eddff84ec494e688b049d964948e36c9",
+        "0d3403ce321b51736f8128a4192ed4ab12507abe3aff3e90124c424c0e111198"),
 }
 
 

@@ -70,7 +70,7 @@ def test_chunked_delta_rule_is_the_recurrence_forward_and_backward(chunk,
     assert float(ins["G"].min()) < -4.99
     opdef = registry.get("kda_scan")
     ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    attrs = {"chunk_size": chunk}
+    attrs = {"chunk_size": chunk, "lower_bound": -5.0}
     routes = [metrics.get(c) for c in _ROUTES]
     with jax.default_matmul_precision("highest"):
         outs = opdef.lower(ctx, {k: [v] for k, v in ins.items()}, attrs)
@@ -127,7 +127,8 @@ def test_delta_rule_in_bf16_keeps_decay_and_states_float32(shape):
     ctx = registry.LowerCtx(rng_key=jax.random.key(0))
     routes = [metrics.get(c) for c in _ROUTES]
     outs = registry.get("kda_scan").lower(
-        ctx, {k: [v] for k, v in low.items()}, {"chunk_size": 64})
+        ctx, {k: [v] for k, v in low.items()},
+        {"chunk_size": 64, "lower_bound": -5.0})
     assert [metrics.get(c) - r for c, r in zip(_ROUTES, routes)] \
         == ([1, 0] if shape else [0, 1])
     want = _recurrence(ins)
@@ -250,7 +251,7 @@ def test_the_kernels_solve_and_decay_bound_under_stress(case):
     ins = _stress(case)
     opdef = registry.get("kda_scan")
     ctx = registry.LowerCtx(rng_key=jax.random.key(0))
-    attrs = {"chunk_size": 64}
+    attrs = {"chunk_size": 64, "lower_bound": -5.0}
     routes = [metrics.get(c) for c in _ROUTES]
     with jax.default_matmul_precision("highest"):
         outs = opdef.lower(ctx, {k: [v] for k, v in ins.items()}, attrs)
@@ -320,7 +321,7 @@ def test_the_kernels_shape_rule_and_the_form_it_leaves(monkeypatch):
     def step(q, k, v, g, beta, do):
         ctx = registry.LowerCtx(rng_key=None)
         ins = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
-        attrs = {"chunk_size": 64}
+        attrs = {"chunk_size": 64, "lower_bound": -5.0}
         outs = opdef.lower(ctx, ins, attrs)
         grads = opdef.grad(ctx, ins, attrs,
                            {s: outs[s] for s in opdef.residual_slots},
