@@ -292,7 +292,7 @@ SPECS: Dict[str, OpSpec] = {
         outputs={"Out": ONE, "Lse": OPT, "Target": OPT},
         attr_types={"scale": _NUM, "dropout": _NUM, "causal": bool,
                     "sequence_parallel": bool, "sp_mode": str,
-                    "window": int, "return_target": bool},
+                    "window": int, "return_target": bool, "layout": str},
         sharding="attention"),
     # --- the sparse-attention indexer (ops/sparse_index.py) ---------------
     "sparse_index": OpSpec(

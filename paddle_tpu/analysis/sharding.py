@@ -525,8 +525,12 @@ def _rule_layer_norm(ctx, i, op):
 
 
 def _rule_attention(ctx, i, op):
-    _set_all_outputs(ctx, op, ctx.spec_of((op.inputs.get("Q")
-                                           or [EMPTY])[0]))
+    spec = ctx.spec_of((op.inputs.get("Q") or [EMPTY])[0])
+    _set_all_outputs(ctx, op, spec)
+    if op.attrs.get("layout") == "bshd" and len(spec) >= 3:
+        # Q is [B, S, heads, hd] there and Lse [B, heads, S] all the same
+        for n in op.outputs.get("Lse", ()):
+            ctx.set_spec(n, (spec[0], spec[2], spec[1]))
 
 
 def _rule_moe(ctx, i, op):

@@ -811,11 +811,16 @@ def pow(x, factor=1.0, name=None):
 def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
                     causal=False, name=None, sequence_parallel=False,
                     sp_mode="ring", window=None, select=None,
-                    return_target=False):
+                    return_target=False, layout="bhsd"):
     """Fused multi-head attention on [B, nh, S, hd] tensors (reference
     fused/multihead_matmul_op.cu); pallas flash kernel on TPU. `k` and `v`
     may carry fewer heads, [B, nkv, S, hd] with nkv dividing nh: query head
-    h attends KV head h // (nh / nkv). With `causal`, `window` w lets a
+    h attends KV head h // (nh / nkv). `layout` "bshd": q [B, S, nh, hd],
+    k and v [B, S, nkv, hd] and the result [B, S, nh, hd_v], a projection's
+    [B, S, heads * hd] under a reshape and no transpose; where the flash
+    kernels can index heads as lane blocks of those rows they take them as
+    they lie, everywhere else the op transposes inside itself, so a builder
+    may always pass it (ops/attention.py, "Layout"). With `causal`, `window` w lets a
     query at position i see keys i-w+1..i only. With
     sequence_parallel=True the op runs ring attention (sp_mode="ring") or
     Ulysses all-to-all (sp_mode="ulysses") over the mesh's sp axis — the
@@ -837,6 +842,10 @@ def fused_attention(q, k, v, mask=None, scale=None, dropout=0.0,
     attrs = {"dropout": dropout, "causal": causal, "is_test": False,
              "sequence_parallel": bool(sequence_parallel),
              "sp_mode": sp_mode}
+    if layout != "bhsd":
+        if layout != "bshd":
+            raise ValueError(f"fused_attention: unknown layout {layout!r}")
+        attrs["layout"] = layout
     if scale is not None:
         attrs["scale"] = scale
     if window is not None:

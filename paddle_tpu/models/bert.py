@@ -80,8 +80,9 @@ def encoder_layer(x, cfg: BertConfig, idx: int, attn_mask=None):
     pre = x
 
     def heads(t):
-        t = layers.reshape(t, [0, 0, nh, hd])
-        return layers.transpose(t, [0, 2, 1, 3])  # [B, nh, S, hd]
+        # cut where the projection's rows lie: the attention op takes
+        # [B, S, nh, hd] (its layout "bshd") and no transpose is built
+        return layers.reshape(t, [0, 0, nh, hd])
 
     with name_scope("attn.proj"):
         # fused QKV projection (one MXU matmul instead of three)
@@ -96,9 +97,9 @@ def encoder_layer(x, cfg: BertConfig, idx: int, attn_mask=None):
         ctx = layers.fused_attention(
             q, k, v, mask=attn_mask, scale=1.0 / math.sqrt(hd),
             dropout=cfg.attention_dropout,
-            sequence_parallel=cfg.sequence_parallel, sp_mode=cfg.sp_mode)
+            sequence_parallel=cfg.sequence_parallel, sp_mode=cfg.sp_mode,
+            layout="bshd")
     with name_scope("attn.proj"):
-        ctx = layers.transpose(ctx, [0, 2, 1, 3])
         ctx = layers.reshape(ctx, [0, 0, h])
         proj = layers.fc(ctx, h, num_flatten_dims=2,
                          param_attr=_attr(f"enc{idx}_attn_proj_w"),

@@ -31,6 +31,22 @@ leave at nkv heads); the dense route computes the same mathematics over a
 head per query head and no window: K and V are repeated there
 (`attention.flash_kv_expanded`), a window raises.
 
+Layout. The attr `layout` says how the inputs arrive: "bhsd" (the default),
+Q [B, nh, S, hd] with K and V [B, nkv, S, ...], or "bshd", Q [B, S, nh, hd]
+with K and V [B, S, nkv, ...]: a projection's [B, S, heads * hd] cut into
+heads by a reshape and nothing else. `Out` leaves in the inputs' layout,
+`Lse` is [B, nh, S] in both. On the flash route, where the shapes are ones
+the kernels index as lane blocks of a row (`rows_layout_fits`: widths that
+are multiples of 128, or 64 on equal, even head counts), a "bshd" op hands
+the arrays over as they are and no transpose exists in either direction of
+the step (`attention.flash_layout_rows`); everywhere else (the dense and the
+ring / Ulysses routes, a width of 192, 64-wide heads on grouped KV heads,
+a selection or a per-head mask at 64) the op transposes inside itself and
+runs exactly what a "bhsd" op runs (`attention.flash_layout_heads` where
+that is the flash kernels), so a builder may always pass "bshd". A static
+fact of the shapes like the route, read by the forward and the grad rule
+alike.
+
 A selection. `Select` [B, S, S] int8 (1 where query t attends key s; a
 learned indexer's, ops/sparse_index.py) is an input of its own, shared by
 all heads of a row and stored once a row; with `causal` alone. The flash
@@ -45,6 +61,7 @@ under the scope `attn.index.target`; no gradient passes through it.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -181,6 +198,51 @@ def _unpack(ins, attrs):
     return q, k, v, mask, scale, dropout, attrs.get("causal", False)
 
 
+def _bshd(attrs):
+    return attrs.get("layout", "bhsd") == "bshd"
+
+
+def _swap_heads(t):
+    """[B, S, heads, w] <-> [B, heads, S, w]."""
+    return jnp.swapaxes(t, 1, 2)
+
+
+# the shape and dtype a "bshd" input has once head-major
+_HeadMajor = collections.namedtuple("_HeadMajor", "shape dtype")
+
+
+def _facts(t, attrs):
+    """`t` as the static gates read it, [B, heads, S, width]: of a "bshd"
+    input the shape alone, since the gates must trace no transpose to
+    decide (a `ShapeDtypeStruct` stays one: `_route` knows it by type)."""
+    if not _bshd(attrs):
+        return t
+    b, s, heads, width = t.shape
+    kind = (jax.ShapeDtypeStruct if isinstance(t, jax.ShapeDtypeStruct)
+            else _HeadMajor)
+    return kind((b, heads, s, width), t.dtype)
+
+
+def _kernel_layout(route, q, k, v, mask, attrs, select=None):
+    """The layout the flash kernels are launched in: "bshd" where a "bshd"
+    op's inputs are ones they take as they lie, else "bhsd" (static facts;
+    `q`, `k`, `v` as `_facts` gives them)."""
+    from .pallas.flash_attention import rows_layout_fits
+    if route == "flash" and _bshd(attrs) and rows_layout_fits(
+            q.shape[-1], v.shape[-1], q.shape[1], k.shape[1],
+            None if mask is None else mask.shape, select is not None):
+        return "bshd"
+    return "bhsd"
+
+
+def _laid_out(outs, moved):
+    """`Out` back in a "bshd" op's layout where the op ran head-major
+    (`moved`: it transposed its inputs)."""
+    if moved:
+        outs["Out"] = [_swap_heads(outs["Out"][0])]
+    return outs
+
+
 def _window(attrs):
     """The attr `window`, absent where the layer has none (the layer
     function holds it to `causal`)."""
@@ -215,6 +277,15 @@ def _count_causal_blocks(s, window):
     metrics.inc("attention.flash_blocks_edge", edge)
 
 
+def _count_layout(layout):
+    """At trace time, per flash forward lowered: whether the kernels read
+    the projection's rows as they lie ("bshd"), or heads a transpose laid
+    out (the caller's, or for a "bshd" op this op's own)."""
+    from ..observability import metrics
+    metrics.inc("attention.flash_layout_rows" if layout == "bshd"
+                else "attention.flash_layout_heads")
+
+
 def _flash_failed(e, q, mask, causal, dropout):
     return RuntimeError(
         f"pallas flash attention failed for q{tuple(q.shape)} "
@@ -231,23 +302,32 @@ def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
     q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
     dout = (ogs.get("Out") or [None])[0]
     select = _select(ins, attrs, mask, causal, dropout)
-    if _route(ctx, q, v, mask, attrs)[0] != "flash" or dout is None \
+    fq, fk, fv = (_facts(t, attrs) for t in (q, k, v))
+    route = _route(ctx, fq, fv, mask, attrs)[0]
+    if route != "flash" or dout is None \
             or not outs.get("Out") or not outs.get("Lse"):
         return None
     from .pallas.flash_attention import flash_attention_bwd
     from ..observability import metrics
     out, lse = outs["Out"][0], outs["Lse"][0]
-    b, nh, s, _ = q.shape
+    b, nh, s, _ = fq.shape
     seed = _derive_seed(ctx.op_key(attrs)) if dropout else None
+    dout = dout.astype(out.dtype)
+    layout = _kernel_layout(route, fq, fk, fv, mask, attrs, select)
+    moved = _bshd(attrs) and layout == "bhsd"
+    if moved:
+        q, k, v, out, dout = (_swap_heads(t) for t in (q, k, v, out, dout))
     try:
-        dq, dk, dv = flash_attention_bwd(
-            q, k, v, out, lse.reshape(b * nh, s), dout.astype(out.dtype),
+        grads = flash_attention_bwd(
+            q, k, v, out, lse.reshape(b * nh, s), dout,
             scale=scale, causal=causal, dropout=dropout, seed=seed,
-            mask=mask, window=_window(attrs), select=select)
+            mask=mask, window=_window(attrs), select=select, layout=layout)
     except Exception as e:
         raise _flash_failed(e, q, mask, causal, dropout) from e
     metrics.inc("attention.flash_bwd_residual")
-    return {"Q": [dq], "K": [dk], "V": [dv]}
+    if moved:
+        grads = [_swap_heads(t) for t in grads]
+    return dict(zip("QKV", ([t] for t in grads)))
 
 
 @register("fused_attention", is_random=True,
@@ -256,32 +336,40 @@ def _fused_attention_grad(ctx, ins, attrs, outs, ogs):
 def _fused_attention(ctx, ins, attrs):
     q, k, v, mask, scale, dropout, causal = _unpack(ins, attrs)
     key = ctx.op_key(attrs) if dropout else None
-    b, nh, s, _ = q.shape
-    route, sp_fn = _route(ctx, q, v, mask, attrs)
+    fq, fk, fv = (_facts(t, attrs) for t in (q, k, v))
+    (b, nh, s, _), nkv = fq.shape, fk.shape[1]
+    route, sp_fn = _route(ctx, fq, fv, mask, attrs)
     window = _window(attrs)
     select = _select(ins, attrs, mask, causal, dropout)
+    layout = _kernel_layout(route, fq, fk, fv, mask, attrs, select)
+    moved = _bshd(attrs) and layout == "bhsd"
+    if moved:
+        q, k, v = (_swap_heads(t) for t in (q, k, v))
     if select is not None:
-        return _selected_attention(ctx, q, k, v, select, scale, route,
-                                   bool(attrs.get("return_target")))
+        return _laid_out(_selected_attention(
+            ctx, q, k, v, select, scale, route,
+            bool(attrs.get("return_target")), layout), moved)
     if route == "sp":
-        if k.shape[1] != nh:
+        if nkv != nh:
             from ..observability import metrics
             metrics.inc("attention.flash_kv_expanded")
             k, v = (jnp.repeat(t, nh // t.shape[1], axis=1) for t in (k, v))
         sp_seed = _derive_seed(key) if dropout else None
         # key-padding masks + in-body counter dropout ride the ring
         # (round 4; full [S, S] masks still raise — see _check_mask)
-        return {"Out": [sp_fn(q, k, v, scale=scale, causal=causal,
-                              mask=mask, dropout=float(dropout),
-                              seed=sp_seed)],
-                "Lse": [_no_lse(q)]}
+        return _laid_out(
+            {"Out": [sp_fn(q, k, v, scale=scale, causal=causal,
+                           mask=mask, dropout=float(dropout),
+                           seed=sp_seed)],
+             "Lse": [_no_lse(q)]}, moved)
     if route == "flash":
         from .pallas.flash_attention import flash_attention
         seed = _derive_seed(key) if dropout else None
         try:
             out, lse = flash_attention(q, k, v, scale=scale, causal=causal,
                                        dropout=dropout, seed=seed, mask=mask,
-                                       return_lse=True, window=window)
+                                       return_lse=True, window=window,
+                                       layout=layout)
         except Exception as e:
             raise _flash_failed(e, q, mask, causal, dropout) from e
         from ..observability import metrics
@@ -289,7 +377,8 @@ def _fused_attention(ctx, ins, attrs):
                     + _OPERAND_TAG.get(q.dtype.name, q.dtype.name))
         metrics.inc("attention.flash_window" if window
                     else "attention.flash_full")
-        if k.shape[1] != nh:
+        _count_layout(layout)
+        if nkv != nh:
             metrics.inc("attention.flash_kv_grouped")
         if causal:
             _count_causal_blocks(s, window)
@@ -297,18 +386,23 @@ def _fused_attention(ctx, ins, attrs):
             # the generic __vjp__ (a whole segment under recompute or layer
             # scan) lowers this forward a second time to differentiate it
             metrics.inc("attention.flash_bwd_recomputed")
-        return {"Out": [out], "Lse": [lse.reshape(b, nh, s)]}
+        return _laid_out({"Out": [out], "Lse": [lse.reshape(b, nh, s)]},
+                         moved)
     if causal:
         tri = _causal_bias(s, window)
         mask = tri if mask is None else mask + tri
-    return {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)],
-            "Lse": [_no_lse(q)]}
+    return _laid_out(
+        {"Out": [_xla_attention(q, k, v, mask, scale, dropout, key)],
+         "Lse": [_no_lse(q)]}, moved)
 
 
-def _selected_attention(ctx, q, k, v, select, scale, route, want_target):
-    """`fused_attention` over a selection: {"Out", "Lse"[, "Target"]}."""
+def _selected_attention(ctx, q, k, v, select, scale, route, want_target,
+                        layout):
+    """`fused_attention` over a selection: {"Out", "Lse"[, "Target"]}; q,
+    k, v and `Out` in `layout` (`_kernel_layout`'s)."""
     from ..observability import metrics
-    b, nh, s, _ = q.shape
+    b, nh = q.shape[0], q.shape[2 if layout == "bshd" else 1]
+    s = select.shape[1]
     if route == "sp":
         raise NotImplementedError(
             "fused_attention: the ring / Ulysses routes have no selection")
@@ -318,11 +412,13 @@ def _selected_attention(ctx, q, k, v, select, scale, route, want_target):
                                              selected_probs_sum)
         try:
             out, lse = flash_attention(q, k, v, scale=scale, causal=True,
-                                       return_lse=True, select=select)
+                                       return_lse=True, select=select,
+                                       layout=layout)
         except Exception as e:
             raise _flash_failed(e, q, None, True, 0.0) from e
         if count:
             metrics.inc("attn.sparse_pallas")
+        _count_layout(layout)
         _count_causal_blocks(s, None)
         if ctx.in_vjp:
             metrics.inc("attention.flash_bwd_recomputed")
@@ -333,7 +429,7 @@ def _selected_attention(ctx, q, k, v, select, scale, route, want_target):
                 # more for a [B, S, S] float32 the loss's backward reads
                 outs["Target"] = [keep_under_recompute(selected_probs_sum(
                     *jax.lax.stop_gradient((q, k, lse)), select,
-                    scale=scale))]
+                    scale=scale, layout=layout))]
         return outs
     if count and not isinstance(q, jax.ShapeDtypeStruct):
         metrics.inc("attn.sparse_xla")

@@ -96,3 +96,67 @@ def selection_with_empty_rows(s, block_q, block_k, seed=0):
     sel[0, row + 1, row + 1] = True
     sel[0, row + 2, 0] = True
     return jnp.asarray(sel.astype(np.int8))
+
+
+def padding_mask(b, s, seed=0):
+    """Additive key-padding mask [B, 1, 1, S] float32: every row keeps at
+    least half its keys."""
+    lens = np.random.RandomState(seed).randint(s // 2, s + 1, size=b)
+    return jnp.asarray(np.where(np.arange(s)[None] < lens[:, None], 0.0,
+                                -1e9)[:, None, None, :], jnp.float32)
+
+
+def check_rows_layout(b, s, nh, nkv, hd, *, causal=False, window=None,
+                      dropout=0.0, padded=False, block_q=256, block_k=512,
+                      select=None, tol=2e-6):
+    """The launches in layout "bshd" (q [B, S, nh, hd], k and v
+    [B, S, nkv, hd]: a projection's rows as they lie) against the launches
+    in layout "bhsd" on the same operands transposed: the forward, its lse
+    and all three gradients, float32. A head's scores, softmax and dropout
+    pattern are the same numbers in the same order in both, so `out`, `lse`
+    and dq are held BIT FOR BIT (one kept probability that differed would
+    show); dk and dv of a 64-wide pair sum over the pair's stacked rows in
+    another order and are held to `tol`. With `select` (causal, 128 wide)
+    the selection rides along and `selected_probs_sum` is held too."""
+    rng = np.random.RandomState(b + s + nh + 3 * nkv + hd)
+    q, cot = (jnp.asarray(rng.randn(b, s, nh, hd), jnp.float32)
+              for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(b, s, nkv, hd), jnp.float32)
+            for _ in range(2))
+    kw = dict(scale=hd ** -0.5, causal=causal, window=window,
+              dropout=dropout, seed=11 if dropout else None,
+              mask=padding_mask(b, s) if padded else None,
+              block_q=block_q, block_k=block_k, select=select)
+
+    def launches(layout, *ops):
+        q, k, v, cot = ops
+        out, lse = fa.flash_attention(q, k, v, return_lse=True,
+                                      layout=layout, **kw)
+        target = ()
+        if select is not None:
+            target = (fa.selected_probs_sum(
+                q, k, lse, select, scale=kw["scale"], block_q=block_q,
+                block_k=block_k, layout=layout),)
+        return (out, lse) + tuple(fa.flash_attention_bwd(
+            q, k, v, out, lse, cot, layout=layout, **kw)) + target
+
+    def swap(t):
+        return jnp.swapaxes(t, 1, 2)
+
+    rows = launches("bshd", q, k, v, cot)
+    heads = launches("bhsd", *(swap(t) for t in (q, k, v, cot)))
+    assert rows[0].shape == (b, s, nh, hd) and rows[1].shape == (b * nh, s)
+    assert rows[2].shape == q.shape
+    assert rows[3].shape == k.shape and rows[4].shape == v.shape
+    heads = ((swap(heads[0]), heads[1]) + tuple(swap(t) for t in heads[2:5])
+             + heads[5:])
+    for name, got, want in zip(("out", "lse", "dq", "dk", "dv", "target"),
+                               rows, heads):
+        got, want = np.asarray(got), np.asarray(want)
+        # a row with no key has lse -inf, in both
+        assert name == "lse" or np.isfinite(got).all(), name
+        if name in ("out", "lse", "dq") or hd % 128 == 0:
+            assert np.array_equal(got, want), name
+        else:
+            err = np.abs(got - want).max() / np.abs(want).max()
+            assert err < tol, (name, err)

@@ -235,3 +235,151 @@ def test_segment_lowerings_keep_recomputing_and_say_so(open_gate, knob):
     losses, rise = counter_rise(two_steps)
     assert np.isfinite(losses).all() and losses[1] < losses[0]
     assert rise[0] == 0 and (rise[1] >= 1) == (knob == "layer_scan")
+
+
+# ---------------------------------------------------------------------------
+# the attr `layout` (PR 52): a "bshd" op hands the flash kernels its inputs
+# as they lie where they can index them, and transposes inside itself
+# everywhere else
+# ---------------------------------------------------------------------------
+
+LAYOUT_COUNTERS = COUNTERS + ("attention.flash_layout_rows",
+                              "attention.flash_layout_heads")
+
+
+def layout_grads(nh, nkv, dqk, dv, layout, use_rule=True, causal=True,
+                 dropout=0.0):
+    """(out, dq, dk, dv) head-major and the rise of `LAYOUT_COUNTERS`, of
+    sum(Out * W) through a one-op program whose inputs arrive in `layout`
+    (the same numbers either way: fed head-major arrays are transposed on
+    the host for "bshd")."""
+    reset_programs(0)
+    rng = np.random.RandomState(5)
+    widths = {"q": (nh, dqk), "k": (nkv, dqk), "v": (nkv, dv),
+              "w": (nh, dv)}
+    rows = layout == "bshd"
+
+    def lay(a):
+        return np.ascontiguousarray(a.swapaxes(1, 2)) if rows else a
+
+    feed, qkv = {}, []
+    for n, (heads, width) in widths.items():
+        feed[n] = lay(rng.randn(B, heads, S, width).astype(np.float32))
+        var = layers.data(name=n, shape=list(feed[n].shape[1:]),
+                          dtype="float32")
+        var.stop_gradient = n == "w"
+        qkv.append(var)
+    w = qkv.pop()
+    out = layers.fused_attention(*qkv, causal=causal, dropout=dropout,
+                                 scale=dqk ** -0.5, layout=layout)
+    assert tuple(out.shape)[1:] == feed["w"].shape[1:]
+    loss = layers.reduce_sum(layers.elementwise_mul(out, w))
+    grads = fluid.gradients(loss, qkv)
+    opdef = registry.get("fused_attention")
+    rule = opdef.grad
+    opdef.grad = rule if use_rule else None
+    before = [metrics.get(n) for n in LAYOUT_COUNTERS]
+    try:
+        vals = fluid.Executor().run(fluid.default_main_program(), feed=feed,
+                                    fetch_list=[out] + grads)
+    finally:
+        opdef.grad = rule
+    rise = tuple(int(metrics.get(n) - b)
+                 for n, b in zip(LAYOUT_COUNTERS, before))
+    return [lay(np.asarray(v)) for v in vals], rise
+
+
+@pytest.fixture
+def wide_gate(monkeypatch):
+    monkeypatch.setattr(
+        attention, "_use_pallas",
+        lambda q: q.shape[2] % 128 == 0 and q.shape[3] in (64, 128, 192))
+
+
+@pytest.mark.parametrize("nh, nkv, dqk, dv, reads_rows", [
+    (4, 4, 64, 64, True),       # BERT's kind: 64 wide, in pairs
+    (4, 2, 128, 128, True),     # grouped KV heads of 128
+    (2, 1, 128, 128, True),
+    # (iv) what the kernels cannot index falls back INSIDE the op
+    (3, 3, 64, 64, False),      # an odd head count
+    (4, 2, 64, 64, False),      # 64 wide on grouped KV heads
+    (2, 2, 192, 128, False),    # latent attention's two widths
+], ids=["pairs", "4on2-128", "2on1-128", "odd-heads", "4on2-64", "192-128"])
+@pytest.mark.parametrize("dropout", [0.0, 0.1])
+def test_a_bshd_op_takes_the_forwards_route_in_its_grad_rule(
+        wide_gate, nh, nkv, dqk, dv, reads_rows, dropout):
+    """The forward and the grad rule read ONE static decision: where the
+    kernels take the rows, both launch them in layout "bshd" (counter
+    `flash_layout_rows`, the backward on the forward's residuals), else
+    both run what a "bhsd" op runs behind transposes of the op's own
+    (`flash_layout_heads`) and give its numbers bit for bit. Either way
+    the rule's gradients are the generic route's, which differentiates
+    the forward's own lowering."""
+    want, rise_heads = layout_grads(nh, nkv, dqk, dv, "bhsd",
+                                    dropout=dropout)
+    got, rise = layout_grads(nh, nkv, dqk, dv, "bshd", dropout=dropout)
+    generic, rise_generic = layout_grads(nh, nkv, dqk, dv, "bshd",
+                                         use_rule=False, dropout=dropout)
+    assert rise_heads == (1, 0, 0, 1)
+    assert rise == ((1, 0, 1, 0) if reads_rows else (1, 0, 0, 1))
+    # the generic route lowers the forward a second time to differentiate it
+    assert rise_generic == ((0, 1, 2, 0) if reads_rows else (0, 1, 0, 2))
+    pair = reads_rows and dqk == 64
+    for name, a, b, c in zip(("out", "dq", "dk", "dv"), got, want, generic):
+        assert np.isfinite(a).all() and np.abs(a).max() > 0, name
+        np.testing.assert_array_equal(a, c, err_msg=name)
+        if pair and name in ("dk", "dv"):
+            # a pair sums its stacked rows in another order
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
+                                       err_msg=name)
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def test_a_bshd_op_on_the_dense_route_gives_the_bhsd_ops_numbers():
+    """Off the flash route (here: any backend but a TPU) a "bshd" op is
+    the dense lowering behind its own transposes: no kernel, no counter,
+    the rule declines, and the numbers are the "bhsd" op's."""
+    want, rise_heads = layout_grads(4, 2, 64, 64, "bhsd")
+    got, rise = layout_grads(4, 2, 64, 64, "bshd")
+    assert rise == rise_heads == (0, 0, 0, 0)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+def test_the_layout_counters_of_a_bert_trace(open_gate):
+    """One count a flash forward lowered, by what the kernels read: BERT's
+    encoder passes "bshd" and its 64-wide heads go in pairs (+layers / +0,
+    +12 / +0 in the s512 cell's trace); a builder that transposes itself
+    (latent attention; kanana's +5 flash layers) counts under heads
+    (tests/test_ling.py holds that builder's trace where it was)."""
+    names = LAYOUT_COUNTERS[2:]
+    before = [metrics.get(n) for n in names]
+    exe, loss, feed = bert_trainer(S)
+    exe.step_jaxpr(feed, [loss])
+    assert [int(metrics.get(n) - b) for n, b in zip(names, before)] == [2, 0]
+    # the same trace at a sequence the gate refuses: the dense route, none
+    before = [metrics.get(n) for n in names]
+    exe, loss, feed = bert_trainer(32)
+    exe.step_jaxpr(feed, [loss])
+    assert [int(metrics.get(n) - b) for n, b in zip(names, before)] == [0, 0]
+
+
+def test_the_layout_counters_of_a_latent_attention_trace(monkeypatch):
+    """kanana's builder (`deepseek_v3.latent_attention`: q and k wider than
+    v) keeps its own transposes and passes the default layout: 0 rows / +n
+    heads for its n flash layers, whatever the widths."""
+    import dataclasses
+    import causal_lm_harness as harness
+    from paddle_tpu.models import deepseek_v3
+    monkeypatch.setattr(attention, "_use_pallas",
+                        lambda q: q.shape[2] % 128 == 0)
+    cfg = dataclasses.replace(deepseek_v3.DeepseekV3Config.tiny(),
+                              seq_len=128, qk_nope_head_dim=120,
+                              v_head_dim=64)        # 128 and 64 wide
+    exe, loss, ids = harness.amp_step(deepseek_v3, cfg)
+    names = LAYOUT_COUNTERS[2:]
+    before = [metrics.get(n) for n in names]
+    exe.step_jaxpr({"tokens": ids}, [loss], k=2)
+    assert [int(metrics.get(n) - b) for n, b in zip(names, before)] == [
+        0, cfg.num_hidden_layers]

@@ -181,6 +181,135 @@ def test_grouped_kernels_compile_for_a_v5e_at_the_cells_size(v5e, window,
     assert text.count("tpu_custom_call") >= 3
 
 
+def _rows_launches_text(v5e, shapes, **kw):
+    """The compiled text of the three launches in layout "bshd" from
+    [B, S, heads * hd] projections (`shapes`: heads of q and of k, v)."""
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    (b, s, hd), (nh, nkv) = shapes
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def cut(t, heads):
+        return t.reshape(b, s, heads, hd)
+
+    def step(q, k, v, do, *extra):
+        kws = dict(kw, **dict(zip(("mask", "seed"), extra)))
+        q, k, v, do = cut(q, nh), cut(k, nkv), cut(v, nkv), cut(do, nh)
+        o, lse = fa.flash_attention(q, k, v, return_lse=True, layout="bshd",
+                                    **kws)
+        return [t.reshape(b, s, -1) for t in (o,) + fa.flash_attention_bwd(
+            q, k, v, o, lse, do, layout="bshd", **kws)]
+
+    extra = ()
+    if kw.get("dropout"):
+        extra = (sd((b, 1, 1, s), jnp.float32), sd((), jnp.int32))
+    try:
+        return jax.jit(step).trace(
+            sd((b, s, nh * hd)), sd((b, s, nkv * hd)), sd((b, s, nkv * hd)),
+            sd((b, s, nh * hd)), *extra).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _head_moves(text):
+    """The copy and transpose instructions of a compiled step whose result
+    is a bf16 [B, S, heads * hd] or its heads cut out, either way round."""
+    return re.findall(
+        r"= (bf16\[(?:32,512,768|32,12,512,64|32,512,12,64)\]\S*) "
+        r"(copy|transpose)\(", text)
+
+
+def test_pair_kernels_compile_for_a_v5e_at_berts_size(v5e, monkeypatch):
+    """BERT's [32, 512, 12 x 64] in layout "bshd": two 64-wide heads a
+    128-lane block (`_stack_pair`), dropout and the key-padding mask in
+    the kernels. Mosaic takes all three inside its default 16 MiB of
+    scoped VMEM (the launches ask for no more), and from [32, 512, 768]
+    operands the compiled step moves nothing around them."""
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    asked = []
+    params = fa._compiler_params
+    monkeypatch.setattr(fa, "_compiler_params", lambda *a, **k: asked.append(
+        params(*a, **k)) or asked[-1])
+    text = _rows_launches_text(v5e, ((32, 512, 64), (12, 12)), dropout=0.1)
+    assert len(asked) == 3
+    assert all(p.vmem_limit_bytes is None for p in asked)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkdv"):
+        assert text.count(kernel), kernel
+    assert text.count("tpu_custom_call") == 3
+    assert not _head_moves(text)
+
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_rows_kernels_compile_for_a_v5e_at_the_grouped_cells_size(
+        v5e, window, monkeypatch):
+    """The window / full cell's [1, 8192, 32 x 128] on 4 KV heads in layout
+    "bshd": only the index maps differ from the head-layout launches above
+    (a head is a lane block; K / V rows of 256 B at a stride of 1 KiB), and
+    the VMEM each asks for is what it asks there."""
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    text = _rows_launches_text(v5e, ((1, 8192, 128), (32, 4)), causal=True,
+                               window=window)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkdv"):
+        assert text.count(kernel), kernel
+    assert text.count("tpu_custom_call") == 3
+    assert not re.search(r"bf16\[1,(?:32|4),8192,128\]", text)
+
+
+def test_a_compiled_bert_layer_moves_no_heads(v5e, monkeypatch):
+    """One encoder layer of the s512 cell (rows 32 x 512, 12 heads of 64,
+    AMP, dropout 0.1, a padded batch), its train step traced as the
+    executor traces it and compiled for the described chip: no copy and
+    no transpose of a bf16 [32, 512, 768] or of its heads, forward or
+    backward. Until PR 52 there were 16 a layer (q, k, v and `Out` to
+    [32, 12, 512, 64], dq, dk, dv and dOut back, each in two passes
+    through an S-minor layout: `PERF.md` section 5). The barrier in
+    `_widen` passes dOut as rows for this count's sake."""
+    import jax.extend
+    import paddle_tpu as paddle
+    from paddle_tpu.models import bert
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    monkeypatch.setattr(attention, "_use_pallas",
+                        lambda q: q.shape[2] >= 512 and q.shape[3] == 64)
+    b, s = 32, 512
+    reset_programs(0)
+    cfg = bert.BertConfig(vocab_size=1024, hidden_size=768, num_layers=1,
+                          num_heads=12, intermediate_size=3072,
+                          max_position=s, seq_len=s, hidden_dropout=0.1,
+                          attention_dropout=0.1)
+    _, _, loss = bert.build_pretrain_program(cfg, use_input_mask=True)
+    # one device's step (through fleet the test process's eight virtual
+    # devices would each take four rows)
+    paddle.optimizer.Adam(learning_rate=1e-3).minimize(loss)
+    fluid.default_main_program()._amp = True
+    exe = fluid.Executor()
+    exe.run(fluid.default_startup_program())
+    feed = {"input_ids": np.zeros((b, s), np.int64),
+            "mlm_labels": np.zeros((b, s, 1), np.int64),
+            "input_mask": np.ones((b, s), np.float32)}
+    before = [metrics.get("attention.flash_layout_" + n)
+              for n in ("rows", "heads")]
+    step = exe.step_jaxpr(feed, [loss])
+    assert [metrics.get("attention.flash_layout_" + n) - was for n, was
+            in zip(("rows", "heads"), before)] == [1, 0]
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(jax.extend.core.jaxpr_as_fun(step)).trace(*(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e)
+            for a in step.in_avals)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert text.count("tpu_custom_call") == 3
+    assert "bf16[32,512,768]" in text
+    assert not _head_moves(text)
+
+
 def test_selection_kernels_compile_for_a_v5e_at_the_cells_size(v5e,
                                                                monkeypatch):
     """The learned-selection cell's attention (32 query heads on 4 KV heads

@@ -426,3 +426,96 @@ def test_causal_block_counts(s, window, blocks, want):
         s // bq, bq, s // bk, bk)
     some, every = tiles.any((1, 3)), tiles.all((1, 3))
     assert (int(every.sum()), int((some & ~every).sum())) == want
+
+
+# ---------------------------------------------------------------------------
+# layout "bshd": the kernels read and write a projection's own rows (PR 52)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("case", [
+    # (i) BERT's: 12 heads of 64 in pairs, dropout 0.1, key-padding mask
+    dict(b=2, s=256, nh=12, nkv=12, hd=64, dropout=0.1, padded=True,
+         block_q=128, block_k=128),
+    # the pair's other loops: causal, a window no block size divides, k
+    # blocks under two q blocks, a padded batch and dropout together
+    dict(b=1, s=512, nh=2, nkv=2, hd=64, causal=True, window=300,
+         block_q=128, block_k=256),
+    dict(b=2, s=512, nh=4, nkv=4, hd=64, causal=True, dropout=0.1,
+         padded=True, block_q=256, block_k=128),
+    # (ii) mellum's: 32-on-4 heads of 128, causal, a window of 1,024 and none
+    dict(b=1, s=2048, nh=32, nkv=4, hd=128, causal=True, window=1024),
+    dict(b=1, s=2048, nh=32, nkv=4, hd=128, causal=True),
+    # (iii) solar's: 8-on-1 of 128, causal (no rotary turn before it)
+    dict(b=1, s=1024, nh=8, nkv=1, hd=128, causal=True),
+    # equal head counts at 128 and a width of 256
+    dict(b=2, s=256, nh=2, nkv=2, hd=128, dropout=0.1, padded=True,
+         block_q=128, block_k=128),
+    dict(b=1, s=256, nh=2, nkv=1, hd=256, causal=True, block_q=128,
+         block_k=128),
+], ids=["bert-pairs", "pairs-causal-window", "pairs-causal-dropout-mask",
+        "32on4-window", "32on4-full", "8on1", "equal-128", "256-wide"])
+def test_rows_layout_launches_match_the_head_layout_launches(case):
+    """The three kernels through "bshd" index maps (a head is a lane block
+    of [B, S, heads * hd]; two 64-wide heads one block) against the same
+    kernels on transposed operands."""
+    flash_harness.check_rows_layout(**case)
+
+
+def test_rows_layout_launches_take_a_selection():
+    """keye's: the selection's int8 tiles beside a "bshd" launch's score
+    tiles, empty rows among them, and `selected_probs_sum` reading q and k
+    through the same index maps."""
+    s, block_q, block_k = 512, 128, 256
+    flash_harness.check_rows_layout(
+        1, s, 4, 2, 128, causal=True, block_q=block_q, block_k=block_k,
+        select=flash_harness.selection_with_empty_rows(s, block_q, block_k))
+
+
+def test_a_pairs_dropout_pattern_is_each_heads_own():
+    """Uniform probabilities (q = k = 0) and v = one row of ones a key:
+    `out` is then the kept share of a query's keys times 1 / (1 - rate),
+    per head. In layout "bshd" at 64 wide (pairs) it is bit for bit what
+    each head draws alone in layout "bhsd", and two heads of a pair draw
+    different patterns."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    b, s, nh, hd = 1, 256, 4, 64
+    zeros = jnp.zeros((b, s, nh, hd), jnp.float32)
+    v = jnp.asarray(np.random.RandomState(0).randn(b, s, nh, hd),
+                    jnp.float32)
+    kw = dict(scale=1.0, dropout=0.25, seed=3, block_q=128, block_k=128)
+    rows = flash_attention(zeros, zeros, v, layout="bshd", **kw)
+    heads = flash_attention(*(jnp.swapaxes(t, 1, 2)
+                              for t in (zeros, zeros, v)), **kw)
+    assert np.array_equal(np.asarray(rows),
+                          np.asarray(jnp.swapaxes(heads, 1, 2)))
+    same_v = jnp.broadcast_to(v[:, :, :1], v.shape)
+    drawn = np.asarray(flash_attention(zeros, zeros, same_v, layout="bshd",
+                                       **kw))
+    assert not np.array_equal(drawn[:, :, 0], drawn[:, :, 1])
+
+
+@pytest.mark.parametrize("shape, why", [
+    (dict(nh=3, nkv=3, hd=64), "an odd head count"),
+    (dict(nh=4, nkv=2, hd=64), "64-wide heads on grouped KV heads"),
+    (dict(nh=2, nkv=2, hd=192, hdv=128), "a width of 192"),
+])
+def test_rows_layout_refuses_what_the_kernels_cannot_index(shape, why):
+    """`rows_layout_fits` is the static fact the op's route reads; the
+    launch itself raises for a shape it does not admit (the op never asks:
+    it transposes inside itself there, tests/test_attention_grad_rule.py)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    nh, nkv, hd = shape["nh"], shape["nkv"], shape["hd"]
+    hdv = shape.get("hdv", hd)
+    assert not fa.rows_layout_fits(hd, hdv, nh, nkv)
+    q = jnp.zeros((1, 128, nh, hd), jnp.float32)
+    k = jnp.zeros((1, 128, nkv, hd), jnp.float32)
+    v = jnp.zeros((1, 128, nkv, hdv), jnp.float32)
+    with pytest.raises(ValueError, match="bshd"):
+        fa.flash_attention(q, k, v, layout="bshd")
+    assert fa.rows_layout_fits(64, 64, 12, 12)
+    assert fa.rows_layout_fits(128, 128, 32, 4)
+    assert not fa.rows_layout_fits(64, 64, 12, 12,
+                                   mask_shape=(1, 12, 128, 128))
+    assert fa.rows_layout_fits(64, 64, 12, 12, mask_shape=(32, 1, 1, 128))
+    assert not fa.rows_layout_fits(64, 64, 12, 12, select=True)
+    assert fa.rows_layout_fits(128, 128, 32, 4, select=True)
