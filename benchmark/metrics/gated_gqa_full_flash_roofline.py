@@ -1,0 +1,20 @@
+"""The full-attention layers' flash kernels' share of their roofline where
+a layer's kind sets its head count: the least time for the causal triangle
+at 48 query heads on 8 KV heads, groups of 6
+(benchmark/counts_gated_gqa.py), over the time of the kernels lowered under
+`attn.attend.full`."""
+from benchmark import attn_scopes, counts, counts_gated_gqa
+
+KIND = "full_attention"
+
+
+def read(ctx):
+    if ctx["kind"] != "train":
+        return None
+    taken = attn_scopes.flash_seconds_under(ctx, attn_scopes.SCOPE[KIND])
+    if not taken:
+        return None
+    flops, nbytes = counts_gated_gqa.flash_train_flops_bytes(
+        ctx["cfg"], ctx["rows"] // ctx["chips"], ctx["seq"], KIND)
+    least, _ = counts.roofline_seconds(flops, nbytes, ctx["peaks"])
+    return 100.0 * ctx["traced_readings"] * ctx["k"] * least / taken

@@ -82,6 +82,14 @@ FULL = {
     # window of 1024, as a sparse LM's sliding and full layers run them
     "grouped": {"flash_shape": (1, 8, 4096, 128), "kv_heads": 1,
                 "window": 1024},
+    # head counts that differ with a layer's kind on the same 8 KV heads,
+    # at 8,192 keys: 48 query heads (groups of 6, the first that is no power
+    # of two) over the whole triangle, 64 (groups of 8) inside a window of
+    # 512, at or under one k block; the dense side two heads at a time
+    "grouped_by_kind": {
+        "full": {"flash_shape": (1, 48, 8192, 128), "kv_heads": 8},
+        "window": {"flash_shape": (1, 64, 8192, 128), "kv_heads": 8,
+                   "window": 512}},
     # an expert layer's grouped matmuls at widths that are odd multiples of
     # 128: rows x d x f over 16 experts (scripts/grouped_matmul_sweep.py
     # runs the same function at the benchmark's sizes, tile by tile)
@@ -130,6 +138,10 @@ TINY = {
     "long": {"seq": 32, "batch": 8, "flash_shape": (1, 1, 128, 64)},
     "latent": {"flash_shape": (1, 1, 128, 192), "v_width": 128},
     "grouped": {"flash_shape": (1, 2, 128, 64), "kv_heads": 1, "window": 48},
+    "grouped_by_kind": {
+        "full": {"flash_shape": (1, 12, 128, 64), "kv_heads": 2},
+        "window": {"flash_shape": (1, 16, 128, 64), "kv_heads": 2,
+                   "window": 48}},
     "experts": {"rows": 96, "d": 384, "f": 128, "experts": 4},
     "scan": {"b": 1, "s": 256, "h": 4, "p": 64, "g": 2, "n": 128,
              "chunk": 128},
@@ -353,11 +365,14 @@ def mosaic_calls(hlo_text):
 
 
 def flash_vs_dense(shape, v_width=None, causal=False, kv_heads=None,
-                   window=None):
+                   window=None, dense_heads=None):
     """Op-level check: flash_attention forward and jax.grad against the
     dense XLA attention (ops/attention._xla_attention) in bf16; `v_width`
     gives v (and the output) another width than q and k, `kv_heads` gives k
-    and v fewer heads than q, `window` (with `causal`) a sliding window."""
+    and v fewer heads than q, `window` (with `causal`) a sliding window.
+    `dense_heads`: the dense side that many query heads at a time (a
+    divisor of a group), where all heads' `[S, S]` scores do not fit; the
+    loss is a sum over heads, so the parts' gradients are the whole's."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.attention import _causal_bias, _xla_attention
@@ -384,8 +399,24 @@ def flash_vs_dense(shape, v_width=None, causal=False, kv_heads=None,
 
     (_, out_f), g_f = jax.jit(jax.value_and_grad(
         flash_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-    (_, out_d), g_d = jax.jit(jax.value_and_grad(
-        dense_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    dense = jax.jit(jax.value_and_grad(dense_loss, argnums=(0, 1, 2),
+                                       has_aux=True))
+    if dense_heads is None:
+        (_, out_d), g_d = dense(q, k, v)
+    else:
+        group = shape[1] // k.shape[1]
+        outs, dqs = [], []
+        dk, dv = (jnp.zeros(t.shape, jnp.float32) for t in (k, v))
+        for h in range(0, shape[1], dense_heads):
+            at = h // group
+            (_, out), (gq, gk, gv) = dense(
+                q[:, h:h + dense_heads], k[:, at:at + 1], v[:, at:at + 1])
+            outs.append(out)
+            dqs.append(gq)
+            dk = dk.at[:, at:at + 1].add(gk.astype(jnp.float32))
+            dv = dv.at[:, at:at + 1].add(gv.astype(jnp.float32))
+        out_d = jnp.concatenate(outs, axis=1)
+        g_d = (jnp.concatenate(dqs, axis=1), dk, dv)
     fwd = float(jnp.max(jnp.abs(out_f.astype(jnp.float32)
                                 - out_d.astype(jnp.float32))))
     check(fwd <= FLASH_FWD_TOL,
@@ -440,10 +471,16 @@ def leg_attention_window_grouped(preset, clock):
     group inside the dkdv kernel), causal, with a sliding window and
     without, against the dense route."""
     grouped = preset["grouped"]
-    return {name: flash_vs_dense(grouped["flash_shape"], causal=True,
-                                 kv_heads=grouped["kv_heads"], window=window)
-            for name, window in (("window", grouped["window"]),
-                                 ("full", None))}
+    facts = {name: flash_vs_dense(grouped["flash_shape"], causal=True,
+                                  kv_heads=grouped["kv_heads"], window=window)
+             for name, window in (("window", grouped["window"]),
+                                  ("full", None))}
+    # and where the head count follows the layer's kind: groups of 6 and 8
+    for name, case in preset["grouped_by_kind"].items():
+        facts[name + "_by_kind"] = flash_vs_dense(
+            case["flash_shape"], causal=True, kv_heads=case["kv_heads"],
+            window=case.get("window"), dense_heads=2)
+    return facts
 
 
 # ---------------------------------------------------------------------------

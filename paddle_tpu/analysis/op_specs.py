@@ -336,7 +336,8 @@ SPECS: Dict[str, OpSpec] = {
         attr_types={"theta": _NUM, "rotary_dim": int, "layout": str,
                     "rope_type": str, "factor": _NUM,
                     "original_max_position": int, "beta_fast": _NUM,
-                    "beta_slow": _NUM, "scale": _NUM, "sections": _LIST},
+                    "beta_slow": _NUM, "scale": _NUM, "sections": _LIST,
+                    "rotary_start": int},
         sharding="follow_x"),
     "swiglu": OpSpec(
         inputs={"Gate": ONE, "Up": ONE}, outputs={"Out": ONE},

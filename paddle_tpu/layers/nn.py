@@ -1000,9 +1000,12 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 def rotary_embedding(x, theta=10000.0, rotary_dim=None, layout="interleaved",
                      rope_type="default", factor=1.0, original_max_position=0,
                      beta_fast=32.0, beta_slow=1.0, scale=1.0, positions=None,
-                     sections=None):
+                     sections=None, rotary_start=None):
     """Rotary positions on x [..., S, D]: the last `rotary_dim` features
     (default all) turn by position along axis -2; the rest passes through.
+    `rotary_start`: the turned features begin there instead (0: the FIRST
+    `rotary_dim`, as a `partial_rotary_factor` under the half-split layout
+    reads; the pairs are (j, j + rotary_dim/2) inside the turned part).
     `layout`: pairs "interleaved" (2j, 2j+1) or "half" (j, j + rotary_dim/2).
     `rope_type` names the frequency rule: "default", theta^(-2j/rotary_dim),
     or "yarn", which blends it with the same divided by `factor` over a ramp
@@ -1028,6 +1031,8 @@ def rotary_embedding(x, theta=10000.0, rotary_dim=None, layout="interleaved",
                              "\"half\" and sections")
         inputs["Positions"] = [positions]
         attrs["sections"] = [int(n) for n in sections]
+    if rotary_start is not None:
+        attrs["rotary_start"] = int(rotary_start)
     helper.append_op("rotary_embedding", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

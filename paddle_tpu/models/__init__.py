@@ -8,7 +8,7 @@ attention, TP rules) -> gpt.py, and SE-ResNeXt 50/101/152 (the reference's
 canonical dist-test model, grouped convs + squeeze-excitation)
 -> se_resnext.py.
 
-Seven sparse causal LMs, each one expert-parallel rank's share of a published
+Eight sparse causal LMs, each one expert-parallel rank's share of a published
 configuration, trained: deepseek_v3.py (latent attention, sigmoid-routed
 experts without drops, shared experts), mellum.py (sliding-window and full
 attention in a period, grouped KV heads, yarn, softmax-routed experts),
@@ -34,11 +34,19 @@ softmax attention on grouped KV heads without rotary positions under an
 element-wise output gate: the decay `-exp(A_log) softplus(.)` has no lower
 bound, so `kda_scan` makes a chunk's decayed products level by level with no
 factor above 1; `beta` in (0, 2); both gates' projections low-rank; every
-layer sparse, a shared expert beside the routed ones).
+layer sparse, a shared expert beside the routed ones), laguna.py (a query
+head COUNT that differs with the layer's kind, 64 in the sliding-window
+layers and 48 in the full ones on 8 KV heads, so groups of 8 and of 6 meet
+in one step; rotary positions on the FIRST half of a head in the full
+layers, `rotary_embedding`'s `rotary_start`, with yarn's table over that
+half, all of a head in the sliding ones; the element-wise output gate; a
+dense layer before sigmoid-routed experts of the narrowest width, 32 held a
+rank, scaled by 2.5 beside a shared one).
 What they share is written
 once in causal_lm.py (the leaves, the expert layer around `routed_moe`,
 attention on grouped KV heads, the layer loop, the loss); a model file holds
 its configuration, the mixers of its own and which layer gets what.
 """
 from . import (lenet, resnet, bert, wide_deep, gpt, se_resnext, causal_lm,
-               deepseek_v3, mellum, nemotron_h, ling, keye, lfm2, solar)
+               deepseek_v3, mellum, nemotron_h, ling, keye, lfm2, solar,
+               laguna)

@@ -33,25 +33,43 @@ def _rms_norm(ctx, ins, attrs):
     return {"Y": [y.astype(x.dtype)]}
 
 
-def rotary_interleaved(x, theta: float, rotary_dim: int):
-    """Rotate the LAST `rotary_dim` features of x [..., S, D] by position
-    (axis -2, positions 0..S-1): the pairs (2i, 2i+1) turn by
-    pos * theta^(-2i / rotary_dim) (Su et al. 2021, the interleaved
-    layout). The rest of D passes through."""
-    s, d = x.shape[-2], x.shape[-1]
+def _turned_part(x, rotary_dim: int, start):
+    """(first turned feature, the `rotary_dim` turned features of x in
+    float32): from `start`, or the LAST ones (None)."""
+    start = x.shape[-1] - rotary_dim if start is None else int(start)
+    if not 0 <= start <= x.shape[-1] - rotary_dim:
+        raise ValueError(
+            f"rotary_embedding: rotary_start {start} + rotary_dim "
+            f"{rotary_dim} lies outside the {x.shape[-1]} features")
+    return start, x[..., start:start + rotary_dim].astype(jnp.float32)
+
+
+def _with_rest(x, out, start: int):
+    """`out` back between the features of x that pass through."""
+    end = start + out.shape[-1]
+    if end - start == x.shape[-1]:
+        return out
+    return jnp.concatenate(
+        ([x[..., :start]] if start else []) + [out]
+        + ([x[..., end:]] if end < x.shape[-1] else []), axis=-1)
+
+
+def rotary_interleaved(x, theta: float, rotary_dim: int, start=None):
+    """Rotate `rotary_dim` features of x [..., S, D], from `start` (None:
+    the LAST ones), by position (axis -2, positions 0..S-1): the pairs
+    (2i, 2i+1) turn by pos * theta^(-2i / rotary_dim) (Su et al. 2021, the
+    interleaved layout). The rest of D passes through."""
+    s = x.shape[-2]
     half = rotary_dim // 2
     freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0
                             / rotary_dim))
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None]
     cos, sin = jnp.cos(ang), jnp.sin(ang)                    # [S, half]
-    rot = x[..., d - rotary_dim:].astype(jnp.float32)
+    start, rot = _turned_part(x, rotary_dim, start)
     pairs = rot.reshape(rot.shape[:-1] + (half, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    out = out.reshape(rot.shape).astype(x.dtype)
-    if rotary_dim == d:
-        return out
-    return jnp.concatenate([x[..., :d - rotary_dim], out], axis=-1)
+    return _with_rest(x, out.reshape(rot.shape).astype(x.dtype), start)
 
 
 def rotary_frequencies(theta: float, rotary_dim: int, rope_type="default",
@@ -104,23 +122,22 @@ def stream_angles(positions, freq, sections, ndim: int):
     return ang if ndim == 3 else ang[:, None]
 
 
-def rotary_half(x, freq, rotary_dim: int, scale: float, angles=None):
-    """Rotate the LAST `rotary_dim` features of x [..., S, D] over the
-    half-split pairs (j, j + rotary_dim / 2) by pos * freq[j], cos and sin
-    times `scale`; `angles` (`stream_angles`) in place of the row's own
-    positions times freq."""
-    s, d = x.shape[-2], x.shape[-1]
+def rotary_half(x, freq, rotary_dim: int, scale: float, angles=None,
+                start=None):
+    """Rotate `rotary_dim` features of x [..., S, D], from `start` (None:
+    the LAST ones), over the half-split pairs (j, j + rotary_dim / 2) of
+    the turned part by pos * freq[j], cos and sin times `scale`; `angles`
+    (`stream_angles`) in place of the row's own positions times freq."""
+    s = x.shape[-2]
     half = rotary_dim // 2
     ang = angles if angles is not None else jnp.arange(
         s, dtype=jnp.float32)[:, None] * jnp.asarray(freq, jnp.float32)[None]
     cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale    # [S, half]
-    rot = x[..., d - rotary_dim:].astype(jnp.float32)
+    start, rot = _turned_part(x, rotary_dim, start)
     a, b = rot[..., :half], rot[..., half:]
     out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                           axis=-1).astype(x.dtype)
-    if rotary_dim == d:
-        return out
-    return jnp.concatenate([x[..., :d - rotary_dim], out], axis=-1)
+    return _with_rest(x, out, start)
 
 
 @register("rotary_embedding", nondiff_slots=("Positions",))
@@ -128,10 +145,13 @@ def _rotary_embedding(ctx, ins, attrs):
     """`Positions` [streams, B, S] (optional, layout "half"): several
     position streams, the pairs shared out by the attr `sections`; without
     it the streams are the row's own positions, the sections change no
-    number and the op is what it was."""
+    number and the op is what it was. The attr `rotary_start` says where
+    the `rotary_dim` turned features begin (a partial rotary factor that
+    turns the FIRST features of a head); without it they are the last."""
     x = ins["X"][0]
     theta = float(attrs.get("theta", 10000.0))
     rotary_dim = int(attrs.get("rotary_dim", x.shape[-1]))
+    start = attrs.get("rotary_start")
     layout = attrs.get("layout", "interleaved")
     rope_type = attrs.get("rope_type", "default")
     scale = float(attrs.get("scale", 1.0))
@@ -140,7 +160,7 @@ def _rotary_embedding(ctx, ins, attrs):
             raise ValueError(
                 f"rotary_embedding: rope_type {rope_type!r} or a scale "
                 "needs layout \"half\"")
-        return {"Out": [rotary_interleaved(x, theta, rotary_dim)]}
+        return {"Out": [rotary_interleaved(x, theta, rotary_dim, start)]}
     if layout != "half":
         raise ValueError(f"rotary_embedding: unknown layout {layout!r}")
     freq = rotary_frequencies(
@@ -148,10 +168,10 @@ def _rotary_embedding(ctx, ins, attrs):
         int(attrs.get("original_max_position", 0)),
         float(attrs.get("beta_fast", 32.0)),
         float(attrs.get("beta_slow", 1.0)))
-    if not ins.get("Positions"):
-        return {"Out": [rotary_half(x, freq, rotary_dim, scale)]}
-    return {"Out": [rotary_half(x, freq, rotary_dim, scale, stream_angles(
-        ins["Positions"][0], freq, attrs["sections"], x.ndim))]}
+    angles = stream_angles(
+        ins["Positions"][0], freq, attrs["sections"],
+        x.ndim) if ins.get("Positions") else None
+    return {"Out": [rotary_half(x, freq, rotary_dim, scale, angles, start)]}
 
 
 @register("swiglu")

@@ -257,6 +257,21 @@ def held_arrays(params, offset, held, bias=True):
     return arrays
 
 
+def uncut_expert_layer(total, d=16, f=8, n=96, seed=0):
+    """(tokens [n, d], the leaves of an uncut expert layer of `total` gated
+    experts of width f with a selection bias of zeros and a shared expert,
+    by their published names), seeded."""
+    rng = np.random.RandomState(seed)
+    mat = lambda *shape: rng.randn(*shape).astype(np.float32) * 0.2  # noqa: E731
+    params = {
+        "router_w": mat(d, total) * 1.5, "router_bias": np.zeros(
+            total, np.float32), "experts_gate_w": mat(total, d, f),
+        "experts_up_w": mat(total, d, f), "experts_down_w": mat(total, f, d),
+        "shared_gate_w": mat(d, f), "shared_up_w": mat(d, f),
+        "shared_down_w": mat(f, d)}
+    return rng.randn(n, d).astype(np.float32), params
+
+
 def mixer_program(build, cfg, x, params, pre):
     """One share's mixer `build(x, cfg, pre)` through a Program from the
     leaves `params`: its output on `x`."""

@@ -389,18 +389,6 @@ def test_the_head_shares_add_up_to_the_uncut_layer(kind, n):
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=5e-6)
 
 
-def _uncut_expert_layer(total, d=16, f=8, n=96, seed=0):
-    rng = np.random.RandomState(seed)
-    mat = lambda *shape: rng.randn(*shape).astype(np.float32) * 0.2  # noqa: E731
-    params = {
-        "router_w": mat(d, total) * 1.5, "router_bias": np.zeros(
-            total, np.float32), "experts_gate_w": mat(total, d, f),
-        "experts_up_w": mat(total, d, f), "experts_down_w": mat(total, f, d),
-        "shared_gate_w": mat(d, f), "shared_up_w": mat(d, f),
-        "shared_down_w": mat(f, d)}
-    return rng.randn(n, d).astype(np.float32), params
-
-
 def _expert_cfg(held, total, offset, top_k):
     return dict(n_routed_experts=held, experts_total=total,
                 expert_offset=offset, num_experts_per_tok=top_k,
@@ -412,7 +400,7 @@ def test_the_ranks_routed_parts_and_the_shared_expert_add_up():
     give plus the shared expert COUNTED ONCE are the uncut reference's
     layer; every rank's TopIdx is the reference's choice and the loads are
     its counts."""
-    x, params = _uncut_expert_layer(16)
+    x, params = harness.uncut_expert_layer(16)
     p = {"l_" + k: jnp.asarray(v) for k, v in params.items()}
     with jax.default_matmul_precision("highest"):
         whole, want_idx = ref.expert_layer(jnp.asarray(x)[None], p, "l_",
@@ -440,7 +428,7 @@ def test_a_router_of_320_over_40_ranks(rank):
     nor a whole number of lane tiles (2.5 x 128), top-8, 8 held a rank of
     40, the first count of ranks that is no power of two: a rank's routed
     part, choice and loads are the reference's."""
-    x, params = _uncut_expert_layer(320, n=160, seed=rank)
+    x, params = harness.uncut_expert_layer(320, n=160, seed=rank)
     offset = 8 * rank
     out, idx, load = harness.routed_share(
         x, harness.held_arrays(params, offset, 8), 8, 320, offset)
